@@ -170,7 +170,10 @@ def main():
          "--out", args.out]
         + (["--quick"] if args.quick else [])
         + (["--hier", args.hier] if args.hier else [])
-        + (["--crc"] if args.crc else []))
+        + (["--crc"] if args.crc else []),
+        # Host plane only (numpy over TCP): the workers stay off any
+        # accelerator, which one process at a time may hold.
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})
         for r in range(args.nproc)]
     rc = [p.wait() for p in procs]
     if any(rc):

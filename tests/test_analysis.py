@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from torchmpi_tpu._compat import shard_map
+from jax import shard_map
 from torchmpi_tpu.analysis import (abi, jaxpr_lint, knobs, locks, registry,
                                    threads, wire)
 

@@ -1,0 +1,105 @@
+"""Ask the chip's compiler, without the chip: the kernels of the main path
+at their real widths, compiled for a described TPU v5e 2x2 (the machine the
+chip tool hands out).  Interpret mode passes shapes Mosaic refuses — a
+slice off the tiling, more VMEM than a kernel may use, a kernel GSPMD would
+have to partition — so these guard every later PR at no chip time.  A
+compile that passes is not a chip run: nothing here says a result is right
+or fast.
+
+Refusals are pinned as they are.  The day someone re-tiles the ring kernel
+the ``pytest.raises`` below fails, and tells them to move the bound.
+"""
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from torchmpi_tpu.ops import flash_attention
+from torchmpi_tpu.ops.flash_attention import flash_bwd_block, flash_fwd_block
+from torchmpi_tpu.runtime import topology
+
+H, D = 32, 128          # Llama-3-8B attention heads
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    try:
+        return topology.topology_devices("v5e-4")
+    except Exception as e:  # noqa: BLE001 — no libtpu in this install
+        pytest.skip(f"TPU topology descriptions unavailable: {e!r}")
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _kernels(compiled) -> int:
+    return compiled.as_text().count("tpu_custom_call")
+
+
+@pytest.mark.parametrize("L", [4096, 16384])
+def test_flash_fwd_and_grad(v5e, L):
+    """``ops.flash_attention`` forward and ``jax.grad``, (1, L, 32, 128)
+    bf16 causal, on one chip."""
+    x = _sds((1, L, H, D), jnp.bfloat16, SingleDeviceSharding(v5e[0]))
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=False)
+
+    def loss(q, k, v):
+        return jnp.sum(fwd(q, k, v).astype(jnp.float32))
+
+    assert _kernels(jax.jit(fwd).lower(x, x, x).compile()) == 1
+    assert _kernels(jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+                    .lower(x, x, x).compile()) == 3     # fwd, dq, dk/dv
+
+
+def test_ring_flash_blocks_f32_out(v5e):
+    """The ring's per-chunk kernels at L=16384 over sp=4: local Q against
+    one circulating K/V chunk of 4096, partial outputs carried in f32."""
+    one = SingleDeviceSharding(v5e[0])
+    Lc = 16384 // 4
+    x = _sds((H, Lc, D), jnp.bfloat16, one)
+    row = _sds((H, Lc, 1), jnp.float32, one)
+
+    def fwd(q, k, v):
+        return flash_fwd_block(q, k, v, causal=True, interpret=False,
+                               out_dtype=jnp.float32)
+
+    def bwd(q, k, v, do, lse, delta):
+        return flash_bwd_block(q, k, v, do, lse, delta, causal=True,
+                               interpret=False, out_dtype=jnp.float32)
+
+    assert _kernels(jax.jit(fwd).lower(x, x, x).compile()) == 1
+    assert _kernels(jax.jit(bwd).lower(x, x, x, x, row, row).compile()) == 2
+
+
+def _ring_allreduce(n):
+    fn, args = topology._build_pallas_ring("v5e-4", "float32", n)
+    return jax.jit(fn).lower(*args).compile()
+
+
+def test_ring_allreduce_compiles_at_64k_elements(v5e):
+    assert _kernels(_ring_allreduce(1 << 16)) == 1
+
+
+def test_ring_allreduce_vmem_bound_pinned(v5e):
+    """The ring keeps the whole payload in VMEM, so 4,194,304 elements
+    (16 MB of float32, a sixth of a ResNet-50 gradient) do not compile:
+    40 MB of scoped VMEM against a 16 MB limit.  Pinned, not repaired
+    (ROADMAP queue 3 item 6): re-tile the kernel and move this bound."""
+    with pytest.raises(Exception, match="(?i)vmem"):
+        _ring_allreduce(1 << 22)
+
+
+def test_llama_flash_step_on_dp_tp(v5e, monkeypatch):
+    """``make_train_step(attn="flash")`` on dp=2 x tp=2: the Mosaic kernel
+    must reach the compiler inside a shard_map (under GSPMD it is refused:
+    "Mosaic kernels cannot be automatically partitioned").  The step reads
+    the running backend to choose interpret mode, so the test answers for
+    it; no option of the program does."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    fn, args = topology._build_llama_dp_tp("v5e-4", attn="flash")
+    assert _kernels(jax.jit(fn).lower(*args).compile()) > 0

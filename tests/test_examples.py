@@ -190,23 +190,7 @@ class TestExamplesConverge:
         assert "'ep': 4" in out and "tok/s" in out
 
 
-#: the documented environment failure from PR 1 (CHANGES.md): on a
-#: <=2-core host running the pre-0.5 jax this example converges to ~45%,
-#: under the 70% bar — an environment limit (thread-starved 8-virtual-
-#: device collectives + old-partitioner numerics), not a code bug.  The
-#: xfail is CONDITIONAL on exactly that box shape so a real regression
-#: still fails loudly everywhere else, and non-strict so a lucky run on
-#: the gated box stays green.
-_SMALL_OLD_BOX = (os.cpu_count() or 1) <= 2 and __import__(
-    "torchmpi_tpu._compat", fromlist=["JAX_PRE_05"]).JAX_PRE_05
-
-
 class TestResNetExample:
-    @pytest.mark.xfail(
-        condition=_SMALL_OLD_BOX, strict=False,
-        reason="documented environment failure (CHANGES.md PR 1): "
-               "converges to ~45% (<70% bar) on a 2-core host with "
-               "jax<0.5; passes on real multi-core/current-jax boxes")
     def test_train_eval_checkpoint_resume(self, tmp_path):
         """BASELINE config 2 end to end: train, EMA BN stats, inference-mode
         eval, async checkpointing, then resume (params AND stats restored)

@@ -19,21 +19,6 @@ def _data(cfg, B=4, L=16, seed=0):
     return tokens, targets
 
 
-# jax < 0.5's SPMD partitioner refuses the AUTO-axes pipeline paths: the
-# scheduled body's axis_index lowers to a PartitionId instruction inside a
-# partial-auto shard_map region, which that partitioner rejects as
-# ambiguous ("PartitionId instruction is not supported for SPMD
-# partitioning").  Reproduced on the unmodified seed; the manual-axes
-# forms (and the AOT TPU compiles, runtime/topology.py) are unaffected.
-from torchmpi_tpu._compat import JAX_PRE_05
-
-_xfail_auto_shardmap = pytest.mark.xfail(
-    JAX_PRE_05, strict=False,
-    reason="jax<0.5 partitioner rejects PartitionId in partial-auto "
-           "shard_map (the GSPMD-composed pipeline paths)")
-_xfail_auto_1f1b = _xfail_auto_shardmap
-
-
 class TestGeometry:
     def test_llama3_8b_param_count(self):
         """Llama-3-8B has ~8.03B parameters."""
@@ -303,6 +288,46 @@ class TestSharded:
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-4, atol=2e-4)
 
+    def test_flash_dp_tp_matches_full(self, devices):
+        """attn='flash' on a dp x tp mesh == attn='full', loss and grads.
+        The kernel must sit in a shard_map over batch and heads: the TPU
+        compiler refuses to partition a Mosaic kernel under GSPMD (only
+        interpret mode, plain XLA ops, ever let that pass on this mesh)."""
+        cfg = llama.tiny()
+        params = llama.init(jax.random.PRNGKey(0), cfg)
+        batch = _data(cfg, B=4, L=32)
+        mesh = parallel.make_mesh({"dp": 2, "tp": 2}, devices=devices[:4])
+        sharded = llama.shard_params(params, mesh, cfg)
+        out = {}
+        for attn in ("full", "flash"):
+            fn = jax.value_and_grad(llama.make_loss_fn(cfg, mesh, attn=attn))
+            out[attn] = jax.jit(fn)(sharded, batch)
+        assert "shard_map" in str(jax.make_jaxpr(
+            llama.make_loss_fn(cfg, mesh, attn="flash"))(sharded, batch))
+        np.testing.assert_allclose(float(out["flash"][0]),
+                                   float(out["full"][0]), rtol=1e-5)
+        for a, b in zip(jax.tree.leaves(out["flash"][1]),
+                        jax.tree.leaves(out["full"][1])):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-4, atol=2e-5)
+
+    def test_pp_auto_flash_matches_single(self, devices):
+        """GPipe stages with GSPMD-composed dp/tp (stage_tp='auto'): the
+        flash kernel nests its shard_map over the axes pp left auto, and
+        the step's loss is the plain single-device loss."""
+        cfg = llama.Config(vocab=128, d_model=32, n_layers=2, n_heads=4,
+                           n_kv_heads=2, d_ff=64)
+        params = llama.init(jax.random.PRNGKey(0), cfg)
+        tokens, targets = _data(cfg, B=4, L=16)
+        want = float(llama.make_loss_fn(cfg)(params, (tokens, targets)))
+        mesh = parallel.make_mesh({"dp": 2, "pp": 2, "tp": 2},
+                                  devices=devices)
+        step, _ = llama.make_pp_train_step(cfg, mesh, n_microbatches=2,
+                                           lr=0.1, attn="flash")
+        _, loss = step(llama.shard_params_pp(params, mesh, cfg),
+                       tokens, targets)
+        np.testing.assert_allclose(float(loss), want, rtol=1e-5)
+
     def test_ring_native_gqa_traffic(self, devices):
         """The ring circulates K/V at n_kv_heads (not repeated to n_heads):
         the compiled sp program's collective-permute payload must scale with
@@ -361,7 +386,6 @@ class TestSharded:
         with pytest.raises(ValueError, match="not divisible"):
             llama.make_loss_fn(cfg, loss_chunk=5)(params, (tokens, targets))
 
-    @_xfail_auto_shardmap
     def test_pp_train_matches_single(self, devices):
         """Pipeline-parallel llama (layers as GPipe stages over pp) produces
         the same loss and updated params as plain single-mesh training."""
@@ -385,7 +409,6 @@ class TestSharded:
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=2e-4, atol=2e-5)
 
-    @_xfail_auto_shardmap
     def test_pp_multi_layer_stages(self, devices):
         """V > 1 layers per stage: 4-layer model over pp=2."""
         cfg = llama.Config(vocab=128, d_model=32, n_layers=4, n_heads=4,
@@ -403,7 +426,6 @@ class TestSharded:
             losses.append(float(loss))
         assert losses[-1] < losses[0] - 0.2, losses
 
-    @_xfail_auto_1f1b
     def test_1f1b_3d_composed_matches_oracle(self, devices):
         """1F1B on the dp x pp x tp mesh: pp manual, dp/tp GSPMD-composed —
         legal under the scheduled lax.conds because every predicate
@@ -472,7 +494,6 @@ class TestSharded:
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=3e-3, atol=2e-4)
 
-    @_xfail_auto_1f1b
     def test_1f1b_train_matches_oracle(self, devices):
         """llama over the 1F1B schedule: FULL-model grads (stage vjps +
         last-stage norm/head loss-params + embed scatter-add from the
@@ -501,7 +522,6 @@ class TestSharded:
             losses.append(float(loss))
         assert losses[-1] < losses[0] - 0.2, losses
 
-    @_xfail_auto_shardmap
     def test_pp3d_matches_oracle(self, devices):
         """The 3-D dp x pp x tp step (VERDICT r03 item 2): stage params
         tp-sharded, micro-batches dp-sharded, pp manual — loss and the
@@ -622,7 +642,6 @@ class TestSharded:
             llama.make_1f1b_train_step(cfg, mesh_no_tp, n_microbatches=4,
                                        attn="flash", stage_tp="manual")
 
-    @_xfail_auto_shardmap
     def test_pp3d_zero1_adam(self, devices):
         """3-D pp step with optax adam + ZeRO-1: optimizer moments shard
         over dp on top of the pp x tp layout and the step runs finite."""
@@ -685,7 +704,6 @@ class TestSharded:
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-4, atol=2e-4)
 
-    @_xfail_auto_shardmap
     def test_zero1_matches_plain_adam(self, devices):
         """make_train_step(zero1=True): optimizer moments shard over dp with
         the per-parameter tp layout preserved (path-suffix matching: wq

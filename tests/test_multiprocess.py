@@ -22,16 +22,6 @@ from conftest import COLLECTIVE_TIMEOUT_FLAG
 # Two full JAX interpreters boot and train: ~a minute of wall time.
 pytestmark = pytest.mark.heavy
 
-# jaxlib < 0.5's CPU backend has no cross-process device collectives at all
-# ("Multiprocess computations aren't implemented on the CPU backend"), so
-# the jax.distributed two-process tests cannot run there; the host-plane
-# (TCP ring / PS) multi-process tests below are unaffected.
-from torchmpi_tpu._compat import JAXLIB_PRE_05
-
-_xfail_cpu_multiprocess = pytest.mark.xfail(
-    JAXLIB_PRE_05, strict=False,
-    reason="jaxlib<0.5 CPU backend lacks multiprocess computations")
-
 _WORKER = textwrap.dedent("""
     import os, sys
     os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=2 "
@@ -408,7 +398,6 @@ def _launch_workers(script_path, argv_per_pid, tag, timeout,
         assert f"{tag}-{pid}-OK" in out, out
 
 
-@_xfail_cpu_multiprocess
 def test_two_process_distributed(tmp_path):
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     script = tmp_path / "worker.py"
@@ -423,7 +412,6 @@ def test_two_process_distributed(tmp_path):
         for pid in range(2)], tag="WORKER", timeout=150)
 
 
-@_xfail_cpu_multiprocess
 def test_two_process_parallelism_matrix(tmp_path):
     """The round-3 shape matrix across REAL process boundaries (the
     no-cluster analogue of the reference's HOSTFILE loop,

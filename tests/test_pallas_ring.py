@@ -87,7 +87,7 @@ class TestRingAllreduce:
         the compiled-engine integration surface."""
         import jax
         import jax.numpy as jnp
-        from torchmpi_tpu._compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from torchmpi_tpu.runtime.communicator import RANK_AXIS
 

@@ -8,7 +8,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from torchmpi_tpu._compat import shard_map
+from jax import shard_map
 
 from torchmpi_tpu import parallel
 from torchmpi_tpu.parallel import blocks as blocks_mod

@@ -864,12 +864,15 @@ class TestScale100Series:
 
 class TestRealHistoryGreen:
     def test_repo_history_passes(self):
-        """Acceptance: the gate runs green against the real artifact
-        trajectory (BENCH_r01..r05 + the OBS drills)."""
+        """Acceptance: the gate runs green against the artifacts at the
+        repo root.  The rounds 1-5 chip records are gone (their set-up no
+        longer exists), so no device series has two points; what still
+        gates are the host-side bands."""
         report = perf_gate.evaluate(_REPO)
         assert report["verdict"] == "PASS", json.dumps(report, indent=1)
-        gated = [c for c in report["checks"] if c["status"] == "pass"]
-        assert len(gated) >= 2   # img/s + guard delta at minimum
+        gated = {c["metric"] for c in report["checks"]
+                 if c["status"] == "pass"}
+        assert gated == {"trace_off_guard_delta_ms", "scale_pause_ms"}
 
     def test_cli_green(self):
         rc = perf_gate.main(["--dir", _REPO])

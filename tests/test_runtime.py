@@ -44,6 +44,39 @@ class TestLifecycle:
         mpi.stop()
 
 
+class TestCompileCache:
+    """``lifecycle.use_compile_cache``: the persistent cache's directory is
+    part of its key, so it must stay put."""
+
+    @pytest.fixture()
+    def cache_dir_config(self):
+        prior = jax.config.jax_compilation_cache_dir
+        yield
+        jax.config.update("jax_compilation_cache_dir", prior)
+
+    def test_env_wins_and_nothing_is_set_in_code(self, monkeypatch,
+                                                 cache_dir_config):
+        from torchmpi_tpu.runtime import lifecycle
+
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
+        assert lifecycle.use_compile_cache() == "/somewhere/else"
+        assert jax.config.jax_compilation_cache_dir is None
+
+    def test_fixed_path_beside_the_package(self, monkeypatch,
+                                           cache_dir_config):
+        import os
+
+        from torchmpi_tpu.runtime import lifecycle
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        first = lifecycle.use_compile_cache()
+        assert lifecycle.use_compile_cache() == first
+        assert first == os.path.join(
+            os.path.dirname(os.path.dirname(mpi.__file__)), ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == first
+
+
 class TestCommunicatorHierarchy:
     """Reference: test/hierarchical_communicators.lua:30-81 — push rank%3,
     check intra group shapes and the cartesian predicate."""

@@ -138,7 +138,7 @@ class TestRingFlash:
     def test_batched_matches_vmapped_oracle(self, devices):
         """The batch-folded form == per-example oracle attention."""
         from jax.sharding import PartitionSpec as P
-        from torchmpi_tpu._compat import shard_map
+        from jax import shard_map
 
         mesh = parallel.make_mesh({"sp": 8}, devices=devices)
         B, L, H, KV, D = 2, 64, 4, 2, 16

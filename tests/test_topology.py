@@ -38,26 +38,36 @@ class TestTopologyDescriptions:
 
 
 class TestHloCollectiveStats:
+    """Fixtures are in the installed JAX's HLO text: operands are named
+    without their types, so the parser resolves each to its definition."""
+
     def test_operand_dtype_and_bytes(self):
         hlo = (
-            "  %all-reduce.1 = (bf16[8,256]{1,0:T(8,128)(2,1)}) "
-            "all-reduce(f32[8,256]{1,0:T(8,128)S(1)} %fusion.1), "
-            "channel_id=1, replica_groups={{0,1},{2,3}}, metadata={}\n"
-            "  %cp = f32[4]{0} collective-permute(f32[4]{0} %x), "
-            "source_target_pairs={{0,1}}\n"
-            "  %ard = f32[8]{0} all-reduce-done(f32[8]{0} %s)\n"
+            "  %fusion.1 = f32[8,256]{1,0:T(8,128)S(1)} fusion(%p0), "
+            "kind=kLoop, calls=%fused_computation\n"
+            "  %all-reduce.1 = f32[8,256]{1,0:T(8,128)S(1)} "
+            "all-reduce(%fusion.1), channel_id=1, "
+            "replica_groups={{0,1},{2,3}}, metadata={op_name=\"a(b)\"}\n"
+            "  %x = bf16[4]{0} parameter(0)\n"
+            "  %ag = bf16[16]{0} all-gather(%x), dimensions={0}\n"
+            "  %s = f32[8]{0} all-reduce-start(%fusion.1)\n"
+            "  %ard = f32[8]{0} all-reduce-done(%s)\n"
         )
         stats = topology.hlo_collective_stats(hlo)
-        # Wire dtype is the OPERAND dtype (f32 here, despite bf16 result);
-        # -done halves don't double count.
-        assert stats["counts"] == {"all-reduce:f32": 1,
-                                   "collective-permute:f32": 1}
-        assert stats["operand_bytes"]["all-reduce:f32"] == 8 * 256 * 4
+        # -start folds onto the base opcode, -done does not double count.
+        assert stats["counts"] == {"all-reduce:f32": 2,
+                                   "all-gather:bf16": 1}
+        assert stats["operand_bytes"]["all-reduce:f32"] == 2 * 8 * 256 * 4
+        # The OPERAND is what rides the wire, not the 4x gathered result.
+        assert stats["operand_bytes"]["all-gather:bf16"] == 4 * 2
 
     def test_tuple_operands_sum(self):
-        hlo = ("  %ar = (bf16[4]{0}, bf16[8]{0}) "
-               "all-reduce(bf16[4]{0} %a, bf16[8]{0} %b), channel_id=1\n")
+        hlo = ("  %a = bf16[4]{0} parameter(0)\n"
+               "  %b = bf16[8]{0} parameter(1)\n"
+               "  ROOT %ar = (bf16[4]{0:T(8,128)(2,1)}, /*index=1*/"
+               "bf16[8]{0}) all-reduce(%a, %b), channel_id=1\n")
         stats = topology.hlo_collective_stats(hlo)
+        assert stats["counts"] == {"all-reduce:bf16": 1}
         assert stats["operand_bytes"]["all-reduce:bf16"] == (4 + 8) * 2
 
 
@@ -90,7 +100,7 @@ class TestManualPsumGate:
 
         f32 = ar_bytes(records["manual_psum_f32"])
         bf16 = ar_bytes(records["manual_psum_bf16"])
-        assert f32 == 2 * bf16, (f32, bf16)
+        assert bf16 > 0 and f32 == 2 * bf16, (f32, bf16)
 
     def test_memory_stats_recorded(self, records):
         mem = records["manual_psum_bf16"].get("memory")

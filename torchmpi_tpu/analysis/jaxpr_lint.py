@@ -101,7 +101,7 @@ SUPPRESSIONS: List[Suppression] = [
 
 
 def _sub_jaxprs(eqn) -> List[Tuple[str, Any]]:
-    import jax.core as core
+    from jax.extend import core
 
     out = []
     for k, v in eqn.params.items():

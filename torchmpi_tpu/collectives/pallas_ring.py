@@ -50,16 +50,6 @@ On a CPU mesh the kernels run under Pallas TPU *interpret* mode
 (``pltpu.InterpretParams``), which emulates the RDMA/semaphore semantics —
 the correctness fixture for the 8-device virtual mesh; on a real TPU mesh
 they compile to Mosaic with true inter-chip DMA.
-
-On a jax whose pallas has no TPU-semantics interpreter (the 0.4.x line:
-its generic ``interpret=True`` cannot discharge remote DMAs/semaphores),
-the public entry points EMULATE the rings on non-TPU backends with the
-algebraically identical XLA collectives (psum / psum_scatter / all_gather
-over the same axis) so callers and the selector keep one contract;
-``RING_KERNELS_AVAILABLE`` says which form executes.  Mosaic itself
-compiles the kernels fine on that jax — proven by AOT compilation against
-named TPU topologies with interpret forced off (TOPOLOGY_r06.json,
-``inner_ring_allreduce(force_kernel=True)``).
 """
 
 from __future__ import annotations
@@ -72,8 +62,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-from .._compat import pltpu_compiler_params, pltpu_interpret_params, shard_map
+from jax import lax, shard_map
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
@@ -266,18 +255,7 @@ def _interpret_mode():
     """Real Mosaic on TPU, interpreter elsewhere (the CPU-mesh fixture)."""
     if jax.default_backend() == "tpu":
         return False
-    return pltpu_interpret_params()
-
-
-# Can the ring KERNELS execute here?  Real Mosaic (TPU backend) or the
-# TPU-semantics interpreter both can; the 0.4.x generic interpreter cannot
-# discharge remote DMAs/semaphores, so the public entry points below
-# substitute the XLA-collective emulation instead.
-RING_KERNELS_AVAILABLE = hasattr(pltpu, "InterpretParams")
-
-
-def _kernels_executable() -> bool:
-    return jax.default_backend() == "tpu" or RING_KERNELS_AVAILABLE
+    return pltpu.InterpretParams()
 
 
 def _scratch(dtype, rows: int, nslots: int, q: int, with_acc: Optional[int]):
@@ -311,7 +289,7 @@ def _ar_call(p: int, rows: int, q: int, subrows: int, nslots: int, dtype,
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         scratch_shapes=_scratch(dtype, rows, nslots, q, with_acc=p),
-        compiler_params=pltpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             collective_id=(_RS_COLLECTIVE_ID if collective_id is None
                            else collective_id)),
         interpret=_interpret_mode() if interpret is None else interpret,
@@ -327,7 +305,7 @@ def _rs_call(p: int, rows: int, q: int, subrows: int, nslots: int, dtype):
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         scratch_shapes=_scratch(dtype, rows, nslots, q, with_acc=p),
-        compiler_params=pltpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             collective_id=_RS_COLLECTIVE_ID),
         interpret=_interpret_mode(),
     )
@@ -342,7 +320,7 @@ def _ag_call(p: int, rows: int, q: int, subrows: int, nslots: int, dtype):
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         scratch_shapes=_scratch(dtype, rows, nslots, q, with_acc=None),
-        compiler_params=pltpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             collective_id=_AG_COLLECTIVE_ID),
         interpret=_interpret_mode(),
     )
@@ -399,24 +377,17 @@ def inner_ring_allreduce(x: jax.Array, p: int, mean: bool = False,
     on ring-skewed devices.
 
     ``force_kernel=True`` traces the Pallas kernel for REAL Mosaic
-    lowering (interpret off) even where this process could not execute
-    it: the AOT topology compiles lower for a TPU while running on a CPU
-    host, and the verdict wanted there is the TPU compiler's, not the
-    interpreter's — ``_interpret_mode()`` keys on the RUNNING backend and
-    would otherwise bake interpret mode into a TPU-targeted lowering.
+    lowering (interpret off) whatever backend this process runs on: the
+    AOT topology compiles lower for a TPU while running on a CPU host, and
+    the verdict wanted there is the TPU compiler's, not the interpreter's
+    — ``_interpret_mode()`` keys on the RUNNING backend and would
+    otherwise bake interpret mode into a TPU-targeted lowering.
     """
     if x.ndim != 1:
         raise ValueError(f"inner ring allreduce expects a flat (n,) local "
                          f"vector, got {x.shape}")
     if p == 1:
         return x
-    if not force_kernel and not _kernels_executable():
-        # XLA-collective emulation (see module docstring): same axis, same
-        # in-dtype reduction, same result layout.
-        out = lax.psum(x, RANK_AXIS)
-        if mean:
-            out = out / jnp.asarray(p, x.dtype)
-        return out
     n = x.shape[0]
     rows, q, subrows = _geometry(n, p, x.dtype.itemsize)
     nslots = _nslots(p)
@@ -484,18 +455,6 @@ def ring_reduce_scatter(comm: Communicator, x: jax.Array, op: str = "sum",
     nslots = _nslots(p)
 
     def build():
-        if not _kernels_executable():
-            def body(xb):
-                # XLA reduce-scatter emulation: same rank-owns-chunk-r
-                # contract, in-dtype reduction.
-                return lax.psum_scatter(xb[0], RANK_AXIS,
-                                        scatter_dimension=0,
-                                        tiled=True)[None]
-
-            return jax.jit(shard_map(body, mesh=comm.mesh(),
-                                     in_specs=P(RANK_AXIS),
-                                     out_specs=P(RANK_AXIS),
-                                     check_vma=False))
         rs = _rs_call(p, rows, q, subrows, nslots, x.dtype)
 
         def body(xb):
@@ -526,16 +485,6 @@ def ring_allgather(comm: Communicator, x: jax.Array) -> jax.Array:
     nslots = _nslots(p)
 
     def build():
-        if not _kernels_executable():
-            def body(xb):
-                # XLA all-gather emulation: rank-order 1-D concatenation.
-                return lax.all_gather(xb[0], RANK_AXIS,
-                                      tiled=True)[None]
-
-            return jax.jit(shard_map(body, mesh=comm.mesh(),
-                                     in_specs=P(RANK_AXIS),
-                                     out_specs=P(RANK_AXIS),
-                                     check_vma=False))
         ag = _ag_call(p, rows, q, subrows, nslots, x.dtype)
 
         def body(xb):

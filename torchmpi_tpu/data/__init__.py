@@ -1,8 +1,9 @@
 """Streaming input data plane — the first-class input subsystem.
 
-``BENCH_r05.json`` measured host->device staging at +2944.75 ms/step for
-39 MB/batch against a 45.5 ms compute step: the headline throughput only
-held because the bench kept data resident on device.  The reference
+Round 5 (no longer reproducible) measured host->device staging at
++2944.75 ms/step for 39 MB/batch against a 45.5 ms compute step: the
+headline throughput only held because the bench kept data resident on
+device.  The reference
 hides exactly this class of host work inside the backward pass (async
 prefetch hooks pipelined into ``onBackwardCriterion``, PAPER.md:16,34);
 this package is the TPU-native analogue — background staging overlapped

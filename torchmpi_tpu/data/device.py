@@ -3,8 +3,8 @@
 The seed's ``DevicePrefetchIterator`` staged on the CONSUMER thread —
 ``jax.device_put`` is async so the *transfer* overlapped compute, but
 the host-side reshape/cast ran inside the training loop's thread,
-exactly the blocked window ``BENCH_r05.json`` measured at +2944.75
-ms/step for 39 MB/batch.  :class:`DeviceStage` moves the whole staging
+exactly the blocked window round 5 (no longer reproducible) measured at
++2944.75 ms/step for 39 MB/batch.  :class:`DeviceStage` moves the whole staging
 call onto a producer thread: the reshape, the cast (through a reusable
 :class:`~torchmpi_tpu.data.staging.HostScratchPool` buffer), and the
 ``device_put`` dispatch with the step's ``NamedSharding`` all run in the

@@ -240,6 +240,15 @@ def _causal_attention(q, k, v, scale):
                       preferred_element_type=jnp.float32).astype(q.dtype)
 
 
+def _tp_head_axis(mesh: Mesh, heads: int, kv_heads: int) -> Optional[str]:
+    """``tp`` when the mesh has it and it divides both head counts, so a
+    shard_map may split attention over heads; else heads stay whole."""
+    tp = dict(mesh.shape).get(AXIS_TP)
+    if tp and heads and kv_heads and heads % tp == 0 and kv_heads % tp == 0:
+        return AXIS_TP
+    return None
+
+
 def _ring_attention_batched(mesh: Mesh, causal_scale,
                             heads: int = 0, kv_heads: int = 0,
                             impl: str = "ring_flash"):
@@ -260,7 +269,7 @@ def _ring_attention_batched(mesh: Mesh, causal_scale,
     forcing an all-gather of the tp-sharded qkv projections at the
     shard_map boundary and repeating the full attention on every tp rank.
     """
-    from .._compat import shard_map
+    from jax import shard_map
     from ..parallel import sequence as seq_mod
 
     if impl == "ring_flash":
@@ -277,14 +286,47 @@ def _ring_attention_batched(mesh: Mesh, causal_scale,
                 q1, k1, v1, axis=AXIS_SP, causal=True, scale=causal_scale)
             return jax.vmap(fn)(q, k, v)
 
-    head_ax = None
-    if AXIS_TP in mesh.axis_names:
-        tp = dict(mesh.shape)[AXIS_TP]
-        if heads and kv_heads and heads % tp == 0 and kv_heads % tp == 0:
-            head_ax = AXIS_TP
-    spec = _mesh_spec(P(AXIS_DP, AXIS_SP, head_ax, None), mesh)
+    spec = _mesh_spec(
+        P(AXIS_DP, AXIS_SP, _tp_head_axis(mesh, heads, kv_heads), None), mesh)
     return shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
                      out_specs=spec, check_vma=False)
+
+
+def _flash_attention_sharded(mesh: Optional[Mesh], heads: int,
+                             kv_heads: int) -> Callable:
+    """Causal flash attention ``(q, k, v) -> o`` for K/V at their native
+    ``kv_heads``.  On a mesh the kernel runs inside a ``shard_map`` over
+    the batch (``dp``) and head (``tp``) axes: the TPU compiler refuses to
+    partition a Mosaic kernel itself (``NotImplementedError: Mosaic kernels
+    cannot be automatically partitioned``), and each device wants only its
+    own batch rows and head shard anyway — the layout the hand-sharded
+    stage (:func:`_decoder_layer_tp_manual`) already runs.  K/V are split
+    at ``kv_heads`` and repeated locally, as in the ring."""
+    from jax import shard_map
+
+    from ..ops import flash_attention
+
+    rep = heads // kv_heads
+
+    def local(q, k, v):
+        return flash_attention(q, jnp.repeat(k, rep, axis=2),
+                               jnp.repeat(v, rep, axis=2), causal=True)
+
+    if mesh is None or mesh.size == 1:
+        return local
+    spec = _mesh_spec(
+        P(AXIS_DP, None, _tp_head_axis(mesh, heads, kv_heads), None), mesh)
+
+    def sharded(q, k, v):
+        # Inside a pipeline stage ``pp`` is already manual: the nested
+        # shard_map then takes the context mesh and only the axes left.
+        manual = set(jax.sharding.get_abstract_mesh().manual_axes)
+        where = (dict(axis_names=set(mesh.axis_names) - manual) if manual
+                 else dict(mesh=mesh))
+        return shard_map(local, in_specs=(spec, spec, spec), out_specs=spec,
+                         check_vma=False, **where)(q, k, v)
+
+    return sharded
 
 
 def _make_attn_impl(cfg: Config, attn: str, mesh: Optional[Mesh],
@@ -309,12 +351,7 @@ def _make_attn_impl(cfg: Config, attn: str, mesh: Optional[Mesh],
                 "ring-xla": "ring"}[attn]
         return _ring_attention_batched(mesh, scale, H, KV, impl=impl)
     if attn == "flash":
-        from ..ops import flash_attention
-
-        rep = H // KV
-        return lambda q, k, v: flash_attention(
-            q, jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2),
-            causal=True)
+        return _flash_attention_sharded(mesh, H, KV)
     if attn == "full":
         return lambda q, k, v: _causal_attention(q, k, v, scale)
     raise ValueError(
@@ -782,7 +819,8 @@ def _decode_step(cfg: Config, params: Params, cache: Params,
 
 
 def _prefill(cfg: Config, params: Params, cache: Params,
-             prompt: jax.Array, attn: str = "auto"):
+             prompt: jax.Array, attn: str = "auto",
+             mesh: Optional[Mesh] = None):
     """Batched prefill: ONE full forward over the prompt (matmul-bound, the
     parameters stream from HBM once) seeding the K/V cache, instead of
     prompt_len matrix-vector decode steps.  Returns (last-position logits,
@@ -793,6 +831,8 @@ def _prefill(cfg: Config, params: Params, cache: Params,
     Pallas flash kernels once the prompt's (Lp, Lp) score matrix is the
     memory term that matters (>= 1024, where flash also wins on time —
     the Llama table in BASELINE.md) and a legal tile divides ``Lp``.
+    ``mesh`` is the mesh the params are sharded on, if any: the flash
+    kernel needs it to run per batch/head shard.
     """
     B, Lp = prompt.shape
     positions = jnp.arange(Lp)
@@ -810,7 +850,7 @@ def _prefill(cfg: Config, params: Params, cache: Params,
                 attn = "flash"
             except ValueError:
                 pass
-    attn_impl = _make_attn_impl(cfg, attn, None, scale)
+    attn_impl = _make_attn_impl(cfg, attn, mesh, scale)
     h = params["embed"][prompt]
 
     def layer(h, xs):
@@ -900,7 +940,7 @@ def make_generate_fn(cfg: Config, prompt_len: int, max_new: int,
         B = prompt.shape[0]
         cache0 = constrain_cache(
             init_kv_cache(cfg, B, max_len, params["embed"].dtype))
-        logits, cache = _prefill(cfg, params, cache0, prompt)
+        logits, cache = _prefill(cfg, params, cache0, prompt, mesh=mesh)
         cache = constrain_cache(cache)
         logits = constrain_logits(logits)
 
@@ -1109,10 +1149,10 @@ def make_pp_train_step(cfg: Config, mesh: Mesh, n_microbatches: int,
     is HAND-sharded: tp joins pp as a manual shard_map axis, each device's
     stage_fn gets raw weight shards, writes the two Megatron psums itself,
     and runs the Pallas flash kernels on its own head shard.  'manual' is
-    the long-context 3-D form: GSPMD cannot partition a Pallas custom
-    call, so under 'auto' + attn='flash' every tick gathers the attention
-    operands and computes them replicated over dp x tp (measured ~4x the
-    exchange, BASELINE.md round 4).  'manual' requires attn='flash'.
+    the long-context 3-D form.  GSPMD cannot partition a Pallas custom
+    call, so under 'auto' + attn='flash' the kernel nests its own
+    shard_map over dp x tp (:func:`_flash_attention_sharded`) while the
+    projections around it stay GSPMD's.  'manual' requires attn='flash'.
 
     Returns ``(step, V)`` with ``V = n_layers/S`` layers per stage.
     Without ``optimizer``: ``step(params, tokens, targets) -> (params,
@@ -1169,7 +1209,8 @@ def make_pp_train_step(cfg: Config, mesh: Mesh, n_microbatches: int,
                                     io_batch_axis=io_batch)
     elif stage_tp == "auto":
         scale = 1.0 / np.sqrt(cfg.head_dim)
-        attn_impl = _make_attn_impl(cfg, attn, None, scale)
+        attn_impl = _make_attn_impl(cfg, attn, mesh if compose else None,
+                                    scale)
         stage_fn = _make_pp_stage_fn(cfg, attn_impl, remat)
         pipe = _pp.make_pipeline_fn(mesh, stage_fn, n_microbatches,
                                     axis=AXIS_PP, auto_other_axes=compose)
@@ -1328,6 +1369,11 @@ def make_1f1b_train_step(cfg: Config, mesh: Mesh, n_microbatches: int,
             raise ValueError("manual_schedule applies to stage_tp='manual' "
                              "only (the auto path is always cond-gated)")
         scale = 1.0 / np.sqrt(cfg.head_dim)
+        # No mesh for the kernel here, unlike make_pp_train_step: a
+        # shard_map nested in this schedule's lax.cond ticks aborts XLA's
+        # SPMD partitioner (spmd_partitioner_util.cc check failure, on the
+        # CPU mesh too).  So attn='flash' with composed dp/tp does not
+        # lower for a TPU on this path; stage_tp='manual' is the flash form.
         attn_impl = _make_attn_impl(cfg, attn, None, scale)
         stage_fn = _make_pp_stage_fn(cfg, attn_impl, remat)
         # dp/tp compose via GSPMD (auto axes): the scheduled lax.cond

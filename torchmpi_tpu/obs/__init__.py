@@ -17,7 +17,7 @@ Shende & Malony 2006) for the whole stack:
 * :mod:`.metrics` — counters/gauges/histograms registry that auto-scrapes
   the existing C-ABI counters and exports Prometheus text + JSON.
 * :mod:`.export`  — merges native events, Python spans and the
-  ``_compat`` xplane reader's device timeline into one Chrome/Perfetto
+  ``jax.profiler`` xplane capture's device timeline into one Chrome/Perfetto
   trace JSON; ``merge_ranks`` joins N per-rank obsdump bundles onto one
   clock-aligned timeline with cross-rank flow arrows; computes the
   span-join and flow-join rates.

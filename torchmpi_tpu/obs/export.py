@@ -8,7 +8,7 @@ JSON (load in ``chrome://tracing`` or ui.perfetto.dev):
 * native phase events (``obs.native``) -> one pid per plane, instant
   ("i") events for start/chunk/retry/error and synthesized "X" events
   for start..complete pairs of the same (correlation, op, rank);
-* the device timeline (``_compat.profile_data_from_file`` over a
+* the device timeline (``jax.profiler.ProfileData`` over a
   ``jax.profiler`` xplane capture) -> pid "device:<plane>", one tid per
   timeline line.
 
@@ -136,47 +136,25 @@ def _native_events(events, t0: int,
 
 
 def _device_events(xplane_path: str, t0_us: float) -> List[Dict[str, Any]]:
-    """The xplane capture's lines as Chrome events, shifted to start at
-    ``t0_us``.  Events without a start offset (older reader surfaces) are
-    laid out cumulatively per line — relative durations stay honest."""
-    from .._compat import profile_data_from_file
+    """The xplane capture's lines as Chrome events, shifted so the
+    earliest device event starts at ``t0_us``."""
+    from jax.profiler import ProfileData
 
-    pd = profile_data_from_file(xplane_path)
-    out: List[Dict[str, Any]] = []
-    # Absolute starts stay exact ints (the compat reader yields epoch-scale
-    # ns that float64 would quantize to ~256 ns); float only after the
-    # base subtraction below, when the values are small again.
-    abs_starts: List[int] = []
-    raw: List[Tuple[int, int, str, Any, float, bool]] = []
-    for p_i, plane in enumerate(pd.planes):
-        for l_i, line in enumerate(plane.lines):
-            cursor = 0.0
-            for ev in line.events:
-                start_ns = getattr(ev, "start_ns", None)
-                if start_ns is None:
-                    start_ns_f, is_abs = cursor, False
-                    cursor += ev.duration_ns
-                else:
-                    start_ns_f, is_abs = start_ns, True
-                    abs_starts.append(start_ns)
-                raw.append((p_i, l_i, ev.name, start_ns_f,
-                            float(ev.duration_ns), is_abs))
-    # Only absolute (clock-anchored) starts share a base; cumulative
-    # cursors are already relative to the capture start, and folding them
-    # into one min() would fling the absolute events hours off the origin
-    # whenever a capture mixes both kinds of line.
-    base = min(abs_starts) if abs_starts else 0.0
-    for p_i, l_i, name, start_ns_f, dur_ns, is_abs in raw:
-        out.append({
-            "ph": "X",
-            "name": name,
-            "cat": "device",
-            "pid": _PID_DEVICE + p_i,
-            "tid": l_i,
-            "ts": t0_us + (start_ns_f - (base if is_abs else 0.0)) / 1e3,
-            "dur": max(dur_ns, 1.0) / 1e3,
-        })
-    return out
+    pd = ProfileData.from_file(xplane_path)
+    raw = [(p_i, l_i, ev.name, ev.start_ns, ev.duration_ns)
+           for p_i, plane in enumerate(pd.planes)
+           for l_i, line in enumerate(plane.lines)
+           for ev in line.events]
+    base = min((r[3] for r in raw), default=0.0)
+    return [{
+        "ph": "X",
+        "name": name,
+        "cat": "device",
+        "pid": _PID_DEVICE + p_i,
+        "tid": l_i,
+        "ts": t0_us + (start_ns - base) / 1e3,
+        "dur": max(dur_ns, 1.0) / 1e3,
+    } for p_i, l_i, name, start_ns, dur_ns in raw]
 
 
 def chrome_trace(spans: Sequence[Dict[str, Any]],

@@ -64,6 +64,23 @@ def started() -> bool:
     return _started
 
 
+def use_compile_cache() -> str:
+    """Point JAX's persistent compilation cache at a directory that stays
+    put, and return it.  The directory is part of the cache key, so one
+    that moves (a temporary name, a pid, the time) never hits.  Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set JAX already uses it and nothing is
+    set here; otherwise it is ``.jax_cache`` beside the package, i.e. at
+    the root of the checkout."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__)))), ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 def _multi_host_env() -> bool:
     """Whether the environment announces a multi-host deployment that needs
     ``jax.distributed.initialize`` (TPU pod workers / explicit coordinator).
@@ -173,7 +190,9 @@ def start(
         config.set("use_tree_communicators", bool(tree_communicators))
         config.set("use_cartesian_communicators", bool(cartesian_communicators))
 
-        # (4) world communicator.
+        # (4) world communicator.  The compile cache is settled before the
+        # first device query, so every program of the run goes through it.
+        use_compile_cache()
         if devices is None:
             devices = jax.devices() if with_tpu else jax.devices("cpu")
         world = Communicator(devices, name="global")

@@ -12,8 +12,7 @@ a PJRT topology description for a NAMED device fabric (v5e 2x4, v4 2x2x4),
 meshes form over its compile-only devices, and ``jit(...).lower(...)
 .compile()`` runs the real TPU compiler (Mosaic included) against it.
 
-:func:`dryrun_topology` is the entry point — the topology-plane sibling of
-``__graft_entry__.dryrun_multichip``: it AOT-compiles each registered
+:func:`dryrun_topology` is the entry point: it AOT-compiles each registered
 program against a named topology and records per-program compile-ok, HLO
 collective counts (per op x wire dtype, with byte estimates), and the
 compiler's memory analysis.  ``scripts/dryrun_topology.py`` sweeps it over
@@ -44,6 +43,7 @@ import numpy as np
 # PJRT spelling (<generation>:<chip grid>); ``chips`` the compile-only
 # device count the description yields.
 TOPOLOGIES: Dict[str, Dict[str, Any]] = {
+    "v5e-4": {"topology_name": "v5e:2x2", "chips": 4},
     "v5e-8": {"topology_name": "v5e:2x4", "chips": 8},
     "v4-32": {"topology_name": "v4:2x2x4", "chips": 32},
 }
@@ -101,53 +101,84 @@ _COLLECTIVE_OPS = ("all-reduce", "all-gather", "reduce-scatter",
 _DTYPE_BYTES = {"f64": 8, "f32": 4, "bf16": 2, "f16": 2, "s32": 4,
                 "u32": 4, "s8": 1, "u8": 1, "pred": 1, "s64": 8, "u64": 8}
 
-_INSTR_RE = re.compile(
-    r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*"          # result name
-    r"[^=]*?\b(" + "|".join(_COLLECTIVE_OPS) + r")(-start)?"
-    r"\((.*)$",
-    re.M)
+_DEF_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*", re.M)
 _SHAPE_RE = re.compile(r"\b([a-z0-9]+)\[([\d,]*)\]")
+_COLLECTIVE_RE = re.compile(
+    r"^(" + "|".join(_COLLECTIVE_OPS) + r")(?:-start)?$")
+
+
+def _close_paren(s: str, i: int) -> int:
+    """Index of the paren that closes the one opened just before ``s[i]``
+    (layout annotations such as ``T(8,128)`` nest their own)."""
+    depth = 1
+    for j in range(i, len(s)):
+        if s[j] == "(":
+            depth += 1
+        elif s[j] == ")":
+            depth -= 1
+            if depth == 0:
+                return j
+    return len(s)
+
+
+def _instructions(hlo_text: str):
+    """Yield ``(name, result_type, opcode, operand_text)`` for each
+    ``%name = <type> opcode(<operands>), attrs`` line of HLO text.  A tuple
+    result type is the balanced-paren region after ``=``; any other type
+    has no space in it."""
+    for m in _DEF_RE.finditer(hlo_text):
+        eol = hlo_text.find("\n", m.end())
+        rest = hlo_text[m.end():eol if eol >= 0 else len(hlo_text)]
+        end = (_close_paren(rest, 1) + 1 if rest.startswith("(")
+               else rest.find(" "))
+        if end <= 0:
+            continue
+        tail = rest[end:].lstrip()
+        lp = tail.find("(")
+        if lp <= 0:
+            continue
+        yield (m.group(1), rest[:end], tail[:lp],
+               tail[lp + 1:_close_paren(tail, lp + 1)])
+
+
+def _shape_bytes(shapes) -> int:
+    total = 0
+    for dt, dims in shapes:
+        n = 1
+        for d in dims.split(","):
+            if d.strip():
+                n *= int(d)
+        total += n * _DTYPE_BYTES.get(dt, 4)
+    return total
 
 
 def hlo_collective_stats(hlo_text: str) -> Dict[str, Any]:
     """Count collective instructions in HLO text, keyed ``op:dtype``, with
     a byte estimate per key.
 
-    The dtype and bytes come from the instruction's OPERANDS, not its
-    result: the operand dtype is the wire dtype (XLA folds output converts
-    into the collective — an f32-wire psum whose consumer wants bf16
-    prints as ``(bf16[...]) all-reduce(f32[...] %x)``, and the f32 operand
-    is what rides the interconnect).  Several psums may fuse into one
+    The dtype and bytes are those of the instruction's OPERANDS, which is
+    what rides the interconnect (an all-gather's result is group-size
+    times its operand, a reduce-scatter's a fraction of it).  HLO text
+    names operands without their types (``all-reduce(%fusion.10,
+    %fusion.2)``), so each is resolved to the result type of the
+    instruction that defines it.  Several psums may fuse into one
     tuple-shaped all-reduce; operand bytes sum across the tuple.
     """
+    instrs = list(_instructions(hlo_text))
+    result_type = {name: rtype for name, rtype, _, _ in instrs}
     counts: Dict[str, int] = {}
     bytes_: Dict[str, int] = {}
-    for m in _INSTR_RE.finditer(hlo_text):
-        op, _, rest = m.groups()
-        # The operand list is the balanced-paren region opened at the
-        # match (attributes like metadata={...} follow the close paren;
-        # layout annotations inside operands carry their own parens).
-        depth, end = 1, len(rest)
-        for i, c in enumerate(rest):
-            if c == "(":
-                depth += 1
-            elif c == ")":
-                depth -= 1
-                if depth == 0:
-                    end = i
-                    break
-        shapes = _SHAPE_RE.findall(rest[:end])
+    for _, _, opcode, operands in instrs:
+        m = _COLLECTIVE_RE.match(opcode)
+        if m is None:
+            continue
+        shapes = []
+        for name in re.findall(r"%([\w.\-]+)", operands):
+            shapes += _SHAPE_RE.findall(result_type.get(name, ""))
         dtype = shapes[0][0] if shapes else "?"
-        key = f"{op}:{dtype}"
+        key = f"{m.group(1)}:{dtype}"
         counts[key] = counts.get(key, 0) + 1
-        total = 0
-        for dt, dims in shapes:
-            n = 1
-            for d in dims.split(","):
-                if d.strip():
-                    n *= int(d)
-            total += n * _DTYPE_BYTES.get(dt, 4)
-        bytes_[key] = bytes_.get(key, 0) + total
+        bytes_[key] = bytes_.get(key, 0) + _shape_bytes(shapes)
     return {"counts": counts, "operand_bytes": bytes_,
             "total": sum(counts.values())}
 
@@ -223,7 +254,7 @@ def _build_manual_psum(topology: str, wire_dtype_name: str):
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from .._compat import shard_map
+    from jax import shard_map
     from ..parallel import tp as _tp
 
     wire = jnp.bfloat16 if wire_dtype_name == "bfloat16" else jnp.float32
@@ -252,14 +283,14 @@ def _build_manual_psum(topology: str, wire_dtype_name: str):
     return fn, (x, w_up, w_down)
 
 
-def _build_pallas_ring(topology: str, dtype_name: str):
+def _build_pallas_ring(topology: str, dtype_name: str, n: int = 1 << 16):
     """The fused reduce-scatter+allgather Pallas ring kernel over every
-    chip of the topology — the Mosaic multi-chip lowering the CPU
-    interpreter cannot exercise."""
+    chip of the topology, ``n`` elements per chip — the Mosaic multi-chip
+    lowering the CPU interpreter cannot exercise."""
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from .._compat import shard_map
+    from jax import shard_map
     from ..collectives import pallas_ring
     from ..runtime.communicator import RANK_AXIS
 
@@ -277,7 +308,6 @@ def _build_pallas_ring(topology: str, dtype_name: str):
 
     fn = shard_map(body, mesh=mesh, in_specs=P(RANK_AXIS),
                    out_specs=P(RANK_AXIS), check_vma=False)
-    n = 1 << 16
     x = _sds((p, n), dtype, mesh, P(RANK_AXIS))
     return fn, (x,)
 
@@ -335,10 +365,10 @@ def _llama_arg_structs(cfg, mesh, shard_fn, B, L):
     return params, tokens, targets
 
 
-def _build_llama_dp_tp(topology: str):
+def _build_llama_dp_tp(topology: str, attn: str = "full"):
     """The dp x tp llama training step (BASELINE config 5's layout) with
-    per-layer remat + chunked loss, exactly as ``dryrun_multichip`` jits
-    it — lowered against the topology instead of the virtual CPU mesh."""
+    per-layer remat + chunked loss — lowered against the topology
+    instead of the virtual CPU mesh."""
     import jax
 
     from ..models import llama
@@ -347,7 +377,7 @@ def _build_llama_dp_tp(topology: str):
     cfg = llama.tiny()
     mesh = topology_mesh(topology, {"dp": -1, "tp": 2})
     B, L = max(2, n // 2) * 2, 32
-    step = llama.make_train_step(cfg, mesh, lr=0.1, remat="dots",
+    step = llama.make_train_step(cfg, mesh, lr=0.1, attn=attn, remat="dots",
                                  loss_chunk=L // 2)
     params, tokens, targets = _llama_arg_structs(
         cfg, mesh, llama.param_specs, B, L)

@@ -211,14 +211,14 @@ def op_breakdown(trace_dir: str, top: int = 25):
     import collections
     import glob
 
-    from .._compat import profile_data_from_file
+    from jax.profiler import ProfileData
 
     files = glob.glob(trace_dir + "/**/*.xplane.pb", recursive=True)
     if not files:
         raise ValueError(f"no .xplane.pb under {trace_dir!r} — did the "
                          f"trace block run?")
     # Newest capture wins (benchmark logdirs accumulate runs).
-    pd = profile_data_from_file(max(files, key=os.path.getmtime))
+    pd = ProfileData.from_file(max(files, key=os.path.getmtime))
     per_op: collections.Counter = collections.Counter()
     # Step count = executions of the dominant jit_* module on ONE timeline
     # line (module events echo on several lines; summing across lines
