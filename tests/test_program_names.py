@@ -1,0 +1,84 @@
+"""Names in the device program (docs/observability.md): the parts of a step
+run under ``jax.named_scope`` and the flash kernels have a ``name``, so the
+``op_name`` of every instruction of the compiled program says which part it
+belongs to, and ``transpose(...)`` round it says backward.  The names are
+metadata: these tests read them off the lowered step programs."""
+
+import re
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from torchmpi_tpu.engine import AllReduceSGDEngine
+from torchmpi_tpu.models import llama, resnet
+from torchmpi_tpu.parallel import mesh as pmesh
+from torchmpi_tpu.runtime import config
+
+
+def _op_names(lowered):
+    """What the lowering gives every operation for its ``op_name``."""
+    return set(re.findall(r'loc\("([^"]+)"', lowered.as_text(debug_info=True)))
+
+
+def _carried(names, scope, inside=""):
+    """Whether some operation runs under ``scope`` (as a path component,
+    bare or inside ``jvp(...)``, ``vmap(...)``, ``transpose(...)``), and
+    under ``inside`` too where that is given."""
+    part = re.compile(r"(^|[/(])" + re.escape(scope) + r"([/)]|$)")
+    return any(part.search(n) and inside in n for n in names)
+
+
+MOE = ("moe.router", "moe.dispatch", "moe.experts", "moe.combine")
+
+
+@pytest.mark.parametrize("make_cfg,ffn,absent", [
+    (llama.moe_tiny, MOE, ("ffn",)), (llama.tiny, ("ffn",), MOE)])
+def test_llama_train_step_carries_scope_and_kernel_names(make_cfg, ffn,
+                                                         absent):
+    cfg = make_cfg()
+    mesh = pmesh.make_mesh({"dp": 1}, devices=jax.devices()[:1])
+    step = llama.make_train_step(cfg, mesh, attn="flash", remat="dots",
+                                 loss_chunk=32)
+    params = jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0), cfg))
+    tokens = jax.ShapeDtypeStruct((2, 64), jnp.int32)
+    names = _op_names(step.lower(params, None, tokens, tokens))
+    for scope in ("embed", "attn", "head_loss", "optimizer") + ffn:
+        assert _carried(names, scope), scope
+    for scope in absent:
+        assert not _carried(names, scope), scope
+    # The three kernels, under the attention's scope; the backward ones
+    # only in the checkpointed layer's backward pass.
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert _carried(names, kernel, inside="attn/" + kernel), kernel
+    assert _carried(names, "flash_bwd_dq", inside="checkpoint/attn/")
+    assert not _carried(names, "flash_bwd_dq", inside="rematted_computation")
+    for scope in ("embed", "head_loss"):
+        assert _carried(names, scope, inside="transpose(jvp(" + scope), scope
+    assert not _carried(names, "optimizer", inside="transpose(")
+
+
+RESNET = ("stem", "conv", "bn", "residual", "pool", "fc_loss")
+
+
+@pytest.mark.parametrize("rings", [False, True], ids=["gspmd", "rings"])
+def test_resnet_engine_step_carries_scope_names(world, rings):
+    config.set("use_pallas_collectives", rings)
+    cfg = resnet.config(depth=18, n_classes=10, width_multiplier=0.25)
+    params = jax.eval_shape(
+        lambda: resnet.init(jax.random.PRNGKey(0), cfg)[0])
+    engine = AllReduceSGDEngine(resnet.make_loss_fn(cfg), lr=0.1, comm=world)
+    step = engine._build_compiled_step(world)
+    names = _op_names(step.lower(
+        params, None, jax.ShapeDtypeStruct((16, 32, 32, 3), jnp.float32),
+        jax.ShapeDtypeStruct((16,), jnp.int32)))
+    for scope in RESNET:
+        assert _carried(names, scope), scope
+        assert _carried(names, scope, inside="transpose(jvp(" + scope), scope
+    assert _carried(names, "optimizer")
+    assert not _carried(names, "optimizer", inside="transpose(")
+    # The explicit rings are a part of the program and have a name; GSPMD's
+    # all-reduces are put in by the partitioner, after this text, and take
+    # the name of the backward operation whose result they sum.
+    assert _carried(names, "grad_sync") == rings

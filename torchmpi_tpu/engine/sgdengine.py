@@ -28,10 +28,13 @@ on_end_epoch, on_end`` — each called with the mutable engine ``state``.
 from __future__ import annotations
 
 import time
-from typing import Any, Callable, Dict, Optional, Tuple
+from collections import deque
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
+import jax.monitoring
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -106,12 +109,147 @@ def sample_array(state, flatten: bool = False):
         if isinstance(a, _Staged):
             return a.array
         if flatten and getattr(a, "ndim", 0) >= 2:
-            import numpy as np
-
             return np.reshape(np.asarray(a), (-1,) + tuple(a.shape[2:]))
         return a
 
     return unwrap(xb), unwrap(yb)
+
+
+# ------------------------------------------------------------ the run record
+
+RUN_RING = 4096         # steps and completions a record keeps (the newest)
+RUNS_KEPT = 16          # records ``runs()`` keeps (the newest)
+
+# The event JAX records round every compilation of a new program, whether
+# the backend compiles it or the persistent cache supplies it.
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+_RUNS: "deque[RunRecord]" = deque(maxlen=RUNS_KEPT)
+_OPEN: Optional["RunRecord"] = None     # the record compilations go to
+_COMPILE_LISTENER_ON = False
+
+
+def runs() -> List["RunRecord"]:
+    """This process's most recent :class:`RunRecord` s, oldest first, at
+    most ``RUNS_KEPT``: one for every ``AllReduceSGDEngine.train()`` call,
+    the one still open included.  For a reader that has neither the engine
+    nor the state ``train()`` returned (docs/observability.md)."""
+    return list(_RUNS)
+
+
+def _on_compile(event, duration, **_):
+    rec = _OPEN
+    if rec is not None and event == _COMPILE_EVENT:
+        rec.compiles.append((rec.steps, float(duration)))
+
+
+def _open_run(rec: "RunRecord") -> Optional["RunRecord"]:
+    """Make ``rec`` the record compilations go to; returns the one that was
+    (a hook may train another engine inside a call)."""
+    global _OPEN, _COMPILE_LISTENER_ON
+    if not _COMPILE_LISTENER_ON:        # once a process: JAX keeps listeners
+        _COMPILE_LISTENER_ON = True
+        jax.monitoring.register_event_duration_secs_listener(_on_compile)
+    outer, _OPEN = _OPEN, rec
+    _RUNS.append(rec)
+    return outer
+
+
+def _close_run(rec: "RunRecord", outer: Optional["RunRecord"]) -> None:
+    global _OPEN
+    _OPEN = outer
+    rec.t_return = time.monotonic_ns()
+
+
+class RunRecord:
+    """The engine's own account of one ``train()`` call.  Always kept, with
+    no knob and independent of ``obs_trace`` and the metrics feed, as
+    ``data/device.py:StageStats`` is: plain Python, a handful of clock reads
+    a step.  Reached as ``state["run"]``, ``engine.last_run`` and
+    :func:`runs`.
+
+    Every stamp is ``time.monotonic_ns()``, the clock of ``obs/tracer.py``;
+    a stamp plus ``epoch_offset_ns`` is on the clock of ``time.time_ns()``,
+    which a profiler capture's ``profile_start_time`` is given in.  A step
+    is numbered within its call, from 0 (``start_step`` is the global
+    number of step 0).
+
+    * ``t_enter``, ``t_return``: the call.  ``t_first_batch``: the
+      iterator's first yield (pipeline start-up and the first staging end
+      here).  ``t_first_dispatch``: the first step's call into the step
+      program has returned (start-up ends here).
+    * ``step_stamps``, the newest ``RUN_RING``: ``(step, t_batch,
+      t_stepped, t_end, wait_ns, hook_ns)``: top of the loop body; the
+      step function has returned; the iteration's last controller has;
+      time inside ``block_until_ready`` of the in-flight bound; time
+      inside the user's hooks.  ``t_batch`` less the step before's
+      ``t_end`` is the wait for input, ``t_end - t_batch - wait_ns -
+      hook_ns`` the engine's own host time.
+    * ``completions``, same ring: ``(step, stamp)``, the moment the host
+      saw step ``step`` finished, taken where the in-flight bound blocks on
+      its loss.  The last ``window`` steps of a call have none: the host
+      does not wait for them inside the call, and a record is not written
+      to after its call has returned.  The eager modes fence within the
+      step and have none at all.
+    * ``compiles``: ``(step, seconds)`` of every program compiled (or
+      loaded from the persistent cache) while the call was open, on any
+      thread; one past step 0 is a recompile.
+    * ``steps``, ``mode``, ``window`` (the in-flight bound in force,
+      negative for none)."""
+
+    def __init__(self, mode: str, window: int, start_step: int):
+        self.mode = mode
+        self.window = window
+        self.start_step = start_step
+        self.steps = 0
+        self.t_enter = time.monotonic_ns()
+        self.epoch_offset_ns = time.time_ns() - self.t_enter
+        self.t_first_batch: Optional[int] = None
+        self.t_first_dispatch: Optional[int] = None
+        self.t_return: Optional[int] = None
+        self.step_stamps: deque = deque(maxlen=RUN_RING)
+        self.completions: deque = deque(maxlen=RUN_RING)
+        self.compiles: deque = deque(maxlen=RUN_RING)
+        # Sums of the step that is open, moved into step_stamps at its end.
+        self._wait_ns = 0
+        self._hook_ns = 0
+
+    def summary(self) -> Dict[str, Any]:
+        """The arithmetic, once.  ``step_ms_p50/p90/p95``: percentiles of
+        the intervals between the completions of consecutive steps; a
+        percentile is given only with ten samples beyond it (``None``
+        under 20, 100, 200 intervals).  ``host_ms_p50``: the engine's own
+        host time a step.  ``input_wait_ms_p50``: the loop's wait for its
+        next batch.  ``start_ms``, ``first_batch_ms``: from ``t_enter`` to
+        ``t_first_dispatch``, ``t_first_batch``.  ``recompiles``:
+        compilations past step 0."""
+        done = list(self.completions)
+        gaps = [(b[1] - a[1]) / 1e6 for a, b in zip(done, done[1:])
+                if b[0] == a[0] + 1]
+        stamps = list(self.step_stamps)
+        host = [(s[3] - s[1] - s[4] - s[5]) / 1e6 for s in stamps]
+        waits = [(b[1] - a[3]) / 1e6 for a, b in zip(stamps, stamps[1:])]
+
+        def since_enter(t):
+            return None if t is None else (t - self.t_enter) / 1e6
+
+        def pct(values, q, least):
+            if len(values) < least:
+                return None
+            return float(np.percentile(values, q))
+
+        return {
+            "steps": self.steps,
+            "intervals": len(gaps),
+            "step_ms_p50": pct(gaps, 50, 20),
+            "step_ms_p90": pct(gaps, 90, 100),
+            "step_ms_p95": pct(gaps, 95, 200),
+            "host_ms_p50": pct(host, 50, 1),
+            "input_wait_ms_p50": pct(waits, 50, 1),
+            "start_ms": since_enter(self.t_first_dispatch),
+            "first_batch_ms": since_enter(self.t_first_batch),
+            "recompiles": sum(1 for step, _ in self.compiles if step > 0),
+        }
 
 
 class AllReduceSGDEngine:
@@ -186,7 +324,10 @@ class AllReduceSGDEngine:
         self._test_fns = {}   # (metric_fn, mode) -> jitted eval, like the
         #                       compiled-step cache: a second test() epoch
         #                       must not retrace
-        self._inflight = []   # dispatch-depth window (see _bound_inflight)
+        self._inflight = []   # dispatch-depth window (see _bound_inflight):
+        #                       (loss, the RunRecord it was queued under, step)
+        self._run = None      # the RunRecord of the train() call in progress
+        self.last_run = None  # ... and of the newest call, open or returned
         # Elastic resize (runtime/resize.py, docs/resize.md): an installed
         # ResizeController is consulted once per step at the boundary.
         # DEPARTED (this rank drained/evicted) ends train() with
@@ -231,19 +372,44 @@ class AllReduceSGDEngine:
         against 47.415 streamed (medians of three 48-step windows).  So the
         chip runs the path the CPU tests run.  A negative knob is
         unbounded."""
-        from ..runtime import config as _config
-
-        window = int(_config.get("engine_max_inflight_steps")) or 8
+        window = self._inflight_window()
         if window < 0:
             return
-        self._inflight.append(marker)
+        rec = self._run
+        self._inflight.append((marker, rec, 0 if rec is None else rec.steps))
         while len(self._inflight) > window:
-            self._inflight.pop(0).block_until_ready()
+            oldest, queued_under, step = self._inflight.pop(0)
+            t0 = time.monotonic_ns()
+            oldest.block_until_ready()
+            if rec is None:             # test(): no record
+                continue
+            t1 = time.monotonic_ns()
+            rec._wait_ns += t1 - t0
+            # ``_inflight`` outlives a call, so a call's first waits are on
+            # steps of the call before: the caller has fenced since, and
+            # this is not the moment they finished.
+            if queued_under is rec:
+                rec.completions.append((step, t1))
+
+    @staticmethod
+    def _inflight_window() -> int:
+        from ..runtime import config as _config
+
+        return int(_config.get("engine_max_inflight_steps")) or 8
 
     def _hook(self, name: str, state: Dict[str, Any]) -> None:
         fn = self.hooks.get(name)
-        if fn is not None:
+        if fn is None:
+            return
+        rec = self._run
+        if rec is None:
             fn(state)
+            return
+        t0 = time.monotonic_ns()
+        try:
+            fn(state)
+        finally:
+            rec._hook_ns += time.monotonic_ns() - t0
 
     # ------------------------------------------------------------- compiled
 
@@ -381,6 +547,7 @@ class AllReduceSGDEngine:
                 synced = list(leaves)
                 chain = [None, 0]      # [prev ring output, ring counter]
 
+                @jax.named_scope("grad_sync")
                 def ring(flat):
                     prev, n = chain
                     if prev is not None:
@@ -444,12 +611,19 @@ class AllReduceSGDEngine:
                 # Fuse fence: keeps the weight-gradient convs out of the
                 # optimizer-update fusion group (A/B knob, see config).
                 params, grads = lax.optimization_barrier((params, grads))
-            if optimizer is not None:
-                updates, opt_state = optimizer.update(grads, opt_state, params)
-                new_params = jax.tree.map(lambda p, u: p + u, params, updates)
-            else:
-                updates = None
-                new_params = sgd_update(params, grads, lr)
+            # Names in the device program (docs/observability.md): metadata
+            # only.  ``grad_sync`` names the explicit rings above; GSPMD's
+            # all-reduces are put in by the partitioner and carry the name
+            # of the backward operation whose result they sum.
+            with jax.named_scope("optimizer"):
+                if optimizer is not None:
+                    updates, opt_state = optimizer.update(grads, opt_state,
+                                                          params)
+                    new_params = jax.tree.map(lambda p, u: p + u, params,
+                                              updates)
+                else:
+                    updates = None
+                    new_params = sgd_update(params, grads, lr)
             if sentinels_on:
                 if updates is None:
                     updates = jax.tree.map(lambda q, p: q - p,
@@ -505,6 +679,17 @@ class AllReduceSGDEngine:
         ``checkpoint.resume_or_init`` so schedules and checkpoint cadence
         continue instead of restarting.
         """
+        rec = RunRecord(self.mode, self._inflight_window(), int(start_step))
+        outer, mine = _open_run(rec), self._run
+        self._run = self.last_run = rec
+        try:
+            return self._train(rec, params, iterator, epochs, opt_state,
+                               start_step)
+        finally:
+            self._run = mine
+            _close_run(rec, outer)
+
+    def _train(self, rec, params, iterator, epochs, opt_state, start_step):
         comm = self.comm
         state: Dict[str, Any] = {
             "params": params,
@@ -515,6 +700,7 @@ class AllReduceSGDEngine:
             "engine": self,
             "training": True,
             "comm": comm,
+            "run": rec,
         }
 
         if self.mode == "compiled":
@@ -618,6 +804,11 @@ class AllReduceSGDEngine:
                 state["loss_meter"].reset()
                 self._hook("on_start_epoch", state)
                 for xb, yb in iterator:
+                    t_batch = time.monotonic_ns()
+                    if rec.t_first_batch is None:
+                        rec.t_first_batch = t_batch
+                    rec._wait_ns = rec._hook_ns = 0
+                    ended = False       # the resize boundary ends the loop
                     state["sample"] = (xb, yb)
                     # Reference fences each sample with a barrier + device
                     # sync (sgdengine.lua:111-114); under SPMD the single
@@ -628,6 +819,7 @@ class AllReduceSGDEngine:
                         self._train_step_compiled(state, xb, yb)
                     else:
                         self._train_step_eager(state, xb, yb)
+                    t_stepped = time.monotonic_ns()
                     state["t"] += 1
                     if (self.check_frequency and self.mode != "compiled"
                             and state["t"] % self.check_frequency == 0):
@@ -672,17 +864,22 @@ class AllReduceSGDEngine:
                             out = (self.election_coordinator
                                    .on_boundary_fault(e))
                         if out == _resize_mod.DEPARTED:
-                            state["departed"] = True
-                            break
-                        if out == _resize_mod.COMMITTED:
+                            state["departed"] = ended = True
+                        elif out == _resize_mod.COMMITTED:
                             state["resized"] = (
                                 self.resize_controller.membership.epoch)
-                            break
+                            ended = True
                     # Retune boundary (collectives/retune.py): acts on
                     # firing perf alerts — probes off the hot path, flips
                     # knobs, never raises and never breaks the loop.
-                    if self.retune_controller is not None:
+                    if self.retune_controller is not None and not ended:
                         self.retune_controller.step_boundary()
+                    rec.step_stamps.append(
+                        (rec.steps, t_batch, t_stepped, time.monotonic_ns(),
+                         rec._wait_ns, rec._hook_ns))
+                    rec.steps += 1
+                    if ended:
+                        break
                 if state.get("departed") or state.get("resized"):
                     break
                 self._hook("on_end_epoch", state)
@@ -749,6 +946,8 @@ class AllReduceSGDEngine:
             with _obs.span("engine.dispatch"):
                 out = self._compiled_step(
                     state["params"], state["opt_state"], xb, yb)
+            if self._run.t_first_dispatch is None:
+                self._run.t_first_dispatch = time.monotonic_ns()
             if self._sentinels_on:
                 params, opt_state, loss, nstats = out
             else:
@@ -817,6 +1016,8 @@ class AllReduceSGDEngine:
             t_staged = time.monotonic_ns() if feed else 0
             with _obs.span("engine.grad"):
                 losses, grads = self._eager_grad_fn(state["params"], xb, yb)
+            if self._run.t_first_dispatch is None:
+                self._run.t_first_dispatch = time.monotonic_ns()
             t_grad = time.monotonic_ns() if feed else 0
             state["loss"] = losses
             state["loss_meter"].add(jnp.mean(losses))

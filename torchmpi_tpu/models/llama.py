@@ -433,28 +433,35 @@ def _moe_ffn(cfg: Config, lp: Params, x: jax.Array, dropless: bool = False):
     xg = x.reshape(T // G, G, D)
 
     def route_group(xt):                    # (G, D) -> ((G, D), aux)
-        logits = xt.astype(jnp.float32) @ lp["router"].astype(jnp.float32)
-        probs = jax.nn.softmax(logits, axis=-1)                     # (G, E)
-        # ONE routing definition for both MoE forms: the shared top-k /
-        # choice-major / capacity-queue step (parallel/moe.py:route_topk).
-        sel_f, w_f, onehot, slot = _route_topk(probs, k, k > 1)
-        me = jnp.mean(probs, axis=0)
-        ce = jnp.mean(jax.nn.one_hot(sel_f[:G], E, dtype=jnp.float32), axis=0)
-        aux = E * jnp.sum(me * ce)
-        # one_hot(slot, C) drops units whose queue position >= C.
-        dispatch = (jax.nn.one_hot(slot, C, dtype=jnp.float32)
-                    * onehot[..., None])                            # (kG, E, C)
-        disp = dispatch.astype(x.dtype)
+        with jax.named_scope("moe.router"):
+            logits = xt.astype(jnp.float32) @ lp["router"].astype(jnp.float32)
+            probs = jax.nn.softmax(logits, axis=-1)                 # (G, E)
+            # ONE routing definition for both MoE forms: the shared top-k /
+            # choice-major / capacity-queue step
+            # (parallel/moe.py:route_topk).
+            sel_f, w_f, onehot, slot = _route_topk(probs, k, k > 1)
+            me = jnp.mean(probs, axis=0)
+            ce = jnp.mean(jax.nn.one_hot(sel_f[:G], E, dtype=jnp.float32),
+                          axis=0)
+            aux = E * jnp.sum(me * ce)
+        with jax.named_scope("moe.dispatch"):
+            # one_hot(slot, C) drops units whose queue position >= C.
+            dispatch = (jax.nn.one_hot(slot, C, dtype=jnp.float32)
+                        * onehot[..., None])                        # (kG, E, C)
+            disp = dispatch.astype(x.dtype)
 
-        xk = jnp.tile(xt, (k, 1))                                   # (kG, D)
-        buckets = jnp.einsum("tec,td->ecd", disp, xk)               # (E, C, D)
-        hb = (jax.nn.silu(jnp.einsum("ecd,edf->ecf", buckets, lp["w_gate"]))
-              * jnp.einsum("ecd,edf->ecf", buckets, lp["w_up"]))
-        out_b = jnp.einsum("ecf,efd->ecd", hb, lp["w_down"])        # (E, C, D)
+            xk = jnp.tile(xt, (k, 1))                               # (kG, D)
+            buckets = jnp.einsum("tec,td->ecd", disp, xk)           # (E, C, D)
+        with jax.named_scope("moe.experts"):
+            hb = (jax.nn.silu(jnp.einsum("ecd,edf->ecf", buckets,
+                                         lp["w_gate"]))
+                  * jnp.einsum("ecd,edf->ecf", buckets, lp["w_up"]))
+            out_b = jnp.einsum("ecf,efd->ecd", hb, lp["w_down"])    # (E, C, D)
 
-        combine = disp * w_f[:, None, None].astype(x.dtype)
-        yk = jnp.einsum("tec,ecd->td", combine, out_b)              # (kG, D)
-        return jnp.sum(yk.reshape(k, G, D), axis=0), aux
+        with jax.named_scope("moe.combine"):
+            combine = disp * w_f[:, None, None].astype(x.dtype)
+            yk = jnp.einsum("tec,ecd->td", combine, out_b)          # (kG, D)
+            return jnp.sum(yk.reshape(k, G, D), axis=0), aux
 
     y, aux = jax.vmap(route_group)(xg)
     return y.reshape(B, L, D), jnp.mean(aux)
@@ -473,17 +480,26 @@ def _decoder_layer(cfg: Config, lp: Params, h: jax.Array,
     decoding."""
     B, L, _ = h.shape
     hd, H, KV = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
-    x = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
-    q = rope((x @ lp["wq"]).reshape(B, L, H, hd), positions, cfg.rope_theta)
-    k = rope((x @ lp["wk"]).reshape(B, L, KV, hd), positions, cfg.rope_theta)
-    v = (x @ lp["wv"]).reshape(B, L, KV, hd)
-    o = attn_impl(q, k, v)
-    h = h + constrain(o.reshape(B, L, H * hd) @ lp["wo"])
+    # Names in the device program (docs/observability.md): ``attn`` (the
+    # projections, rope, the attention itself, the output projection),
+    # ``moe.router``/``moe.dispatch``/``moe.experts``/``moe.combine`` or
+    # ``ffn``, ``embed``, ``head_loss``, ``optimizer``.  Metadata only.
+    with jax.named_scope("attn"):
+        x = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
+        q = rope((x @ lp["wq"]).reshape(B, L, H, hd), positions,
+                 cfg.rope_theta)
+        k = rope((x @ lp["wk"]).reshape(B, L, KV, hd), positions,
+                 cfg.rope_theta)
+        v = (x @ lp["wv"]).reshape(B, L, KV, hd)
+        o = attn_impl(q, k, v)
+        h = h + constrain(o.reshape(B, L, H * hd) @ lp["wo"])
     x = rms_norm(h, lp["mlp_norm"], cfg.norm_eps)
     if cfg.n_experts:
         g, aux = _moe_ffn(cfg, lp, x)
     else:
-        g = (jax.nn.silu(x @ lp["w_gate"]) * (x @ lp["w_up"])) @ lp["w_down"]
+        with jax.named_scope("ffn"):
+            g = ((jax.nn.silu(x @ lp["w_gate"]) * (x @ lp["w_up"]))
+                 @ lp["w_down"])
         aux = jnp.zeros((), jnp.float32)
     h = h + constrain(g)
     if with_kv:
@@ -492,6 +508,7 @@ def _decoder_layer(cfg: Config, lp: Params, h: jax.Array,
 
 
 @jax.checkpoint
+@jax.named_scope("head_loss")
 def _chunk_nll(head, h_c, t_c):
     """Summed NLL of one (B, C, D) chunk; checkpointed so the backward
     re-forms its (B, C, V) logits instead of storing them per chunk."""
@@ -501,6 +518,7 @@ def _chunk_nll(head, h_c, t_c):
     return jnp.sum(lse - tgt)
 
 
+@jax.named_scope("head_loss")
 def _nll_from_hidden(head: jax.Array, h: jax.Array, targets: jax.Array,
                      loss_chunk: int) -> jax.Array:
     """Mean next-token NLL from final (post-norm) hidden states — the one
@@ -680,7 +698,8 @@ def apply(cfg: Config, params: Params, tokens: jax.Array,
         kept = _mesh_spec(P(AXIS_DP, AXIS_SP, None), mesh)
         return lax.with_sharding_constraint(x, NamedSharding(mesh, kept))
 
-    h = constrain(params["embed"][tokens])          # (B, L, D)
+    with jax.named_scope("embed"):
+        h = constrain(params["embed"][tokens])      # (B, L, D)
     attn_impl = _make_attn_impl(cfg, attn, mesh, scale)
 
     def layer(carry, lp):
@@ -1563,12 +1582,14 @@ def make_train_step(cfg: Config, mesh: Mesh, lr: float = 3e-4,
 
     def step(params, opt_state, tokens, targets):
         loss, grads = jax.value_and_grad(loss_fn)(params, (tokens, targets))
-        if optimizer is not None:
-            updates, opt_state = optimizer.update(grads, opt_state, params)
-            params = jax.tree.map(lambda p, u: p + u, params, updates)
-        else:
-            params = jax.tree.map(lambda p, g: p - lr * g.astype(p.dtype),
-                                  params, grads)
+        with jax.named_scope("optimizer"):
+            if optimizer is not None:
+                updates, opt_state = optimizer.update(grads, opt_state,
+                                                      params)
+                params = jax.tree.map(lambda p, u: p + u, params, updates)
+            else:
+                params = jax.tree.map(lambda p, g: p - lr * g.astype(p.dtype),
+                                      params, grads)
         return params, opt_state, loss
 
     return jax.jit(

@@ -105,6 +105,12 @@ def _bn_state(c: int) -> Params:
     return {"mean": jnp.zeros((c,), jnp.float32), "var": jnp.ones((c,), jnp.float32)}
 
 
+# Every part of the step runs under a ``jax.named_scope`` (stem, conv, bn,
+# residual, pool, fc_loss): metadata only, carried into the ``op_name`` of the
+# compiled program's instructions (``transpose(...)`` round it on the backward
+# pass), so that a device trace can be read by part (docs/observability.md).
+
+@jax.named_scope("conv")
 def _conv(x: jax.Array, w: jax.Array, stride: int = 1) -> jax.Array:
     return lax.conv_general_dilated(
         x, w, window_strides=(stride, stride), padding="SAME",
@@ -112,6 +118,7 @@ def _conv(x: jax.Array, w: jax.Array, stride: int = 1) -> jax.Array:
     )
 
 
+@jax.named_scope("stem")
 def _stem_s2d(x: jax.Array, w7: jax.Array) -> jax.Array:
     """The 7x7 stride-2 SAME stem conv as an identical 4x4 stride-1 conv on
     space-to-depth input.
@@ -141,6 +148,7 @@ def _stem_s2d(x: jax.Array, w7: jax.Array) -> jax.Array:
     )
 
 
+@jax.named_scope("bn")
 def _batch_norm(x: jax.Array, p: Params, stats: Optional[Params], train: bool,
                 eps: float = 1e-5, collect: Optional[list] = None) -> jax.Array:
     """Mixed-precision batch norm: statistics *accumulate* in f32 (via the
@@ -213,7 +221,8 @@ def _block_apply(kind: str, p: Params, s: Optional[Params], x: jax.Array,
         out = bn(out, "bn3", "bn3")
     if "proj" in p:
         x = bn(_conv(x, p["proj"], stride), "bn_proj", "bn_proj")
-    return jax.nn.relu(out + x)
+    with jax.named_scope("residual"):
+        return jax.nn.relu(out + x)
 
 
 # ----------------------------------------------------------------- public API
@@ -254,18 +263,23 @@ def apply(cfg: Config, params: Params, x: jax.Array,
     if cfg.stem_space_to_depth:
         h = _stem_s2d(x, params["stem_conv"])
     else:
-        h = _conv(x, params["stem_conv"], stride=2)
+        with jax.named_scope("stem"):
+            h = _conv(x, params["stem_conv"], stride=2)
     h = jax.nn.relu(_batch_norm(h, params["stem_bn"],
                                 state["stem_bn"] if state else None, train,
                                 collect=_collect))
-    h = lax.reduce_window(h, -jnp.inf, lax.max, (1, 3, 3, 1), (1, 2, 2, 1), "SAME")
+    with jax.named_scope("pool"):
+        h = lax.reduce_window(h, -jnp.inf, lax.max, (1, 3, 3, 1),
+                              (1, 2, 2, 1), "SAME")
 
     for p, s, stride in zip(params["blocks"], sblocks, cfg.strides):
         h = _block_apply(cfg.kind, p, s, h, stride, train, collect=_collect)
 
-    h = jnp.mean(h, axis=(1, 2))  # global average pool
-    return (h.astype(jnp.float32) @ params["fc_w"].astype(jnp.float32)
-            + params["fc_b"].astype(jnp.float32))
+    with jax.named_scope("pool"):
+        h = jnp.mean(h, axis=(1, 2))  # global average pool
+    with jax.named_scope("fc_loss"):
+        return (h.astype(jnp.float32) @ params["fc_w"].astype(jnp.float32)
+                + params["fc_b"].astype(jnp.float32))
 
 
 def make_update_stats_fn(cfg: Config, momentum: float = 0.9):
@@ -309,8 +323,9 @@ def make_loss_fn(cfg: Config):
     def loss_fn(params: Params, batch: Tuple[jax.Array, jax.Array]) -> jax.Array:
         x, y = batch
         logits = apply(cfg, params, x, train=True)
-        logp = jax.nn.log_softmax(logits)
-        return -jnp.mean(jnp.take_along_axis(logp, y[:, None], axis=1))
+        with jax.named_scope("fc_loss"):
+            logp = jax.nn.log_softmax(logits)
+            return -jnp.mean(jnp.take_along_axis(logp, y[:, None], axis=1))
 
     return loss_fn
 

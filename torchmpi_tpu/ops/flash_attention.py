@@ -123,6 +123,7 @@ def _flash_bh(qbh, kbh, vbh, *, causal: bool, block_q: int, block_k: int,
             pltpu.VMEM((block_q, 1), jnp.float32),   # running denominator
         ],
         interpret=interpret,
+        name="flash_fwd",       # the kernel's name in the compiled program
     )(qbh, kbh, vbh)
 
 
@@ -259,6 +260,7 @@ def _flash_bh_bwd(qbh, kbh, vbh, dobh, lse, delta, *, causal: bool,
         out_specs=qd,
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qbh, kbh, vbh, dobh, lse, delta)
 
     qd2 = pl.BlockSpec((None, block_q, D), lambda b, ki, qi: (b, qi, 0))
@@ -274,6 +276,7 @@ def _flash_bh_bwd(qbh, kbh, vbh, dobh, lse, delta, *, causal: bool,
         scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
                         pltpu.VMEM((block_k, D), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qbh, kbh, vbh, dobh, lse, delta)
     return dq, dk, dv
 
