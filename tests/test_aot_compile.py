@@ -10,6 +10,8 @@ Refusals are pinned as they are.  The day someone re-tiles the ring kernel
 the ``pytest.raises`` below fails, and tells them to move the bound.
 """
 
+import re
+
 import pytest
 
 import jax
@@ -39,10 +41,28 @@ def _kernels(compiled) -> int:
     return compiled.as_text().count("tpu_custom_call")
 
 
+V5E_VMEM_BYTES = 128 * 1024 * 1024     # one v5e TensorCore
+
+
+def _kernel_vmem(compiled):
+    """(stated, used) bytes of scoped VMEM of each Mosaic kernel of the
+    program, from the custom call's ``backend_config``."""
+    size = r'scoped_memory_configs":\[\{"memory_space":"1","offset":"0","size":"(\d+)"'
+    out = []
+    for line in compiled.as_text().splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            stated = re.search('"' + size, line)
+            used = re.search('"used_' + size, line)
+            out.append((int(stated.group(1)), int(used.group(1))))
+    return out
+
+
 @pytest.mark.parametrize("L", [4096, 16384])
 def test_flash_fwd_and_grad(v5e, L):
     """``ops.flash_attention`` forward and ``jax.grad``, (1, L, 32, 128)
-    bf16 causal, on one chip."""
+    bf16 causal, on one chip: one forward kernel, one backward kernel whose
+    float32 dq block of the whole (L, 128) sits in VMEM.  The compile is the
+    check that its plan fits; what it asks for stays under the chip's."""
     x = _sds((1, L, H, D), jnp.bfloat16, SingleDeviceSharding(v5e[0]))
 
     def fwd(q, k, v):
@@ -52,15 +72,14 @@ def test_flash_fwd_and_grad(v5e, L):
         return jnp.sum(fwd(q, k, v).astype(jnp.float32))
 
     assert _kernels(jax.jit(fwd).lower(x, x, x).compile()) == 1
-    assert _kernels(jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
-                    .lower(x, x, x).compile()) == 3     # fwd, dq, dk/dv
+    grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(x, x, x).compile()
+    assert _kernels(grad) == 2                          # flash_fwd, flash_bwd
+    (_, fwd_used), (stated, used) = _kernel_vmem(grad)
+    assert fwd_used < 16 * 1024 * 1024                  # the default limit
+    assert 2 * L * D * 4 < used <= stated < V5E_VMEM_BYTES
 
 
-def test_ring_flash_blocks_f32_out(v5e):
-    """The ring's per-chunk kernels at L=16384 over sp=4: local Q against
-    one circulating K/V chunk of 4096, partial outputs carried in f32."""
-    one = SingleDeviceSharding(v5e[0])
-    Lc = 16384 // 4
+def _ring_blocks(one, Lc):
     x = _sds((H, Lc, D), jnp.bfloat16, one)
     row = _sds((H, Lc, 1), jnp.float32, one)
 
@@ -72,8 +91,27 @@ def test_ring_flash_blocks_f32_out(v5e):
         return flash_bwd_block(q, k, v, do, lse, delta, causal=True,
                                interpret=False, out_dtype=jnp.float32)
 
-    assert _kernels(jax.jit(fwd).lower(x, x, x).compile()) == 1
-    assert _kernels(jax.jit(bwd).lower(x, x, x, x, row, row).compile()) == 2
+    return (jax.jit(fwd).lower(x, x, x).compile(),
+            jax.jit(bwd).lower(x, x, x, x, row, row).compile())
+
+
+def test_ring_flash_blocks_f32_out(v5e):
+    """The ring's per-chunk kernels at L=16384 over sp=4: local Q against
+    one circulating K/V chunk of 4096, partial outputs carried in f32."""
+    fwd, bwd = _ring_blocks(SingleDeviceSharding(v5e[0]), 16384 // 4)
+    assert _kernels(fwd) == 1
+    assert _kernels(bwd) == 1                           # flash_bwd
+
+
+@pytest.mark.parametrize("Lc,kernels", [(65536, 1), (131072, 2)])
+def test_flash_bwd_form_at_long_chunks(v5e, Lc, kernels):
+    """A local chunk of 65,536 rows still takes the one backward kernel
+    (its dq block is 64 MiB in both buffers) and the compiler takes its
+    VMEM plan; at 131,072 the two streaming kernels compile instead."""
+    _, bwd = _ring_blocks(SingleDeviceSharding(v5e[0]), Lc)
+    assert _kernels(bwd) == kernels
+    assert all(used <= stated < V5E_VMEM_BYTES
+               for stated, used in _kernel_vmem(bwd))
 
 
 def _ring_allreduce(n):

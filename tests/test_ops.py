@@ -8,6 +8,8 @@ import jax
 import jax.numpy as jnp
 
 from torchmpi_tpu.ops import flash_attention
+from torchmpi_tpu.ops.flash_attention import (
+    _flash_bh_bwd, _bwd_vmem_bytes, flash_bwd_block, flash_fwd_block)
 from torchmpi_tpu.parallel import sequence as seq
 
 
@@ -57,3 +59,166 @@ class TestFlashAttention:
         got = llama.apply(cfg, params, tokens, attn="flash")
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-4, atol=2e-4)
+
+
+# ------------------------------------------------------------ flash backward
+
+def _pallas_calls(jaxpr):
+    """Names of the ``pallas_call``s of a jaxpr, in order, sub-jaxprs
+    (scan, checkpoint, custom_vjp, pjit) included."""
+    names = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            names.append(eqn.params["name"])
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names += _pallas_calls(sub)
+    return names
+
+
+def _reference(q, k, v, do, causal):
+    """``o`` and ``(dq, dk, dv)`` of plain attention by autodiff, (B, L, H,
+    D) layout: ``llama._causal_attention`` where causal and square,
+    ``seq.full_attention`` (the same mask, rows >= cols) where not."""
+    from torchmpi_tpu.models import llama
+
+    if causal and q.shape == k.shape:
+        scale = 1.0 / np.sqrt(q.shape[-1])
+        ref = lambda q, k, v: llama._causal_attention(q, k, v, scale)
+    else:
+        ref = jax.vmap(
+            lambda q, k, v: seq.full_attention(q, k, v, causal=causal))
+    o, vjp = jax.vjp(ref, q, k, v)
+    return o, vjp(do)
+
+
+def _bh(x):
+    """(B, L, H, D) -> (B*H, L, D), the kernels' layout."""
+    B, L, H, D = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(B * H, L, D)
+
+
+class TestFlashBackward:
+    """The one ``flash_bwd`` kernel against the two streaming kernels it
+    replaces (still the form past a VMEM budget) and against autodiff of
+    plain attention, float32 on the interpreter."""
+
+    @pytest.mark.parametrize("Lq,Lk,block_q,block_k,causal", [
+        (64, 64, 16, 16, True), (64, 64, 16, 16, False),  # several blocks
+        (96, 96, 32, 16, True), (96, 96, 16, 32, True),   # block_q != block_k
+        (96, 96, 48, 16, False),
+        (32, 96, 16, 32, True), (96, 32, 32, 16, True),   # Lq != Lk
+        (197, 197, 197, 197, False),                      # ViT: one block
+    ])
+    def test_matches_two_kernels_and_autodiff(self, Lq, Lk, block_q, block_k,
+                                              causal):
+        q, do = _qkv(L=Lq)[0], _qkv(L=Lq, seed=1)[0]
+        _, k, v = _qkv(L=Lk, seed=2)
+        kw = dict(causal=causal, block_q=block_q, block_k=block_k,
+                  interpret=True)
+        o, lse = flash_fwd_block(_bh(q), _bh(k), _bh(v), **kw)
+        delta = jnp.sum(_bh(do) * o, axis=-1, keepdims=True)
+        args = (_bh(q), _bh(k), _bh(v), _bh(do), lse, delta)
+        fused = _flash_bh_bwd(*args, **kw)
+        two = _flash_bh_bwd(*args, **kw, vmem_budget=0)
+        want_o, want = _reference(q, k, v, do, causal)
+        np.testing.assert_allclose(np.asarray(o), np.asarray(_bh(want_o)),
+                                   rtol=1e-5, atol=1e-5)
+        for name, a, b, w in zip(("dq", "dk", "dv"), fused, two, want):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-5, atol=1e-5, err_msg=name)
+            np.testing.assert_allclose(np.asarray(a), np.asarray(_bh(w)),
+                                       rtol=1e-5, atol=1e-5, err_msg=name)
+
+    def test_custom_vjp_uses_it(self):
+        """``jax.grad`` of ``flash_attention`` runs the one kernel and
+        agrees with autodiff of plain attention."""
+        q, k, v = _qkv()
+        do = _qkv(seed=1)[0]
+        f = lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                            block_q=16, block_k=32)
+        got = jax.vjp(f, q, k, v)[1](do)
+        for a, w in zip(got, _reference(q, k, v, do, True)[1]):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(w),
+                                       rtol=1e-5, atol=1e-5)
+        jaxpr = jax.make_jaxpr(lambda *a: jax.vjp(f, *a[:3])[1](a[3]))(
+            q, k, v, do)
+        assert _pallas_calls(jaxpr.jaxpr) == ["flash_fwd", "flash_bwd"]
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_ring_call_external_lse_f32_out(self, dtype):
+        """The ring's call: local Q (32 rows) against one K/V chunk of 64 at
+        a time, the GLOBAL lse and delta supplied, float32 partials.  The
+        chunks' dq summed and their dk, dv side by side are the gradients
+        of attention over the whole K/V."""
+        q = _qkv(L=32)[0]
+        _, k, v = _qkv(L=128, seed=2)
+        do = _qkv(L=32, seed=1)[0]
+        q, k, v, do = (x.astype(dtype).astype(jnp.float32)
+                       for x in (q, k, v, do))
+        D = q.shape[-1]
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(D)
+        lse = jax.nn.logsumexp(s, axis=-1).reshape(-1, 32, 1)
+        o, want = _reference(q, k, v, do, False)
+        delta = jnp.sum(_bh(do) * _bh(o), axis=-1, keepdims=True)
+
+        def chunks(**extra):
+            out = [flash_bwd_block(
+                _bh(q).astype(dtype), _bh(k)[:, c:c + 64].astype(dtype),
+                _bh(v)[:, c:c + 64].astype(dtype), _bh(do).astype(dtype),
+                lse, delta, causal=False, block_q=16, block_k=32,
+                interpret=True, out_dtype=jnp.float32, **extra)
+                for c in (0, 64)]
+            assert all(g.dtype == jnp.float32 for part in out for g in part)
+            return (out[0][0] + out[1][0],
+                    jnp.concatenate([out[0][1], out[1][1]], axis=1),
+                    jnp.concatenate([out[0][2], out[1][2]], axis=1))
+
+        for a, w in zip(chunks(), want):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(_bh(w)),
+                                       rtol=1e-5, atol=1e-5)
+
+    @pytest.mark.parametrize("Lq,fused", [(65536, True), (131072, False)])
+    def test_form_follows_shape(self, Lq, fused):
+        """Past the VMEM budget (read from the shapes: a local chunk of
+        about 80k rows at D=128) the two streaming kernels are the form."""
+        x = jax.ShapeDtypeStruct((1, Lq, 128), jnp.bfloat16)
+        row = jax.ShapeDtypeStruct((1, Lq, 1), jnp.float32)
+        jaxpr = jax.make_jaxpr(lambda *a: flash_bwd_block(
+            *a, causal=True, interpret=True))(x, x, x, x, row, row)
+        assert _pallas_calls(jaxpr.jaxpr) == (
+            ["flash_bwd"] if fused else ["flash_bwd_dq", "flash_bwd_dkv"])
+
+    def test_budget_is_the_threshold(self):
+        x = jax.ShapeDtypeStruct((2, 64, 16), jnp.float32)
+        row = jax.ShapeDtypeStruct((2, 64, 1), jnp.float32)
+        need = (_bwd_vmem_bytes(16, 16, 16, jnp.float32, jnp.float32)
+                + 2 * 64 * 16 * 4)          # and dq: 64 rows, two buffers
+
+        def calls(budget):
+            return _pallas_calls(jax.make_jaxpr(lambda *a: _flash_bh_bwd(
+                *a, causal=True, block_q=16, block_k=16, interpret=True,
+                vmem_budget=budget))(x, x, x, x, row, row).jaxpr)
+
+        assert calls(need) == ["flash_bwd"]
+        assert calls(need - 1) == ["flash_bwd_dq", "flash_bwd_dkv"]
+
+
+@pytest.mark.parametrize("remat,forwards", [("dots", 1), ("none", 1),
+                                            ("full", 2)])
+def test_train_step_forms_scores_once_each_way(remat, forwards):
+    """The step of ``make_train_step(attn="flash")`` holds one forward and
+    one backward kernel: under ``remat="dots"`` the layer's checkpoint keeps
+    the kernel's ``o`` and ``lse`` (``flash_attention.RESIDUAL_NAMES``), so
+    the forward is not replayed; ``"full"`` keeps nothing and replays it."""
+    from torchmpi_tpu.models import llama
+    from torchmpi_tpu.parallel import mesh as pmesh
+
+    cfg = llama.moe_tiny()
+    mesh = pmesh.make_mesh({"dp": 1}, devices=jax.devices()[:1])
+    step = llama.make_train_step(cfg, mesh, attn="flash", remat=remat)
+    params = jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0), cfg))
+    tokens = jax.ShapeDtypeStruct((2, 64), jnp.int32)
+    calls = _pallas_calls(
+        jax.make_jaxpr(step)(params, None, tokens, tokens).jaxpr)
+    assert sorted(calls) == ["flash_bwd"] + ["flash_fwd"] * forwards

@@ -48,12 +48,18 @@ def test_llama_train_step_carries_scope_and_kernel_names(make_cfg, ffn,
         assert _carried(names, scope), scope
     for scope in absent:
         assert not _carried(names, scope), scope
-    # The three kernels, under the attention's scope; the backward ones
-    # only in the checkpointed layer's backward pass.
-    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+    # The two kernels, under the attention's scope.  The backward one runs
+    # only in the checkpointed layer's backward pass; the forward one only
+    # in the forward pass, not again in the layer's recomputation, because
+    # remat="dots" keeps the kernel's o and lse.
+    for kernel in ("flash_fwd", "flash_bwd"):
         assert _carried(names, kernel, inside="attn/" + kernel), kernel
-    assert _carried(names, "flash_bwd_dq", inside="checkpoint/attn/")
-    assert not _carried(names, "flash_bwd_dq", inside="rematted_computation")
+    assert not _carried(names, "flash_bwd_dq")
+    assert not _carried(names, "flash_bwd_dkv")
+    assert _carried(names, "flash_bwd", inside="checkpoint/attn/")
+    assert not _carried(names, "flash_bwd", inside="rematted_computation")
+    assert not _carried(names, "flash_fwd", inside="rematted_computation")
+    assert _carried(names, "attn", inside="rematted_computation")
     for scope in ("embed", "head_loss"):
         assert _carried(names, scope, inside="transpose(jvp(" + scope), scope
     assert not _carried(names, "optimizer", inside="transpose(")

@@ -301,7 +301,10 @@ def _flash_attention_sharded(mesh: Optional[Mesh], heads: int,
     cannot be automatically partitioned``), and each device wants only its
     own batch rows and head shard anyway — the layout the hand-sharded
     stage (:func:`_decoder_layer_tp_manual`) already runs.  K/V are split
-    at ``kv_heads`` and repeated locally, as in the ring."""
+    at ``kv_heads`` and repeated locally, as in the ring.  A step runs two
+    kernels a layer, ``flash_fwd`` and ``flash_bwd``; under ``remat="dots"``
+    the layer's checkpoint keeps the forward's ``o`` and ``lse``
+    (:func:`_wrap_remat`), under ``"full"`` the forward runs twice."""
     from jax import shard_map
 
     from ..ops import flash_attention
@@ -659,9 +662,11 @@ def apply(cfg: Config, params: Params, tokens: jax.Array,
     (gradient checkpointing — the HBM/FLOPs trade SURVEY.md §7 prescribes
     for 8B-scale):
       * ``"none"``  — save all residuals (small models),
-      * ``"dots"``  — save matmul outputs, recompute elementwise
-        (``jax.checkpoint_policies.dots_with_no_batch_dims_saveable``; the
-        transformer default: activations per layer shrink ~4x),
+      * ``"dots"``  — save matmul outputs and the flash kernel's output
+        and log-sum-exp, recompute elementwise
+        (``jax.checkpoint_policies.dots_with_no_batch_dims_saveable`` plus
+        the kernel's residual names, :func:`_wrap_remat`; the transformer
+        default: activations per layer shrink ~4x),
       * ``"full"``  — save only layer boundaries, recompute everything
         (longest contexts; backward recomputes each layer's forward).
 
@@ -1014,11 +1019,20 @@ def make_generate_fn(cfg: Config, prompt_len: int, max_new: int,
 
 def _wrap_remat(layer: Callable, remat: str) -> Callable:
     """THE remat taxonomy ('none'/'dots'/'full'), one definition for the
-    scanned forward and both pipeline stage builders."""
+    scanned forward and both pipeline stage builders.
+
+    ``"dots"`` keeps matmul outputs and the flash kernel's two residuals,
+    ``o`` and ``lse``: the kernel is no dot, so the dots policy alone would
+    replay the whole forward kernel in the backward pass.  The names are the
+    kernel's (``ops.flash_attention.RESIDUAL_NAMES``); no other attention
+    mode emits them, so ``attn="full"`` and the rings compile as before."""
     if remat == "dots":
-        return jax.checkpoint(
-            layer,
-            policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
+        from ..ops.flash_attention import RESIDUAL_NAMES
+
+        policies = jax.checkpoint_policies
+        return jax.checkpoint(layer, policy=policies.save_from_both_policies(
+            policies.dots_with_no_batch_dims_saveable,
+            policies.save_only_these_names(*RESIDUAL_NAMES)))
     if remat == "full":
         return jax.checkpoint(layer)
     if remat != "none":

@@ -11,7 +11,13 @@ sequence length is bounded by HBM, not VMEM (pallas_guide.md: memory
 hierarchy, MXU notes, scratch shapes).
 
 Causal mode predicates whole K blocks above the diagonal off with
-``pl.when``, skipping ~half the MXU work.
+``pl.when``, skipping ~half the MXU work, and the index maps name no new
+block for such a pair, so nothing is fetched for it either.
+
+A training step forms each score block once in the forward pass and once in
+the backward pass: the backward is one kernel (see its section), and the
+forward rule names its residuals (``RESIDUAL_NAMES``) so that a caller's
+``jax.checkpoint`` can keep them and not replay the forward kernel.
 
 ``interpret=True`` (automatic off-TPU) runs the same kernel through the
 Pallas interpreter, keeping CPU tests exact.
@@ -27,10 +33,47 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+
+# The names the forward rule gives its two residuals that are not inputs
+# (``jax.ad_checkpoint.checkpoint_name``).  A caller that wraps attention in
+# ``jax.checkpoint`` saves these names in its policy, or the backward pass
+# replays the whole forward kernel to get them back: a Pallas call is no dot,
+# so ``dots_with_no_batch_dims_saveable`` alone keeps neither
+# (``models/llama.py:_wrap_remat``).
+RESIDUAL_NAMES = ("flash_o", "flash_lse")
+
+
+def _when_unmasked(causal: bool, q_start, bq: int, k_start, compute):
+    """Run ``compute`` unless causal masking blanks the whole pair (the K
+    block lies strictly above the diagonal of the Q block)."""
+    if causal:
+        pl.when(q_start + bq - 1 >= k_start)(compute)
+    else:
+        compute()
+
+
+def _unmasked_k(causal: bool, block_q: int, block_k: int, nk: int):
+    """``(qi, ki) -> ki`` for a K-side index map: under causal masking, the
+    K blocks past the last one that Q block ``qi`` meets name that last one
+    again, so the pipeline fetches nothing for a pair that does not run."""
+    if not causal:
+        return lambda qi, ki: ki
+    return lambda qi, ki: jnp.minimum(
+        ki, jnp.minimum((qi * block_q + block_q - 1) // block_k, nk - 1))
+
+
+def _unmasked_q(causal: bool, block_q: int, block_k: int, nq: int):
+    """``(ki, qi) -> qi`` for a Q-side index map: the Q blocks before the
+    first one that K block ``ki`` meets name that first one."""
+    if not causal:
+        return lambda ki, qi: qi
+    return lambda ki, qi: jnp.maximum(
+        qi, jnp.minimum((ki * block_k) // block_q, nq - 1))
 
 
 def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
@@ -72,18 +115,14 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
                          + jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
                                                preferred_element_type=jnp.float32))
 
-    if causal:
-        # Skip K blocks strictly above the diagonal (every position masked).
-        pl.when(q_start + bq - 1 >= k_start)(_compute)
-    else:
-        _compute()
+    _when_unmasked(causal, q_start, bq, k_start, _compute)
 
     @pl.when(ki == nk - 1)
     def _finalize():
         l = jnp.maximum(l_ref[:, 0], 1e-20)
         o_ref[:, :] = (acc_ref[:, :] / l[:, None]).astype(o_ref.dtype)
         # log-sum-exp per query row — the single residual the backward
-        # kernels need to re-form p = exp(s - lse) block-by-block.
+        # kernel needs to re-form p = exp(s - lse) block-by-block.
         lse_ref[:, 0] = m_ref[:, 0] + jnp.log(l)
 
 
@@ -104,6 +143,9 @@ def _flash_bh(qbh, kbh, vbh, *, causal: bool, block_q: int, block_k: int,
     out_dtype = qbh.dtype if out_dtype is None else out_dtype
     grid = (BH, L // block_q, Lk // block_k)
     kernel = functools.partial(_attn_kernel, causal=causal, scale=scale)
+    k_of = _unmasked_k(causal, block_q, block_k, grid[2])
+    kd = pl.BlockSpec((None, block_k, D),
+                      lambda b, qi, ki: (b, k_of(qi, ki), 0))
     return pl.pallas_call(
         kernel,
         out_shape=(jax.ShapeDtypeStruct((BH, L, D), out_dtype),
@@ -111,8 +153,7 @@ def _flash_bh(qbh, kbh, vbh, *, causal: bool, block_q: int, block_k: int,
         grid=grid,
         in_specs=[
             pl.BlockSpec((None, block_q, D), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((None, block_k, D), lambda b, qi, ki: (b, ki, 0)),
-            pl.BlockSpec((None, block_k, D), lambda b, qi, ki: (b, ki, 0)),
+            kd, kd,
         ],
         out_specs=(pl.BlockSpec((None, block_q, D), lambda b, qi, ki: (b, qi, 0)),
                    pl.BlockSpec((None, block_q, 1),
@@ -129,18 +170,126 @@ def _flash_bh(qbh, kbh, vbh, *, causal: bool, block_q: int, block_k: int,
 
 # ------------------------------------------------------------------ backward
 #
-# FlashAttention-2 backward split into two streaming kernels so each keeps a
-# single accumulator in VMEM and neither ever forms the (L, L) score matrix:
-#   * dq:     grid (BH, q-blocks, k-blocks) — k innermost, dq accumulates;
-#   * dk/dv:  grid (BH, k-blocks, q-blocks) — q innermost, dk/dv accumulate.
-# Both re-form the probability block p = exp(s - lse) from the forward's
-# saved log-sum-exp and use delta_i = rowsum(do_i * o_i) for the softmax
-# Jacobian: ds = p * (dp - delta), dp = do @ v^T.
+# FlashAttention-2 backward.  The forward's saved log-sum-exp re-forms the
+# probability block p = exp(s - lse), and delta_i = rowsum(do_i * o_i) gives
+# the softmax Jacobian: ds = p * (dp - delta), dp = do @ v^T.  Three
+# gradients come out of one (q-block, k-block) pair: dv += p^T @ do,
+# dk += ds^T @ q, dq += ds @ k.  dk/dv accumulate along q and dq along k, so
+# no grid order keeps all three accumulators in one tile of VMEM.
+#
+#   * ``flash_bwd`` forms s, p, dp, ds ONCE per pair (5 matrix products):
+#     grid (BH, k-blocks, q-blocks), q innermost; dk/dv accumulate in VMEM
+#     scratch across q; dq is a float32 output block over the whole (Lq, D)
+#     of one batch*head, so it stays in VMEM across that b's sweep, is
+#     zeroed at the sweep's first program, added into a block of rows at a
+#     time and written back once.  It needs 2 * Lq * D * 4 bytes of VMEM
+#     (both pipeline buffers) on top of the tiles.
+#   * Where that does not fit ``_VMEM_BUDGET`` (a local chunk past about
+#     80k rows at D=128), two streaming kernels whose working set is one
+#     tile a side do the same work with the score block formed twice (7
+#     products): ``flash_bwd_dq`` on grid (BH, q-blocks, k-blocks),
+#     ``flash_bwd_dkv`` on (BH, k-blocks, q-blocks).
+#
+# Neither form ever holds the (L, L) score matrix.
+
+# VMEM of one v5e TensorCore is 128 MiB, of which a kernel gets 16 MiB
+# unless it states its need (``vmem_limit_bytes``).  The backward kernels
+# state theirs, and ``flash_bwd`` is the form taken while its need stays
+# under this budget.
+_VMEM_BUDGET = 100 * 1024 * 1024
+
+
+def _bwd_vmem_bytes(block_q: int, block_k: int, D: int, in_dtype,
+                    out_dtype) -> int:
+    """VMEM a streaming backward kernel asks for, from its shapes: every
+    streamed tile in both pipeline buffers (a (block_q, 1) column of lse or
+    delta pads to 128 lanes), the float32 accumulators, and four float32
+    (block_q, block_k) blocks for s/p, dp/ds and the operands the compiler
+    transposes.  At 1024-wide blocks and D=128 in bfloat16 this gives
+    22 MiB, where the compiler's own count for ``flash_bwd`` is 16.6 beside
+    its dq block."""
+    isz, osz = jnp.dtype(in_dtype).itemsize, jnp.dtype(out_dtype).itemsize
+    wide = max(block_q, block_k)
+    tiles = (2 * block_q * D * isz + 2 * block_k * D * isz   # q, do; k, v
+             + 2 * block_q * 128 * 4                         # lse, delta
+             + 2 * wide * D * osz)                           # dq, or dk and dv
+    return 2 * tiles + 2 * wide * D * 4 + 4 * block_q * block_k * 4
+
+
+def _bwd_pair(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, q_start,
+              k_start, *, causal: bool, scale: float):
+    """One (q-block, k-block) pair of the backward: the float32 operands and
+    the blocks ``p`` and ``ds`` (bq, bk) every gradient is a product of."""
+    bq, bk = q_ref.shape[0], k_ref.shape[0]
+    q = q_ref[:, :].astype(jnp.float32)
+    k = k_ref[:, :].astype(jnp.float32)
+    v = v_ref[:, :].astype(jnp.float32)
+    do = do_ref[:, :].astype(jnp.float32)
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    if causal:
+        rows = q_start + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+        cols = k_start + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+        s = jnp.where(rows >= cols, s, NEG_INF)
+    p = jnp.exp(s - lse_ref[:, 0][:, None])
+    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    ds = p * (dp - delta_ref[:, 0][:, None]) * scale
+    return q, k, do, p, ds
+
+
+def _attn_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                     dq_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
+                     causal: bool, scale: float):
+    """One (batch*head, k-block, q-block) program of ``flash_bwd``.
+    ``dq_ref`` is the whole (Lq, D) of this batch*head; with ``dq_ref``
+    None the program is ``flash_bwd_dkv``'s."""
+    bq = q_ref.shape[0]
+    bk = k_ref.shape[0]
+    ki = pl.program_id(1)
+    qi = pl.program_id(2)
+    nq = pl.num_programs(2)
+    q_start = qi * bq
+    k_start = ki * bk
+
+    if dq_ref is not None:
+        @pl.when((ki == 0) & (qi == 0))
+        def _init_dq():
+            dq_ref[:, :] = jnp.zeros_like(dq_ref)
+
+    @pl.when(qi == 0)
+    def _init():
+        dk_acc[:, :] = jnp.zeros_like(dk_acc)
+        dv_acc[:, :] = jnp.zeros_like(dv_acc)
+
+    def _compute():
+        q, k, do, p, ds = _bwd_pair(q_ref, k_ref, v_ref, do_ref, lse_ref,
+                                    delta_ref, q_start, k_start,
+                                    causal=causal, scale=scale)
+        dv_acc[:, :] += jax.lax.dot_general(
+            p, do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)                    # p^T @ do
+        dk_acc[:, :] += jax.lax.dot_general(
+            ds, q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)                    # ds^T @ q
+        if dq_ref is not None:
+            rows = pl.ds(pl.multiple_of(q_start, bq), bq)
+            dq_ref[rows, :] += jax.lax.dot_general(
+                ds, k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)                # ds @ k
+
+    # Skip Q blocks wholly above the diagonal for this K block.
+    _when_unmasked(causal, q_start, bq, k_start, _compute)
+
+    @pl.when(qi == nq - 1)
+    def _finalize():
+        dk_ref[:, :] = dk_acc[:, :].astype(dk_ref.dtype)
+        dv_ref[:, :] = dv_acc[:, :].astype(dv_ref.dtype)
 
 
 def _attn_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                         dq_ref, acc_ref, *, causal: bool, scale: float):
-    bq, d = q_ref.shape
+    bq = q_ref.shape[0]
     bk = k_ref.shape[0]
     qi = pl.program_id(1)
     ki = pl.program_id(2)
@@ -153,28 +302,14 @@ def _attn_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         acc_ref[:, :] = jnp.zeros_like(acc_ref)
 
     def _compute():
-        q = q_ref[:, :].astype(jnp.float32)
-        k = k_ref[:, :].astype(jnp.float32)
-        v = v_ref[:, :].astype(jnp.float32)
-        do = do_ref[:, :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if causal:
-            rows = q_start + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            cols = k_start + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            s = jnp.where(rows >= cols, s, NEG_INF)
-        p = jnp.exp(s - lse_ref[:, 0][:, None])
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[:, 0][:, None]) * scale
+        _, k, _, _, ds = _bwd_pair(q_ref, k_ref, v_ref, do_ref, lse_ref,
+                                   delta_ref, q_start, k_start,
+                                   causal=causal, scale=scale)
         acc_ref[:, :] += jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    if causal:
-        pl.when(q_start + bq - 1 >= k_start)(_compute)
-    else:
-        _compute()
+    _when_unmasked(causal, q_start, bq, k_start, _compute)
 
     @pl.when(ki == nk - 1)
     def _finalize():
@@ -182,75 +317,71 @@ def _attn_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _attn_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                         dk_ref, dv_ref, dk_acc, dv_acc, *,
-                         causal: bool, scale: float):
-    bk, d = k_ref.shape
-    bq = q_ref.shape[0]
-    ki = pl.program_id(1)
-    qi = pl.program_id(2)
-    nq = pl.num_programs(2)
-    q_start = qi * bq
-    k_start = ki * bk
-
-    @pl.when(qi == 0)
-    def _init():
-        dk_acc[:, :] = jnp.zeros_like(dk_acc)
-        dv_acc[:, :] = jnp.zeros_like(dv_acc)
-
-    def _compute():
-        q = q_ref[:, :].astype(jnp.float32)
-        k = k_ref[:, :].astype(jnp.float32)
-        v = v_ref[:, :].astype(jnp.float32)
-        do = do_ref[:, :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if causal:
-            rows = q_start + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            cols = k_start + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            s = jnp.where(rows >= cols, s, NEG_INF)
-        p = jnp.exp(s - lse_ref[:, 0][:, None])                    # (bq, bk)
-        dv_acc[:, :] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)                    # p^T @ do
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[:, 0][:, None]) * scale
-        dk_acc[:, :] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)                    # ds^T @ q
-
-    if causal:
-        # Skip Q blocks wholly above the diagonal for this K block.
-        pl.when(q_start + bq - 1 >= k_start)(_compute)
-    else:
-        _compute()
-
-    @pl.when(qi == nq - 1)
-    def _finalize():
-        dk_ref[:, :] = dk_acc[:, :].astype(dk_ref.dtype)
-        dv_ref[:, :] = dv_acc[:, :].astype(dv_ref.dtype)
+                         dk_ref, dv_ref, dk_acc, dv_acc, **static):
+    _attn_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, None,
+                     dk_ref, dv_ref, dk_acc, dv_acc, **static)
 
 
 def _flash_bh_bwd(qbh, kbh, vbh, dobh, lse, delta, *, causal: bool,
                   block_q: int, block_k: int, interpret: bool,
-                  scale: Optional[float] = None, out_dtype=None):
-    """Backward kernels against an externally-supplied (lse, delta).
+                  scale: Optional[float] = None, out_dtype=None,
+                  vmem_budget: int = _VMEM_BUDGET):
+    """Backward against an externally-supplied (lse, delta).
 
     For single-chip flash, lse/delta come from this call's own forward; the
     ring caller instead passes the *globally combined* lse and the delta of
     the final output — then ``p = exp(s - lse)`` is the globally-normalized
     probability block and each per-chunk call yields that chunk's exact
     gradient contribution (the FlashAttention-2 identity carried across
-    ring steps)."""
+    ring steps).
+
+    One ``flash_bwd`` kernel where its resident dq block fits
+    ``vmem_budget`` (read from the shapes, see the section comment), else
+    the two streaming kernels."""
     BH, L, D = qbh.shape
     Lk = kbh.shape[1]
     if scale is None:
         scale = 1.0 / np.sqrt(D)
     dq_dtype = qbh.dtype if out_dtype is None else out_dtype
     dkv_dtype = kbh.dtype if out_dtype is None else out_dtype
+    dkv_shape = jax.ShapeDtypeStruct((BH, Lk, D), dkv_dtype)
+    dkv_scratch = [pltpu.VMEM((block_k, D), jnp.float32),
+                   pltpu.VMEM((block_k, D), jnp.float32)]
 
+    # Grid (BH, k-blocks, q-blocks): flash_bwd and flash_bwd_dkv.
+    q_of = _unmasked_q(causal, block_q, block_k, L // block_q)
+    qd2 = pl.BlockSpec((None, block_q, D),
+                       lambda b, ki, qi: (b, q_of(ki, qi), 0))
+    kd2 = pl.BlockSpec((None, block_k, D), lambda b, ki, qi: (b, ki, 0))
+    qrow2 = pl.BlockSpec((None, block_q, 1),
+                         lambda b, ki, qi: (b, q_of(ki, qi), 0))
+    args = (qbh, kbh, vbh, dobh, lse, delta)
+
+    stream_vmem = _bwd_vmem_bytes(block_q, block_k, D, qbh.dtype, dkv_dtype)
+    # flash_bwd's float32 dq block of the whole (L, D), in both buffers.
+    fused_vmem = stream_vmem + 2 * L * D * 4
+    if fused_vmem <= vmem_budget:
+        dq, dk, dv = pl.pallas_call(
+            functools.partial(_attn_bwd_kernel, causal=causal, scale=scale),
+            out_shape=(jax.ShapeDtypeStruct((BH, L, D), jnp.float32),
+                       dkv_shape, dkv_shape),
+            grid=(BH, Lk // block_k, L // block_q),
+            in_specs=[qd2, kd2, kd2, qd2, qrow2, qrow2],
+            out_specs=(pl.BlockSpec((None, L, D), lambda b, ki, qi: (b, 0, 0)),
+                       kd2, kd2),
+            scratch_shapes=dkv_scratch,
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=fused_vmem),
+            interpret=interpret,
+            name="flash_bwd",
+        )(*args)
+        return dq.astype(dq_dtype), dk, dv
+
+    streaming = pltpu.CompilerParams(vmem_limit_bytes=stream_vmem)
+    k_of = _unmasked_k(causal, block_q, block_k, Lk // block_k)
     qd = pl.BlockSpec((None, block_q, D), lambda b, qi, ki: (b, qi, 0))
-    kd = pl.BlockSpec((None, block_k, D), lambda b, qi, ki: (b, ki, 0))
+    kd = pl.BlockSpec((None, block_k, D),
+                      lambda b, qi, ki: (b, k_of(qi, ki), 0))
     qrow = pl.BlockSpec((None, block_q, 1), lambda b, qi, ki: (b, qi, 0))
     dq = pl.pallas_call(
         functools.partial(_attn_bwd_dq_kernel, causal=causal, scale=scale),
@@ -259,25 +390,21 @@ def _flash_bh_bwd(qbh, kbh, vbh, dobh, lse, delta, *, causal: bool,
         in_specs=[qd, kd, kd, qd, qrow, qrow],
         out_specs=qd,
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
+        compiler_params=streaming,
         interpret=interpret,
         name="flash_bwd_dq",
-    )(qbh, kbh, vbh, dobh, lse, delta)
-
-    qd2 = pl.BlockSpec((None, block_q, D), lambda b, ki, qi: (b, qi, 0))
-    kd2 = pl.BlockSpec((None, block_k, D), lambda b, ki, qi: (b, ki, 0))
-    qrow2 = pl.BlockSpec((None, block_q, 1), lambda b, ki, qi: (b, qi, 0))
+    )(*args)
     dk, dv = pl.pallas_call(
         functools.partial(_attn_bwd_dkv_kernel, causal=causal, scale=scale),
-        out_shape=(jax.ShapeDtypeStruct((BH, Lk, D), dkv_dtype),
-                   jax.ShapeDtypeStruct((BH, Lk, D), dkv_dtype)),
+        out_shape=(dkv_shape, dkv_shape),
         grid=(BH, Lk // block_k, L // block_q),
         in_specs=[qd2, kd2, kd2, qd2, qrow2, qrow2],
         out_specs=(kd2, kd2),
-        scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
-                        pltpu.VMEM((block_k, D), jnp.float32)],
+        scratch_shapes=dkv_scratch,
+        compiler_params=streaming,
         interpret=interpret,
         name="flash_bwd_dkv",
-    )(qbh, kbh, vbh, dobh, lse, delta)
+    )(*args)
     return dq, dk, dv
 
 
@@ -292,6 +419,8 @@ def _flash_core_fwd(causal, block_q, block_k, interpret, scale,
                     qbh, kbh, vbh):
     o, lse = _flash_bh(qbh, kbh, vbh, causal=causal, block_q=block_q,
                        block_k=block_k, interpret=interpret, scale=scale)
+    o = checkpoint_name(o, RESIDUAL_NAMES[0])
+    lse = checkpoint_name(lse, RESIDUAL_NAMES[1])
     return o, (qbh, kbh, vbh, o, lse)
 
 
@@ -338,10 +467,12 @@ def flash_attention(
 ) -> jax.Array:
     """Blocked attention, (B, L, H, D) layout (GQA: repeat K/V first).
 
-    Differentiable: a ``custom_vjp`` pairs the forward with FlashAttention-2
-    style backward Pallas kernels (dq and dk/dv passes streaming over the
-    opposite sequence axis), so training never materializes the (L, L)
-    score matrix either.  Sequence length must be divisible by the (clamped)
+    Differentiable: a ``custom_vjp`` pairs the forward with a
+    FlashAttention-2 style backward Pallas kernel (``flash_bwd``: all three
+    gradients from one pass over the score blocks), so training never
+    materializes the (L, L) score matrix either.  Under ``jax.checkpoint``,
+    save ``RESIDUAL_NAMES`` in the policy or the forward kernel runs again
+    in the backward pass.  Sequence length must be divisible by the (clamped)
     block sizes; callers pad or pick L accordingly.  Off-TPU the interpreter
     path keeps the semantics identical for tests.
     """
