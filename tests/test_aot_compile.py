@@ -141,3 +141,72 @@ def test_llama_flash_step_on_dp_tp(v5e, monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     fn, args = topology._build_llama_dp_tp("v5e-4", attn="flash")
     assert _kernels(jax.jit(fn).lower(*args).compile()) > 0
+
+
+def test_olmoe_adamw_step_at_published_widths(v5e, monkeypatch):
+    """The benchmark's `olmoe-1b-7b-l4096` step on one chip: OLMoE-1B-7B at
+    its published widths, 2 of 16 layers through the layer scan, 4 x 4096
+    tokens, flash, remat "dots", AdamW with bfloat16 moments, weights and
+    state donated.  It fits the chip, and holds the two flash kernels and
+    the grouped matmuls of the sorted dispatch: `gmm` for gate, up and down
+    forward, gate and up again in the layer's recomputation (nothing needs
+    the down product's output again) and the three gradients of the rows
+    (8), `tgmm` for the three gradients of the weights."""
+    import dataclasses
+
+    import optax
+    from jax.sharding import Mesh
+
+    from torchmpi_tpu.models import llama
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(llama.olmoe_1b_7b(), n_layers=2)
+    one = SingleDeviceSharding(v5e[0])
+    place = lambda tree: jax.tree.map(
+        lambda a: _sds(a.shape, a.dtype, one), tree)
+    params = jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0), cfg,
+                                               dtype=jnp.bfloat16))
+    assert sum(a.size for a in jax.tree.leaves(params)) == 1_045_186_560
+    optimizer = optax.adamw(4e-4, b1=0.9, b2=0.95, weight_decay=0.1)
+    state = jax.eval_shape(optimizer.init, jax.tree.map(
+        lambda a: _sds(a.shape, jnp.bfloat16, one), params))
+    mesh = Mesh([v5e[0]], ("dp",))
+    step = llama.make_train_step(cfg, mesh, attn="flash", optimizer=optimizer,
+                                 remat="dots", loss_chunk=512)
+    tokens = _sds((4, 4096), jnp.int32, one)
+    compiled = step.lower(place(params), place(state), tokens, tokens).compile()
+    kernels = [line for line in compiled.as_text().splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    named = lambda what: sum(what in line for line in kernels)
+    assert (named("flash_fwd"), named("flash_bwd")) == (1, 1)
+    assert named("moe.experts/jit(gmm)") == 8
+    assert named("moe.experts/jit(tgmm)") == 3
+    assert len(kernels) == 13
+    m = compiled.memory_analysis()
+    held = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    # weights and both moments donated: all but the tokens and a few norms
+    assert m.argument_size_in_bytes - m.alias_size_in_bytes < 1e6
+    assert m.alias_size_in_bytes > 3 * 2 * 1_045_000_000
+    assert 9e9 < held < 16e9
+
+
+def test_olmoe_step_on_dp_tp_takes_the_compilers_grouped_matmul(monkeypatch):
+    """On more than one device the sorted dispatch leaves the grouped matmul
+    to `lax.ragged_dot`, which the compiler partitions under GSPMD (its own
+    Mosaic kernel, named `ragged-dot-none`); megablox's, a Mosaic kernel of
+    ours, it would refuse to.  One layer at published widths on dp=2 x tp=2."""
+    import dataclasses
+
+    from torchmpi_tpu.models import llama
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(llama.olmoe_1b_7b(), n_layers=1)
+    mesh = topology.topology_mesh("v5e-4", {"dp": 2, "tp": 2})
+    args = topology._llama_arg_structs(cfg, mesh, llama.param_specs, 4, 4096)
+    step = llama.make_train_step(cfg, mesh, attn="flash", remat="dots",
+                                 loss_chunk=512)
+    text = jax.jit(lambda p, t, y: step(p, None, t, y)).lower(
+        *args).compile().as_text()
+    assert text.count('op_name="ragged-dot-none"') == 11
+    assert "jit(gmm)" not in text

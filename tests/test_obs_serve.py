@@ -455,11 +455,19 @@ class TestScrapeConcurrentWithNativeEmission:
         srv = serve.ObsHTTPServer(health=serve.HealthState())  # global reg
         try:
             def worker(r):
-                a = np.ones((4096,), np.float32)
+                # The stop flag rides in the reduced array: both ranks read
+                # the same sum and leave after the same collective.  A rank
+                # that acted on its own reading of `stop_ev` could leave its
+                # peer blocked in one more allreduce, for good.
+                a = np.empty((4096,), np.float32)
                 n = 0
-                while not stop_ev.is_set() and n < 60:
+                while n < 60:
+                    a.fill(1.0)
+                    a[0] = float(stop_ev.is_set())
                     comms[r].allreduce(a)
                     n += 1
+                    if a[0] > 0:
+                        break
                 return n
 
             with ThreadPoolExecutor(2) as ex:
@@ -469,7 +477,7 @@ class TestScrapeConcurrentWithNativeEmission:
                     bodies.append(_get(srv.url + "/metrics"))
                 stop_ev.set()
                 counts = [f.result(timeout=60) for f in futs]
-            assert all(c > 0 for c in counts)
+            assert counts[0] == counts[1] > 0
             assert all("tmpi_trace_dropped_total" in b for b in bodies)
         finally:
             stop_ev.set()

@@ -4,6 +4,7 @@ run under ``jax.named_scope`` and the flash kernels have a ``name``, so the
 belongs to, and ``transpose(...)`` round it says backward.  The names are
 metadata: these tests read them off the lowered step programs."""
 
+import dataclasses
 import re
 
 import pytest
@@ -33,8 +34,18 @@ def _carried(names, scope, inside=""):
 MOE = ("moe.router", "moe.dispatch", "moe.experts", "moe.combine")
 
 
+def _olmoe_tiny():
+    """Dropless sorted dispatch and QK-norm, at `moe_tiny`'s sizes."""
+    return dataclasses.replace(llama.moe_tiny(), n_kv_heads=4,
+                               capacity_factor=None, moe_renormalize=False,
+                               moe_z_coef=1e-3, qk_norm=True)
+
+
 @pytest.mark.parametrize("make_cfg,ffn,absent", [
-    (llama.moe_tiny, MOE, ("ffn",)), (llama.tiny, ("ffn",), MOE)])
+    (llama.moe_tiny, MOE, ("ffn", "attn.qk_norm")),
+    (llama.tiny, ("ffn",), MOE + ("attn.qk_norm",)),
+    (_olmoe_tiny, MOE + ("attn.qk_norm",), ("ffn",))],
+    ids=["mixtral", "dense", "olmoe"])
 def test_llama_train_step_carries_scope_and_kernel_names(make_cfg, ffn,
                                                          absent):
     cfg = make_cfg()
@@ -63,6 +74,18 @@ def test_llama_train_step_carries_scope_and_kernel_names(make_cfg, ffn,
     for scope in ("embed", "head_loss"):
         assert _carried(names, scope, inside="transpose(jvp(" + scope), scope
     assert not _carried(names, "optimizer", inside="transpose(")
+    if "attn.qk_norm" in ffn:
+        # QK-norm sits inside the attention's scope; the sorted dispatch's
+        # grouped matmuls (megablox's `gmm`, and `tgmm` for the weights'
+        # gradients) are under moe.experts: forward, backward, and in the
+        # layer's recomputation, since the kernel is no dot the policy saves.
+        assert _carried(names, "attn.qk_norm", inside="attn/attn.qk_norm")
+        for kernel, inside in (("gmm", "jit(step)/"), ("gmm", "checkpoint/"),
+                               ("tgmm", "checkpoint/"),
+                               ("gmm", "rematted_computation/")):
+            assert _carried(names, kernel,
+                            inside=inside + "moe.experts/jit("), (kernel, inside)
+        assert not _carried(names, "tgmm", inside="rematted_computation")
 
 
 RESNET = ("stem", "conv", "bn", "residual", "pool", "fc_loss")

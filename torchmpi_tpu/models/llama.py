@@ -35,6 +35,7 @@ norms, softmax, and the loss run in f32.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import numpy as np
@@ -68,9 +69,16 @@ class Config:
     # (Mixtral-style), expert weights sharded over the `ep` mesh axis.
     n_experts: int = 0
     expert_top_k: int = 2
-    capacity_factor: float = 1.25  # per-expert slots = cf * k * G / E
+    # Per-expert slots = cf * k * G / E; None = dropless: no capacity and no
+    # routing group, every routed unit reaches its expert (_moe_ffn_sorted).
+    capacity_factor: Optional[float] = 1.25
     moe_aux_coef: float = 0.01     # load-balance aux-loss weight
     moe_group_size: int = 512      # tokens per routing group (GShard groups)
+    moe_renormalize: bool = True   # top-k>1 weights renormalised over the k
+    moe_z_coef: float = 0.0        # router z-loss weight (ST-MoE eq. 5)
+    # RMSNorm over the whole q and k projections before the heads are split
+    # (OLMoE): two more leaves a layer, ``q_norm`` and ``k_norm``.
+    qk_norm: bool = False
 
     @property
     def head_dim(self) -> int:
@@ -81,7 +89,7 @@ class Config:
         assert self.n_heads % self.n_kv_heads == 0
         if self.n_experts:
             assert 1 <= self.expert_top_k <= self.n_experts
-            assert self.capacity_factor > 0
+            assert self.capacity_factor is None or self.capacity_factor > 0
 
 
 def llama3_8b() -> Config:
@@ -95,6 +103,16 @@ def mixtral_8x7b() -> Config:
     return Config(vocab=32000, d_model=4096, n_layers=32, n_heads=32,
                   n_kv_heads=8, d_ff=14336, max_seq=8192, rope_theta=1e6,
                   n_experts=8, expert_top_k=2)
+
+
+def olmoe_1b_7b() -> Config:
+    """OLMoE-1B-7B geometry (arXiv:2409.02060): 64 SwiGLU experts of width
+    1024 a layer, 8 a token, dropless, combine weights not renormalised,
+    QK-norm, router z-loss."""
+    return Config(vocab=50304, d_model=2048, n_layers=16, n_heads=16,
+                  n_kv_heads=16, d_ff=1024, max_seq=4096, rope_theta=1e4,
+                  n_experts=64, expert_top_k=8, capacity_factor=None,
+                  moe_renormalize=False, moe_z_coef=1e-3, qk_norm=True)
 
 
 def tiny(vocab: int = 256, seq: int = 64) -> Config:
@@ -146,6 +164,11 @@ def init(rng: jax.Array, cfg: Config, dtype=jnp.float32) -> Params:
             "w_down": stack(keys[7], cfg.d_ff, cfg.d_model),
         }
 
+    qk = {}
+    if cfg.qk_norm:
+        qk = {"q_norm": jnp.ones((cfg.n_layers, H * hd), jnp.float32),
+              "k_norm": jnp.ones((cfg.n_layers, KV * hd), jnp.float32)}
+
     return {
         "embed": (jax.random.normal(keys[0], (cfg.vocab, cfg.d_model), jnp.float32)
                   * 0.02).astype(dtype),
@@ -156,6 +179,7 @@ def init(rng: jax.Array, cfg: Config, dtype=jnp.float32) -> Params:
             "wv": stack(keys[3], cfg.d_model, KV * hd),
             "wo": stack(keys[4], H * hd, cfg.d_model),
             "mlp_norm": jnp.ones((cfg.n_layers, cfg.d_model), jnp.float32),
+            **qk,
             **ffn,
         },
         "norm": jnp.ones((cfg.d_model,), jnp.float32),
@@ -179,12 +203,17 @@ def param_specs(cfg: Config) -> Params:
         }
     else:
         ffn = {"w_gate": col, "w_up": col, "w_down": row}
+    # The norm runs over the whole projection, so its weight follows the
+    # projection's columns and GSPMD sums the squares over tp.
+    qk = ({"q_norm": P(None, AXIS_TP), "k_norm": P(None, AXIS_TP)}
+          if cfg.qk_norm else {})
     return {
         "embed": P(None, None),
         "layers": {
             "attn_norm": P(None, None),
             "wq": col, "wk": col, "wv": col, "wo": row,
             "mlp_norm": P(None, None),
+            **qk,
             **ffn,
         },
         "norm": P(None),
@@ -403,7 +432,8 @@ def _moe_capacity(cfg: Config, group: int) -> int:
     return max(1, min(cap, group))
 
 
-def _moe_ffn(cfg: Config, lp: Params, x: jax.Array, dropless: bool = False):
+def _moe_ffn(cfg: Config, lp: Params, x: jax.Array, dropless: bool = False,
+             mesh: Optional[Mesh] = None):
     """Mixture-of-experts SwiGLU FFN on normed input x (B, L, D) ->
     ``(out (B, L, D), aux-loss scalar f32)``.
 
@@ -422,12 +452,20 @@ def _moe_ffn(cfg: Config, lp: Params, x: jax.Array, dropless: bool = False):
     unit past capacity is dropped (contributes 0 to the residual stream).
     ``dropless=True`` sets C = G (an expert can receive at most one unit
     per token since top-k picks distinct experts) — the decode path's
-    guarantee that routing never depends on bucket pressure.
+    guarantee that routing never depends on bucket pressure, on its
+    handful of tokens.  A configuration with ``capacity_factor=None`` is
+    dropless in training and prefill too, and takes
+    :func:`_moe_ffn_sorted` there: at C = G this form computes E/k times
+    the required expert FLOPs.  Weights are renormalised over the chosen k
+    unless ``cfg.moe_renormalize`` is off; with ``cfg.moe_z_coef`` the
+    aux result is the pair (load balance, router z-loss).
 
     The aux loss is the Switch/GShard load-balance term
     ``E * sum_e mean_prob_e * primary_fraction_e`` (= 1 at perfect balance),
     averaged over groups.
     """
+    if cfg.capacity_factor is None and not dropless:
+        return _moe_ffn_sorted(cfg, lp, x, mesh)
     B, L, D = x.shape
     E, k = cfg.n_experts, cfg.expert_top_k
     T = B * L
@@ -442,11 +480,14 @@ def _moe_ffn(cfg: Config, lp: Params, x: jax.Array, dropless: bool = False):
             # ONE routing definition for both MoE forms: the shared top-k /
             # choice-major / capacity-queue step
             # (parallel/moe.py:route_topk).
-            sel_f, w_f, onehot, slot = _route_topk(probs, k, k > 1)
+            sel_f, w_f, onehot, slot = _route_topk(
+                probs, k, cfg.moe_renormalize and k > 1)
             me = jnp.mean(probs, axis=0)
             ce = jnp.mean(jax.nn.one_hot(sel_f[:G], E, dtype=jnp.float32),
                           axis=0)
             aux = E * jnp.sum(me * ce)
+            if cfg.moe_z_coef:
+                aux = jnp.stack([aux, _router_z(logits)])
         with jax.named_scope("moe.dispatch"):
             # one_hot(slot, C) drops units whose queue position >= C.
             dispatch = (jax.nn.one_hot(slot, C, dtype=jnp.float32)
@@ -467,47 +508,201 @@ def _moe_ffn(cfg: Config, lp: Params, x: jax.Array, dropless: bool = False):
             return jnp.sum(yk.reshape(k, G, D), axis=0), aux
 
     y, aux = jax.vmap(route_group)(xg)
-    return y.reshape(B, L, D), jnp.mean(aux)
+    return y.reshape(B, L, D), jnp.mean(aux, axis=0)
+
+
+def _router_z(logits: jax.Array) -> jax.Array:
+    """Router z-loss, ``mean(logsumexp(logits)^2)`` (Zoph et al.,
+    arXiv:2202.08906 eq. 5): keeps the router's logits small."""
+    return jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _dispatch_rows(x, order, inverse, k):
+    """Rows of ``x`` (T, D) to their k routed units in sorted order, (k*T,
+    D): unit u = t * k + j is token t's j-th choice, ``order`` lists the
+    units by expert and ``inverse`` is its inverse permutation.  With
+    :func:`_combine_rows` a pair, each the other's transpose, so a backward
+    pass gathers where autodiff would scatter-add."""
+    return x[order // k]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _combine_rows(ys, order, inverse, k):
+    """Sorted units ``ys`` (k*T, D) back to tokens: the sum of each token's
+    k units, (T, D), accumulated in float32."""
+    yk = ys[inverse].reshape(ys.shape[0] // k, k, ys.shape[1])
+    return jnp.sum(yk, axis=1, dtype=jnp.float32).astype(ys.dtype)
+
+
+def _rows_bwd(transpose):
+    def bwd(k, perms, g):
+        zero = np.zeros(perms[0].shape, jax.dtypes.float0)
+        return transpose(g, *perms, k), zero, zero
+    return bwd
+
+
+_dispatch_rows.defvjp(lambda x, o, i, k: (_dispatch_rows(x, o, i, k), (o, i)),
+                      _rows_bwd(_combine_rows))
+_combine_rows.defvjp(lambda ys, o, i, k: (_combine_rows(ys, o, i, k), (o, i)),
+                     _rows_bwd(_dispatch_rows))
+
+
+def _grouped_matmul(xs: jax.Array, w: jax.Array, counts: jax.Array,
+                    kernel: bool) -> jax.Array:
+    """``xs`` (M, K), whose rows lie in contiguous segments of ``counts[e]``
+    rows for expert e, times that expert's ``w[e]`` (E, K, N) -> (M, N).
+    On one device the Mosaic grouped matmul that JAX ships
+    (``pallas.ops.tpu.megablox``: row tiles of 512 each owned by one expert,
+    a tile that two experts share visited once for each; its VJP is two more
+    such kernels), in interpret mode off the TPU; 81-87% of the v5e's peak on
+    131,072 rows of 2048 x 1024 where XLA's own lowering of
+    ``lax.ragged_dot`` reaches 55-68% (PERF.md section 6, PR 26).  Under GSPMD
+    on several devices ``lax.ragged_dot``, which the compiler partitions
+    itself and a Mosaic kernel of ours it would refuse to."""
+    if not kernel:
+        return lax.ragged_dot(xs, w, counts)
+    from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+
+    (M, K), N = xs.shape, w.shape[-1]
+    tile = min(512, -(-M // 16) * 16)
+    pad = -M % tile         # whole row tiles: the last expert takes the zeros
+    if pad:
+        xs = jnp.pad(xs, ((0, pad), (0, 0)))
+        counts = counts.at[-1].add(pad)
+    return megablox.gmm(xs, w, counts, xs.dtype,
+                        (tile, min(K, 1024), min(N, 1024)),
+                        interpret=jax.default_backend() != "tpu")[:M]
+
+
+def _route_tokens(cfg: Config, lp: Params, xt: jax.Array):
+    """The dropless router on tokens ``xt`` (T, D): float32 softmax over all
+    experts, top-k with the weights renormalised over the chosen k or not as
+    the configuration says.  Returns ``(weight (T, k) f32, expert (T, k)
+    int32, counts (E,) int32, aux)``: ``counts[e]`` units go to expert e and
+    sum to k*T; ``aux`` is the Switch load-balance term over the whole batch
+    (first choices), stacked with the z-loss where that has a weight."""
+    E, k = cfg.n_experts, cfg.expert_top_k
+    logits = xt.astype(jnp.float32) @ lp["router"].astype(jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)                         # (T, E)
+    weight, expert = lax.top_k(probs, k)
+    if cfg.moe_renormalize and k > 1:
+        weight = weight / jnp.maximum(
+            jnp.sum(weight, axis=-1, keepdims=True), 1e-9)
+    chosen = jax.nn.one_hot(expert, E, dtype=jnp.int32)             # (T, k, E)
+    counts = jnp.sum(chosen, axis=(0, 1))
+    aux = E * jnp.sum(jnp.mean(probs, axis=0)
+                      * jnp.mean(chosen[:, 0].astype(jnp.float32), axis=0))
+    if cfg.moe_z_coef:
+        aux = jnp.stack([aux, _router_z(logits)])
+    return weight, expert, counts, aux
+
+
+def _moe_ffn_sorted(cfg: Config, lp: Params, x: jax.Array,
+                    mesh: Optional[Mesh] = None):
+    """The dropless mixture-of-experts FFN, x (B, L, D) -> ``(out, aux)``:
+    no capacity, no routing group, every one of the k*T routed units reaches
+    its expert.  Units are sorted by expert (stable, so in token order within
+    an expert) and gathered into one (k*T, D) array whose contiguous segments
+    each meet their own expert's weights in a grouped matmul
+    (:func:`_grouped_matmul`) for gate, up and down; the hidden rows carry
+    the router's weight into the down product, so the results only have to
+    be summed back to their tokens by the inverse permutation, and no
+    backward pass needs the down product's output.  The cost is k/E of the
+    one-hot form's at C = G and does not grow with E.  On one device
+    (``mesh`` None or of size 1) or under GSPMD on dp and tp; :func:`apply`
+    refuses an ``ep`` axis."""
+    B, L, D = x.shape
+    k = cfg.expert_top_k
+    T = B * L
+    xt = x.reshape(T, D)
+    kernel = mesh is None or mesh.size == 1
+    with jax.named_scope("moe.router"):
+        weight, expert, counts, aux = _route_tokens(cfg, lp, xt)
+    with jax.named_scope("moe.dispatch"):
+        order = jnp.argsort(expert.reshape(T * k), stable=True)
+        inverse = jnp.argsort(order)
+        xs = _dispatch_rows(xt, order, inverse, k)
+        ws = _dispatch_rows(weight.reshape(T * k, 1), order, inverse, 1)
+    with jax.named_scope("moe.experts"):
+        hs = (jax.nn.silu(_grouped_matmul(xs, lp["w_gate"], counts, kernel))
+              * _grouped_matmul(xs, lp["w_up"], counts, kernel))
+        ys = _grouped_matmul((hs * ws).astype(x.dtype), lp["w_down"], counts,
+                             kernel)                                # (kT, D)
+    with jax.named_scope("moe.combine"):
+        y = _combine_rows(ys, order, inverse, k)
+    return y.reshape(B, L, D), aux
+
+
+def _qk_norm(cfg: Config, lp: Params, q: jax.Array, k: jax.Array):
+    """QK-norm: RMSNorm over the whole q and k projections (..., H*hd) and
+    (..., KV*hd), before the heads are split and rotated (OLMoE,
+    arXiv:2409.02060 section 3).  The identity for other configurations."""
+    if not cfg.qk_norm:
+        return q, k
+    with jax.named_scope("attn.qk_norm"):
+        return (rms_norm(q, lp["q_norm"], cfg.norm_eps),
+                rms_norm(k, lp["k_norm"], cfg.norm_eps))
+
+
+def _attention_block(cfg: Config, lp: Params, h: jax.Array,
+                     positions: jax.Array, attn_impl: Callable,
+                     constrain: Callable = lambda x: x,
+                     with_kv: bool = False):
+    """The attention half of a decoder block: ``h`` plus the attention of
+    its pre-norm; with ``with_kv`` also the (pre-repeat, native-KV-head) K/V
+    projections."""
+    B, L, _ = h.shape
+    hd, H, KV = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
+    # Names in the device program (docs/observability.md): ``attn`` (the
+    # projections, ``attn.qk_norm``, rope, the attention itself, the output
+    # projection), ``moe.router``/``moe.dispatch``/``moe.experts``/
+    # ``moe.combine`` or ``ffn``, ``embed``, ``head_loss``, ``optimizer``.
+    # Metadata only.
+    with jax.named_scope("attn"):
+        x = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
+        q, k = _qk_norm(cfg, lp, x @ lp["wq"], x @ lp["wk"])
+        q = rope(q.reshape(B, L, H, hd), positions, cfg.rope_theta)
+        k = rope(k.reshape(B, L, KV, hd), positions, cfg.rope_theta)
+        v = (x @ lp["wv"]).reshape(B, L, KV, hd)
+        o = attn_impl(q, k, v)
+        h = h + constrain(o.reshape(B, L, H * hd) @ lp["wo"])
+    return (h, (k, v)) if with_kv else h
+
+
+def _ffn_block(cfg: Config, lp: Params, h: jax.Array,
+               constrain: Callable = lambda x: x,
+               mesh: Optional[Mesh] = None):
+    """The feed-forward half: ``h`` plus the SwiGLU or mixture-of-experts
+    FFN of its pre-norm, and the MoE aux term (0 for dense configs)."""
+    x = rms_norm(h, lp["mlp_norm"], cfg.norm_eps)
+    if cfg.n_experts:
+        g, aux = _moe_ffn(cfg, lp, x, mesh=mesh)
+    else:
+        with jax.named_scope("ffn"):
+            g = ((jax.nn.silu(x @ lp["w_gate"]) * (x @ lp["w_up"]))
+                 @ lp["w_down"])
+        aux = jnp.zeros((), jnp.float32)
+    return h + constrain(g), aux
 
 
 def _decoder_layer(cfg: Config, lp: Params, h: jax.Array,
                    positions: jax.Array, attn_impl: Callable,
                    constrain: Callable = lambda x: x,
-                   with_kv: bool = False):
+                   with_kv: bool = False, mesh: Optional[Mesh] = None):
     """One pre-norm decoder block (attention + SwiGLU-or-MoE FFN with
     residuals) — the single definition the scanned forward (:func:`apply`),
     the pipeline stages (:func:`make_pp_train_step`), and decode prefill
     run.  Returns ``(h, aux)`` where ``aux`` is the MoE load-balance term
     (0 for dense configs); with ``with_kv`` also returns the (pre-repeat,
     native-KV-head) K/V projections — the cache seed for autoregressive
-    decoding."""
-    B, L, _ = h.shape
-    hd, H, KV = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
-    # Names in the device program (docs/observability.md): ``attn`` (the
-    # projections, rope, the attention itself, the output projection),
-    # ``moe.router``/``moe.dispatch``/``moe.experts``/``moe.combine`` or
-    # ``ffn``, ``embed``, ``head_loss``, ``optimizer``.  Metadata only.
-    with jax.named_scope("attn"):
-        x = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
-        q = rope((x @ lp["wq"]).reshape(B, L, H, hd), positions,
-                 cfg.rope_theta)
-        k = rope((x @ lp["wk"]).reshape(B, L, KV, hd), positions,
-                 cfg.rope_theta)
-        v = (x @ lp["wv"]).reshape(B, L, KV, hd)
-        o = attn_impl(q, k, v)
-        h = h + constrain(o.reshape(B, L, H * hd) @ lp["wo"])
-    x = rms_norm(h, lp["mlp_norm"], cfg.norm_eps)
-    if cfg.n_experts:
-        g, aux = _moe_ffn(cfg, lp, x)
-    else:
-        with jax.named_scope("ffn"):
-            g = ((jax.nn.silu(x @ lp["w_gate"]) * (x @ lp["w_up"]))
-                 @ lp["w_down"])
-        aux = jnp.zeros((), jnp.float32)
-    h = h + constrain(g)
+    decoding.  ``mesh`` is the mesh the parameters live on, if any: the
+    dropless expert layer runs its kernel on one device only."""
+    h = _attention_block(cfg, lp, h, positions, attn_impl, constrain, with_kv)
     if with_kv:
-        return h, aux, (k, v)
-    return h, aux
+        h, kv = h
+        return (*_ffn_block(cfg, lp, h, constrain, mesh), kv)
+    return _ffn_block(cfg, lp, h, constrain, mesh)
 
 
 @jax.checkpoint
@@ -641,6 +836,38 @@ def _nll_from_hidden_tp_manual(head_local: jax.Array, h: jax.Array,
     return total / N
 
 
+def _refuse_dropless_ep(cfg: Config, mesh: Optional[Mesh]) -> None:
+    if (cfg.n_experts and cfg.capacity_factor is None and mesh is not None
+            and dict(mesh.shape).get(AXIS_EP, 1) > 1):
+        raise NotImplementedError(
+            "a dropless configuration (capacity_factor=None) sorts its "
+            "routed units into one array for a grouped matmul, which has no "
+            "form yet for experts sharded over an ep axis "
+            f"(ep={dict(mesh.shape)[AXIS_EP]}): use a mesh without ep, or a "
+            "capacity_factor for the one-hot dispatch that GSPMD shards")
+
+
+def expert_unit_counts(cfg: Config, params: Params, tokens: jax.Array,
+                       mesh: Optional[Mesh] = None,
+                       attn: str = "full") -> jax.Array:
+    """(n_layers, n_experts) int32: how many of a batch's k*T routed units
+    each expert of each layer is sent, by the router code the training step
+    runs (:func:`_route_tokens`), on the activations the forward pass gives
+    it.  A counter for outside the step: a row sums to k*T, and its largest
+    entry over its mean says how lopsided that layer's routing is."""
+    _refuse_dropless_ep(cfg, mesh)
+    positions = jnp.arange(tokens.shape[1])
+    attn_impl = _make_attn_impl(cfg, attn, mesh, 1.0 / np.sqrt(cfg.head_dim))
+
+    def layer(h, lp):
+        h = _attention_block(cfg, lp, h, positions, attn_impl)
+        x = rms_norm(h, lp["mlp_norm"], cfg.norm_eps)
+        counts = _route_tokens(cfg, lp, x.reshape(-1, x.shape[-1]))[2]
+        return _ffn_block(cfg, lp, h, mesh=mesh)[0], counts
+
+    return lax.scan(layer, params["embed"][tokens], params["layers"])[1]
+
+
 def apply(cfg: Config, params: Params, tokens: jax.Array,
           mesh: Optional[Mesh] = None, attn: str = "full",
           remat: str = "none", return_hidden: bool = False,
@@ -652,7 +879,8 @@ def apply(cfg: Config, params: Params, tokens: jax.Array,
     ``(B, L, V)`` f32 logits never materialize).  With ``return_aux`` the
     result is ``(out, aux)`` where ``aux`` is the layer-mean MoE
     load-balance loss (0 for dense configs) — the training path for
-    ``n_experts > 0`` configs adds ``cfg.moe_aux_coef * aux``.
+    ``n_experts > 0`` configs adds ``cfg.moe_aux_coef * aux`` — and with
+    ``cfg.moe_z_coef`` the pair (load balance, router z-loss).
 
     ``mesh`` enables activation sharding constraints (and is required for
     ``attn='ring'``); without it the model runs unconstrained (single-device
@@ -703,26 +931,29 @@ def apply(cfg: Config, params: Params, tokens: jax.Array,
         kept = _mesh_spec(P(AXIS_DP, AXIS_SP, None), mesh)
         return lax.with_sharding_constraint(x, NamedSharding(mesh, kept))
 
+    _refuse_dropless_ep(cfg, mesh)
     with jax.named_scope("embed"):
         h = constrain(params["embed"][tokens])      # (B, L, D)
     attn_impl = _make_attn_impl(cfg, attn, mesh, scale)
 
     def layer(carry, lp):
         h, aux = carry
-        h, a = _decoder_layer(cfg, lp, h, positions, attn_impl, constrain)
+        h, a = _decoder_layer(cfg, lp, h, positions, attn_impl, constrain,
+                              mesh=mesh)
         return (h, aux + a), None
 
     layer = _wrap_remat(layer, remat)
+    aux0 = jnp.zeros((2,) if cfg.n_experts and cfg.moe_z_coef else (),
+                     jnp.float32)
 
     if layer_loop == "unroll":
-        carry = (h, jnp.zeros((), jnp.float32))
+        carry = (h, aux0)
         for i in range(cfg.n_layers):
             carry, _ = layer(carry, jax.tree.map(lambda a: a[i],
                                                  params["layers"]))
         h, aux = carry
     elif layer_loop == "scan":
-        (h, aux), _ = lax.scan(layer, (h, jnp.zeros((), jnp.float32)),
-                               params["layers"])
+        (h, aux), _ = lax.scan(layer, (h, aux0), params["layers"])
     else:
         raise ValueError("layer_loop must be 'scan' or 'unroll'")
     aux = aux / cfg.n_layers
@@ -768,7 +999,9 @@ def make_loss_fn(cfg: Config, mesh: Optional[Mesh] = None, attn: str = "full",
                        layer_loop=layer_loop,
                        positions=positions)                  # (B, L, D)
         nll = _nll_from_hidden(params["head"], h, targets, loss_chunk)
-        if cfg.n_experts:
+        if cfg.n_experts and cfg.moe_z_coef:
+            nll = nll + cfg.moe_aux_coef * aux[0] + cfg.moe_z_coef * aux[1]
+        elif cfg.n_experts:
             nll = nll + cfg.moe_aux_coef * aux
         return nll
 
@@ -802,10 +1035,10 @@ def _decode_step(cfg: Config, params: Params, cache: Params,
     def layer(h, xs):
         lp, ck, cv = xs                              # ck/cv: (B, max_len, KV, hd)
         x = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
-        q = rope((x @ lp["wq"]).reshape(B, 1, H, hd), positions,
+        q, k_new = _qk_norm(cfg, lp, x @ lp["wq"], x @ lp["wk"])
+        q = rope(q.reshape(B, 1, H, hd), positions,
                  cfg.rope_theta)[:, 0]               # (B, H, hd)
-        k_new = rope((x @ lp["wk"]).reshape(B, 1, KV, hd), positions,
-                     cfg.rope_theta)
+        k_new = rope(k_new.reshape(B, 1, KV, hd), positions, cfg.rope_theta)
         v_new = (x @ lp["wv"]).reshape(B, 1, KV, hd)
         ck = lax.dynamic_update_slice(ck, k_new.astype(ck.dtype),
                                       (0, pos, 0, 0))
@@ -880,7 +1113,7 @@ def _prefill(cfg: Config, params: Params, cache: Params,
     def layer(h, xs):
         lp, ck, cv = xs
         h, _, (k, v) = _decoder_layer(cfg, lp, h, positions, attn_impl,
-                                      with_kv=True)
+                                      with_kv=True, mesh=mesh)
         ck = lax.dynamic_update_slice(ck, k.astype(ck.dtype), (0, 0, 0, 0))
         cv = lax.dynamic_update_slice(cv, v.astype(cv.dtype), (0, 0, 0, 0))
         return h, (ck, cv)
@@ -1059,6 +1292,12 @@ def _decoder_layer_tp_manual(cfg: Config, lp, h, positions,
     from ..ops import flash_attention as _flash
     from ..parallel import tp as _tp
 
+    if cfg.qk_norm:
+        raise NotImplementedError(
+            "QK-norm runs over the whole q and k projections, which the "
+            "tp-manual stage holds as column shards: it would need a psum of "
+            "the squares over tp that this stage does not write; use the "
+            "GSPMD pipeline (tp_manual=False) or make_train_step")
     B, L, _ = h.shape
     hd = cfg.head_dim
     x = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
