@@ -140,7 +140,22 @@ def test_llama_flash_step_on_dp_tp(v5e, monkeypatch):
     it; no option of the program does."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     fn, args = topology._build_llama_dp_tp("v5e-4", attn="flash")
-    assert _kernels(jax.jit(fn).lower(*args).compile()) > 0
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert _kernels(compiled) > 0
+    # The chunked head under GSPMD (head columns over tp, rows over dp): the
+    # program with a checkpointed chunk held 11 all-reduces of 255,364 bytes,
+    # four in a chunk (max and target logit, then max and sum again in the
+    # replay) and dh's after the scan.  Now three in a chunk (max, sum, and
+    # the target logit with the chunk's dh: the same bytes a step), and dW is
+    # still summed over dp once, after the scan, with the other gradients.
+    text = compiled.as_text()
+    stats = topology.hlo_collective_stats(text)
+    assert set(stats["counts"]) == {"all-reduce:f32"}
+    assert stats["total"] <= 11
+    assert sum(stats["operand_bytes"].values()) <= 255_364
+    in_chunk = [line for line in text.splitlines()
+                if " all-reduce(" in line and "head_loss" in line]
+    assert len(in_chunk) == 3 and all("while/body" in line for line in in_chunk)
 
 
 def test_olmoe_adamw_step_at_published_widths(v5e, monkeypatch):
@@ -182,6 +197,13 @@ def test_olmoe_adamw_step_at_published_widths(v5e, monkeypatch):
     assert named("moe.experts/jit(gmm)") == 8
     assert named("moe.experts/jit(tgmm)") == 3
     assert len(kernels) == 13
+    # The head: three products over the vocabulary in one scan body (logits,
+    # dh, dW), and no replay of `h_c @ head` in a backward scan.
+    head = [line for line in compiled.as_text().splitlines()
+            if "head_loss" in line and " convolution(" in line]
+    assert len(head) == 3 and not any("rematted" in line for line in head)
+    assert sum("bf16[4,512,50304]" in line.split(" convolution(")[0]
+               for line in head) == 1
     m = compiled.memory_analysis()
     held = (m.argument_size_in_bytes + m.output_size_in_bytes
             - m.alias_size_in_bytes + m.temp_size_in_bytes)

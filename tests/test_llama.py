@@ -2,6 +2,8 @@
 ring-attention path equivalence, and a dp x tp train step on the virtual mesh
 (BASELINE config 5 shrunk to 8 CPU devices)."""
 
+import re
+
 import numpy as np
 import pytest
 
@@ -756,6 +758,109 @@ class TestSharded:
             params, opt_state, loss = step(params, opt_state, tokens, targets)
             losses.append(float(loss))
         assert losses[-1] < losses[0] - 0.3, losses
+
+
+def _head_loss_dots(fn, *args):
+    """(computation, result shape) of every dot the compiled ``fn`` runs
+    under the ``head_loss`` scope, from the executable's ``op_name``s."""
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    found, where = [], None
+    for line in text.splitlines():
+        if line and not line[0].isspace():
+            where = line.split()[0]
+        if "head_loss" in line and re.search(r" (dot|convolution)\(", line):
+            shape = re.search(r" = \w+\[([\d,]*)\]", line).group(1)
+            found.append((where, tuple(int(n) for n in shape.split(","))))
+    return found, text
+
+
+class TestChunkedHead:
+    """The chunked output head (``make_loss_fn(loss_chunk=C)``): a
+    ``custom_vjp`` whose forward pass takes the head's gradients chunk by
+    chunk, so the logits are formed once a step."""
+
+    B, L, C = 2, 16, 4
+
+    def _args(self, cfg):
+        params = jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0), cfg))
+        tokens = jax.ShapeDtypeStruct((self.B, self.L), jnp.int32)
+        return params, (tokens, tokens)
+
+    @pytest.mark.parametrize("remat", ["none", "dots", "full"])
+    def test_three_products_a_chunk_under_grad(self, remat):
+        """(a) ``value_and_grad``: the scan's body holds the three products
+        the mathematics needs (logits, ``dh``, ``dW``) and no second
+        ``h_c @ head``; no other computation holds a product of the head."""
+        cfg = llama.tiny()
+        loss_fn = llama.make_loss_fn(cfg, loss_chunk=self.C, remat=remat)
+        dots, text = _head_loss_dots(jax.value_and_grad(loss_fn),
+                                     *self._args(cfg))
+        rows, D, V = self.B * self.C, cfg.d_model, cfg.vocab
+        assert sorted(shape for _, shape in dots) == sorted(
+            [(rows, V), (rows, D), (D, V)]), dots
+        assert len({where for where, _ in dots}) == 1, dots
+        assert "rematted_computation/head_loss" not in text
+
+    def test_train_step_holds_the_same_three(self, devices):
+        """(a) the same in ``make_train_step``'s program, MoE and flash."""
+        cfg = llama.moe_tiny()
+        mesh = parallel.make_mesh({"dp": 1}, devices=devices[:1])
+        step = llama.make_train_step(cfg, mesh, attn="flash", remat="dots",
+                                     loss_chunk=self.C)
+        params, (tokens, _) = self._args(cfg)
+        dots, _ = _head_loss_dots(step, params, None, tokens, tokens)
+        assert len(dots) == 3 and len({where for where, _ in dots}) == 1, dots
+
+    def test_forward_only_is_one_product_a_chunk(self):
+        """(c) no gradient asked: one product a chunk, no (D, V) accumulator
+        and no (B, L, D) one in the scan."""
+        cfg = llama.tiny()
+        dots, text = _head_loss_dots(
+            llama.make_loss_fn(cfg, loss_chunk=self.C), *self._args(cfg))
+        assert [shape for _, shape in dots] == [(self.B * self.C, cfg.vocab)]
+        # what the scope's instructions make; the head itself rides through
+        # the loop as an element of its tuple
+        made = {m.group(1) for line in text.splitlines() if "head_loss" in line
+                for m in [re.search(r" = \w+\[([\d,]*)\]\S* ([\w\-]+)\(", line)]
+                if m and m.group(2) not in ("get-tuple-element", "parameter")}
+        assert f"{cfg.d_model},{cfg.vocab}" not in made
+        assert f"{self.B},{self.L},{cfg.d_model}" not in made
+
+    @pytest.mark.parametrize("scale", [1.0, 3.0])
+    @pytest.mark.parametrize("chunks", [1, 2, 8])
+    def test_matches_dense(self, chunks, scale):
+        """(b) loss and every gradient leaf against the dense head, float32,
+        with a cotangent of 1 and of 3 (``bwd`` scales what ``fwd`` took)."""
+        cfg = llama.tiny()
+        params = llama.init(jax.random.PRNGKey(0), cfg)
+        batch = _data(cfg, B=self.B, L=self.L)
+        dense = jax.value_and_grad(llama.make_loss_fn(cfg))(params, batch)
+        loss_fn = llama.make_loss_fn(cfg, loss_chunk=self.L // chunks)
+        loss, grads = jax.value_and_grad(
+            lambda p, b: scale * loss_fn(p, b))(params, batch)
+        np.testing.assert_allclose(float(loss), scale * float(dense[0]),
+                                   rtol=1e-6)
+        flat = jax.tree_util.tree_flatten_with_path(grads)[0]
+        for (path, a), b in zip(flat, jax.tree.leaves(dense[1])):
+            b = scale * np.asarray(b)
+            np.testing.assert_allclose(
+                np.asarray(a), b, rtol=1e-5, atol=1e-6 * np.abs(b).max(),
+                err_msg=jax.tree_util.keystr(path))
+
+    def test_bf16_gradients_keep_their_dtypes(self):
+        """bfloat16 weights: gradients come back in the leaves' dtypes and
+        near the float32 ones (the accumulator over chunks is the head's)."""
+        cfg = llama.tiny()
+        params = llama.init(jax.random.PRNGKey(0), cfg)
+        batch = _data(cfg, B=self.B, L=self.L)
+        exact = jax.grad(llama.make_loss_fn(cfg))(params, batch)
+        half = jax.tree.map(lambda a: a.astype(jnp.bfloat16), params)
+        grads = jax.grad(llama.make_loss_fn(cfg, loss_chunk=self.C))(half, batch)
+        for name in ("head", "embed"):
+            assert grads[name].dtype == jnp.bfloat16
+            err = np.abs(np.asarray(grads[name], np.float32)
+                         - np.asarray(exact[name]))
+            assert err.max() < 0.05 * np.abs(np.asarray(exact[name])).max()
 
 
 @pytest.mark.heavy

@@ -42,14 +42,19 @@ REF_CFG = {"hidden_size": 64, "intermediate_size": 32, "num_hidden_layers": LAYE
 RTOL = 2e-5
 
 
-@pytest.fixture(scope="module")
-def reference():
+def _benchmark_module(kind, name):
+    """A file of the benchmark, loaded by path."""
     spec = importlib.util.spec_from_file_location(
-        "olmoe_reference",
-        os.path.join(ROOT, "benchmark", "reference", "olmoe-1b-7b.py"))
+        name.replace("-", "_"),
+        os.path.join(ROOT, "benchmark", kind, name + ".py"))
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
     return module
+
+
+@pytest.fixture(scope="module")
+def reference():
+    return _benchmark_module("reference", "olmoe-1b-7b")
 
 
 @pytest.fixture(scope="module")
@@ -78,10 +83,11 @@ def sample():
     return tokens, targets
 
 
-def _system(cfg, params, sample, attn="full"):
+def _system(cfg, params, sample, attn="full", loss_chunk=64):
     """(loss, logits, gradients) through the normal path, with the layer's
     remat and the chunked loss the benchmark's cell runs."""
-    loss_fn = llama.make_loss_fn(cfg, attn=attn, remat="dots", loss_chunk=64)
+    loss_fn = llama.make_loss_fn(cfg, attn=attn, remat="dots",
+                                 loss_chunk=loss_chunk)
     loss, grads = jax.jit(jax.value_and_grad(loss_fn))(params, sample)
     return loss, jax.jit(lambda p, t: llama.apply(cfg, p, t, attn=attn))(
         params, sample[0]), grads
@@ -107,6 +113,26 @@ def test_system_matches_the_plain_reference(expected, weights, sample, attn):
     found = _worst(_system(CFG, weights, sample, attn), expected)
     assert max(found.values()) < RTOL, found
     assert len(found) == 2 + len(jax.tree.leaves(weights))
+
+
+@pytest.mark.parametrize("chunks", [1, 8])
+def test_chunked_head_matches_the_plain_reference(expected, weights, sample,
+                                                  chunks):
+    """The head's gradients, taken in the chunk's forward pass, under the
+    cell's flash kernels and layer remat: one chunk and eight (two above)."""
+    found = _worst(_system(CFG, weights, sample, "flash",
+                           loss_chunk=sample[0].shape[1] // chunks), expected)
+    assert max(found.values()) < RTOL, found
+
+
+def test_head_loss_ms_reads_the_join():
+    """`benchmark/layers/head_loss_ms.py` (tier-1 does not collect
+    `benchmark/tests/`): the `head_loss` entry of the runner's join, `None`
+    where the join left nothing."""
+    read = _benchmark_module("layers", "head_loss_ms").read
+    assert read({"counters": {}}) is None
+    assert read({"counters": {"scope_ms": {"optimizer": 21.3}}}) is None
+    assert read({"counters": {"scope_ms": {"head_loss": 61.5}}}) == 61.5
 
 
 def _skip_an_expert(params):

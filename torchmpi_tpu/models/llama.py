@@ -705,15 +705,70 @@ def _decoder_layer(cfg: Config, lp: Params, h: jax.Array,
     return _ffn_block(cfg, lp, h, constrain, mesh)
 
 
-@jax.checkpoint
-@jax.named_scope("head_loss")
 def _chunk_nll(head, h_c, t_c):
-    """Summed NLL of one (B, C, D) chunk; checkpointed so the backward
-    re-forms its (B, C, V) logits instead of storing them per chunk."""
+    """One (B, C, D) chunk: its summed NLL, its (B, C, V) f32 logits, their
+    log-sum-exp and where the targets are among them.  The target's logit is
+    a masked sum (one term, so exact), which the compiler takes in the pass
+    that sums the exponentials; a gather would have the chunk's logits
+    written out in float32 for it."""
     logits = (h_c @ head).astype(jnp.float32)
     lse = jax.nn.logsumexp(logits, axis=-1)
-    tgt = jnp.take_along_axis(logits, t_c[..., None], axis=-1)[..., 0]
-    return jnp.sum(lse - tgt)
+    hit = t_c[..., None] == lax.broadcasted_iota(t_c.dtype, logits.shape, 2)
+    tgt = jnp.sum(jnp.where(hit, logits, 0.0), axis=-1)
+    return jnp.sum(lse - tgt), logits, lse, hit
+
+
+def _chunk_at(h, targets, idx, C):
+    return (lax.dynamic_slice_in_dim(h, idx * C, C, axis=1),
+            lax.dynamic_slice_in_dim(targets, idx * C, C, axis=1))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _chunked_nll(head, h, targets, C):
+    """Mean NLL over ``L // C`` sequence chunks, one ``h_c @ head`` a chunk.
+    Under differentiation the chunk that forms the logits takes the head's
+    gradients too (:func:`_chunked_nll_fwd`): three products over the
+    vocabulary a chunk, where a checkpointed chunk would form its logits
+    again in the backward pass and run four."""
+    B, L, _ = h.shape
+
+    def step(acc, idx):
+        return acc + _chunk_nll(head, *_chunk_at(h, targets, idx, C))[0], None
+
+    total, _ = lax.scan(step, jnp.zeros((), jnp.float32), jnp.arange(L // C))
+    return total / (B * L)
+
+
+def _chunked_nll_fwd(head, h, targets, C):
+    B, L, _ = h.shape
+    # The dtype the logits are formed in, so the dtype their cotangent has.
+    dtype = jnp.result_type(h.dtype, head.dtype)
+
+    def step(carry, idx):
+        acc, dh, dw = carry
+        h_c, t_c = _chunk_at(h, targets, idx, C)
+        nll, logits, lse, hit = _chunk_nll(head, h_c, t_c)
+        dl = ((jnp.exp(logits - lse[..., None]) - hit) / (B * L)).astype(dtype)
+        dh_c = (dl @ head.T).astype(h.dtype)
+        dw = dw + jnp.einsum("bcd,bcv->dv", h_c, dl).astype(dw.dtype)
+        dh = lax.dynamic_update_slice_in_dim(dh, dh_c, idx * C, axis=1)
+        return (acc + nll, dh, dw), None
+
+    (total, dh, dw), _ = lax.scan(
+        step, (jnp.zeros((), jnp.float32), jnp.zeros_like(h),
+               jnp.zeros_like(head)), jnp.arange(L // C))
+    # The residuals are the gradients themselves, (B, L, D) and (D, V): the
+    # (B, C, V) logits never leave their chunk.
+    return total / (B * L), (dh, dw, targets)
+
+
+def _chunked_nll_bwd(C, saved, g):
+    dh, dw, targets = saved
+    scale = lambda a: (a.astype(jnp.float32) * g).astype(a.dtype)
+    return scale(dw), scale(dh), np.zeros(targets.shape, jax.dtypes.float0)
+
+
+_chunked_nll.defvjp(_chunked_nll_fwd, _chunked_nll_bwd)
 
 
 @jax.named_scope("head_loss")
@@ -727,18 +782,10 @@ def _nll_from_hidden(head: jax.Array, h: jax.Array, targets: jax.Array,
         logp = jax.nn.log_softmax(logits, axis=-1)
         return -jnp.mean(jnp.take_along_axis(logp, targets[..., None],
                                              axis=-1)[..., 0])
-    B, L, _ = h.shape
-    C = int(loss_chunk)
+    L, C = h.shape[1], int(loss_chunk)
     if L % C:
         raise ValueError(f"seq len {L} not divisible by loss_chunk {C}")
-
-    def step(acc, idx):
-        h_c = lax.dynamic_slice_in_dim(h, idx * C, C, axis=1)
-        t_c = lax.dynamic_slice_in_dim(targets, idx * C, C, axis=1)
-        return acc + _chunk_nll(head, h_c, t_c), None
-
-    total, _ = lax.scan(step, jnp.zeros((), jnp.float32), jnp.arange(L // C))
-    return total / (B * L)
+    return _chunked_nll(head, h, targets, C)
 
 
 def _make_tp_ce_sum(axis: str):
@@ -971,9 +1018,10 @@ def make_loss_fn(cfg: Config, mesh: Optional[Mesh] = None, attn: str = "full",
     ``loss_chunk`` > 0 computes the loss in sequence chunks of that size so
     the full ``(B, L, V)`` f32 logits never materialize — at 8B scale
     (V=128256) those logits alone are ~4 GB per 8k sequence, more than the
-    layer activations; chunking caps the live buffer at ``(B, C, V)``.  Each
-    chunk is rematerialized in the backward, so the peak holds there too.
-    ``L`` must be divisible by ``loss_chunk``.
+    layer activations; chunking caps the live buffer at ``(B, C, V)``.  Under
+    differentiation each chunk takes the head's gradients in the pass that
+    forms its logits (:func:`_chunked_nll`), so the peak holds there too and
+    no chunk is formed twice.  ``L`` must be divisible by ``loss_chunk``.
     """
 
     def loss_fn(params: Params, batch: Tuple[jax.Array, jax.Array]) -> jax.Array:
