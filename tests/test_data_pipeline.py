@@ -261,7 +261,7 @@ class TestDeviceStage:
         list(stage)
         st = stage.stats
         serve.publish_input(staged_bytes=st.staged_bytes,
-                            stage_s=st.stage_s, wait_s=st.wait_s,
+                            stage_s=st.stage_s,
                             overlap_fraction=st.overlap_fraction(),
                             registry=reg)
         assert (reg.counter("tmpi_data_staged_bytes_total").value()

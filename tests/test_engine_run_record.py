@@ -70,8 +70,14 @@ def test_stamps_are_ordered_and_steps_counted(world, mode):
     stamps = list(rec.step_stamps)
     assert [s[0] for s in stamps] == list(range(10))
     last_end = rec.t_first_batch
-    for _, t_batch, t_stepped, t_end, wait_ns, hook_ns in stamps:
+    for _, t_batch, t_stepped, t_end, wait_ns, hook_ns, *own in stamps:
         assert last_end <= t_batch <= t_stepped <= t_end
+        # The step function's own: entry, staged, dispatched, the two ends
+        # of its wait, its last statement; then the async drain's blocked.
+        assert [t_batch, *own[:6], t_stepped] == sorted(
+            [t_batch, *own[:6], t_stepped])
+        assert (own[6] is None) == (mode != "eager_async")
+        assert own[6] is None or 0 <= own[6] <= own[4] - own[3]
         assert wait_ns >= 0 and hook_ns == 0
         assert t_end - t_batch >= wait_ns
         last_end = t_end
@@ -89,6 +95,65 @@ def test_stamps_are_ordered_and_steps_counted(world, mode):
         # The eager modes fence within the step.
         assert not rec.completions and s["step_ms_p50"] is None
         assert s["intervals"] == 0
+
+
+@pytest.mark.parametrize("mode", ["compiled", "eager_sync"])
+def test_the_live_feed_is_computed_from_the_steps_own_stamps(world, mode):
+    """``obs/serve.py:engine_step`` reads no clock: with the feed on, the
+    step-time gauge and the phase gauges are functions of the last
+    ``step_stamps`` entry, and the training is the one the feed-off run
+    does."""
+    from torchmpi_tpu.obs import alerts, native
+    from torchmpi_tpu.obs.metrics import registry
+
+    config.set("data_pipeline", "off")      # no pipeline wait_s: stamps only
+    off = _engine(mode).train(_params(mode, world), _batches(4))
+    config.set("obs_http", True)            # the feed, without the tracer
+    on = _engine(mode).train(_params(mode, world), _batches(4))
+    assert on["run"].steps == off["run"].steps == 4
+    np.testing.assert_array_equal(np.asarray(on["loss"]),
+                                  np.asarray(off["loss"]))
+    jax.tree.map(np.testing.assert_array_equal, on["params"], off["params"])
+
+    entry, staged, dispatched, sync, synced, done, _ = \
+        on["run"].step_stamps[-1][6:]
+    assert registry.gauge("tmpi_engine_step_seconds").value() == \
+        (done - entry) / 1e9
+    spans = {"data_wait": (entry, staged), "dispatch": (staged, dispatched),
+             "collective": (sync, synced),
+             "optimizer": (synced, done) if mode == "eager_sync" else (0, 0),
+             # Hook time, where a test before this one loaded the plane.
+             "ps": (synced, done) if mode == "compiled"
+             and native.loaded("ps") else (0, 0)}
+    assert tuple(spans) == alerts.PHASES
+    phase = registry.gauge("tmpi_step_phase_seconds")
+    for name, (t0, t1) in spans.items():
+        assert phase.value(labels={"phase": name}) == (t1 - t0) / 1e9
+    assert sum((t1 - t0) / 1e9 for t0, t1 in spans.values()) <= \
+        (done - entry) / 1e9
+
+
+def test_the_loop_imports_no_step_boundary_plane():
+    """Resize, failure, election and retune attach through
+    ``engine.step_boundaries`` from outside: the loop's module imports none
+    of them, at its top or inside a function."""
+    import ast
+
+    barred = {"runtime.resize", "runtime.failure", "runtime.election",
+              "collectives.retune"}
+    with open(sgdengine.__file__) as f:
+        tree = ast.parse(f.read())
+    seen = set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            seen.update(alias.name for alias in node.names)
+        elif isinstance(node, ast.ImportFrom):
+            module = node.module or ""
+            seen.add(module)
+            seen.update(f"{module}.{alias.name}".lstrip(".")
+                        for alias in node.names)
+    assert seen and not {name for name in seen
+                         if any(name.endswith(b) for b in barred)}
 
 
 def test_completions_name_the_right_steps_with_a_window_of_two(world):

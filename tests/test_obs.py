@@ -354,32 +354,49 @@ class TestExport:
 
 
 class TestEngineSpans:
-    def test_compiled_step_phases_share_one_correlation(self, world, obs_on):
+    @pytest.mark.parametrize("mode, phases", [
+        ("compiled",
+         ("engine.stage", "engine.dispatch", "engine.inflight_wait")),
+        ("eager_sync", ("engine.stage", "engine.grad", "engine.sync",
+                        "engine.optimizer")),
+        ("eager_async", ("engine.stage", "engine.grad", "engine.sync")),
+    ])
+    def test_step_phases_share_one_correlation(self, world, obs_on, mode,
+                                               phases):
         import jax.numpy as jnp
 
+        from torchmpi_tpu.collectives import eager
         from torchmpi_tpu.engine import AllReduceSGDEngine
 
         def loss_fn(params, batch):
             x, y = batch
             return jnp.mean((x @ params["w"] - y) ** 2)
 
-        engine = AllReduceSGDEngine(loss_fn, lr=0.01, mode="compiled")
+        engine = AllReduceSGDEngine(loss_fn, lr=0.01, mode=mode)
         params = {"w": jnp.zeros((3,), jnp.float32)}
+        if mode != "compiled":
+            params = {"w": eager.shard(world, np.zeros((8, 3), np.float32))}
         rng = np.random.default_rng(0)
         batches = [(rng.standard_normal((8, 4, 3)).astype(np.float32),
                     rng.standard_normal((8, 4)).astype(np.float32))]
         engine.train(params, batches, epochs=2)
-        spans = tracer.drain()
         by_name = {}
-        for s in spans:
+        for s in tracer.drain():
             by_name.setdefault(s["name"], []).append(s)
-        assert len(by_name["engine.step"]) == 2
-        for phase in ("engine.stage", "engine.dispatch"):
-            assert len(by_name[phase]) == 2
-        # phases nest under their step: same correlation id
-        step_corrs = {s["correlation"] for s in by_name["engine.step"]}
-        assert {s["correlation"]
-                for s in by_name["engine.dispatch"]} == step_corrs
+        assert {n for n in by_name if n.startswith("engine.")} == {
+            "engine.step", *phases}
+        steps = {s["correlation"]: s for s in by_name["engine.step"]}
+        assert len(steps) == 2
+        for phase in phases:
+            # One a step, under its step's correlation id and inside it:
+            # registered from the step's own stamps (obs/serve.py).
+            assert sorted(s["correlation"] for s in by_name[phase]) == \
+                sorted(steps)
+            for s in by_name[phase]:
+                step = steps[s["correlation"]]
+                assert (step["t0_ns"] <= s["t0_ns"] <= s["t1_ns"]
+                        <= step["t1_ns"])
+                assert s["thread"] == step["thread"]
 
     def test_profiler_hooks_compose_with_tracer_hooks(self):
         from torchmpi_tpu.utils.profiler import (StepWindowProfiler,

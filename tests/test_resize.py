@@ -40,7 +40,7 @@ from torchmpi_tpu.collectives.hostcomm import HostCommunicator, free_ports
 from torchmpi_tpu.obs import metrics as obs_metrics
 from torchmpi_tpu.obs import rca, serve
 from torchmpi_tpu.runtime import chaos, config, election, resize
-from torchmpi_tpu.runtime.failure import InjectedFault
+from torchmpi_tpu.runtime.failure import HostcommError, InjectedFault
 
 pytestmark = pytest.mark.resize
 
@@ -874,7 +874,9 @@ class TestEngineBoundary:
 
         eng = AllReduceSGDEngine(loss, lr=0.01, mode="compiled")
         stub = _StubController(after=3)
-        eng.resize_controller = stub
+        eng.step_boundaries.append(resize.engine_boundary(stub))
+        later = []                      # a boundary attached after it
+        eng.step_boundaries.append(lambda state: later.append(state["t"]))
         rng = np.random.default_rng(0)
         x = rng.normal(size=(8, 2, 4)).astype(np.float32)
         y = rng.normal(size=(8, 2)).astype(np.float32)
@@ -883,6 +885,7 @@ class TestEngineBoundary:
         assert state.get("departed") is True
         assert stub.calls == 3
         assert state["t"] == 3          # three steps ran, then departure
+        assert later == [1, 2]          # skipped on the step that ended it
 
     def test_committed_ends_train_for_rebuild(self, world):
         """A COMMITTED membership change ends train() with
@@ -896,8 +899,8 @@ class TestEngineBoundary:
             return jnp.mean((xb @ params["w"] - yb) ** 2)
 
         eng = AllReduceSGDEngine(loss, lr=0.01, mode="compiled")
-        eng.resize_controller = _StubController(
-            after=2, outcome=resize.COMMITTED)
+        eng.step_boundaries.append(resize.engine_boundary(
+            _StubController(after=2, outcome=resize.COMMITTED)))
         rng = np.random.default_rng(0)
         it = [(rng.normal(size=(8, 2, 4)).astype(np.float32),
                rng.normal(size=(8, 2)).astype(np.float32))] * 5
@@ -905,3 +908,33 @@ class TestEngineBoundary:
         assert state.get("resized") == 7       # the stub's new epoch
         assert "departed" not in state
         assert state["t"] == 2
+
+    @pytest.mark.parametrize("elects", [True, False])
+    def test_a_boundary_fault_goes_to_the_election_when_there_is_one(
+            self, elects):
+        """``engine_boundary`` hands a transport fault of the controller's
+        to the coordinator, whose outcome ends the loop as a commit does;
+        with none attached, or for any other error, it propagates."""
+        class Faulting(_StubController):
+            def step_boundary(self):
+                raise self.outcome
+
+        class Coordinator:
+            def on_boundary_fault(self, exc):
+                assert isinstance(exc, HostcommError)
+                return resize.COMMITTED
+
+        boundary = resize.engine_boundary(
+            Faulting(after=1, outcome=HostcommError("leader gone")),
+            election=Coordinator() if elects else None)
+        state = {}
+        if elects:
+            boundary(state)
+            assert state == {"resized": 7}
+        else:
+            with pytest.raises(HostcommError):
+                boundary(state)
+        with pytest.raises(KeyError):       # not a transport fault
+            resize.engine_boundary(
+                Faulting(after=1, outcome=KeyError("bug")),
+                election=Coordinator())(state)

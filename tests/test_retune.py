@@ -275,13 +275,13 @@ class TestInstall:
         config.set("retune_enabled", True)
 
         class Eng:
-            retune_controller = None
+            step_boundaries = []
 
         eng = Eng()
         ctl = retune.maybe_install(
             engine=eng, alert_engine=StubAlertEngine(), store=StubStore())
         assert ctl is not None
-        assert eng.retune_controller is ctl
+        assert eng.step_boundaries == [ctl.step_boundary]
         assert retune.installed() is ctl
 
     def test_engine_consults_at_step_boundary(self, world):
@@ -290,20 +290,20 @@ class TestInstall:
         calls = []
 
         class Probe:
-            def step_boundary(self):
-                calls.append(1)
+            def step_boundary(self, state):
+                calls.append(state["t"])
 
         def loss(params, batch):
             x, y = batch
             return jnp.mean((x @ params - y) ** 2)
 
         eng = AllReduceSGDEngine(loss, lr=0.1, comm=world, mode="compiled")
-        eng.retune_controller = Probe()
+        eng.step_boundaries.append(Probe().step_boundary)
         params = jnp.zeros((4, 2), jnp.float32)
         xs = np.ones((world.size, 2, 4), np.float32)
         ys = np.zeros((world.size, 2, 2), np.float32)
         eng.train(params, [(xs, ys)] * 3)
-        assert len(calls) >= 3
+        assert calls == [1, 2, 3]       # once a step, after its on_update
 
 
 # ----------------------------------------------------- the mix-drift alert

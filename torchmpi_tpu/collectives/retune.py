@@ -4,10 +4,10 @@ PERFORMANCE knobs, the same pattern the autoscaler proved for membership.
 The alert plane (obs/alerts.py) *detects* a sagging step rate, a collapsed
 async overlap, or live traffic drifting off the autotune cache's measured
 cells — and, before this module, nothing *acted* on a firing.  The
-:class:`RetuneController` closes the loop.  It installs beside
-``engine.resize_controller`` and is consulted at the same step boundary
-(the only place no collective is in flight); a consult is a few dict reads
-and NEVER blocks or breaks the train loop.
+:class:`RetuneController` closes the loop.  It attaches to
+``engine.step_boundaries`` and is consulted at the same step boundary as
+the resize controller (the only place no collective is in flight); a
+consult is a few dict reads and NEVER blocks or breaks the train loop.
 
 Lifecycle (mirroring the autoscaler's two-debounce discipline — the alert
 plane's ``for_s`` already debounced once, the controller still demands its
@@ -168,10 +168,11 @@ class RetuneController:
 
     # -------------------------------------------------- the step hook
 
-    def step_boundary(self) -> str:
-        """Consulted by the engine once per step; returns the controller
-        state.  MUST never raise and never block: probes run on their own
-        thread, and any internal failure leaves the loop training."""
+    def step_boundary(self, state=None) -> str:
+        """Consulted by the engine once per step (``state`` is the engine's,
+        unused); returns the controller state.  MUST never raise and never
+        block: probes run on their own thread, and any internal failure
+        leaves the loop training."""
         self._steps += 1
         if self._steps % self.cfg["poll_interval_steps"]:
             return self.state
@@ -404,15 +405,16 @@ class RetuneController:
 
 def maybe_install(engine=None, **kwargs) -> Optional[RetuneController]:
     """Arm the controller when ``retune_enabled`` is set: construct it,
-    hang it on ``engine.retune_controller`` (the step-boundary consult
-    point beside ``resize_controller``), and register it for GET /retune.
-    Off = one config read, None, nothing installed."""
+    append its ``step_boundary`` to ``engine.step_boundaries`` (after a
+    resize boundary, which skips it on the step that ends the loop), and
+    register it for GET /retune.  Off = one config read, None, nothing
+    installed."""
     global _installed
     if not bool(config.get("retune_enabled")):
         return None
     ctl = RetuneController(**kwargs)
     if engine is not None:
-        engine.retune_controller = ctl
+        engine.step_boundaries.append(ctl.step_boundary)
     with _lock:
         _installed = ctl
     return ctl

@@ -202,7 +202,7 @@ class DeviceStageIterator:
         if publish:
             from ..obs import serve as _serve
             _serve.publish_input(
-                staged_bytes=nbytes, stage_s=stage_s, wait_s=wait_s,
+                staged_bytes=nbytes, stage_s=stage_s,
                 overlap_fraction=stats.overlap_fraction())
         return (Staged(sx.array, wait_s=wait_s), sy)
 

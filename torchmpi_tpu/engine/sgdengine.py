@@ -40,13 +40,12 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .. import nn as mpinn
 from ..collectives import eager
-from ..obs import native as _obs_native
 from ..obs import numerics as _numerics
 from ..obs import serve as _obs_serve
 from ..obs import tracer as _obs
 from ..data import pipeline as _data_pipe
-from ..utils.data import Staged as _Staged
-from ..utils.data import stage_rank_major as _stage
+from ..data.staging import Staged as _Staged
+from ..data.staging import stage_rank_major as _stage
 from ..runtime import communicator as _comm_mod
 from ..runtime.communicator import RANK_AXIS
 from ..utils.meters import AverageValueMeter
@@ -55,22 +54,6 @@ LossFn = Callable[[Any, Tuple[jax.Array, jax.Array]], jax.Array]
 Hooks = Dict[str, Callable[[Dict[str, Any]], None]]
 
 MODES = ("compiled", "eager_sync", "eager_async")
-
-
-_PROC_COUNT: Optional[int] = None
-
-
-def _local_examples(global_rows: int) -> int:
-    """Examples THIS process contributed to a step: every controller
-    stages the full global batch (stage_rank_major / eager.shard are
-    SPMD — same global array on each process) but computes only
-    1/process_count of it, and the published counters say "processed by
-    this process" — summing them across the federation's rank label must
-    give the job total once, not process_count times."""
-    global _PROC_COUNT
-    if _PROC_COUNT is None:
-        _PROC_COUNT = max(1, jax.process_count())
-    return max(1, global_rows // _PROC_COUNT)
 
 
 def _step_correlation(t) -> Optional[int]:
@@ -83,6 +66,11 @@ def _step_correlation(t) -> Optional[int]:
     if not _obs.enabled():
         return None
     return _obs.cluster_correlation("engine.step", int(t))
+
+
+def _ended(state) -> bool:
+    """A step boundary has ended the loop (``step_boundaries``)."""
+    return bool(state.get("departed") or state.get("resized"))
 
 
 def sgd_update(params, grads, lr):
@@ -184,7 +172,16 @@ class RunRecord:
       time inside ``block_until_ready`` of the in-flight bound; time
       inside the user's hooks.  ``t_batch`` less the step before's
       ``t_end`` is the wait for input, ``t_end - t_batch - wait_ns -
-      hook_ns`` the engine's own host time.
+      hook_ns`` the engine's own host time.  After those six, what the
+      step function stamped, the only clock it reads: ``t_entry, t_staged,
+      t_dispatched, t_sync, t_synced, t_done, blocked_ns``: it has opened
+      its ``engine.step`` span; staging has ended; the call into the step
+      program (eager: the gradient function) has returned; the two ends of
+      its one wait (compiled: the in-flight bound; eager: the gradient
+      sync); its last statement inside the span; and, under eager_async
+      alone (else ``None``), the time the drain spent inside handle waits.
+      The live feed and the phase spans are derived from these
+      (``obs/serve.py:engine_step``).
     * ``completions``, same ring: ``(step, stamp)``, the moment the host
       saw step ``step`` finished, taken where the in-flight bound blocks on
       its loss.  The last ``window`` steps of a call have none: the host
@@ -309,14 +306,10 @@ class AllReduceSGDEngine:
         self._batch_sh = None       # staging sharding, hoisted per compile
         self._eager_grad_fn = None
         self._eager_grad_for = None
-        # Numerics plane (obs/numerics.py, docs/numerics.md): whether the
-        # CURRENT compiled step carries in-graph sentinels (set beside
-        # the compile key — mode changes rebuild), and the optional
-        # cross-rank auditor the train loop consults per step.  Assign a
-        # numerics.Auditor over a hostcomm-plane communicator to enable
-        # audit mode's digest exchange.
+        # Whether the CURRENT compiled step carries the numerics plane's
+        # in-graph sentinels (set beside the compile key: a mode change
+        # rebuilds).
         self._sentinels_on = False
-        self.numerics_auditor = None
         # Compute-efficiency feed: the compiled step's analytical FLOPs
         # (XLA cost model), probed once per compile when telemetry is on.
         self._step_flops = None
@@ -328,30 +321,14 @@ class AllReduceSGDEngine:
         #                       (loss, the RunRecord it was queued under, step)
         self._run = None      # the RunRecord of the train() call in progress
         self.last_run = None  # ... and of the newest call, open or returned
-        # Elastic resize (runtime/resize.py, docs/resize.md): an installed
-        # ResizeController is consulted once per step at the boundary.
-        # DEPARTED (this rank drained/evicted) ends train() with
-        # state["departed"] True; COMMITTED ends it with state["resized"]
-        # = the new epoch — the compiled world cannot follow a live
-        # world-size change, so the elastic layer rebuilds the engine
-        # against the new membership.  None = one attribute check per
-        # step, nothing else.
-        self.resize_controller = None
-        # Election coordinator (runtime/election.py, docs/election.md):
-        # when installed beside the resize controller, a transport fault
-        # at the boundary with a provably DEAD leader runs the unplanned
-        # failover (survivors re-elect and rewire) instead of escalating
-        # to the restart path; the loop then ends with state["resized"]
-        # exactly as for a commit, and the elastic layer rebuilds the
-        # engine against the surviving membership.  None = the fault
-        # propagates untouched (restart path, the pre-election behavior).
-        self.election_coordinator = None
-        # Retune controller (collectives/retune.py, docs/autotune.md): an
-        # installed RetuneController is consulted at the same boundary —
-        # it acts on firing perf alerts by re-benching off the hot path
-        # and flipping knobs, and unlike resize it NEVER ends the loop.
-        # None = one attribute check per step, nothing else.
-        self.retune_controller = None
+        # Step-boundary planes attach here from outside
+        # (resize.engine_boundary, retune.maybe_install,
+        # numerics.Auditor.step_boundary): callables of the engine ``state``,
+        # run in order once a step after ``on_update``, outside hook time,
+        # where no collective is in flight.  One that sets
+        # ``state["departed"]`` or ``state["resized"]`` ends train() there:
+        # those after it are skipped, ``on_end_epoch`` and ``on_end`` too.
+        self.step_boundaries: List[Callable[[Dict[str, Any]], None]] = []
 
     @property
     def comm(self):
@@ -584,7 +561,6 @@ class AllReduceSGDEngine:
                 out_specs=(P(), P()), check_vma=False,
             )(params, xb, yb)
 
-        update_barrier = bool(_config.get("engine_update_barrier"))
         # In-step numerics sentinels (obs/numerics.py): with the knob on,
         # the step additionally returns fused in-graph statistics over
         # the SYNCED gradients and the applied update.  "off" is the
@@ -607,10 +583,6 @@ class AllReduceSGDEngine:
                 # instead reduce-scatters into the optimizer shard and
                 # all-gathers the updated parameters.
                 loss, grads = grads_of(params, xb, yb)
-            if update_barrier:
-                # Fuse fence: keeps the weight-gradient convs out of the
-                # optimizer-update fusion group (A/B knob, see config).
-                params, grads = lax.optimization_barrier((params, grads))
             # Names in the device program (docs/observability.md): metadata
             # only.  ``grad_sync`` names the explicit rings above; GSPMD's
             # all-reduces are put in by the partitioner and carry the name
@@ -759,8 +731,7 @@ class AllReduceSGDEngine:
                     f"numerics_mode must be one of {_numerics.MODES}, "
                     f"got {num_mode!r}")
             key = (comm, self.lr, self.optimizer, self.loss_fn, self.zero1,
-                   self.accum_steps, opt_shapes, ring_key,
-                   bool(_config.get("engine_update_barrier")), num_mode)
+                   self.accum_steps, opt_shapes, ring_key, num_mode)
             if self._compiled_step is None or self._compiled_for != key:
                 self._compiled_step = self._build_compiled_step(
                     comm, state["opt_state"])
@@ -808,7 +779,7 @@ class AllReduceSGDEngine:
                     if rec.t_first_batch is None:
                         rec.t_first_batch = t_batch
                     rec._wait_ns = rec._hook_ns = 0
-                    ended = False       # the resize boundary ends the loop
+                    ended = False       # a step boundary may end the loop
                     state["sample"] = (xb, yb)
                     # Reference fences each sample with a barrier + device
                     # sync (sgdengine.lua:111-114); under SPMD the single
@@ -816,74 +787,30 @@ class AllReduceSGDEngine:
                     # barrier is only kept for the eager modes' first step.
                     self._hook("on_sample", state)
                     if self.mode == "compiled":
-                        self._train_step_compiled(state, xb, yb)
+                        stepped = self._train_step_compiled(state, xb, yb)
                     else:
-                        self._train_step_eager(state, xb, yb)
+                        stepped = self._train_step_eager(state, xb, yb)
                     t_stepped = time.monotonic_ns()
                     state["t"] += 1
                     if (self.check_frequency and self.mode != "compiled"
                             and state["t"] % self.check_frequency == 0):
                         mpinn.check_with_allreduce(state["params"], comm)
-                    # Cross-rank numerics audit (obs/numerics.py): with an
-                    # installed auditor, audit mode allgathers parameter
-                    # fingerprints every numerics_audit_interval steps —
-                    # the replica-fork detector no wall-clock probe can
-                    # replace.  Off-mode cost: two config reads.
-                    if self.numerics_auditor is not None:
-                        self.numerics_auditor.maybe_audit(
-                            state["params"], state["t"])
                     self._hook("on_update", state)
-                    # Elastic resize boundary (runtime/resize.py): the
-                    # step boundary is the ONLY place membership may
-                    # change — no member is inside a collective here.
-                    # DEPARTED = this rank drained/was evicted; the loop
-                    # ends (its capacity is gone, not its process).
-                    # COMMITTED = the HOST membership advanced under us:
-                    # this engine's compiled world (mesh, shardings,
-                    # donated buffers) is fixed at construction and
-                    # CANNOT follow a live world-size change, so the
-                    # loop ends cleanly with the current params and
-                    # state["resized"] set — the elastic layer rebuilds
-                    # the engine against the new membership (the fence
-                    # guarantees no collective was in flight).  ABORTED
-                    # changed nothing: keep training.
-                    if self.resize_controller is not None:
-                        from ..runtime import resize as _resize_mod
-
-                        try:
-                            out = self.resize_controller.step_boundary()
-                        except Exception as e:
-                            from ..runtime.failure import (
-                                TransportFailure as _TF)
-
-                            if (self.election_coordinator is None
-                                    or not isinstance(e, _TF)):
-                                raise
-                            # A dead LEADER elects; anything else
-                            # re-raises inside on_boundary_fault.
-                            out = (self.election_coordinator
-                                   .on_boundary_fault(e))
-                        if out == _resize_mod.DEPARTED:
-                            state["departed"] = ended = True
-                        elif out == _resize_mod.COMMITTED:
-                            state["resized"] = (
-                                self.resize_controller.membership.epoch)
-                            ended = True
-                    # Retune boundary (collectives/retune.py): acts on
-                    # firing perf alerts — probes off the hot path, flips
-                    # knobs, never raises and never breaks the loop.
-                    if self.retune_controller is not None and not ended:
-                        self.retune_controller.step_boundary()
+                    for boundary in self.step_boundaries:
+                        boundary(state)
+                        ended = _ended(state)
+                        if ended:
+                            break
                     rec.step_stamps.append(
                         (rec.steps, t_batch, t_stepped, time.monotonic_ns(),
-                         rec._wait_ns, rec._hook_ns))
+                         rec._wait_ns, rec._hook_ns) + stepped)
                     rec.steps += 1
                     if ended:
                         break
-                if state.get("departed") or state.get("resized"):
+                if _ended(state):
                     break
                 self._hook("on_end_epoch", state)
-            if not (state.get("departed") or state.get("resized")):
+            if not _ended(state):
                 self._hook("on_end", state)
         finally:
             # A loop that ENDED (cleanly or by a recoverable fault the
@@ -893,46 +820,30 @@ class AllReduceSGDEngine:
         return state
 
     def _train_step_compiled(self, state, xb, yb):
-        # Rank-major host batches (p, b, ...) are flattened and placed on the
-        # replica axis; ``Staged`` batches (from
-        # ``utils.data.DevicePrefetchIterator``, the reference's
-        # iterator-prefetch hook) pass through untouched.
-        # Step phases are spans (torchmpi_tpu/obs): any host collective /
-        # PS traffic a hook dispatches inherits the step's correlation id
-        # through the contextvar, so "where did this step's ms go" reads
-        # off one merged timeline.  obs_trace off = shared no-op contexts.
-        # The id is the CLUSTER correlation for this step number —
-        # identical on every rank with no coordination — so merge_ranks
-        # draws step t as one flow across the whole job and the straggler
-        # detector matches its collectives by exact id.
-        # The live feed (obs/serve.py): per-step gauges for /metrics and
-        # the item-2 autotuner — step time, examples/s, staged bytes,
-        # host/device overlap fraction from the phase timings the spans
-        # already bracket.  Gated on one bool read per step; off = two
-        # dead locals, the engine-loop-overhead guard's fast path.
-        feed = _obs_serve.metrics_feed()
-        t0 = time.monotonic_ns() if feed else 0
-        t_blocked = 0
-        # A pre-staged pair carries the pipeline's measured consumer wait
-        # (data/device.py): THAT is the step's input-blocked time — it
-        # happened between steps, outside this timed window, while the
-        # engine.stage span below is a pure handoff (an isinstance
-        # check).  Charging the handoff would pin the gauge at ~1.0 even
-        # when a starved pipeline stalls the loop for seconds (the
-        # mirror of the PR 9 reg.blocked_s fix on the sync side).
-        pre_staged = isinstance(xb, _Staged)
-        pipe_wait_s = xb.wait_s if (feed and pre_staged) else 0.0
+        """One compiled step; returns its stamps (``RunRecord.step_stamps``,
+        positions 6 on).  ``engine.step`` is a live span because a hook's
+        host collectives and parameter-server traffic inherit its
+        correlation id through the context; the id is the CLUSTER
+        correlation of this step number, identical on every rank with no
+        coordination.  The phase spans inside it and the live feed are
+        derived from the stamps (``obs/serve.py:engine_step``)."""
+        now = time.monotonic_ns
+        # A pre-staged pair (``data/device.py``) carries the pipeline's
+        # measured consumer wait: it happened between steps, outside these
+        # stamps, and staging such a pair below is a pure handoff.
+        wait_s = xb.wait_s if isinstance(xb, _Staged) else 0.0
         nstats = None
-        with _obs.span("engine.step", step=state["t"],
-                       correlation=_step_correlation(state["t"])):
-            with _obs.span("engine.stage"):
-                sh = self._batch_sh
-                xb = _stage(xb, sh).array
-                yb = _stage(yb, sh).array
-            t_staged = time.monotonic_ns() if feed else 0
-            if feed and not pre_staged:
-                t_blocked = t_staged - t0              # staging blocks
-            if feed and not self._flops_probed:
+        step = state["t"]
+        with _obs.span("engine.step", step=step,
+                       correlation=_step_correlation(step)) as corr:
+            t_entry = now()
+            # Rank-major host batches (p, b, ...) are flattened and placed
+            # on the replica axis; ``Staged`` batches pass through untouched.
+            sh = self._batch_sh
+            xb = _stage(xb, sh).array
+            yb = _stage(yb, sh).array
+            t_staged = now()
+            if not self._flops_probed and _obs_serve.metrics_feed():
                 # One-time compute-efficiency probe per compiled step
                 # (obs/numerics.py): XLA's analytical FLOPs via lower()
                 # — a re-trace, no compile, no execution — feeding the
@@ -943,11 +854,11 @@ class AllReduceSGDEngine:
                 self._step_flops = _numerics.probe_step_flops(
                     self._compiled_step,
                     (state["params"], state["opt_state"], xb, yb))
-            with _obs.span("engine.dispatch"):
-                out = self._compiled_step(
-                    state["params"], state["opt_state"], xb, yb)
+            out = self._compiled_step(
+                state["params"], state["opt_state"], xb, yb)
+            t_dispatched = now()
             if self._run.t_first_dispatch is None:
-                self._run.t_first_dispatch = time.monotonic_ns()
+                self._run.t_first_dispatch = t_dispatched
             if self._sentinels_on:
                 params, opt_state, loss, nstats = out
             else:
@@ -958,140 +869,84 @@ class AllReduceSGDEngine:
             # with compute.
             state["loss"] = loss
             state["loss_meter"].add(loss)
-            t_wait = time.monotonic_ns() if feed else 0
-            with _obs.span("engine.inflight_wait"):
-                self._bound_inflight(loss)
+            t_wait = now()
+            self._bound_inflight(loss)
             # The blocked window closes HERE: hook time below is the
-            # user's, not staging/sync block — it belongs in step_s but
-            # must not depress the overlap gauge.
-            t_waited = time.monotonic_ns() if feed else 0
+            # user's, not staging or sync block.
+            t_waited = now()
             self._hook("on_forward", state)
             self._hook("on_backward", state)
-        if feed:
-            t_end = time.monotonic_ns()
-            # The pipeline wait joins both sides: it is real wall time the
-            # host spent blocked on input for this step (examples/s must
-            # not read 2810 img/s while the loop starves between steps).
-            step_s = (t_end - t0) / 1e9 + pipe_wait_s
-            blocked_s = (t_blocked + (t_waited - t_wait)) / 1e9 + pipe_wait_s
-            # Phase decomposition from the stamps already taken
-            # (obs/alerts.PHASES): data_wait = input-blocked time,
-            # dispatch = trace/launch of the fused step, collective =
-            # the inflight drain (device compute + gradient sync live
-            # there in compiled mode), optimizer = 0 (fused into
-            # dispatch by XLA), ps = hook time when the PS plane is
-            # loaded (PS traffic dispatches from the step hooks).
-            hook_s = (t_end - t_waited) / 1e9
-            phases = {
-                "data_wait": t_blocked / 1e9 + pipe_wait_s,
-                "dispatch": (t_wait - t_staged) / 1e9,
-                "collective": (t_waited - t_wait) / 1e9,
-                "optimizer": 0.0,
-                "ps": hook_s if _obs_native.loaded("ps") else 0.0,
-            }
-            _obs_serve.publish_step(
-                step_s=step_s, examples=_local_examples(int(xb.shape[0])),
-                staged_bytes=int(xb.nbytes) + int(yb.nbytes),
-                overlap_fraction=1.0 - blocked_s / max(step_s, 1e-12),
-                step=state["t"], numerics=nstats, phases=phases)
-            if self._step_flops:
-                _numerics.publish_flops(self._step_flops, step_s)
-        else:
-            _obs_serve.note("engine_step")
+            t_done = now()
+        stamps = (t_entry, t_staged, t_dispatched, t_wait, t_waited, t_done,
+                  None)
+        _obs_serve.engine_step(stamps, self.mode, step, corr, xb, yb,
+                               wait_s=wait_s, numerics=nstats,
+                               flops=self._step_flops)
+        return stamps
 
     def _train_step_eager(self, state, xb, yb):
-        # No _bound_inflight here by design: the eager modes synchronize
-        # gradients within the step (eager collectives block_until_ready;
-        # the async form drains its handles before the update below), so
-        # host run-ahead is already <= 1 step.
+        """One eager step; as ``_train_step_compiled``.  No
+        ``_bound_inflight`` here by design: the eager modes synchronize
+        gradients within the step (eager collectives block_until_ready; the
+        async form drains its handles before the update below), so host
+        run-ahead is already <= 1 step."""
+        now = time.monotonic_ns
         comm = state["comm"]
-        feed = _obs_serve.metrics_feed()
-        t0 = time.monotonic_ns() if feed else 0
-        t_sync = 0
-        with _obs.span("engine.step", step=state["t"], mode=self.mode,
-                       correlation=_step_correlation(state["t"])):
-            with _obs.span("engine.stage"):
-                xb = eager.shard(comm, xb)
-                yb = eager.shard(comm, yb)
-            t_staged = time.monotonic_ns() if feed else 0
-            with _obs.span("engine.grad"):
-                losses, grads = self._eager_grad_fn(state["params"], xb, yb)
+        blocked_ns = None
+        step = state["t"]
+        with _obs.span("engine.step", step=step, mode=self.mode,
+                       correlation=_step_correlation(step)) as corr:
+            t_entry = now()
+            xb = eager.shard(comm, xb)
+            yb = eager.shard(comm, yb)
+            t_staged = now()
+            losses, grads = self._eager_grad_fn(state["params"], xb, yb)
+            t_grad = now()
             if self._run.t_first_dispatch is None:
-                self._run.t_first_dispatch = time.monotonic_ns()
-            t_grad = time.monotonic_ns() if feed else 0
+                self._run.t_first_dispatch = t_grad
             state["loss"] = losses
             state["loss_meter"].add(jnp.mean(losses))
             self._hook("on_forward", state)
             # Gradient synchronization (reference hook 'onBackward',
             # sgdengine.lua:126-131).
-            t_sync = time.monotonic_ns() if feed else 0
-            blocked_s = None
-            with _obs.span("engine.sync"):
-                if self.mode == "eager_async":
-                    from ..runtime import config as _config
+            t_sync = now()
+            if self.mode == "eager_async":
+                from ..runtime import config as _config
 
-                    reg = mpinn.async_.register_async_backward(
-                        grads, comm, step=state["t"])
-                    self._hook("on_backward", state)
-                    if str(_config.get("engine_async_drain")) == "barrier":
-                        # A/B baseline: the old post-backward barrier.
-                        grads = mpinn.async_.synchronize_gradients(reg)
-                        state["params"] = sgd_update(state["params"], grads,
-                                                     self.lr)
-                    else:
-                        # Drain at the optimizer boundary: each bucket's
-                        # parameters update the moment its collective
-                        # completes, while later buckets stay in flight
-                        # (nn.async_.drain_at_optimizer — the
-                        # registerAsyncMPIBackward pipeline).
-                        lr = self.lr
-                        state["params"] = mpinn.async_.drain_at_optimizer(
-                            reg, state["params"],
-                            lambda p, g: p - lr * g)
-                    # Real blocked time: only what the host spent INSIDE
-                    # handle waits — ready-order update work between
-                    # waits is overlap, not block.
-                    blocked_s = reg.blocked_s
-                else:
-                    grads = mpinn.synchronize_gradients(grads, comm)
-                    self._hook("on_backward", state)
-            t_synced = time.monotonic_ns() if feed else 0
-            if self.mode != "eager_async":
-                with _obs.span("engine.optimizer"):
+                reg = mpinn.async_.register_async_backward(
+                    grads, comm, step=step)
+                self._hook("on_backward", state)
+                if str(_config.get("engine_async_drain")) == "barrier":
+                    # A/B baseline: the old post-backward barrier.
+                    grads = mpinn.async_.synchronize_gradients(reg)
                     state["params"] = sgd_update(state["params"], grads,
                                                  self.lr)
-        if feed:
-            t_end = time.monotonic_ns()
-            step_s = (t_end - t0) / 1e9
-            sync_wall_s = (t_synced - t_sync) / 1e9
-            if blocked_s is None:
-                blocked_s = sync_wall_s
-            # Phase decomposition (obs/alerts.PHASES): in eager_async
-            # the ready-order drain interleaves bucket updates with
-            # handle waits inside the sync window, so optimizer = the
-            # drain's non-blocked share; the sync modes update after
-            # the sync span, so optimizer = the post-sync tail.
-            if self.mode == "eager_async":
-                opt_s = max(0.0, sync_wall_s - blocked_s)
+                else:
+                    # Drain at the optimizer boundary: each bucket's
+                    # parameters update the moment its collective
+                    # completes, while later buckets stay in flight
+                    # (nn.async_.drain_at_optimizer — the
+                    # registerAsyncMPIBackward pipeline).
+                    lr = self.lr
+                    state["params"] = mpinn.async_.drain_at_optimizer(
+                        reg, state["params"],
+                        lambda p, g: p - lr * g)
+                # Real blocked time: only what the host spent INSIDE
+                # handle waits — ready-order update work between
+                # waits is overlap, not block.
+                blocked_ns = int(reg.blocked_s * 1e9)
             else:
-                opt_s = (t_end - t_synced) / 1e9
-            phases = {
-                "data_wait": (t_staged - t0) / 1e9,
-                "dispatch": (t_grad - t_staged) / 1e9,
-                "collective": blocked_s,
-                "optimizer": opt_s,
-                "ps": 0.0,
-            }
-            # Rank-major (p, b, ...): the global batch is p*b examples.
-            examples = int(xb.shape[0]) * (int(xb.shape[1])
-                                           if xb.ndim > 1 else 1)
-            _obs_serve.publish_step(
-                step_s=step_s, examples=_local_examples(examples),
-                staged_bytes=int(xb.nbytes) + int(yb.nbytes),
-                overlap_fraction=1.0 - blocked_s / max(step_s, 1e-12),
-                step=state["t"], phases=phases)
-        else:
-            _obs_serve.note("engine_step")
+                grads = mpinn.synchronize_gradients(grads, comm)
+                self._hook("on_backward", state)
+            t_synced = now()
+            if self.mode != "eager_async":
+                state["params"] = sgd_update(state["params"], grads,
+                                             self.lr)
+            t_done = now()
+        stamps = (t_entry, t_staged, t_grad, t_sync, t_synced, t_done,
+                  blocked_ns)
+        _obs_serve.engine_step(stamps, self.mode, step, corr, xb, yb)
+        return stamps
 
     # ----------------------------------------------------------------- test
 

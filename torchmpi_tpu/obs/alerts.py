@@ -112,8 +112,9 @@ STATES = ("inactive", "pending", "firing", "resolved")
 #: (``tmpi_step_phase_seconds{phase=...}``), in publication order.
 PHASES = ("data_wait", "dispatch", "collective", "optimizer", "ps")
 
-#: engine/plane span names -> step phase, for :func:`phase_seconds` (the
-#: span-derived twin of the engine's direct-timestamp decomposition).
+#: engine span names -> step phase: what names the intervals of the live
+#: feed (``serve.engine_step`` sums, for each phase, the very intervals it
+#: registers as these spans) and of :func:`phase_seconds` alike.
 SPAN_PHASE = {
     "engine.stage": "data_wait",
     "engine.dispatch": "dispatch",
@@ -704,10 +705,10 @@ def phase_seconds(spans: Sequence[Mapping[str, Any]],
     """The span-derived step decomposition: bucket the child spans of
     the LAST complete ``engine.step`` by :data:`SPAN_PHASE` (plus the
     plane prefixes — ``hostcomm.*`` time is ``collective``, ``ps.*`` is
-    ``ps``), in seconds.  The engine's live gauges use its own
-    timestamps (they publish even with tracing off); this function is
-    the offline twin for obsdump analysis and the math the tests pin —
-    both must tell the same story about where the step's time went."""
+    ``ps``), in seconds.  The live gauges are summed over the same
+    engine intervals from the step's own stamps (``serve.engine_step``:
+    they publish even with tracing off); this function reads them back
+    from a dump, with the planes' own spans beside them."""
     steps = [s for s in spans if s.get("name") == "engine.step"]
     out = {p: 0.0 for p in PHASES}
     if not steps:

@@ -423,6 +423,11 @@ class Auditor:
             return None
         return self.audit(params, step=step, reference=reference)
 
+    def step_boundary(self, state: Dict[str, Any]) -> None:
+        """:meth:`maybe_audit` as a step boundary of the engine:
+        ``engine.step_boundaries.append(auditor.step_boundary)``."""
+        self.maybe_audit(state["params"], state["t"])
+
     def audit(self, params: Any, step: Optional[int] = None,
               reference: Any = None) -> AuditResult:
         """Run one audit now.  ``reference``: an optional known-good

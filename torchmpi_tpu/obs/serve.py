@@ -74,10 +74,12 @@ from urllib.parse import parse_qs, urlparse
 
 from . import native as obs_native
 from . import tracer
+from .alerts import PHASES, SPAN_PHASE
 
 __all__ = [
     "HealthState",
     "ObsHTTPServer",
+    "engine_step",
     "health",
     "maybe_start",
     "metrics_feed",
@@ -818,12 +820,116 @@ def end_drain() -> None:
     health.set_draining(False)
 
 
+# The step functions' own stamps, ``step_stamps[i][6:]`` of the engine's
+# ``RunRecord`` (engine/sgdengine.py, which says where each is taken).
+_ENTRY, _STAGED, _DISPATCHED, _SYNC, _SYNCED, _DONE, _BLOCKED_NS = range(7)
+
+# The phase spans of a step as (name, opening stamp, closing stamp): the ONE
+# statement of which interval is which.  ``engine_step`` registers them as
+# spans and sums the same intervals, named through ``alerts.SPAN_PHASE``.
+_STAGE = ("engine.stage", _ENTRY, _STAGED)
+_GRAD = ("engine.grad", _STAGED, _DISPATCHED)
+_SYNC_SPAN = ("engine.sync", _SYNC, _SYNCED)
+STEP_SPANS = {
+    "compiled": (_STAGE, ("engine.dispatch", _STAGED, _DISPATCHED),
+                 ("engine.inflight_wait", _SYNC, _SYNCED)),
+    "eager_sync": (_STAGE, _GRAD, _SYNC_SPAN,
+                   ("engine.optimizer", _SYNCED, _DONE)),
+    # The ready-order drain updates each bucket inside the sync window.
+    "eager_async": (_STAGE, _GRAD, _SYNC_SPAN),
+}
+
+_process_count: Optional[int] = None
+
+
+def _local_examples(global_rows: int) -> int:
+    """Examples THIS process contributed to a step: every controller
+    stages the full global batch (stage_rank_major / eager.shard are
+    SPMD — same global array on each process) but computes only
+    1/process_count of it, and the published counters say "processed by
+    this process" — summing them across the federation's rank label must
+    give the job total once, not process_count times."""
+    global _process_count
+    if _process_count is None:
+        import jax
+
+        _process_count = max(1, jax.process_count())
+    return max(1, global_rows // _process_count)
+
+
+def engine_step(stamps: Tuple, mode: str, step: int, correlation: int,
+                x, y, wait_s: float = 0.0,
+                numerics: Optional[Dict[str, Any]] = None,
+                flops: Optional[float] = None) -> None:
+    """The engine's one call a step: what the live feed and the tracer say
+    of the step's phases is derived here from ``stamps``, the clock reads
+    the step took for its ``RunRecord``; this function reads no clock.
+
+    Feed off (:func:`metrics_feed`): the ``engine_step`` health mark and
+    nothing else.  On: with tracing on (``correlation``, the id the live
+    ``engine.step`` span yielded, is then not 0), the spans of
+    :data:`STEP_SPANS` go under that id, inside that span; then
+    :func:`publish_step` with ``step_s`` (entry to the last statement),
+    the phase seconds summed over those same intervals, and the overlap
+    fraction: 1 less the share of ``step_s`` the host was blocked, on input
+    and the in-flight bound when compiled, in the gradient sync when eager.
+    ``wait_s``, the consumer wait a pre-staged pair carries
+    (``data/device.py``), happened between steps, outside every stamp, and
+    is the step's real input-blocked time, so it joins ``data_wait``,
+    ``step_s`` and the blocked time alike (examples/s must not read 2810
+    while the loop starves between steps).  Two intervals
+    have no span: under eager_async the time inside handle waits is
+    ``collective`` and the rest of the sync window, where the drain applies
+    updates, ``optimizer``; compiled, the hooks' time after the wait is
+    ``ps`` when the parameter-server plane is loaded (its traffic dispatches
+    from the step hooks).  ``x``, ``y``: the step's batch as its program
+    got it; ``numerics``: its sentinel stats; ``flops``: the program's
+    analytical FLOPs, where probed."""
+    if not metrics_feed():
+        health.note("engine_step")
+        return
+    spans = STEP_SPANS[mode]
+    if correlation:
+        offset = tracer.clock_offset()
+        for name, a, b in spans:
+            tracer.record(name, stamps[a] - offset, stamps[b] - offset,
+                          correlation)
+    phases = dict.fromkeys(PHASES, 0.0)
+    for name, a, b in spans:
+        phases[SPAN_PHASE[name]] += (stamps[b] - stamps[a]) / 1e9
+    phases["data_wait"] += wait_s
+    step_s = (stamps[_DONE] - stamps[_ENTRY]) / 1e9 + wait_s
+    rows = int(x.shape[0])
+    if mode == "compiled":
+        blocked_s = phases["data_wait"] + phases["collective"]
+        if obs_native.loaded("ps"):
+            phases["ps"] = (stamps[_DONE] - stamps[_SYNCED]) / 1e9
+    else:
+        if x.ndim > 1:          # rank-major (p, b, ...): p * b examples
+            rows *= int(x.shape[1])
+        if stamps[_BLOCKED_NS] is not None:
+            sync_wall_s = phases["collective"]
+            phases["collective"] = stamps[_BLOCKED_NS] / 1e9
+            phases["optimizer"] = max(
+                0.0, sync_wall_s - phases["collective"])
+        blocked_s = phases["collective"]
+    publish_step(
+        step_s=step_s, examples=_local_examples(rows),
+        staged_bytes=int(x.nbytes) + int(y.nbytes),
+        overlap_fraction=1.0 - blocked_s / max(step_s, 1e-12),
+        step=step, numerics=numerics, phases=phases)
+    if flops:
+        from . import numerics as numerics_mod
+
+        numerics_mod.publish_flops(flops, step_s)
+
+
 def publish_step(step_s: float, examples: int, staged_bytes: int,
                  overlap_fraction: float, step: Optional[int] = None,
                  registry=None, numerics: Optional[Dict[str, Any]] = None,
                  phases: Optional[Dict[str, float]] = None,
                  ) -> None:
-    """The engine's per-step live feed (``engine/sgdengine.py``): last
+    """The engine's per-step live feed (through :func:`engine_step`): last
     step time, examples/s, staged bytes, and the sync/dispatch overlap
     fraction as gauges, plus monotonic step/example counters a poller
     turns into rates.  This is the production feed the collective
@@ -840,8 +946,8 @@ def publish_step(step_s: float, examples: int, staged_bytes: int,
     optimizer / ps), published as
     ``tmpi_step_phase_seconds{phase=...}`` gauges — the per-phase feed
     a firing alert's ``phase="auto"`` attribution reads, so "step got
-    slower" becomes "data_wait regressed".  The engine derives them
-    from the timestamps it already takes under the feed gate."""
+    slower" becomes "data_wait regressed".  :func:`engine_step` derives
+    them from the stamps of the step's ``RunRecord``."""
     if registry is None:
         from .metrics import registry as registry_
         registry = registry_
@@ -894,19 +1000,16 @@ def publish_step(step_s: float, examples: int, staged_bytes: int,
             "collective-overlap health the overlap_collapse alert "
             "watches").set(min(1.0, max(
                 0.0, 1.0 - float(phases.get("collective", 0.0)) / denom)))
-    if step is not None:
-        registry.gauge(
-            "tmpi_engine_step", "most recent global step index").set(
-                float(step))
     health.note("engine_step")
 
 
-def publish_input(staged_bytes: int, stage_s: float, wait_s: float,
+def publish_input(staged_bytes: int, stage_s: float,
                   overlap_fraction: float, registry=None) -> None:
     """The data pipeline's per-batch live feed (``data/device.py``):
-    bytes staged, staging-call latency, consumer wait, and the running
-    input-overlap fraction — the acceptance surface ``bench.py``'s
-    non-resident mode and ``scripts/perf_gate.py``'s input series read.
+    bytes staged, staging-call latency and the running input-overlap
+    fraction (batches and consumer wait are ``StageStats``') — the
+    acceptance surface ``bench.py``'s non-resident mode and
+    ``scripts/perf_gate.py``'s input series read.
     Gated by the same :func:`metrics_feed` discipline as
     :func:`publish_step` (the stage publishes only when someone is — or
     could be — watching)."""
@@ -917,13 +1020,6 @@ def publish_input(staged_bytes: int, stage_s: float, wait_s: float,
         "tmpi_data_staged_bytes_total",
         "host bytes the input pipeline staged to device").inc(
             max(0.0, float(staged_bytes)))
-    registry.counter(
-        "tmpi_data_batches_total",
-        "batches the input pipeline delivered to the consumer").inc()
-    registry.counter(
-        "tmpi_data_wait_seconds_total",
-        "seconds the consumer blocked waiting on the input pipeline").inc(
-            max(0.0, float(wait_s)))
     registry.histogram(
         "tmpi_data_stage_seconds",
         "latency of one background staging call (host reshape/cast + "

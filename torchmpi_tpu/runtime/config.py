@@ -161,14 +161,6 @@ class Constants:
     # device_put may alias host memory (docs/data.md "Buffer reuse").
     data_reuse_host_buffers: bool = True
 
-    # Place an XLA optimization_barrier between the gradient computation
-    # and the optimizer update in the compiled engine step.  Off by
-    # default: it exists to A/B whether un-fusing the filter-gradient
-    # convs from the SGD multiply-subtract (the 9.6 ms/21% fusion group in
-    # the round-3 trace, BASELINE.md) helps or hurts on a given chip —
-    # measured, not assumed.
-    engine_update_barrier: bool = False
-
     # --- collective wire dtypes (the device-plane counterpart of the
     # hostcomm/PS wire-dtype taxonomy: bf16/f16/i8 wires on the host planes,
     # hostcomm.py:29-49 / ps.cpp Dtype enum) ---
@@ -502,7 +494,7 @@ class Constants:
     #                obs/serve.publish_step.
     #   "audit"    — sentinel plus the cross-rank parameter-fingerprint
     #                auditor every numerics_audit_interval steps (an
-    #                installed engine.numerics_auditor allgathers blake2b
+    #                Auditor on engine.step_boundaries allgathers blake2b
     #                digests over the hostcomm plane and binary-searches
     #                the leaf tree on mismatch).
     numerics_mode: str = _env("TORCHMPI_TPU_NUMERICS_MODE", "off", str)
@@ -575,8 +567,8 @@ class Constants:
     # measured knob flip, the same detect->decide->act pattern the
     # autoscaler proved for membership; all reads funnel through
     # retune.retune_config() — see docs/autotune.md "Retune controller") ---
-    # Arms the controller: with this off, engine.retune_controller stays
-    # None and the step boundary costs nothing.
+    # Arms the controller: with this off, nothing joins
+    # engine.step_boundaries and the step boundary costs nothing.
     retune_enabled: bool = _env_bool("TORCHMPI_TPU_RETUNE_ENABLED", False)
     # Step boundaries between controller polls; 1 = every boundary.  Each
     # poll is a few dict reads — the alert plane already did the watching.

@@ -299,7 +299,7 @@ def stop() -> None:
         from ..collectives import eager as _eager
         from ..collectives import pallas_ring as _pallas_ring
         from ..nn import _replica_stats_fn
-        from ..utils.data import _local_mesh_rows
+        from ..data.staging import _local_mesh_rows
 
         _eager.clear_cache()
         _pallas_ring.clear_cache()

@@ -92,6 +92,7 @@ __all__ = [
     "ResizeController",
     "ResizeRejected",
     "StateServer",
+    "engine_boundary",
     "enqueue_request",
     "maybe_rejoin",
     "pending_requests",
@@ -775,6 +776,41 @@ class ResizeController:
                          id=proposal["id"],
                          error=f"{type(e).__name__}: {e}"[:300])
         return COMMITTED
+
+
+def engine_boundary(controller, election=None) -> Callable[[Dict[str, Any]],
+                                                            None]:
+    """``controller`` as a step boundary of an ``AllReduceSGDEngine``:
+    ``engine.step_boundaries.append(engine_boundary(ctl))`` has every step
+    call :meth:`ResizeController.step_boundary` at the one place no member
+    is inside a collective.  :data:`DEPARTED` (this rank drained or was
+    evicted: its capacity is gone, not its process) sets
+    ``state["departed"]``; :data:`COMMITTED` sets ``state["resized"]`` to
+    the new epoch.  Either ends ``train()`` with the current parameters:
+    the engine's compiled world (mesh, shardings, donated buffers) is fixed
+    at construction and cannot follow a live change of world size, so the
+    elastic layer rebuilds it against the new membership (the fence
+    guarantees no collective was in flight).  :data:`ABORTED` changed
+    nothing: training goes on.
+
+    With ``election`` (an ``election.ElectionCoordinator``), a transport
+    fault at the boundary with a provably dead leader runs the unplanned
+    failover and ends the loop as a commit does; anything else re-raises
+    inside ``on_boundary_fault``.  Without it the fault propagates
+    untouched (the restart path)."""
+    def boundary(state: Dict[str, Any]) -> None:
+        try:
+            out = controller.step_boundary()
+        except TransportFailure as e:
+            if election is None:
+                raise
+            out = election.on_boundary_fault(e)
+        if out == DEPARTED:
+            state["departed"] = True
+        elif out == COMMITTED:
+            state["resized"] = controller.membership.epoch
+
+    return boundary
 
 
 # ----------------------------------------------------------------- joining
