@@ -148,11 +148,16 @@ def test_llama_flash_step_on_dp_tp(v5e, monkeypatch):
     # replay) and dh's after the scan.  Now three in a chunk (max, sum, and
     # the target logit with the chunk's dh: the same bytes a step), and dW is
     # still summed over dp once, after the scan, with the other gradients.
+    # The text counts an instruction once: `tiny`'s two layers are inlined,
+    # so each layer's four activation all-reduces over tp (16,384 bytes,
+    # attention and FFN, forward and backward) stands there itself, where a
+    # scan's body stood once whatever its trips (9 instructions, 246,916
+    # bytes), and the layers' dp sums ride with the embedding's.
     text = compiled.as_text()
     stats = topology.hlo_collective_stats(text)
     assert set(stats["counts"]) == {"all-reduce:f32"}
-    assert stats["total"] <= 11
-    assert sum(stats["operand_bytes"].values()) <= 255_364
+    assert stats["total"] <= 12
+    assert sum(stats["operand_bytes"].values()) <= 386_692
     in_chunk = [line for line in text.splitlines()
                 if " all-reduce(" in line and "head_loss" in line]
     assert len(in_chunk) == 3 and all("while/body" in line for line in in_chunk)
@@ -160,14 +165,19 @@ def test_llama_flash_step_on_dp_tp(v5e, monkeypatch):
 
 def test_olmoe_adamw_step_at_published_widths(v5e, monkeypatch):
     """The benchmark's `olmoe-1b-7b-l4096` step on one chip: OLMoE-1B-7B at
-    its published widths, 2 of 16 layers through the layer scan, 4 x 4096
-    tokens, flash, remat "dots", AdamW with bfloat16 moments, weights and
-    state donated.  It fits the chip, and holds the two flash kernels and
-    the grouped matmuls of the sorted dispatch: `gmm` for gate, up and down
-    forward, gate and up again in the layer's recomputation (nothing needs
-    the down product's output again) and the three gradients of the rows
-    (8), `tgmm` for the three gradients of the weights."""
+    its published widths, 2 of 16 layers, inlined (`llama.apply` scans no
+    stack this shallow), 4 x 4096 tokens, flash, remat "dots", AdamW with
+    bfloat16 moments, weights and state donated.  It fits the chip, and
+    holds, for each layer, the two flash kernels and the grouped matmuls of
+    the sorted dispatch: `gmm` for gate, up and down forward, gate and up
+    again in the layer's recomputation (nothing needs the down product's
+    output again) and the three gradients of the rows (8), `tgmm` for the
+    three gradients of the weights.  What the scan cost is not there: no
+    layer's expert weights copied out of the stack by a `dynamic-slice`, no
+    gradient written into it by a `dynamic-update-slice` (22.4 ms of a
+    316.65 ms step and 3.65 GB of the plan: PERF_LEDGER.jsonl, PR 28)."""
     import dataclasses
+    import re
 
     import optax
     from jax.sharding import Mesh
@@ -176,6 +186,7 @@ def test_olmoe_adamw_step_at_published_widths(v5e, monkeypatch):
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = dataclasses.replace(llama.olmoe_1b_7b(), n_layers=2)
+    assert cfg.n_layers <= llama._INLINE_MAX_LAYERS
     one = SingleDeviceSharding(v5e[0])
     place = lambda tree: jax.tree.map(
         lambda a: _sds(a.shape, a.dtype, one), tree)
@@ -190,18 +201,29 @@ def test_olmoe_adamw_step_at_published_widths(v5e, monkeypatch):
                                  remat="dots", loss_chunk=512)
     tokens = _sds((4, 4096), jnp.int32, one)
     compiled = step.lower(place(params), place(state), tokens, tokens).compile()
-    kernels = [line for line in compiled.as_text().splitlines()
+    text = compiled.as_text().splitlines()
+    kernels = [line for line in text
                if 'custom_call_target="tpu_custom_call"' in line]
-    named = lambda what: sum(what in line for line in kernels)
-    assert (named("flash_fwd"), named("flash_bwd")) == (1, 1)
-    assert named("moe.experts/jit(gmm)") == 8
-    assert named("moe.experts/jit(tgmm)") == 3
-    assert len(kernels) == 13
+    # (inlined, a forward kernel's scope reads `jvp(moe.experts)/jit(gmm)`)
+    named = lambda what: sum(bool(re.search(what, line)) for line in kernels)
+    assert (named("flash_fwd"), named("flash_bwd")) == (2, 2)
+    assert named(r"moe\.experts\)?/jit\(gmm\)") == 16
+    assert named(r"moe\.experts\)?/jit\(tgmm\)") == 6
+    assert len(kernels) == 26
+    # No slice of the stacked expert weights, (2, 64, 2048, 1024) and its
+    # transpose, cut or written at an index the program computes.
+    expert = re.compile(r"bf16\[(2,)?64,(2048,1024|1024,2048)\]")
+    assert not [line for line in text
+                if re.search(r"dynamic-(update-)?slice", line)
+                and expert.search(line)]
     # The head: three products over the vocabulary in one scan body (logits,
     # dh, dW), and no replay of `h_c @ head` in a backward scan.
-    head = [line for line in compiled.as_text().splitlines()
+    head = [line for line in text
             if "head_loss" in line and " convolution(" in line]
     assert len(head) == 3 and not any("rematted" in line for line in head)
+    assert all("jvp(head_loss)/while/body" in line for line in head)
+    assert sum('head_loss)/while"' in line and " while(" in line
+               for line in text) == 1
     assert sum("bf16[4,512,50304]" in line.split(" convolution(")[0]
                for line in head) == 1
     m = compiled.memory_analysis()
@@ -210,7 +232,7 @@ def test_olmoe_adamw_step_at_published_widths(v5e, monkeypatch):
     # weights and both moments donated: all but the tokens and a few norms
     assert m.argument_size_in_bytes - m.alias_size_in_bytes < 1e6
     assert m.alias_size_in_bytes > 3 * 2 * 1_045_000_000
-    assert 9e9 < held < 16e9
+    assert 9e9 < held < 13e9
 
 
 def test_olmoe_step_on_dp_tp_takes_the_compilers_grouped_matmul(monkeypatch):

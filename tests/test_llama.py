@@ -2,6 +2,7 @@
 ring-attention path equivalence, and a dp x tp train step on the virtual mesh
 (BASELINE config 5 shrunk to 8 CPU devices)."""
 
+import dataclasses
 import re
 
 import numpy as np
@@ -84,27 +85,61 @@ class TestForward:
         assert logits.dtype == jnp.float32
         assert np.all(np.isfinite(np.asarray(logits)))
 
-    def test_unrolled_matches_scan(self):
-        """layer_loop='unroll' computes the same function as the scan
-        (forward and gradients) — only the loop form differs."""
-        cfg = llama.tiny()
+    @pytest.mark.parametrize("cfg", [
+        llama.tiny(),
+        dataclasses.replace(llama.moe_tiny(), capacity_factor=None,
+                            moe_z_coef=1e-3),
+    ], ids=["dense", "moe-sorted-dropless"])
+    def test_unrolled_matches_scan(self, cfg):
+        """layer_loop='unroll' computes the same function as the scan:
+        the logits, the loss and every gradient leaf — only the loop form
+        differs.  The sorted dropless dispatch (``capacity_factor=None``) is
+        the path the benchmark's OLMoE cell inlines."""
         params = llama.init(jax.random.PRNGKey(0), cfg)
         tokens, targets = _data(cfg)
-        a = llama.apply(cfg, params, tokens)
+        a = llama.apply(cfg, params, tokens, layer_loop="scan")
         b = llama.apply(cfg, params, tokens, layer_loop="unroll")
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-5, atol=1e-5)
-        for loop in ("scan", "unroll"):
-            loss_fn = llama.make_loss_fn(cfg, layer_loop=loop)
-            loss, grads = jax.value_and_grad(loss_fn)(params,
-                                                      (tokens, targets))
-            if loop == "scan":
-                want = (float(loss),
-                        np.asarray(jax.tree.leaves(grads)[0]))
-            else:
-                got = (float(loss), np.asarray(jax.tree.leaves(grads)[0]))
-        assert abs(want[0] - got[0]) < 1e-5
-        np.testing.assert_allclose(want[1], got[1], rtol=1e-4, atol=1e-5)
+        scan, unroll = (
+            jax.value_and_grad(llama.make_loss_fn(cfg, layer_loop=loop))(
+                params, (tokens, targets)) for loop in ("scan", "unroll"))
+        assert abs(float(scan[0]) - float(unroll[0])) < 1e-5
+        want, got = (jax.tree_util.tree_leaves_with_path(g)
+                     for g in (scan[1], unroll[1]))
+        assert [k for k, _ in want] == [k for k, _ in got]
+        for (key, w), (_, g) in zip(want, got):
+            assert np.any(np.asarray(w) != 0), jax.tree_util.keystr(key)
+            np.testing.assert_allclose(
+                np.asarray(w), np.asarray(g), rtol=1e-4, atol=1e-5,
+                err_msg=jax.tree_util.keystr(key))
+
+    @pytest.mark.parametrize("cfg,scanned", [
+        (llama.tiny(), False),
+        (llama.moe_tiny(), False),
+        (dataclasses.replace(llama.tiny(),
+                             n_layers=llama._INLINE_MAX_LAYERS), False),
+        (dataclasses.replace(llama.tiny(),
+                             n_layers=llama._INLINE_MAX_LAYERS + 1), True),
+    ], ids=["tiny", "moe_tiny", "at-the-bound", "one-over-the-bound"])
+    def test_default_loop_form_follows_depth(self, cfg, scanned):
+        """Left to itself :func:`llama.apply` inlines a stack of at most
+        ``_INLINE_MAX_LAYERS`` layers and scans a deeper one: the jaxpr
+        holds a ``scan`` over the ``n_layers`` stacked layers, or none."""
+        params = jax.eval_shape(
+            lambda: llama.init(jax.random.PRNGKey(0), cfg))
+        tokens = jax.ShapeDtypeStruct((2, 16), jnp.int32)
+
+        def layer_scans(**how):
+            jaxpr = jax.make_jaxpr(
+                lambda p, t: llama.apply(cfg, p, t, **how))(params, tokens)
+            return [e for e in jaxpr.eqns if e.primitive.name == "scan"
+                    and e.params["length"] == cfg.n_layers]
+
+        assert 2 <= cfg.n_layers
+        assert len(layer_scans()) == (1 if scanned else 0)
+        assert len(layer_scans(layer_loop="scan")) == 1
+        assert len(layer_scans(layer_loop="unroll")) == 0
 
 
 class TestGenerate:

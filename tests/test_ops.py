@@ -208,9 +208,11 @@ class TestFlashBackward:
                                             ("full", 2)])
 def test_train_step_forms_scores_once_each_way(remat, forwards):
     """The step of ``make_train_step(attn="flash")`` holds one forward and
-    one backward kernel: under ``remat="dots"`` the layer's checkpoint keeps
-    the kernel's ``o`` and ``lse`` (``flash_attention.RESIDUAL_NAMES``), so
-    the forward is not replayed; ``"full"`` keeps nothing and replays it."""
+    one backward kernel a layer (the two layers of ``moe_tiny`` are inlined,
+    so the step's jaxpr holds each layer's own): under ``remat="dots"`` the
+    layer's checkpoint keeps the kernel's ``o`` and ``lse``
+    (``flash_attention.RESIDUAL_NAMES``), so the forward is not replayed;
+    ``"full"`` keeps nothing and replays it."""
     from torchmpi_tpu.models import llama
     from torchmpi_tpu.parallel import mesh as pmesh
 
@@ -221,4 +223,6 @@ def test_train_step_forms_scores_once_each_way(remat, forwards):
     tokens = jax.ShapeDtypeStruct((2, 64), jnp.int32)
     calls = _pallas_calls(
         jax.make_jaxpr(step)(params, None, tokens, tokens).jaxpr)
-    assert sorted(calls) == ["flash_bwd"] + ["flash_fwd"] * forwards
+    assert cfg.n_layers <= llama._INLINE_MAX_LAYERS
+    assert sorted(calls) == (["flash_bwd"] * cfg.n_layers
+                             + ["flash_fwd"] * forwards * cfg.n_layers)

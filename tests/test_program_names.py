@@ -26,9 +26,13 @@ def _op_names(lowered):
 def _carried(names, scope, inside=""):
     """Whether some operation runs under ``scope`` (as a path component,
     bare or inside ``jvp(...)``, ``vmap(...)``, ``transpose(...)``), and
-    under ``inside`` too where that is given."""
+    under ``inside`` too where that is given.  A ``/`` of ``inside`` also
+    matches the ``)/`` that closes a ``jvp(scope)``: a forward operation of
+    an inlined layer reads ``jvp(attn)/flash_fwd``, of a scanned one
+    ``jvp()/while/body/closed_call/attn/flash_fwd``."""
     part = re.compile(r"(^|[/(])" + re.escape(scope) + r"([/)]|$)")
-    return any(part.search(n) and inside in n for n in names)
+    within = re.compile(re.escape(inside).replace("/", r"\)?/"))
+    return any(part.search(n) and within.search(n) for n in names)
 
 
 MOE = ("moe.router", "moe.dispatch", "moe.experts", "moe.combine")
@@ -41,20 +45,28 @@ def _olmoe_tiny():
                                moe_z_coef=1e-3, qk_norm=True)
 
 
+@pytest.mark.parametrize("depth", [2, llama._INLINE_MAX_LAYERS + 1],
+                         ids=["inlined", "scanned"])
 @pytest.mark.parametrize("make_cfg,ffn,absent", [
     (llama.moe_tiny, MOE, ("ffn", "attn.qk_norm")),
     (llama.tiny, ("ffn",), MOE + ("attn.qk_norm",)),
     (_olmoe_tiny, MOE + ("attn.qk_norm",), ("ffn",))],
     ids=["mixtral", "dense", "olmoe"])
 def test_llama_train_step_carries_scope_and_kernel_names(make_cfg, ffn,
-                                                         absent):
-    cfg = make_cfg()
+                                                         absent, depth):
+    """The names are the same whichever form `llama.apply` gives the layer
+    loop (inlined to `_INLINE_MAX_LAYERS` layers, scanned beyond)."""
+    cfg = dataclasses.replace(make_cfg(), n_layers=depth)
     mesh = pmesh.make_mesh({"dp": 1}, devices=jax.devices()[:1])
     step = llama.make_train_step(cfg, mesh, attn="flash", remat="dots",
                                  loss_chunk=32)
     params = jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0), cfg))
     tokens = jax.ShapeDtypeStruct((2, 64), jnp.int32)
     names = _op_names(step.lower(params, None, tokens, tokens))
+    # (inlined, the layers' forward operations are the step's own; scanned,
+    # the body is a function of its own and its names start at the scope)
+    inlined = any(n.startswith("jit(step)/jvp(attn)/") for n in names)
+    assert inlined == (depth <= llama._INLINE_MAX_LAYERS)
     for scope in ("embed", "attn", "head_loss", "optimizer") + ffn:
         assert _carried(names, scope), scope
     for scope in absent:
@@ -80,11 +92,12 @@ def test_llama_train_step_carries_scope_and_kernel_names(make_cfg, ffn,
         # gradients) are under moe.experts: forward, backward, and in the
         # layer's recomputation, since the kernel is no dot the policy saves.
         assert _carried(names, "attn.qk_norm", inside="attn/attn.qk_norm")
-        for kernel, inside in (("gmm", "jit(step)/"), ("gmm", "checkpoint/"),
-                               ("tgmm", "checkpoint/"),
+        for kernel, inside in (("gmm", "checkpoint/"), ("tgmm", "checkpoint/"),
                                ("gmm", "rematted_computation/")):
             assert _carried(names, kernel,
                             inside=inside + "moe.experts/jit("), (kernel, inside)
+        assert _carried([n for n in names if "checkpoint" not in n], "gmm",
+                        inside="moe.experts/jit(")
         assert not _carried(names, "tgmm", inside="rematted_computation")
 
 

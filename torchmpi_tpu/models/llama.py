@@ -8,7 +8,9 @@ machinery (SURVEY.md §5.7, §7 item 7-8).  TPU-first design:
 
 * layer parameters are **stacked** (leading ``n_layers`` axis) and the
   forward is a ``lax.scan`` over layers — one compiled block, fast compiles
-  at depth 32+, and the natural substrate for pipeline stacking;
+  at depth 32+, and the natural substrate for pipeline stacking; a shallow
+  stack (a chip's slice of an MoE model) is inlined instead, which spares
+  the scan's copies (:func:`apply`, ``layer_loop``);
 * :func:`param_specs` returns the PartitionSpec pytree for Megatron-style
   tensor parallelism (qkv/gate/up column-sharded, o/down row-sharded) —
   under pjit GSPMD inserts exactly the one-psum-per-block collectives the
@@ -26,7 +28,7 @@ machinery (SURVEY.md §5.7, §7 item 7-8).  TPU-first design:
 * mixture-of-experts FFN (``Config(n_experts=E, expert_top_k=k)``,
   Mixtral-style — :func:`mixtral_8x7b`): GShard dispatch/combine einsums
   with expert weights sharded over ``ep`` (:func:`_moe_ffn`), Switch
-  load-balance aux loss through the layer scan, dropless decode routing.
+  load-balance aux loss through the layer loop, dropless decode routing.
 
 Compute dtype is configurable (bfloat16 for TPU, float32 for CPU tests);
 norms, softmax, and the loss run in f32.
@@ -915,10 +917,22 @@ def expert_unit_counts(cfg: Config, params: Params, tokens: jax.Array,
     return lax.scan(layer, params["embed"][tokens], params["layers"])[1]
 
 
+# The deepest stack :func:`apply` inlines; a deeper one it scans.  Scanning
+# costs copies every step in proportion to the depth, inlining compile time
+# in proportion to it.  Measured on a v5e, scanned -> inlined (chip runs of
+# PR 29, PERF.md section 6): OLMoE widths, 2 layers, step 316.4 -> 301.2 ms
+# and compile 24.8 -> 23.2 s, 4 layers 296.2 -> 258.1 ms and 26.8 -> 25.8 s;
+# dense Llama-3-8B widths, 4 layers 400.6 -> 369.1 ms and 6.8 -> 12.5 s, 8
+# layers 359.1 -> 348.3 ms and 7.0 -> 21.0 s.  Up to 4 layers the step wins
+# 5-13% for at most 6 s of compile; at 8 it wins 3% for 14 s, and a dense
+# layer inlined adds 1.4-1.8 s: a minute at depth 32.
+_INLINE_MAX_LAYERS = 4
+
+
 def apply(cfg: Config, params: Params, tokens: jax.Array,
           mesh: Optional[Mesh] = None, attn: str = "full",
           remat: str = "none", return_hidden: bool = False,
-          return_aux: bool = False, layer_loop: str = "scan",
+          return_aux: bool = False, layer_loop: Optional[str] = None,
           positions: Optional[jax.Array] = None) -> jax.Array:
     """Forward: tokens (B, L) int32 -> logits (B, L, vocab) f32, or the
     final hidden states (B, L, D) in compute dtype when ``return_hidden``
@@ -933,7 +947,7 @@ def apply(cfg: Config, params: Params, tokens: jax.Array,
     ``attn='ring'``); without it the model runs unconstrained (single-device
     or auto-sharded).
 
-    ``remat`` is the rematerialization policy applied to each scanned layer
+    ``remat`` is the rematerialization policy applied to each layer
     (gradient checkpointing — the HBM/FLOPs trade SURVEY.md §7 prescribes
     for 8B-scale):
       * ``"none"``  — save all residuals (small models),
@@ -945,13 +959,25 @@ def apply(cfg: Config, params: Params, tokens: jax.Array,
       * ``"full"``  — save only layer boundaries, recompute everything
         (longest contexts; backward recomputes each layer's forward).
 
-    ``layer_loop``: ``"scan"`` (default — one compiled block, fast
-    compiles at 32 layers) or ``"unroll"`` — inlines the layers so the
-    backward's saved residuals stay plain buffers instead of being
-    dynamic-update-sliced into stacked (n_layers, ...) arrays (the copy
-    tax measured on ViT: 23% of the step; see BASELINE.md round 3).
-    Worth trying for shallow slices and short-L configs; at deep
-    configs the compile-time trade usually favours scan.
+    ``layer_loop`` is the form of the loop over the stacked layers.  Left
+    ``None``, the code chooses from the depth it is given: a stack of at
+    most ``_INLINE_MAX_LAYERS`` layers is inlined (``"unroll"``), a deeper
+    one goes through ``lax.scan`` (``"scan"``: one compiled block, and a
+    compile time that does not grow with the depth).  The scan pays in
+    copies, every layer, every step: a Mosaic kernel (the grouped matmuls of
+    the sorted dispatch, flash) cannot fuse the ``dynamic-slice`` of its
+    layer's weights as an XLA convolution does, so each is first copied out
+    of the stack, forward and again backward, and the layer's gradients and
+    saved residuals are ``dynamic-update-slice``d into stacked buffers the
+    loop carries.  On OLMoE's two layers at published widths
+    (``olmoe-1b-7b-l4096``) those were ``fusion:_dynamic-slice_bitcast``,
+    12.4 ms, and ``fusion:_bitcast_dynamic-update-slice``, 10.0 ms, of a
+    316.65 ms step (PERF_LEDGER.jsonl, PR 28), and 3.65 GB of the plan;
+    on ViT 23% of a step (BASELINE.md round 3).  Inlined, a layer's slice
+    of a weight is a static slice, its gradient an operand of the update,
+    its residuals plain buffers.  Both forms compute the same function
+    (``tests/test_llama.py::test_unrolled_matches_scan``), and both names
+    stay accepted for that test's sake.
     """
     B, L = tokens.shape
     scale = 1.0 / np.sqrt(cfg.head_dim)
@@ -993,6 +1019,8 @@ def apply(cfg: Config, params: Params, tokens: jax.Array,
     aux0 = jnp.zeros((2,) if cfg.n_experts and cfg.moe_z_coef else (),
                      jnp.float32)
 
+    if layer_loop is None:
+        layer_loop = "unroll" if cfg.n_layers <= _INLINE_MAX_LAYERS else "scan"
     if layer_loop == "unroll":
         carry = (h, aux0)
         for i in range(cfg.n_layers):
@@ -1002,7 +1030,7 @@ def apply(cfg: Config, params: Params, tokens: jax.Array,
     elif layer_loop == "scan":
         (h, aux), _ = lax.scan(layer, (h, aux0), params["layers"])
     else:
-        raise ValueError("layer_loop must be 'scan' or 'unroll'")
+        raise ValueError("layer_loop must be 'scan', 'unroll' or None")
     aux = aux / cfg.n_layers
     h = rms_norm(h, params["norm"], cfg.norm_eps)
     out = h if return_hidden else (h @ params["head"]).astype(jnp.float32)
@@ -1011,7 +1039,7 @@ def apply(cfg: Config, params: Params, tokens: jax.Array,
 
 def make_loss_fn(cfg: Config, mesh: Optional[Mesh] = None, attn: str = "full",
                  remat: str = "none", loss_chunk: int = 0,
-                 layer_loop: str = "scan"):
+                 layer_loop: Optional[str] = None):
     """Next-token cross-entropy: ``loss_fn(params, (tokens, targets))`` —
     the engine contract; targets = tokens shifted by the caller.
 
@@ -1022,6 +1050,7 @@ def make_loss_fn(cfg: Config, mesh: Optional[Mesh] = None, attn: str = "full",
     differentiation each chunk takes the head's gradients in the pass that
     forms its logits (:func:`_chunked_nll`), so the peak holds there too and
     no chunk is formed twice.  ``L`` must be divisible by ``loss_chunk``.
+    ``layer_loop`` as in :func:`apply`: left ``None``, the depth decides.
     """
 
     def loss_fn(params: Params, batch: Tuple[jax.Array, jax.Array]) -> jax.Array:
