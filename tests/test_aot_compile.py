@@ -254,3 +254,81 @@ def test_olmoe_step_on_dp_tp_takes_the_compilers_grouped_matmul(monkeypatch):
         *args).compile().as_text()
     assert text.count('op_name="ragged-dot-none"') == 11
     assert "jit(gmm)" not in text
+
+
+def test_ouro_adamw_step_at_published_widths(v5e, monkeypatch):
+    """The benchmark's `ouro-2.6b-b2-l4096` step on one chip: Ouro-2.6B at
+    its published widths, 8 of 48 layers run four times (32 layer
+    applications: the layer scan inside, the recurrent steps inlined round
+    it), 2 x 4096 tokens, flash, the configuration file's remat (three steps
+    `"full"`, one `"dots"`), the four heads through one chunked call, AdamW
+    with float32 moments, weights and state donated.  `benchmark/sizing.py`
+    knows no function for this runner, so this is the cell's plan: under the
+    chip's 15.75 GiB, where `"dots"` at every step is refused."""
+    import dataclasses
+    import json
+    import os
+
+    import optax
+    from jax.sharding import Mesh
+
+    from torchmpi_tpu.models import llama
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "ouro-2.6b.json")) as fh:
+        run = json.load(fh)["run"]
+    cfg = dataclasses.replace(llama.ouro_2_6b(), n_layers=8)
+    assert cfg.n_layers > llama._INLINE_MAX_LAYERS and cfg.ut_steps == 4
+    one = SingleDeviceSharding(v5e[0])
+    place = lambda tree: jax.tree.map(
+        lambda a: _sds(a.shape, a.dtype, one), tree)
+    params = jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0), cfg,
+                                               dtype=jnp.bfloat16))
+    assert sum(a.size for a in jax.tree.leaves(params)) == 612_438_017
+    adamw = optax.adamw(3e-4, b1=0.9, b2=0.95, weight_decay=0.1)
+    f32 = lambda tree: jax.tree.map(lambda a: a.astype(jnp.float32), tree)
+
+    def update(grads, state, params):       # moments float32, as the runner
+        updates, state = adamw.update(f32(grads), state, f32(params))
+        return jax.tree.map(lambda u, p: u.astype(p.dtype), updates,
+                            params), state
+
+    optimizer = optax.GradientTransformation(lambda p: adamw.init(f32(p)),
+                                             update)
+    state = jax.eval_shape(optimizer.init, params)
+    mesh = Mesh([v5e[0]], ("dp",))
+    tokens = _sds((2, 4096), jnp.int32, one)
+
+    def compiled(remat):
+        step = llama.make_train_step(cfg, mesh, attn="flash",
+                                     optimizer=optimizer, remat=remat,
+                                     loss_chunk=run["loss_chunk"])
+        return step.lower(place(params), place(state), tokens, tokens).compile()
+
+    program = compiled(run["remat"])
+    text = program.as_text().splitlines()
+    kernels = [line for line in text
+               if 'custom_call_target="tpu_custom_call"' in line]
+    # One forward and one backward kernel in each recurrent step's scan body,
+    # and the forward again where the step recomputes its layers ("full").
+    full = sum(r == "full" for r in run["remat"])
+    assert sum("flash_fwd" in line for line in kernels) == 4 + full
+    assert sum("flash_bwd" in line for line in kernels) == 4
+    assert sum("flash_fwd" in line and "rematted_computation" in line
+               for line in kernels) == full
+    # The head: three products over the vocabulary in one scan body, on the
+    # 4 x 2 rows of all the recurrent steps' states, none replayed.
+    head = [line for line in text
+            if "head_loss" in line and " convolution(" in line]
+    assert len(head) == 3 and not any("rematted" in line for line in head)
+    assert sum("bf16[8,512,49152]" in line.split(" convolution(")[0]
+               for line in head) == 1
+    m = program.memory_analysis()
+    held = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    # weights and both float32 moments donated: 10 bytes a parameter
+    assert m.alias_size_in_bytes > 10 * 612_000_000
+    assert 0.25 * 16e9 < held < 15.75 * 2**30
+    with pytest.raises(Exception, match="hbm"):
+        compiled("dots")

@@ -101,6 +101,44 @@ def test_llama_train_step_carries_scope_and_kernel_names(make_cfg, ffn,
         assert not _carried(names, "tgmm", inside="rematted_computation")
 
 
+def _looped_tiny():
+    """One stack run three times, sandwich norms, an exit gate: at `tiny`'s
+    sizes."""
+    return dataclasses.replace(llama.tiny(), n_kv_heads=4, ut_steps=3,
+                               sandwich_norm=True, exit_gate=True,
+                               exit_entropy_coef=0.1)
+
+
+@pytest.mark.parametrize("depth", [2, llama._INLINE_MAX_LAYERS + 1],
+                         ids=["inlined", "scanned"])
+def test_looped_train_step_carries_scope_names(depth):
+    """A looped model's step: the per-step final norm and the exit gate have
+    scopes of their own, forward and backward; the stack's names are the
+    dense model's, and a step under `"full"` recomputes its forward kernel
+    where a step under `"dots"` does not."""
+    cfg = dataclasses.replace(_looped_tiny(), n_layers=depth)
+    mesh = pmesh.make_mesh({"dp": 1}, devices=jax.devices()[:1])
+    step = llama.make_train_step(cfg, mesh, attn="flash",
+                                 remat=("full", "full", "dots"), loss_chunk=32)
+    params = jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0), cfg))
+    tokens = jax.ShapeDtypeStruct((2, 64), jnp.int32)
+    names = _op_names(step.lower(params, None, tokens, tokens))
+    for scope in ("embed", "attn", "ffn", "final_norm", "exit_gate",
+                  "head_loss", "optimizer"):
+        assert _carried(names, scope), scope
+    for scope in MOE + ("attn.qk_norm",):
+        assert not _carried(names, scope), scope
+    for scope in ("final_norm", "exit_gate", "head_loss"):
+        assert _carried(names, scope, inside="transpose(jvp(" + scope), scope
+    assert _carried(names, "ffn", inside="rematted_computation/ffn")
+    assert not _carried(names, "optimizer", inside="transpose(")
+    # the gate's product over all steps' states is the gate's, not the head's
+    assert _carried(names, "exit_gate", inside="exit_gate)/tbld,d->tbl")
+    assert not _carried(names, "head_loss", inside="tbld,d->tbl")
+    assert _carried(names, "flash_fwd", inside="rematted_computation")
+    assert _carried(names, "flash_bwd", inside="checkpoint/attn/")
+
+
 RESNET = ("stem", "conv", "bn", "residual", "pool", "fc_loss")
 
 

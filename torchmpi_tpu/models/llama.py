@@ -10,7 +10,9 @@ machinery (SURVEY.md §5.7, §7 item 7-8).  TPU-first design:
   forward is a ``lax.scan`` over layers — one compiled block, fast compiles
   at depth 32+, and the natural substrate for pipeline stacking; a shallow
   stack (a chip's slice of an MoE model) is inlined instead, which spares
-  the scan's copies (:func:`apply`, ``layer_loop``);
+  the scan's copies (:func:`apply`, ``layer_loop``); a looped model
+  (``Config(ut_steps=T)``, Ouro-style — :func:`ouro_2_6b`) runs the one
+  stack T times with shared weights, a head and an exit gate at every step;
 * :func:`param_specs` returns the PartitionSpec pytree for Megatron-style
   tensor parallelism (qkv/gate/up column-sharded, o/down row-sharded) —
   under pjit GSPMD inserts exactly the one-psum-per-block collectives the
@@ -38,7 +40,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
@@ -53,6 +55,8 @@ from ._common import dense_init as _dense, mesh_spec as _mesh_spec, \
     num_params, shard_by_specs, stack_dense
 
 Params = Dict[str, Any]
+# A remat policy's name, or one for each recurrent step of a looped model.
+Remat = Union[str, Sequence[str]]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -81,6 +85,22 @@ class Config:
     # RMSNorm over the whole q and k projections before the heads are split
     # (OLMoE): two more leaves a layer, ``q_norm`` and ``k_norm``.
     qk_norm: bool = False
+    # A looped ("universal transformer") model (Ouro, :func:`ouro_2_6b`):
+    # the one stack of layers runs ``ut_steps`` times with the same weights,
+    # the final norm after every pass, so the normed state is what the next
+    # pass reads, and the head reads every pass's state.
+    ut_steps: int = 1
+    # Sandwich normalisation: an RMSNorm on each branch's output too, before
+    # the residual add; two more leaves a layer, ``attn_post_norm`` and
+    # ``mlp_post_norm``.
+    sandwich_norm: bool = False
+    # An exit gate at every recurrent step, ``sigmoid(h @ w + b)``, one number
+    # a token: a Linear(d_model -> 1) with bias, shared by the steps, the
+    # leaves ``gate_w`` (d_model,) and ``gate_b`` (1,); and the expected-exit
+    # loss of :func:`make_loss_fn`, whose entropy term has the weight
+    # ``exit_entropy_coef``.
+    exit_gate: bool = False
+    exit_entropy_coef: float = 0.0
 
     @property
     def head_dim(self) -> int:
@@ -89,6 +109,7 @@ class Config:
     def __post_init__(self):
         assert self.d_model % self.n_heads == 0
         assert self.n_heads % self.n_kv_heads == 0
+        assert self.ut_steps >= 1
         if self.n_experts:
             assert 1 <= self.expert_top_k <= self.n_experts
             assert self.capacity_factor is None or self.capacity_factor > 0
@@ -115,6 +136,17 @@ def olmoe_1b_7b() -> Config:
                   n_kv_heads=16, d_ff=1024, max_seq=4096, rope_theta=1e4,
                   n_experts=64, expert_top_k=8, capacity_factor=None,
                   moe_renormalize=False, moe_z_coef=1e-3, qk_norm=True)
+
+
+def ouro_2_6b() -> Config:
+    """Ouro-2.6B geometry ("Scaling Latent Reasoning via Looped Language
+    Models", 2025): 48 dense SwiGLU layers run four times with shared
+    weights, sandwich norms, a head and an exit gate at every recurrent step,
+    trained on the expected-exit loss with an entropy weight of 0.1."""
+    return Config(vocab=49152, d_model=2048, n_layers=48, n_heads=16,
+                  n_kv_heads=16, d_ff=5632, max_seq=65536, rope_theta=1e6,
+                  norm_eps=1e-6, ut_steps=4, sandwich_norm=True,
+                  exit_gate=True, exit_entropy_coef=0.1)
 
 
 def tiny(vocab: int = 256, seq: int = 64) -> Config:
@@ -170,6 +202,18 @@ def init(rng: jax.Array, cfg: Config, dtype=jnp.float32) -> Params:
     if cfg.qk_norm:
         qk = {"q_norm": jnp.ones((cfg.n_layers, H * hd), jnp.float32),
               "k_norm": jnp.ones((cfg.n_layers, KV * hd), jnp.float32)}
+    post = {}
+    if cfg.sandwich_norm:
+        post = {"attn_post_norm": jnp.ones((cfg.n_layers, cfg.d_model),
+                                           jnp.float32),
+                "mlp_post_norm": jnp.ones((cfg.n_layers, cfg.d_model),
+                                          jnp.float32)}
+    gate = {}
+    if cfg.exit_gate:
+        # A key of its own, folded in: the nine above stay what they were.
+        gate = {"gate_w": _dense(jax.random.fold_in(rng, 9), cfg.d_model, 1,
+                                 dtype)[:, 0],
+                "gate_b": jnp.zeros((1,), dtype)}
 
     return {
         "embed": (jax.random.normal(keys[0], (cfg.vocab, cfg.d_model), jnp.float32)
@@ -182,10 +226,12 @@ def init(rng: jax.Array, cfg: Config, dtype=jnp.float32) -> Params:
             "wo": stack(keys[4], H * hd, cfg.d_model),
             "mlp_norm": jnp.ones((cfg.n_layers, cfg.d_model), jnp.float32),
             **qk,
+            **post,
             **ffn,
         },
         "norm": jnp.ones((cfg.d_model,), jnp.float32),
         "head": _dense(keys[8], cfg.d_model, cfg.vocab, dtype),
+        **gate,
     }
 
 
@@ -209,6 +255,9 @@ def param_specs(cfg: Config) -> Params:
     # projection's columns and GSPMD sums the squares over tp.
     qk = ({"q_norm": P(None, AXIS_TP), "k_norm": P(None, AXIS_TP)}
           if cfg.qk_norm else {})
+    post = ({"attn_post_norm": P(None, None), "mlp_post_norm": P(None, None)}
+            if cfg.sandwich_norm else {})
+    gate = ({"gate_w": P(None), "gate_b": P(None)} if cfg.exit_gate else {})
     return {
         "embed": P(None, None),
         "layers": {
@@ -216,10 +265,12 @@ def param_specs(cfg: Config) -> Params:
             "wq": col, "wk": col, "wv": col, "wo": row,
             "mlp_norm": P(None, None),
             **qk,
+            **post,
             **ffn,
         },
         "norm": P(None),
         "head": P(None, AXIS_TP),
+        **gate,
     }
 
 
@@ -652,23 +703,26 @@ def _attention_block(cfg: Config, lp: Params, h: jax.Array,
                      constrain: Callable = lambda x: x,
                      with_kv: bool = False):
     """The attention half of a decoder block: ``h`` plus the attention of
-    its pre-norm; with ``with_kv`` also the (pre-repeat, native-KV-head) K/V
-    projections."""
+    its pre-norm (under ``cfg.sandwich_norm`` the branch's output is normed
+    too, before the add); with ``with_kv`` also the (pre-repeat,
+    native-KV-head) K/V projections."""
     B, L, _ = h.shape
     hd, H, KV = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
     # Names in the device program (docs/observability.md): ``attn`` (the
     # projections, ``attn.qk_norm``, rope, the attention itself, the output
     # projection), ``moe.router``/``moe.dispatch``/``moe.experts``/
-    # ``moe.combine`` or ``ffn``, ``embed``, ``head_loss``, ``optimizer``.
-    # Metadata only.
+    # ``moe.combine`` or ``ffn``, ``embed``, ``final_norm``, ``exit_gate``,
+    # ``head_loss``, ``optimizer``.  Metadata only.
     with jax.named_scope("attn"):
         x = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
         q, k = _qk_norm(cfg, lp, x @ lp["wq"], x @ lp["wk"])
         q = rope(q.reshape(B, L, H, hd), positions, cfg.rope_theta)
         k = rope(k.reshape(B, L, KV, hd), positions, cfg.rope_theta)
         v = (x @ lp["wv"]).reshape(B, L, KV, hd)
-        o = attn_impl(q, k, v)
-        h = h + constrain(o.reshape(B, L, H * hd) @ lp["wo"])
+        o = attn_impl(q, k, v).reshape(B, L, H * hd) @ lp["wo"]
+        if cfg.sandwich_norm:
+            o = rms_norm(o, lp["attn_post_norm"], cfg.norm_eps)
+        h = h + constrain(o)
     return (h, (k, v)) if with_kv else h
 
 
@@ -676,7 +730,8 @@ def _ffn_block(cfg: Config, lp: Params, h: jax.Array,
                constrain: Callable = lambda x: x,
                mesh: Optional[Mesh] = None):
     """The feed-forward half: ``h`` plus the SwiGLU or mixture-of-experts
-    FFN of its pre-norm, and the MoE aux term (0 for dense configs)."""
+    FFN of its pre-norm (normed again under ``cfg.sandwich_norm``), and the
+    MoE aux term (0 for dense configs)."""
     x = rms_norm(h, lp["mlp_norm"], cfg.norm_eps)
     if cfg.n_experts:
         g, aux = _moe_ffn(cfg, lp, x, mesh=mesh)
@@ -685,6 +740,9 @@ def _ffn_block(cfg: Config, lp: Params, h: jax.Array,
             g = ((jax.nn.silu(x @ lp["w_gate"]) * (x @ lp["w_up"]))
                  @ lp["w_down"])
         aux = jnp.zeros((), jnp.float32)
+    if cfg.sandwich_norm:
+        with jax.named_scope("ffn"):
+            g = rms_norm(g, lp["mlp_post_norm"], cfg.norm_eps)
     return h + constrain(g), aux
 
 
@@ -708,66 +766,84 @@ def _decoder_layer(cfg: Config, lp: Params, h: jax.Array,
 
 
 def _chunk_nll(head, h_c, t_c):
-    """One (B, C, D) chunk: its summed NLL, its (B, C, V) f32 logits, their
-    log-sum-exp and where the targets are among them.  The target's logit is
-    a masked sum (one term, so exact), which the compiler takes in the pass
-    that sums the exponentials; a gather would have the chunk's logits
-    written out in float32 for it."""
+    """One (B, C, D) chunk: its tokens' NLL (B, C), its (B, C, V) f32 logits,
+    their log-sum-exp and where the targets are among them.  The target's
+    logit is a masked sum (one term, so exact), which the compiler takes in
+    the pass that sums the exponentials; a gather would have the chunk's
+    logits written out in float32 for it."""
     logits = (h_c @ head).astype(jnp.float32)
     lse = jax.nn.logsumexp(logits, axis=-1)
     hit = t_c[..., None] == lax.broadcasted_iota(t_c.dtype, logits.shape, 2)
     tgt = jnp.sum(jnp.where(hit, logits, 0.0), axis=-1)
-    return jnp.sum(lse - tgt), logits, lse, hit
+    return lse - tgt, logits, lse, hit
 
 
-def _chunk_at(h, targets, idx, C):
-    return (lax.dynamic_slice_in_dim(h, idx * C, C, axis=1),
-            lax.dynamic_slice_in_dim(targets, idx * C, C, axis=1))
+def _chunk_at(arrays, idx, C):
+    return [lax.dynamic_slice_in_dim(a, idx * C, C, axis=1) for a in arrays]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _chunked_nll(head, h, targets, C):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _chunked_nll(head, h, targets, weights, C):
     """Mean NLL over ``L // C`` sequence chunks, one ``h_c @ head`` a chunk.
     Under differentiation the chunk that forms the logits takes the head's
     gradients too (:func:`_chunked_nll_fwd`): three products over the
     vocabulary a chunk, where a checkpointed chunk would form its logits
-    again in the backward pass and run four."""
+    again in the backward pass and run four.
+
+    With ``weights`` (B, L) float32 the result is ``sum(weights * nll)``, not
+    the mean: the weights carry the normalisation.  A looped model
+    (``cfg.ut_steps`` > 1) hands the states of all its recurrent steps as
+    rows of one ``h`` and each token's exit probability over the token count
+    as its weight.  The weights are an input of the chunk's forward pass, so
+    its gradient products take them there and still no chunk's logits are
+    formed twice; the gradient of the weights is the tokens' NLL, which the
+    forward pass keeps, (B, L) float32."""
     B, L, _ = h.shape
+    rows = (h, targets) if weights is None else (h, targets, weights)
 
     def step(acc, idx):
-        return acc + _chunk_nll(head, *_chunk_at(h, targets, idx, C))[0], None
+        h_c, t_c, *w_c = _chunk_at(rows, idx, C)
+        nll = _chunk_nll(head, h_c, t_c)[0]
+        return acc + jnp.sum(nll * w_c[0] if w_c else nll), None
 
     total, _ = lax.scan(step, jnp.zeros((), jnp.float32), jnp.arange(L // C))
-    return total / (B * L)
+    return total / (B * L) if weights is None else total
 
 
-def _chunked_nll_fwd(head, h, targets, C):
+def _chunked_nll_fwd(head, h, targets, weights, C):
     B, L, _ = h.shape
     # The dtype the logits are formed in, so the dtype their cotangent has.
     dtype = jnp.result_type(h.dtype, head.dtype)
+    rows = (h, targets) if weights is None else (h, targets, weights)
 
     def step(carry, idx):
         acc, dh, dw = carry
-        h_c, t_c = _chunk_at(h, targets, idx, C)
+        h_c, t_c, *w_c = _chunk_at(rows, idx, C)
         nll, logits, lse, hit = _chunk_nll(head, h_c, t_c)
-        dl = ((jnp.exp(logits - lse[..., None]) - hit) / (B * L)).astype(dtype)
+        dl = jnp.exp(logits - lse[..., None]) - hit
+        dl = (dl * w_c[0][..., None] if w_c else dl / (B * L)).astype(dtype)
         dh_c = (dl @ head.T).astype(h.dtype)
         dw = dw + jnp.einsum("bcd,bcv->dv", h_c, dl).astype(dw.dtype)
         dh = lax.dynamic_update_slice_in_dim(dh, dh_c, idx * C, axis=1)
-        return (acc + nll, dh, dw), None
+        return ((acc + jnp.sum(nll * w_c[0] if w_c else nll), dh, dw),
+                nll if w_c else None)
 
-    (total, dh, dw), _ = lax.scan(
+    (total, dh, dw), nll = lax.scan(
         step, (jnp.zeros((), jnp.float32), jnp.zeros_like(h),
                jnp.zeros_like(head)), jnp.arange(L // C))
-    # The residuals are the gradients themselves, (B, L, D) and (D, V): the
-    # (B, C, V) logits never leave their chunk.
-    return total / (B * L), (dh, dw, targets)
+    # The residuals are the gradients themselves, (B, L, D) and (D, V), and
+    # with weights the tokens' NLL: the (B, C, V) logits never leave their
+    # chunk.
+    if weights is None:
+        return total / (B * L), (dh, dw, targets, None)
+    return total, (dh, dw, targets, jnp.moveaxis(nll, 0, 1).reshape(B, L))
 
 
 def _chunked_nll_bwd(C, saved, g):
-    dh, dw, targets = saved
+    dh, dw, targets, nll = saved
     scale = lambda a: (a.astype(jnp.float32) * g).astype(a.dtype)
-    return scale(dw), scale(dh), np.zeros(targets.shape, jax.dtypes.float0)
+    return (scale(dw), scale(dh), np.zeros(targets.shape, jax.dtypes.float0),
+            None if nll is None else nll * g)
 
 
 _chunked_nll.defvjp(_chunked_nll_fwd, _chunked_nll_bwd)
@@ -775,19 +851,23 @@ _chunked_nll.defvjp(_chunked_nll_fwd, _chunked_nll_bwd)
 
 @jax.named_scope("head_loss")
 def _nll_from_hidden(head: jax.Array, h: jax.Array, targets: jax.Array,
-                     loss_chunk: int) -> jax.Array:
+                     loss_chunk: int,
+                     weights: Optional[jax.Array] = None) -> jax.Array:
     """Mean next-token NLL from final (post-norm) hidden states — the one
     place the output head is applied, dense or sequence-chunked (the
-    memory-critical path: chunking caps the live (B, C, V) f32 logits)."""
+    memory-critical path: chunking caps the live (B, C, V) f32 logits).
+    With ``weights`` (B, L) float32, ``sum(weights * nll)`` over the tokens
+    (:func:`_chunked_nll`)."""
     if not loss_chunk:
         logits = (h @ head).astype(jnp.float32)
         logp = jax.nn.log_softmax(logits, axis=-1)
-        return -jnp.mean(jnp.take_along_axis(logp, targets[..., None],
-                                             axis=-1)[..., 0])
+        picked = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+        return (-jnp.mean(picked) if weights is None
+                else -jnp.sum(weights * picked))
     L, C = h.shape[1], int(loss_chunk)
     if L % C:
         raise ValueError(f"seq len {L} not divisible by loss_chunk {C}")
-    return _chunked_nll(head, h, targets, C)
+    return _chunked_nll(head, h, targets, weights, C)
 
 
 def _make_tp_ce_sum(axis: str):
@@ -896,6 +976,44 @@ def _refuse_dropless_ep(cfg: Config, mesh: Optional[Mesh]) -> None:
             "capacity_factor for the one-hot dispatch that GSPMD shards")
 
 
+def _refuse_looped(cfg: Config, what: str) -> None:
+    if cfg.ut_steps > 1 or cfg.sandwich_norm or cfg.exit_gate:
+        raise NotImplementedError(
+            f"{what} runs its layers once, with no norm on a branch's output "
+            "and no exit gate: it has no form yet for a looped configuration "
+            f"(ut_steps={cfg.ut_steps}, sandwich_norm={cfg.sandwich_norm}, "
+            f"exit_gate={cfg.exit_gate}), whose cache would hold ut_steps x "
+            "n_layers slots and whose pipeline stages would be passed "
+            "ut_steps times; train it with make_train_step")
+
+
+def exit_distribution(cfg: Config, params: Params, tokens: jax.Array,
+                      mesh: Optional[Mesh] = None,
+                      attn: str = "full") -> jax.Array:
+    """(ut_steps, B, L) float32: the probability that each token of a batch
+    leaves at each recurrent step, by the gate code the training step runs
+    (:func:`_exit_log_probs`) on the states the forward pass gives it; it
+    sums to 1 over the steps.  A counter for outside the step: its mean over
+    the tokens, weighted by the step's number, is the expected exit step."""
+    h = apply(cfg, params, tokens, mesh=mesh, attn=attn, return_hidden=True,
+              all_steps=True)
+    return jnp.exp(_exit_log_probs(params, h))
+
+
+def _exit_log_probs(params: Params, h: jax.Array) -> jax.Array:
+    """The log of a looped model's exit distribution from the normed states
+    ``h`` (T, B, L, D) of its T recurrent steps, (T, B, L) float32: with
+    ``lambda_t = sigmoid(h_t @ gate_w + gate_b)`` the chance to leave at
+    step t once there, ``p_t = lambda_t * prod_{j<t} (1 - lambda_j)`` for
+    t < T and the rest at T, ``p_T = prod_{j<T} (1 - lambda_j)``."""
+    a = jnp.einsum("tbld,d->tbl", h, params["gate_w"],
+                   preferred_element_type=jnp.float32) + params["gate_b"]
+    stay = jnp.cumsum(jax.nn.log_sigmoid(-a[:-1]), axis=0)  # log S_1..S_{T-1}
+    before = jnp.concatenate([jnp.zeros_like(a[:1]), stay], axis=0)
+    return before + jnp.concatenate(
+        [jax.nn.log_sigmoid(a[:-1]), jnp.zeros_like(a[:1])], axis=0)
+
+
 def expert_unit_counts(cfg: Config, params: Params, tokens: jax.Array,
                        mesh: Optional[Mesh] = None,
                        attn: str = "full") -> jax.Array:
@@ -905,6 +1023,7 @@ def expert_unit_counts(cfg: Config, params: Params, tokens: jax.Array,
     it.  A counter for outside the step: a row sums to k*T, and its largest
     entry over its mean says how lopsided that layer's routing is."""
     _refuse_dropless_ep(cfg, mesh)
+    _refuse_looped(cfg, "expert_unit_counts")
     positions = jnp.arange(tokens.shape[1])
     attn_impl = _make_attn_impl(cfg, attn, mesh, 1.0 / np.sqrt(cfg.head_dim))
 
@@ -925,15 +1044,28 @@ def expert_unit_counts(cfg: Config, params: Params, tokens: jax.Array,
 # dense Llama-3-8B widths, 4 layers 400.6 -> 369.1 ms and 6.8 -> 12.5 s, 8
 # layers 359.1 -> 348.3 ms and 7.0 -> 21.0 s.  Up to 4 layers the step wins
 # 5-13% for at most 6 s of compile; at 8 it wins 3% for 14 s, and a dense
-# layer inlined adds 1.4-1.8 s: a minute at depth 32.
+# layer inlined adds 1.4-1.8 s: a minute at depth 32.  A looped stack
+# (``cfg.ut_steps`` > 1) is 32 layer applications at depth 8 and 4 steps, and
+# the rule still reads the depth alone (chip runs of PR 30, Ouro-2.6B widths,
+# 8 layers x 4 steps, 2 x 4096 tokens, AdamW; scanned -> inlined): remat
+# "full" 1078.7 -> 1033.5 ms, the plan 14.39 -> 11.24 GB (a scanned pass
+# leaves a stacked gradient of 0.82 GB until the four are summed) and compile
+# 21.7 -> 81.4 s; three steps "full" and one "dots" 1041.5 -> 988.3 ms, 15.07
+# -> 13.24 GB, 26.6 -> 84.6 s.  4-5% of a step for a minute of compile in
+# every program that holds the stack: the trade declined at depth 8, so the
+# bound was not changed by them.  The recurrent steps themselves are always
+# inlined: a ``lax.scan`` over them with the stack closed over ran 1061.6 ms
+# for 1078.7 (-1.6%) under "full", but it takes one remat policy for all its
+# steps, and a policy for each step wins more (1041.5).
 _INLINE_MAX_LAYERS = 4
 
 
 def apply(cfg: Config, params: Params, tokens: jax.Array,
           mesh: Optional[Mesh] = None, attn: str = "full",
-          remat: str = "none", return_hidden: bool = False,
+          remat: Remat = "none", return_hidden: bool = False,
           return_aux: bool = False, layer_loop: Optional[str] = None,
-          positions: Optional[jax.Array] = None) -> jax.Array:
+          positions: Optional[jax.Array] = None,
+          all_steps: bool = False) -> jax.Array:
     """Forward: tokens (B, L) int32 -> logits (B, L, vocab) f32, or the
     final hidden states (B, L, D) in compute dtype when ``return_hidden``
     (the chunked-loss path applies the output head itself so the full
@@ -942,6 +1074,14 @@ def apply(cfg: Config, params: Params, tokens: jax.Array,
     load-balance loss (0 for dense configs) — the training path for
     ``n_experts > 0`` configs adds ``cfg.moe_aux_coef * aux`` — and with
     ``cfg.moe_z_coef`` the pair (load balance, router z-loss).
+
+    A looped configuration (``cfg.ut_steps`` = T > 1) runs the stack T times
+    with the same weights and positions, the final norm after every pass, and
+    returns the last step's logits or states: no step leaves early.  With
+    ``all_steps`` the result carries a leading axis of the T recurrent steps,
+    (T, B, L, ...): what the expected-exit loss and the comparison with a
+    reference read.  ``aux`` is then the mean over all T * n_layers layer
+    applications.
 
     ``mesh`` enables activation sharding constraints (and is required for
     ``attn='ring'``); without it the model runs unconstrained (single-device
@@ -958,6 +1098,15 @@ def apply(cfg: Config, params: Params, tokens: jax.Array,
         default: activations per layer shrink ~4x),
       * ``"full"``  — save only layer boundaries, recompute everything
         (longest contexts; backward recomputes each layer's forward).
+    For a looped configuration the policy holds for every layer application
+    of every recurrent step, or ``remat`` is a sequence of T such names, one
+    for each recurrent step's layers: the T * n_layers applications of a
+    step's backward pass all keep what their policy keeps at once, so
+    ``"dots"`` for as many steps as the memory holds and ``"full"`` for the
+    rest is the recomputation chosen to fit (8 layers x 4 steps of Ouro-2.6B
+    on a v5e: ``"dots"`` everywhere needs 20.25 GB of 15.75, ``("full",
+    "full", "full", "dots")`` runs a step in 1041.5 ms where ``"full"`` takes
+    1078.7; chip runs of PR 30).
 
     ``layer_loop`` is the form of the loop over the stacked layers.  Left
     ``None``, the code chooses from the depth it is given: a stack of at
@@ -977,7 +1126,13 @@ def apply(cfg: Config, params: Params, tokens: jax.Array,
     of a weight is a static slice, its gradient an operand of the update,
     its residuals plain buffers.  Both forms compute the same function
     (``tests/test_llama.py::test_unrolled_matches_scan``), and both names
-    stay accepted for that test's sake.
+    stay accepted for that test's sake.  The form is that of one pass
+    through the stack; a looped configuration's T passes are always inlined
+    round it (a Python loop: T is small, each step may have a remat policy of
+    its own, and each pass's states go to the head), so ``"scan"`` is T scans
+    of one layer and ``"unroll"`` T * n_layers inlined layers.  The rule
+    reads the depth alone, whatever T (``_INLINE_MAX_LAYERS``'s comment has
+    the measurements).
     """
     B, L = tokens.shape
     scale = 1.0 / np.sqrt(cfg.head_dim)
@@ -1015,30 +1170,67 @@ def apply(cfg: Config, params: Params, tokens: jax.Array,
                               mesh=mesh)
         return (h, aux + a), None
 
-    layer = _wrap_remat(layer, remat)
-    aux0 = jnp.zeros((2,) if cfg.n_experts and cfg.moe_z_coef else (),
-                     jnp.float32)
-
+    remats = (remat,) * cfg.ut_steps if isinstance(remat, str) else tuple(remat)
+    if len(remats) != cfg.ut_steps:
+        raise ValueError(f"remat names {len(remats)} recurrent steps, the "
+                         f"configuration has {cfg.ut_steps}")
+    wrapped = {r: _wrap_remat(layer, r) for r in dict.fromkeys(remats)}
     if layer_loop is None:
         layer_loop = "unroll" if cfg.n_layers <= _INLINE_MAX_LAYERS else "scan"
-    if layer_loop == "unroll":
-        carry = (h, aux0)
+    if layer_loop not in ("scan", "unroll"):
+        raise ValueError("layer_loop must be 'scan', 'unroll' or None")
+
+    def stack(carry, layer):
+        """One pass through the stacked layers."""
+        if layer_loop == "scan":
+            return lax.scan(layer, carry, params["layers"])[0]
         for i in range(cfg.n_layers):
             carry, _ = layer(carry, jax.tree.map(lambda a: a[i],
                                                  params["layers"]))
-        h, aux = carry
-    elif layer_loop == "scan":
-        (h, aux), _ = lax.scan(layer, (h, aux0), params["layers"])
-    else:
-        raise ValueError("layer_loop must be 'scan', 'unroll' or None")
-    aux = aux / cfg.n_layers
-    h = rms_norm(h, params["norm"], cfg.norm_eps)
+        return carry
+
+    def ut_step(carry, layer):
+        h, aux = stack(carry, layer)
+        with jax.named_scope("final_norm"):
+            return rms_norm(h, params["norm"], cfg.norm_eps), aux
+
+    carry = (h, jnp.zeros((2,) if cfg.n_experts and cfg.moe_z_coef else (),
+                          jnp.float32))
+    states = []
+    for r in remats:
+        carry = ut_step(carry, wrapped[r])
+        states.append(carry[0])
+    aux = carry[1] / (cfg.n_layers * cfg.ut_steps)
+    h = jnp.stack(states) if all_steps else states[-1]
     out = h if return_hidden else (h @ params["head"]).astype(jnp.float32)
     return (out, aux) if return_aux else out
 
 
+def _expected_exit_nll(cfg: Config, params: Params, h: jax.Array,
+                       targets: jax.Array, loss_chunk: int) -> jax.Array:
+    """A looped model's training loss from the normed states ``h`` (T, B, L,
+    D) of its T recurrent steps: the mean over the tokens of ``sum_t p_t *
+    nll_t - cfg.exit_entropy_coef * H(p)``, with ``nll_t`` the next-token NLL
+    of step t's logits, ``p`` the token's exit distribution
+    (:func:`_exit_log_probs`) and ``H`` its entropy.  The gate learns through
+    both terms, the head and the stack through the p-weighted NLL of every
+    step.  The steps' states go through the head as T * B rows of one call,
+    each token's weight its ``p_t`` over the token count: with a
+    ``loss_chunk`` one pass over the head's gradient accumulator a chunk, not
+    T (:func:`_chunked_nll`).  With T = 1 it is the plain mean NLL."""
+    T, B, L, D = h.shape
+    with jax.named_scope("exit_gate"):
+        logp = _exit_log_probs(params, h)
+        p = jnp.exp(logp)
+        entropy = -jnp.mean(jnp.sum(p * logp, axis=0))
+        weights = (p / (B * L)).reshape(T * B, L)
+    nll = _nll_from_hidden(params["head"], h.reshape(T * B, L, D),
+                           jnp.tile(targets, (T, 1)), loss_chunk, weights)
+    return nll - cfg.exit_entropy_coef * entropy
+
+
 def make_loss_fn(cfg: Config, mesh: Optional[Mesh] = None, attn: str = "full",
-                 remat: str = "none", loss_chunk: int = 0,
+                 remat: Remat = "none", loss_chunk: int = 0,
                  layer_loop: Optional[str] = None):
     """Next-token cross-entropy: ``loss_fn(params, (tokens, targets))`` —
     the engine contract; targets = tokens shifted by the caller.
@@ -1051,6 +1243,10 @@ def make_loss_fn(cfg: Config, mesh: Optional[Mesh] = None, attn: str = "full",
     forms its logits (:func:`_chunked_nll`), so the peak holds there too and
     no chunk is formed twice.  ``L`` must be divisible by ``loss_chunk``.
     ``layer_loop`` as in :func:`apply`: left ``None``, the depth decides.
+
+    A configuration with an exit gate (``cfg.exit_gate``, a looped model)
+    trains on the expected-exit loss over all its recurrent steps
+    (:func:`_expected_exit_nll`); without one, on the last step's NLL.
     """
 
     def loss_fn(params: Params, batch: Tuple[jax.Array, jax.Array]) -> jax.Array:
@@ -1073,9 +1269,12 @@ def make_loss_fn(cfg: Config, mesh: Optional[Mesh] = None, attn: str = "full",
             positions = jnp.asarray(idx)
         h, aux = apply(cfg, params, tokens, mesh=mesh, attn=attn, remat=remat,
                        return_hidden=True, return_aux=True,
-                       layer_loop=layer_loop,
-                       positions=positions)                  # (B, L, D)
-        nll = _nll_from_hidden(params["head"], h, targets, loss_chunk)
+                       layer_loop=layer_loop, positions=positions,
+                       all_steps=cfg.exit_gate)              # (B, L, D)
+        if cfg.exit_gate:
+            nll = _expected_exit_nll(cfg, params, h, targets, loss_chunk)
+        else:
+            nll = _nll_from_hidden(params["head"], h, targets, loss_chunk)
         if cfg.n_experts and cfg.moe_z_coef:
             nll = nll + cfg.moe_aux_coef * aux[0] + cfg.moe_z_coef * aux[1]
         elif cfg.n_experts:
@@ -1102,6 +1301,7 @@ def _decode_step(cfg: Config, params: Params, cache: Params,
     (logits (B, V) f32, updated cache).  Attention reads the cache up to and
     including ``pos`` (causality holds by construction: later slots are
     still zero and masked off)."""
+    _refuse_looped(cfg, "the decode step")
     B = tokens.shape[0]
     hd, H, KV = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
     scale = 1.0 / np.sqrt(hd)
@@ -1168,6 +1368,7 @@ def _prefill(cfg: Config, params: Params, cache: Params,
     ``mesh`` is the mesh the params are sharded on, if any: the flash
     kernel needs it to run per batch/head shard.
     """
+    _refuse_looped(cfg, "prefill")
     B, Lp = prompt.shape
     positions = jnp.arange(Lp)
     scale = 1.0 / np.sqrt(cfg.head_dim)
@@ -1233,6 +1434,7 @@ def make_generate_fn(cfg: Config, prompt_len: int, max_new: int,
     (the per-layer attention/MLP psums) are GSPMD's, inferred from the
     pinned weight + cache shardings.
     """
+    _refuse_looped(cfg, "make_generate_fn")
     if prompt_len < 1 or max_new < 1:
         raise ValueError("prompt_len and max_new must be >= 1")
     if mesh is not None and cfg.n_kv_heads % dict(mesh.shape).get(AXIS_TP, 1):
@@ -1329,7 +1531,9 @@ def make_generate_fn(cfg: Config, prompt_len: int, max_new: int,
 
 def _wrap_remat(layer: Callable, remat: str) -> Callable:
     """THE remat taxonomy ('none'/'dots'/'full'), one definition for the
-    scanned forward and both pipeline stage builders.
+    scanned forward and both pipeline stage builders.  One policy for every
+    application of ``layer``; :func:`apply` wraps the layer once for each
+    policy a looped configuration's recurrent steps name.
 
     ``"dots"`` keeps matmul outputs and the flash kernel's two residuals,
     ``o`` and ``lse``: the kernel is no dot, so the dots policy alone would
@@ -1519,6 +1723,7 @@ def make_pp_train_step(cfg: Config, mesh: Mesh, n_microbatches: int,
         # aux loss through the stage boundary needs an augmented carrier.
         # Train MoE configs with the dp x tp x ep step (make_train_step).
         raise NotImplementedError("pipeline step does not support MoE configs")
+    _refuse_looped(cfg, "make_pp_train_step")
     S = mesh.shape[AXIS_PP]
     sizes = dict(mesh.shape)
     compose = _gspmd_compose(mesh)
@@ -1655,6 +1860,7 @@ def make_1f1b_train_step(cfg: Config, mesh: Mesh, n_microbatches: int,
 
     if cfg.n_experts:
         raise NotImplementedError("pipeline step does not support MoE configs")
+    _refuse_looped(cfg, "make_1f1b_train_step")
     S = mesh.shape[AXIS_PP]
     sizes = dict(mesh.shape)
     if cfg.n_layers % S:
@@ -1877,7 +2083,7 @@ def _zero1_opt_shardings(cfg: Config, mesh: Mesh, opt_state_example,
 
 def make_train_step(cfg: Config, mesh: Mesh, lr: float = 3e-4,
                     attn: str = "full", optimizer=None,
-                    remat: str = "none", loss_chunk: int = 0,
+                    remat: Remat = "none", loss_chunk: int = 0,
                     zero1: bool = False, opt_state_example=None):
     """One pjit'd dp x tp (x sp/ep) training step over ``mesh``:
     ``step(params, opt_state, tokens, targets) -> (params, opt_state, loss)``.
