@@ -305,9 +305,18 @@ class TestOnlineMode:
                                ["hostcomm", "xla"]) == "xla"
         h = obs_metrics.registry.histogram(
             "tmpi_collective_seconds", "test feed")
+        fast = dict(labels={"op": "allreduce", "plane": "hostcomm",
+                            "bytes_bucket": "1KiB"})
         for _ in range(6):   # 0.1 ms mean beats the cached 1.0 ms xla
-            h.observe(1e-4, labels={"op": "allreduce", "plane": "hostcomm",
-                                    "bytes_bucket": "1KiB"})
+            h.observe(1e-4, **fast)
+        # The registry is the process's: a file that ran before this one on
+        # the same worker may have left real, slower samples in this series
+        # (the test failed so, one run in several, under six workers).
+        mean = lambda: autotune._online_observations()[
+            ("allreduce", "1KiB", "hostcomm")][0]
+        while mean() >= 5e-4:
+            for _ in range(1000):
+                h.observe(1e-4, **fast)
         config.set("autotune_mode", "online")
         assert autotune.decide("allreduce", "cpu", "singlenode", "sync",
                                payload,

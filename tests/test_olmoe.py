@@ -135,6 +135,16 @@ def test_head_loss_ms_reads_the_join():
     assert read({"counters": {"scope_ms": {"head_loss": 61.5}}}) == 61.5
 
 
+def test_kernel_calls_reads_the_runners_counter():
+    """`benchmark/layers/kernel_calls.py`: the count the token runners leave
+    of the compiled step's `tpu_custom_call`s, 0 where the program holds no
+    kernel, `None` where a runner left no count (the ResNet cells')."""
+    read = _benchmark_module("layers", "kernel_calls").read
+    assert read({"counters": {}}) is None
+    assert read({"counters": {"kernel_calls": 0}}) == 0
+    assert read({"counters": {"kernel_calls": 22, "scope_ms": {}}}) == 22
+
+
 def _skip_an_expert(params):
     lay = dict(params["layers"])
     lay["w_down"] = lay["w_down"].at[:, 3].set(0.0)

@@ -1,6 +1,10 @@
 """Pallas kernel tests (interpreter path on the CPU mesh; the same kernel
 compiles on TPU — bench.py exercises that)."""
 
+import collections
+import dataclasses
+import functools
+
 import numpy as np
 import pytest
 
@@ -204,15 +208,15 @@ class TestFlashBackward:
         assert calls(need - 1) == ["flash_bwd_dq", "flash_bwd_dkv"]
 
 
-@pytest.mark.parametrize("remat,forwards", [("dots", 1), ("none", 1),
-                                            ("full", 2)])
-def test_train_step_forms_scores_once_each_way(remat, forwards):
+@pytest.mark.parametrize("remat", ["dots", "none", "full"])
+def test_train_step_forms_scores_once_each_way(remat):
     """The step of ``make_train_step(attn="flash")`` holds one forward and
     one backward kernel a layer (the two layers of ``moe_tiny`` are inlined,
-    so the step's jaxpr holds each layer's own): under ``remat="dots"`` the
-    layer's checkpoint keeps the kernel's ``o`` and ``lse``
-    (``flash_attention.RESIDUAL_NAMES``), so the forward is not replayed;
-    ``"full"`` keeps nothing and replays it."""
+    so the step's jaxpr holds each layer's own) under every remat policy:
+    the layer's checkpoint keeps the kernel's ``o`` and ``lse``
+    (``flash_attention.RESIDUAL_NAMES``) under ``"dots"`` and under
+    ``"full"`` alike (``llama._wrap_remat``), so no policy replays the
+    forward kernel."""
     from torchmpi_tpu.models import llama
     from torchmpi_tpu.parallel import mesh as pmesh
 
@@ -225,4 +229,106 @@ def test_train_step_forms_scores_once_each_way(remat, forwards):
         jax.make_jaxpr(step)(params, None, tokens, tokens).jaxpr)
     assert cfg.n_layers <= llama._INLINE_MAX_LAYERS
     assert sorted(calls) == (["flash_bwd"] * cfg.n_layers
-                             + ["flash_fwd"] * forwards * cfg.n_layers)
+                             + ["flash_fwd"] * cfg.n_layers)
+
+
+def _grouped_products(jaxpr, found=None):
+    """How many grouped matmuls a jaxpr holds, by form (megablox's jitted
+    ``gmm`` and ``tgmm`` on one device, ``ragged_dot_general`` under GSPMD),
+    how many flash kernels and how many ``checkpoint_name``s by name."""
+    found = collections.Counter() if found is None else found
+    for eqn in jaxpr.eqns:
+        kind = eqn.primitive.name
+        if kind == "pallas_call":
+            found[eqn.params["name"]] += 1          # megablox's have none
+            continue
+        if kind == "name" or (kind == "jit" and eqn.params["name"] in
+                              ("gmm", "tgmm")):
+            found[eqn.params["name"]] += 1
+        elif kind == "ragged_dot_general":
+            found[kind] += 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _grouped_products(sub, found)
+    return found
+
+
+@pytest.mark.parametrize("remat,formed", [("dots", 1), ("full", 2)])
+@pytest.mark.parametrize("devices", [1, 2], ids=["gmm", "ragged_dot"])
+def test_train_step_forms_each_grouped_product_once(devices, remat, formed):
+    """The dropless sorted expert layer's step requires 9 grouped matmuls a
+    layer (gate, up and down, each forward, for its rows' gradient and for
+    its weights').  ``remat="dots"`` runs those and no other: the gate and up
+    products carry names (``llama.GROUPED_DOT_NAMES``) that the policy keeps
+    as the dots they are, whether megablox's ``gmm`` forms them (one device)
+    or ``lax.ragged_dot`` (GSPMD), and nothing reads the down product's
+    output again.  ``"full"`` keeps the flash kernel's ``o`` and ``lse`` and
+    not those two, so it forms gate and up a second time (``formed``)."""
+    from torchmpi_tpu.models import llama
+    from torchmpi_tpu.parallel import mesh as pmesh
+
+    cfg = dataclasses.replace(llama.moe_tiny(), capacity_factor=None)
+    mesh = pmesh.make_mesh({"dp": devices}, devices=jax.devices()[:devices])
+    step = llama.make_train_step(cfg, mesh, attn="flash", remat=remat)
+    params = jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0), cfg))
+    tokens = jax.ShapeDtypeStruct((2, 64), jnp.int32)
+    found = _grouped_products(
+        jax.make_jaxpr(step)(params, None, tokens, tokens).jaxpr)
+    layers = cfg.n_layers
+    # down forward and the three gradients of the rows, gate and up `formed`
+    # times each; the three gradients of the weights
+    if devices == 1:
+        assert found["gmm"] == (4 + 2 * formed) * layers
+        assert found["tgmm"] == 3 * layers
+        assert found["ragged_dot_general"] == 0
+    else:
+        assert found["ragged_dot_general"] == (7 + 2 * formed) * layers
+        assert found["gmm"] == found["tgmm"] == 0
+    for name in llama.GROUPED_DOT_NAMES:
+        assert found[name] == formed * layers
+    assert found["flash_fwd"] == found["flash_bwd"] == layers
+
+
+# ---------------------------------------------- remat keeps kernels' outputs
+
+@functools.cache
+def _flash_loss_and_grads(model, remat):
+    """Loss and gradients of a tiny model through the flash kernels (and,
+    for the dropless sorted expert layer, megablox's ``gmm``) in interpret
+    mode, under one remat policy or one for each recurrent step."""
+    from torchmpi_tpu.models import llama
+
+    cfg = {"sorted": dataclasses.replace(llama.moe_tiny(), capacity_factor=None,
+                                         moe_z_coef=1e-3),
+           "looped": dataclasses.replace(llama.tiny(), n_kv_heads=4, ut_steps=3,
+                                         sandwich_norm=True, exit_gate=True,
+                                         exit_entropy_coef=0.1)}[model]
+    params = llama.init(jax.random.PRNGKey(0), cfg)
+    tokens, targets = (jax.random.randint(jax.random.PRNGKey(i), (2, 64), 0,
+                                          cfg.vocab) for i in (1, 2))
+    return jax.jit(jax.value_and_grad(llama.make_loss_fn(
+        cfg, attn="flash", remat=remat, loss_chunk=32)))(
+            params, (tokens, targets))
+
+
+@pytest.mark.parametrize("model,remat", [
+    ("sorted", "dots"), ("sorted", "full"),
+    ("looped", "dots"), ("looped", "full"), ("looped", ("full", "dots", "none")),
+], ids=["sorted-dots", "sorted-full", "looped-dots", "looped-full",
+        "looped-a-policy-a-step"])
+def test_remat_with_kernels_changes_no_value(model, remat):
+    """What a policy keeps of a kernel (``llama._wrap_remat``: the flash
+    kernel's ``o`` and ``lse`` under ``"dots"`` and ``"full"``, the grouped
+    matmul's gate and up products under ``"dots"``) is what a replay would
+    have formed: loss and every gradient leaf are those of ``remat="none"``,
+    for the dropless sorted expert layer (megablox's ``gmm`` in interpret
+    mode) and for a looped model with a policy for each recurrent step."""
+    (want, want_g), (got, got_g) = (_flash_loss_and_grads(model, r)
+                                    for r in ("none", remat))
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-6)
+    want_g, got_g = (jax.tree_util.tree_leaves_with_path(g)
+                     for g in (want_g, got_g))
+    assert [k for k, _ in want_g] == [k for k, _ in got_g]
+    for (key, w), (_, g) in zip(want_g, got_g):
+        assert np.any(np.asarray(w) != 0), jax.tree_util.keystr(key)
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-5,
+                                   atol=1e-6, err_msg=jax.tree_util.keystr(key))

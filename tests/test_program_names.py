@@ -62,11 +62,16 @@ def test_llama_train_step_carries_scope_and_kernel_names(make_cfg, ffn,
                                  loss_chunk=32)
     params = jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0), cfg))
     tokens = jax.ShapeDtypeStruct((2, 64), jnp.int32)
-    names = _op_names(step.lower(params, None, tokens, tokens))
+    lowered = step.lower(params, None, tokens, tokens)
+    names = _op_names(lowered)
     # (inlined, the layers' forward operations are the step's own; scanned,
     # the body is a function of its own and its names start at the scope)
     inlined = any(n.startswith("jit(step)/jvp(attn)/") for n in names)
     assert inlined == (depth <= llama._INLINE_MAX_LAYERS)
+    # An inlined layer's checkpoint stands behind an optimization barrier,
+    # or the compiler merges the recomputation with the forward pass; a
+    # scanned layer's needs none (`llama._wrap_remat`).
+    assert ("optimization_barrier" in lowered.as_text()) == inlined
     for scope in ("embed", "attn", "head_loss", "optimizer") + ffn:
         assert _carried(names, scope), scope
     for scope in absent:
@@ -89,16 +94,19 @@ def test_llama_train_step_carries_scope_and_kernel_names(make_cfg, ffn,
     if "attn.qk_norm" in ffn:
         # QK-norm sits inside the attention's scope; the sorted dispatch's
         # grouped matmuls (megablox's `gmm`, and `tgmm` for the weights'
-        # gradients) are under moe.experts: forward, backward, and in the
-        # layer's recomputation, since the kernel is no dot the policy saves.
+        # gradients) are under moe.experts: forward and backward, and none in
+        # the layer's recomputation, where the SwiGLU between them still is:
+        # remat="dots" keeps the gate and up products by their names
+        # (`llama.GROUPED_DOT_NAMES`), and nothing reads the down product's.
         assert _carried(names, "attn.qk_norm", inside="attn/attn.qk_norm")
-        for kernel, inside in (("gmm", "checkpoint/"), ("tgmm", "checkpoint/"),
-                               ("gmm", "rematted_computation/")):
+        for kernel in ("gmm", "tgmm"):
             assert _carried(names, kernel,
-                            inside=inside + "moe.experts/jit("), (kernel, inside)
+                            inside="checkpoint/moe.experts/jit("), kernel
+            assert not _carried(names, kernel, inside="rematted_computation")
         assert _carried([n for n in names if "checkpoint" not in n], "gmm",
                         inside="moe.experts/jit(")
-        assert not _carried(names, "tgmm", inside="rematted_computation")
+        assert _carried(names, "moe.experts",
+                        inside="rematted_computation/moe.experts")
 
 
 def _looped_tiny():
@@ -114,8 +122,8 @@ def _looped_tiny():
 def test_looped_train_step_carries_scope_names(depth):
     """A looped model's step: the per-step final norm and the exit gate have
     scopes of their own, forward and backward; the stack's names are the
-    dense model's, and a step under `"full"` recomputes its forward kernel
-    where a step under `"dots"` does not."""
+    dense model's, and neither a step under `"full"` nor one under `"dots"`
+    runs its forward kernel again, while `"full"` recomputes the rest."""
     cfg = dataclasses.replace(_looped_tiny(), n_layers=depth)
     mesh = pmesh.make_mesh({"dp": 1}, devices=jax.devices()[:1])
     step = llama.make_train_step(cfg, mesh, attn="flash",
@@ -135,7 +143,9 @@ def test_looped_train_step_carries_scope_names(depth):
     # the gate's product over all steps' states is the gate's, not the head's
     assert _carried(names, "exit_gate", inside="exit_gate)/tbld,d->tbl")
     assert not _carried(names, "head_loss", inside="tbld,d->tbl")
-    assert _carried(names, "flash_fwd", inside="rematted_computation")
+    assert _carried(names, "flash_fwd", inside="attn/flash_fwd")
+    assert not _carried(names, "flash_fwd", inside="rematted_computation")
+    assert _carried(names, "attn", inside="rematted_computation")
     assert _carried(names, "flash_bwd", inside="checkpoint/attn/")
 
 

@@ -47,6 +47,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..parallel.mesh import AXIS_DP, AXIS_EP, AXIS_PP, AXIS_SP, AXIS_TP
@@ -384,9 +385,11 @@ def _flash_attention_sharded(mesh: Optional[Mesh], heads: int,
     own batch rows and head shard anyway — the layout the hand-sharded
     stage (:func:`_decoder_layer_tp_manual`) already runs.  K/V are split
     at ``kv_heads`` and repeated locally, as in the ring.  A step runs two
-    kernels a layer, ``flash_fwd`` and ``flash_bwd``; under ``remat="dots"``
-    the layer's checkpoint keeps the forward's ``o`` and ``lse``
-    (:func:`_wrap_remat`), under ``"full"`` the forward runs twice."""
+    kernels a layer, ``flash_fwd`` and ``flash_bwd``, under every remat
+    policy: the layer's checkpoint keeps the forward's ``o`` and ``lse``
+    under ``"dots"`` and under ``"full"`` alike (:func:`_wrap_remat`), so
+    ``"full"`` holds one more array of the layer input's size a layer
+    application and never runs the forward kernel twice."""
     from jax import shard_map
 
     from ..ops import flash_attention
@@ -661,7 +664,13 @@ def _moe_ffn_sorted(cfg: Config, lp: Params, x: jax.Array,
     (:func:`_grouped_matmul`) for gate, up and down; the hidden rows carry
     the router's weight into the down product, so the results only have to
     be summed back to their tokens by the inverse permutation, and no
-    backward pass needs the down product's output.  The cost is k/E of the
+    backward pass needs the down product's output.  The gate and the up
+    products' outputs a backward pass does read, through the SwiGLU between
+    them: each carries a ``checkpoint_name`` (``GROUPED_DOT_NAMES``), given
+    after :func:`_grouped_matmul` returns so that megablox's ``gmm`` and
+    ``lax.ragged_dot`` are named alike, and ``remat="dots"`` keeps them as it
+    keeps any dot (:func:`_wrap_remat`): a step forms the 9 products a layer
+    that it requires and none twice.  The cost is k/E of the
     one-hot form's at C = G and does not grow with E.  On one device
     (``mesh`` None or of size 1) or under GSPMD on dp and tp; :func:`apply`
     refuses an ``ep`` axis."""
@@ -678,8 +687,13 @@ def _moe_ffn_sorted(cfg: Config, lp: Params, x: jax.Array,
         xs = _dispatch_rows(xt, order, inverse, k)
         ws = _dispatch_rows(weight.reshape(T * k, 1), order, inverse, 1)
     with jax.named_scope("moe.experts"):
-        hs = (jax.nn.silu(_grouped_matmul(xs, lp["w_gate"], counts, kernel))
-              * _grouped_matmul(xs, lp["w_up"], counts, kernel))
+        gate = checkpoint_name(
+            _grouped_matmul(xs, lp["w_gate"], counts, kernel),
+            GROUPED_DOT_NAMES[0])
+        up = checkpoint_name(
+            _grouped_matmul(xs, lp["w_up"], counts, kernel),
+            GROUPED_DOT_NAMES[1])
+        hs = jax.nn.silu(gate) * up
         ys = _grouped_matmul((hs * ws).astype(x.dtype), lp["w_down"], counts,
                              kernel)                                # (kT, D)
     with jax.named_scope("moe.combine"):
@@ -1091,13 +1105,20 @@ def apply(cfg: Config, params: Params, tokens: jax.Array,
     (gradient checkpointing — the HBM/FLOPs trade SURVEY.md §7 prescribes
     for 8B-scale):
       * ``"none"``  — save all residuals (small models),
-      * ``"dots"``  — save matmul outputs and the flash kernel's output
-        and log-sum-exp, recompute elementwise
+      * ``"dots"``  — save matmul outputs, the flash kernel's output and
+        log-sum-exp and the sorted expert layer's gate and up grouped
+        matmuls, recompute elementwise
         (``jax.checkpoint_policies.dots_with_no_batch_dims_saveable`` plus
-        the kernel's residual names, :func:`_wrap_remat`; the transformer
+        the kernels' names, :func:`_wrap_remat`; the transformer
         default: activations per layer shrink ~4x),
-      * ``"full"``  — save only layer boundaries, recompute everything
-        (longest contexts; backward recomputes each layer's forward).
+      * ``"full"``  — save only layer boundaries and, with ``attn="flash"``,
+        the kernel's output and log-sum-exp (one more array of the layer
+        input's size a layer application; the backward pass recomputes the
+        rest of each layer's forward, the grouped matmuls included, and never
+        the L^2 part; longest contexts).
+    No policy runs a flash kernel twice (:func:`_wrap_remat` has the list of
+    what each keeps, and why a scanned layer's checkpoint carries no
+    optimization barrier).
     For a looped configuration the policy holds for every layer application
     of every recurrent step, or ``remat`` is a sequence of T such names, one
     for each recurrent step's layers: the T * n_layers applications of a
@@ -1174,11 +1195,12 @@ def apply(cfg: Config, params: Params, tokens: jax.Array,
     if len(remats) != cfg.ut_steps:
         raise ValueError(f"remat names {len(remats)} recurrent steps, the "
                          f"configuration has {cfg.ut_steps}")
-    wrapped = {r: _wrap_remat(layer, r) for r in dict.fromkeys(remats)}
     if layer_loop is None:
         layer_loop = "unroll" if cfg.n_layers <= _INLINE_MAX_LAYERS else "scan"
     if layer_loop not in ("scan", "unroll"):
         raise ValueError("layer_loop must be 'scan', 'unroll' or None")
+    wrapped = {r: _wrap_remat(layer, r, scanned=layer_loop == "scan")
+               for r in dict.fromkeys(remats)}
 
     def stack(carry, layer):
         """One pass through the stacked layers."""
@@ -1529,29 +1551,68 @@ def make_generate_fn(cfg: Config, prompt_len: int, max_new: int,
 
 # ------------------------------------------------------------- pipeline (pp)
 
-def _wrap_remat(layer: Callable, remat: str) -> Callable:
+# The sorted expert layer's gate and up products (:func:`_moe_ffn_sorted`
+# names them): the grouped matmul's outputs that a backward pass reads again.
+GROUPED_DOT_NAMES = ("moe_gate", "moe_up")
+
+
+def _wrap_remat(layer: Callable, remat: str,
+                scanned: bool = False) -> Callable:
     """THE remat taxonomy ('none'/'dots'/'full'), one definition for the
-    scanned forward and both pipeline stage builders.  One policy for every
-    application of ``layer``; :func:`apply` wraps the layer once for each
-    policy a looped configuration's recurrent steps name.
+    forward's layer loop and both pipeline stage builders.  One policy for
+    every application of ``layer``; :func:`apply` wraps the layer once for
+    each policy a looped configuration's recurrent steps name.
 
-    ``"dots"`` keeps matmul outputs and the flash kernel's two residuals,
-    ``o`` and ``lse``: the kernel is no dot, so the dots policy alone would
-    replay the whole forward kernel in the backward pass.  The names are the
-    kernel's (``ops.flash_attention.RESIDUAL_NAMES``); no other attention
-    mode emits them, so ``attn="full"`` and the rings compile as before."""
-    if remat == "dots":
-        from ..ops.flash_attention import RESIDUAL_NAMES
+    No policy replays a Mosaic kernel whose output it can keep for about a
+    layer input's bytes.  A kernel is no ``dot_general``, so a policy sees
+    its output only by the name the output carries, and this is the one list
+    of the names each policy keeps:
 
-        policies = jax.checkpoint_policies
-        return jax.checkpoint(layer, policy=policies.save_from_both_policies(
-            policies.dots_with_no_batch_dims_saveable,
-            policies.save_only_these_names(*RESIDUAL_NAMES)))
-    if remat == "full":
-        return jax.checkpoint(layer)
-    if remat != "none":
+    * ``"dots"``: matmul outputs; the flash kernel's ``o`` and ``lse``
+      (``ops.flash_attention.RESIDUAL_NAMES``); the grouped matmul's gate and
+      up products (``GROUPED_DOT_NAMES``), which are dots whether megablox's
+      ``gmm`` or ``lax.ragged_dot`` forms them: two arrays of k*T x d_expert a
+      layer, for two forward kernels a layer not run again.
+    * ``"full"``: the layer's input and the flash kernel's ``o`` and ``lse``,
+      nothing else: with ``attn="flash"`` one more array the size of the
+      layer's input for each layer application (``o``, B*L*H*hd; ``lse``,
+      float32, is 1/64 of its bytes at a head of 128), and the one part of a
+      layer whose recomputation grows with L^2 runs once.  The grouped
+      matmul's products it does not keep (at OLMoE's shapes they are eight
+      layer inputs) and replays.
+    * ``"none"``: everything, no checkpoint.
+
+    No other attention mode emits the flash names and no other FFN the
+    grouped ones, so ``attn="full"``, the rings, the dense SwiGLU and the
+    one-hot experts compile as before.
+
+    ``scanned`` says the wrapped layer is the body of a ``lax.scan``.  The
+    checkpoint's optimization barrier (``prevent_cse``) is there so that the
+    compiler cannot merge a layer's recomputation with its forward pass and
+    keep everything after all; under a scan the two run in different loops
+    and nothing can be merged, while the barrier still makes every kept
+    array and every cotangent stand in memory as it is handed over, laid out
+    again for the kernel that reads it: on Ouro-2.6B's 8 scanned layers x 4
+    steps 43 ms of a 1,035 ms step, and with it ``"full"`` keeping ``o`` and
+    ``lse`` shortened the step by 4.5 ms of the 33 it takes out of the flash
+    kernels (PERF.md section 6, PR 31).  So a scanned layer is checkpointed
+    without it, an inlined one with.  The pipeline stage builders scan their
+    layers too and keep the barrier: no cell times them."""
+    if remat == "none":
+        return layer
+    if remat not in ("dots", "full"):
         raise ValueError("remat must be 'none', 'dots', or 'full'")
-    return layer
+    from ..ops.flash_attention import RESIDUAL_NAMES
+
+    policies = jax.checkpoint_policies
+    if remat == "full":
+        policy = policies.save_only_these_names(*RESIDUAL_NAMES)
+    else:
+        policy = policies.save_from_both_policies(
+            policies.dots_with_no_batch_dims_saveable,
+            policies.save_only_these_names(*RESIDUAL_NAMES,
+                                           *GROUPED_DOT_NAMES))
+    return jax.checkpoint(layer, policy=policy, prevent_cse=not scanned)
 
 
 def _decoder_layer_tp_manual(cfg: Config, lp, h, positions,
