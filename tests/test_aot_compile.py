@@ -352,3 +352,101 @@ def test_ouro_adamw_step_at_published_widths(v5e, monkeypatch):
     assert 0.25 * 16e9 < held < 15.75 * 2**30
     with pytest.raises(Exception, match="hbm"):
         compiled("dots")
+
+
+def test_kimi_linear_adamw_step_at_published_widths(v5e, monkeypatch):
+    """The benchmark's `kimi-linear-48b-a3b-l16k` step on one chip:
+    Kimi-Linear-48B-A3B at its published widths, the first 5 of 27 layers (KDA
+    and a dense FFN; KDA, KDA, MLA, KDA with experts: four runs, inlined), 8
+    of 256 routed experts a layer held here beside the shared one, 20,480
+    rows of the vocabulary, 1 x 16,384 tokens, flash with keys of 192 and
+    values of 128, the configuration file's remat, AdamW with float32
+    moments, weights and state donated.  It fits the chip: the compiler's
+    own peak is 15.48 GB of 16.91 (15.75 GiB) and the sum the cell reports
+    18.14 GB.  Two flash kernels for the one MLA layer and, for each of the
+    four expert layers, the grouped matmuls of one pass of the held experts'
+    loops: `gmm` forward (3), for the rows' gradients (3) and, the backward
+    loop forming what it does not keep, gate and up again (2), `tgmm` for
+    the weights' gradients (3); the forward loop that `"full"` replays is
+    dead there and gone.  The KDA recurrence is no Mosaic kernel: one forward and
+    one backward scan over 256 chunks a KDA layer (and the backward pass's
+    map over groups of heads), none under `rematted_computation`."""
+    import dataclasses
+    import json
+    import os
+
+    import optax
+    from jax.sharding import Mesh
+
+    from torchmpi_tpu.models import llama
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "kimi-linear-48b-a3b.json")) as fh:
+        file = json.load(fh)
+    run = file["run"]
+    published = llama.kimi_linear_48b_a3b()
+    cfg = dataclasses.replace(
+        published, n_layers=5, layer_kinds=published.layer_kinds[:5],
+        experts_held=(0, 8), vocab=20480)
+    assert (file["num_hidden_layers"], file["num_experts"],
+            file["vocab_size"]) == (5, 8, 20480)
+    assert [n for *_, n in llama.layer_runs(cfg)] == [1, 2, 1, 1]
+    one = SingleDeviceSharding(v5e[0])
+    place = lambda tree: jax.tree.map(
+        lambda a: _sds(a.shape, a.dtype, one), tree)
+    params = jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0), cfg,
+                                               dtype=jnp.bfloat16))
+    assert sum(a.size for a in jax.tree.leaves(params)) == 602_450_816
+    adamw = optax.adamw(run["optimizer"]["learning_rate"], b1=0.9, b2=0.95,
+                        weight_decay=0.1)
+    f32 = lambda tree: jax.tree.map(lambda a: a.astype(jnp.float32), tree)
+
+    def update(grads, state, params):       # moments float32, as the runner
+        updates, state = adamw.update(f32(grads), state, f32(params))
+        return jax.tree.map(lambda u, p: u.astype(p.dtype), updates,
+                            params), state
+
+    optimizer = optax.GradientTransformation(lambda p: adamw.init(f32(p)),
+                                             update)
+    state = jax.eval_shape(optimizer.init, params)
+    mesh = Mesh([v5e[0]], ("dp",))
+    tokens = _sds((1, 16384), jnp.int32, one)
+    step = llama.make_train_step(cfg, mesh, attn="flash", optimizer=optimizer,
+                                 remat=run["remat"],
+                                 loss_chunk=run["loss_chunk"])
+    program = step.lower(place(params), place(state), tokens,
+                         tokens).compile()
+    text = program.as_text().splitlines()
+    kernels = [line for line in text
+               if 'custom_call_target="tpu_custom_call"' in line]
+    named = lambda what: sum(bool(re.search(what, line)) for line in kernels)
+    assert run["remat"] == "full"
+    assert (named("flash_fwd"), named("flash_bwd")) == (1, 1)
+    assert all("/mla/" in line for line in kernels if "flash_" in line)
+    assert named(r"jit\(gmm\)") == 4 * 8 and named(r"jit\(tgmm\)") == 4 * 3
+    assert len(kernels) == 46
+    assert not any("rematted_computation" in line for line in kernels
+                   if "flash_" in line)
+    # A KDA layer's loops: the forward scan over the chunks, and in the
+    # backward pass the map over groups of heads with the reverse scan in it.
+    loops = [line for line in text if " while(" in line and "/kda/" in line]
+    assert sum("jvp(attn)/kda/closed_call/while" in line
+               for line in loops) == 4
+    assert sum("checkpoint/attn/kda/while" in line for line in loops) == 8
+    assert sum("kda/while/body/closed_call/while" in line
+               for line in loops) == 4
+    assert len(loops) == 12
+    assert not any("rematted_computation" in line for line in loops)
+    m = program.memory_analysis()
+    held = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    # weights and both float32 moments donated: 10 bytes a parameter
+    assert m.alias_size_in_bytes > 10 * 602_000_000
+    # That it compiled is the check that it fits 15.75 GiB.  The compiler's
+    # own peak is 15.48 GB; arguments plus temporaries, the sum the cell
+    # reports as `hbm_program_gb`, counts the donated state's copies twice
+    # and the loops' carried sums beside their first values: 18.14 GB.
+    assert 8e9 < m.peak_memory_in_bytes < 15.75 * 2**30
+    assert m.peak_memory_in_bytes < held < 18.5e9

@@ -1,0 +1,521 @@
+"""Kimi-Linear-style stacks (``llama.kimi_linear_48b_a3b``): the chunked KDA
+recurrence against the token-by-token one, flash attention with keys and
+values of different widths, the KDA and latent-attention blocks, the sigmoid
+router and a chip's share of the experts against the plain reference the
+benchmark keeps (``benchmark/reference/kimi-linear-48b-a3b.py``, which imports
+nothing of the program), the runs a stack is built from, the remat policies,
+the frozen selection bias, the refusals and the names in the device program.
+Small widths, float32, the CPU."""
+
+import dataclasses
+import importlib.util
+import os
+import re
+
+import numpy as np
+import optax
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from torchmpi_tpu.models import llama
+from torchmpi_tpu.ops import flash_attention
+from torchmpi_tpu.ops import kda as kda_ops
+from torchmpi_tpu.ops.flash_attention import _flash_bh, _flash_bh_bwd
+from torchmpi_tpu.parallel import mesh as pmesh
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+PUBLISHED = llama.kimi_linear_48b_a3b()
+
+
+@pytest.fixture(scope="module")
+def reference():
+    path = os.path.join(ROOT, "benchmark", "reference",
+                        "kimi-linear-48b-a3b.py")
+    spec = importlib.util.spec_from_file_location("kimi_reference", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def kimi_tiny(n_layers=5, n_experts=8, held=(0, 2), k=2):
+    """The published pattern's first ``n_layers`` layers at toy widths."""
+    return dataclasses.replace(
+        PUBLISHED, vocab=128, d_model=64, n_layers=n_layers, n_heads=4,
+        n_kv_heads=4, d_ff=32, dense_d_ff=96, max_seq=256,
+        n_experts=n_experts, expert_top_k=k, kda_heads=4, kda_head_dim=16,
+        kv_lora_rank=24, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        v_head_dim=16, layer_kinds=PUBLISHED.layer_kinds[:n_layers],
+        experts_held=held)
+
+
+def file_of(cfg):
+    """The configuration file's keys the reference reads, for ``cfg``."""
+    kda = [i + 1 for i, (m, _) in enumerate(PUBLISHED.layer_kinds)
+           if m == "kda"]
+    mla = [i + 1 for i, (m, _) in enumerate(PUBLISHED.layer_kinds)
+           if m == "mla"]
+    first, held = cfg.experts_held or (0, cfg.n_experts)
+    return {
+        "hidden_size": cfg.d_model, "num_hidden_layers": cfg.n_layers,
+        "num_attention_heads": cfg.n_heads, "rms_norm_eps": cfg.norm_eps,
+        "kv_lora_rank": cfg.kv_lora_rank,
+        "qk_nope_head_dim": cfg.qk_nope_head_dim,
+        "qk_rope_head_dim": cfg.qk_rope_head_dim,
+        "v_head_dim": cfg.v_head_dim, "first_k_dense_replace": 1,
+        "linear_attn_config": {
+            "kda_layers": kda, "full_attn_layers": mla,
+            "num_heads": cfg.kda_heads, "head_dim": cfg.kda_head_dim,
+            "short_conv_kernel_size": cfg.kda_conv},
+        "published": {"num_experts": cfg.n_experts},
+        "num_experts": held, "experts_held_first": first,
+        "num_experts_per_token": cfg.expert_top_k,
+        "num_shared_experts": cfg.n_shared_experts,
+        "moe_renormalize": cfg.moe_renormalize,
+        "routed_scaling_factor": cfg.routed_scale,
+    }
+
+
+def rel(a, b):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    return float(np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30))
+
+
+def layer_of(params, run, i=0):
+    return jax.tree.map(lambda a: a[i], params["layers"][run])
+
+
+# ----------------------------------------------------------- the recurrence
+
+def kda_inputs(L, decay, B=2, H=2, D=32, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)
+    q = unit(jax.random.normal(ks[0], (B, L, H, D))) * D ** -0.5
+    k = unit(jax.random.normal(ks[1], (B, L, H, D)))
+    v = jax.random.normal(ks[2], (B, L, H, D))
+    g = -decay * jax.nn.softplus(jax.random.normal(ks[3], (B, L, H, D)))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (B, L, H)))
+    return q, k, v, g, beta
+
+
+@pytest.mark.parametrize("L,decay", [
+    (64, 0.1), (200, 1.0), (130, 30.0), (256, 1e-3), (40, 80.0)],
+    ids=["one-chunk", "ragged-200", "decay-to-0", "decay-near-1",
+         "short-and-strong"])
+def test_chunked_recurrence_is_the_token_recurrence(L, decay):
+    """Values and all five gradients, at lengths that are and are not whole
+    chunks, with decays near 1 (g about -1e-3) and near 0 (g to -100 a token:
+    ``e^{-G}`` of one chunk would be e^6000) and nothing overflowing."""
+    x = kda_inputs(L, decay)
+    o, want = kda_ops.kda(*x), kda_ops.kda_recurrent(*x)
+    assert bool(jnp.all(jnp.isfinite(o)))
+    assert rel(o, want) < 2e-6
+    w = jax.random.normal(jax.random.PRNGKey(9), o.shape)
+    grads = lambda fn: jax.grad(lambda *a: jnp.sum(fn(*a) * w),
+                                argnums=(0, 1, 2, 3, 4))(*x)
+    for name, got, ref in zip("q k v g beta".split(), grads(kda_ops.kda),
+                              grads(kda_ops.kda_recurrent)):
+        assert bool(jnp.all(jnp.isfinite(got))), name
+        # (the log-decay's gradient under strong decay is what float32 leaves
+        # of terms near 1e-9)
+        assert rel(got, ref) < (1e-3 if name == "g" else 2e-4), name
+
+
+@pytest.mark.parametrize("spread", [0.3, 0.0], ids=["correlated", "collinear"])
+def test_correlated_keys_and_strong_writes_stay_stable(spread):
+    """Keys of a chunk nearly (or wholly) one direction, beta near 1, hardly
+    any decay: what one optimizer step made of seeded keys on the chip.  The
+    unit triangular inverse by the powers of N (``(I + N)(I + N^2) ...``)
+    cancels 1e17 down to 1 there and the state grows without bound; by
+    substitution in blocks no entry passes 1."""
+    ks = jax.random.split(jax.random.PRNGKey(0), 6)
+    unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)
+    B, L, H, D = 1, 512, 2, 32
+    k = unit(jax.random.normal(ks[5], (1, 1, H, D))
+             + spread * jax.random.normal(ks[1], (B, L, H, D)))
+    x = (unit(jax.random.normal(ks[0], (B, L, H, D))) * D ** -0.5, k,
+         jax.random.normal(ks[2], (B, L, H, D)),
+         -1e-3 * jax.nn.softplus(jax.random.normal(ks[3], (B, L, H, D))),
+         jax.nn.sigmoid(4.0 + jax.random.normal(ks[4], (B, L, H))))
+    want = kda_ops.kda_recurrent(*x)
+    assert rel(kda_ops.kda(*x), want) < 1e-5
+    w = jax.random.normal(jax.random.PRNGKey(9), want.shape)
+    grads = lambda fn: jax.grad(lambda *a: jnp.sum(fn(*a) * w),
+                                argnums=(0, 1, 2, 3, 4))(*x)
+    for got, ref in zip(grads(kda_ops.kda), grads(kda_ops.kda_recurrent)):
+        assert rel(got, ref) < 1e-4
+    N = -0.9 * jnp.tril(jnp.ones((64, 64)), -1)
+    inverse = kda_ops._unit_lower_inverse(N)
+    assert float(jnp.max(jnp.abs(inverse))) <= 1.0
+    np.testing.assert_allclose(inverse, jnp.linalg.inv(jnp.eye(64) - N),
+                               atol=1e-6)
+
+
+def test_recurrence_in_bfloat16_keeps_a_float32_state():
+    """bfloat16 operands, float32 state and decay sums: the output stays
+    within bfloat16's rounding of the float32 recurrence over 8 chunks."""
+    x = kda_inputs(512, 0.05, B=1)
+    cast = lambda a: a.astype(jnp.bfloat16)
+    o = kda_ops.kda(cast(x[0]), cast(x[1]), cast(x[2]), x[3], x[4])
+    assert o.dtype == jnp.bfloat16
+    assert rel(o.astype(jnp.float32), kda_ops.kda_recurrent(*x)) < 2e-2
+    assert kda_ops.n_chunks(512) == 8 and kda_ops.n_chunks(130) == 3
+
+
+# ------------------------------------------------- flash with Dk != Dv
+
+def _qkv(L, H, Dk, Dv, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return (jax.random.normal(ks[0], (1, L, H, Dk)),
+            jax.random.normal(ks[1], (1, L, H, Dk)),
+            jax.random.normal(ks[2], (1, L, H, Dv)),
+            jax.random.normal(ks[3], (1, L, H, Dv)))
+
+
+@pytest.mark.parametrize("Dk,Dv", [(48, 32), (24, 32), (32, 32)])
+def test_flash_with_values_of_their_own_width(Dk, Dv):
+    """q and k ``Dk`` wide, v and o ``Dv``: output and all three gradients
+    against full attention, several blocks a side; the equal case too."""
+    q, k, v, w = _qkv(256, 2, Dk, Dv)
+    flash = lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, causal=True, block_q=64, block_k=128) * w)
+    full = lambda q, k, v: jnp.sum(
+        llama._causal_attention(q, k, v, Dk ** -0.5) * w)
+    assert flash_attention(q, k, v, causal=True).shape == (1, 256, 2, Dv)
+    assert abs(float(flash(q, k, v)) - float(full(q, k, v))) < 1e-3
+    for got, want in zip(jax.grad(flash, (0, 1, 2))(q, k, v),
+                         jax.grad(full, (0, 1, 2))(q, k, v)):
+        assert got.shape == want.shape and rel(got, want) < 1e-5
+
+
+def test_flash_streaming_backward_with_values_of_their_own_width():
+    """The two streaming kernels give what the one kernel gives."""
+    q, k, v, do = (a.transpose(0, 2, 1, 3).reshape(2, 128, -1)
+                   for a in _qkv(128, 2, 48, 32))
+    kw = dict(causal=True, block_q=32, block_k=64, interpret=True)
+    o, lse = _flash_bh(q, k, v, **kw)
+    delta = jnp.sum(do * o, axis=-1, keepdims=True)
+    one = _flash_bh_bwd(q, k, v, do, lse, delta, **kw)
+    two = _flash_bh_bwd(q, k, v, do, lse, delta, vmem_budget=0, **kw)
+    assert [a.shape for a in one] == [q.shape, k.shape, v.shape]
+    for a, b in zip(one, two):
+        assert rel(a, b) < 1e-6
+
+
+# ------------------------------------------------- blocks against the reference
+
+@pytest.fixture(scope="module")
+def model():
+    cfg = kimi_tiny()
+    return cfg, llama.init(jax.random.PRNGKey(0), cfg)
+
+
+def test_kda_block_against_the_reference(model, reference):
+    cfg, params = model
+    lp = layer_of(params, 1)                 # a KDA layer of the moe run
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, 96, cfg.d_model))
+    want = jax.vmap(lambda s: reference.kda_mixer(file_of(cfg), lp, s))(x)
+    assert rel(llama._kda_block(cfg, lp, x), want) < 1e-5
+
+
+@pytest.mark.parametrize("attn", ["full", "flash"])
+def test_mla_block_against_the_reference(model, reference, attn):
+    cfg, params = model
+    assert llama.layer_runs(cfg)[2][:2] == ("mla", "moe")
+    lp = layer_of(params, 2)
+    x = jax.random.normal(jax.random.PRNGKey(4), (2, 64, cfg.d_model))
+    want = jax.vmap(lambda s: reference.mla_mixer(file_of(cfg), lp, s))(x)
+    impl = llama._mixer_impls(cfg, attn, None)["mla"]
+    assert rel(llama._mla_block(cfg, lp, x, impl), want) < 1e-5
+
+
+def test_sigmoid_router_with_a_bias_that_changes_the_choice(model, reference):
+    """The bias moves the top-k choice and nothing else: weights are the
+    chosen scores without it, renormalised and scaled; the whole layer (all
+    experts held) is the reference's; the bias's gradient is exactly zero."""
+    cfg, params = model
+    cfg = dataclasses.replace(cfg, experts_held=None)
+    params = llama.init(jax.random.PRNGKey(0), cfg)
+    lp = layer_of(params, 1)
+    x = jax.random.normal(jax.random.PRNGKey(5), (1, 64, cfg.d_model))
+    xt = x.reshape(-1, cfg.d_model)
+    weight, expert, counts, _ = llama._route_tokens(cfg, lp, xt)
+    unbiased = llama._route_tokens(
+        cfg, {**lp, "router_bias": jnp.zeros_like(lp["router_bias"])}, xt)[1]
+    assert int(jnp.sum(jnp.sort(expert) != jnp.sort(unbiased))) > 0
+    assert int(jnp.sum(counts)) == cfg.expert_top_k * 64
+    np.testing.assert_allclose(jnp.sum(weight, axis=-1), cfg.routed_scale,
+                               rtol=1e-6)
+    want = reference.experts_ffn(file_of(cfg), lp, xt)
+    got, _ = llama._moe_ffn(cfg, lp, x)
+    assert rel(got.reshape(want.shape), want) < 1e-5
+    g = jax.grad(lambda b: jnp.sum(llama._moe_ffn(
+        cfg, {**lp, "router_bias": b}, x)[0] ** 2))(lp["router_bias"])
+    assert float(jnp.max(jnp.abs(g))) == 0.0
+
+
+@pytest.mark.parametrize("held", [2, 4])
+def test_the_shares_add_up(reference, held):
+    """Over all ``n_experts / held`` shares of a layer, the held experts'
+    parts, with the shared expert counted once, sum to the uncut reference's
+    layer output; a share's weights are the uncut layer's experts."""
+    whole = kimi_tiny(held=None)
+    full = layer_of(llama.init(jax.random.PRNGKey(0), whole), 1)
+    x = jax.random.normal(jax.random.PRNGKey(6), (1, 64, whole.d_model))
+    xt = x.reshape(-1, whole.d_model)
+    want = reference.experts_ffn(file_of(whole), full, xt)
+    shared = reference.swiglu(xt, full["shared_gate"], full["shared_up"],
+                              full["shared_down"])
+    total = 0.0
+    for first in range(0, whole.n_experts, held):
+        cfg = kimi_tiny(held=(first, held))
+        lp = layer_of(llama.init(jax.random.PRNGKey(0), cfg), 1)
+        np.testing.assert_array_equal(lp["w_up"],
+                                      full["w_up"][first:first + held])
+        part, _ = llama._moe_ffn(cfg, lp, x)
+        assert rel(part.reshape(xt.shape), reference.experts_ffn(
+            file_of(cfg), lp, xt)) < 1e-5
+        total = total + part.reshape(xt.shape) - shared
+    assert rel(total + shared, want) < 1e-5
+
+
+@pytest.mark.parametrize("bias, passes", [(0.0, 1), (10.0, 8)])
+def test_every_held_unit_is_computed(reference, monkeypatch, bias, passes):
+    """The held experts take their units a pass of static rows at a time, as
+    many passes as arrived: a bias that sends every token to the two held
+    experts of 32 makes 128 units where a pass takes 16, and the layer's
+    output and every gradient are still the reference's, as they are where
+    one pass is enough."""
+    cfg = kimi_tiny(n_experts=32, held=(0, 2))
+    assert llama.held_pass_rows(cfg, 64) == 32
+    assert llama.held_pass_rows(kimi_tiny(), 64) == 128   # never over k * T
+    monkeypatch.setattr(llama, "_HELD_PASS_OVER_SHARE", 2)
+    assert llama.held_pass_rows(cfg, 64) == 16
+    lp = layer_of(llama.init(jax.random.PRNGKey(0), cfg), 1)
+    lp["router_bias"] = jnp.zeros(32).at[:2].set(bias)
+    x = jax.random.normal(jax.random.PRNGKey(7), (1, 64, cfg.d_model))
+    arrived = llama._route_tokens(cfg, lp, x[0])[2][:2]
+    assert -(-int(jnp.sum(arrived)) // 16) == passes
+    ours = lambda lp, x: jnp.sum(jnp.sin(llama._moe_ffn(cfg, lp, x)[0]))
+    theirs = lambda lp, x: jnp.sum(jnp.sin(reference.experts_ffn(
+        file_of(cfg), lp, x[0])))
+    got, want = (jax.value_and_grad(f, argnums=(0, 1))(lp, x)
+                 for f in (ours, theirs))
+    assert float(got[0]) == pytest.approx(float(want[0]), rel=1e-5)
+    assert rel(got[1][1], want[1][1]) < 1e-5
+    for name in lp:
+        if name in want[1][0] and name != "router_bias":
+            assert rel(got[1][0][name], want[1][0][name]) < 1e-5, name
+
+
+# ------------------------------------------------------------- the stack
+
+def test_the_published_pattern_builds_its_runs():
+    kinds = PUBLISHED.layer_kinds
+    assert len(kinds) == 27 and kinds[0] == ("kda", "dense")
+    assert [i + 1 for i, (m, _) in enumerate(kinds) if m == "mla"] == [
+        4, 8, 12, 16, 20, 24, 27]
+    assert all(f == "moe" for _, f in kinds[1:])
+    runs = llama.layer_runs(PUBLISHED)
+    assert len(runs) == 15 and sum(n for *_, n in runs) == 27
+    assert runs[:4] == (("kda", "dense", 1), ("kda", "moe", 2),
+                        ("mla", "moe", 1), ("kda", "moe", 3))
+    assert max(n for *_, n in runs) <= llama._INLINE_MAX_LAYERS
+    assert llama.layer_runs(llama.olmoe_1b_7b()) == (("attn", "moe", 16),)
+    with pytest.raises(ValueError, match="neither list"):
+        llama.layer_kinds(3, [1, 2], [], 1)
+
+
+def test_the_published_27_layers_build_and_run():
+    cfg = kimi_tiny(n_layers=27)
+    params = llama.init(jax.random.PRNGKey(0), cfg)
+    assert len(params["layers"]) == 15
+    specs = llama.param_specs(cfg)
+    assert jax.tree.structure(jax.tree.map(lambda a: 0, params)) == \
+        jax.tree.structure(jax.tree.map(lambda s: 0, specs,
+                                        is_leaf=lambda s: isinstance(
+                                            s, jax.sharding.PartitionSpec)))
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (1, 64), 0, cfg.vocab)
+    logits = jax.jit(lambda p, t: llama.apply(cfg, p, t))(params, tokens)
+    assert logits.shape == (1, 64, cfg.vocab)
+    assert bool(jnp.all(jnp.isfinite(logits)))
+    counts = llama.expert_unit_counts(cfg, params, tokens)
+    assert counts.shape == (26, cfg.n_experts)
+    assert [int(c) for c in counts.sum(axis=1)] == [2 * 64] * 26
+
+
+@pytest.mark.parametrize("name,leaves,checksum,loss", [
+    ("tiny", 12, 55238.10294479898, 6.17431116104126),
+    ("moe_tiny", 13, 156614.26303055455, 6.086923122406006),
+    ("olmoe", 15, 208743.01844608856, 6.17168664932251),
+    ("looped", 16, 99763.4812040137, 5.88877534866333)])
+def test_a_homogeneous_configuration_is_what_it_was(name, leaves, checksum,
+                                                    loss):
+    """One run, the parameter tree and the weights for a seed that the
+    commit before runs existed gave (numbers taken from it), and its loss."""
+    cfg = {
+        "tiny": llama.tiny(), "moe_tiny": llama.moe_tiny(),
+        "olmoe": dataclasses.replace(
+            llama.moe_tiny(), n_kv_heads=4, capacity_factor=None,
+            moe_renormalize=False, moe_z_coef=1e-3, qk_norm=True),
+        "looped": dataclasses.replace(
+            llama.tiny(), n_kv_heads=4, ut_steps=3, sandwich_norm=True,
+            exit_gate=True, exit_entropy_coef=0.1)}[name]
+    assert len(llama.layer_runs(cfg)) == 1
+    params = llama.init(jax.random.PRNGKey(7), cfg)
+    assert isinstance(params["layers"], dict)
+    flat = jax.tree.leaves(params)
+    assert len(flat) == leaves
+    total = sum(np.sum(np.abs(np.asarray(a, np.float64))) * (i + 1)
+                for i, a in enumerate(flat))
+    assert total == pytest.approx(checksum, rel=1e-12)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 32), 0, 256)
+    got = jax.jit(llama.make_loss_fn(cfg, attn="flash", remat="dots",
+                                     loss_chunk=16))(params, (tokens, tokens))
+    assert float(got) == pytest.approx(loss, rel=1e-6)
+
+
+@pytest.fixture(scope="module")
+def sample():
+    return (jax.random.randint(jax.random.PRNGKey(1), (2, 96), 0, 128),
+            jax.random.randint(jax.random.PRNGKey(2), (2, 96), 0, 128))
+
+
+@pytest.fixture(scope="module")
+def plain(model, reference, sample):
+    cfg, params = model
+    return jax.jit(lambda p, s: reference.loss_and_grads(file_of(cfg), p, s))(
+        params, sample)
+
+
+def test_five_layers_against_the_reference(model, reference, sample, plain):
+    """Loss, logits and every leaf's gradient of the five-layer model (all
+    three layer kinds, a share of the experts, the chunked head) against the
+    plain reference."""
+    cfg, params = model
+    want_loss, want_logits, want = plain
+    loss_fn = llama.make_loss_fn(cfg, attn="flash", loss_chunk=32)
+    loss, grads = jax.jit(jax.value_and_grad(loss_fn))(params, sample)
+    assert float(loss) == pytest.approx(float(want_loss), rel=1e-5)
+    logits = llama.apply(cfg, params, sample[0], attn="flash")
+    assert rel(logits, want_logits) < 1e-4
+    flat = jax.tree_util.tree_flatten_with_path(want)[0]
+    assert len(flat) == len(jax.tree.leaves(grads)) > 80
+    for (path, w), g in zip(flat, jax.tree.leaves(grads)):
+        if path[-1].key == "router_bias":   # moves the choice alone
+            assert float(jnp.max(jnp.abs(g))) == 0.0 == float(jnp.max(w))
+        else:
+            assert rel(g, w) < 2e-3, jax.tree_util.keystr(path)
+
+
+@pytest.mark.parametrize("remat", ["dots", "full"])
+def test_remat_gives_the_gradients_and_runs_nothing_twice(model, sample,
+                                                          remat):
+    """``"dots"`` and ``"full"`` give ``"none"``'s gradients, and the step's
+    jaxpr holds each flash kernel and each KDA scan once forward and once
+    backward: neither policy replays a kernel or the recurrence."""
+    cfg, params = model
+    grads = lambda r: jax.jit(jax.grad(llama.make_loss_fn(
+        cfg, attn="flash", remat=r, loss_chunk=32)))(params, sample)
+    for g, w in zip(jax.tree.leaves(grads(remat)),
+                    jax.tree.leaves(grads("none"))):
+        assert rel(g, w) < 1e-4
+    mesh = pmesh.make_mesh({"dp": 1}, devices=jax.devices()[:1])
+    step = llama.make_train_step(cfg, mesh, attn="flash", remat=remat,
+                                 loss_chunk=32)
+    tokens = jnp.zeros((1, 160), jnp.int32)
+    found = _scans_and_kernels(jax.make_jaxpr(step)(
+        params, None, tokens, tokens).jaxpr)
+    # 160 tokens are 3 chunks: a scan of that length is the recurrence's (the
+    # head's has 5, the grouped matmuls' metadata 2 experts), one forward and
+    # one backward for each of the four KDA layers; the one latent layer's two
+    # flash kernels.
+    chunks = kda_ops.n_chunks(160)
+    assert found.count(("scan", chunks, False)) == 4
+    assert found.count(("scan", chunks, True)) == 4
+    assert [f for f in found if f[0] == "pallas_call"
+            and "flash" in (f[1] or "")] == [("pallas_call", "flash_fwd"),
+                                              ("pallas_call", "flash_bwd")]
+
+
+def _scans_and_kernels(jaxpr):
+    """``("scan", length, reverse)`` and ``("pallas_call", name)`` of a
+    jaxpr's equations, in order, sub-jaxprs (checkpoint, custom_vjp, pjit)
+    included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(("pallas_call", eqn.params["name"]))
+            continue
+        if eqn.primitive.name == "scan":
+            found.append(("scan", eqn.params["length"],
+                          eqn.params["reverse"]))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _scans_and_kernels(sub)
+    return found
+
+
+def test_adamw_leaves_the_selection_bias_alone(model, sample):
+    """Weight decay would move a bias whose gradient is zero: the step hands
+    every ``router_bias`` back to the bit and steps the router beside it."""
+    cfg, params = model
+    mesh = pmesh.make_mesh({"dp": 1}, devices=jax.devices()[:1])
+    optimizer = optax.adamw(1e-2, weight_decay=0.1)
+    step = llama.make_train_step(cfg, mesh, optimizer=optimizer, attn="flash",
+                                 remat="full", loss_chunk=32)
+    stepped, _, loss = step(jax.tree.map(jnp.copy, params),
+                            optimizer.init(params), *sample)
+    assert np.isfinite(float(loss))
+    for new, old in zip(stepped["layers"], params["layers"]):
+        if "router_bias" in old:
+            np.testing.assert_array_equal(new["router_bias"],
+                                          old["router_bias"])
+            assert float(jnp.max(jnp.abs(new["router"] - old["router"]))) > 0
+    assert sum("router_bias" in run for run in params["layers"]) == 3
+
+
+@pytest.mark.parametrize("call,missing", [
+    (lambda cfg, p: llama._decode_step(cfg, p, None, None, None),
+     "recurrent-state cache"),
+    (lambda cfg, p: llama._prefill(cfg, p, None, jnp.zeros((1, 8), int)),
+     "latent cache"),
+    (lambda cfg, p: llama.make_generate_fn(cfg, 8, 8), "two caches"),
+    (lambda cfg, p: llama.make_pp_train_step(cfg, None, 2),
+     "stage split by run"),
+    (lambda cfg, p: llama.make_1f1b_train_step(cfg, None, 2),
+     "stage split by run"),
+    (lambda cfg, p: llama.apply(cfg, p, jnp.zeros((1, 8), int), attn="ring"),
+     "one head width")],
+    ids=["decode", "prefill", "generate", "gpipe", "1f1b", "ring"])
+def test_the_refusals_say_their_reason(model, call, missing):
+    cfg, params = model
+    with pytest.raises(NotImplementedError, match=missing):
+        call(cfg, params)
+
+
+def test_the_programs_names(model, sample):
+    """``kda`` (the recurrence alone) and ``mla`` (the whole latent mixer)
+    inside ``attn``, ``moe.shared`` beside the four ``moe.`` scopes, ``ffn``
+    for the dense first layer, forward and backward."""
+    cfg, params = model
+    mesh = pmesh.make_mesh({"dp": 1}, devices=jax.devices()[:1])
+    step = llama.make_train_step(cfg, mesh, attn="flash", remat="full",
+                                 loss_chunk=32)
+    shapes = jax.eval_shape(lambda: params)
+    names = set(re.findall(r'loc\("([^"]+)"', step.lower(
+        shapes, None, *sample).as_text(debug_info=True)))
+    part = lambda scope: re.compile(
+        r"(^|[/(])" + re.escape(scope) + r"([/)]|$)")
+    for scope in ("embed", "attn", "kda", "mla", "ffn", "moe.router",
+                  "moe.dispatch", "moe.experts", "moe.combine", "moe.shared",
+                  "final_norm", "head_loss", "optimizer"):
+        assert any(part(scope).search(n) for n in names), scope
+    ops = [n for n in names if n.startswith("jit(step)")]
+    for inner in ("kda", "mla"):
+        assert all(re.search(r"attn\)*/(.*/)?" + inner, n)
+                   for n in ops if part(inner).search(n)), inner
+    assert any(part("kda").search(n) and "transpose(" in n for n in names)
+    assert any("mla" in n and "flash_fwd" in n for n in names)
+    assert any("mla" in n and "flash_bwd" in n for n in names)
+    assert not any("rope" in n for n in names)

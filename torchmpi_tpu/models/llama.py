@@ -13,6 +13,13 @@ machinery (SURVEY.md §5.7, §7 item 7-8).  TPU-first design:
   the scan's copies (:func:`apply`, ``layer_loop``); a looped model
   (``Config(ut_steps=T)``, Ouro-style — :func:`ouro_2_6b`) runs the one
   stack T times with shared weights, a head and an exit gate at every step;
+  a stack whose layers differ in kind (``Config(layer_kinds=...)``, Kimi
+  Linear-style — :func:`kimi_linear_48b_a3b`: KDA linear-attention layers
+  among latent-attention ones, a dense first layer before expert layers) is
+  a sequence of homogeneous runs (:func:`layer_runs`), ``params["layers"]``
+  a tuple of such stacks, and the depth that decides between scan and
+  inlining is each run's own: the runs follow one another inlined, so kinds
+  that alternate every few layers mean every layer inlined;
 * :func:`param_specs` returns the PartitionSpec pytree for Megatron-style
   tensor parallelism (qkv/gate/up column-sharded, o/down row-sharded) —
   under pjit GSPMD inserts exactly the one-psum-per-block collectives the
@@ -30,7 +37,11 @@ machinery (SURVEY.md §5.7, §7 item 7-8).  TPU-first design:
 * mixture-of-experts FFN (``Config(n_experts=E, expert_top_k=k)``,
   Mixtral-style — :func:`mixtral_8x7b`): GShard dispatch/combine einsums
   with expert weights sharded over ``ep`` (:func:`_moe_ffn`), Switch
-  load-balance aux loss through the layer loop, dropless decode routing.
+  load-balance aux loss through the layer loop, dropless decode routing;
+  a dropless configuration sorts its routed units for a grouped matmul
+  (:func:`_moe_ffn_sorted`), with sigmoid scoring, a selection bias, a shared
+  expert and a chip's share of the experts (``Config(experts_held=...)``,
+  :func:`_held_experts`) where the configuration has them.
 
 Compute dtype is configurable (bfloat16 for TPU, float32 for CPU tests);
 norms, softmax, and the loss run in f32.
@@ -102,6 +113,46 @@ class Config:
     # ``exit_entropy_coef``.
     exit_gate: bool = False
     exit_entropy_coef: float = 0.0
+    # A stack that is not homogeneous (Kimi Linear,
+    # :func:`kimi_linear_48b_a3b`): for every layer its mixer (``"attn"``, the
+    # softmax attention over ``n_heads`` heads of ``head_dim``; ``"kda"``,
+    # :func:`_kda_block`; ``"mla"``, :func:`_mla_block`) and its FFN
+    # (``"dense"`` or ``"moe"``); :func:`layer_kinds` builds it from a
+    # configuration file's lists.  None: every layer ``"attn"`` with the FFN
+    # that ``n_experts`` says, one run, the parameter tree it always had.
+    layer_kinds: Optional[Tuple[Tuple[str, str], ...]] = None
+    # KDA layers: heads of ``kda_head_dim`` for q, k and v alike, a causal
+    # depthwise convolution of ``kda_conv`` taps on each, and two low-rank
+    # pairs (d_model -> kda_head_dim -> heads * kda_head_dim) for the decay
+    # and the output gate.
+    kda_heads: int = 0
+    kda_head_dim: int = 128
+    kda_conv: int = 4
+    # MLA layers (``n_heads`` heads): keys of ``qk_nope_head_dim`` from the
+    # latent of ``kv_lora_rank`` beside ``qk_rope_head_dim`` that all heads
+    # share (not rotated: no layer of such a stack calls :func:`rope`),
+    # values of ``v_head_dim``; q projected whole (no q latent).
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # A "dense" layer's SwiGLU width in a stack whose experts are ``d_ff``.
+    dense_d_ff: int = 0
+    # Experts every token meets, beside the routed ones (scope ``moe.shared``):
+    # one SwiGLU of width ``n_shared_experts * d_ff``.
+    n_shared_experts: int = 0
+    # The router (:func:`_route_tokens`): ``"softmax"`` over the experts, or
+    # ``"sigmoid"`` scores; ``router_bias``: a selection bias a expert, added
+    # for the top-k choice alone (leaf ``router_bias``, never stepped);
+    # ``routed_scale`` multiplies the combine weights.
+    router_act: str = "softmax"
+    router_bias: bool = False
+    routed_scale: float = 1.0
+    # A chip's share of the experts: ``(first, count)``, a contiguous range of
+    # expert ids whose weights are held here.  The router stays ``n_experts``
+    # wide and the weights are normalised over all k choices; the layer
+    # returns the held experts' part (:func:`_held_experts`).  None: all.
+    experts_held: Optional[Tuple[int, int]] = None
 
     @property
     def head_dim(self) -> int:
@@ -114,6 +165,24 @@ class Config:
         if self.n_experts:
             assert 1 <= self.expert_top_k <= self.n_experts
             assert self.capacity_factor is None or self.capacity_factor > 0
+        assert self.router_act in ("softmax", "sigmoid")
+        if (self.router_act == "sigmoid" or self.router_bias
+                or self.n_shared_experts or self.experts_held):
+            # Written for the sorted, dropless expert layer alone.
+            assert self.n_experts and self.capacity_factor is None
+            assert self.moe_aux_coef == 0 and self.moe_z_coef == 0
+        if self.experts_held:
+            first, count = self.experts_held
+            assert 0 <= first and count >= 1
+            assert first + count <= self.n_experts
+        if self.layer_kinds is not None:
+            assert len(self.layer_kinds) == self.n_layers
+            assert self.ut_steps == 1 and not self.sandwich_norm
+            assert not self.qk_norm
+            for mixer, ffn in self.layer_kinds:
+                assert mixer in ("attn", "kda", "mla"), mixer
+                assert ffn in ("dense", "moe"), ffn
+                assert ffn == "dense" or self.n_experts
 
 
 def llama3_8b() -> Config:
@@ -150,6 +219,60 @@ def ouro_2_6b() -> Config:
                   exit_gate=True, exit_entropy_coef=0.1)
 
 
+def layer_kinds(n_layers: int, kda_layers: Sequence[int],
+                full_attn_layers: Sequence[int],
+                first_k_dense_replace: int) -> Tuple[Tuple[str, str], ...]:
+    """``Config.layer_kinds`` for the first ``n_layers`` layers of a model
+    whose file lists its KDA and its latent-attention layers (1-based, as
+    ``linear_attn_config`` does) and says how many leading layers are dense
+    (every other one a mixture of experts)."""
+    kda, mla = set(kda_layers), set(full_attn_layers)
+    if kda & mla:
+        raise ValueError(f"layers {sorted(kda & mla)} are listed twice")
+    kinds = []
+    for i in range(1, n_layers + 1):
+        if i not in kda | mla:
+            raise ValueError(f"layer {i} is in neither list")
+        kinds.append(("kda" if i in kda else "mla",
+                      "dense" if i <= first_k_dense_replace else "moe"))
+    return tuple(kinds)
+
+
+def kimi_linear_48b_a3b() -> Config:
+    """Kimi-Linear-48B-A3B geometry ("Kimi Linear: An Expressive, Efficient
+    Attention Architecture", Moonshot AI, 2025-10): 27 layers, KDA
+    linear-attention layers three to one with latent attention without
+    positions, a dense first layer, then 256 sigmoid-routed experts of width
+    1024, 8 a token, beside a shared one; no positional encoding at all."""
+    return Config(vocab=163840, d_model=2304, n_layers=27, n_heads=32,
+                  n_kv_heads=32, d_ff=1024, dense_d_ff=9216, max_seq=1048576,
+                  norm_eps=1e-5, n_experts=256, expert_top_k=8,
+                  capacity_factor=None, moe_aux_coef=0.0,
+                  moe_renormalize=True, n_shared_experts=1,
+                  router_act="sigmoid", router_bias=True, routed_scale=2.446,
+                  kda_heads=32, kda_head_dim=128, kda_conv=4,
+                  kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+                  v_head_dim=128,
+                  layer_kinds=layer_kinds(
+                      27, [1, 2, 3, 5, 6, 7, 9, 10, 11, 13, 14, 15, 17, 18, 19,
+                           21, 22, 23, 25, 26], [4, 8, 12, 16, 20, 24, 27], 1))
+
+
+def layer_runs(cfg: Config) -> Tuple[Tuple[str, str, int], ...]:
+    """The stack as homogeneous runs, ``(mixer, ffn, length)`` each:
+    consecutive layers of one kind.  A configuration without
+    ``layer_kinds`` is one run."""
+    if cfg.layer_kinds is None:
+        return (("attn", "moe" if cfg.n_experts else "dense", cfg.n_layers),)
+    runs = []
+    for kinds in cfg.layer_kinds:
+        if runs and runs[-1][:2] == kinds:
+            runs[-1] = (*kinds, runs[-1][2] + 1)
+        else:
+            runs.append((*kinds, 1))
+    return tuple(runs)
+
+
 def tiny(vocab: int = 256, seq: int = 64) -> Config:
     """Test-scale config for the 8-device CPU mesh."""
     return Config(vocab=vocab, d_model=64, n_layers=2, n_heads=4, n_kv_heads=2,
@@ -165,8 +288,78 @@ def moe_tiny(vocab: int = 256, seq: int = 64, n_experts: int = 4,
 
 # ---------------------------------------------------------------------- init
 
+def _init_run(key: jax.Array, cfg: Config, mixer: str, ffn: str, n: int,
+              dtype) -> Params:
+    """One run of a stack that is not homogeneous: ``n`` layers of one
+    mixer and one FFN kind, every leaf led by ``n``.  An expert's weights
+    are drawn from its id, so a share (``cfg.experts_held``) holds the very
+    experts the whole layer would."""
+    D, F = cfg.d_model, cfg.d_ff
+    keys = iter(jax.random.split(key, 24))
+    dense = lambda d_in, d_out: stack_dense(next(keys), n, d_in, d_out, dtype)
+    ones = lambda *shape: jnp.ones((n, *shape), jnp.float32)
+    normal = lambda shape, std: (jax.random.normal(
+        next(keys), (n, *shape), jnp.float32) * std)
+    lp = {"attn_norm": ones(D), "mlp_norm": ones(D)}
+    if mixer == "kda":
+        H, hd, taps = cfg.kda_heads, cfg.kda_head_dim, cfg.kda_conv
+        conv = lambda: normal((taps, H * hd), taps ** -0.5).astype(dtype)
+        # The decay as Mamba-style layers seed it: A in [1, 16], and a bias
+        # that puts softplus(dt_bias) log-uniform in [1e-3, 1e-1].
+        dt = jnp.exp(jax.random.uniform(next(keys), (n, H * hd), jnp.float32,
+                                        np.log(1e-3), np.log(1e-1)))
+        lp.update(
+            wq=dense(D, H * hd), wk=dense(D, H * hd), wv=dense(D, H * hd),
+            conv_q=conv(), conv_k=conv(), conv_v=conv(),
+            f_down=dense(D, hd), f_up=dense(hd, H * hd),
+            a_log=jnp.log(jax.random.uniform(next(keys), (n, H), jnp.float32,
+                                             1.0, 16.0)),
+            dt_bias=dt + jnp.log(-jnp.expm1(-dt)),
+            wb=dense(D, H), g_down=dense(D, hd), g_up=dense(hd, H * hd),
+            g_bias=jnp.zeros((n, H * hd), dtype), o_norm=ones(hd),
+            wo=dense(H * hd, D))
+    elif mixer == "mla":
+        H, r = cfg.n_heads, cfg.kv_lora_rank
+        qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+        lp.update(
+            wq=dense(D, H * qk), wkv_a=dense(D, r + cfg.qk_rope_head_dim),
+            kv_norm=ones(r),
+            wkv_b=dense(r, H * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+            wo=dense(H * cfg.v_head_dim, D))
+    else:
+        hd, H, KV = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
+        lp.update(wq=dense(D, H * hd), wk=dense(D, KV * hd),
+                  wv=dense(D, KV * hd), wo=dense(H * hd, D))
+    if ffn == "dense":
+        W = cfg.dense_d_ff or F
+        lp.update(w_gate=dense(D, W), w_up=dense(D, W), w_down=dense(W, D))
+        return lp
+    first, held = cfg.experts_held or (0, cfg.n_experts)
+
+    def experts(d_in, d_out):
+        key = next(keys)
+        draw = lambda e: jax.random.normal(jax.random.fold_in(key, e),
+                                           (n, d_in, d_out), jnp.float32)
+        w = jax.vmap(draw, out_axes=1)(first + jnp.arange(held))
+        return (w * np.sqrt(1.0 / d_in)).astype(dtype)
+
+    lp.update(router=normal((D, cfg.n_experts), 0.02).astype(dtype),
+              w_gate=experts(D, F), w_up=experts(D, F), w_down=experts(F, D))
+    if cfg.router_bias:
+        # Not zero, so that it changes choices: a twentieth of a sigmoid
+        # score's range is several ranks among 256 experts.
+        lp["router_bias"] = normal((cfg.n_experts,), 0.05)
+    if cfg.n_shared_experts:
+        S = cfg.n_shared_experts * F
+        lp.update(shared_gate=dense(D, S), shared_up=dense(D, S),
+                  shared_down=dense(S, D))
+    return lp
+
+
 def init(rng: jax.Array, cfg: Config, dtype=jnp.float32) -> Params:
-    """Stacked-layer parameter pytree (leaves lead with n_layers)."""
+    """Stacked-layer parameter pytree (leaves lead with n_layers).  With
+    ``cfg.layer_kinds`` the ``"layers"`` entry is a tuple of such stacks, one
+    for each run of :func:`layer_runs` (:func:`_init_run`)."""
     hd, H, KV = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
     # 9-way split exactly as v0.1: dense configs must produce identical
     # initial weights for the same seed across versions.  MoE-only keys are
@@ -216,9 +409,20 @@ def init(rng: jax.Array, cfg: Config, dtype=jnp.float32) -> Params:
                                  dtype)[:, 0],
                 "gate_b": jnp.zeros((1,), dtype)}
 
+    embed = (jax.random.normal(keys[0], (cfg.vocab, cfg.d_model), jnp.float32)
+             * 0.02).astype(dtype)
+    if cfg.layer_kinds is not None:
+        return {
+            "embed": embed,
+            "layers": tuple(
+                _init_run(jax.random.fold_in(rng, 16 + i), cfg, mixer, ffn,
+                          n, dtype)
+                for i, (mixer, ffn, n) in enumerate(layer_runs(cfg))),
+            "norm": jnp.ones((cfg.d_model,), jnp.float32),
+            "head": _dense(keys[8], cfg.d_model, cfg.vocab, dtype),
+        }
     return {
-        "embed": (jax.random.normal(keys[0], (cfg.vocab, cfg.d_model), jnp.float32)
-                  * 0.02).astype(dtype),
+        "embed": embed,
         "layers": {
             "attn_norm": jnp.ones((cfg.n_layers, cfg.d_model), jnp.float32),
             "wq": stack(keys[1], cfg.d_model, H * hd),
@@ -259,6 +463,23 @@ def param_specs(cfg: Config) -> Params:
     post = ({"attn_post_norm": P(None, None), "mlp_post_norm": P(None, None)}
             if cfg.sandwich_norm else {})
     gate = ({"gate_w": P(None), "gate_b": P(None)} if cfg.exit_gate else {})
+    if cfg.layer_kinds is not None:
+        # A run's projections by columns and rows as above, its experts over
+        # ``ep`` and ``tp`` too; every other leaf (norms, convolutions, the
+        # low-rank pairs, the router and its bias) whole on each device.
+        sharded = {"wq": col, "wk": col, "wv": col, "wkv_b": col, "wo": row,
+                   "shared_gate": col, "shared_up": col, "shared_down": row}
+        dense = {"w_gate": col, "w_up": col, "w_down": row}
+
+        def run_specs(run):
+            by_name = {**sharded, **(dense if run["w_gate"].ndim == 3 else ffn)}
+            return {name: by_name.get(name, P(*[None] * a.ndim))
+                    for name, a in run.items()}
+
+        shapes = jax.eval_shape(lambda: init(jax.random.PRNGKey(0), cfg))
+        return {"embed": P(None, None),
+                "layers": tuple(run_specs(run) for run in shapes["layers"]),
+                "norm": P(None), "head": P(None, AXIS_TP)}
     return {
         "embed": P(None, None),
         "layers": {
@@ -637,14 +858,37 @@ def _route_tokens(cfg: Config, lp: Params, xt: jax.Array):
     the configuration says.  Returns ``(weight (T, k) f32, expert (T, k)
     int32, counts (E,) int32, aux)``: ``counts[e]`` units go to expert e and
     sum to k*T; ``aux`` is the Switch load-balance term over the whole batch
-    (first choices), stacked with the z-loss where that has a weight."""
+    (first choices), stacked with the z-loss where that has a weight.
+
+    With ``cfg.router_act == "sigmoid"`` the scores are float32 sigmoids, the
+    k experts those with the largest ``score + router_bias`` (the bias moves
+    the choice alone: the weights are the chosen scores without it, and its
+    gradient is exactly zero), renormalised as published (``w / (sum + 1e-20)
+    ``), and there is no auxiliary term (``aux`` 0).  ``cfg.routed_scale``
+    multiplies the weights of either router."""
     E, k = cfg.n_experts, cfg.expert_top_k
     logits = xt.astype(jnp.float32) @ lp["router"].astype(jnp.float32)
+    if cfg.router_act == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        ranked = scores
+        if cfg.router_bias:
+            ranked = scores + lax.stop_gradient(lp["router_bias"])
+        expert = lax.top_k(ranked, k)[1]
+        weight = jnp.take_along_axis(scores, expert, axis=-1)
+        if cfg.moe_renormalize and k > 1:
+            weight = weight / (jnp.sum(weight, axis=-1, keepdims=True)
+                               + 1e-20)
+        counts = jnp.sum(jax.nn.one_hot(expert, E, dtype=jnp.int32),
+                         axis=(0, 1))
+        return (weight * cfg.routed_scale, expert, counts,
+                jnp.zeros((), jnp.float32))
     probs = jax.nn.softmax(logits, axis=-1)                         # (T, E)
     weight, expert = lax.top_k(probs, k)
     if cfg.moe_renormalize and k > 1:
         weight = weight / jnp.maximum(
             jnp.sum(weight, axis=-1, keepdims=True), 1e-9)
+    if cfg.routed_scale != 1.0:
+        weight = weight * cfg.routed_scale
     chosen = jax.nn.one_hot(expert, E, dtype=jnp.int32)             # (T, k, E)
     counts = jnp.sum(chosen, axis=(0, 1))
     aux = E * jnp.sum(jnp.mean(probs, axis=0)
@@ -673,7 +917,15 @@ def _moe_ffn_sorted(cfg: Config, lp: Params, x: jax.Array,
     that it requires and none twice.  The cost is k/E of the
     one-hot form's at C = G and does not grow with E.  On one device
     (``mesh`` None or of size 1) or under GSPMD on dp and tp; :func:`apply`
-    refuses an ``ep`` axis."""
+    refuses an ``ep`` axis.
+
+    For a chip's share of the experts (``cfg.experts_held``) the router is
+    still ``n_experts`` wide and its weights are normalised over all k
+    choices, but only the units whose expert is held here are computed
+    (:func:`_held_experts`, as dropless as the rest), and ``out`` is the held
+    experts' part of the layer's result plus the shared expert's, which every
+    chip computes alike.  What the absent experts would add is left out: on
+    one chip there is no exchange, and nothing stands in for one."""
     B, L, D = x.shape
     k = cfg.expert_top_k
     T = B * L
@@ -681,6 +933,21 @@ def _moe_ffn_sorted(cfg: Config, lp: Params, x: jax.Array,
     kernel = mesh is None or mesh.size == 1
     with jax.named_scope("moe.router"):
         weight, expert, counts, aux = _route_tokens(cfg, lp, xt)
+    if cfg.experts_held:
+        first, held = cfg.experts_held
+        R = held_pass_rows(cfg, T)
+        with jax.named_scope("moe.dispatch"):
+            local = expert.reshape(T * k) - first
+            here = (local >= 0) & (local < held)
+            # Held units first, by expert, in token order within one; whole
+            # passes, so that no slice of a pass is moved to fit.
+            order = jnp.pad(
+                jnp.argsort(jnp.where(here, local, held), stable=True),
+                (0, -(T * k) % R))
+        y = _held_experts(k, R, kernel, xt, weight.reshape(T * k), order,
+                          counts[first:first + held],
+                          (lp["w_gate"], lp["w_up"], lp["w_down"]))
+        return _add_shared_expert(cfg, lp, xt, y).reshape(B, L, D), aux
     with jax.named_scope("moe.dispatch"):
         order = jnp.argsort(expert.reshape(T * k), stable=True)
         inverse = jnp.argsort(order)
@@ -698,7 +965,147 @@ def _moe_ffn_sorted(cfg: Config, lp: Params, x: jax.Array,
                              kernel)                                # (kT, D)
     with jax.named_scope("moe.combine"):
         y = _combine_rows(ys, order, inverse, k)
-    return y.reshape(B, L, D), aux
+    return _add_shared_expert(cfg, lp, xt, y).reshape(B, L, D), aux
+
+
+def _add_shared_expert(cfg: Config, lp: Params, xt: jax.Array, y: jax.Array):
+    """``y`` plus the expert every token meets, on tokens ``xt`` (T, D): one
+    SwiGLU of width ``n_shared_experts * d_ff`` with weight 1.  ``y`` itself
+    for a configuration without one."""
+    if not cfg.n_shared_experts:
+        return y
+    with jax.named_scope("moe.shared"):
+        return y + ((jax.nn.silu(xt @ lp["shared_gate"])
+                     * (xt @ lp["shared_up"])) @ lp["shared_down"])
+
+
+# Rows of one pass of :func:`_held_experts` over the rows uniform routing
+# sends the held experts (k * T * held / n_experts).  It decides time and
+# memory, never the result: a layer whose held experts draw more takes another
+# pass.  At seeded weights 8 of 256 experts drew 0.4% to 6.8% of a layer's
+# units where uniform routing gives 3.1%, and a router trained on its held
+# experts' part of the gradient alone drifts towards or away from them (one
+# layer of one seed in six passed four times the share within 13 AdamW steps;
+# chip runs of PR 32, PERF.md section 6): at four times the share nearly every
+# layer of every step is one pass, and the pass's rows are an eighth of the
+# worst case's for 8 of 256.
+_HELD_PASS_OVER_SHARE = 4
+
+
+def held_pass_rows(cfg: Config, n_tokens: int) -> int:
+    """Rows of one pass of :func:`_held_experts` for ``n_tokens`` tokens:
+    ``_HELD_PASS_OVER_SHARE`` (4) times the held experts' share under uniform
+    routing, in whole tiles of 16, and never more than the units that can
+    reach them (a token meets an expert once)."""
+    k, (_, held) = cfg.expert_top_k, cfg.experts_held
+    share = -(-k * n_tokens * held // cfg.n_experts)
+    return min(min(k, held) * n_tokens,
+               -(-_HELD_PASS_OVER_SHARE * share // 16) * 16)
+
+
+def _held_pass(k, R, xt, wflat, order, arrived, p):
+    """Pass ``p`` of :func:`_held_experts`: rows ``p * R`` to ``(p + 1) * R``
+    of the held units in sorted order, gathered.  Returns ``(token, unit,
+    rows, xs, ws, kept)``: each row's token and unit (``T`` and ``T * k``,
+    which no gather or scatter reaches, where the row is past the units that
+    arrived), the valid rows as a column, the tokens' rows of ``xt`` and the
+    router's weights (0 where not valid), and each held expert's rows in
+    this pass."""
+    T = xt.shape[0]
+    ends = jnp.cumsum(arrived)
+    lo = p * R
+    valid = lo + jnp.arange(R) < ends[-1]
+    unit = jnp.where(valid, lax.dynamic_slice(order, (lo,), (R,)), T * k)
+    token = jnp.where(valid, unit // k, T)
+    xs = xt.at[token].get(mode="fill", fill_value=0)
+    ws = wflat.at[unit].get(mode="fill", fill_value=0)[:, None]
+    kept = jnp.clip(ends - lo, 0, R) - jnp.clip(ends - arrived - lo, 0, R)
+    return token, unit, valid[:, None], xs, ws, kept
+
+
+def _held_swiglu(kernel, rows, kept, xs, ws, w_gate, w_up, w_down):
+    """The held experts' SwiGLUs on one pass's rows ``xs`` (R, D), the hidden
+    rows carrying the router's weights ``ws`` into the down product.  Rows
+    past the units that arrived belong to no expert: megablox's ``gmm``
+    leaves them unwritten (whatever the buffer held), so every product's
+    output is selected against the valid ``rows``, forward and, through the
+    selects' transposes, backward."""
+    product = lambda a, w: jnp.where(
+        rows, _grouped_matmul(a, w, kept, kernel), 0)
+    hs = jnp.where(rows, jax.nn.silu(product(xs, w_gate))
+                   * product(xs, w_up) * ws, 0)
+    return product(hs.astype(xs.dtype), w_down)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _held_experts(k, R, kernel, xt, wflat, order, arrived, w):
+    """The routed experts held here on tokens ``xt`` (T, D) -> (T, D): the
+    sum, for each token, of its units whose expert is held, each unit the
+    expert's SwiGLU times the router's weight ``wflat`` (T * k, float32).
+    ``order`` lists the units, those of held experts first and by expert
+    (padded to whole passes), ``arrived`` counts them by held expert, ``w``
+    is ``(w_gate, w_up, w_down)`` of the held experts.
+
+    The worst case is min(k, held) * T rows, thirty-two times what uniform
+    routing sends 8 of 256 experts, and the gathers and scatter-adds of that
+    many rows, nearly all of them empty, would cost several times what the
+    experts do (PERF.md section 6).  So the units are taken ``R`` rows a pass
+    (:func:`held_pass_rows`) in a loop of as many passes as the units that
+    arrived need, one as a rule: the shapes are static, time and memory are
+    those of the units that arrived, and no unit is ever dropped.  Each pass gathers its rows,
+    runs the grouped matmuls (:func:`_held_swiglu`) and adds the results to
+    their tokens in float32.  A loop whose length the data decides has no
+    transpose, so the gradient is written out: it keeps the inputs alone and
+    takes the same passes, each through :func:`_held_swiglu`'s own VJP (its
+    gate and up products formed again there), the weights' gradients summed
+    over the passes in float32."""
+    return _held_experts_fwd(k, R, kernel, xt, wflat, order, arrived, w)[0]
+
+
+def _held_experts_fwd(k, R, kernel, xt, wflat, order, arrived, w):
+    def one_pass(p, y):
+        with jax.named_scope("moe.dispatch"):
+            token, _, rows, xs, ws, kept = _held_pass(k, R, xt, wflat, order,
+                                                      arrived, p)
+        with jax.named_scope("moe.experts"):
+            ys = _held_swiglu(kernel, rows, kept, xs, ws, *w)
+        with jax.named_scope("moe.combine"):
+            return y.at[token].add(ys.astype(jnp.float32), mode="drop")
+
+    y = lax.fori_loop(0, -(-jnp.sum(arrived) // R), one_pass,
+                      jnp.zeros(xt.shape, jnp.float32))
+    return y.astype(xt.dtype), (xt, wflat, order, arrived, w)
+
+
+def _held_experts_bwd(k, R, kernel, saved, dy):
+    xt, wflat, order, arrived, w = saved
+    f32 = lambda a: a.astype(jnp.float32)
+
+    def one_pass(p, grads):
+        dxt, dwflat, dw = grads
+        with jax.named_scope("moe.dispatch"):
+            token, unit, rows, xs, ws, kept = _held_pass(k, R, xt, wflat,
+                                                         order, arrived, p)
+        with jax.named_scope("moe.combine"):
+            dys = dy.at[token].get(mode="fill", fill_value=0)
+        with jax.named_scope("moe.experts"):
+            dxs, dws, *dwp = jax.vjp(functools.partial(
+                _held_swiglu, kernel, rows, kept), xs, ws, *w)[1](dys)
+        with jax.named_scope("moe.dispatch"):
+            return (dxt.at[token].add(f32(dxs), mode="drop"),
+                    dwflat.at[unit].add(dws[:, 0], mode="drop"),
+                    tuple(a + f32(b) for a, b in zip(dw, dwp)))
+
+    zeros = lambda a: jnp.zeros(a.shape, jnp.float32)
+    dxt, dwflat, dw = lax.fori_loop(
+        0, -(-jnp.sum(arrived) // R), one_pass,
+        (zeros(xt), zeros(wflat), tuple(zeros(a) for a in w)))
+    none = lambda a: np.zeros(a.shape, jax.dtypes.float0)
+    return (dxt.astype(xt.dtype), dwflat.astype(wflat.dtype), none(order),
+            none(arrived), tuple(g.astype(a.dtype) for g, a in zip(dw, w)))
+
+
+_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
 def _qk_norm(cfg: Config, lp: Params, q: jax.Array, k: jax.Array):
@@ -712,21 +1119,100 @@ def _qk_norm(cfg: Config, lp: Params, q: jax.Array, k: jax.Array):
                 rms_norm(k, lp["k_norm"], cfg.norm_eps))
 
 
+def _short_conv(x: jax.Array, w: jax.Array) -> jax.Array:
+    """A causal depthwise convolution along the sequence, one filter a
+    channel and no bias: x (B, L, C), w (taps, C) -> ``y_t = sum_i w[i] *
+    x_{t - taps + 1 + i}``, the last tap on the token itself; float32."""
+    taps, L = w.shape[0], x.shape[1]
+    xp = jnp.pad(x.astype(jnp.float32), ((0, 0), (taps - 1, 0), (0, 0)))
+    return sum(xp[:, i:i + L] * w[i].astype(jnp.float32) for i in range(taps))
+
+
+def _kda_block(cfg: Config, lp: Params, x: jax.Array) -> jax.Array:
+    """The KDA mixer on the normed input x (B, L, D): q, k and v each a
+    projection, a short convolution and a SiLU, q and k L2-normalised over a
+    head's channels (q scaled by ``head_dim ** -0.5``); the log-decay a head
+    and channel ``g = -exp(a_log) * softplus(x W_f + dt_bias)`` and the write
+    strength ``beta = sigmoid(x W_b)`` in float32; the recurrence
+    (:func:`ops.kda.kda`, scope ``kda``); the output normed a head and gated
+    by ``sigmoid(x W_g + b_g)`` before ``W_o``.  ``W_f`` and ``W_g`` are
+    low-rank pairs through ``head_dim``."""
+    from ..ops.kda import kda
+
+    B, L, _ = x.shape
+    H, hd = cfg.kda_heads, cfg.kda_head_dim
+
+    def branch(w, conv):
+        y = jax.nn.silu(_short_conv(x @ lp[w], lp[conv]))
+        return y.reshape(B, L, H, hd)
+
+    unit = lambda y: y * lax.rsqrt(
+        jnp.sum(y * y, axis=-1, keepdims=True) + 1e-6)
+    q = (unit(branch("wq", "conv_q")) * hd ** -0.5).astype(x.dtype)
+    k = unit(branch("wk", "conv_k")).astype(x.dtype)
+    v = branch("wv", "conv_v").astype(x.dtype)
+    f = ((x @ lp["f_down"]) @ lp["f_up"]).astype(jnp.float32) + lp["dt_bias"]
+    g = (-jnp.exp(lp["a_log"])[:, None]
+         * jax.nn.softplus(f).reshape(B, L, H, hd))
+    beta = jax.nn.sigmoid((x @ lp["wb"]).astype(jnp.float32))
+    o = kda(q, k, v, g, beta)
+    gate = jax.nn.sigmoid((((x @ lp["g_down"]) @ lp["g_up"])
+                           + lp["g_bias"]).astype(jnp.float32))
+    o = (rms_norm(o, lp["o_norm"], cfg.norm_eps).astype(jnp.float32)
+         * gate.reshape(B, L, H, hd)).astype(x.dtype)
+    return o.reshape(B, L, H * hd) @ lp["wo"]
+
+
+def _mla_block(cfg: Config, lp: Params, x: jax.Array,
+               attn_impl: Callable) -> jax.Array:
+    """The latent-attention mixer on the normed input x (B, L, D), as a
+    training step runs it: q projected whole; the keys' and values' latent
+    and the key part all heads share from one projection; the latent normed
+    and expanded to each head's keys and values; softmax attention with keys
+    of ``qk_nope_head_dim + qk_rope_head_dim`` and values of ``v_head_dim``
+    (``attn_impl``, made for that scale); ``W_o``.  Nothing is rotated."""
+    B, L, _ = x.shape
+    H, r = cfg.n_heads, cfg.kv_lora_rank
+    nope, shared, vd = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                        cfg.v_head_dim)
+    with jax.named_scope("mla"):
+        q = (x @ lp["wq"]).reshape(B, L, H, nope + shared)
+        latent = x @ lp["wkv_a"]
+        kv = (rms_norm(latent[..., :r], lp["kv_norm"], cfg.norm_eps)
+              @ lp["wkv_b"]).reshape(B, L, H, nope + vd)
+        k = jnp.concatenate(
+            [kv[..., :nope], jnp.broadcast_to(
+                latent[..., None, r:], (B, L, H, shared))], axis=-1)
+        o = attn_impl(q, k, kv[..., nope:])
+        return o.reshape(B, L, H * vd) @ lp["wo"]
+
+
 def _attention_block(cfg: Config, lp: Params, h: jax.Array,
                      positions: jax.Array, attn_impl: Callable,
                      constrain: Callable = lambda x: x,
-                     with_kv: bool = False):
+                     with_kv: bool = False, mixer: str = "attn"):
     """The attention half of a decoder block: ``h`` plus the attention of
     its pre-norm (under ``cfg.sandwich_norm`` the branch's output is normed
     too, before the add); with ``with_kv`` also the (pre-repeat,
-    native-KV-head) K/V projections."""
+    native-KV-head) K/V projections.  ``mixer`` is the layer's kind:
+    ``"attn"`` the softmax attention written here, ``"kda"``
+    :func:`_kda_block`, ``"mla"`` :func:`_mla_block` (``attn_impl`` then the
+    one made for its scale)."""
     B, L, _ = h.shape
     hd, H, KV = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
     # Names in the device program (docs/observability.md): ``attn`` (the
     # projections, ``attn.qk_norm``, rope, the attention itself, the output
-    # projection), ``moe.router``/``moe.dispatch``/``moe.experts``/
-    # ``moe.combine`` or ``ffn``, ``embed``, ``final_norm``, ``exit_gate``,
-    # ``head_loss``, ``optimizer``.  Metadata only.
+    # projection; in it ``kda``, the chunked recurrence alone, or ``mla``,
+    # the whole latent mixer), ``moe.router``/``moe.dispatch``/
+    # ``moe.experts``/``moe.combine``/``moe.shared`` or ``ffn``, ``embed``,
+    # ``final_norm``, ``exit_gate``, ``head_loss``, ``optimizer``.  Metadata
+    # only.
+    if mixer != "attn":
+        with jax.named_scope("attn"):
+            x = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
+            o = (_kda_block(cfg, lp, x) if mixer == "kda"
+                 else _mla_block(cfg, lp, x, attn_impl))
+            return h + constrain(o)
     with jax.named_scope("attn"):
         x = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
         q, k = _qk_norm(cfg, lp, x @ lp["wq"], x @ lp["wk"])
@@ -740,20 +1226,29 @@ def _attention_block(cfg: Config, lp: Params, h: jax.Array,
     return (h, (k, v)) if with_kv else h
 
 
+def _aux_zero(cfg: Config):
+    """What a layer without experts adds to the stack's aux sum: 0 (two for
+    a configuration with a z-loss)."""
+    return jnp.zeros((2,) if cfg.n_experts and cfg.moe_z_coef else (),
+                     jnp.float32)
+
+
 def _ffn_block(cfg: Config, lp: Params, h: jax.Array,
                constrain: Callable = lambda x: x,
-               mesh: Optional[Mesh] = None):
+               mesh: Optional[Mesh] = None, ffn: Optional[str] = None):
     """The feed-forward half: ``h`` plus the SwiGLU or mixture-of-experts
     FFN of its pre-norm (normed again under ``cfg.sandwich_norm``), and the
-    MoE aux term (0 for dense configs)."""
+    MoE aux term (0 for dense configs; :func:`_aux_zero`).  ``ffn`` is the
+    layer's kind, ``"dense"`` or ``"moe"``; left None, what
+    ``cfg.n_experts`` says."""
     x = rms_norm(h, lp["mlp_norm"], cfg.norm_eps)
-    if cfg.n_experts:
+    if ffn == "moe" or (ffn is None and cfg.n_experts):
         g, aux = _moe_ffn(cfg, lp, x, mesh=mesh)
     else:
         with jax.named_scope("ffn"):
             g = ((jax.nn.silu(x @ lp["w_gate"]) * (x @ lp["w_up"]))
                  @ lp["w_down"])
-        aux = jnp.zeros((), jnp.float32)
+        aux = _aux_zero(cfg)
     if cfg.sandwich_norm:
         with jax.named_scope("ffn"):
             g = rms_norm(g, lp["mlp_post_norm"], cfg.norm_eps)
@@ -763,7 +1258,8 @@ def _ffn_block(cfg: Config, lp: Params, h: jax.Array,
 def _decoder_layer(cfg: Config, lp: Params, h: jax.Array,
                    positions: jax.Array, attn_impl: Callable,
                    constrain: Callable = lambda x: x,
-                   with_kv: bool = False, mesh: Optional[Mesh] = None):
+                   with_kv: bool = False, mesh: Optional[Mesh] = None,
+                   mixer: str = "attn", ffn: Optional[str] = None):
     """One pre-norm decoder block (attention + SwiGLU-or-MoE FFN with
     residuals) — the single definition the scanned forward (:func:`apply`),
     the pipeline stages (:func:`make_pp_train_step`), and decode prefill
@@ -771,12 +1267,15 @@ def _decoder_layer(cfg: Config, lp: Params, h: jax.Array,
     (0 for dense configs); with ``with_kv`` also returns the (pre-repeat,
     native-KV-head) K/V projections — the cache seed for autoregressive
     decoding.  ``mesh`` is the mesh the parameters live on, if any: the
-    dropless expert layer runs its kernel on one device only."""
-    h = _attention_block(cfg, lp, h, positions, attn_impl, constrain, with_kv)
+    dropless expert layer runs its kernel on one device only.  ``mixer``
+    and ``ffn`` are the layer's kinds in a stack that is not homogeneous
+    (:func:`layer_runs`)."""
+    h = _attention_block(cfg, lp, h, positions, attn_impl, constrain, with_kv,
+                         mixer)
     if with_kv:
         h, kv = h
-        return (*_ffn_block(cfg, lp, h, constrain, mesh), kv)
-    return _ffn_block(cfg, lp, h, constrain, mesh)
+        return (*_ffn_block(cfg, lp, h, constrain, mesh, ffn), kv)
+    return _ffn_block(cfg, lp, h, constrain, mesh, ffn)
 
 
 def _chunk_nll(head, h_c, t_c):
@@ -1001,6 +1500,37 @@ def _refuse_looped(cfg: Config, what: str) -> None:
             "ut_steps times; train it with make_train_step")
 
 
+def _refuse_runs(cfg: Config, what: str, missing: str) -> None:
+    if cfg.layer_kinds is not None:
+        raise NotImplementedError(
+            f"{what} has no form yet for a stack of runs of different layer "
+            f"kinds (KDA and latent-attention layers among them): it lacks "
+            f"{missing}; train it with make_train_step")
+
+
+def _mixer_impls(cfg: Config, attn: str, mesh: Optional[Mesh]):
+    """{mixer kind: attention callable} for the layers of ``cfg``: the
+    softmax layers' at ``head_dim ** -0.5``, the latent layers' at the scale
+    of their whole key; a KDA layer takes none."""
+    mla = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+    if cfg.layer_kinds is not None and attn.startswith("ring"):
+        raise NotImplementedError(
+            "the ring kernels take one head width for q, k and v and no "
+            "recurrent state crosses sequence shards: a stack with KDA or "
+            "latent-attention layers takes attn='full' or 'flash'")
+    return {"attn": _make_attn_impl(cfg, attn, mesh,
+                                    1.0 / np.sqrt(cfg.head_dim)),
+            "mla": (_make_attn_impl(cfg, attn, mesh, 1.0 / np.sqrt(mla))
+                    if mla else None),
+            "kda": None}
+
+
+def _stacks(cfg: Config, params: Params):
+    """The stacked layers of each run of :func:`layer_runs`, in order."""
+    return (params["layers"] if cfg.layer_kinds is not None
+            else (params["layers"],))
+
+
 def exit_distribution(cfg: Config, params: Params, tokens: jax.Array,
                       mesh: Optional[Mesh] = None,
                       attn: str = "full") -> jax.Array:
@@ -1031,26 +1561,43 @@ def _exit_log_probs(params: Params, h: jax.Array) -> jax.Array:
 def expert_unit_counts(cfg: Config, params: Params, tokens: jax.Array,
                        mesh: Optional[Mesh] = None,
                        attn: str = "full") -> jax.Array:
-    """(n_layers, n_experts) int32: how many of a batch's k*T routed units
-    each expert of each layer is sent, by the router code the training step
-    runs (:func:`_route_tokens`), on the activations the forward pass gives
-    it.  A counter for outside the step: a row sums to k*T, and its largest
-    entry over its mean says how lopsided that layer's routing is."""
+    """(layers with experts, n_experts) int32: how many of a batch's k*T
+    routed units each expert of each layer is sent, by the router code the
+    training step runs (:func:`_route_tokens`), on the activations the forward
+    pass gives it.  A counter for outside the step: a row sums to k*T, and its
+    largest entry over its mean says how lopsided that layer's routing is.  A
+    stack of runs has a row for each layer of its ``"moe"`` runs, in order,
+    over all ``n_experts`` whatever ``cfg.experts_held``: the held experts'
+    columns are what the step's tiles see."""
     _refuse_dropless_ep(cfg, mesh)
     _refuse_looped(cfg, "expert_unit_counts")
     positions = jnp.arange(tokens.shape[1])
-    attn_impl = _make_attn_impl(cfg, attn, mesh, 1.0 / np.sqrt(cfg.head_dim))
+    impls = _mixer_impls(cfg, attn, mesh)
+    h, rows = params["embed"][tokens], []
+    for (mixer, ffn, _), stack in zip(layer_runs(cfg), _stacks(cfg, params)):
+        def layer(h, lp):
+            h = _attention_block(cfg, lp, h, positions, impls[mixer],
+                                 mixer=mixer)
+            counts = None
+            if ffn == "moe":
+                x = rms_norm(h, lp["mlp_norm"], cfg.norm_eps)
+                counts = _route_tokens(cfg, lp, x.reshape(-1, x.shape[-1]))[2]
+            return _ffn_block(cfg, lp, h, mesh=mesh, ffn=ffn)[0], counts
 
-    def layer(h, lp):
-        h = _attention_block(cfg, lp, h, positions, attn_impl)
-        x = rms_norm(h, lp["mlp_norm"], cfg.norm_eps)
-        counts = _route_tokens(cfg, lp, x.reshape(-1, x.shape[-1]))[2]
-        return _ffn_block(cfg, lp, h, mesh=mesh)[0], counts
-
-    return lax.scan(layer, params["embed"][tokens], params["layers"])[1]
+        h, counts = lax.scan(layer, h, stack)
+        if counts is not None:
+            rows.append(counts)
+    return jnp.concatenate(rows)
 
 
-# The deepest stack :func:`apply` inlines; a deeper one it scans.  Scanning
+# The deepest stack :func:`apply` inlines; a deeper one it scans.  In a stack
+# of runs of different layer kinds (:func:`layer_runs`) the depth is a run's:
+# each run is inlined or scanned by its own length, and the runs follow one
+# another inlined, so Kimi Linear's published 27 layers (runs of at most
+# three) are 27 inlined layers, where a homogeneous 27 would be one scan: the
+# price of kinds that alternate every fourth layer is the compile time of
+# each layer (not measured at that depth; the benchmark's cut runs five).
+# Scanning
 # costs copies every step in proportion to the depth, inlining compile time
 # in proportion to it.  Measured on a v5e, scanned -> inlined (chip runs of
 # PR 29, PERF.md section 6): OLMoE widths, 2 layers, step 316.4 -> 301.2 ms
@@ -1156,7 +1703,6 @@ def apply(cfg: Config, params: Params, tokens: jax.Array,
     the measurements).
     """
     B, L = tokens.shape
-    scale = 1.0 / np.sqrt(cfg.head_dim)
     if attn == "ring-zigzag" and positions is None:
         # The zigzag kernels mask as if row blocks sit in the zigzag
         # layout; contiguous rows with default positions would compute a
@@ -1183,44 +1729,53 @@ def apply(cfg: Config, params: Params, tokens: jax.Array,
     _refuse_dropless_ep(cfg, mesh)
     with jax.named_scope("embed"):
         h = constrain(params["embed"][tokens])      # (B, L, D)
-    attn_impl = _make_attn_impl(cfg, attn, mesh, scale)
-
-    def layer(carry, lp):
-        h, aux = carry
-        h, a = _decoder_layer(cfg, lp, h, positions, attn_impl, constrain,
-                              mesh=mesh)
-        return (h, aux + a), None
+    impls = _mixer_impls(cfg, attn, mesh)
 
     remats = (remat,) * cfg.ut_steps if isinstance(remat, str) else tuple(remat)
     if len(remats) != cfg.ut_steps:
         raise ValueError(f"remat names {len(remats)} recurrent steps, the "
                          f"configuration has {cfg.ut_steps}")
-    if layer_loop is None:
-        layer_loop = "unroll" if cfg.n_layers <= _INLINE_MAX_LAYERS else "scan"
-    if layer_loop not in ("scan", "unroll"):
+    if layer_loop not in ("scan", "unroll", None):
         raise ValueError("layer_loop must be 'scan', 'unroll' or None")
-    wrapped = {r: _wrap_remat(layer, r, scanned=layer_loop == "scan")
-               for r in dict.fromkeys(remats)}
 
-    def stack(carry, layer):
-        """One pass through the stacked layers."""
-        if layer_loop == "scan":
-            return lax.scan(layer, carry, params["layers"])[0]
-        for i in range(cfg.n_layers):
-            carry, _ = layer(carry, jax.tree.map(lambda a: a[i],
-                                                 params["layers"]))
-        return carry
+    def run_loop(mixer, ffn, n, stacked):
+        """{remat name: one pass through a run of ``n`` layers of one kind},
+        the run inlined or scanned by its own length."""
+        loop = layer_loop or ("unroll" if n <= _INLINE_MAX_LAYERS else "scan")
 
-    def ut_step(carry, layer):
-        h, aux = stack(carry, layer)
+        def layer(carry, lp):
+            h, aux = carry
+            h, a = _decoder_layer(cfg, lp, h, positions, impls[mixer],
+                                  constrain, mesh=mesh, mixer=mixer, ffn=ffn)
+            return (h, jax.tree.map(jnp.add, aux, a)), None
+
+        def run(layer, carry):
+            if loop == "scan":
+                return lax.scan(layer, carry, stacked)[0]
+            for i in range(n):
+                carry, _ = layer(carry, jax.tree.map(lambda a: a[i], stacked))
+            return carry
+
+        return {r: functools.partial(
+                    run, _wrap_remat(layer, r, scanned=loop == "scan"))
+                for r in dict.fromkeys(remats)}
+
+    runs = [run_loop(mixer, ffn, n, stacked)
+            for (mixer, ffn, n), stacked in zip(layer_runs(cfg),
+                                                _stacks(cfg, params))]
+
+    def ut_step(carry, r):
+        """One pass through the stack: its runs one after the other."""
+        for run in runs:
+            carry = run[r](carry)
+        h, aux = carry
         with jax.named_scope("final_norm"):
             return rms_norm(h, params["norm"], cfg.norm_eps), aux
 
-    carry = (h, jnp.zeros((2,) if cfg.n_experts and cfg.moe_z_coef else (),
-                          jnp.float32))
+    carry = (h, _aux_zero(cfg))
     states = []
     for r in remats:
-        carry = ut_step(carry, wrapped[r])
+        carry = ut_step(carry, r)
         states.append(carry[0])
     aux = carry[1] / (cfg.n_layers * cfg.ut_steps)
     h = jnp.stack(states) if all_steps else states[-1]
@@ -1324,6 +1879,9 @@ def _decode_step(cfg: Config, params: Params, cache: Params,
     including ``pos`` (causality holds by construction: later slots are
     still zero and masked off)."""
     _refuse_looped(cfg, "the decode step")
+    _refuse_runs(cfg, "the decode step", "a recurrent-state cache for the "
+                 "KDA layers (a head's d x d state and the convolutions' last "
+                 "taps) beside a latent cache for the others")
     B = tokens.shape[0]
     hd, H, KV = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
     scale = 1.0 / np.sqrt(hd)
@@ -1391,6 +1949,9 @@ def _prefill(cfg: Config, params: Params, cache: Params,
     kernel needs it to run per batch/head shard.
     """
     _refuse_looped(cfg, "prefill")
+    _refuse_runs(cfg, "prefill", "a latent cache (the normed latent and the "
+                 "shared key part a token) and the KDA layers' final state "
+                 "to seed decoding with")
     B, Lp = prompt.shape
     positions = jnp.arange(Lp)
     scale = 1.0 / np.sqrt(cfg.head_dim)
@@ -1457,6 +2018,8 @@ def make_generate_fn(cfg: Config, prompt_len: int, max_new: int,
     pinned weight + cache shardings.
     """
     _refuse_looped(cfg, "make_generate_fn")
+    _refuse_runs(cfg, "make_generate_fn", "the two caches its prefill and "
+                 "decode step would fill (recurrent state, latent)")
     if prompt_len < 1 or max_new < 1:
         raise ValueError("prompt_len and max_new must be >= 1")
     if mesh is not None and cfg.n_kv_heads % dict(mesh.shape).get(AXIS_TP, 1):
@@ -1580,10 +2143,15 @@ def _wrap_remat(layer: Callable, remat: str,
       layer whose recomputation grows with L^2 runs once.  The grouped
       matmul's products it does not keep (at OLMoE's shapes they are eight
       layer inputs) and replays.
+    * both: the KDA recurrence's output and chunk-entry states
+      (``ops.kda.KDA_RESIDUAL_NAMES``: a layer input's bytes and, in float32,
+      L/64 states of d x d a head, four layer inputs at Kimi Linear's widths),
+      so the recurrence, a sequential scan over the chunks and no Mosaic
+      kernel, runs once forward and once backward like one.
     * ``"none"``: everything, no checkpoint.
 
-    No other attention mode emits the flash names and no other FFN the
-    grouped ones, so ``attn="full"``, the rings, the dense SwiGLU and the
+    No other attention mode emits the flash names, no other mixer the KDA
+    ones and no other FFN the grouped ones, so ``attn="full"``, the rings, the dense SwiGLU and the
     one-hot experts compile as before.
 
     ``scanned`` says the wrapped layer is the body of a ``lax.scan``.  The
@@ -1603,15 +2171,16 @@ def _wrap_remat(layer: Callable, remat: str,
     if remat not in ("dots", "full"):
         raise ValueError("remat must be 'none', 'dots', or 'full'")
     from ..ops.flash_attention import RESIDUAL_NAMES
+    from ..ops.kda import KDA_RESIDUAL_NAMES
 
     policies = jax.checkpoint_policies
+    kernels = (*RESIDUAL_NAMES, *KDA_RESIDUAL_NAMES)
     if remat == "full":
-        policy = policies.save_only_these_names(*RESIDUAL_NAMES)
+        policy = policies.save_only_these_names(*kernels)
     else:
         policy = policies.save_from_both_policies(
             policies.dots_with_no_batch_dims_saveable,
-            policies.save_only_these_names(*RESIDUAL_NAMES,
-                                           *GROUPED_DOT_NAMES))
+            policies.save_only_these_names(*kernels, *GROUPED_DOT_NAMES))
     return jax.checkpoint(layer, policy=policy, prevent_cse=not scanned)
 
 
@@ -1779,6 +2348,8 @@ def make_pp_train_step(cfg: Config, mesh: Mesh, n_microbatches: int,
     from ..parallel import pipeline as _pp
     from ..parallel.mesh import AXIS_PP
 
+    _refuse_runs(cfg, "make_pp_train_step", "a stage split by run (a stage "
+                 "is one stacked scan of identical layers)")
     if cfg.n_experts:
         # The GPipe carrier is a single (mb, L, D) array; threading the MoE
         # aux loss through the stage boundary needs an augmented carrier.
@@ -1919,6 +2490,8 @@ def make_1f1b_train_step(cfg: Config, mesh: Mesh, n_microbatches: int,
     """
     from ..parallel import pipeline as _pp
 
+    _refuse_runs(cfg, "make_1f1b_train_step", "a stage split by run (a stage "
+                 "is one stacked scan of identical layers)")
     if cfg.n_experts:
         raise NotImplementedError("pipeline step does not support MoE configs")
     _refuse_looped(cfg, "make_1f1b_train_step")
@@ -2157,7 +2730,12 @@ def make_train_step(cfg: Config, mesh: Mesh, lr: float = 3e-4,
     ``jax.eval_shape(optimizer.init, params)``) shards the optimizer state
     over ``dp`` on top of tp — GSPMD then reduce-scatters gradients into
     each replica's optimizer shard and all-gathers updated parameters, the
-    ZeRO-1 exchange, at the same collective volume as plain allreduce."""
+    ZeRO-1 exchange, at the same collective volume as plain allreduce.
+
+    A selection bias (``cfg.router_bias``) is a buffer the balancing rule
+    outside the gradient owns: its gradient is exactly zero and no optimizer
+    steps or decays it here (the leaf ``router_bias`` leaves the step as it
+    came)."""
     loss_fn = make_loss_fn(cfg, mesh=mesh, attn=attn, remat=remat,
                            loss_chunk=loss_chunk)
     specs = param_specs(cfg)
@@ -2179,6 +2757,7 @@ def make_train_step(cfg: Config, mesh: Mesh, lr: float = 3e-4,
 
     def step(params, opt_state, tokens, targets):
         loss, grads = jax.value_and_grad(loss_fn)(params, (tokens, targets))
+        before = params
         with jax.named_scope("optimizer"):
             if optimizer is not None:
                 updates, opt_state = optimizer.update(grads, opt_state,
@@ -2187,6 +2766,11 @@ def make_train_step(cfg: Config, mesh: Mesh, lr: float = 3e-4,
             else:
                 params = jax.tree.map(lambda p, g: p - lr * g.astype(p.dtype),
                                       params, grads)
+        if cfg.router_bias:
+            params = jax.tree_util.tree_map_with_path(
+                lambda path, old, new: old if getattr(
+                    path[-1], "key", None) == "router_bias" else new,
+                before, params)
         return params, opt_state, loss
 
     return jax.jit(

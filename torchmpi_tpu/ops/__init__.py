@@ -5,6 +5,10 @@ bandwidth for the ring allreduce (reference: lib/detail/reduce_kernel.cu:26-138)
 XLA subsumes that on TPU.  The hot op worth hand-tiling here is attention —
 the MXU/VMEM blocking of flash attention feeds both the single-chip path and
 the per-step block compute of ring attention (parallel/sequence.py).
+``ops/kda.py`` is the other mixer's hot op, the chunked gated delta rule of
+KDA linear-attention layers, forward and a hand-written backward, in plain
+XLA so far (``from torchmpi_tpu.ops import kda``; the function is
+``kda.kda``).
 """
 
 from .flash_attention import flash_attention  # noqa: F401

@@ -80,7 +80,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
                  causal: bool, scale: float):
     """One (batch*head, q-block, k-block) program.  Scratch (acc, m, l)
     persists across the k dimension (innermost, sequential on TPU)."""
-    bq, d = q_ref.shape
+    bq = q_ref.shape[0]
     bk = k_ref.shape[0]
     qi = pl.program_id(1)
     ki = pl.program_id(2)
@@ -132,34 +132,36 @@ def _flash_bh(qbh, kbh, vbh, *, causal: bool, block_q: int, block_k: int,
     """(BH, L, D) flash attention forward; returns (o, lse).
 
     ``kbh``/``vbh`` may have a different sequence length than ``qbh`` (the
-    ring caller attends local Q against a circulating K/V chunk).
+    ring caller attends local Q against a circulating K/V chunk), and
+    ``vbh`` a width of its own: q and k are ``D`` wide, v and o ``Dv``
+    (latent attention's 192 and 128; no operand is padded to the other's).
     ``out_dtype`` overrides the output dtype (the ring carries its partial
     outputs in f32 across steps so per-step rounding doesn't accumulate).
     """
     BH, L, D = qbh.shape
-    Lk = kbh.shape[1]
+    Lk, Dv = vbh.shape[1:]
     if scale is None:
         scale = 1.0 / np.sqrt(D)
     out_dtype = qbh.dtype if out_dtype is None else out_dtype
     grid = (BH, L // block_q, Lk // block_k)
     kernel = functools.partial(_attn_kernel, causal=causal, scale=scale)
     k_of = _unmasked_k(causal, block_q, block_k, grid[2])
-    kd = pl.BlockSpec((None, block_k, D),
-                      lambda b, qi, ki: (b, k_of(qi, ki), 0))
+    kv_block = lambda d: pl.BlockSpec((None, block_k, d),
+                                      lambda b, qi, ki: (b, k_of(qi, ki), 0))
     return pl.pallas_call(
         kernel,
-        out_shape=(jax.ShapeDtypeStruct((BH, L, D), out_dtype),
+        out_shape=(jax.ShapeDtypeStruct((BH, L, Dv), out_dtype),
                    jax.ShapeDtypeStruct((BH, L, 1), jnp.float32)),
         grid=grid,
         in_specs=[
             pl.BlockSpec((None, block_q, D), lambda b, qi, ki: (b, qi, 0)),
-            kd, kd,
+            kv_block(D), kv_block(Dv),
         ],
-        out_specs=(pl.BlockSpec((None, block_q, D), lambda b, qi, ki: (b, qi, 0)),
+        out_specs=(pl.BlockSpec((None, block_q, Dv), lambda b, qi, ki: (b, qi, 0)),
                    pl.BlockSpec((None, block_q, 1),
                                 lambda b, qi, ki: (b, qi, 0))),
         scratch_shapes=[
-            pltpu.VMEM((block_q, D), jnp.float32),   # output accumulator
+            pltpu.VMEM((block_q, Dv), jnp.float32),  # output accumulator
             pltpu.VMEM((block_q, 1), jnp.float32),   # running max
             pltpu.VMEM((block_q, 1), jnp.float32),   # running denominator
         ],
@@ -199,21 +201,31 @@ def _flash_bh(qbh, kbh, vbh, *, causal: bool, block_q: int, block_k: int,
 _VMEM_BUDGET = 100 * 1024 * 1024
 
 
+def _lanes(d: int) -> int:
+    """The lanes a tile ``d`` wide is counted as in VMEM: past one register
+    of 128 lanes, whole registers (a latent head's 192 takes 256, and the
+    compiler refused ``flash_bwd`` the 47 MB that 192 gave it for the 52.7 it
+    needed); up to 128, the width itself, as the budget was set against."""
+    return d if d <= 128 else -(-d // 128) * 128
+
+
 def _bwd_vmem_bytes(block_q: int, block_k: int, D: int, in_dtype,
-                    out_dtype) -> int:
-    """VMEM a streaming backward kernel asks for, from its shapes: every
-    streamed tile in both pipeline buffers (a (block_q, 1) column of lse or
-    delta pads to 128 lanes), the float32 accumulators, and four float32
-    (block_q, block_k) blocks for s/p, dp/ds and the operands the compiler
-    transposes.  At 1024-wide blocks and D=128 in bfloat16 this gives
-    22 MiB, where the compiler's own count for ``flash_bwd`` is 16.6 beside
-    its dq block."""
+                    out_dtype, Dv: Optional[int] = None) -> int:
+    """VMEM a streaming backward kernel asks for, from its shapes (q, k and
+    their gradients ``D`` wide, v, do and dv ``Dv``, which is ``D`` unless
+    given): every streamed tile in
+    both pipeline buffers (a (block_q, 1) column of lse or delta pads to 128
+    lanes), the float32 accumulators, and four float32 (block_q, block_k)
+    blocks for s/p, dp/ds and the operands the compiler transposes; a width
+    counts as the lanes it takes (:func:`_lanes`).  At 1024-wide blocks and D=Dv=128 in bfloat16 this gives 22 MiB, where the
+    compiler's own count for ``flash_bwd`` is 16.6 beside its dq block."""
     isz, osz = jnp.dtype(in_dtype).itemsize, jnp.dtype(out_dtype).itemsize
+    D, Dv = _lanes(D), _lanes(D if Dv is None else Dv)
     wide = max(block_q, block_k)
-    tiles = (2 * block_q * D * isz + 2 * block_k * D * isz   # q, do; k, v
+    tiles = ((block_q + block_k) * (D + Dv) * isz            # q, do; k, v
              + 2 * block_q * 128 * 4                         # lse, delta
-             + 2 * wide * D * osz)                           # dq, or dk and dv
-    return 2 * tiles + 2 * wide * D * 4 + 4 * block_q * block_k * 4
+             + wide * (D + Dv) * osz)                        # dq, or dk and dv
+    return 2 * tiles + wide * (D + Dv) * 4 + 4 * block_q * block_k * 4
 
 
 def _bwd_pair(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, q_start,
@@ -339,36 +351,41 @@ def _flash_bh_bwd(qbh, kbh, vbh, dobh, lse, delta, *, causal: bool,
     ``vmem_budget`` (read from the shapes, see the section comment), else
     the two streaming kernels."""
     BH, L, D = qbh.shape
-    Lk = kbh.shape[1]
+    Lk, Dv = vbh.shape[1:]
     if scale is None:
         scale = 1.0 / np.sqrt(D)
     dq_dtype = qbh.dtype if out_dtype is None else out_dtype
     dkv_dtype = kbh.dtype if out_dtype is None else out_dtype
-    dkv_shape = jax.ShapeDtypeStruct((BH, Lk, D), dkv_dtype)
+    dkv_shape = (jax.ShapeDtypeStruct((BH, Lk, D), dkv_dtype),
+                 jax.ShapeDtypeStruct((BH, Lk, Dv), dkv_dtype))
     dkv_scratch = [pltpu.VMEM((block_k, D), jnp.float32),
-                   pltpu.VMEM((block_k, D), jnp.float32)]
+                   pltpu.VMEM((block_k, Dv), jnp.float32)]
 
     # Grid (BH, k-blocks, q-blocks): flash_bwd and flash_bwd_dkv.
     q_of = _unmasked_q(causal, block_q, block_k, L // block_q)
-    qd2 = pl.BlockSpec((None, block_q, D),
-                       lambda b, ki, qi: (b, q_of(ki, qi), 0))
-    kd2 = pl.BlockSpec((None, block_k, D), lambda b, ki, qi: (b, ki, 0))
-    qrow2 = pl.BlockSpec((None, block_q, 1),
-                         lambda b, ki, qi: (b, q_of(ki, qi), 0))
+    q_block2 = lambda d: pl.BlockSpec(
+        (None, block_q, d), lambda b, ki, qi: (b, q_of(ki, qi), 0))
+    k_block2 = lambda d: pl.BlockSpec(
+        (None, block_k, d), lambda b, ki, qi: (b, ki, 0))
+    qrow2 = q_block2(1)
+    in_specs2 = [q_block2(D), k_block2(D), k_block2(Dv), q_block2(Dv),
+                 qrow2, qrow2]
+    dkv_specs2 = (k_block2(D), k_block2(Dv))
     args = (qbh, kbh, vbh, dobh, lse, delta)
 
-    stream_vmem = _bwd_vmem_bytes(block_q, block_k, D, qbh.dtype, dkv_dtype)
+    stream_vmem = _bwd_vmem_bytes(block_q, block_k, D, qbh.dtype, dkv_dtype,
+                                  Dv)
     # flash_bwd's float32 dq block of the whole (L, D), in both buffers.
-    fused_vmem = stream_vmem + 2 * L * D * 4
+    fused_vmem = stream_vmem + 2 * L * _lanes(D) * 4
     if fused_vmem <= vmem_budget:
         dq, dk, dv = pl.pallas_call(
             functools.partial(_attn_bwd_kernel, causal=causal, scale=scale),
             out_shape=(jax.ShapeDtypeStruct((BH, L, D), jnp.float32),
-                       dkv_shape, dkv_shape),
+                       *dkv_shape),
             grid=(BH, Lk // block_k, L // block_q),
-            in_specs=[qd2, kd2, kd2, qd2, qrow2, qrow2],
+            in_specs=in_specs2,
             out_specs=(pl.BlockSpec((None, L, D), lambda b, ki, qi: (b, 0, 0)),
-                       kd2, kd2),
+                       *dkv_specs2),
             scratch_shapes=dkv_scratch,
             compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=fused_vmem),
@@ -379,16 +396,18 @@ def _flash_bh_bwd(qbh, kbh, vbh, dobh, lse, delta, *, causal: bool,
 
     streaming = pltpu.CompilerParams(vmem_limit_bytes=stream_vmem)
     k_of = _unmasked_k(causal, block_q, block_k, Lk // block_k)
-    qd = pl.BlockSpec((None, block_q, D), lambda b, qi, ki: (b, qi, 0))
-    kd = pl.BlockSpec((None, block_k, D),
-                      lambda b, qi, ki: (b, k_of(qi, ki), 0))
-    qrow = pl.BlockSpec((None, block_q, 1), lambda b, qi, ki: (b, qi, 0))
+    q_block = lambda d: pl.BlockSpec((None, block_q, d),
+                                     lambda b, qi, ki: (b, qi, 0))
+    k_block = lambda d: pl.BlockSpec((None, block_k, d),
+                                     lambda b, qi, ki: (b, k_of(qi, ki), 0))
+    qrow = q_block(1)
     dq = pl.pallas_call(
         functools.partial(_attn_bwd_dq_kernel, causal=causal, scale=scale),
         out_shape=jax.ShapeDtypeStruct((BH, L, D), dq_dtype),
         grid=(BH, L // block_q, Lk // block_k),
-        in_specs=[qd, kd, kd, qd, qrow, qrow],
-        out_specs=qd,
+        in_specs=[q_block(D), k_block(D), k_block(Dv), q_block(Dv), qrow,
+                  qrow],
+        out_specs=q_block(D),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         compiler_params=streaming,
         interpret=interpret,
@@ -396,10 +415,10 @@ def _flash_bh_bwd(qbh, kbh, vbh, dobh, lse, delta, *, causal: bool,
     )(*args)
     dk, dv = pl.pallas_call(
         functools.partial(_attn_bwd_dkv_kernel, causal=causal, scale=scale),
-        out_shape=(dkv_shape, dkv_shape),
+        out_shape=dkv_shape,
         grid=(BH, Lk // block_k, L // block_q),
-        in_specs=[qd2, kd2, kd2, qd2, qrow2, qrow2],
-        out_specs=(kd2, kd2),
+        in_specs=in_specs2,
+        out_specs=dkv_specs2,
         scratch_shapes=dkv_scratch,
         compiler_params=streaming,
         interpret=interpret,
@@ -465,7 +484,8 @@ def flash_attention(
     interpret: Optional[bool] = None,
     scale: Optional[float] = None,
 ) -> jax.Array:
-    """Blocked attention, (B, L, H, D) layout (GQA: repeat K/V first).
+    """Blocked attention, (B, L, H, D) layout (GQA: repeat K/V first); v
+    may be (B, L, H, Dv) with a width of its own, and o then has it too.
 
     Differentiable: a ``custom_vjp`` pairs the forward with a
     FlashAttention-2 style backward Pallas kernel (``flash_bwd``: all three
@@ -477,8 +497,10 @@ def flash_attention(
     path keeps the semantics identical for tests.
     """
     B, L, H, D = q.shape
-    if k.shape != q.shape or v.shape != q.shape:
-        raise ValueError("q, k, v must share (B, L, H, D); repeat GQA KV first")
+    Dv = v.shape[-1]
+    if k.shape != q.shape or v.shape != (B, L, H, Dv):
+        raise ValueError("q and k must share (B, L, H, D) and v be (B, L, H, "
+                         "Dv); repeat GQA KV first")
     block_q = _auto_block(L) if block_q is None else min(block_q, L)
     block_k = _auto_block(L) if block_k is None else min(block_k, L)
     if L % block_q or L % block_k:
@@ -490,11 +512,11 @@ def flash_attention(
     # (B, L, H, D) -> (B*H, L, D)
     qbh = q.transpose(0, 2, 1, 3).reshape(B * H, L, D)
     kbh = k.transpose(0, 2, 1, 3).reshape(B * H, L, D)
-    vbh = v.transpose(0, 2, 1, 3).reshape(B * H, L, D)
+    vbh = v.transpose(0, 2, 1, 3).reshape(B * H, L, Dv)
     obh = _flash_core(causal, block_q, block_k, interpret,
                       None if scale is None else float(scale),
                       qbh, kbh, vbh)
-    return obh.reshape(B, H, L, D).transpose(0, 2, 1, 3)
+    return obh.reshape(B, H, L, Dv).transpose(0, 2, 1, 3)
 
 
 # ------------------------------------------------------- ring building blocks
