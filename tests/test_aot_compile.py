@@ -79,6 +79,38 @@ def test_flash_fwd_and_grad(v5e, L):
     assert 2 * L * D * 4 < used <= stated < V5E_VMEM_BYTES
 
 
+def test_kda_kernels_at_kimi_linears_widths(v5e, monkeypatch):
+    """``ops.kda`` forward and ``jax.grad`` of all five inputs at (1, 16384,
+    32, 128), q, k and v bfloat16, the log-decay float32: one kernel each way
+    (``kda_fwd``, ``kda_bwd``; no loop over the 256 chunks is left for XLA),
+    each inside the 16 MiB of VMEM a kernel gets unasked, reading the (B, L,
+    H * D) layout in place: Mosaic takes the tiles' slices, their
+    transposed products and the float32 ones at ``HIGHEST``."""
+    from torchmpi_tpu.ops import kda
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one = SingleDeviceSharding(v5e[0])
+    x = _sds((1, 16384, H, D), jnp.bfloat16, one)
+    g = _sds((1, 16384, H, D), jnp.float32, one)
+    beta = _sds((1, 16384, H), jnp.float32, one)
+
+    def loss(q, k, v, g, beta):
+        return jnp.sum(kda.kda(q, k, v, g, beta).astype(jnp.float32))
+
+    fwd = jax.jit(kda.kda).lower(x, x, x, g, beta).compile()
+    assert _kernels(fwd) == 1 and "kda_fwd" in fwd.as_text()
+    grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        x, x, x, g, beta).compile()
+    text = grad.as_text()
+    assert _kernels(grad) == 2 and "kda_fwd" in text and "kda_bwd" in text
+    assert " while(" not in text
+    used = [int(re.search(
+        r'"used_scoped_memory_configs":\[\{"memory_space":"1","offset":"0",'
+        r'"size":"(\d+)"', line).group(1)) for line in text.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(used) == 2 and max(used) < 16 * 1024 * 1024
+
+
 def _ring_blocks(one, Lc):
     x = _sds((H, Lc, D), jnp.bfloat16, one)
     row = _sds((H, Lc, 1), jnp.float32, one)
@@ -266,6 +298,50 @@ def test_olmoe_step_on_dp_tp_takes_the_compilers_grouped_matmul(monkeypatch):
     assert "jit(gmm)" not in text
 
 
+def test_kimi_linear_step_on_dp_tp_runs_each_devices_kernels(monkeypatch):
+    """On more than one device the KDA recurrence runs in a ``shard_map``
+    over the batch and the heads (``llama._kda_sharded``), as flash does:
+    its kernels are Mosaic's, and the compiler refuses to partition one
+    (``NotImplementedError: Mosaic kernels cannot be automatically
+    partitioned``; bare under GSPMD this step does not lower).  Kimi Linear's
+    first four layers at published widths (KDA, KDA, KDA, MLA; a share of the
+    experts) on dp=2 x tp=2: each device runs ``kda_fwd`` and ``kda_bwd``
+    once a KDA layer on its own row of the batch and its 16 of 32 heads,
+    and the latent layer's two flash kernels on its 16 heads."""
+    import dataclasses
+
+    from torchmpi_tpu.models import llama
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    published = llama.kimi_linear_48b_a3b()
+    cfg = dataclasses.replace(
+        published, n_layers=4, layer_kinds=published.layer_kinds[:4],
+        experts_held=(0, 8), vocab=20480)
+    assert [m for m, _ in cfg.layer_kinds] == ["kda", "kda", "kda", "mla"]
+    mesh = topology.topology_mesh("v5e-4", {"dp": 2, "tp": 2})
+    args = topology._llama_arg_structs(cfg, mesh, llama.param_specs, 2, 4096)
+
+    def lowered():
+        step = llama.make_train_step(cfg, mesh, attn="flash", remat="full",
+                                     loss_chunk=512)
+        return jax.jit(lambda p, t, y: step(p, None, t, y)).lower(*args)
+
+    text = lowered().compile().as_text()
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    named = lambda what: sum(what in line for line in kernels)
+    assert (named("kda_fwd"), named("kda_bwd")) == (3, 3)
+    assert (named("flash_fwd"), named("flash_bwd")) == (1, 1)
+    assert "jit(gmm)" not in text
+    # a device's o and states: its one row, 4096 tokens in 64 chunks, 16 heads
+    fwd = next(line for line in kernels if "kda_fwd" in line)
+    assert "[1,4096,2048]" in fwd and "[64,1,16,128,128]" in fwd
+    from torchmpi_tpu.ops.kda import kda
+    monkeypatch.setattr(llama, "_kda_sharded", lambda mesh, heads: kda)
+    with pytest.raises(NotImplementedError, match="automatically partitioned"):
+        lowered()
+
+
 def test_ouro_adamw_step_at_published_widths(v5e, monkeypatch):
     """The benchmark's `ouro-2.6b-b2-l4096` step on one chip: Ouro-2.6B at
     its published widths, 8 of 48 layers run four times (32 layer
@@ -362,15 +438,16 @@ def test_kimi_linear_adamw_step_at_published_widths(v5e, monkeypatch):
     rows of the vocabulary, 1 x 16,384 tokens, flash with keys of 192 and
     values of 128, the configuration file's remat, AdamW with float32
     moments, weights and state donated.  It fits the chip: the compiler's
-    own peak is 15.48 GB of 16.91 (15.75 GiB) and the sum the cell reports
-    18.14 GB.  Two flash kernels for the one MLA layer and, for each of the
+    own peak is 13.47 GB of 16.91 (15.75 GiB) and the sum the cell reports
+    13.56 GB.  Two flash kernels for the one MLA layer and, for each of the
     four expert layers, the grouped matmuls of one pass of the held experts'
     loops: `gmm` forward (3), for the rows' gradients (3) and, the backward
     loop forming what it does not keep, gate and up again (2), `tgmm` for
     the weights' gradients (3); the forward loop that `"full"` replays is
-    dead there and gone.  The KDA recurrence is no Mosaic kernel: one forward and
-    one backward scan over 256 chunks a KDA layer (and the backward pass's
-    map over groups of heads), none under `rematted_computation`."""
+    dead there and gone.  A KDA layer's recurrence is two more, `kda_fwd`
+    and `kda_bwd`, the 256 chunks a grid axis each runs in turn: no loop is
+    left under `kda`, and the forward kernel that `"full"` would replay is
+    dead, its output and states kept."""
     import dataclasses
     import json
     import os
@@ -426,27 +503,21 @@ def test_kimi_linear_adamw_step_at_published_widths(v5e, monkeypatch):
     assert (named("flash_fwd"), named("flash_bwd")) == (1, 1)
     assert all("/mla/" in line for line in kernels if "flash_" in line)
     assert named(r"jit\(gmm\)") == 4 * 8 and named(r"jit\(tgmm\)") == 4 * 3
-    assert len(kernels) == 46
+    assert (named("kda_fwd"), named("kda_bwd")) == (4, 4)
+    assert all("/kda/" in line for line in kernels if "kda_" in line)
+    assert len(kernels) == 46 + 2 * 4
     assert not any("rematted_computation" in line for line in kernels
-                   if "flash_" in line)
-    # A KDA layer's loops: the forward scan over the chunks, and in the
-    # backward pass the map over groups of heads with the reverse scan in it.
-    loops = [line for line in text if " while(" in line and "/kda/" in line]
-    assert sum("jvp(attn)/kda/closed_call/while" in line
-               for line in loops) == 4
-    assert sum("checkpoint/attn/kda/while" in line for line in loops) == 8
-    assert sum("kda/while/body/closed_call/while" in line
-               for line in loops) == 4
-    assert len(loops) == 12
-    assert not any("rematted_computation" in line for line in loops)
+                   if "flash_" in line or "kda_" in line)
+    assert not any(" while(" in line and "/kda/" in line for line in text)
     m = program.memory_analysis()
     held = (m.argument_size_in_bytes + m.output_size_in_bytes
             - m.alias_size_in_bytes + m.temp_size_in_bytes)
     # weights and both float32 moments donated: 10 bytes a parameter
     assert m.alias_size_in_bytes > 10 * 602_000_000
     # That it compiled is the check that it fits 15.75 GiB.  The compiler's
-    # own peak is 15.48 GB; arguments plus temporaries, the sum the cell
-    # reports as `hbm_program_gb`, counts the donated state's copies twice
-    # and the loops' carried sums beside their first values: 18.14 GB.
-    assert 8e9 < m.peak_memory_in_bytes < 15.75 * 2**30
-    assert m.peak_memory_in_bytes < held < 18.5e9
+    # own peak is 13.47 GB and arguments plus temporaries, the sum the cell
+    # reports as `hbm_program_gb`, 13.56 GB (15.48 and 18.14 while the
+    # recurrence was plain XLA: the chunk-local tensors of a group of heads,
+    # their float32 temporaries and the scans' carried sums are gone).
+    assert 8e9 < m.peak_memory_in_bytes < 14e9
+    assert m.peak_memory_in_bytes < held < 14e9

@@ -108,12 +108,11 @@ def test_chunked_recurrence_is_the_token_recurrence(L, decay):
     chunks, with decays near 1 (g about -1e-3) and near 0 (g to -100 a token:
     ``e^{-G}`` of one chunk would be e^6000) and nothing overflowing."""
     x = kda_inputs(L, decay)
-    o, want = kda_ops.kda(*x), kda_ops.kda_recurrent(*x)
+    o, want = jax.jit(kda_ops.kda)(*x), kda_ops.kda_recurrent(*x)
     assert bool(jnp.all(jnp.isfinite(o)))
     assert rel(o, want) < 2e-6
     w = jax.random.normal(jax.random.PRNGKey(9), o.shape)
-    grads = lambda fn: jax.grad(lambda *a: jnp.sum(fn(*a) * w),
-                                argnums=(0, 1, 2, 3, 4))(*x)
+    grads = lambda fn: jax.jit(lambda *a: all_grads(fn, a, w))(*x)
     for name, got, ref in zip("q k v g beta".split(), grads(kda_ops.kda),
                               grads(kda_ops.kda_recurrent)):
         assert bool(jnp.all(jnp.isfinite(got))), name
@@ -139,10 +138,9 @@ def test_correlated_keys_and_strong_writes_stay_stable(spread):
          -1e-3 * jax.nn.softplus(jax.random.normal(ks[3], (B, L, H, D))),
          jax.nn.sigmoid(4.0 + jax.random.normal(ks[4], (B, L, H))))
     want = kda_ops.kda_recurrent(*x)
-    assert rel(kda_ops.kda(*x), want) < 1e-5
+    assert rel(jax.jit(kda_ops.kda)(*x), want) < 1e-5
     w = jax.random.normal(jax.random.PRNGKey(9), want.shape)
-    grads = lambda fn: jax.grad(lambda *a: jnp.sum(fn(*a) * w),
-                                argnums=(0, 1, 2, 3, 4))(*x)
+    grads = lambda fn: jax.jit(lambda *a: all_grads(fn, a, w))(*x)
     for got, ref in zip(grads(kda_ops.kda), grads(kda_ops.kda_recurrent)):
         assert rel(got, ref) < 1e-4
     N = -0.9 * jnp.tril(jnp.ones((64, 64)), -1)
@@ -161,6 +159,195 @@ def test_recurrence_in_bfloat16_keeps_a_float32_state():
     assert o.dtype == jnp.bfloat16
     assert rel(o.astype(jnp.float32), kda_ops.kda_recurrent(*x)) < 2e-2
     assert kda_ops.n_chunks(512) == 8 and kda_ops.n_chunks(130) == 3
+
+
+# ------------------------------------------- the chunk-local kernels
+#
+# A head of 128 channels takes ``kda_fwd`` and ``kda_bwd`` (the Pallas
+# interpreter here): the chunk-local part and the recurrence over the chunks
+# in one kernel each way, the state in VMEM from chunk to chunk.  The plain
+# XLA form, which narrower heads keep, is their oracle.
+
+def kernel_inputs(L, decay, H=2, seed=0):
+    return kda_inputs(L, decay, B=1, H=H, D=128, seed=seed)
+
+
+def plain_form(monkeypatch, fn, *args):
+    """``fn(*args)`` traced with the plain XLA form at every head width."""
+    with monkeypatch.context() as m:
+        m.setattr(kda_ops, "_takes_kernel", lambda head_dim: False)
+        return jax.jit(lambda *a: fn(*a))(*args)
+
+
+def all_grads(fn, x, w):
+    return jax.grad(lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * w),
+                    argnums=(0, 1, 2, 3, 4))(*x)
+
+
+KERNEL_CASES = pytest.mark.parametrize("L,decay", [
+    (130, 1.0), (130, 30.0), (192, 1e-3), (40, 80.0)],
+    ids=["ragged-130", "decay-to-0", "decay-near-1", "short-and-strong"])
+
+
+@KERNEL_CASES
+def test_kernel_forward_is_the_token_recurrence_and_the_plain_form(
+        monkeypatch, L, decay):
+    """The forward kernel against the token-by-token recurrence and against
+    the plain form, at one to three chunks of which the last is padded,
+    decays near 1 and near 0 (``e^{-G}`` of a chunk would be e^6000): the
+    output, and the state that enters each chunk, which the kernel keeps
+    transposed."""
+    assert kda_ops._takes_kernel(128) and not kda_ops._takes_kernel(32)
+    x = kernel_inputs(L, decay)
+    o = jax.jit(kda_ops.kda)(*x)
+    assert bool(jnp.all(jnp.isfinite(o)))
+    assert rel(o, kda_ops.kda_recurrent(*x)) < 2e-6
+    assert rel(o, plain_form(monkeypatch, kda_ops.kda, *x)) < 1e-6
+    pad = lambda a: jnp.pad(a, ((0, 0), (0, -L % 64)) + ((0, 0),)
+                            * (a.ndim - 2))
+    padded = tuple(map(pad, x))
+    got, (*_, states) = jax.jit(kda_ops._kda_chunks_fwd)(*padded)
+    want, (*_, plain) = plain_form(monkeypatch, kda_ops._kda_chunks_fwd,
+                                   *padded)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    assert states.shape == plain.shape == (kda_ops.n_chunks(L), 1, 2, 128,
+                                           128)
+    assert states.dtype == plain.dtype == jnp.float32
+    assert not bool(jnp.any(states[0])) and bool(jnp.all(jnp.isfinite(states)))
+    if len(states) > 1:
+        assert rel(states[1:], jnp.swapaxes(plain, -1, -2)[1:]) < 1e-6
+
+
+@KERNEL_CASES
+def test_kernel_gradients_are_the_plain_forms(monkeypatch, L, decay):
+    """All five gradients through ``kda_bwd`` (the chunk-local part formed
+    again in VMEM, the recurrence run backward and the chunk-local gradient,
+    a chunk at a time from the last) against the plain form's: autodiff of
+    ``_intra`` and of ``_inter`` in the scan, nothing of it written by
+    hand."""
+    x = kernel_inputs(L, decay, seed=1)
+    w = jax.random.normal(jax.random.PRNGKey(9), x[0].shape)
+    got = jax.jit(lambda *a: all_grads(kda_ops.kda, a, w))(*x)
+    want = plain_form(monkeypatch,
+                      lambda *a: all_grads(kda_ops.kda, a, w), *x)
+    for name, a, b in zip("q k v g beta".split(), got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        assert bool(jnp.all(jnp.isfinite(a))), name
+        # (the log-decay's gradient under strong decay is what float32 leaves
+        # of terms near 1e-9, in either form)
+        assert rel(a, b) < (1e-3 if name == "g" else 1e-5), name
+
+
+@pytest.mark.parametrize("spread", [0.3, 0.0], ids=["correlated", "collinear"])
+def test_kernels_stay_stable_on_correlated_keys(spread):
+    """``test_correlated_keys_and_strong_writes_stay_stable``'s inputs
+    through the kernels: the tile's inverse is substitution in blocks and
+    merges too, no entry past 1."""
+    ks = jax.random.split(jax.random.PRNGKey(0), 6)
+    unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)
+    B, L, H, D = 1, 192, 2, 128
+    k = unit(jax.random.normal(ks[5], (1, 1, H, D))
+             + spread * jax.random.normal(ks[1], (B, L, H, D)))
+    x = (unit(jax.random.normal(ks[0], (B, L, H, D))) * D ** -0.5, k,
+         jax.random.normal(ks[2], (B, L, H, D)),
+         -1e-3 * jax.nn.softplus(jax.random.normal(ks[3], (B, L, H, D))),
+         jax.nn.sigmoid(4.0 + jax.random.normal(ks[4], (B, L, H))))
+    want = kda_ops.kda_recurrent(*x)
+    assert rel(jax.jit(kda_ops.kda)(*x), want) < 1e-5
+    w = jax.random.normal(jax.random.PRNGKey(9), want.shape)
+    for got, ref in zip(jax.jit(lambda *a: all_grads(kda_ops.kda, a, w))(*x),
+                        all_grads(kda_ops.kda_recurrent, x, w)):
+        assert rel(got, ref) < 1e-4
+    N = -0.9 * jnp.tril(jnp.ones((64, 64)), -1)
+    row, col = (jax.lax.broadcasted_iota(jnp.int32, (64, 64), i)
+                for i in (0, 1))
+    inverse = kda_ops._tile_inverse(N, row, col)
+    assert float(jnp.max(jnp.abs(inverse))) <= 1.0
+    np.testing.assert_allclose(inverse, jnp.linalg.inv(jnp.eye(64) - N),
+                               atol=1e-6)
+    np.testing.assert_allclose(inverse, kda_ops._unit_lower_inverse(N),
+                               atol=1e-6)
+
+
+@pytest.mark.parametrize("decay_sums", ["float32", "bfloat16"])
+def test_kernels_in_bfloat16_keep_float32_decay_sums_and_state(
+        monkeypatch, decay_sums):
+    """bfloat16 q, k and v through the forward kernel, three chunks of decay
+    near 1/2 a token (``G`` to -45 a chunk): the output stays within 1e-2 of
+    the float32 recurrence on the same inputs, bfloat16's rounding, and the
+    chunk-entry states and the log-decay's gradient are float32.  With the
+    decay sums rounded to bfloat16 inside the tile it is more than 3e-2 off:
+    this is the case that sees what the benchmark's ``correct`` cannot
+    (PERF.md section 6, PR 32 (3)).  Both cases trace the kernel's call anew
+    (what ``ops.kda`` jits, unjitted), so the tile helper as patched is the
+    one traced and no other trace is touched."""
+    x = kernel_inputs(192, 1.0)
+    x = tuple(a.astype(jnp.bfloat16) for a in x[:3]) + x[3:]
+    want = kda_ops.kda_recurrent(*x)
+    if decay_sums == "bfloat16":
+        exact = kda_ops._decay_sums
+        monkeypatch.setattr(kda_ops, "_decay_sums", lambda g, row, col: exact(
+            g, row, col).astype(jnp.bfloat16).astype(jnp.float32))
+    o, states = jax.jit(lambda *a: kda_ops._kda_kernel.__wrapped__(
+        *map(kda_ops._flat, a[:4]), a[4], H=2, interpret=True))(*x)
+    off = rel(o.reshape(want.shape).astype(jnp.float32), want)
+    assert o.dtype == jnp.bfloat16
+    assert states.dtype == jnp.float32 and states.shape[0] == 3
+    if decay_sums == "bfloat16":
+        assert off > 3e-2
+        return
+    assert off < 1e-2
+    assert rel(o.reshape(want.shape).astype(jnp.float32),
+               kda_ops.kda(*x).astype(jnp.float32)) == 0.0
+    grads = jax.eval_shape(lambda *a: all_grads(kda_ops.kda, a, 1.0), *x)
+    assert [g.dtype for g in grads] == [jnp.bfloat16] * 3 + [jnp.float32] * 2
+
+
+# ------------------------------------------------ on more than one device
+#
+# The compiler partitions no Mosaic kernel (tests/test_aot_compile.py asks the
+# chip's), so on a mesh the recurrence runs in a ``shard_map`` over the batch
+# and the heads, whichever form the head width takes.
+
+@pytest.mark.parametrize("axes", [{"dp": 2, "tp": 2}, {"tp": 4}, {"dp": 4}],
+                         ids=["dp2-tp2", "tp4", "dp4"])
+@pytest.mark.parametrize("head_dim", [16, 128], ids=["plain", "kernels"])
+def test_the_recurrence_on_a_mesh_is_one_devices(head_dim, axes):
+    """``llama._kda_sharded``: values and all five gradients on a mesh are
+    one device's, the batch split over ``dp`` and the heads over ``tp``; the
+    call is a ``shard_map`` and each device's kernels, where the width takes
+    them, stand inside it on its own rows and heads."""
+    mesh = pmesh.make_mesh(axes, devices=jax.devices()[:4])
+    x = kda_inputs(130, 1.0, B=4, H=4, D=head_dim, seed=2)
+    w = jax.random.normal(jax.random.PRNGKey(9), x[0].shape)
+    sharded = llama._kda_sharded(mesh, 4)
+    assert llama._kda_sharded(None, 4) is kda_ops.kda
+    both = lambda fn: jax.jit(lambda *a: (fn(*a), all_grads(fn, a, w)))
+    (o, grads), (want, want_grads) = both(sharded)(*x), both(kda_ops.kda)(*x)
+    assert rel(o, want) < 1e-6
+    for name, a, b in zip("q k v g beta".split(), grads, want_grads):
+        assert a.shape == b.shape and rel(a, b) < 1e-5, name
+    (outer,) = [e for e in jax.make_jaxpr(sharded)(*x).jaxpr.eqns]
+    assert outer.primitive.name == "shard_map"
+    local = (4 // axes.get("dp", 1), 192, 4 // axes.get("tp", 1) * head_dim)
+    inside = _scans_and_kernels(outer.params["jaxpr"])
+    if head_dim == 128:
+        assert inside == [("pallas_call", "kda_fwd")]
+        kernel = _find(outer.params["jaxpr"], "pallas_call")
+        assert kernel.invars[0].aval.shape == local
+    else:
+        assert inside == [("scan", 3, False)]
+
+
+def _find(jaxpr, primitive):
+    """The first equation of ``primitive`` in a jaxpr, sub-jaxprs included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == primitive:
+            return eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            if (found := _find(sub, primitive)) is not None:
+                return found
+    return None
 
 
 # ------------------------------------------------- flash with Dk != Dv
@@ -216,7 +403,7 @@ def test_kda_block_against_the_reference(model, reference):
     lp = layer_of(params, 1)                 # a KDA layer of the moe run
     x = jax.random.normal(jax.random.PRNGKey(3), (2, 96, cfg.d_model))
     want = jax.vmap(lambda s: reference.kda_mixer(file_of(cfg), lp, s))(x)
-    assert rel(llama._kda_block(cfg, lp, x), want) < 1e-5
+    assert rel(llama._kda_block(cfg, lp, x, kda_ops.kda), want) < 1e-5
 
 
 @pytest.mark.parametrize("attn", ["full", "flash"])
@@ -409,15 +596,48 @@ def test_five_layers_against_the_reference(model, reference, sample, plain):
             assert rel(g, w) < 2e-3, jax.tree_util.keystr(path)
 
 
+def test_four_layers_on_a_mesh():
+    """Under GSPMD on dp x tp the hybrid stack (KDA, KDA, KDA, MLA; heads of
+    128 channels, so the kernels) gives one device's loss and gradients, the
+    flash kernels and the KDA recurrence each in a ``shard_map`` of its own
+    over the batch and the heads."""
+    cfg = dataclasses.replace(kimi_tiny(n_layers=4), kda_head_dim=128)
+    params = llama.init(jax.random.PRNGKey(0), cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 96), 0, cfg.vocab)
+    sample = (tokens, jnp.roll(tokens, -1, 1))
+    loss_of = lambda mesh: jax.jit(jax.value_and_grad(llama.make_loss_fn(
+        cfg, mesh, attn="flash", loss_chunk=32)))
+    alone = loss_of(None)(params, sample)
+    mesh = pmesh.make_mesh({"dp": 2, "tp": 2}, devices=jax.devices()[:4])
+    loss, grads = loss_of(mesh)(llama.shard_params(params, mesh, cfg), sample)
+    np.testing.assert_allclose(loss, alone[0], rtol=1e-5)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(grads),
+                            jax.tree.leaves(alone[1])):
+        assert rel(a, b) < 1e-4, jax.tree_util.keystr(path)
+
+
+@pytest.mark.parametrize("head_dim", [16, 128], ids=["plain", "kernels"])
 @pytest.mark.parametrize("remat", ["dots", "full"])
 def test_remat_gives_the_gradients_and_runs_nothing_twice(model, sample,
-                                                          remat):
+                                                          remat, head_dim):
     """``"dots"`` and ``"full"`` give ``"none"``'s gradients, and the step's
     jaxpr holds each flash kernel and each KDA scan once forward and once
-    backward: neither policy replays a kernel or the recurrence."""
+    backward: neither policy replays a kernel or the recurrence.  At a head
+    width that takes the KDA kernels there is no scan: a KDA layer holds
+    ``kda_fwd`` once and ``kda_bwd`` once, and the forward pass a policy
+    replays, whose output and states it kept, adds none."""
     cfg, params = model
-    grads = lambda r: jax.jit(jax.grad(llama.make_loss_fn(
-        cfg, attn="flash", remat=r, loss_chunk=32)))(params, sample)
+    if head_dim == 16:
+        grads = lambda r: jax.jit(jax.grad(llama.make_loss_fn(
+            cfg, attn="flash", remat=r, loss_chunk=32)))(params, sample)
+    else:       # the recurrence alone, checkpointed as a layer is
+        cfg = dataclasses.replace(cfg, kda_heads=1, kda_head_dim=head_dim)
+        params = jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0),
+                                                   cfg))
+        x = kda_inputs(130, 1.0, B=1, H=1, D=head_dim)
+        grads = lambda r: jax.jit(jax.grad(
+            lambda *a: jnp.sum(jnp.sin(llama._wrap_remat(kda_ops.kda, r)(
+                *a))), argnums=(0, 1, 2, 3, 4)))(*x)
     for g, w in zip(jax.tree.leaves(grads(remat)),
                     jax.tree.leaves(grads("none"))):
         assert rel(g, w) < 1e-4
@@ -432,11 +652,16 @@ def test_remat_gives_the_gradients_and_runs_nothing_twice(model, sample,
     # one backward for each of the four KDA layers; the one latent layer's two
     # flash kernels.
     chunks = kda_ops.n_chunks(160)
-    assert found.count(("scan", chunks, False)) == 4
-    assert found.count(("scan", chunks, True)) == 4
+    scans = 4 if head_dim == 16 else 0
+    assert found.count(("scan", chunks, False)) == scans
+    assert found.count(("scan", chunks, True)) == scans
     assert [f for f in found if f[0] == "pallas_call"
             and "flash" in (f[1] or "")] == [("pallas_call", "flash_fwd"),
                                               ("pallas_call", "flash_bwd")]
+    kda_kernels = [f[1] for f in found if f[0] == "pallas_call"
+                   and "kda" in (f[1] or "")]
+    assert kda_kernels == ([] if head_dim == 16 else
+                           ["kda_fwd"] * 4 + ["kda_bwd"] * 4)
 
 
 def _scans_and_kernels(jaxpr):

@@ -638,6 +638,26 @@ def _flash_attention_sharded(mesh: Optional[Mesh], heads: int,
     return sharded
 
 
+def _kda_sharded(mesh: Optional[Mesh], heads: int) -> Callable:
+    """The KDA recurrence ``(q, k, v, g, beta) -> o`` (:func:`ops.kda.kda`).
+    On a mesh it runs inside a ``shard_map`` over the batch (``dp``) and head
+    (``tp``) axes, as the flash kernel does and for its reason: its kernels
+    are Mosaic's, which the compiler will not partition, and a head's
+    recurrence needs no other head's and no other row's.  The sequence stays
+    whole on every device (the state crosses no shard; the rings are
+    refused)."""
+    from ..ops.kda import kda
+
+    if mesh is None or mesh.size == 1:
+        return kda
+    from jax import shard_map
+
+    wide = _mesh_spec(P(AXIS_DP, None, _tp_head_axis(mesh, heads, heads),
+                        None), mesh)
+    return shard_map(kda, mesh=mesh, in_specs=(wide,) * 4 + (P(*wide[:3]),),
+                     out_specs=wide, check_vma=False)
+
+
 def _make_attn_impl(cfg: Config, attn: str, mesh: Optional[Mesh],
                     scale: float) -> Callable:
     """Resolve the attention mode to one callable ``(q, k, v) -> o`` with
@@ -1128,17 +1148,16 @@ def _short_conv(x: jax.Array, w: jax.Array) -> jax.Array:
     return sum(xp[:, i:i + L] * w[i].astype(jnp.float32) for i in range(taps))
 
 
-def _kda_block(cfg: Config, lp: Params, x: jax.Array) -> jax.Array:
+def _kda_block(cfg: Config, lp: Params, x: jax.Array,
+               kda: Callable) -> jax.Array:
     """The KDA mixer on the normed input x (B, L, D): q, k and v each a
     projection, a short convolution and a SiLU, q and k L2-normalised over a
     head's channels (q scaled by ``head_dim ** -0.5``); the log-decay a head
     and channel ``g = -exp(a_log) * softplus(x W_f + dt_bias)`` and the write
-    strength ``beta = sigmoid(x W_b)`` in float32; the recurrence
-    (:func:`ops.kda.kda`, scope ``kda``); the output normed a head and gated
-    by ``sigmoid(x W_g + b_g)`` before ``W_o``.  ``W_f`` and ``W_g`` are
-    low-rank pairs through ``head_dim``."""
-    from ..ops.kda import kda
-
+    strength ``beta = sigmoid(x W_b)`` in float32; the recurrence (``kda``,
+    :func:`_kda_sharded`'s for the mesh; scope ``kda``); the output normed a
+    head and gated by ``sigmoid(x W_g + b_g)`` before ``W_o``.  ``W_f`` and
+    ``W_g`` are low-rank pairs through ``head_dim``."""
     B, L, _ = x.shape
     H, hd = cfg.kda_heads, cfg.kda_head_dim
 
@@ -1196,8 +1215,8 @@ def _attention_block(cfg: Config, lp: Params, h: jax.Array,
     too, before the add); with ``with_kv`` also the (pre-repeat,
     native-KV-head) K/V projections.  ``mixer`` is the layer's kind:
     ``"attn"`` the softmax attention written here, ``"kda"``
-    :func:`_kda_block`, ``"mla"`` :func:`_mla_block` (``attn_impl`` then the
-    one made for its scale)."""
+    :func:`_kda_block` (``attn_impl`` then the recurrence), ``"mla"``
+    :func:`_mla_block` (``attn_impl`` then the one made for its scale)."""
     B, L, _ = h.shape
     hd, H, KV = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
     # Names in the device program (docs/observability.md): ``attn`` (the
@@ -1210,8 +1229,8 @@ def _attention_block(cfg: Config, lp: Params, h: jax.Array,
     if mixer != "attn":
         with jax.named_scope("attn"):
             x = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
-            o = (_kda_block(cfg, lp, x) if mixer == "kda"
-                 else _mla_block(cfg, lp, x, attn_impl))
+            o = (_kda_block if mixer == "kda" else _mla_block)(
+                cfg, lp, x, attn_impl)
             return h + constrain(o)
     with jax.named_scope("attn"):
         x = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
@@ -1511,7 +1530,8 @@ def _refuse_runs(cfg: Config, what: str, missing: str) -> None:
 def _mixer_impls(cfg: Config, attn: str, mesh: Optional[Mesh]):
     """{mixer kind: attention callable} for the layers of ``cfg``: the
     softmax layers' at ``head_dim ** -0.5``, the latent layers' at the scale
-    of their whole key; a KDA layer takes none."""
+    of their whole key; a KDA layer's is its recurrence, ``(q, k, v, g, beta)
+    -> o``."""
     mla = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
     if cfg.layer_kinds is not None and attn.startswith("ring"):
         raise NotImplementedError(
@@ -1522,7 +1542,7 @@ def _mixer_impls(cfg: Config, attn: str, mesh: Optional[Mesh]):
                                     1.0 / np.sqrt(cfg.head_dim)),
             "mla": (_make_attn_impl(cfg, attn, mesh, 1.0 / np.sqrt(mla))
                     if mla else None),
-            "kda": None}
+            "kda": _kda_sharded(mesh, cfg.kda_heads)}
 
 
 def _stacks(cfg: Config, params: Params):

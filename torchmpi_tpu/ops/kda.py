@@ -1,5 +1,6 @@
 """Kimi Delta Attention (KDA): the gated delta rule with a per-channel decay,
-in its chunked form, forward and backward, in plain XLA.
+in its chunked form, forward and backward: two Pallas kernels where a head's
+channels fill whole lanes, plain XLA elsewhere.
 
 The recurrence, for one head with keys and values ``d`` wide, state ``S``
 (d x d, float32, zero at the start), decay ``a_t = exp(g_t)`` in (0, 1)^d
@@ -24,31 +25,62 @@ over the chunk's rows up to r, ``S0`` the state that enters the chunk)::
     O  = (Q * e^G) S0 + tril(P) U
     S' = Diag(e^{G_C}) S0 + (K * e^{G_C - G})^T U
 
-``M``, ``P``, ``T``, ``W`` and ``T V`` are formed for all chunks at once
-(:func:`_intra`); ``U``, ``O`` and ``S'`` run chunk after chunk, a
-``lax.scan`` over L / 64 steps each batched over batch and heads
-(:func:`_inter`).  Every exponent is a difference ``G_i - G_j`` with i >= j,
+``M``, ``P``, ``T``, ``W`` and ``T V`` are chunk-local (:func:`_intra`);
+``U``, ``O`` and ``S'`` run chunk after chunk (:func:`_inter`).  Every
+exponent is a difference ``G_i - G_j`` with i >= j,
 so never positive: ``e^{-G}`` alone overflows float32 after a few tokens of
 strong decay.  The products that need both sides of such a difference run in
 sub-blocks of ``SUB`` = 16 rows: off the diagonal each side is taken against
 the first row of the row block, which lies between them, and on the diagonal
-the 16 x 16 x d exponents are formed one by one (sub-blocks of 8 would halve
-those elementwise passes and double the copies of k the other products
-read: 0.1 GB more of a plan that has 0.15 to spare at Kimi Linear's widths;
-compiled, not run).  ``T``'s inverse is that of
-a unit lower-triangular 64 x 64 matrix, by substitution in blocks
-(:func:`_unit_lower_inverse`, which says why not by the powers of N).  The state and the decay
-sums are float32; the other products take operands of the inputs' type and
-accumulate in float32.
+the 16 x 16 x d exponents are formed one by one.  ``T``'s inverse is that
+of a unit lower-triangular 64 x 64 matrix, by substitution in blocks
+(:func:`_unit_lower_inverse`, which says why not by the powers of N).  The
+state and the decay sums are float32; the other products take operands of
+the inputs' type and accumulate in float32.
 
 The gradient is a hand-written rule (``jax.custom_vjp``): the backward pass
-holds the inputs and the chunk-entry states and, a group of heads at a time,
-forms the chunk-local part again for all chunks at once, runs the recurrence
-backward once, a reverse scan over the chunks, and differentiates the
-chunk-local part.  The forward rule names what
-a caller's ``jax.checkpoint`` must keep so that neither scan runs twice
+holds the inputs and the chunk-entry states, forms the chunk-local part
+again, runs the recurrence backward once over the chunks and differentiates
+the chunk-local part.  The forward rule names what a caller's
+``jax.checkpoint`` must keep so that the recurrence never runs twice
 (``KDA_RESIDUAL_NAMES``: the output and the chunk-entry states), as
 ``ops.flash_attention`` names its own.
+
+Which form runs is read from the head width alone (:func:`_takes_kernel`),
+on every backend (off the TPU through the Pallas interpreter, as
+``ops.flash_attention``):
+
+* ``head_dim % 128 == 0`` (Kimi Linear's 128): two Mosaic kernels,
+  ``kda_fwd`` and ``kda_bwd``, on a grid of (batch, groups of
+  ``_HEADS_A_STEP`` heads, chunks) whose chunk axis runs in turn.  A step
+  loads a chunk's tiles of q, k, v and g (64 x 128 a head, out of the (B,
+  L, H * D) layout the layer keeps: no chunked copy) and beta into VMEM
+  once, and every intermediate of the algebra above lives and dies there
+  (:func:`_tile_forward`: the decay sums, ``near``/``far``, the
+  off-diagonal products, the diagonal blocks a column of all four at a
+  time, the inverse by substitution and merges, ``T``, ``W``, ``T V``,
+  ``Q e^G``, ``K e^{G_C - G}``); the state, float32, stays in a VMEM scratch
+  from one chunk to the next (:func:`_tile_inter`) and is written out, as it
+  enters each chunk, as the residual.  ``kda_bwd`` walks the chunks from
+  the last: it forms the tile again, takes :func:`_tile_inter`'s gradient
+  with the state's cotangent in scratch and then the tile's
+  (:func:`_tile_backward`, the gradient written out on VMEM tiles: ``dG =
+  rows * drows - cols * dcols``, ``N' = X^T X' X^T``), and writes dq, dk,
+  dv, dg and dbeta.  Nothing chunk-local is ever in HBM, and there is no scan.
+* other widths: :func:`_intra` for all chunks at once, :func:`_inter` in a
+  ``lax.scan``, and in the backward pass autodiff of both
+  (:func:`_plain_fwd`, :func:`_plain_bwd`).  No configuration has such a
+  width: this form is the algebra as XLA's own operations, kept short, and
+  the kernels' oracle in ``tests/``, its gradient autodiff's where the
+  kernels' is written out.
+
+The two forms share no code, only the algebra: same sub-blocks, same
+exponents (differences, never positive), same float32 decay sums, state and
+inverse at ``Precision.HIGHEST``, same operands rounded to the inputs' type.
+
+A Mosaic kernel is not partitioned by the compiler: on a mesh of several
+devices the caller runs :func:`kda` inside a ``shard_map`` over the batch
+and the heads (``models.llama._kda_sharded``), as it does the flash kernel.
 """
 
 from __future__ import annotations
@@ -59,6 +91,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 CHUNK = 64
 SUB = 16
@@ -70,9 +104,6 @@ KDA_RESIDUAL_NAMES = ("kda_o", "kda_state")
 
 _F32 = jnp.float32
 _EXACT = lax.Precision.HIGHEST
-# Three bfloat16 passes a product: float32's exponent and 16 of its mantissa's
-# bits, for what is rounded to the operands' type once it is formed.
-_NEAR = lax.Precision.HIGH
 
 
 def n_chunks(seq_len: int) -> int:
@@ -92,31 +123,12 @@ def _pair_decay(Gb, strict: bool):
     return jnp.exp(jnp.where(keep, diff, -1e30))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _diag_blocks(a, k, Gb, strict: bool):
     """``sum_c a_ic k_jc exp(G_ic - G_jc)`` inside each sub-block, (..., nb,
-    SUB, SUB) float32, for i > j (``strict``) or i >= j, zero elsewhere.
-    These are the recurrence's elementwise passes, SUB * C * D exponentials
-    a chunk, and the gradient is written out to make two of them, not the
-    four autodiff makes: with ``A_ic = sum_j M'_ij k_jc E_ijc`` and ``K_jc =
-    sum_i M'_ij a_ic E_ijc`` the gradients of a and k, that of G is ``a * A -
-    k * K`` (E's derivative in G_i is E, in G_j is -E).  (M's and P's blocks
-    in one call, the exponentials formed once for both, held 0.9 GB more at
-    Kimi Linear's widths: compiled, not run.)"""
+    SUB, SUB) float32, for i > j (``strict``) or i >= j, zero elsewhere: SUB
+    * C * D exponentials a chunk."""
     return jnp.sum(a[..., :, None, :] * k[..., None, :, :]
                    * _pair_decay(Gb, strict), axis=-1)
-
-
-def _diag_blocks_bwd(strict, saved, dM):
-    a, k, Gb = saved
-    weighted = dM[..., None] * _pair_decay(Gb, strict)
-    da = jnp.sum(weighted * k[..., None, :, :], axis=-2)
-    dk = jnp.sum(weighted * a[..., :, None, :], axis=-3)
-    return da, dk, a * da - k * dk
-
-
-_diag_blocks.defvjp(lambda a, k, Gb, strict: (_diag_blocks(a, k, Gb, strict),
-                                              (a, k, Gb)), _diag_blocks_bwd)
 
 
 def _block_diagonal(blocks):
@@ -143,7 +155,6 @@ def _substitute(N):
     return jnp.stack(rows, axis=-2)
 
 
-@jax.custom_vjp
 def _unit_lower_inverse(N):
     """``(I - N)^-1`` for strictly lower-triangular N (..., C, C), float32:
     the ``SUB`` x ``SUB`` blocks on the diagonal by forward substitution
@@ -155,9 +166,7 @@ def _unit_lower_inverse(N):
     correlated (``N^32`` to 1e17 for collinear keys) and the inverse is what
     is left of their cancellation; float32 has none of it left, and the
     recurrence's state grew a thousandfold a chunk after one optimizer step
-    had moved seeded keys together (my chip runs, PR 32).  The gradient is
-    written out (``X' = X N' X``), so a backward pass holds the inverse and
-    no intermediate."""
+    had moved seeded keys together (my chip runs, PR 32)."""
     C = N.shape[-1]
     lead = N.shape[:-2]
 
@@ -186,16 +195,6 @@ def _unit_lower_inverse(N):
              jnp.concatenate([low, B], axis=-1)], axis=-2)
         size *= 2
     return X[..., 0, :, :]
-
-
-def _unit_lower_inverse_bwd(inv, g):
-    t = jnp.swapaxes(inv, -1, -2)
-    return (jnp.matmul(jnp.matmul(t, g, precision=_NEAR), t,
-                       precision=_NEAR),)
-
-
-_unit_lower_inverse.defvjp(lambda N: (_unit_lower_inverse(N),) * 2,
-                           _unit_lower_inverse_bwd)
 
 
 def _intra(q, k, v, g, beta):
@@ -252,70 +251,467 @@ def _inter(S, chunk):
     return S, O.astype(dt)
 
 
+def _chunked(a):
+    """(B, L, H, ...) -> (B, H, N, CHUNK, ...), L whole chunks."""
+    a = jnp.moveaxis(a, 2, 1)
+    return a.reshape(*a.shape[:2], -1, CHUNK, *a.shape[3:])
+
+
+def _unchunked(a):
+    """(B, H, N, CHUNK, ...) -> (B, L, H, ...)."""
+    return jnp.moveaxis(a.reshape(*a.shape[:2], -1, *a.shape[4:]), 1, 2)
+
+
 def _by_chunk(tree):
     """(B, H, N, ...) leaves -> (N, B, H, ...): the scan's axis first."""
     return jax.tree.map(lambda a: jnp.moveaxis(a, 2, 0), tree)
 
 
-@jax.custom_vjp
-def _kda_chunks(q, k, v, g, beta):
-    """q, k, v, g: (B, H, N, C, D); beta: (B, H, N, C) -> o (B, H, N, C, D)."""
-    return _kda_chunks_fwd(q, k, v, g, beta)[0]
-
-
-def _kda_chunks_fwd(q, k, v, g, beta):
-    B, H, _, _, D = q.shape
+def _plain_fwd(q, k, v, g, beta):
+    """The plain form forward: the chunk-local part of all chunks at once,
+    then the chunks in a scan.  q, k, v, g (B, L, H, D), beta (B, L, H), L
+    whole chunks -> o (B, L, H, D) and the chunk-entry states (N, B, H, D,
+    D) float32."""
+    B, _, H, D = q.shape
 
     def step(S, chunk):
         S2, O = _inter(S, chunk)
         return S2, (S, O)
 
-    _, (states, o) = lax.scan(step, jnp.zeros((B, H, D, D), _F32),
-                              _by_chunk(_intra(q, k, v, g, beta)))
-    o = checkpoint_name(jnp.moveaxis(o, 0, 2), KDA_RESIDUAL_NAMES[0])
+    _, (states, o) = lax.scan(
+        step, jnp.zeros((B, H, D, D), _F32),
+        _by_chunk(_intra(*map(_chunked, (q, k, v, g, beta)))))
+    return _unchunked(jnp.moveaxis(o, 0, 2)), states
+
+
+def _plain_bwd(q, k, v, g, beta, states, do):
+    """The plain form backward, by autodiff of its two parts: the
+    chunk-local part formed again and kept for its gradient, the recurrence
+    run backward over the chunks from the states kept, the chunk-local
+    gradient."""
+    chunks, intra_vjp = jax.vjp(_intra, *map(_chunked, (q, k, v, g, beta)))
+
+    def step(dS, xs):
+        S, chunk, dO = xs
+        _, vjp = jax.vjp(_inter, S, chunk)
+        return vjp((dS, dO))
+
+    _, dchunks = lax.scan(
+        step, jnp.zeros_like(states[0]),
+        (states, _by_chunk(chunks), _by_chunk(_chunked(do))), reverse=True)
+    return tuple(map(_unchunked, intra_vjp(
+        jax.tree.map(lambda a: jnp.moveaxis(a, 0, 2), dchunks))))
+
+
+# ------------------------------------------------------------ the kernels
+#
+# One chunk of a few heads a grid step, the chunks of a head in turn: a
+# chunk's tiles of q, k, v and g (CHUNK x D a head) and beta are loaded into
+# VMEM once, everything :func:`_intra` forms on the way to its six results
+# lives and dies there, and :func:`_inter`'s step follows on the spot, the
+# state in a VMEM scratch.  The tile functions are that algebra on
+# two-dimensional values (rows on sublanes, channels or columns on lanes), in
+# what Mosaic lowers: static slices, broadcasts along one axis, sums along
+# one axis, ``dot_general``.  The kernels read and write the sequence as the
+# layer lays it out, (B, L, H * D), a block of CHUNK rows and a head's D
+# lanes: no chunked copy of anything stands in HBM.
+
+def _dot(a, b, contract=(1, 0), precision=None):
+    """``a @ b`` or, by ``contract``, ``a @ b^T`` (1, 1) and ``a^T @ b`` (0,
+    0): float32 out of operands of the inputs' type."""
+    return lax.dot_general(a, b, (((contract[0],), (contract[1],)), ((), ())),
+                           precision=precision, preferred_element_type=_F32)
+
+
+_exact = functools.partial(_dot, precision=_EXACT)
+
+
+def _each_block(a, row_of):
+    """(CHUNK, n) -> (CHUNK, n): every row of a sub-block takes ``row_of``
+    its block, (SUB, n) -> (1, n)."""
+    return jnp.concatenate(
+        [jnp.broadcast_to(row_of(a[lo:lo + SUB]), (SUB, a.shape[1]))
+         for lo in range(0, CHUNK, SUB)], axis=0)
+
+
+def _block_row(a, j: int):
+    """Every row of a sub-block takes the block's row ``j``."""
+    return _each_block(a, lambda block: block[j:j + 1])
+
+
+def _block_sum(a):
+    """Every row of a sub-block takes the sum of the block's rows."""
+    return _each_block(a, lambda block: jnp.sum(block, axis=0, keepdims=True))
+
+
+def _block_columns(a, row):
+    """(CHUNK, CHUNK) -> (CHUNK, SUB): of each row the columns of its own
+    sub-block (the diagonal blocks, one under the other)."""
+    out = jnp.zeros((CHUNK, SUB), a.dtype)
+    for lo in range(0, CHUNK, SUB):
+        mine = (row[:, :SUB] >= lo) & (row[:, :SUB] < lo + SUB)
+        out = jnp.where(mine, a[:, lo:lo + SUB], out)
+    return out
+
+
+def _decay_sums(g, row, col):
+    """``G_r``, the sum of g over the tile's rows up to r: float32, a product
+    with the lower-triangular ones as in :func:`_intra`."""
+    return _exact((col <= row).astype(_F32), g)
+
+
+def _transposed(a, row, col):
+    """A row (1, CHUNK) as a column (CHUNK, 1) and the other way round:
+    through the diagonal of a tile."""
+    return jnp.sum(jnp.where(row == col, a, 0.0),
+                   axis=0 if a.shape[1] == 1 else 1, keepdims=True)
+
+
+def _tile_off_block(G, qf, kf, lo: int):
+    """Row block ``lo`` against every earlier row, both sides taken against
+    the block's first row: ``near`` (SUB, D), ``far`` (CHUNK, D) and the
+    product's two operands before they are rounded, ``rows`` (k's then q's,
+    2 SUB x D) and ``cols`` (CHUNK, D)."""
+    ref = G[lo:lo + 1]
+    near = jnp.exp(G[lo:lo + SUB] - ref)
+    far = jnp.exp(jnp.minimum(ref - G, 0.0))
+    rows = jnp.concatenate([kf[lo:lo + SUB] * near, qf[lo:lo + SUB] * near],
+                           axis=0)
+    return near, far, rows, kf * far
+
+
+def _earlier(lo: int):
+    """(2 SUB, CHUNK): the columns before row ``lo``.  (An iota of its own:
+    Mosaic refuses a slice of one along sublanes.)"""
+    return lax.broadcasted_iota(jnp.int32, (2 * SUB, CHUNK), 1) < lo
+
+
+def _tile_pair_decay(G, kf, j: int):
+    """``exp(G_i - G_j)`` of every row i against row ``j`` of its own
+    sub-block (1 where i < j: every use masks those), and ``k_j`` beside
+    it."""
+    return (jnp.exp(jnp.minimum(G - _block_row(G, j), 0.0)),
+            _block_row(kf, j))
+
+
+def _tile_inverse(N, row, col):
+    """:func:`_unit_lower_inverse` on one tile: the diagonal blocks by
+    forward substitution, the four at once (a block's row j is final after
+    step j - 1 and is then added into the rows below it), then the two merges
+    as products of whole tiles, N's blocks under the diagonal masked in."""
+    Nc = _block_columns(N, row)
+    X = (row == col).astype(_F32)
+    for j in range(SUB - 1):
+        X = X + Nc[:, j:j + 1] * _block_row(X, j)
+    size = SUB
+    while size < CHUNK:
+        below = ((row // size) % 2 == 1) & (col // size == row // size - 1)
+        X = X + _exact(_exact(X, jnp.where(below, N, 0.0)), X)
+        size *= 2
+    return X
+
+
+def _tile_forward(q, k, v, g, brow):
+    """:func:`_intra` for one chunk of one head: q, k, v (CHUNK, D), g
+    (CHUNK, D) float32, beta as a row (1, CHUNK).  Returns the six results
+    (``dC`` (1, D)) and what the backward tile reads again."""
+    dt = q.dtype
+    row = lax.broadcasted_iota(jnp.int32, (CHUNK, CHUNK), 0)
+    col = lax.broadcasted_iota(jnp.int32, (CHUNK, CHUNK), 1)
+    bcol = _transposed(brow, row, col)
+    G = _decay_sums(g, row, col)
+    qf, kf = q.astype(_F32), k.astype(_F32)
+    off = [jnp.zeros((2 * SUB, CHUNK), _F32)]
+    for lo in range(SUB, CHUNK, SUB):
+        _, _, rows, cols = _tile_off_block(G, qf, kf, lo)
+        off.append(jnp.where(_earlier(lo),
+                             _dot(rows.astype(dt), cols.astype(dt), (1, 1)),
+                             0.0))
+    # The diagonal blocks, column j of all four at a time.
+    inside = col - row // SUB * SUB
+    Md = jnp.zeros((CHUNK, CHUNK), _F32)
+    Pd = jnp.zeros((CHUNK, CHUNK), _F32)
+    for j in range(SUB):
+        E, kj = _tile_pair_decay(G, kf, j)
+        kE = kj * E
+        Md = jnp.where(inside == j, jnp.sum(kf * kE, -1, keepdims=True), Md)
+        Pd = jnp.where(inside == j, jnp.sum(qf * kE, -1, keepdims=True), Pd)
+    M = (jnp.concatenate([o[:SUB] for o in off], axis=0)
+         + jnp.where(col < row, Md, 0.0))
+    P = (jnp.concatenate([o[SUB:] for o in off], axis=0)
+         + jnp.where(col <= row, Pd, 0.0))
+    # T = (I - N)^-1 Diag(beta), N = -Diag(beta) tril(M, -1) nilpotent.
+    X = _tile_inverse(-bcol * M, row, col)
+    T = (X * brow).astype(dt)
+    eG = jnp.exp(G)
+    Ke = (kf * eG).astype(dt)
+    GC = G[CHUNK - 1:]
+    out = (_dot(T, Ke).astype(dt), _dot(T, v).astype(dt),
+           (qf * eG).astype(dt), P.astype(dt),
+           (kf * jnp.exp(GC - G)).astype(dt), jnp.exp(GC))
+    return out, (G, qf, kf, M, X, T, eG, Ke, bcol, row, col)
+
+
+def _tile_backward(q, k, v, g, brow, saved, dW, dTV, dQe, dP, dKdec, ddC):
+    """The gradient of :func:`_tile_forward`'s six results in q, k, v, g and
+    beta (a row), the forward intermediates formed again.  Two rules carry
+    it: a product of two decayed operands gives ``dG = rows * drows - cols *
+    dcols``, whatever row both sides were taken against (``exp(G_i - G_j)``'s
+    derivative in ``G_i`` is itself, in ``G_j`` its negative); the inverse
+    ``X = (I - N)^-1`` gives ``N' = X^T X' X^T``, so the tile holds the
+    inverse and no intermediate of it."""
+    dt = q.dtype
+    G, qf, kf, M, X, T, eG, Ke, bcol, row, col = saved
+    f = lambda a: a.astype(_F32)
+    dW, dTV = dW.astype(dt), dTV.astype(dt)
+    # W = T Ke, TV = T v, T = X Diag(beta).
+    dT = _dot(dW, Ke, (1, 1)) + _dot(dTV, v, (1, 1))
+    dKe = _dot(T, dW, (0, 0))
+    dv = _dot(T, dTV, (0, 0))
+    dN = _exact(_exact(X, dT * brow, (0, 0)), X, (1, 1))
+    # N = -Diag(beta) tril(M, -1): M is masked already.
+    dbeta = (jnp.sum(dT * X, axis=0, keepdims=True) - _transposed(
+        jnp.sum(dN * M, -1, keepdims=True), row, col))           # (1, CHUNK)
+    dM = jnp.where(col < row, -bcol * dN, 0.0)
+    dP = jnp.where(col <= row, f(dP), 0.0)
+    # Ke = k e^G, Qe = q e^G, Kdec = k e^{G_C - G}, dC = e^{G_C}.
+    decay = jnp.exp(G[CHUNK - 1:] - G)
+    dkd = f(dKdec) * decay
+    dq = f(dQe) * eG
+    dk = dKe * eG + dkd
+    dG = kf * (dKe * eG - dkd) + qf * dq
+    last = (jnp.sum(kf * dkd, axis=0, keepdims=True)
+            + ddC * jnp.exp(G[CHUNK - 1:]))
+    dG = dG + jnp.where(row[:, :1] == CHUNK - 1, last, 0.0)
+    # The off-diagonal blocks of M and P.
+    zero = jnp.zeros((SUB, q.shape[1]), _F32)
+    dq_rows, dk_rows, dG_rows = [zero], [zero], [zero]
+    for lo in range(SUB, CHUNK, SUB):
+        near, far, rows, cols = _tile_off_block(G, qf, kf, lo)
+        dA = jnp.where(_earlier(lo), jnp.concatenate(
+            [dM[lo:lo + SUB], dP[lo:lo + SUB]], axis=0), 0.0).astype(dt)
+        drows = _dot(dA, cols.astype(dt))                        # (2 SUB, D)
+        dcols = _dot(dA, rows.astype(dt), (0, 0))                # (CHUNK, D)
+        dk_rows.append(near * drows[:SUB])
+        dq_rows.append(near * drows[SUB:])
+        both = rows * drows
+        dG_rows.append(both[:SUB] + both[SUB:])
+        dk = dk + far * dcols
+        dG = dG - cols * dcols
+    dq = dq + jnp.concatenate(dq_rows, axis=0)
+    dk = dk + jnp.concatenate(dk_rows, axis=0)
+    dG = dG + jnp.concatenate(dG_rows, axis=0)
+    # The diagonal blocks: ``da`` sums over j, ``dk_j`` over a block's rows.
+    dMc, dPc = _block_columns(dM, row), _block_columns(dP, row)
+    da_k, da_q, dk_j = (jnp.zeros_like(dG),) * 3
+    for j in range(SUB):
+        E, kj = _tile_pair_decay(G, kf, j)
+        wM, wP = dMc[:, j:j + 1] * E, dPc[:, j:j + 1] * E
+        da_k = da_k + wM * kj
+        da_q = da_q + wP * kj
+        dk_j = jnp.where(row[:, :1] % SUB == j,
+                         _block_sum(kf * wM + qf * wP), dk_j)
+    dq = dq + da_q
+    dk = dk + da_k + dk_j
+    dG = dG + kf * (da_k - dk_j) + qf * da_q
+    dg = _exact((col >= row).astype(_F32), dG)
+    return dq.astype(dt), dk.astype(dt), dv.astype(dt), dg, dbeta
+
+
+def _tile_update(St, chunk):
+    """``U`` of a chunk, and the state that enters it as the products read
+    it.  The state is kept transposed (``St`` (D, D) float32, values on the
+    rows), so that the decay a key channel runs along lanes."""
+    W, TV = chunk[:2]
+    Sd = St.astype(W.dtype)
+    return (TV.astype(_F32) - _dot(W, Sd, (1, 1))).astype(W.dtype), Sd
+
+
+def _tile_inter(St, chunk):
+    """:func:`_inter` on one tile: the state that leaves the chunk and the
+    chunk's output."""
+    _, _, Qe, P, Kdec, dC = chunk
+    U, Sd = _tile_update(St, chunk)
+    O = _dot(Qe, Sd, (1, 1)) + _dot(P, U)
+    return St * dC + _dot(U, Kdec, (0, 0)), O.astype(P.dtype)
+
+
+def _tile_inter_bwd(St, dSt, chunk, dO):
+    """The gradient of :func:`_tile_inter`: the cotangent of the state that
+    leaves the chunk (``dSt``, transposed like it) and of the chunk's output
+    -> that of the state that enters it and of the six chunk-local
+    tensors."""
+    W, _, Qe, P, Kdec, dC = chunk
+    dt = W.dtype
+    U, Sd = _tile_update(St, chunk)
+    dSd = dSt.astype(dt)
+    dU = _dot(P, dO, (0, 0)) + _dot(Kdec, dSd, (1, 1))
+    dUd = dU.astype(dt)
+    dchunk = (-_dot(dUd, Sd), dU, _dot(dO, Sd), _dot(dO, U, (1, 1)),
+              _dot(U, dSd), jnp.sum(dSt * St, axis=0, keepdims=True))
+    return (dSt * dC + _dot(dO, Qe, (0, 0)) - _dot(dUd, W, (0, 0))), dchunk
+
+
+# Heads a grid step takes, one after the other in one body.  On the chip, at
+# Kimi Linear's shapes, a layer forward and backward: 1 head 46.2 ms, 2 44.1,
+# 4 43.9, 8 43.0; the body is traced and lowered once for each head, 0.6 s a
+# program a head.
+_HEADS_A_STEP = 2
+
+
+def _heads_a_step(H: int) -> int:
+    return _HEADS_A_STEP if H % _HEADS_A_STEP == 0 else 1
+
+
+def _head_tiles(i: int, D: int, beta, *refs):
+    """Head ``i`` of a grid step: its lanes of the sequence blocks and its
+    row of beta."""
+    lanes = slice(i * D, (i + 1) * D)
+    return lanes, (*(ref[:, lanes] for ref in refs), beta[i:i + 1, :])
+
+
+def _kda_fwd_kernel(q, k, v, g, beta, o, states, St):
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        St[...] = jnp.zeros_like(St)
+
+    for i in range(St.shape[0]):
+        lanes, tile = _head_tiles(i, St.shape[1], beta, q, k, v, g)
+        chunk, _ = _tile_forward(*tile)
+        states[i] = St[i]
+        St[i], o[:, lanes] = _tile_inter(St[i], chunk)
+
+
+def _kda_bwd_kernel(q, k, v, g, beta, do, states, dq, dk, dv, dg, dbeta,
+                    dSt):
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        dSt[...] = jnp.zeros_like(dSt)
+
+    for i in range(dSt.shape[0]):
+        lanes, tile = _head_tiles(i, dSt.shape[1], beta, q, k, v, g)
+        chunk, saved = _tile_forward(*tile)
+        dSt[i], dchunk = _tile_inter_bwd(states[i], dSt[i], chunk,
+                                         do[:, lanes])
+        *grads, dbeta[i:i + 1, :] = _tile_backward(*tile, saved, *dchunk)
+        for ref, value in zip((dq, dk, dv, dg), grads):
+            ref[:, lanes] = value
+
+
+def _takes_kernel(head_dim: int) -> bool:
+    """The kernels run where a head's channels fill whole lanes."""
+    return head_dim % 128 == 0
+
+
+def _off_tpu() -> bool:
+    """Off the TPU the kernels run through the Pallas interpreter."""
+    return jax.default_backend() != "tpu"
+
+
+def _kernel_call(kernel, name: str, H: int, outs, *ins, reverse: bool,
+                 interpret: bool):
+    """One of the two kernels on the grid (B, H / heads a step, N), the
+    chunks innermost and in turn, last to first if ``reverse``.  ``ins`` and
+    ``outs`` name what each operand is: ``"sequence"`` (B, L, H * D), a block
+    of CHUNK rows and a step's heads' lanes out of the layout the layer
+    keeps; ``"beta"`` (B, L, H), laid out as (N, B, H / heads, heads, CHUNK),
+    a block of a step's heads' rows; ``"states"`` (N, B, H, D, D), a step's
+    heads' states."""
+    B, L, lanes = ins[0][1].shape
+    D, N, heads = lanes // H, L // CHUNK, _heads_a_step(H)
+    at = (lambda n: N - 1 - n) if reverse else (lambda n: n)
+    specs = {
+        "sequence": pl.BlockSpec((None, CHUNK, heads * D),
+                                 lambda b, h, n: (b, at(n), h)),
+        "beta": pl.BlockSpec((None, None, None, heads, CHUNK),
+                             lambda b, h, n: (at(n), b, h, 0, 0)),
+        "states": pl.BlockSpec((None, None, heads, D, D),
+                               lambda b, h, n: (at(n), b, h, 0, 0))}
+    shapes = {"sequence": (B, L, H * D),
+              "beta": (N, B, H // heads, heads, CHUNK),
+              "states": (N, B, H, D, D)}
+    by_chunk = lambda beta: beta.reshape(
+        B, N, CHUNK, H // heads, heads).transpose(1, 0, 3, 4, 2)
+    return pl.pallas_call(
+        kernel,
+        grid=(B, H // heads, N),
+        in_specs=[specs[kind] for kind, _ in ins],
+        out_specs=[specs[kind] for kind, _ in outs],
+        out_shape=[jax.ShapeDtypeStruct(shapes[kind], t) for kind, t in outs],
+        scratch_shapes=[pltpu.VMEM((heads, D, D), _F32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name=name,              # the kernel's name in the compiled program
+    )(*(by_chunk(a) if kind == "beta" else a for kind, a in ins))
+
+
+# Jitted, so that a program's layers share one trace and one lowering of the
+# body, and on (B, L, H * D) arguments: reshaped inside the call, the
+# sequence arrays were laid out again on the way in (25 ms a step, on the
+# chip), where the caller's reshape is its producers' own layout.
+@functools.partial(jax.jit, static_argnames=("H", "interpret"))
+def _kda_kernel(q, k, v, g, beta, *, H: int, interpret: bool):
+    """``kda_fwd``: the whole recurrence forward, q, k, v, g (B, L, H * D)
+    and beta (B, L, H), L whole chunks -> o (B, L, H * D) and the
+    chunk-entry states (N, B, H, D, D) float32, each transposed."""
+    return _kernel_call(
+        _kda_fwd_kernel, "kda_fwd", H,
+        (("sequence", q.dtype), ("states", _F32)),
+        ("sequence", q), ("sequence", k), ("sequence", v), ("sequence", g),
+        ("beta", beta), reverse=False, interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("H", "interpret"))
+def _kda_kernel_bwd(q, k, v, g, beta, states, do, *, H: int,
+                    interpret: bool):
+    """``kda_bwd``: the cotangent of o -> those of q, k, v, g and beta, in
+    their shapes."""
+    *grads, dbeta = _kernel_call(
+        _kda_bwd_kernel, "kda_bwd", H,
+        (("sequence", q.dtype),) * 3 + (("sequence", _F32), ("beta", _F32)),
+        ("sequence", q), ("sequence", k), ("sequence", v), ("sequence", g),
+        ("beta", beta), ("sequence", do), ("states", states), reverse=True,
+        interpret=interpret)
+    return (*grads, dbeta.transpose(1, 0, 4, 2, 3).reshape(beta.shape))
+
+
+def _flat(a):
+    """(B, L, H, D) -> (B, L, H * D): the layout the layer keeps."""
+    return a.reshape(*a.shape[:2], -1)
+
+
+# ------------------------------------------------------- the recurrence
+
+@jax.custom_vjp
+def _kda_chunks(q, k, v, g, beta):
+    """q, k, v, g: (B, L, H, D), L whole chunks; beta: (B, L, H) -> o (B, L,
+    H, D)."""
+    return _kda_chunks_fwd(q, k, v, g, beta)[0]
+
+
+def _kda_chunks_fwd(q, k, v, g, beta):
+    if _takes_kernel(q.shape[-1]):
+        o, states = _kda_kernel(*map(_flat, (q, k, v, g)), beta,
+                                H=q.shape[2], interpret=_off_tpu())
+        o = o.reshape(q.shape)
+    else:
+        o, states = _plain_fwd(q, k, v, g, beta)
+    o = checkpoint_name(o, KDA_RESIDUAL_NAMES[0])
     states = checkpoint_name(states, KDA_RESIDUAL_NAMES[1])
     return o, (q, k, v, g, beta, states)
 
 
-# Heads whose backward pass runs at once (:func:`_kda_chunks_bwd`).
-_HEAD_GROUP = 8
-
-
 def _kda_chunks_bwd(saved, do):
-    """A group of heads at a time: the chunk-local part formed again and
-    kept for its gradient, the recurrence run backward over the group's
-    chunks, the chunk-local gradient.  The chunk-local part's float32
-    temporaries, a dozen arrays of the log-decay's size, are most of what a
-    backward pass holds (Kimi Linear's 32 heads at 16,384 tokens: 4 GB at
-    once, 1 GB by groups of 8); a scan step over 8 heads is short of work
-    either way, and the groups' four scans cost less than forming the
-    chunk-local part of all heads a second time would."""
-    *inputs, states = saved
-    B, H = states.shape[1:3]
-    groups = H // _HEAD_GROUP if H % _HEAD_GROUP == 0 else 1
-    split = lambda a, axis: jnp.moveaxis(
-        a.reshape(*a.shape[:axis], groups, H // groups, *a.shape[axis + 1:]),
-        axis, 0)
-
-    def group(xs):
-        inputs, states, do = xs
-        chunks, intra_vjp = jax.vjp(_intra, *inputs)
-
-        def step(dS, xs):
-            S, chunk, dO = xs
-            _, vjp = jax.vjp(_inter, S, chunk)
-            return vjp((dS, dO))
-
-        _, dchunks = lax.scan(step, jnp.zeros_like(states[0]),
-                              (states, _by_chunk(chunks), _by_chunk(do)),
-                              reverse=True)
-        return intra_vjp(jax.tree.map(lambda a: jnp.moveaxis(a, 0, 2),
-                                      dchunks))
-
-    grads = lax.map(group, (jax.tree.map(lambda a: split(a, 1), tuple(inputs)),
-                            split(states, 2), split(do, 1)))
-    return jax.tree.map(
-        lambda a: jnp.moveaxis(a, 0, 1).reshape(B, H, *a.shape[3:]), grads)
+    """One pass over the chunks, last to first, from the inputs and the
+    chunk-entry states: no forward recurrence runs again."""
+    *inputs, beta, states = saved
+    if not _takes_kernel(do.shape[-1]):
+        return _plain_bwd(*inputs, beta, states, do)
+    *grads, dbeta = _kda_kernel_bwd(
+        *map(_flat, inputs), beta, states, _flat(do), H=do.shape[2],
+        interpret=_off_tpu())
+    return (*(a.reshape(do.shape) for a in grads), dbeta)
 
 
 _kda_chunks.defvjp(_kda_chunks_fwd, _kda_chunks_bwd)
@@ -330,18 +726,11 @@ def kda(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     log of the decay a channel, <= 0; ``beta`` (B, L, H) float32.  Returns o
     (B, L, H, D).  L is padded to whole chunks here (a padded token writes
     nothing: k = 0, beta = 0, g = 0) and cropped again."""
-    B, L, H, D = q.shape
-    pad = -L % CHUNK
-    N = (L + pad) // CHUNK
-
-    def chunked(a):                     # (B, L, H, ...) -> (B, H, N, C, ...)
-        a = jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
-        a = jnp.moveaxis(a, 2, 1)
-        return a.reshape(B, H, N, CHUNK, *a.shape[3:])
-
-    o = _kda_chunks(chunked(q), chunked(k), chunked(v),
-                    chunked(g.astype(_F32)), chunked(beta.astype(_F32)))
-    return jnp.moveaxis(o.reshape(B, H, N * CHUNK, D), 1, 2)[:, :L]
+    L = q.shape[1]
+    padded = lambda a: jnp.pad(
+        a, ((0, 0), (0, -L % CHUNK)) + ((0, 0),) * (a.ndim - 2))
+    return _kda_chunks(padded(q), padded(k), padded(v),
+                       padded(g.astype(_F32)), padded(beta.astype(_F32)))[:, :L]
 
 
 def kda_recurrent(q, k, v, g, beta):
