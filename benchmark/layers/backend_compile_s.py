@@ -1,0 +1,22 @@
+"""`backend_compile_s` (entry): seconds inside JAX's `backend_compile_duration`
+event, which covers the backend's compile of a new program and the persistent
+cache's load of a known one alike; from the program's start-up account
+(`torchmpi_tpu/_startup.py`, reached as `mpi.startup()`;
+`docs/observability.md`, "The start-up account").  `cache_load_s` is the part
+of it spent loading.  The sum is the whole process's, not the set-up's alone:
+the program sees no timed window in a token cell (the runner calls an AOT
+executable), and the Ouro and Kimi runners make a few programs of the
+benchmark's own after the window, for the timed step's check against the
+reference (PR 34 saw 4 of the Ouro cell's 11 programs and 3 of the Kimi
+cell's 11 there; `mpi.startup().summary(until_ns)` leaves them out).  `None`
+where the program keeps no account (a parent of PR 34)."""
+
+
+def read(obs):
+    import sys
+
+    mpi = sys.modules.get("torchmpi_tpu")       # the runner imported it
+    startup = getattr(mpi, "startup", None)     # none: no account
+    if startup is None:
+        return None
+    return startup().summary()["backend_compile_s"]

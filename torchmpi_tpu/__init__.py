@@ -17,6 +17,8 @@ handle waits.  Subpackages: ``collectives``, ``nn``, ``engine``,
 ``parameterserver``, ``parallel``, ``models``, ``utils``.
 """
 
+from ._startup import ACCOUNT as _ACCOUNT  # first: it stamps the import's start
+
 from .version import __version__  # noqa: F401
 
 from .runtime import (  # noqa: F401
@@ -84,3 +86,14 @@ def num_nodes_in_communicator():
     """Distinct hosts in the current communicator
     (reference: torchmpi_num_nodes_in_communicator, torch_mpi.cpp:321-350)."""
     return stack.current().num_nodes()
+
+
+def startup():
+    """This process's start-up account (``_startup.py``): the package's
+    import, ``start()`` and ``stop()`` by their parts, and every program the
+    process traced, lowered, compiled or loaded, on the clock a profiler
+    capture shares.  ``mpi.startup().summary()`` is the table."""
+    return _ACCOUNT
+
+
+_ACCOUNT.imported()     # last: the import's end; jax is here, so listen

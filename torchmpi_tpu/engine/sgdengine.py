@@ -32,12 +32,12 @@ from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
-import jax.monitoring
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from .. import _startup
 from .. import nn as mpinn
 from ..collectives import eager
 from ..obs import numerics as _numerics
@@ -108,13 +108,8 @@ def sample_array(state, flatten: bool = False):
 RUN_RING = 4096         # steps and completions a record keeps (the newest)
 RUNS_KEPT = 16          # records ``runs()`` keeps (the newest)
 
-# The event JAX records round every compilation of a new program, whether
-# the backend compiles it or the persistent cache supplies it.
-_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-
 _RUNS: "deque[RunRecord]" = deque(maxlen=RUNS_KEPT)
 _OPEN: Optional["RunRecord"] = None     # the record compilations go to
-_COMPILE_LISTENER_ON = False
 
 
 def runs() -> List["RunRecord"]:
@@ -125,19 +120,23 @@ def runs() -> List["RunRecord"]:
     return list(_RUNS)
 
 
-def _on_compile(event, duration, **_):
+def _compiled(row) -> None:
+    """A finished ``compile`` row of the start-up account (``_startup.py``,
+    the program's one set of ``jax.monitoring`` listeners): JAX records one
+    round every compilation of a new program, whether the backend compiles
+    it or the persistent cache supplies it."""
     rec = _OPEN
-    if rec is not None and event == _COMPILE_EVENT:
-        rec.compiles.append((rec.steps, float(duration)))
+    if rec is not None:
+        rec.compiles.append((rec.steps, (row.t1 - row.t0) / 1e9))
+
+
+_startup.ACCOUNT.compile_sinks.append(_compiled)
 
 
 def _open_run(rec: "RunRecord") -> Optional["RunRecord"]:
     """Make ``rec`` the record compilations go to; returns the one that was
     (a hook may train another engine inside a call)."""
-    global _OPEN, _COMPILE_LISTENER_ON
-    if not _COMPILE_LISTENER_ON:        # once a process: JAX keeps listeners
-        _COMPILE_LISTENER_ON = True
-        jax.monitoring.register_event_duration_secs_listener(_on_compile)
+    global _OPEN
     outer, _OPEN = _OPEN, rec
     _RUNS.append(rec)
     return outer
@@ -190,7 +189,8 @@ class RunRecord:
       step and have none at all.
     * ``compiles``: ``(step, seconds)`` of every program compiled (or
       loaded from the persistent cache) while the call was open, on any
-      thread; one past step 0 is a recompile.
+      thread, from the start-up account's ``compile`` rows
+      (``mpi.startup()``); one past step 0 is a recompile.
     * ``steps``, ``mode``, ``window`` (the in-flight bound in force,
       negative for none)."""
 

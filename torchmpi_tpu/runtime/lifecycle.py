@@ -20,10 +20,12 @@ import atexit
 import os
 import socket
 import threading
+import time
 from typing import Callable, List, Optional, Sequence
 
 import jax
 
+from .. import _startup
 from . import config
 from . import handles as _handles
 from .communicator import (
@@ -40,24 +42,28 @@ _distributed_initialized: bool = False
 _process_index: int = 0
 
 
-def _monotonic_ns() -> int:
-    # Through the tracer's clock so lifecycle spans land on the aligned
-    # cluster timeline when obs/clocksync.apply ran (raw monotonic
-    # otherwise — the offset defaults to 0).
-    from ..obs import tracer as _obs_tracer
-
-    return _obs_tracer.now_ns()
-
-
-def _record_span(name: str, t0_ns: int, **attrs) -> None:
-    """Register [t0_ns, now) as an observability span (no-op with
-    obs_trace off) — used where a context manager can't bracket the
-    interval without re-indenting a locked body."""
+def _derive_span(name: str, t0_ns: int, t1_ns: int) -> None:
+    """Register two of the start-up account's stamps as an observability
+    span (no-op with obs_trace off), on the tracer's clock: the aligned
+    cluster timeline when obs/clocksync.apply ran (raw monotonic otherwise,
+    the offset defaults to 0)."""
     from ..obs import tracer as _obs_tracer
 
     if _obs_tracer.enabled():
-        _obs_tracer.record(name, t0_ns, _monotonic_ns(),
-                           _obs_tracer.current_correlation(), **attrs)
+        offset = _obs_tracer.clock_offset()
+        _obs_tracer.record(name, t0_ns - offset, t1_ns - offset,
+                           _obs_tracer.current_correlation())
+
+
+def _backend_is_up() -> Optional[bool]:
+    """Whether a JAX backend is initialised already (``None``: this JAX
+    does not say)."""
+    try:
+        from jax._src import xla_bridge
+
+        return bool(xla_bridge.backends_are_initialized())
+    except (ImportError, AttributeError):
+        return None
 
 
 def started() -> bool:
@@ -132,10 +138,9 @@ def start(
     mesh); default is ``jax.devices()`` — every chip PJRT can see.
     """
     global _started, _need_inter_node
-    # Lifecycle boundaries register as spans (torchmpi_tpu/obs): a
-    # restarted world's wiring cost shows up on the merged timeline next
-    # to the transport frames it triggers.  No-op with obs_trace off.
-    _t0 = _monotonic_ns()
+    # The start-up account's stamps (_startup.py), always taken; handed over
+    # when the call returns, so one that raises leaves none.
+    stamps = {"t_enter": time.monotonic_ns()}
     with _state_lock:
         if _started:
             raise RuntimeError("start() called twice without stop()")
@@ -175,6 +180,7 @@ def start(
                 process_id=_ienv("JAX_PROCESS_ID", "PROCESS_ID"),
             )
             _distributed_initialized = True
+        stamps["t_group"] = time.monotonic_ns()
 
         # (3) communicator-mode flags (reference: init.lua:61-65 forwarding
         # into torchmpi_set_tree|cartesian_communicator).  Written every
@@ -193,8 +199,10 @@ def start(
         # (4) world communicator.  The compile cache is settled before the
         # first device query, so every program of the run goes through it.
         use_compile_cache()
+        stamps["backend_was_up"] = _backend_is_up()
         if devices is None:
             devices = jax.devices() if with_tpu else jax.devices("cpu")
+        stamps["t_backend"] = time.monotonic_ns()
         world = Communicator(devices, name="global")
         stack.reset(world)
 
@@ -205,6 +213,7 @@ def start(
             custom_communicator_init()
         else:
             _init_per_node_communicators(world)
+        stamps["t_communicators"] = time.monotonic_ns()
 
         # (7) selector — imported lazily to avoid a cycle.
         from ..collectives import selector as _selector
@@ -221,7 +230,11 @@ def start(
             _process_index = 0
 
         _started = True
-    _record_span("runtime.start", _t0)
+    stamps["t_selector"] = time.monotonic_ns()
+    # Lifecycle boundaries register as spans (torchmpi_tpu/obs), derived
+    # from the account's stamps: a restarted world's wiring cost shows up on
+    # the merged timeline next to the transport frames it triggers.
+    _derive_span("runtime.start", stamps["t_enter"], stamps["t_selector"])
     # Live telemetry endpoint (obs/serve.py, knob-gated off by default):
     # a fresh world is not draining, whatever a prior stop() left behind.
     from ..obs import serve as _obs_serve
@@ -237,6 +250,8 @@ def start(
 
     _obs_journal.set_rank(_process_index)
     _obs_history.maybe_start(rank=_process_index)
+    stamps["t_return"] = time.monotonic_ns()
+    _startup.ACCOUNT.starts.append(stamps)
 
 
 def _init_per_node_communicators(world: Communicator) -> None:
@@ -274,7 +289,7 @@ def stop() -> None:
     async work, stop the parameter-server thread, free retained resources,
     then drop the communicator stack.  Safe to call once after start()."""
     global _started, _need_inter_node, _distributed_initialized
-    _t0 = _monotonic_ns()
+    stamps = {"t_enter": time.monotonic_ns()}
     with _state_lock:
         if not _started:
             return
@@ -313,7 +328,8 @@ def stop() -> None:
             finally:
                 _distributed_initialized = False
         _started = False
-    _record_span("runtime.stop", _t0)
+    stamps["t_down"] = time.monotonic_ns()
+    _derive_span("runtime.stop", stamps["t_enter"], stamps["t_down"])
     # History sampler stops (final persist included) before the obsdump
     # so the on-disk history covers the teardown drain above.
     try:
@@ -331,6 +347,8 @@ def stop() -> None:
         _obs_serve.stop()
     except Exception:
         pass
+    stamps["t_return"] = time.monotonic_ns()
+    _startup.ACCOUNT.stops.append(stamps)
 
 
 def _maybe_shutdown_obsdump() -> None:
