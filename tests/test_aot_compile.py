@@ -299,14 +299,17 @@ def test_olmoe_step_on_dp_tp_takes_the_compilers_grouped_matmul(monkeypatch):
 
 
 def test_kimi_linear_step_on_dp_tp_runs_each_devices_kernels(monkeypatch):
-    """On more than one device the KDA recurrence runs in a ``shard_map``
-    over the batch and the heads (``llama._kda_sharded``), as flash does:
-    its kernels are Mosaic's, and the compiler refuses to partition one
+    """On more than one device a KDA layer between its projections (the way
+    in, the recurrence, the way out) runs in ONE ``shard_map`` over the batch
+    and the heads (``llama._kda_sharded``), as flash does: its kernels are
+    Mosaic's, and the compiler refuses to partition one
     (``NotImplementedError: Mosaic kernels cannot be automatically
     partitioned``; bare under GSPMD this step does not lower).  Kimi Linear's
     first four layers at published widths (KDA, KDA, KDA, MLA; a share of the
     experts) on dp=2 x tp=2: each device runs ``kda_fwd`` and ``kda_bwd``
     once a KDA layer on its own row of the batch and its 16 of 32 heads,
+    beside them ``kda_pre`` and ``kda_post`` twice (``"full"`` forms them
+    again from their inputs) and ``kda_pre_bwd`` and ``kda_post_bwd`` once,
     and the latent layer's two flash kernels on its 16 heads."""
     import dataclasses
 
@@ -329,15 +332,24 @@ def test_kimi_linear_step_on_dp_tp_runs_each_devices_kernels(monkeypatch):
     text = lowered().compile().as_text()
     kernels = [line for line in text.splitlines()
                if 'custom_call_target="tpu_custom_call"' in line]
-    named = lambda what: sum(what in line for line in kernels)
+    named = lambda what: sum(bool(re.search(what, line)) for line in kernels)
     assert (named("kda_fwd"), named("kda_bwd")) == (3, 3)
+    assert (named(r"kda_pre(?!_bwd)"), named(r"kda_post(?!_bwd)")) == (6, 6)
+    assert (named("kda_pre_bwd"), named("kda_post_bwd")) == (3, 3)
     assert (named("flash_fwd"), named("flash_bwd")) == (1, 1)
     assert "jit(gmm)" not in text
     # a device's o and states: its one row, 4096 tokens in 64 chunks, 16 heads
     fwd = next(line for line in kernels if "kda_fwd" in line)
     assert "[1,4096,2048]" in fwd and "[64,1,16,128,128]" in fwd
-    from torchmpi_tpu.ops.kda import kda
-    monkeypatch.setattr(llama, "_kda_sharded", lambda mesh, heads: kda)
+    # the way in's four results on the same rows and heads
+    pre = next(line for line in kernels if "kda_pre" in line
+               and "kda_pre_bwd" not in line)
+    assert pre.split(" custom-call(")[0].count("[1,4096,2048]") == 4
+    import functools
+
+    from torchmpi_tpu.ops.kda_mixer import kda_mixer
+    monkeypatch.setattr(llama, "_kda_sharded", lambda mesh, heads, eps:
+                        functools.partial(kda_mixer, eps=eps))
     with pytest.raises(NotImplementedError, match="automatically partitioned"):
         lowered()
 
@@ -447,7 +459,11 @@ def test_kimi_linear_adamw_step_at_published_widths(v5e, monkeypatch):
     dead there and gone.  A KDA layer's recurrence is two more, `kda_fwd`
     and `kda_bwd`, the 256 chunks a grid axis each runs in turn: no loop is
     left under `kda`, and the forward kernel that `"full"` would replay is
-    dead, its output and states kept."""
+    dead, its output and states kept.  The layer's passes round the
+    recurrence are six more (`ops/kda_mixer.py`): `kda_pre` and `kda_post`
+    forward and, kept by their inputs alone, formed again under `"full"`,
+    `kda_pre_bwd` and `kda_post_bwd` once; they stand under `attn`, not under
+    `kda`."""
     import dataclasses
     import json
     import os
@@ -504,10 +520,19 @@ def test_kimi_linear_adamw_step_at_published_widths(v5e, monkeypatch):
     assert all("/mla/" in line for line in kernels if "flash_" in line)
     assert named(r"jit\(gmm\)") == 4 * 8 and named(r"jit\(tgmm\)") == 4 * 3
     assert (named("kda_fwd"), named("kda_bwd")) == (4, 4)
-    assert all("/kda/" in line for line in kernels if "kda_" in line)
-    assert len(kernels) == 46 + 2 * 4
+    assert (named(r"kda_pre(?!_bwd)"), named(r"kda_post(?!_bwd)")) == (8, 8)
+    assert (named("kda_pre_bwd"), named("kda_post_bwd")) == (4, 4)
+    recurrence = lambda line: "kda_fwd" in line or "kda_bwd" in line
+    assert all(("/kda/" in line) == recurrence(line)
+               and re.search(r"[/(]attn[/)]", line)
+               for line in kernels if "kda_" in line)
+    assert len(kernels) == 46 + 2 * 4 + 6 * 4
+    # what "full" forms again: the way in and the way out, never a
+    # recurrence or a flash kernel
     assert not any("rematted_computation" in line for line in kernels
-                   if "flash_" in line or "kda_" in line)
+                   if "flash_" in line or recurrence(line))
+    assert sum("rematted_computation" in line for line in kernels
+               if "kda_" in line) == 2 * 4
     assert not any(" while(" in line and "/kda/" in line for line in text)
     m = program.memory_analysis()
     held = (m.argument_size_in_bytes + m.output_size_in_bytes

@@ -309,34 +309,63 @@ def test_kernels_in_bfloat16_keep_float32_decay_sums_and_state(
 # chip's), so on a mesh the recurrence runs in a ``shard_map`` over the batch
 # and the heads, whichever form the head width takes.
 
+def mixer_inputs(L, B, H, D, seed=0):
+    """``ops.kda_mixer.kda_mixer``'s twelve inputs: the projections' outputs,
+    beta, the gate's pre-activation and the layer's per-channel leaves."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 12)
+    C = H * D
+    xq, xk, xv, f, z = (jax.random.normal(k, (B, L, C)) for k in ks[:5])
+    beta = jax.nn.sigmoid(jax.random.normal(ks[5], (B, L, H)))
+    conv = [jax.random.normal(k, (4, C)) * 0.5 for k in ks[6:9]]
+    a_log = jnp.log(jax.random.uniform(ks[9], (H,), jnp.float32, 1.0, 16.0))
+    return (xq, xk, xv, f, beta, z, *conv, a_log,
+            jax.random.normal(ks[10], (C,)),
+            1.0 + 0.1 * jax.random.normal(ks[11], (D,)))
+
+
+MIXER_INPUTS = ("xq xk xv f beta z conv_q conv_k conv_v a_log dt_bias "
+                "o_norm").split()
+
+
 @pytest.mark.parametrize("axes", [{"dp": 2, "tp": 2}, {"tp": 4}, {"dp": 4}],
                          ids=["dp2-tp2", "tp4", "dp4"])
 @pytest.mark.parametrize("head_dim", [16, 128], ids=["plain", "kernels"])
 def test_the_recurrence_on_a_mesh_is_one_devices(head_dim, axes):
-    """``llama._kda_sharded``: values and all five gradients on a mesh are
-    one device's, the batch split over ``dp`` and the heads over ``tp``; the
-    call is a ``shard_map`` and each device's kernels, where the width takes
-    them, stand inside it on its own rows and heads."""
+    """``llama._kda_sharded``: a KDA layer between its projections, the way
+    in, the recurrence and the way out.  Values and all twelve gradients on a
+    mesh are one device's, the batch split over ``dp`` and the heads over
+    ``tp`` (the filters and ``dt_bias`` with their channels, ``a_log`` with
+    its heads, ``o_norm`` whole; a leaf's gradient summed over ``dp``); the
+    call is ONE ``shard_map`` and each device's kernels, where the width
+    takes them, stand inside it on its own rows and heads."""
+    from torchmpi_tpu.ops import kda_mixer
+
     mesh = pmesh.make_mesh(axes, devices=jax.devices()[:4])
-    x = kda_inputs(130, 1.0, B=4, H=4, D=head_dim, seed=2)
+    x = mixer_inputs(130, B=4, H=4, D=head_dim, seed=2)
     w = jax.random.normal(jax.random.PRNGKey(9), x[0].shape)
-    sharded = llama._kda_sharded(mesh, 4)
-    assert llama._kda_sharded(None, 4) is kda_ops.kda
+    sharded = llama._kda_sharded(mesh, 4, 1e-5)
+    alone = llama._kda_sharded(None, 4, 1e-5)
+    assert alone.func is kda_mixer.kda_mixer and alone.keywords == {
+        "eps": 1e-5}
     both = lambda fn: jax.jit(lambda *a: (fn(*a), all_grads(fn, a, w)))
-    (o, grads), (want, want_grads) = both(sharded)(*x), both(kda_ops.kda)(*x)
+    (o, grads), (want, want_grads) = both(sharded)(*x), both(alone)(*x)
     assert rel(o, want) < 1e-6
-    for name, a, b in zip("q k v g beta".split(), grads, want_grads):
+    for name, a, b in zip(MIXER_INPUTS, grads, want_grads):
         assert a.shape == b.shape and rel(a, b) < 1e-5, name
     (outer,) = [e for e in jax.make_jaxpr(sharded)(*x).jaxpr.eqns]
     assert outer.primitive.name == "shard_map"
-    local = (4 // axes.get("dp", 1), 192, 4 // axes.get("tp", 1) * head_dim)
+    local = (4 // axes.get("dp", 1), 4 // axes.get("tp", 1) * head_dim)
     inside = _scans_and_kernels(outer.params["jaxpr"])
     if head_dim == 128:
-        assert inside == [("pallas_call", "kda_fwd")]
+        assert inside == [("pallas_call", "kda_pre"),
+                          ("pallas_call", "kda_fwd"),
+                          ("pallas_call", "kda_post")]
         kernel = _find(outer.params["jaxpr"], "pallas_call")
-        assert kernel.invars[0].aval.shape == local
+        B, L, C = kernel.invars[0].aval.shape       # the rows in whole blocks
+        assert (B, C) == local and L >= 130
     else:
         assert inside == [("scan", 3, False)]
+    assert _scans_and_kernels(jax.make_jaxpr(alone)(*x).jaxpr) == inside
 
 
 def _find(jaxpr, primitive):
@@ -403,7 +432,8 @@ def test_kda_block_against_the_reference(model, reference):
     lp = layer_of(params, 1)                 # a KDA layer of the moe run
     x = jax.random.normal(jax.random.PRNGKey(3), (2, 96, cfg.d_model))
     want = jax.vmap(lambda s: reference.kda_mixer(file_of(cfg), lp, s))(x)
-    assert rel(llama._kda_block(cfg, lp, x, kda_ops.kda), want) < 1e-5
+    mixer = llama._kda_sharded(None, cfg.kda_heads, cfg.norm_eps)
+    assert rel(llama._kda_block(cfg, lp, x, mixer), want) < 1e-5
 
 
 @pytest.mark.parametrize("attn", ["full", "flash"])
@@ -660,8 +690,15 @@ def test_remat_gives_the_gradients_and_runs_nothing_twice(model, sample,
                                               ("pallas_call", "flash_bwd")]
     kda_kernels = [f[1] for f in found if f[0] == "pallas_call"
                    and "kda" in (f[1] or "")]
-    assert kda_kernels == ([] if head_dim == 16 else
-                           ["kda_fwd"] * 4 + ["kda_bwd"] * 4)
+    # the way in and the way out keep their inputs alone and are formed
+    # again in the backward pass; the recurrence between them is not
+    forward = ["kda_pre", "kda_fwd", "kda_post"]
+    backward = ["kda_post", "kda_post_bwd", "kda_bwd", "kda_pre",
+                "kda_pre_bwd"]
+    assert sorted(kda_kernels) == ([] if head_dim == 16 else
+                                   sorted(4 * (forward + backward)))
+    assert (kda_kernels.count("kda_fwd"), kda_kernels.count("kda_bwd")) == (
+        (0, 0) if head_dim == 16 else (4, 4))
 
 
 def _scans_and_kernels(jaxpr):

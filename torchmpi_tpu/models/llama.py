@@ -638,24 +638,24 @@ def _flash_attention_sharded(mesh: Optional[Mesh], heads: int,
     return sharded
 
 
-def _kda_sharded(mesh: Optional[Mesh], heads: int) -> Callable:
-    """The KDA recurrence ``(q, k, v, g, beta) -> o`` (:func:`ops.kda.kda`).
-    On a mesh it runs inside a ``shard_map`` over the batch (``dp``) and head
-    (``tp``) axes, as the flash kernel does and for its reason: its kernels
-    are Mosaic's, which the compiler will not partition, and a head's
-    recurrence needs no other head's and no other row's.  The sequence stays
-    whole on every device (the state crosses no shard; the rings are
-    refused)."""
-    from ..ops.kda import kda
+def _kda_sharded(mesh: Optional[Mesh], heads: int, eps: float) -> Callable:
+    """A KDA layer between its projections (``ops.kda_mixer.kda_mixer``: the
+    way in, the recurrence, the way out).  On a mesh all three run in ONE
+    ``shard_map`` over the batch (``dp``) and head (``tp``) axes, as the
+    flash kernel does and for its reason; :func:`_kda_block` says how the
+    per-channel leaves go.  On no mesh or one device: the plain call."""
+    from ..ops.kda_mixer import kda_mixer
 
+    mixer = functools.partial(kda_mixer, eps=eps)
     if mesh is None or mesh.size == 1:
-        return kda
+        return mixer
     from jax import shard_map
 
-    wide = _mesh_spec(P(AXIS_DP, None, _tp_head_axis(mesh, heads, heads),
-                        None), mesh)
-    return shard_map(kda, mesh=mesh, in_specs=(wide,) * 4 + (P(*wide[:3]),),
-                     out_specs=wide, check_vma=False)
+    tp = _tp_head_axis(mesh, heads, heads)
+    wide = _mesh_spec(P(AXIS_DP, None, tp), mesh)
+    return shard_map(
+        mixer, mesh=mesh, out_specs=wide, check_vma=False,
+        in_specs=(wide,) * 6 + (P(None, tp),) * 3 + (P(tp), P(tp), P()))
 
 
 def _make_attn_impl(cfg: Config, attn: str, mesh: Optional[Mesh],
@@ -1139,47 +1139,47 @@ def _qk_norm(cfg: Config, lp: Params, q: jax.Array, k: jax.Array):
                 rms_norm(k, lp["k_norm"], cfg.norm_eps))
 
 
-def _short_conv(x: jax.Array, w: jax.Array) -> jax.Array:
-    """A causal depthwise convolution along the sequence, one filter a
-    channel and no bias: x (B, L, C), w (taps, C) -> ``y_t = sum_i w[i] *
-    x_{t - taps + 1 + i}``, the last tap on the token itself; float32."""
-    taps, L = w.shape[0], x.shape[1]
-    xp = jnp.pad(x.astype(jnp.float32), ((0, 0), (taps - 1, 0), (0, 0)))
-    return sum(xp[:, i:i + L] * w[i].astype(jnp.float32) for i in range(taps))
-
-
 def _kda_block(cfg: Config, lp: Params, x: jax.Array,
-               kda: Callable) -> jax.Array:
-    """The KDA mixer on the normed input x (B, L, D): q, k and v each a
-    projection, a short convolution and a SiLU, q and k L2-normalised over a
-    head's channels (q scaled by ``head_dim ** -0.5``); the log-decay a head
-    and channel ``g = -exp(a_log) * softplus(x W_f + dt_bias)`` and the write
-    strength ``beta = sigmoid(x W_b)`` in float32; the recurrence (``kda``,
-    :func:`_kda_sharded`'s for the mesh; scope ``kda``); the output normed a
-    head and gated by ``sigmoid(x W_g + b_g)`` before ``W_o``.  ``W_f`` and
-    ``W_g`` are low-rank pairs through ``head_dim``."""
-    B, L, _ = x.shape
-    H, hd = cfg.kda_heads, cfg.kda_head_dim
+               mixer: Callable) -> jax.Array:
+    """The KDA mixer on the normed input x (B, L, D).
 
-    def branch(w, conv):
-        y = jax.nn.silu(_short_conv(x @ lp[w], lp[conv]))
-        return y.reshape(B, L, H, hd)
+    Here, as XLA's matmuls: the q, k and v projections, the low-rank pairs
+    through ``head_dim`` of the decay (``W_f``) and of the gate (``W_g``,
+    with its bias), the write strength ``beta = sigmoid(x W_b)`` in float32,
+    and ``W_o``.  Their outputs are rounded to the compute type, and stay the
+    only copies of the layer's activations that a pass reads: nothing is cast
+    to float32, padded or laid out a head at a time on its way to the mixer.
 
-    unit = lambda y: y * lax.rsqrt(
-        jnp.sum(y * y, axis=-1, keepdims=True) + 1e-6)
-    q = (unit(branch("wq", "conv_q")) * hd ** -0.5).astype(x.dtype)
-    k = unit(branch("wk", "conv_k")).astype(x.dtype)
-    v = branch("wv", "conv_v").astype(x.dtype)
-    f = ((x @ lp["f_down"]) @ lp["f_up"]).astype(jnp.float32) + lp["dt_bias"]
-    g = (-jnp.exp(lp["a_log"])[:, None]
-         * jax.nn.softplus(f).reshape(B, L, H, hd))
+    Between them ``mixer`` (:func:`_kda_sharded`'s for the mesh;
+    ``ops.kda_mixer.kda_mixer``), on (B, L, H * head_dim) arrays throughout:
+    the way in (q, k and v each through a short causal convolution and a
+    SiLU, q and k L2-normalised over a head's channels, q scaled by
+    ``head_dim ** -0.5``; the log-decay a head and channel ``g = -exp(a_log)
+    * softplus(x W_f + dt_bias)``, float32), the recurrence (``ops.kda.kda``,
+    scope ``kda``), the way out (the output normed a head by ``o_norm`` and
+    gated by ``sigmoid(x W_g + b_g)``).
+
+    Which form runs is read from the head width alone.  Where a head is a
+    multiple of 128 channels wide the way in and the way out are a fused
+    Pallas kernel each (``kda_pre``, ``kda_post``: every array read once and
+    written once a pass) with a hand-written gradient (``kda_pre_bwd``,
+    ``kda_post_bwd``) whose residuals are its inputs alone, so a remat
+    policy keeps nothing more for them and forms them again in the backward
+    pass; at any other width the ``jax.numpy`` form
+    (``ops.kda_mixer.pre_plain``, ``post_plain``), which is also the tests'
+    oracle.  The recurrence chooses between its own two forms likewise.
+
+    On a mesh every step is a channel's or a head's own and the sequence is
+    whole on each device: the filters, ``dt_bias`` and the gate's bias go
+    with their channels over ``tp``, ``a_log`` with its heads, ``o_norm``
+    whole to every device; the rings are refused."""
+    f = (x @ lp["f_down"]) @ lp["f_up"]
     beta = jax.nn.sigmoid((x @ lp["wb"]).astype(jnp.float32))
-    o = kda(q, k, v, g, beta)
-    gate = jax.nn.sigmoid((((x @ lp["g_down"]) @ lp["g_up"])
-                           + lp["g_bias"]).astype(jnp.float32))
-    o = (rms_norm(o, lp["o_norm"], cfg.norm_eps).astype(jnp.float32)
-         * gate.reshape(B, L, H, hd)).astype(x.dtype)
-    return o.reshape(B, L, H * hd) @ lp["wo"]
+    z = ((x @ lp["g_down"]) @ lp["g_up"]) + lp["g_bias"]
+    o = mixer(x @ lp["wq"], x @ lp["wk"], x @ lp["wv"], f, beta, z,
+              lp["conv_q"], lp["conv_k"], lp["conv_v"], lp["a_log"],
+              lp["dt_bias"], lp["o_norm"])
+    return o @ lp["wo"]
 
 
 def _mla_block(cfg: Config, lp: Params, x: jax.Array,
@@ -1530,8 +1530,8 @@ def _refuse_runs(cfg: Config, what: str, missing: str) -> None:
 def _mixer_impls(cfg: Config, attn: str, mesh: Optional[Mesh]):
     """{mixer kind: attention callable} for the layers of ``cfg``: the
     softmax layers' at ``head_dim ** -0.5``, the latent layers' at the scale
-    of their whole key; a KDA layer's is its recurrence, ``(q, k, v, g, beta)
-    -> o``."""
+    of their whole key; a KDA layer's is the layer between its projections
+    (:func:`_kda_sharded`)."""
     mla = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
     if cfg.layer_kinds is not None and attn.startswith("ring"):
         raise NotImplementedError(
@@ -1542,7 +1542,7 @@ def _mixer_impls(cfg: Config, attn: str, mesh: Optional[Mesh]):
                                     1.0 / np.sqrt(cfg.head_dim)),
             "mla": (_make_attn_impl(cfg, attn, mesh, 1.0 / np.sqrt(mla))
                     if mla else None),
-            "kda": _kda_sharded(mesh, cfg.kda_heads)}
+            "kda": _kda_sharded(mesh, cfg.kda_heads, cfg.norm_eps)}
 
 
 def _stacks(cfg: Config, params: Params):

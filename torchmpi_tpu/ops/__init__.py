@@ -6,9 +6,11 @@ XLA subsumes that on TPU.  The hot op worth hand-tiling here is attention —
 the MXU/VMEM blocking of flash attention feeds both the single-chip path and
 the per-step block compute of ring attention (parallel/sequence.py).
 ``ops/kda.py`` is the other mixer's hot op, the chunked gated delta rule of
-KDA linear-attention layers, forward and a hand-written backward, in plain
-XLA so far (``from torchmpi_tpu.ops import kda``; the function is
-``kda.kda``).
+KDA linear-attention layers, forward and a hand-written backward, in two
+Pallas kernels where a head fills whole lanes (``from torchmpi_tpu.ops import
+kda``; the function is ``kda.kda``), and ``ops/kda_mixer.py`` the layer's
+passes round it (``kda_mixer.kda_mixer``: short convolutions, SiLU, norms,
+decay and gate, a fused kernel each way in and out).
 """
 
 from .flash_attention import flash_attention  # noqa: F401

@@ -78,9 +78,15 @@ The two forms share no code, only the algebra: same sub-blocks, same
 exponents (differences, never positive), same float32 decay sums, state and
 inverse at ``Precision.HIGHEST``, same operands rounded to the inputs' type.
 
+The layer's passes round the recurrence (what makes q, k, v and g of the
+projections' outputs, and the output's norm and gate) are ``ops.kda_mixer``'s
+and choose between their two forms by the same rule, ``head_dim % 128 == 0``:
+fused kernels in this file's (B, L, H * D) layout, or ``jax.numpy``.
+
 A Mosaic kernel is not partitioned by the compiler: on a mesh of several
-devices the caller runs :func:`kda` inside a ``shard_map`` over the batch
-and the heads (``models.llama._kda_sharded``), as it does the flash kernel.
+devices the caller runs :func:`kda`, between those passes, inside a
+``shard_map`` over the batch and the heads (``models.llama._kda_sharded``),
+as it does the flash kernel.
 """
 
 from __future__ import annotations
