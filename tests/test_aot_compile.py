@@ -10,6 +10,7 @@ Refusals are pinned as they are.  The day someone re-tiles the ring kernel
 the ``pytest.raises`` below fails, and tells them to move the bound.
 """
 
+import dataclasses
 import re
 
 import pytest
@@ -77,6 +78,31 @@ def test_flash_fwd_and_grad(v5e, L):
     (_, fwd_used), (stated, used) = _kernel_vmem(grad)
     assert fwd_used < 16 * 1024 * 1024                  # the default limit
     assert 2 * L * D * 4 < used <= stated < V5E_VMEM_BYTES
+
+
+def test_flash_at_heads_of_256(v5e):
+    """GLM-4.7-Flash's latent attention, (1, 16384, 20, 256) bf16 causal, keys
+    and values both two whole registers a row: the 1024-row blocks as they
+    stand, one forward kernel and the ONE backward kernel (not the streaming
+    pair), whose float32 dq block of the whole (L, 256) sits in VMEM: 60.8 MB
+    asked for, under ``_VMEM_BUDGET`` and the chip's."""
+    L, heads, width = 16384, 20, 256
+    x = _sds((1, L, heads, width), jnp.bfloat16, SingleDeviceSharding(v5e[0]))
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True, interpret=False,
+                                       scale=width ** -0.5)
+                       .astype(jnp.float32))
+
+    grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(x, x, x).compile()
+    text = grad.as_text()
+    assert _kernels(grad) == 2
+    assert "flash_fwd" in text and "flash_bwd" in text
+    assert "flash_bwd_dq" not in text and "flash_bwd_dkv" not in text
+    (_, fwd_used), (stated, used) = _kernel_vmem(grad)
+    assert fwd_used < 16 * 1024 * 1024                  # the default limit
+    assert 2 * L * width * 4 < used <= stated < V5E_VMEM_BYTES
+    assert stated <= 64 * 1024 * 1024
 
 
 def test_kda_kernels_at_kimi_linears_widths(v5e, monkeypatch):
@@ -546,3 +572,76 @@ def test_kimi_linear_adamw_step_at_published_widths(v5e, monkeypatch):
     # their float32 temporaries and the scans' carried sums are gone).
     assert 8e9 < m.peak_memory_in_bytes < 14e9
     assert m.peak_memory_in_bytes < held < 14e9
+
+
+def test_glm_flash_adamw_step_at_published_widths(v5e, monkeypatch):
+    """The benchmark's `glm-4.7-flash-l16k` step on one chip: GLM-4.7-Flash at
+    its published widths, the first 5 of 47 layers (a dense FFN, then four
+    with experts: two runs, inlined) and the multi-token-prediction module, 8
+    of 64 routed experts a layer held here beside the shared one, 19,360 rows
+    of the vocabulary (151.25 tiles of 128), 1 x 16,384 tokens, flash at
+    heads of 256, the configuration file's remat, AdamW with float32 moments,
+    weights and state donated.  It fits the chip, not by much: the
+    compiler's own peak is 15.39 GB of 16.91 (15.75 GiB), where `"dots"` is
+    refused by 58 MB.  Two flash kernels for each of the six latent layers,
+    the module's under `mtp`, none replayed, and eleven grouped matmuls for
+    each of the five expert layers, as in the Kimi Linear step."""
+    import json
+    import os
+
+    import optax
+    from jax.sharding import Mesh
+
+    from torchmpi_tpu.models import llama
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "glm-4.7-flash.json")) as fh:
+        file = json.load(fh)
+    run = file["run"]
+    published = llama.glm_4_7_flash()
+    cfg = dataclasses.replace(
+        published, n_layers=5, layer_kinds=published.layer_kinds[:5],
+        experts_held=(0, 8), vocab=19360)
+    assert (file["num_hidden_layers"], file["n_routed_experts"],
+            file["vocab_size"], file["num_nextn_predict_layers"]) == (
+        5, 8, 19360, 1)
+    assert [n for *_, n in llama.layer_runs(cfg)] == [1, 4]
+    one = SingleDeviceSharding(v5e[0])
+    place = lambda tree: jax.tree.map(
+        lambda a: _sds(a.shape, a.dtype, one), tree)
+    params = jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0), cfg,
+                                               dtype=jnp.bfloat16))
+    assert sum(a.size for a in jax.tree.leaves(params)) == 706_518_848
+    adamw = optax.adamw(run["optimizer"]["learning_rate"], b1=0.9, b2=0.95,
+                        weight_decay=0.1)
+    f32 = lambda tree: jax.tree.map(lambda a: a.astype(jnp.float32), tree)
+
+    def update(grads, state, params):       # moments float32, as the runner
+        updates, state = adamw.update(f32(grads), state, f32(params))
+        return jax.tree.map(lambda u, p: u.astype(p.dtype), updates,
+                            params), state
+
+    optimizer = optax.GradientTransformation(lambda p: adamw.init(f32(p)),
+                                             update)
+    state = jax.eval_shape(optimizer.init, params)
+    mesh = Mesh([v5e[0]], ("dp",))
+    tokens = _sds((1, 16384), jnp.int32, one)
+    step = llama.make_train_step(cfg, mesh, attn="flash", optimizer=optimizer,
+                                 remat=run["remat"],
+                                 loss_chunk=run["loss_chunk"])
+    program = step.lower(place(params), place(state), tokens,
+                         tokens).compile()
+    kernels = [line for line in program.as_text().splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    named = lambda what, lines=kernels: sum(
+        bool(re.search(what, line)) for line in lines)
+    assert run["remat"] == "full"
+    assert (named("flash_fwd"), named("flash_bwd[^_]")) == (6, 6)
+    assert all("/mla/" in line for line in kernels if "flash_" in line)
+    module = [line for line in kernels if re.search(r"[(/]mtp[)/]", line)]
+    assert (named("flash_fwd", module), named("flash_bwd", module)) == (1, 1)
+    assert len(kernels) == 6 * 2 + 5 * 11 and len(module) == 2 + 11
+    peak = program.memory_analysis().peak_memory_in_bytes
+    assert 14.5e9 < peak < 15.75 * 2 ** 30

@@ -19,7 +19,10 @@ machinery (SURVEY.md §5.7, §7 item 7-8).  TPU-first design:
   a sequence of homogeneous runs (:func:`layer_runs`), ``params["layers"]``
   a tuple of such stacks, and the depth that decides between scan and
   inlining is each run's own: the runs follow one another inlined, so kinds
-  that alternate every few layers mean every layer inlined;
+  that alternate every few layers mean every layer inlined; a
+  multi-token-prediction module (``Config(mtp_layers=1)``, GLM-4.7-Flash —
+  :func:`glm_4_7_flash`) is one more layer after the stack with a loss of its
+  own through the same embedding and head (:func:`_mtp_input`);
 * :func:`param_specs` returns the PartitionSpec pytree for Megatron-style
   tensor parallelism (qkv/gate/up column-sharded, o/down row-sharded) —
   under pjit GSPMD inserts exactly the one-psum-per-block collectives the
@@ -130,12 +133,24 @@ class Config:
     kda_conv: int = 4
     # MLA layers (``n_heads`` heads): keys of ``qk_nope_head_dim`` from the
     # latent of ``kv_lora_rank`` beside ``qk_rope_head_dim`` that all heads
-    # share (not rotated: no layer of such a stack calls :func:`rope`),
-    # values of ``v_head_dim``; q projected whole (no q latent).
+    # share, values of ``v_head_dim``.  ``q_lora_rank`` 0: q projected whole
+    # (Kimi Linear); else through a latent of that width with a norm of its
+    # own (leaves ``wq_a``, ``q_norm``, ``wq_b`` in ``wq``'s place).
+    # ``mla_rope``: the shared key part, once, and each head's last
+    # ``qk_rope_head_dim`` query channels go through :func:`rope` at
+    # ``rope_theta``; off, nothing is rotated (Kimi Linear's NoPE layers).
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    q_lora_rank: int = 0
+    mla_rope: bool = False
+    # A multi-token-prediction module (DeepSeek-V3's report, section 2.2, at
+    # depth 1; :func:`_mtp_input`): ``mtp_layers`` 0 or 1 layers of the
+    # stack's last kind after the stack, under ``params["mtp"]``, and
+    # ``mtp_coef`` times their next-but-one-token loss added to the loss.
+    mtp_layers: int = 0
+    mtp_coef: float = 0.3
     # A "dense" layer's SwiGLU width in a stack whose experts are ``d_ff``.
     dense_d_ff: int = 0
     # Experts every token meets, beside the routed ones (scope ``moe.shared``):
@@ -159,7 +174,11 @@ class Config:
         return self.d_model // self.n_heads
 
     def __post_init__(self):
-        assert self.d_model % self.n_heads == 0
+        # ``head_dim`` is the softmax ("attn") layers'; a stack without one
+        # (GLM-4.7-Flash: 20 latent heads on 2048) need not divide.
+        assert self.d_model % self.n_heads == 0 or (
+            self.layer_kinds is not None
+            and all(mixer != "attn" for mixer, _ in self.layer_kinds))
         assert self.n_heads % self.n_kv_heads == 0
         assert self.ut_steps >= 1
         if self.n_experts:
@@ -183,6 +202,14 @@ class Config:
                 assert mixer in ("attn", "kda", "mla"), mixer
                 assert ffn in ("dense", "moe"), ffn
                 assert ffn == "dense" or self.n_experts
+        if self.q_lora_rank or self.mla_rope:
+            assert self.layer_kinds is not None and self.kv_lora_rank
+        assert self.mtp_layers in (0, 1)
+        if self.mtp_layers:
+            # The module is a layer of a stack of runs; its router has no
+            # auxiliary term to add to the stack's mean.
+            assert self.layer_kinds is not None
+            assert self.moe_aux_coef == 0 and self.moe_z_coef == 0
 
 
 def llama3_8b() -> Config:
@@ -258,6 +285,24 @@ def kimi_linear_48b_a3b() -> Config:
                            21, 22, 23, 25, 26], [4, 8, 12, 16, 20, 24, 27], 1))
 
 
+def glm_4_7_flash() -> Config:
+    """GLM-4.7-Flash geometry (``zai-org/GLM-4.7-Flash``, ``glm4_moe_lite``):
+    47 layers of rotary latent attention with a query latent of 768 and heads
+    of 256 (192 + 64 rotated; values 256), a dense first layer of 10240, then
+    64 sigmoid-routed experts of width 1536, 4 a token, scaled by 1.8, beside
+    a shared one, and one multi-token-prediction layer after the stack."""
+    return Config(vocab=154880, d_model=2048, n_layers=47, n_heads=20,
+                  n_kv_heads=20, d_ff=1536, dense_d_ff=10240, max_seq=202752,
+                  rope_theta=1e6, norm_eps=1e-5, n_experts=64, expert_top_k=4,
+                  capacity_factor=None, moe_aux_coef=0.0,
+                  moe_renormalize=True, n_shared_experts=1,
+                  router_act="sigmoid", router_bias=True, routed_scale=1.8,
+                  kv_lora_rank=512, qk_nope_head_dim=192, qk_rope_head_dim=64,
+                  v_head_dim=256, q_lora_rank=768, mla_rope=True,
+                  layer_kinds=(("mla", "dense"),) + (("mla", "moe"),) * 46,
+                  mtp_layers=1, mtp_coef=0.3)
+
+
 def layer_runs(cfg: Config) -> Tuple[Tuple[str, str, int], ...]:
     """The stack as homogeneous runs, ``(mixer, ffn, length)`` each:
     consecutive layers of one kind.  A configuration without
@@ -321,8 +366,14 @@ def _init_run(key: jax.Array, cfg: Config, mixer: str, ffn: str, n: int,
     elif mixer == "mla":
         H, r = cfg.n_heads, cfg.kv_lora_rank
         qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+        if cfg.q_lora_rank:
+            lp.update(wq_a=dense(D, cfg.q_lora_rank),
+                      q_norm=ones(cfg.q_lora_rank),
+                      wq_b=dense(cfg.q_lora_rank, H * qk))
+        else:
+            lp.update(wq=dense(D, H * qk))
         lp.update(
-            wq=dense(D, H * qk), wkv_a=dense(D, r + cfg.qk_rope_head_dim),
+            wkv_a=dense(D, r + cfg.qk_rope_head_dim),
             kv_norm=ones(r),
             wkv_b=dense(r, H * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
             wo=dense(H * cfg.v_head_dim, D))
@@ -412,6 +463,21 @@ def init(rng: jax.Array, cfg: Config, dtype=jnp.float32) -> Params:
     embed = (jax.random.normal(keys[0], (cfg.vocab, cfg.d_model), jnp.float32)
              * 0.02).astype(dtype)
     if cfg.layer_kinds is not None:
+        mtp = {}
+        if cfg.mtp_layers:
+            # The module's leaves: a norm for the next token's embedding and
+            # one for the stack's state, the projection of the two side by
+            # side, one layer of the stack's last kind (a run of one, every
+            # leaf led by 1) and a final norm; the embedding and the head are
+            # the model's own.  A key of its own, folded in.
+            k_eh, k_layer = jax.random.split(jax.random.fold_in(rng, 10))
+            unit = lambda: jnp.ones((cfg.d_model,), jnp.float32)
+            mtp = {"mtp": {
+                "enorm": unit(), "hnorm": unit(),
+                "w_eh": _dense(k_eh, 2 * cfg.d_model, cfg.d_model, dtype),
+                "layer": _init_run(k_layer, cfg, *cfg.layer_kinds[-1], 1,
+                                   dtype),
+                "norm": unit()}}
         return {
             "embed": embed,
             "layers": tuple(
@@ -420,6 +486,7 @@ def init(rng: jax.Array, cfg: Config, dtype=jnp.float32) -> Params:
                 for i, (mixer, ffn, n) in enumerate(layer_runs(cfg))),
             "norm": jnp.ones((cfg.d_model,), jnp.float32),
             "head": _dense(keys[8], cfg.d_model, cfg.vocab, dtype),
+            **mtp,
         }
     return {
         "embed": embed,
@@ -468,6 +535,7 @@ def param_specs(cfg: Config) -> Params:
         # ``ep`` and ``tp`` too; every other leaf (norms, convolutions, the
         # low-rank pairs, the router and its bias) whole on each device.
         sharded = {"wq": col, "wk": col, "wv": col, "wkv_b": col, "wo": row,
+                   "wq_b": col,
                    "shared_gate": col, "shared_up": col, "shared_down": row}
         dense = {"w_gate": col, "w_up": col, "w_down": row}
 
@@ -477,9 +545,14 @@ def param_specs(cfg: Config) -> Params:
                     for name, a in run.items()}
 
         shapes = jax.eval_shape(lambda: init(jax.random.PRNGKey(0), cfg))
+        # The module's layer as a run of one; its norms and ``w_eh`` whole.
+        mtp = ({"mtp": {"enorm": P(None), "hnorm": P(None),
+                        "w_eh": P(None, None), "norm": P(None),
+                        "layer": run_specs(shapes["mtp"]["layer"])}}
+               if cfg.mtp_layers else {})
         return {"embed": P(None, None),
                 "layers": tuple(run_specs(run) for run in shapes["layers"]),
-                "norm": P(None), "head": P(None, AXIS_TP)}
+                "norm": P(None), "head": P(None, AXIS_TP), **mtp}
     return {
         "embed": P(None, None),
         "layers": {
@@ -1182,26 +1255,44 @@ def _kda_block(cfg: Config, lp: Params, x: jax.Array,
     return o @ lp["wo"]
 
 
-def _mla_block(cfg: Config, lp: Params, x: jax.Array,
-               attn_impl: Callable) -> jax.Array:
+def _mla_block(cfg: Config, lp: Params, x: jax.Array, attn_impl: Callable,
+               positions: Optional[jax.Array] = None) -> jax.Array:
     """The latent-attention mixer on the normed input x (B, L, D), as a
-    training step runs it: q projected whole; the keys' and values' latent
+    training step runs it: q projected whole or, with ``cfg.q_lora_rank``,
+    through a latent with a norm of its own; the keys' and values' latent
     and the key part all heads share from one projection; the latent normed
     and expanded to each head's keys and values; softmax attention with keys
     of ``qk_nope_head_dim + qk_rope_head_dim`` and values of ``v_head_dim``
-    (``attn_impl``, made for that scale); ``W_o``.  Nothing is rotated."""
+    (``attn_impl``, made for that scale); ``W_o``.  With ``cfg.mla_rope`` the
+    shared key part is rotated once, as one head, and each head's last
+    ``qk_rope_head_dim`` query channels with it (:func:`rope` at
+    ``positions``, 0 to L - 1 where not given, all of those channels);
+    without, nothing is rotated."""
     B, L, _ = x.shape
     H, r = cfg.n_heads, cfg.kv_lora_rank
     nope, shared, vd = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                         cfg.v_head_dim)
     with jax.named_scope("mla"):
-        q = (x @ lp["wq"]).reshape(B, L, H, nope + shared)
+        if cfg.q_lora_rank:
+            q = rms_norm(x @ lp["wq_a"], lp["q_norm"],
+                         cfg.norm_eps) @ lp["wq_b"]
+        else:
+            q = x @ lp["wq"]
+        q = q.reshape(B, L, H, nope + shared)
         latent = x @ lp["wkv_a"]
         kv = (rms_norm(latent[..., :r], lp["kv_norm"], cfg.norm_eps)
               @ lp["wkv_b"]).reshape(B, L, H, nope + vd)
+        k_n = kv[..., :nope]
+        k_shared = latent[..., None, r:]                    # (B, L, 1, shared)
+        if cfg.mla_rope:
+            if positions is None:
+                positions = jnp.arange(L)
+            k_shared = rope(k_shared, positions, cfg.rope_theta)
+            q = jnp.concatenate(
+                [q[..., :nope], rope(q[..., nope:], positions,
+                                     cfg.rope_theta)], axis=-1)
         k = jnp.concatenate(
-            [kv[..., :nope], jnp.broadcast_to(
-                latent[..., None, r:], (B, L, H, shared))], axis=-1)
+            [k_n, jnp.broadcast_to(k_shared, (B, L, H, shared))], axis=-1)
         o = attn_impl(q, k, kv[..., nope:])
         return o.reshape(B, L, H * vd) @ lp["wo"]
 
@@ -1224,13 +1315,14 @@ def _attention_block(cfg: Config, lp: Params, h: jax.Array,
     # projection; in it ``kda``, the chunked recurrence alone, or ``mla``,
     # the whole latent mixer), ``moe.router``/``moe.dispatch``/
     # ``moe.experts``/``moe.combine``/``moe.shared`` or ``ffn``, ``embed``,
-    # ``final_norm``, ``exit_gate``, ``head_loss``, ``optimizer``.  Metadata
-    # only.
+    # ``final_norm``, ``exit_gate``, ``head_loss``, ``optimizer``; ``mtp``,
+    # outermost, round a multi-token-prediction module's copy of those.
+    # Metadata only.
     if mixer != "attn":
         with jax.named_scope("attn"):
             x = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
-            o = (_kda_block if mixer == "kda" else _mla_block)(
-                cfg, lp, x, attn_impl)
+            o = (_kda_block(cfg, lp, x, attn_impl) if mixer == "kda"
+                 else _mla_block(cfg, lp, x, attn_impl, positions))
             return h + constrain(o)
     with jax.named_scope("attn"):
         x = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
@@ -1527,17 +1619,33 @@ def _refuse_runs(cfg: Config, what: str, missing: str) -> None:
             f"{missing}; train it with make_train_step")
 
 
+def _refuse_rotary_latent(cfg: Config, what: str, missing: str) -> None:
+    if cfg.q_lora_rank or cfg.mla_rope or cfg.mtp_layers:
+        raise NotImplementedError(
+            f"{what} has no form yet for rotary latent attention with a query "
+            f"latent or for a multi-token-prediction module (q_lora_rank="
+            f"{cfg.q_lora_rank}, mla_rope={cfg.mla_rope}, mtp_layers="
+            f"{cfg.mtp_layers}): it lacks {missing}; train it with "
+            "make_train_step")
+
+
 def _mixer_impls(cfg: Config, attn: str, mesh: Optional[Mesh]):
     """{mixer kind: attention callable} for the layers of ``cfg``: the
     softmax layers' at ``head_dim ** -0.5``, the latent layers' at the scale
     of their whole key; a KDA layer's is the layer between its projections
     (:func:`_kda_sharded`)."""
     mla = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
-    if cfg.layer_kinds is not None and attn.startswith("ring"):
-        raise NotImplementedError(
-            "the ring kernels take one head width for q, k and v and no "
-            "recurrent state crosses sequence shards: a stack with KDA or "
-            "latent-attention layers takes attn='full' or 'flash'")
+    if attn.startswith("ring"):
+        _refuse_rotary_latent(
+            cfg, f"attn={attn!r}", "a ring form of the latent layer (the one "
+            "rotated key part all heads share would circulate with every "
+            "head's keys) and a module whose next token and target lie past "
+            "a sequence shard's edge")
+        if cfg.layer_kinds is not None:
+            raise NotImplementedError(
+                "the ring kernels take one head width for q, k and v and no "
+                "recurrent state crosses sequence shards: a stack with KDA "
+                "or latent-attention layers takes attn='full' or 'flash'")
     return {"attn": _make_attn_impl(cfg, attn, mesh,
                                     1.0 / np.sqrt(cfg.head_dim)),
             "mla": (_make_attn_impl(cfg, attn, mesh, 1.0 / np.sqrt(mla))
@@ -1578,9 +1686,60 @@ def _exit_log_probs(params: Params, h: jax.Array) -> jax.Array:
         [jax.nn.log_sigmoid(a[:-1]), jnp.zeros_like(a[:1])], axis=0)
 
 
+def _mtp_input(cfg: Config, params: Params, h: jax.Array,
+               mtp_tokens: jax.Array, constrain: Callable) -> jax.Array:
+    """What a multi-token-prediction module's layer reads (DeepSeek-V3's
+    report, section 2.2, depth 1): for every position the embedding of its
+    NEXT token (``mtp_tokens`` (B, L): ``targets`` in the ``(tokens,
+    targets)`` contract) and the stack's state ``h`` (B, L, D) BEFORE the
+    final norm, each through a norm of its own (``enorm``, ``hnorm``), side
+    by side in that order, projected back to D by ``w_eh`` (2D, D).  The
+    embedding is the model's own, read a second time."""
+    mp = params["mtp"]
+    with jax.named_scope("embed"):
+        e = constrain(params["embed"][mtp_tokens])
+    return jnp.concatenate(
+        [rms_norm(e, mp["enorm"], cfg.norm_eps),
+         rms_norm(h, mp["hnorm"], cfg.norm_eps)], axis=-1) @ mp["w_eh"]
+
+
+def _mtp_loss_parts(cfg: Config, params: Params, h, targets: jax.Array,
+                    loss_chunk: int):
+    """The two terms of the loss of a configuration with a
+    multi-token-prediction module, from ``h``, the pair of normed states
+    :func:`apply` hands out (B, L, D each): the main model's mean NLL, and
+    ``cfg.mtp_coef`` times the module's.  The module's position i, which read
+    token i + 1, is held to token i + 2, ``targets[i + 1]``, through the
+    model's own head (a call of its own under ``mtp``, so the head's gradient
+    is the sum of two paths).  All L rows run so that the chunks keep their
+    shape; the last row, which has no such target, carries weight 0, and the
+    others ``mtp_coef`` over their count."""
+    B, L, _ = h[1].shape
+    weights = jnp.broadcast_to(
+        jnp.where(jnp.arange(L) < L - 1, cfg.mtp_coef / (B * (L - 1)), 0.0
+                  ).astype(jnp.float32), (B, L))
+    main = _nll_from_hidden(params["head"], h[0], targets, loss_chunk)
+    with jax.named_scope("mtp"):
+        return main, _nll_from_hidden(params["head"], h[1],
+                                      jnp.roll(targets, -1, axis=1),
+                                      loss_chunk, weights)
+
+
+def mtp_loss_parts(cfg: Config, params: Params, batch, mesh=None,
+                   attn: str = "full", loss_chunk: int = 0):
+    """``(main NLL, the module's NLL)`` of a batch ``(tokens, targets)``,
+    both unweighted means, by the code the training step runs
+    (:func:`_mtp_loss_parts`): a counter for outside the step."""
+    tokens, targets = batch
+    h = apply(cfg, params, tokens, mesh=mesh, attn=attn, return_hidden=True,
+              mtp_tokens=targets)
+    main, module = _mtp_loss_parts(cfg, params, h, targets, loss_chunk)
+    return main, module / cfg.mtp_coef
+
+
 def expert_unit_counts(cfg: Config, params: Params, tokens: jax.Array,
-                       mesh: Optional[Mesh] = None,
-                       attn: str = "full") -> jax.Array:
+                       mesh: Optional[Mesh] = None, attn: str = "full",
+                       mtp_tokens: Optional[jax.Array] = None) -> jax.Array:
     """(layers with experts, n_experts) int32: how many of a batch's k*T
     routed units each expert of each layer is sent, by the router code the
     training step runs (:func:`_route_tokens`), on the activations the forward
@@ -1588,13 +1747,20 @@ def expert_unit_counts(cfg: Config, params: Params, tokens: jax.Array,
     largest entry over its mean says how lopsided that layer's routing is.  A
     stack of runs has a row for each layer of its ``"moe"`` runs, in order,
     over all ``n_experts`` whatever ``cfg.experts_held``: the held experts'
-    columns are what the step's tiles see."""
+    columns are what the step's tiles see.  A multi-token-prediction
+    module's router is one more row, the last, on the next tokens
+    ``mtp_tokens`` it reads (:func:`_mtp_input`)."""
     _refuse_dropless_ep(cfg, mesh)
     _refuse_looped(cfg, "expert_unit_counts")
+    if cfg.mtp_layers and mtp_tokens is None:
+        raise ValueError("a configuration with a multi-token-prediction "
+                         "module needs mtp_tokens, the batch's targets")
     positions = jnp.arange(tokens.shape[1])
     impls = _mixer_impls(cfg, attn, mesh)
     h, rows = params["embed"][tokens], []
-    for (mixer, ffn, _), stack in zip(layer_runs(cfg), _stacks(cfg, params)):
+
+    def through(h, mixer, ffn, stack):
+        """A run's layers on ``h``, its routers' counts added to ``rows``."""
         def layer(h, lp):
             h = _attention_block(cfg, lp, h, positions, impls[mixer],
                                  mixer=mixer)
@@ -1607,6 +1773,13 @@ def expert_unit_counts(cfg: Config, params: Params, tokens: jax.Array,
         h, counts = lax.scan(layer, h, stack)
         if counts is not None:
             rows.append(counts)
+        return h
+
+    for (mixer, ffn, _), stack in zip(layer_runs(cfg), _stacks(cfg, params)):
+        h = through(h, mixer, ffn, stack)
+    if cfg.mtp_layers:
+        through(_mtp_input(cfg, params, h, mtp_tokens, lambda x: x),
+                *cfg.layer_kinds[-1], params["mtp"]["layer"])
     return jnp.concatenate(rows)
 
 
@@ -1646,7 +1819,8 @@ def apply(cfg: Config, params: Params, tokens: jax.Array,
           remat: Remat = "none", return_hidden: bool = False,
           return_aux: bool = False, layer_loop: Optional[str] = None,
           positions: Optional[jax.Array] = None,
-          all_steps: bool = False) -> jax.Array:
+          all_steps: bool = False,
+          mtp_tokens: Optional[jax.Array] = None) -> jax.Array:
     """Forward: tokens (B, L) int32 -> logits (B, L, vocab) f32, or the
     final hidden states (B, L, D) in compute dtype when ``return_hidden``
     (the chunked-loss path applies the output head itself so the full
@@ -1663,6 +1837,12 @@ def apply(cfg: Config, params: Params, tokens: jax.Array,
     (T, B, L, ...): what the expected-exit loss and the comparison with a
     reference read.  ``aux`` is then the mean over all T * n_layers layer
     applications.
+
+    With ``mtp_tokens`` (B, L), each position's next token, a configuration
+    with a multi-token-prediction module (``cfg.mtp_layers``) returns the pair
+    ``(main, module's)`` in ``out``'s place: the module's logits or normed
+    states for the token after next (:func:`_mtp_input`).  Without it the
+    module does not run and the result is the main model's alone.
 
     ``mesh`` enables activation sharding constraints (and is required for
     ``attn='ring'``); without it the model runs unconstrained (single-device
@@ -1785,21 +1965,35 @@ def apply(cfg: Config, params: Params, tokens: jax.Array,
                                                 _stacks(cfg, params))]
 
     def ut_step(carry, r):
-        """One pass through the stack: its runs one after the other."""
+        """One pass through the stack: its runs one after the other.  With
+        the normed state and the aux sum, the state before the norm."""
         for run in runs:
             carry = run[r](carry)
         h, aux = carry
         with jax.named_scope("final_norm"):
-            return rms_norm(h, params["norm"], cfg.norm_eps), aux
+            return (rms_norm(h, params["norm"], cfg.norm_eps), aux), h
 
     carry = (h, _aux_zero(cfg))
     states = []
     for r in remats:
-        carry = ut_step(carry, r)
+        carry, before_norm = ut_step(carry, r)
         states.append(carry[0])
     aux = carry[1] / (cfg.n_layers * cfg.ut_steps)
     h = jnp.stack(states) if all_steps else states[-1]
-    out = h if return_hidden else (h @ params["head"]).astype(jnp.float32)
+    head = lambda h: (h if return_hidden
+                      else (h @ params["head"]).astype(jnp.float32))
+    out = head(h)
+    if cfg.mtp_layers and mtp_tokens is not None:
+        # One more layer of the stack's last kind after the stack, under the
+        # stack's own remat policy, and a norm of its own; ``mtp`` outermost
+        # round the names every layer has.
+        with jax.named_scope("mtp"):
+            x = _mtp_input(cfg, params, before_norm, mtp_tokens, constrain)
+            x, _ = run_loop(*cfg.layer_kinds[-1], 1, params["mtp"]["layer"])[
+                remats[-1]]((x, _aux_zero(cfg)))
+            with jax.named_scope("final_norm"):
+                x = rms_norm(x, params["mtp"]["norm"], cfg.norm_eps)
+            out = (out, head(x))
     return (out, aux) if return_aux else out
 
 
@@ -1843,7 +2037,11 @@ def make_loss_fn(cfg: Config, mesh: Optional[Mesh] = None, attn: str = "full",
 
     A configuration with an exit gate (``cfg.exit_gate``, a looped model)
     trains on the expected-exit loss over all its recurrent steps
-    (:func:`_expected_exit_nll`); without one, on the last step's NLL.
+    (:func:`_expected_exit_nll`); without one, on the last step's NLL.  A
+    configuration with a multi-token-prediction module (``cfg.mtp_layers``)
+    adds ``cfg.mtp_coef`` times the module's loss (:func:`_mtp_loss_parts`):
+    the embedding and the head are each read twice, and their gradients are
+    the sums of both paths.
     """
 
     def loss_fn(params: Params, batch: Tuple[jax.Array, jax.Array]) -> jax.Array:
@@ -1867,9 +2065,12 @@ def make_loss_fn(cfg: Config, mesh: Optional[Mesh] = None, attn: str = "full",
         h, aux = apply(cfg, params, tokens, mesh=mesh, attn=attn, remat=remat,
                        return_hidden=True, return_aux=True,
                        layer_loop=layer_loop, positions=positions,
-                       all_steps=cfg.exit_gate)              # (B, L, D)
+                       all_steps=cfg.exit_gate,              # (B, L, D)
+                       mtp_tokens=targets if cfg.mtp_layers else None)
         if cfg.exit_gate:
             nll = _expected_exit_nll(cfg, params, h, targets, loss_chunk)
+        elif cfg.mtp_layers:
+            nll = sum(_mtp_loss_parts(cfg, params, h, targets, loss_chunk))
         else:
             nll = _nll_from_hidden(params["head"], h, targets, loss_chunk)
         if cfg.n_experts and cfg.moe_z_coef:
@@ -1899,6 +2100,11 @@ def _decode_step(cfg: Config, params: Params, cache: Params,
     including ``pos`` (causality holds by construction: later slots are
     still zero and masked off)."""
     _refuse_looped(cfg, "the decode step")
+    _refuse_rotary_latent(cfg, "the decode step", "a latent cache (the "
+                          "normed latent and the rotated shared key part "
+                          "a token), the absorbed form of the query "
+                          "latent's product with it, and a self-drafting "
+                          "step for the module")
     _refuse_runs(cfg, "the decode step", "a recurrent-state cache for the "
                  "KDA layers (a head's d x d state and the convolutions' last "
                  "taps) beside a latent cache for the others")
@@ -1969,6 +2175,10 @@ def _prefill(cfg: Config, params: Params, cache: Params,
     kernel needs it to run per batch/head shard.
     """
     _refuse_looped(cfg, "prefill")
+    _refuse_rotary_latent(cfg, "prefill", "a latent cache to seed "
+                          "decoding with (the normed latent and the "
+                          "rotated shared key part a token) and the "
+                          "module's state for a first draft")
     _refuse_runs(cfg, "prefill", "a latent cache (the normed latent and the "
                  "shared key part a token) and the KDA layers' final state "
                  "to seed decoding with")
@@ -2038,6 +2248,9 @@ def make_generate_fn(cfg: Config, prompt_len: int, max_new: int,
     pinned weight + cache shardings.
     """
     _refuse_looped(cfg, "make_generate_fn")
+    _refuse_rotary_latent(cfg, "make_generate_fn", "the latent cache its "
+                          "prefill and decode step would fill and a step "
+                          "that drafts with the module and verifies")
     _refuse_runs(cfg, "make_generate_fn", "the two caches its prefill and "
                  "decode step would fill (recurrent state, latent)")
     if prompt_len < 1 or max_new < 1:
@@ -2368,6 +2581,10 @@ def make_pp_train_step(cfg: Config, mesh: Mesh, n_microbatches: int,
     from ..parallel import pipeline as _pp
     from ..parallel.mesh import AXIS_PP
 
+    _refuse_rotary_latent(cfg, "make_pp_train_step", "a last stage that "
+                          "hands the module the state before the final "
+                          "norm, and the embedding on the first and the "
+                          "last stage at once")
     _refuse_runs(cfg, "make_pp_train_step", "a stage split by run (a stage "
                  "is one stacked scan of identical layers)")
     if cfg.n_experts:
@@ -2510,6 +2727,10 @@ def make_1f1b_train_step(cfg: Config, mesh: Mesh, n_microbatches: int,
     """
     from ..parallel import pipeline as _pp
 
+    _refuse_rotary_latent(cfg, "make_1f1b_train_step", "a last stage that "
+                          "hands the module the state before the final "
+                          "norm, and the embedding on the first and the "
+                          "last stage at once")
     _refuse_runs(cfg, "make_1f1b_train_step", "a stage split by run (a stage "
                  "is one stacked scan of identical layers)")
     if cfg.n_experts:
