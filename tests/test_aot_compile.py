@@ -111,7 +111,11 @@ def test_kda_kernels_at_kimi_linears_widths(v5e, monkeypatch):
     (``kda_fwd``, ``kda_bwd``; no loop over the 256 chunks is left for XLA),
     each inside the 16 MiB of VMEM a kernel gets unasked, reading the (B, L,
     H * D) layout in place: Mosaic takes the tiles' slices, their
-    transposed products and the float32 ones at ``HIGHEST``."""
+    transposed products and the float32 ones at ``HIGHEST``.  With every
+    tile's float32 inverse and bfloat16 ``P`` as ``kda_fwd``'s third and
+    fourth results and ``kda_bwd``'s operands (each (256, 1, 16, 64, 128): two
+    heads' tiles a block, the second stored and read at lane 64) they use
+    1.90 and 3.66 MB of it (1.84 and 3.45 without)."""
     from torchmpi_tpu.ops import kda
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -134,7 +138,14 @@ def test_kda_kernels_at_kimi_linears_widths(v5e, monkeypatch):
         r'"used_scoped_memory_configs":\[\{"memory_space":"1","offset":"0",'
         r'"size":"(\d+)"', line).group(1)) for line in text.splitlines()
         if 'custom_call_target="tpu_custom_call"' in line]
-    assert len(used) == 2 and max(used) < 16 * 1024 * 1024
+    assert len(used) == 2 and max(used) < 4 * 1024 * 1024
+    fwd_line, bwd_line = (
+        next(line for line in text.splitlines()
+             if f"%{name}" in line and "custom-call(" in line)
+        for name in ("kda_fwd", "kda_bwd"))
+    for kept in ("f32[256,1,16,64,128]", "bf16[256,1,16,64,128]"):
+        assert kept in fwd_line.split("custom-call(")[0]        # a result
+        assert kept in bwd_line.split("operand_layout_constraints")[1]
 
 
 def _ring_blocks(one, Lc):
@@ -476,8 +487,8 @@ def test_kimi_linear_adamw_step_at_published_widths(v5e, monkeypatch):
     rows of the vocabulary, 1 x 16,384 tokens, flash with keys of 192 and
     values of 128, the configuration file's remat, AdamW with float32
     moments, weights and state donated.  It fits the chip: the compiler's
-    own peak is 13.47 GB of 16.91 (15.75 GiB) and the sum the cell reports
-    13.56 GB.  Two flash kernels for the one MLA layer and, for each of the
+    own peak is 13.41 GB of 16.91 (15.75 GiB) and the sum the cell reports
+    13.89 GB.  Two flash kernels for the one MLA layer and, for each of the
     four expert layers, the grouped matmuls of one pass of the held experts'
     loops: `gmm` forward (3), for the rows' gradients (3) and, the backward
     loop forming what it does not keep, gate and up again (2), `tgmm` for
@@ -485,9 +496,10 @@ def test_kimi_linear_adamw_step_at_published_widths(v5e, monkeypatch):
     dead there and gone.  A KDA layer's recurrence is two more, `kda_fwd`
     and `kda_bwd`, the 256 chunks a grid axis each runs in turn: no loop is
     left under `kda`, and the forward kernel that `"full"` would replay is
-    dead, its output and states kept.  The layer's passes round the
-    recurrence are six more (`ops/kda_mixer.py`): `kda_pre` and `kda_post`
-    forward and, kept by their inputs alone, formed again under `"full"`,
+    dead, its output, states, inverses and `P` kept.  The layer's passes
+    round the recurrence are six more (`ops/kda_mixer.py`): `kda_pre` and
+    `kda_post` forward and, kept by their inputs alone, formed again under
+    `"full"`,
     `kda_pre_bwd` and `kda_post_bwd` once; they stand under `attn`, not under
     `kda`."""
     import dataclasses
@@ -566,10 +578,19 @@ def test_kimi_linear_adamw_step_at_published_widths(v5e, monkeypatch):
     # weights and both float32 moments donated: 10 bytes a parameter
     assert m.alias_size_in_bytes > 10 * 602_000_000
     # That it compiled is the check that it fits 15.75 GiB.  The compiler's
-    # own peak is 13.47 GB and arguments plus temporaries, the sum the cell
-    # reports as `hbm_program_gb`, 13.56 GB (15.48 and 18.14 while the
-    # recurrence was plain XLA: the chunk-local tensors of a group of heads,
-    # their float32 temporaries and the scans' carried sums are gone).
+    # own peak is 13.41 GB and arguments plus temporaries, the sum the cell
+    # reports as `hbm_program_gb`, 13.89 GB: a record of the plan, not a
+    # limit of the chip.  Before the four KDA layers kept their tiles'
+    # inverses and `P` (`ops.kda.residual_bytes`: 4 x (0.134 + 0.067) = 0.81
+    # GB from forward to backward) they read 12.94 and 13.69; the sum grew by
+    # 0.20 and not by 0.81 because its temporaries are the highest point of a
+    # heap that the compiler packs anew, not a sum of what is kept; the peak
+    # by 0.47.
+    from torchmpi_tpu.ops import kda
+
+    kept = kda.residual_bytes(1, 16384, cfg.kda_heads, cfg.kda_head_dim,
+                              jnp.bfloat16)
+    assert 4 * (kept["kda_inverse"] + kept["kda_p"]) == 6 * 2**27
     assert 8e9 < m.peak_memory_in_bytes < 14e9
     assert m.peak_memory_in_bytes < held < 14e9
 

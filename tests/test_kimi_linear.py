@@ -99,6 +99,20 @@ def kda_inputs(L, decay, B=2, H=2, D=32, seed=0):
     return q, k, v, g, beta
 
 
+def correlated_inputs(spread, L, H, D):
+    """Keys of a chunk nearly (``spread`` 0.3) or wholly (0) one direction,
+    beta near 1, hardly any decay: what one optimizer step made of seeded
+    keys on the chip."""
+    ks = jax.random.split(jax.random.PRNGKey(0), 6)
+    unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)
+    k = unit(jax.random.normal(ks[5], (1, 1, H, D))
+             + spread * jax.random.normal(ks[1], (1, L, H, D)))
+    return (unit(jax.random.normal(ks[0], (1, L, H, D))) * D ** -0.5, k,
+            jax.random.normal(ks[2], (1, L, H, D)),
+            -1e-3 * jax.nn.softplus(jax.random.normal(ks[3], (1, L, H, D))),
+            jax.nn.sigmoid(4.0 + jax.random.normal(ks[4], (1, L, H))))
+
+
 @pytest.mark.parametrize("L,decay", [
     (64, 0.1), (200, 1.0), (130, 30.0), (256, 1e-3), (40, 80.0)],
     ids=["one-chunk", "ragged-200", "decay-to-0", "decay-near-1",
@@ -128,15 +142,7 @@ def test_correlated_keys_and_strong_writes_stay_stable(spread):
     unit triangular inverse by the powers of N (``(I + N)(I + N^2) ...``)
     cancels 1e17 down to 1 there and the state grows without bound; by
     substitution in blocks no entry passes 1."""
-    ks = jax.random.split(jax.random.PRNGKey(0), 6)
-    unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)
-    B, L, H, D = 1, 512, 2, 32
-    k = unit(jax.random.normal(ks[5], (1, 1, H, D))
-             + spread * jax.random.normal(ks[1], (B, L, H, D)))
-    x = (unit(jax.random.normal(ks[0], (B, L, H, D))) * D ** -0.5, k,
-         jax.random.normal(ks[2], (B, L, H, D)),
-         -1e-3 * jax.nn.softplus(jax.random.normal(ks[3], (B, L, H, D))),
-         jax.nn.sigmoid(4.0 + jax.random.normal(ks[4], (B, L, H))))
+    x = correlated_inputs(spread, L=512, H=2, D=32)
     want = kda_ops.kda_recurrent(*x)
     assert rel(jax.jit(kda_ops.kda)(*x), want) < 1e-5
     w = jax.random.normal(jax.random.PRNGKey(9), want.shape)
@@ -206,9 +212,13 @@ def test_kernel_forward_is_the_token_recurrence_and_the_plain_form(
     pad = lambda a: jnp.pad(a, ((0, 0), (0, -L % 64)) + ((0, 0),)
                             * (a.ndim - 2))
     padded = tuple(map(pad, x))
-    got, (*_, states) = jax.jit(kda_ops._kda_chunks_fwd)(*padded)
-    want, (*_, plain) = plain_form(monkeypatch, kda_ops._kda_chunks_fwd,
-                                   *padded)
+    # the residuals: the five inputs, then what each form keeps, the states
+    # first
+    got, kept = jax.jit(kda_ops._kda_chunks_fwd)(*padded)
+    want, kept_plain = plain_form(monkeypatch, kda_ops._kda_chunks_fwd,
+                                  *padded)
+    assert (len(kept), len(kept_plain)) == (8, 6)
+    states, plain = kept[5], kept_plain[5]
     assert got.shape == want.shape and got.dtype == want.dtype
     assert states.shape == plain.shape == (kda_ops.n_chunks(L), 1, 2, 128,
                                            128)
@@ -243,15 +253,7 @@ def test_kernels_stay_stable_on_correlated_keys(spread):
     """``test_correlated_keys_and_strong_writes_stay_stable``'s inputs
     through the kernels: the tile's inverse is substitution in blocks and
     merges too, no entry past 1."""
-    ks = jax.random.split(jax.random.PRNGKey(0), 6)
-    unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)
-    B, L, H, D = 1, 192, 2, 128
-    k = unit(jax.random.normal(ks[5], (1, 1, H, D))
-             + spread * jax.random.normal(ks[1], (B, L, H, D)))
-    x = (unit(jax.random.normal(ks[0], (B, L, H, D))) * D ** -0.5, k,
-         jax.random.normal(ks[2], (B, L, H, D)),
-         -1e-3 * jax.nn.softplus(jax.random.normal(ks[3], (B, L, H, D))),
-         jax.nn.sigmoid(4.0 + jax.random.normal(ks[4], (B, L, H))))
+    x = correlated_inputs(spread, L=192, H=2, D=128)
     want = kda_ops.kda_recurrent(*x)
     assert rel(jax.jit(kda_ops.kda)(*x), want) < 1e-5
     w = jax.random.normal(jax.random.PRNGKey(9), want.shape)
@@ -267,6 +269,107 @@ def test_kernels_stay_stable_on_correlated_keys(spread):
                                atol=1e-6)
     np.testing.assert_allclose(inverse, kda_ops._unit_lower_inverse(N),
                                atol=1e-6)
+
+
+def plain_n(k, g, beta):
+    """``N = -Diag(beta) tril(M, -1)`` of every tile, (B, H, N, 64, 64), as
+    the plain form's algebra has it, written out here in float32: ``M_ij =
+    sum_c k_ic k_jc exp(G_ic - G_jc)``."""
+    k, g, beta = map(kda_ops._chunked, (k, g, beta))
+    G = jnp.cumsum(g, axis=-2)
+    decay = jnp.exp(jnp.minimum(G[..., :, None, :] - G[..., None, :, :], 0.0))
+    M = jnp.einsum("...ic,...jc,...ijc->...ij", k, k, decay,
+                   precision=jax.lax.Precision.HIGHEST)
+    return -beta[..., :, None] * jnp.tril(M, -1)
+
+
+def by_head(tiles, H):
+    """The kernels' kept tiles, (N, B, H / 2, 64, 2 * 64): a grid step's two
+    heads side by side on the lanes -> (B, H, N, 64, 64)."""
+    n, b = tiles.shape[:2]
+    tiles = tiles.reshape(n, b, H // 2, 64, 2, 64)      # (n, b, h2, r, i, c)
+    return jnp.einsum("nbhric->bhinrc", tiles).reshape(b, H, n, 64, 64)
+
+
+@pytest.mark.parametrize("keys", ["seeded", "correlated", "collinear"])
+def test_the_kernel_keeps_every_tiles_inverse(monkeypatch, keys):
+    """The third residual ``kda_fwd`` writes is ``(I - N)^-1`` of every
+    tile, float32, the tiles of a grid step's two heads side by side on the
+    lanes ((N, B, H / 2, 64, 2 * 64)): against ``jnp.linalg.inv`` of the
+    plain algebra's N, on seeded keys and on the keys that break an inverse
+    by powers.  The fourth is the tile's ``P`` in the inputs' type, laid out
+    the same: the plain form's, which ``_intra`` returns."""
+    H = 4
+    x = (kernel_inputs(192, 1.0, H=H) if keys == "seeded" else
+         correlated_inputs({"correlated": 0.3, "collinear": 0.0}[keys],
+                           L=192, H=H, D=128))
+    _, (*_, inverse, pairs) = jax.jit(kda_ops._kda_chunks_fwd)(*x)
+    assert (inverse.dtype, pairs.dtype) == (jnp.float32, x[0].dtype)
+    assert inverse.shape == pairs.shape == (3, 1, H // 2, 64, 2 * 64)
+    tiles = by_head(inverse, H)
+    want = jnp.linalg.inv(jnp.eye(64) - plain_n(x[1], x[3], x[4]))
+    np.testing.assert_allclose(tiles, want, atol=2e-6)
+    assert float(jnp.max(jnp.abs(tiles))) <= 1.0 + 1e-6
+    assert not bool(jnp.any(jnp.triu(tiles, 1)))
+    plain_p = jax.jit(kda_ops._intra)(*map(kda_ops._chunked, x))[3]
+    np.testing.assert_allclose(by_head(pairs, H), plain_p, atol=1e-6)
+    # an odd number of heads: one head a grid step, a tile a block
+    odd = tuple(a[:, :, :1] for a in x)
+    _, (*_, alone, alone_p) = jax.jit(kda_ops._kda_chunks_fwd)(*odd)
+    assert alone.shape == alone_p.shape == (3, 1, 1, 64, 64)
+    np.testing.assert_array_equal(alone[:, 0, 0], tiles[0, 0])
+    np.testing.assert_array_equal(alone_p[:, 0, 0], by_head(pairs, H)[0, 0])
+
+
+def test_the_backward_kernel_inverts_no_tile(monkeypatch):
+    """``kda_bwd`` reads the inverse ``kda_fwd`` made: tracing the backward
+    kernel calls ``_tile_inverse`` for no tile and the forward kernel once a
+    head.  Both kernel calls are traced anew (what ``ops.kda`` jits,
+    unjitted), so the patched helper is the one traced and no other trace is
+    touched."""
+    calls = []
+    inverse_of = kda_ops._tile_inverse
+    monkeypatch.setattr(kda_ops, "_tile_inverse", lambda *a: (
+        calls.append(1), inverse_of(*a))[1])
+    H = 4
+    x = kernel_inputs(128, 1.0, H=H)
+    flat = (*map(kda_ops._flat, x[:4]), x[4])
+    o, *kept = jax.eval_shape(lambda *a: kda_ops._kda_kernel.__wrapped__(
+        *a, H=H, interpret=True), *flat)
+    # (a grid step's two heads are two tiles of one traced body)
+    assert len(calls) == kda_ops._heads_a_step(H) == 2
+    del calls[:]
+    grads = jax.eval_shape(lambda *a: kda_ops._kda_kernel_bwd.__wrapped__(
+        *a, H=H, interpret=True), *flat, *kept, o)
+    assert calls == []
+    assert [a.shape for a in grads] == [a.shape for a in flat]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("L,H,D", [(16384, 32, 128), (130, 3, 128),
+                                   (200, 2, 32)],
+                         ids=["kimi-linear", "odd-heads", "plain-form"])
+def test_residual_bytes_are_the_arrays(monkeypatch, L, H, D, dtype):
+    """``residual_bytes`` (shapes alone) against what the forward rule hands
+    the backward one beside the inputs: ``o``, the states and, from the
+    kernels, the inverses and ``P``; at Kimi Linear's shapes 134, 537, 134
+    and 67 MB."""
+    dtype = jnp.dtype(dtype)
+    B = 1
+    padded = kda_ops.n_chunks(L) * 64
+    x = [jax.ShapeDtypeStruct((B, padded, H, D), t)
+         for t in (dtype, dtype, dtype, jnp.float32)]
+    beta = jax.ShapeDtypeStruct((B, padded, H), jnp.float32)
+    monkeypatch.setattr(kda_ops, "_off_tpu", lambda: True)
+    o, kept = jax.eval_shape(kda_ops._kda_chunks_fwd, *x, beta)
+    nbytes = lambda a: a.size * a.dtype.itemsize
+    want = dict(zip(kda_ops.KDA_RESIDUAL_NAMES, map(nbytes, (o, *kept[5:]))))
+    got = kda_ops.residual_bytes(B, L, H, D, dtype)
+    assert got == want
+    assert ("kda_inverse" in got) == ("kda_p" in got) == (D == 128)
+    if (L, dtype) == (16384, jnp.bfloat16):
+        assert got == {"kda_o": 2**27, "kda_state": 2**29,
+                       "kda_inverse": 2**27, "kda_p": 2**26}
 
 
 @pytest.mark.parametrize("decay_sums", ["float32", "bfloat16"])
@@ -288,11 +391,14 @@ def test_kernels_in_bfloat16_keep_float32_decay_sums_and_state(
         exact = kda_ops._decay_sums
         monkeypatch.setattr(kda_ops, "_decay_sums", lambda g, row, col: exact(
             g, row, col).astype(jnp.bfloat16).astype(jnp.float32))
-    o, states = jax.jit(lambda *a: kda_ops._kda_kernel.__wrapped__(
-        *map(kda_ops._flat, a[:4]), a[4], H=2, interpret=True))(*x)
+    o, states, inverse, pairs = jax.jit(
+        lambda *a: kda_ops._kda_kernel.__wrapped__(
+            *map(kda_ops._flat, a[:4]), a[4], H=2, interpret=True))(*x)
     off = rel(o.reshape(want.shape).astype(jnp.float32), want)
     assert o.dtype == jnp.bfloat16
     assert states.dtype == jnp.float32 and states.shape[0] == 3
+    assert inverse.dtype == jnp.float32 and inverse.shape[0] == 3
+    assert pairs.dtype == jnp.bfloat16 and pairs.shape == inverse.shape
     if decay_sums == "bfloat16":
         assert off > 3e-2
         return
@@ -699,6 +805,48 @@ def test_remat_gives_the_gradients_and_runs_nothing_twice(model, sample,
                                    sorted(4 * (forward + backward)))
     assert (kda_kernels.count("kda_fwd"), kda_kernels.count("kda_bwd")) == (
         (0, 0) if head_dim == 16 else (4, 4))
+    # every forward kernel, then every backward one: nothing replayed between
+    assert [n for n in kda_kernels if n in ("kda_fwd", "kda_bwd")] == (
+        [] if head_dim == 16 else 4 * ["kda_fwd"] + 4 * ["kda_bwd"])
+
+
+@pytest.mark.parametrize("remat", ["dots", "full"])
+def test_both_policies_keep_the_inverse_by_name(remat):
+    """The recurrence checkpointed as a layer is: the forward kernel's four
+    results each carry their name, ``_wrap_remat``'s policies keep all four
+    and the backward pass holds ``kda_fwd`` once and ``kda_bwd`` once; a
+    policy that keeps the names the plain form has, or all but the last,
+    runs ``kda_fwd`` again for what it lacks."""
+    x = kernel_inputs(130, 1.0, H=2)
+
+    def program(wrapped):
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda *a: jnp.sum(jnp.sin(wrapped(*a))),
+            argnums=(0, 1, 2, 3, 4)))(*x).jaxpr
+        return ([name for kind, name in _scans_and_kernels(jaxpr)
+                 if kind == "pallas_call"], _names(jaxpr))
+
+    kernels, names = program(llama._wrap_remat(kda_ops.kda, remat))
+    assert kernels == ["kda_fwd", "kda_bwd"]
+    assert kda_ops.KDA_RESIDUAL_NAMES == ("kda_o", "kda_state", "kda_inverse",
+                                          "kda_p")
+    assert set(kda_ops.KDA_RESIDUAL_NAMES) <= names
+    for n in (2, 3):
+        some = jax.checkpoint_policies.save_only_these_names(
+            *kda_ops.KDA_RESIDUAL_NAMES[:n])
+        kernels, _ = program(jax.checkpoint(kda_ops.kda, policy=some))
+        assert kernels == ["kda_fwd", "kda_fwd", "kda_bwd"]
+
+
+def _names(jaxpr):
+    """The names ``checkpoint_name`` left in a jaxpr, sub-jaxprs included."""
+    found = set()
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "name":
+            found.add(eqn.params["name"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found |= _names(sub)
+    return found
 
 
 def _scans_and_kernels(jaxpr):

@@ -2376,11 +2376,11 @@ def _wrap_remat(layer: Callable, remat: str,
       layer whose recomputation grows with L^2 runs once.  The grouped
       matmul's products it does not keep (at OLMoE's shapes they are eight
       layer inputs) and replays.
-    * both: the KDA recurrence's output and chunk-entry states
-      (``ops.kda.KDA_RESIDUAL_NAMES``: a layer input's bytes and, in float32,
-      L/64 states of d x d a head, four layer inputs at Kimi Linear's widths),
-      so the recurrence, a sequential scan over the chunks and no Mosaic
-      kernel, runs once forward and once backward like one.
+    * both: the KDA recurrence's output, chunk-entry states and, from its
+      kernels, each chunk's inverse and ``P`` (``ops.kda.KDA_RESIDUAL_NAMES``:
+      L/64 states of d x d and tiles of 64 x 64 a head; one, four, one and a
+      half layer inputs' bytes at Kimi Linear's widths), so the recurrence
+      runs once each way and its kernels invert no tile and sum no P twice.
     * ``"none"``: everything, no checkpoint.
 
     No other attention mode emits the flash names, no other mixer the KDA

@@ -39,12 +39,31 @@ state and the decay sums are float32; the other products take operands of
 the inputs' type and accumulate in float32.
 
 The gradient is a hand-written rule (``jax.custom_vjp``): the backward pass
-holds the inputs and the chunk-entry states, forms the chunk-local part
-again, runs the recurrence backward once over the chunks and differentiates
-the chunk-local part.  The forward rule names what a caller's
-``jax.checkpoint`` must keep so that the recurrence never runs twice
-(``KDA_RESIDUAL_NAMES``: the output and the chunk-entry states), as
-``ops.flash_attention`` names its own.
+holds the inputs, the chunk-entry states and, in the kernels, every chunk's
+inverse ``(I + Diag(b) tril(M, -1))^-1`` and ``P`` as the forward pass made
+them; it forms the rest of the chunk-local part again (``M``, then ``T``,
+``W``, ``T V`` from the inverse it was given), runs the recurrence backward
+once over the chunks and differentiates the chunk-local part.  The forward
+rule names what a caller's ``jax.checkpoint`` must keep so that the
+recurrence never runs twice and no tile is inverted twice
+(``KDA_RESIDUAL_NAMES``), as ``ops.flash_attention`` names its own; a layer
+of B x L tokens and H heads keeps, beside its inputs
+(:func:`residual_bytes`):
+
+* ``kda_o``, the output, (B, L, H, D) in the inputs' type: a layer input's
+  bytes (134 MB at Kimi Linear's 1 x 16,384 x 32 x 128 in bfloat16);
+* ``kda_state``, the state that enters each chunk, (L / 64, B, H, D, D)
+  float32: four times that (537 MB);
+* ``kda_inverse``, from the kernels alone, each chunk's inverse, 64 x 64
+  float32, 16 KB a tile: once that again (134 MB).  Inverting a tile is 40%
+  of the forward kernel's time and was 7.7 of the backward kernel's 29.3 ms
+  a layer; written out and read back it is 0.16 ms a layer each way at the
+  HBM's peak, in kernels whose DMA engines idle nine tenths of the time.  It
+  is kept exactly as computed: a bfloat16 inverse would be another result;
+* ``kda_p``, from the kernels alone, each chunk's ``tril(P)`` in the inputs'
+  type, as the forward pass rounded it for its own product: half that (67
+  MB), for 4.2 ms a layer of the backward kernel (the 16 lane sums of
+  ``P``'s diagonal blocks and the q rows of the products off them).
 
 Which form runs is read from the head width alone (:func:`_takes_kernel`),
 on every backend (off the TPU through the Pallas interpreter, as
@@ -61,18 +80,22 @@ on every backend (off the TPU through the Pallas interpreter, as
   time, the inverse by substitution and merges, ``T``, ``W``, ``T V``,
   ``Q e^G``, ``K e^{G_C - G}``); the state, float32, stays in a VMEM scratch
   from one chunk to the next (:func:`_tile_inter`) and is written out, as it
-  enters each chunk, as the residual.  ``kda_bwd`` walks the chunks from
-  the last: it forms the tile again, takes :func:`_tile_inter`'s gradient
-  with the state's cotangent in scratch and then the tile's
-  (:func:`_tile_backward`, the gradient written out on VMEM tiles: ``dG =
-  rows * drows - cols * dcols``, ``N' = X^T X' X^T``), and writes dq, dk,
-  dv, dg and dbeta.  Nothing chunk-local is ever in HBM, and there is no scan.
+  enters each chunk, as a residual; the tile's inverse and ``P`` are the
+  others (two heads' tiles side by side, a block of 64 x 128, so that a
+  store fills whole lanes).  ``kda_bwd`` walks the chunks from the last: it
+  forms the tile again but for the inverse and ``P``, which it reads
+  (:func:`_tile_local`, then :func:`_tile_chunk`), takes
+  :func:`_tile_inter`'s gradient with the state's cotangent in scratch and
+  then the tile's (:func:`_tile_backward`, the gradient written out on VMEM
+  tiles: ``dG = rows * drows - cols * dcols``, ``N' = X^T X' X^T``), and
+  writes dq, dk, dv, dg and dbeta.  Nothing else chunk-local is ever in HBM,
+  and there is no scan.
 * other widths: :func:`_intra` for all chunks at once, :func:`_inter` in a
-  ``lax.scan``, and in the backward pass autodiff of both
-  (:func:`_plain_fwd`, :func:`_plain_bwd`).  No configuration has such a
-  width: this form is the algebra as XLA's own operations, kept short, and
-  the kernels' oracle in ``tests/``, its gradient autodiff's where the
-  kernels' is written out.
+  ``lax.scan``, and in the backward pass autodiff of both from the inputs
+  and the states alone (:func:`_plain_fwd`, :func:`_plain_bwd`).  No
+  configuration has such a width: this form is the algebra as XLA's own
+  operations, kept short, and the kernels' oracle in ``tests/``, its
+  gradient autodiff's where the kernels' is written out.
 
 The two forms share no code, only the algebra: same sub-blocks, same
 exponents (differences, never positive), same float32 decay sums, state and
@@ -104,9 +127,11 @@ CHUNK = 64
 SUB = 16
 
 # What the forward rule names (``checkpoint_name``): the output, which the
-# layer reads again, and the chunk-entry states, which the backward scan
-# reads.  A policy that keeps neither runs the forward scan again.
-KDA_RESIDUAL_NAMES = ("kda_o", "kda_state")
+# layer reads again, the chunk-entry states, which the backward pass over
+# the chunks reads, and, from the kernels, every tile's inverse and ``P``,
+# which ``kda_bwd`` reads in place of forming them again.  A policy that
+# does not keep them all runs the forward recurrence again.
+KDA_RESIDUAL_NAMES = ("kda_o", "kda_state", "kda_inverse", "kda_p")
 
 _F32 = jnp.float32
 _EXACT = lax.Precision.HIGHEST
@@ -115,6 +140,21 @@ _EXACT = lax.Precision.HIGHEST
 def n_chunks(seq_len: int) -> int:
     """Chunks of the recurrence a sequence of ``seq_len`` tokens takes."""
     return -(-seq_len // CHUNK)
+
+
+def residual_bytes(batch: int, seq_len: int, heads: int, head_dim: int,
+                   dtype) -> dict[str, int]:
+    """The bytes one KDA layer keeps from its forward to its backward pass
+    beside its inputs, by the name each array carries
+    (``KDA_RESIDUAL_NAMES``): the output in ``dtype``, the float32 state that
+    enters each chunk and, where the kernels run, each chunk's tile of the
+    float32 inverse and of ``P`` in ``dtype``.  From shapes alone."""
+    tiles = n_chunks(seq_len) * batch * heads
+    item = jnp.dtype(dtype).itemsize
+    kept = (tiles * CHUNK * head_dim * item, tiles * head_dim * head_dim * 4)
+    if _takes_kernel(head_dim):
+        kept += (tiles * CHUNK * CHUNK * 4, tiles * CHUNK * CHUNK * item)
+    return dict(zip(KDA_RESIDUAL_NAMES, kept))
 
 
 def _pair_decay(Gb, strict: bool):
@@ -386,10 +426,10 @@ def _tile_off_block(G, qf, kf, lo: int):
     return near, far, rows, kf * far
 
 
-def _earlier(lo: int):
-    """(2 SUB, CHUNK): the columns before row ``lo``.  (An iota of its own:
+def _earlier(rows: int, lo: int):
+    """(rows, CHUNK): the columns before row ``lo``.  (An iota of its own:
     Mosaic refuses a slice of one along sublanes.)"""
-    return lax.broadcasted_iota(jnp.int32, (2 * SUB, CHUNK), 1) < lo
+    return lax.broadcasted_iota(jnp.int32, (rows, CHUNK), 1) < lo
 
 
 def _tile_pair_decay(G, kf, j: int):
@@ -417,10 +457,13 @@ def _tile_inverse(N, row, col):
     return X
 
 
-def _tile_forward(q, k, v, g, brow):
-    """:func:`_intra` for one chunk of one head: q, k, v (CHUNK, D), g
-    (CHUNK, D) float32, beta as a row (1, CHUNK).  Returns the six results
-    (``dC`` (1, D)) and what the backward tile reads again."""
+def _tile_local(q, k, g, brow, P=None):
+    """:func:`_intra` for one chunk of one head up to the inverse: q, k
+    (CHUNK, D), g (CHUNK, D) float32, beta as a row (1, CHUNK) -> the decay
+    sums ``G``, q and k in float32, ``M`` and ``P`` (masked, float32), beta as
+    a column and the tile's two iotas.  Given ``P`` (``kda_bwd``, as
+    ``kda_fwd`` rounded and kept it) only ``M`` is formed: half the lane
+    sums of the diagonal blocks and half the rows of the products off it."""
     dt = q.dtype
     row = lax.broadcasted_iota(jnp.int32, (CHUNK, CHUNK), 0)
     col = lax.broadcasted_iota(jnp.int32, (CHUNK, CHUNK), 1)
@@ -430,7 +473,9 @@ def _tile_forward(q, k, v, g, brow):
     off = [jnp.zeros((2 * SUB, CHUNK), _F32)]
     for lo in range(SUB, CHUNK, SUB):
         _, _, rows, cols = _tile_off_block(G, qf, kf, lo)
-        off.append(jnp.where(_earlier(lo),
+        if P is not None:
+            rows = rows[:SUB]                   # k's alone
+        off.append(jnp.where(_earlier(rows.shape[0], lo),
                              _dot(rows.astype(dt), cols.astype(dt), (1, 1)),
                              0.0))
     # The diagonal blocks, column j of all four at a time.
@@ -441,13 +486,25 @@ def _tile_forward(q, k, v, g, brow):
         E, kj = _tile_pair_decay(G, kf, j)
         kE = kj * E
         Md = jnp.where(inside == j, jnp.sum(kf * kE, -1, keepdims=True), Md)
-        Pd = jnp.where(inside == j, jnp.sum(qf * kE, -1, keepdims=True), Pd)
+        if P is None:
+            Pd = jnp.where(inside == j, jnp.sum(qf * kE, -1, keepdims=True),
+                           Pd)
     M = (jnp.concatenate([o[:SUB] for o in off], axis=0)
          + jnp.where(col < row, Md, 0.0))
-    P = (jnp.concatenate([o[SUB:] for o in off], axis=0)
-         + jnp.where(col <= row, Pd, 0.0))
-    # T = (I - N)^-1 Diag(beta), N = -Diag(beta) tril(M, -1) nilpotent.
-    X = _tile_inverse(-bcol * M, row, col)
+    if P is None:
+        P = (jnp.concatenate([o[SUB:] for o in off], axis=0)
+             + jnp.where(col <= row, Pd, 0.0))
+    return G, qf, kf, M, P, bcol, row, col
+
+
+def _tile_chunk(v, brow, X, local):
+    """The rest of :func:`_intra` for the tile, from :func:`_tile_local`'s
+    results and the inverse ``X = (I - N)^-1`` (CHUNK, CHUNK) float32, formed
+    on the spot (``kda_fwd``) or read back (``kda_bwd``): the six results
+    (``dC`` (1, D); ``P`` rounded to the inputs' type, or as given in it) and
+    what the backward tile reads again."""
+    dt = v.dtype
+    G, qf, kf, M, P, bcol, row, col = local
     T = (X * brow).astype(dt)
     eG = jnp.exp(G)
     Ke = (kf * eG).astype(dt)
@@ -456,6 +513,18 @@ def _tile_forward(q, k, v, g, brow):
            (qf * eG).astype(dt), P.astype(dt),
            (kf * jnp.exp(GC - G)).astype(dt), jnp.exp(GC))
     return out, (G, qf, kf, M, X, T, eG, Ke, bcol, row, col)
+
+
+def _tile_forward(q, k, v, g, brow):
+    """:func:`_intra` for one chunk of one head: q, k, v (CHUNK, D), g
+    (CHUNK, D) float32, beta as a row (1, CHUNK).  Returns the six results
+    (``P`` the fourth) and the inverse, float32: the backward kernel reads
+    both back."""
+    local = _tile_local(q, k, g, brow)
+    _, _, _, M, _, bcol, row, col = local
+    # T = (I - N)^-1 Diag(beta), N = -Diag(beta) tril(M, -1) nilpotent.
+    X = _tile_inverse(-bcol * M, row, col)
+    return _tile_chunk(v, brow, X, local)[0], X
 
 
 def _tile_backward(q, k, v, g, brow, saved, dW, dTV, dQe, dP, dKdec, ddC):
@@ -494,7 +563,7 @@ def _tile_backward(q, k, v, g, brow, saved, dW, dTV, dQe, dP, dKdec, ddC):
     dq_rows, dk_rows, dG_rows = [zero], [zero], [zero]
     for lo in range(SUB, CHUNK, SUB):
         near, far, rows, cols = _tile_off_block(G, qf, kf, lo)
-        dA = jnp.where(_earlier(lo), jnp.concatenate(
+        dA = jnp.where(_earlier(2 * SUB, lo), jnp.concatenate(
             [dM[lo:lo + SUB], dP[lo:lo + SUB]], axis=0), 0.0).astype(dt)
         drows = _dot(dA, cols.astype(dt))                        # (2 SUB, D)
         dcols = _dot(dA, rows.astype(dt), (0, 0))                # (CHUNK, D)
@@ -561,7 +630,11 @@ def _tile_inter_bwd(St, dSt, chunk, dO):
 # Heads a grid step takes, one after the other in one body.  On the chip, at
 # Kimi Linear's shapes, a layer forward and backward: 1 head 46.2 ms, 2 44.1,
 # 4 43.9, 8 43.0; the body is traced and lowered once for each head, 0.6 s a
-# program a head.
+# program a head.  (Those were read while ``kda_bwd`` formed the whole tile
+# again.  Since it reads the inverse and ``P``, at 2 heads: ``kda_fwd`` 16.0
+# ms a layer as before, ``kda_bwd`` 27.2 -> 15.4 in the cell's step; alone,
+# with beta's layout, 18.6 and 29.3 -> 17.5.  Two heads' tiles also fill the
+# 128 lanes of a block of kept tiles.)
 _HEADS_A_STEP = 2
 
 
@@ -570,33 +643,38 @@ def _heads_a_step(H: int) -> int:
 
 
 def _head_tiles(i: int, D: int, beta, *refs):
-    """Head ``i`` of a grid step: its lanes of the sequence blocks and its
-    row of beta."""
+    """Head ``i`` of a grid step: its lanes of the sequence blocks, its
+    lanes of the blocks of kept tiles, and its tiles and row of beta."""
     lanes = slice(i * D, (i + 1) * D)
-    return lanes, (*(ref[:, lanes] for ref in refs), beta[i:i + 1, :])
+    return (lanes, slice(i * CHUNK, (i + 1) * CHUNK),
+            (*(ref[:, lanes] for ref in refs), beta[i:i + 1, :]))
 
 
-def _kda_fwd_kernel(q, k, v, g, beta, o, states, St):
+def _kda_fwd_kernel(q, k, v, g, beta, o, states, inverse, pairs, St):
     @pl.when(pl.program_id(2) == 0)
     def _():
         St[...] = jnp.zeros_like(St)
 
     for i in range(St.shape[0]):
-        lanes, tile = _head_tiles(i, St.shape[1], beta, q, k, v, g)
-        chunk, _ = _tile_forward(*tile)
+        lanes, mine, tile = _head_tiles(i, St.shape[1], beta, q, k, v, g)
+        chunk, inverse[:, mine] = _tile_forward(*tile)
+        pairs[:, mine] = chunk[3]
         states[i] = St[i]
         St[i], o[:, lanes] = _tile_inter(St[i], chunk)
 
 
-def _kda_bwd_kernel(q, k, v, g, beta, do, states, dq, dk, dv, dg, dbeta,
-                    dSt):
+def _kda_bwd_kernel(q, k, v, g, beta, states, inverse, pairs, do, dq, dk, dv,
+                    dg, dbeta, dSt):
     @pl.when(pl.program_id(2) == 0)
     def _():
         dSt[...] = jnp.zeros_like(dSt)
 
     for i in range(dSt.shape[0]):
-        lanes, tile = _head_tiles(i, dSt.shape[1], beta, q, k, v, g)
-        chunk, saved = _tile_forward(*tile)
+        lanes, mine, tile = _head_tiles(i, dSt.shape[1], beta, q, k, v, g)
+        q_i, k_i, v_i, g_i, brow = tile
+        chunk, saved = _tile_chunk(
+            v_i, brow, inverse[:, mine],
+            _tile_local(q_i, k_i, g_i, brow, pairs[:, mine]))
         dSt[i], dchunk = _tile_inter_bwd(states[i], dSt[i], chunk,
                                          do[:, lanes])
         *grads, dbeta[i:i + 1, :] = _tile_backward(*tile, saved, *dchunk)
@@ -622,20 +700,24 @@ def _kernel_call(kernel, name: str, H: int, outs, *ins, reverse: bool,
     of CHUNK rows and a step's heads' lanes out of the layout the layer
     keeps; ``"beta"`` (B, L, H), laid out as (N, B, H / heads, heads, CHUNK),
     a block of a step's heads' rows; ``"states"`` (N, B, H, D, D), a step's
-    heads' states."""
+    heads' states; ``"tiles"`` (N, B, H / heads, CHUNK, heads * CHUNK), a
+    step's heads' CHUNK x CHUNK tiles (of the inverse, of ``P``) side by
+    side, so that a store fills whole lanes."""
     B, L, lanes = ins[0][1].shape
     D, N, heads = lanes // H, L // CHUNK, _heads_a_step(H)
     at = (lambda n: N - 1 - n) if reverse else (lambda n: n)
+    a_step = lambda b, h, n: (at(n), b, h, 0, 0)
     specs = {
         "sequence": pl.BlockSpec((None, CHUNK, heads * D),
                                  lambda b, h, n: (b, at(n), h)),
-        "beta": pl.BlockSpec((None, None, None, heads, CHUNK),
-                             lambda b, h, n: (at(n), b, h, 0, 0)),
-        "states": pl.BlockSpec((None, None, heads, D, D),
-                               lambda b, h, n: (at(n), b, h, 0, 0))}
+        "beta": pl.BlockSpec((None, None, None, heads, CHUNK), a_step),
+        "states": pl.BlockSpec((None, None, heads, D, D), a_step),
+        "tiles": pl.BlockSpec((None, None, None, CHUNK, heads * CHUNK),
+                              a_step)}
     shapes = {"sequence": (B, L, H * D),
               "beta": (N, B, H // heads, heads, CHUNK),
-              "states": (N, B, H, D, D)}
+              "states": (N, B, H, D, D),
+              "tiles": (N, B, H // heads, CHUNK, heads * CHUNK)}
     by_chunk = lambda beta: beta.reshape(
         B, N, CHUNK, H // heads, heads).transpose(1, 0, 3, 4, 2)
     return pl.pallas_call(
@@ -659,25 +741,30 @@ def _kernel_call(kernel, name: str, H: int, outs, *ins, reverse: bool,
 @functools.partial(jax.jit, static_argnames=("H", "interpret"))
 def _kda_kernel(q, k, v, g, beta, *, H: int, interpret: bool):
     """``kda_fwd``: the whole recurrence forward, q, k, v, g (B, L, H * D)
-    and beta (B, L, H), L whole chunks -> o (B, L, H * D) and the
-    chunk-entry states (N, B, H, D, D) float32, each transposed."""
+    and beta (B, L, H), L whole chunks -> o (B, L, H * D), the chunk-entry
+    states (N, B, H, D, D) float32, each transposed, and every tile's
+    inverse ``(I - N)^-1``, float32, and ``P``, in the inputs' type, each (N,
+    B, H / heads, CHUNK, heads * CHUNK), the tiles of a grid step's heads side
+    by side."""
     return _kernel_call(
         _kda_fwd_kernel, "kda_fwd", H,
-        (("sequence", q.dtype), ("states", _F32)),
+        (("sequence", q.dtype), ("states", _F32), ("tiles", _F32),
+         ("tiles", q.dtype)),
         ("sequence", q), ("sequence", k), ("sequence", v), ("sequence", g),
         ("beta", beta), reverse=False, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("H", "interpret"))
-def _kda_kernel_bwd(q, k, v, g, beta, states, do, *, H: int,
+def _kda_kernel_bwd(q, k, v, g, beta, states, inverse, pairs, do, *, H: int,
                     interpret: bool):
     """``kda_bwd``: the cotangent of o -> those of q, k, v, g and beta, in
-    their shapes."""
+    their shapes, from the inputs and what ``kda_fwd`` kept."""
     *grads, dbeta = _kernel_call(
         _kda_bwd_kernel, "kda_bwd", H,
         (("sequence", q.dtype),) * 3 + (("sequence", _F32), ("beta", _F32)),
         ("sequence", q), ("sequence", k), ("sequence", v), ("sequence", g),
-        ("beta", beta), ("sequence", do), ("states", states), reverse=True,
+        ("beta", beta), ("states", states), ("tiles", inverse),
+        ("tiles", pairs), ("sequence", do), reverse=True,
         interpret=interpret)
     return (*grads, dbeta.transpose(1, 0, 4, 2, 3).reshape(beta.shape))
 
@@ -697,25 +784,29 @@ def _kda_chunks(q, k, v, g, beta):
 
 
 def _kda_chunks_fwd(q, k, v, g, beta):
+    """-> o and the residuals: the five inputs, then what the forward pass
+    made and the backward pass reads, each under its name (the chunk-entry
+    states; from the kernels also the tiles' inverses and ``P``)."""
     if _takes_kernel(q.shape[-1]):
-        o, states = _kda_kernel(*map(_flat, (q, k, v, g)), beta,
-                                H=q.shape[2], interpret=_off_tpu())
+        o, *kept = _kda_kernel(*map(_flat, (q, k, v, g)), beta,
+                               H=q.shape[2], interpret=_off_tpu())
         o = o.reshape(q.shape)
     else:
-        o, states = _plain_fwd(q, k, v, g, beta)
+        o, *kept = _plain_fwd(q, k, v, g, beta)
     o = checkpoint_name(o, KDA_RESIDUAL_NAMES[0])
-    states = checkpoint_name(states, KDA_RESIDUAL_NAMES[1])
-    return o, (q, k, v, g, beta, states)
+    kept = map(checkpoint_name, kept, KDA_RESIDUAL_NAMES[1:])
+    return o, (q, k, v, g, beta, *kept)
 
 
 def _kda_chunks_bwd(saved, do):
-    """One pass over the chunks, last to first, from the inputs and the
-    chunk-entry states: no forward recurrence runs again."""
-    *inputs, beta, states = saved
+    """One pass over the chunks, last to first, from the inputs and what the
+    forward pass kept: no forward recurrence runs again, and in the kernels
+    no tile is inverted again and no ``P`` formed again."""
+    q, k, v, g, beta, *kept = saved
     if not _takes_kernel(do.shape[-1]):
-        return _plain_bwd(*inputs, beta, states, do)
+        return _plain_bwd(q, k, v, g, beta, *kept, do)
     *grads, dbeta = _kda_kernel_bwd(
-        *map(_flat, inputs), beta, states, _flat(do), H=do.shape[2],
+        *map(_flat, (q, k, v, g)), beta, *kept, _flat(do), H=do.shape[2],
         interpret=_off_tpu())
     return (*(a.reshape(do.shape) for a in grads), dbeta)
 
