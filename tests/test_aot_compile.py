@@ -666,3 +666,94 @@ def test_glm_flash_adamw_step_at_published_widths(v5e, monkeypatch):
     assert len(kernels) == 6 * 2 + 5 * 11 and len(module) == 2 + 11
     peak = program.memory_analysis().peak_memory_in_bytes
     assert 14.5e9 < peak < 15.75 * 2 ** 30
+
+
+@pytest.mark.parametrize("tile", [256, 512, 1024])
+def test_windowed_flash_at_laguna_widths(v5e, tile):
+    """A Laguna-S-2.1 sliding layer's attention, (1, 16384, 72, 128) bf16
+    over a 512-key window, at the tile the program chooses (512) and at its
+    neighbours: one forward kernel and the ONE backward kernel, whose grids
+    walk the band's blocks alone (3, 2 and 2 of them a Q block where the
+    causal grid has 64, 32 and 16), inside the chip's VMEM."""
+    from torchmpi_tpu.ops.flash_attention import blocks_met
+
+    L, heads, width, window = 16384, 72, 128, 512
+    assert blocks_met(L, window)["tile"] == 512
+    x = _sds((1, L, heads, width), jnp.bfloat16, SingleDeviceSharding(v5e[0]))
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True, interpret=False,
+                                       window=window, block_q=tile,
+                                       block_k=tile).astype(jnp.float32))
+
+    grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(x, x, x).compile()
+    text = grad.as_text()
+    assert _kernels(grad) == 2
+    assert "flash_fwd" in text and "flash_bwd" in text
+    assert "flash_bwd_dq" not in text and "flash_bwd_dkv" not in text
+    (_, fwd_used), (stated, used) = _kernel_vmem(grad)
+    assert fwd_used < 16 * 1024 * 1024                  # the default limit
+    assert 2 * L * width * 4 < used <= stated < V5E_VMEM_BYTES
+
+
+def test_laguna_adamw_step_at_published_widths(v5e, monkeypatch):
+    """The benchmark's `laguna-s-2.1-l16k` step on one chip: Laguna-S-2.1 at
+    its published widths, the first 5 of 48 layers (a full layer with the
+    dense FFN, three window layers and a full one with experts: three runs,
+    inlined), 8 of 256 routed experts a layer held here beside the shared
+    one, 12,544 rows of the vocabulary, 1 x 16,384 tokens, the configuration
+    file's remat, AdamW with bfloat16 moments, weights and state donated.
+    The compiler's own peak is 14.09 GB of 16.91 (15.75 GiB); with float32
+    moments it refuses the step by 437 MB (my compile of PR 40: 2.5 GB of the
+    plan is the five layers' log-sum-exp columns padded to 128 lanes).  Two
+    flash kernels a layer, the window layers' under `swa`, none replayed."""
+    import json
+    import os
+
+    import optax
+    from jax.sharding import Mesh
+
+    from torchmpi_tpu.models import llama
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "laguna-s-2.1.json")) as fh:
+        file = json.load(fh)
+    run = file["run"]
+    published = llama.laguna_s_2_1()
+    cfg = dataclasses.replace(
+        published, n_layers=5, layer_kinds=published.layer_kinds[:5],
+        experts_held=(0, 8), vocab=12544)
+    assert (file["num_hidden_layers"], file["num_experts"],
+            file["vocab_size"]) == (5, 8, 12544)
+    assert [n for *_, n in llama.layer_runs(cfg)] == [1, 3, 1]
+    one = SingleDeviceSharding(v5e[0])
+    place = lambda tree: jax.tree.map(
+        lambda a: _sds(a.shape, a.dtype, one), tree)
+    params = jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0), cfg,
+                                               dtype=jnp.bfloat16))
+    assert sum(a.size for a in jax.tree.leaves(params)) == 811_017_216
+    assert run["optimizer"]["moments_dtype"] == "bfloat16"
+    optimizer = optax.adamw(run["optimizer"]["learning_rate"], b1=0.9,
+                            b2=0.95, weight_decay=0.1)
+    state = jax.eval_shape(optimizer.init, params)      # bfloat16, as they
+    mesh = Mesh([v5e[0]], ("dp",))
+    tokens = _sds((1, 16384), jnp.int32, one)
+    step = llama.make_train_step(cfg, mesh, attn="flash", optimizer=optimizer,
+                                 remat=run["remat"],
+                                 loss_chunk=run["loss_chunk"])
+    program = step.lower(place(params), place(state), tokens,
+                         tokens).compile()
+    kernels = [line for line in program.as_text().splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    named = lambda what, lines=kernels: sum(
+        bool(re.search(what, line)) for line in lines)
+    assert run["remat"] == "full"
+    assert (named("flash_fwd"), named("flash_bwd[^_]")) == (5, 5)
+    window = [line for line in kernels if "/swa/" in line]
+    assert (named("flash_fwd", window), named("flash_bwd", window)) == (3, 3)
+    assert len(window) == 6
+    assert len(kernels) == 5 * 2 + 4 * 11
+    peak = program.memory_analysis().peak_memory_in_bytes
+    assert 13.5e9 < peak < 15.0e9

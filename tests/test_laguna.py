@@ -1,0 +1,628 @@
+"""Laguna-S-2.1-style stacks (``llama.laguna_s_2_1``): window and full softmax
+layers in a pattern with head counts of their own, heads wider than the
+state's share, a flash kernel that walks the band alone, YaRN on half of a
+full layer's head, a gate a head on the attention output, a chip's share of
+256 sigmoid-routed experts beside a shared one, against the plain reference
+the benchmark keeps (``benchmark/reference/laguna-s-2.1.py``, which imports
+nothing of the program); the remat policies, the refusals and the names in
+the device program.  Small widths, float32, the CPU."""
+
+import dataclasses
+import importlib.util
+import os
+import re
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from torchmpi_tpu.models import llama
+from torchmpi_tpu.ops.flash_attention import (_band_k_map, _band_q_map,
+                                              _flash_bh, _flash_bh_bwd,
+                                              blocks_met, flash_attention)
+from torchmpi_tpu.parallel import mesh as pmesh
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+PUBLISHED = llama.laguna_s_2_1()
+YARN = (8.0, 32, 32.0, 1.0, 1.2079441541679836)
+
+
+@pytest.fixture(scope="module")
+def reference():
+    path = os.path.join(ROOT, "benchmark", "reference", "laguna-s-2.1.py")
+    spec = importlib.util.spec_from_file_location("laguna_reference", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def laguna_tiny(n_layers=5, n_experts=16, held=(0, 4), k=3, window=24,
+                **more):
+    """The published pattern's first ``n_layers`` layers at toy widths: heads
+    of 16 on a state of 48 (4 full heads, 6 window heads, 2 KV heads)."""
+    return dataclasses.replace(
+        PUBLISHED, vocab=128, d_model=48, n_layers=n_layers, n_heads=4,
+        n_kv_heads=2, head_dim=16, d_ff=32, dense_d_ff=96, max_seq=256,
+        n_experts=n_experts, expert_top_k=k, swa_heads=6, swa_window=window,
+        rope_yarn=YARN, layer_kinds=PUBLISHED.layer_kinds[:n_layers],
+        experts_held=held, **more)
+
+
+def file_of(cfg):
+    """The configuration file's keys the reference reads, for ``cfg``."""
+    first, held = cfg.experts_held or (0, cfg.n_experts)
+    factor, original, fast, slow, attention = cfg.rope_yarn
+    names = {"attn": "full_attention", "swa": "sliding_attention"}
+    return {
+        "hidden_size": cfg.d_model, "num_hidden_layers": cfg.n_layers,
+        "head_dim": cfg.head_dim, "num_key_value_heads": cfg.n_kv_heads,
+        "rms_norm_eps": cfg.norm_eps, "sliding_window": cfg.swa_window,
+        "layer_types": [names[m] for m, _ in cfg.layer_kinds],
+        "mlp_layer_types": ["dense" if f == "dense" else "sparse"
+                            for _, f in cfg.layer_kinds],
+        "num_attention_heads_per_layer": [
+            llama.softmax_heads(cfg, m) for m, _ in cfg.layer_kinds],
+        "rope_parameters": {
+            "full_attention": {
+                "rope_theta": cfg.rope_theta, "rope_type": "yarn",
+                "factor": factor, "original_max_position_embeddings": original,
+                "beta_fast": fast, "beta_slow": slow,
+                "attention_factor": attention,
+                "partial_rotary_factor": cfg.rope_fraction},
+            "sliding_attention": {
+                "rope_type": "default", "rope_theta": cfg.swa_rope_theta,
+                "partial_rotary_factor": 1}},
+        "published": {"num_experts": cfg.n_experts},
+        "num_experts": held, "experts_held_first": first,
+        "num_experts_per_tok": cfg.expert_top_k,
+        "norm_topk_prob": cfg.moe_renormalize,
+        "moe_routed_scaling_factor": cfg.routed_scale,
+    }
+
+
+def rel(a, b):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    return float(np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30))
+
+
+def layer_of(params, run, i=0):
+    return jax.tree.map(lambda a: a[i], params["layers"][run])
+
+
+@pytest.fixture(scope="module")
+def five():
+    cfg = laguna_tiny()
+    return cfg, llama.init(jax.random.PRNGKey(0), cfg)
+
+
+@pytest.fixture(scope="module")
+def sample():
+    return (jax.random.randint(jax.random.PRNGKey(1), (2, 96), 0, 128),
+            jax.random.randint(jax.random.PRNGKey(2), (2, 96), 0, 128))
+
+
+@pytest.fixture(scope="module")
+def plain(five, reference, sample):
+    cfg, params = five
+    return jax.jit(lambda p, s: reference.loss_and_grads(file_of(cfg), p, s))(
+        params, sample)
+
+
+def _kernels(jaxpr, found):
+    """Names of a jaxpr's ``pallas_call`` s, in order, sub-jaxprs included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn.params["name"])
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _kernels(sub, found)
+    return found
+
+
+# ------------------------------------------------------ the windowed kernel
+
+def masked_plain(q, k, v, window=None):
+    """Softmax over the keys ``i - window < j <= i`` of whole score rows."""
+    L = q.shape[1]
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    i, j = jnp.arange(L)[:, None], jnp.arange(L)[None]
+    seen = j <= i if window is None else (j <= i) & (j > i - window)
+    return jnp.einsum("bhqk,bkhd->bqhd",
+                      jax.nn.softmax(jnp.where(seen, s, -jnp.inf), -1), v)
+
+
+@pytest.fixture(scope="module")
+def qkv():
+    return tuple(jax.random.normal(jax.random.PRNGKey(i), (1, 256, 2, 32))
+                 for i in range(3))
+
+
+# Windows smaller than, equal to, not a multiple of and larger than a block;
+# one key; blocks that differ; a window past the sequence.
+WINDOWS = [(16, 32, 32), (32, 32, 32), (33, 32, 32), (40, 32, 32),
+           (100, 32, 32), (1, 32, 32), (64, 32, 64), (64, 64, 32),
+           (48, 64, 64), (300, 32, 32)]
+
+
+@pytest.mark.parametrize("window,bq,bk", WINDOWS)
+def test_the_windowed_kernels_against_the_masked_plain_form(qkv, window, bq,
+                                                            bk):
+    """Forward and the one backward kernel in interpret mode."""
+    ours = lambda *a: flash_attention(*a, causal=True, window=window,
+                                      block_q=bq, block_k=bk)
+    want = lambda *a: masked_plain(*a, window=window)
+    assert rel(ours(*qkv), want(*qkv)) < 1e-5
+    loss = lambda f: lambda *a: jnp.sum(jnp.sin(f(*a)))
+    grads = jax.grad(loss(ours), argnums=(0, 1, 2))
+    names = _kernels(jax.make_jaxpr(grads)(*qkv).jaxpr, [])
+    assert names == ["flash_fwd", "flash_bwd"]
+    for g, w in zip(grads(*qkv), jax.grad(loss(want), (0, 1, 2))(*qkv)):
+        # one key a row: its weight is 1 whatever q and k, their gradients 0
+        assert rel(g, w) < 1e-5 or float(jnp.max(jnp.abs(g - w))) < 1e-5
+
+
+@pytest.mark.parametrize("window,bq,bk", [(40, 32, 32), (64, 32, 64),
+                                          (64, 64, 32), (16, 32, 32)])
+def test_the_streamed_backward_kernels_under_a_window(qkv, window, bq, bk):
+    """``flash_bwd_dq`` and ``flash_bwd_dkv`` (the form taken where the
+    resident dq block does not fit) give the one kernel's gradients."""
+    bh = lambda a: a.transpose(0, 2, 1, 3).reshape(2, 256, 32)
+    q, k, v = map(bh, qkv)
+    how = dict(causal=True, block_q=bq, block_k=bk, interpret=True,
+               window=window)
+    o, lse = _flash_bh(q, k, v, **how)
+    do = jnp.cos(o)
+    delta = jnp.sum(do * o, axis=-1, keepdims=True)
+    one = _flash_bh_bwd(q, k, v, do, lse, delta, **how)
+    streamed = jax.make_jaxpr(lambda *a: _flash_bh_bwd(
+        *a, vmem_budget=0, **how))(q, k, v, do, lse, delta)
+    assert _kernels(streamed.jaxpr, []) == ["flash_bwd_dq", "flash_bwd_dkv"]
+    two = _flash_bh_bwd(q, k, v, do, lse, delta, vmem_budget=0, **how)
+    want = jax.vjp(lambda *a: masked_plain(*a, window=window), *qkv)[1](
+        do.reshape(1, 2, 256, 32).transpose(0, 2, 1, 3))
+    for a, b, w in zip(one, two, want):
+        assert rel(a, b) < 1e-6
+        assert rel(a, bh(w)) < 1e-5
+
+
+def test_without_a_window_the_kernels_are_what_they_were(qkv):
+    """``window=None`` and a window no shorter than the sequence give the
+    causal call's output to the bit, and the same program."""
+    causal = flash_attention(*qkv, causal=True, block_q=32, block_k=32)
+    for window in (None, 256, 1000):
+        got = flash_attention(*qkv, causal=True, window=window, block_q=32,
+                              block_k=32)
+        np.testing.assert_array_equal(got, causal)
+    text = lambda **kw: re.sub(r"0x[0-9a-f]+", "0x", str(jax.make_jaxpr(
+        jax.grad(lambda *a: jnp.sum(flash_attention(
+            *a, causal=True, block_q=64, block_k=64, **kw))))(*qkv)))
+    assert text() == text(window=256)
+    assert text() != text(window=64)
+    with pytest.raises(ValueError, match="causal"):
+        flash_attention(*qkv, causal=False, window=8)
+
+
+@pytest.mark.parametrize("L,window,tile,inner,most", [
+    (16384, 512, 512, 2, 2), (2048, 512, 512, 2, 2), (4096, 1024, 1024, 2, 2),
+    (4096, 300, 256, 3, 3), (16384, 4096, 1024, 5, 5)])
+def test_a_q_block_runs_the_bands_k_blocks(L, window, tile, inner, most):
+    """From the index map the forward kernel is given: a Q block of a
+    windowed call names the band's K blocks and no other, its grid is as
+    long as the band is wide, and the tile comes from ``(L, window)``; a
+    causal call's Q block names the triangle's."""
+    met = blocks_met(L, window)
+    assert (met["tile"], met["grid_inner"], met["k_blocks_max"]) == (
+        tile, inner, most)
+    causal = blocks_met(L)
+    assert causal["k_blocks_max"] == causal["q_blocks"] == L // causal["tile"]
+    assert causal["k_blocks_mean"] == (causal["q_blocks"] + 1) / 2
+    # the band's: ceil((window - 1) / tile) + 1 blocks, fewer at the start
+    assert met["k_blocks_max"] == -(-(window - 1) // tile) + 1
+    assert met["k_blocks_mean"] < met["k_blocks_max"]
+    # both sides' index maps stay inside the band and never past the end
+    n = L // tile
+    k_of, k_width = _band_k_map(window, tile, tile, n)
+    q_of, q_width = _band_q_map(window, tile, tile, n, n)
+    ids = np.arange(n)[:, None]
+    ks = np.asarray(k_of(ids, np.arange(k_width)[None]))
+    qs = np.asarray(q_of(ids, np.arange(q_width)[None]))
+    assert ks.min() == 0 and qs.max() == n - 1
+    assert np.all(ks <= ids) and np.all(ks * tile + tile > ids * tile - window)
+    assert np.all(qs >= ids) and np.all(
+        qs * tile < ids * tile + tile - 1 + window)
+
+
+# ------------------------------------------------------------ the rotations
+
+def test_yarn_at_the_published_numbers(reference):
+    """``low`` 9, ``high`` 18 and the 32 frequencies of the full layers."""
+    rope = {"rope_theta": 500000, "rope_type": "yarn", "factor": 128,
+            "original_max_position_embeddings": 8192, "beta_slow": 1,
+            "beta_fast": 32, "attention_factor": 1.4852030263919618,
+            "partial_rotary_factor": 0.5}
+    assert reference.yarn_range(rope, 64) == (9, 18)
+    assert PUBLISHED.rope_yarn[-1] == pytest.approx(0.1 * np.log(128) + 1)
+    got = llama.yarn_inv_freq(64, 500000.0, 128.0, 8192, 32.0, 1.0)
+    plain_f = 500000.0 ** (-np.arange(32) / 32)
+    assert got.shape == (32,)
+    np.testing.assert_allclose(got[:10], plain_f[:10], rtol=1e-6)
+    np.testing.assert_allclose(got[18:], plain_f[18:] / 128, rtol=1e-6)
+    ramp = (np.arange(10, 18) - 9) / 9
+    np.testing.assert_allclose(
+        got[10:18], plain_f[10:18] * (1 - ramp) + plain_f[10:18] / 128 * ramp,
+        rtol=1e-6)
+    want, factor = reference.inverse_frequencies(rope, 128)
+    np.testing.assert_allclose(got, want, rtol=1e-6)
+    assert factor == 1.4852030263919618
+
+
+def test_the_partial_rotation_leaves_the_passed_half(reference):
+    cfg = laguna_tiny()
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, 40, 4, 16))
+    positions = jnp.arange(40) + 5
+    full = llama._rotation(cfg, "attn")(x, positions)
+    np.testing.assert_array_equal(full[..., 8:], x[..., 8:])
+    assert float(jnp.min(jnp.abs(full[:, 1:, :, :8] - x[:, 1:, :, :8]))) > 0
+    rope = file_of(cfg)["rope_parameters"]
+    for b in range(2):
+        assert rel(full[b], reference.rotate(x[b], positions,
+                                             rope["full_attention"])) < 1e-6
+    # position 0 turns nothing and still carries the attention factor
+    at_zero = llama._rotation(cfg, "attn")(x, jnp.zeros(40, int))
+    np.testing.assert_allclose(at_zero[..., :8], YARN[-1] * x[..., :8],
+                               rtol=1e-6)
+    whole = llama._rotation(cfg, "swa")(x, positions)
+    np.testing.assert_array_equal(
+        whole, llama.rope(x, positions, cfg.swa_rope_theta))
+    assert rel(whole[0], reference.rotate(x[0], positions,
+                                          rope["sliding_attention"])) < 1e-6
+
+
+# ------------------------------------------------- the gate and the layers
+
+def test_the_gates_forward_and_gradient():
+    o = jax.random.normal(jax.random.PRNGKey(0), (2, 8, 6, 16))
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 8, 48))
+    wg = jax.random.normal(jax.random.PRNGKey(2), (48, 6)) * 0.3
+    want = lambda o, x, wg: o * jax.nn.sigmoid(
+        jnp.einsum("bld,dh->blh", x, wg))[..., None]
+    assert rel(llama._gate_heads(o, x, wg), want(o, x, wg)) < 1e-6
+    loss = lambda f: lambda *a: jnp.sum(jnp.sin(f(*a)))
+    for g, w in zip(jax.grad(loss(llama._gate_heads), (0, 1, 2))(o, x, wg),
+                    jax.grad(loss(want), (0, 1, 2))(o, x, wg)):
+        assert rel(g, w) < 1e-5
+    # a gate of its own a head: head 0's column moves head 0's output alone
+    moved = llama._gate_heads(o, x, wg.at[:, 0].add(1.0))
+    np.testing.assert_array_equal(moved[:, :, 1:],
+                                  llama._gate_heads(o, x, wg)[:, :, 1:])
+
+
+@pytest.mark.parametrize("attn", ["full", "flash"])
+@pytest.mark.parametrize("run,kind,heads", [(0, "full_attention", 4),
+                                            (1, "sliding_attention", 6)])
+def test_a_layer_of_each_kind_against_the_reference(five, reference, attn,
+                                                    run, kind, heads):
+    """A full and a sliding layer's mixer, with head counts of their own over
+    the same two KV heads: forward and every leaf's gradient."""
+    cfg, params = five
+    mixer = cfg.layer_kinds[run if run == 0 else 1][0]
+    lp = layer_of(params, run)
+    assert lp["wq"].shape == (48, heads * 16) and lp["wg"].shape == (48, heads)
+    assert lp["wk"].shape == (48, 2 * 16)
+    h = jax.random.normal(jax.random.PRNGKey(4), (2, 64, 48))
+    impls = llama._mixer_impls(cfg, attn, None)
+
+    def ours(lp, h):
+        return llama._attention_block(cfg, lp, h, jnp.arange(64),
+                                      impls[mixer], mixer=mixer) - h
+
+    def want(lp, h):
+        x = reference.rms_norm(h, lp["attn_norm"], cfg.norm_eps)
+        return jax.vmap(
+            lambda x: reference.mixer(file_of(cfg), kind, lp, x))(x)
+
+    assert rel(ours(lp, h), want(lp, h)) < 1e-5
+    loss = lambda f: lambda *a: jnp.sum(jnp.sin(f(*a)))
+    got, wanted = (jax.grad(loss(f), (0, 1))(lp, h) for f in (ours, want))
+    for name in ("wq", "wk", "wv", "wo", "wg", "attn_norm"):
+        assert rel(got[0][name], wanted[0][name]) < 1e-4, name
+    assert rel(got[1], wanted[1]) < 1e-4
+
+
+def test_five_layers_against_the_reference(five, reference, sample, plain):
+    """Loss, logits and every leaf's gradient of the five-layer cut (a dense
+    full layer, three sliding expert layers, a full expert layer)."""
+    cfg, params = five
+    assert llama.layer_runs(cfg) == (("attn", "dense", 1), ("swa", "moe", 3),
+                                     ("attn", "moe", 1))
+    loss, grads = jax.jit(jax.value_and_grad(llama.make_loss_fn(
+        cfg, attn="flash", remat="full", loss_chunk=32)))(params, sample)
+    logits = llama.apply(cfg, params, sample[0], attn="flash")
+    want_loss, want_logits, want_grads = plain
+    assert float(loss) == pytest.approx(float(want_loss), rel=1e-5)
+    assert rel(logits, want_logits) < 1e-5
+    flat = jax.tree_util.tree_leaves_with_path(grads)
+    assert len(flat) == len(jax.tree.leaves(want_grads))
+    for (path, g), w in zip(flat, jax.tree.leaves(want_grads)):
+        assert rel(g, w) < 2e-4, jax.tree_util.keystr(path)
+    counts = llama.expert_unit_counts(cfg, params, sample[0])
+    assert counts.shape == (4, cfg.n_experts)
+    np.testing.assert_array_equal(
+        counts, reference.hidden(file_of(cfg), params, sample[0])[1])
+
+
+@pytest.mark.parametrize("change,least", [
+    (dict(swa_window=96), 1e-3), (dict(swa_window=25), 1e-5),
+    (dict(attn_gate=False), 1e-2), (dict(rope_yarn=None), 1e-3)],
+    ids=["no-window", "one-key-more", "no-gate", "plain-rotation"])
+def test_what_the_controls_change_shows(five, sample, plain, change, least):
+    """The faults the benchmark's controls plant (the window left out, a
+    window one key wider, the gate left out, the plain rotation) each move the
+    logits off the reference's by more than rounding does."""
+    cfg, params = five
+    logits = jax.jit(lambda p, t: llama.apply(
+        dataclasses.replace(cfg, **change), p, t, attn="flash"))(
+            params, sample[0])
+    assert rel(logits, plain[1]) > least
+
+
+@pytest.mark.parametrize("change,wrong", [
+    ({}, 0), (dict(swa_window=25), 2), (dict(swa_window=23), 2),
+    (dict(swa_window=128), 128 - 19 - 24 - 24)],
+    ids=["configured", "one-key-more", "one-key-fewer", "no-window"])
+def test_the_band_probe_counts_the_rows_a_wrong_window_moves(
+        five, reference, change, wrong):
+    """What rounding hides from every norm the benchmark compares, a window
+    one key off, the runner's probe counts to the row: one sliding layer's
+    logits change, to the bit, on the rows whose band holds a changed token
+    and on no other, in the program and in the reference alike."""
+    path = os.path.join(ROOT, "benchmark", "runners", "step_tokens_mixed.py")
+    spec = importlib.util.spec_from_file_location("laguna_runner", path)
+    runner = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(runner)
+    cfg, _ = five
+    mesh = jax.sharding.Mesh(np.array(jax.devices()[:1]), ("dp",))
+    got = runner.band_rows_wrong(
+        dataclasses.replace(cfg, **change), file_of(cfg), reference, mesh,
+        dict(attn="flash", remat="full"), 5, jnp.float32, 128)
+    assert got == wrong
+
+
+@pytest.mark.parametrize("n_experts,held", [(256, 8), (16, 4), (16, 2)])
+def test_the_shares_add_up(reference, n_experts, held):
+    """Over all ``n_experts / held`` shares of a layer (thirty-two of eight,
+    as the deployment's chips, and fewer), the held experts' parts, with the
+    shared expert counted once, sum to the uncut reference's layer output; a
+    share's weights are the uncut layer's experts."""
+    whole = laguna_tiny(n_layers=2, n_experts=n_experts, held=None, k=10)
+    full = layer_of(llama.init(jax.random.PRNGKey(0), whole), 1)
+    x = jax.random.normal(jax.random.PRNGKey(6), (1, 32, whole.d_model))
+    xt = x.reshape(-1, whole.d_model)
+    want = reference.experts_ffn(file_of(whole), full, xt)
+    shared = reference.swiglu(xt, full["shared_gate"], full["shared_up"],
+                              full["shared_down"])
+    total = 0.0
+    for first in range(0, n_experts, held):
+        cfg = laguna_tiny(n_layers=2, n_experts=n_experts,
+                          held=(first, held), k=10)
+        lp = dict(full, **{name: full[name][first:first + held]
+                           for name in ("w_gate", "w_up", "w_down")})
+        if first in (0, n_experts - held):
+            mine = layer_of(llama.init(jax.random.PRNGKey(0), cfg), 1)
+            np.testing.assert_array_equal(mine["w_up"], lp["w_up"])
+        part, _ = llama._moe_ffn(cfg, lp, x)
+        total = total + part.reshape(xt.shape) - shared
+    assert rel(total + shared, want) < 1e-5
+
+
+# ------------------------------------------------------- mesh, remat, names
+
+def test_four_devices_against_one():
+    """Under GSPMD on dp x tp the stack gives one device's loss and
+    gradients, the windowed flash kernels in a ``shard_map`` over the batch
+    and the heads (6 and 4 of them over tp 2), ``wg`` sharded by head."""
+    cfg = laguna_tiny(n_layers=2)
+    params = llama.init(jax.random.PRNGKey(0), cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 64), 0, cfg.vocab)
+    sample = (tokens, jnp.roll(tokens, -1, 1))
+    loss_of = lambda mesh: jax.jit(jax.value_and_grad(llama.make_loss_fn(
+        cfg, mesh, attn="flash", loss_chunk=32)))
+    alone = loss_of(None)(params, sample)
+    mesh = pmesh.make_mesh({"dp": 2, "tp": 2}, devices=jax.devices()[:4])
+    specs = llama.param_specs(cfg)
+    for run in specs["layers"]:
+        assert run["wg"] == run["wq"] == jax.sharding.PartitionSpec(
+            None, None, "tp")
+    sharded = llama.shard_params(params, mesh, cfg)
+    assert sharded["layers"][1]["wg"].sharding.shard_shape((1, 48, 6)) == (
+        1, 48, 3)
+    loss, grads = loss_of(mesh)(sharded, sample)
+    np.testing.assert_allclose(loss, alone[0], rtol=1e-5)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(grads),
+                            jax.tree.leaves(alone[1])):
+        assert rel(a, b) < 1e-4 or float(jnp.max(jnp.abs(b))) == 0.0, \
+            jax.tree_util.keystr(path)
+
+
+_GRADS = {}
+
+
+def _grads(cfg, params, sample, remat):
+    """The five-layer cut's gradients under a remat policy, taken once."""
+    if remat not in _GRADS:
+        _GRADS[remat] = jax.jit(jax.grad(llama.make_loss_fn(
+            cfg, attn="flash", remat=remat, loss_chunk=32)))(params, sample)
+    return _GRADS[remat]
+
+
+@pytest.mark.parametrize("remat", ["dots", "full"])
+def test_remat_gives_the_gradients_and_replays_no_kernel(five, sample, remat):
+    """``"dots"`` and ``"full"`` give ``"none"``'s gradients, and the step
+    holds each of the five layers' flash kernels once forward and once
+    backward."""
+    cfg, params = five
+    for g, w in zip(jax.tree.leaves(_grads(cfg, params, sample, remat)),
+                    jax.tree.leaves(_grads(cfg, params, sample, "none"))):
+        assert rel(g, w) < 1e-4 or float(jnp.max(jnp.abs(w))) == 0.0
+    mesh = pmesh.make_mesh({"dp": 1}, devices=jax.devices()[:1])
+    step = llama.make_train_step(cfg, mesh, attn="flash", remat=remat,
+                                 loss_chunk=32)
+    tokens = jnp.zeros((1, 160), jnp.int32)
+    shapes = jax.eval_shape(lambda: params)
+    flash = [n for n in _kernels(jax.make_jaxpr(step)(
+        shapes, None, tokens, tokens).jaxpr, []) if n and "flash" in n]
+    assert flash == ["flash_fwd"] * 5 + ["flash_bwd"] * 5
+
+
+def test_the_published_48_layers_build():
+    runs = llama.layer_runs(PUBLISHED)
+    assert len(runs) == 24
+    assert runs[:3] == (("attn", "dense", 1), ("swa", "moe", 3),
+                        ("attn", "moe", 1))
+    assert (PUBLISHED.head_dim, PUBLISHED.n_heads, PUBLISHED.swa_heads) == (
+        128, 48, 72)
+    shapes = jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0),
+                                               PUBLISHED, jnp.bfloat16))
+    count = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(shapes))
+    assert 117.5e9 < count < 117.6e9
+    full, sliding = shapes["layers"][0], shapes["layers"][1]
+    assert full["wq"].shape == (1, 3072, 48 * 128)
+    assert full["wg"].shape == (1, 3072, 48)
+    assert full["w_gate"].shape == (1, 3072, 12288)
+    assert sliding["wq"].shape == (3, 3072, 72 * 128)
+    assert sliding["wk"].shape == (3, 3072, 8 * 128)
+    assert sliding["wo"].shape == (3, 72 * 128, 3072)
+    assert sliding["router"].shape == (3, 3072, 256)
+    assert sliding["w_gate"].shape == (3, 256, 3072, 1024)
+    mixer = lambda run: sum(int(np.prod(run[k].shape[1:])) for k in (
+        "wq", "wk", "wv", "wg", "wo"))
+    assert (mixer(full), mixer(sliding)) == (44_187_648, 63_135_744)
+    # the benchmark's cut: five layers, experts 0-7, an eighth of the rows
+    cut = dataclasses.replace(
+        PUBLISHED, n_layers=5, vocab=12544, experts_held=(0, 8),
+        layer_kinds=PUBLISHED.layer_kinds[:5])
+    held = jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0), cut))
+    assert sum(int(np.prod(a.shape))
+               for a in jax.tree.leaves(held)) == 811_017_216
+    specs = llama.param_specs(PUBLISHED)
+    is_spec = lambda s: isinstance(s, jax.sharding.PartitionSpec)
+    assert jax.tree.structure(jax.tree.map(lambda a: 0, shapes)) == \
+        jax.tree.structure(jax.tree.map(lambda s: 0, specs, is_leaf=is_spec))
+    # all 48 layers at toy widths
+    cfg = laguna_tiny(n_layers=48)
+    params = llama.init(jax.random.PRNGKey(0), cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (1, 64), 0, cfg.vocab)
+    loss = jax.jit(llama.make_loss_fn(cfg, attn="flash", remat="full",
+                                      loss_chunk=32))(params, (tokens, tokens))
+    assert np.isfinite(float(loss))
+
+
+def test_the_kinds_come_from_the_files_lists():
+    kinds = llama.window_layer_kinds(
+        ["full_attention", "sliding_attention"], ["dense", "sparse"])
+    assert kinds == (("attn", "dense"), ("swa", "moe"))
+    with pytest.raises(ValueError, match="chunked_attention"):
+        llama.window_layer_kinds(["chunked_attention"], ["dense"])
+    with pytest.raises(ValueError, match="1 layer types for 2"):
+        llama.window_layer_kinds(["full_attention"], ["dense", "sparse"])
+
+
+@pytest.mark.parametrize("preset,leaves,checksum,loss", [
+    ("glm", 53, 733133.2083365738, 7.012062072753906)])
+def test_a_stack_of_runs_is_what_it_was(preset, leaves, checksum, loss):
+    """With the new fields at their defaults the GLM-4.7-Flash preset builds
+    the parameter tree and the weights for a seed that the commit before
+    this model gave, and its loss (numbers taken from that commit; the Kimi
+    Linear preset's are in ``test_glm_flash.py``, the homogeneous
+    configurations' in ``test_kimi_linear.py``)."""
+    glm = llama.glm_4_7_flash()
+    assert (glm.head_dim, glm.swa_window, glm.attn_gate, glm.rope_yarn,
+            glm.rope_fraction) == (102, 0, False, None, 1.0)
+    cfg = dataclasses.replace(
+        glm, vocab=128, d_model=64, n_layers=5, n_heads=4, n_kv_heads=4,
+        d_ff=32, dense_d_ff=96, max_seq=256, n_experts=8, expert_top_k=2,
+        q_lora_rank=40, kv_lora_rank=24, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=24, head_dim=0,
+        layer_kinds=glm.layer_kinds[:5], experts_held=(0, 2))
+    params = llama.init(jax.random.PRNGKey(7), cfg)
+    flat = jax.tree.leaves(params)
+    assert len(flat) == leaves
+    total = sum(np.sum(np.abs(np.asarray(a, np.float64))) * (i + 1)
+                for i, a in enumerate(flat))
+    assert total == pytest.approx(checksum, rel=1e-12)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 64), 0, 128)
+    got = jax.jit(llama.make_loss_fn(cfg, attn="flash", remat="dots",
+                                     loss_chunk=16))(params, (tokens, tokens))
+    assert float(got) == pytest.approx(loss, rel=1e-6)
+
+
+def test_the_head_width_is_a_field():
+    """0: derived, as every configuration before had it; given, q is
+    ``n_heads * head_dim`` wide on any state."""
+    assert llama.tiny().head_dim == 16 and llama.llama3_8b().head_dim == 128
+    wide = dataclasses.replace(llama.tiny(), head_dim=32)
+    params = llama.init(jax.random.PRNGKey(0), wide)
+    assert params["layers"]["wq"].shape == (2, 64, 4 * 32)
+    assert params["layers"]["wo"].shape == (2, 4 * 32, 64)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 32), 0, 256)
+    flash, full = (jax.jit(llama.make_loss_fn(wide, attn=a))(
+        params, (tokens, tokens)) for a in ("flash", "full"))
+    assert float(flash) == pytest.approx(float(full), rel=1e-5)
+
+
+@pytest.mark.parametrize("call,missing", [
+    (lambda cfg, p: llama._decode_step(cfg, p, None, None, None),
+     "rolling cache of swa_window positions"),
+    (lambda cfg, p: llama._prefill(cfg, p, None, jnp.zeros((1, 8), int)),
+     "rolling cache of the last swa_window positions"),
+    (lambda cfg, p: llama.make_generate_fn(cfg, 8, 8),
+     "the gate in the one-row path"),
+    (lambda cfg, p: llama.make_pp_train_step(cfg, None, 2),
+     "head count, window and rotation"),
+    (lambda cfg, p: llama.make_1f1b_train_step(cfg, None, 2),
+     "head count, window and rotation"),
+    (lambda cfg, p: llama.apply(cfg, p, jnp.zeros((1, 8), int), attn="ring"),
+     "ring form of the band")],
+    ids=["decode", "prefill", "generate", "gpipe", "1f1b", "ring"])
+def test_the_refusals_say_their_reason(five, call, missing):
+    cfg, params = five
+    with pytest.raises(NotImplementedError, match=missing) as refused:
+        call(cfg, params)
+    assert "swa_window=24" in str(refused.value)
+    assert "attn_gate=True" in str(refused.value)
+
+
+def test_the_programs_names(five, sample):
+    """``swa`` inside ``attn`` round a sliding layer's kernels alone, forward
+    and backward; ``attn.gate`` round the gate; the full layers' kernels
+    under ``attn`` outside ``swa``."""
+    cfg, params = five
+    mesh = pmesh.make_mesh({"dp": 1}, devices=jax.devices()[:1])
+    step = llama.make_train_step(cfg, mesh, attn="flash", remat="full",
+                                 loss_chunk=32)
+    shapes = jax.eval_shape(lambda: params)
+    names = set(re.findall(r'loc\("([^"]+)"', step.lower(
+        shapes, None, *sample).as_text(debug_info=True)))
+    part = lambda scope: re.compile(
+        r"(^|[/(])" + re.escape(scope) + r"([/)]|$)")
+    for scope in ("embed", "attn", "swa", "attn.gate", "ffn", "moe.router",
+                  "moe.dispatch", "moe.experts", "moe.combine", "moe.shared",
+                  "final_norm", "head_loss", "optimizer"):
+        assert any(part(scope).search(n) for n in names), scope
+    inside = [n for n in names if part("swa").search(n)]
+    assert all(part("attn").search(n) for n in inside)
+    assert any("flash_fwd" in n for n in inside)
+    assert any("flash_bwd" in n for n in inside)
+    # the projections, the rotation and the gate lie outside it
+    assert not any(part("attn.gate").search(n) for n in inside)
+    assert not any("dot_general" in n and "flash" not in n and
+                   "pallas" not in n for n in inside)
+    outside = [n for n in names if part("attn").search(n)
+               and not part("swa").search(n)]
+    assert any("flash_fwd" in n for n in outside)
+    assert any("flash_bwd" in n for n in outside)
+    gate = [n for n in names if part("attn.gate").search(n)]
+    assert any("logistic" in n for n in gate)
+    assert any("transpose(" in n for n in gate)

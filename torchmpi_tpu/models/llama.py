@@ -22,7 +22,10 @@ machinery (SURVEY.md §5.7, §7 item 7-8).  TPU-first design:
   that alternate every few layers mean every layer inlined; a
   multi-token-prediction module (``Config(mtp_layers=1)``, GLM-4.7-Flash —
   :func:`glm_4_7_flash`) is one more layer after the stack with a loss of its
-  own through the same embedding and head (:func:`_mtp_input`);
+  own through the same embedding and head (:func:`_mtp_input`); window and
+  full softmax layers in a pattern (``"swa"`` among ``"attn"``, Laguna-S-2.1
+  — :func:`laguna_s_2_1`) are two kinds of run with head counts, rotations
+  and a key window of their own, and a gate a head on the attention output;
 * :func:`param_specs` returns the PartitionSpec pytree for Megatron-style
   tensor parallelism (qkv/gate/up column-sharded, o/down row-sharded) —
   under pjit GSPMD inserts exactly the one-psum-per-block collectives the
@@ -168,18 +171,46 @@ class Config:
     # wide and the weights are normalised over all k choices; the layer
     # returns the held experts' part (:func:`_held_experts`).  None: all.
     experts_held: Optional[Tuple[int, int]] = None
-
-    @property
-    def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+    # The softmax layers' head width; 0: ``d_model // n_heads``, derived and
+    # written here (so ``dataclasses.replace`` with another ``d_model`` or
+    # ``n_heads`` passes ``head_dim=0`` too).  A width of its own makes q
+    # ``n_heads * head_dim`` wide on any state (Laguna-S-2.1: 48 heads of 128
+    # on 3072).
+    head_dim: int = 0
+    # Window layers (``"swa"`` in ``layer_kinds``): softmax attention over
+    # ``swa_heads`` heads (0: ``n_heads``) of ``head_dim`` and the same
+    # ``n_kv_heads``, where row i sees keys ``i - swa_window < j <= i`` (its
+    # own among the ``swa_window``), every channel rotated at
+    # ``swa_rope_theta``, nothing scaled.
+    swa_heads: int = 0
+    swa_window: int = 0
+    swa_rope_theta: float = 10000.0
+    # The full (``"attn"``) layers' rotation: the first ``rope_fraction`` of a
+    # head's channels at ``rope_theta``, the others pass; with ``rope_yarn``
+    # ``(factor, original length, beta_fast, beta_slow, attention factor)``
+    # the frequencies are YaRN's (:func:`yarn_inv_freq`) and cos and sin carry
+    # the attention factor.  1.0 and None: :func:`rope` as it always was.
+    rope_fraction: float = 1.0
+    rope_yarn: Optional[Tuple[float, int, float, float, float]] = None
+    # A gate a head on the attention output, ``sigmoid(x @ wg)`` of the normed
+    # layer input, before the output projection (leaf ``wg`` (d_model, heads)
+    # of every softmax layer; scope ``attn.gate``).
+    attn_gate: bool = False
 
     def __post_init__(self):
-        # ``head_dim`` is the softmax ("attn") layers'; a stack without one
-        # (GLM-4.7-Flash: 20 latent heads on 2048) need not divide.
-        assert self.d_model % self.n_heads == 0 or (
-            self.layer_kinds is not None
-            and all(mixer != "attn" for mixer, _ in self.layer_kinds))
+        # ``head_dim`` is the softmax ("attn", "swa") layers'; a stack without
+        # one (GLM-4.7-Flash: 20 latent heads on 2048) need not divide.
+        softmax = self.layer_kinds is None or any(
+            mixer in ("attn", "swa") for mixer, _ in self.layer_kinds)
+        assert (self.head_dim or not softmax
+                or self.d_model % self.n_heads == 0)
+        if not self.head_dim:
+            object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
         assert self.n_heads % self.n_kv_heads == 0
+        assert self.swa_heads % self.n_kv_heads == 0
+        # Channels rotate in pairs.
+        assert 0 < self.rope_fraction <= 1
+        assert not softmax or self.head_dim * self.rope_fraction % 2 == 0
         assert self.ut_steps >= 1
         if self.n_experts:
             assert 1 <= self.expert_top_k <= self.n_experts
@@ -199,9 +230,13 @@ class Config:
             assert self.ut_steps == 1 and not self.sandwich_norm
             assert not self.qk_norm
             for mixer, ffn in self.layer_kinds:
-                assert mixer in ("attn", "kda", "mla"), mixer
+                assert mixer in ("attn", "swa", "kda", "mla"), mixer
                 assert ffn in ("dense", "moe"), ffn
                 assert ffn == "dense" or self.n_experts
+                assert mixer != "swa" or self.swa_window >= 1
+        if self.attn_gate:
+            # The gate is a leaf of a run (:func:`_init_run`).
+            assert self.layer_kinds is not None
         if self.q_lora_rank or self.mla_rope:
             assert self.layer_kinds is not None and self.kv_lora_rank
         assert self.mtp_layers in (0, 1)
@@ -303,6 +338,50 @@ def glm_4_7_flash() -> Config:
                   mtp_layers=1, mtp_coef=0.3)
 
 
+def window_layer_kinds(layer_types: Sequence[str],
+                       mlp_layer_types: Sequence[str]
+                       ) -> Tuple[Tuple[str, str], ...]:
+    """``Config.layer_kinds`` from a configuration file's two lists, one
+    entry a layer each: ``"full_attention"`` or ``"sliding_attention"``, and
+    ``"dense"`` or ``"sparse"``."""
+    mixers = {"full_attention": "attn", "sliding_attention": "swa"}
+    ffns = {"dense": "dense", "sparse": "moe"}
+    if len(layer_types) != len(mlp_layer_types):
+        raise ValueError(f"{len(layer_types)} layer types for "
+                         f"{len(mlp_layer_types)} FFN types")
+    for names, known in ((layer_types, mixers), (mlp_layer_types, ffns)):
+        for name in names:
+            if name not in known:
+                raise ValueError(f"{name!r} is none of {sorted(known)}")
+    return tuple((mixers[t], ffns[f])
+                 for t, f in zip(layer_types, mlp_layer_types))
+
+
+def laguna_s_2_1() -> Config:
+    """Laguna-S-2.1 geometry (``poolside/Laguna-S-2.1``, ``laguna``): 48
+    layers on a 3072-wide state, heads of 128 over 8 KV heads; every fourth
+    layer from the first attends to all earlier keys with 48 heads, half of
+    each rotated with YaRN-scaled frequencies at theta 500,000, the others to
+    the last 512 with 72 heads rotated whole at theta 10,000; a sigmoid gate a
+    head on the attention output; a dense first layer of 12,288, then 256
+    sigmoid-routed experts of width 1024, 10 a token, their normalised
+    weights scaled by 2.5, beside a shared one."""
+    return Config(vocab=100352, d_model=3072, n_layers=48, n_heads=48,
+                  n_kv_heads=8, head_dim=128, d_ff=1024, dense_d_ff=12288,
+                  max_seq=1048576, rope_theta=500000.0, norm_eps=1e-6,
+                  n_experts=256, expert_top_k=10, capacity_factor=None,
+                  moe_aux_coef=0.0, moe_renormalize=True, n_shared_experts=1,
+                  router_act="sigmoid", router_bias=False, routed_scale=2.5,
+                  swa_heads=72, swa_window=512, swa_rope_theta=10000.0,
+                  rope_fraction=0.5,
+                  rope_yarn=(128.0, 8192, 32.0, 1.0, 1.4852030263919618),
+                  attn_gate=True,
+                  layer_kinds=window_layer_kinds(
+                      ["full_attention", "sliding_attention",
+                       "sliding_attention", "sliding_attention"] * 12,
+                      ["dense"] + ["sparse"] * 47))
+
+
 def layer_runs(cfg: Config) -> Tuple[Tuple[str, str, int], ...]:
     """The stack as homogeneous runs, ``(mixer, ffn, length)`` each:
     consecutive layers of one kind.  A configuration without
@@ -316,6 +395,12 @@ def layer_runs(cfg: Config) -> Tuple[Tuple[str, str, int], ...]:
         else:
             runs.append((*kinds, 1))
     return tuple(runs)
+
+
+def softmax_heads(cfg: Config, mixer: str) -> int:
+    """Query heads of a softmax layer of kind ``mixer``: a window layer's
+    (``"swa"``) where the configuration gives them a count of their own."""
+    return cfg.swa_heads if mixer == "swa" and cfg.swa_heads else cfg.n_heads
 
 
 def tiny(vocab: int = 256, seq: int = 64) -> Config:
@@ -378,9 +463,12 @@ def _init_run(key: jax.Array, cfg: Config, mixer: str, ffn: str, n: int,
             wkv_b=dense(r, H * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
             wo=dense(H * cfg.v_head_dim, D))
     else:
-        hd, H, KV = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
+        hd, KV = cfg.head_dim, cfg.n_kv_heads
+        H = softmax_heads(cfg, mixer)
         lp.update(wq=dense(D, H * hd), wk=dense(D, KV * hd),
                   wv=dense(D, KV * hd), wo=dense(H * hd, D))
+        if cfg.attn_gate:
+            lp.update(wg=dense(D, H))
     if ffn == "dense":
         W = cfg.dense_d_ff or F
         lp.update(w_gate=dense(D, W), w_up=dense(D, W), w_down=dense(W, D))
@@ -531,11 +619,12 @@ def param_specs(cfg: Config) -> Params:
             if cfg.sandwich_norm else {})
     gate = ({"gate_w": P(None), "gate_b": P(None)} if cfg.exit_gate else {})
     if cfg.layer_kinds is not None:
-        # A run's projections by columns and rows as above, its experts over
-        # ``ep`` and ``tp`` too; every other leaf (norms, convolutions, the
-        # low-rank pairs, the router and its bias) whole on each device.
+        # A run's projections by columns and rows as above (the output gate's
+        # columns are heads, as ``wq``'s are heads' channels), its experts
+        # over ``ep`` and ``tp`` too; every other leaf (norms, convolutions,
+        # the low-rank pairs, the router and its bias) whole on each device.
         sharded = {"wq": col, "wk": col, "wv": col, "wkv_b": col, "wo": row,
-                   "wq_b": col,
+                   "wq_b": col, "wg": col,
                    "shared_gate": col, "shared_up": col, "shared_down": row}
         dense = {"w_gate": col, "w_up": col, "w_down": row}
 
@@ -593,11 +682,65 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     return out.reshape(x.shape).astype(x.dtype)
 
 
+def yarn_inv_freq(dim: int, theta: float, factor: float, original: int,
+                  beta_fast: float, beta_slow: float) -> np.ndarray:
+    """YaRN's ``dim // 2`` inverse frequencies (Peng et al.,
+    arXiv:2309.00071, as ``transformers``' ``_compute_yarn_parameters``
+    writes them, truncated): channel pair i keeps ``theta ** (-2i / dim)``
+    where it turns more than ``beta_fast`` times over the ``original``
+    length, takes it over ``factor`` where it turns fewer than ``beta_slow``
+    times, and a linear ramp between the two pairs those counts name."""
+    plain = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    turns_at = lambda n: (dim * np.log(original / (2 * np.pi * n))
+                          / (2 * np.log(theta)))
+    low = max(int(np.floor(turns_at(beta_fast))), 0)
+    high = min(int(np.ceil(turns_at(beta_slow))), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3), 0, 1)
+    return (plain * (1 - ramp) + plain / factor * ramp).astype(np.float32)
+
+
+def rope_scaled(x: jax.Array, positions: jax.Array, inv_freq,
+                factor: float = 1.0) -> jax.Array:
+    """:func:`rope` on the first ``2 * len(inv_freq)`` channels of x (B, L,
+    H, D_head) at the inverse frequencies given, cos and sin times
+    ``factor``; the channels after them pass as they are."""
+    r = 2 * len(inv_freq)
+    angles = positions[:, None].astype(jnp.float32) * jnp.asarray(inv_freq)
+    cos = factor * jnp.cos(angles)[None, :, None, :]
+    sin = factor * jnp.sin(angles)[None, :, None, :]
+    x1 = x[..., 0:r:2].astype(jnp.float32)
+    x2 = x[..., 1:r:2].astype(jnp.float32)
+    out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+    out = out.reshape(*x.shape[:-1], r).astype(x.dtype)
+    return jnp.concatenate([out, x[..., r:]], axis=-1)
+
+
+def _rotation(cfg: Config, mixer: str) -> Callable:
+    """``(x, positions) -> x`` rotated as a softmax layer of kind ``mixer``
+    rotates its queries and keys: a window layer every channel at
+    ``swa_rope_theta``; a full layer the first ``rope_fraction`` of them at
+    ``rope_theta``, with YaRN's frequencies and attention factor where the
+    configuration has ``rope_yarn``."""
+    if mixer == "swa":
+        return lambda x, positions: rope(x, positions, cfg.swa_rope_theta)
+    if cfg.rope_yarn is None and cfg.rope_fraction == 1:
+        return lambda x, positions: rope(x, positions, cfg.rope_theta)
+    dim = int(cfg.head_dim * cfg.rope_fraction)
+    if cfg.rope_yarn is None:
+        inv_freq, factor = cfg.rope_theta ** (
+            -np.arange(0, dim, 2, dtype=np.float32) / dim), 1.0
+    else:
+        *yarn, factor = cfg.rope_yarn
+        inv_freq = yarn_inv_freq(dim, cfg.rope_theta, *yarn)
+    return lambda x, positions: rope_scaled(x, positions, inv_freq, factor)
+
+
 _NEG_INF = -1e30   # attention mask fill, shared by training and decode paths
 
 
-def _causal_attention(q, k, v, scale):
-    """(B, L, H, Dh) x (B, L, KV, Dh): GQA causal attention, f32 softmax."""
+def _causal_attention(q, k, v, scale, window: Optional[int] = None):
+    """(B, L, H, Dh) x (B, L, KV, Dh): GQA causal attention, f32 softmax;
+    with a ``window`` row i sees keys ``i - window < j <= i``."""
     B, L, H, Dh = q.shape
     KV = k.shape[2]
     rep = H // KV
@@ -611,6 +754,8 @@ def _causal_attention(q, k, v, scale):
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                    preferred_element_type=jnp.float32) * scale
     mask = jnp.tril(jnp.ones((L, L), bool))
+    if window is not None:
+        mask &= ~jnp.tril(jnp.ones((L, L), bool), -window)
     s = jnp.where(mask[None, None], s, _NEG_INF)
     w = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", w, v,
@@ -670,9 +815,12 @@ def _ring_attention_batched(mesh: Mesh, causal_scale,
 
 
 def _flash_attention_sharded(mesh: Optional[Mesh], heads: int,
-                             kv_heads: int) -> Callable:
+                             kv_heads: int,
+                             window: Optional[int] = None) -> Callable:
     """Causal flash attention ``(q, k, v) -> o`` for K/V at their native
-    ``kv_heads``.  On a mesh the kernel runs inside a ``shard_map`` over
+    ``kv_heads``, over the last ``window`` keys where one is given (the
+    kernels then run the band's blocks alone).  On a mesh the kernel runs
+    inside a ``shard_map`` over
     the batch (``dp``) and head (``tp``) axes: the TPU compiler refuses to
     partition a Mosaic kernel itself (``NotImplementedError: Mosaic kernels
     cannot be automatically partitioned``), and each device wants only its
@@ -692,7 +840,8 @@ def _flash_attention_sharded(mesh: Optional[Mesh], heads: int,
 
     def local(q, k, v):
         return flash_attention(q, jnp.repeat(k, rep, axis=2),
-                               jnp.repeat(v, rep, axis=2), causal=True)
+                               jnp.repeat(v, rep, axis=2), causal=True,
+                               window=window)
 
     if mesh is None or mesh.size == 1:
         return local
@@ -732,14 +881,18 @@ def _kda_sharded(mesh: Optional[Mesh], heads: int, eps: float) -> Callable:
 
 
 def _make_attn_impl(cfg: Config, attn: str, mesh: Optional[Mesh],
-                    scale: float) -> Callable:
+                    scale: float, mixer: str = "attn") -> Callable:
     """Resolve the attention mode to one callable ``(q, k, v) -> o`` with
     q (B, L, H, hd) and k/v at the native (B, L, KV, hd) — the single
-    dispatch point shared by :func:`apply` and the pipeline stages."""
-    H, KV = cfg.n_heads, cfg.n_kv_heads
+    dispatch point shared by :func:`apply` and the pipeline stages.
+    ``mixer`` ``"swa"``: a window layer's, at its own head count over the
+    last ``cfg.swa_window`` keys."""
+    H, KV = softmax_heads(cfg, mixer), cfg.n_kv_heads
+    window = cfg.swa_window if mixer == "swa" else None
     if attn in ("ring", "ring-xla", "ring-zigzag"):
         if mesh is None:
             raise ValueError("attn='ring' needs a mesh with an sp axis")
+        assert window is None, "refused before (_refuse_window)"
         # K/V enter the ring at their native n_kv_heads — the ring
         # circulates 1/(H/KV) of the bytes; blocks repeat locally.
         # Contiguous head sharding over tp keeps each rank's q heads
@@ -753,9 +906,9 @@ def _make_attn_impl(cfg: Config, attn: str, mesh: Optional[Mesh],
                 "ring-xla": "ring"}[attn]
         return _ring_attention_batched(mesh, scale, H, KV, impl=impl)
     if attn == "flash":
-        return _flash_attention_sharded(mesh, H, KV)
+        return _flash_attention_sharded(mesh, H, KV, window)
     if attn == "full":
-        return lambda q, k, v: _causal_attention(q, k, v, scale)
+        return lambda q, k, v: _causal_attention(q, k, v, scale, window)
     raise ValueError(
         f"attn must be 'full', 'flash', 'ring', 'ring-zigzag', or "
         f"'ring-xla', got {attn!r}")
@@ -1305,36 +1458,58 @@ def _attention_block(cfg: Config, lp: Params, h: jax.Array,
     its pre-norm (under ``cfg.sandwich_norm`` the branch's output is normed
     too, before the add); with ``with_kv`` also the (pre-repeat,
     native-KV-head) K/V projections.  ``mixer`` is the layer's kind:
-    ``"attn"`` the softmax attention written here, ``"kda"``
-    :func:`_kda_block` (``attn_impl`` then the recurrence), ``"mla"``
-    :func:`_mla_block` (``attn_impl`` then the one made for its scale)."""
+    ``"attn"`` the softmax attention written here, ``"swa"`` the same body
+    at a window layer's head count and rotation (``attn_impl`` then the one
+    made for its window), ``"kda"`` :func:`_kda_block` (``attn_impl`` then
+    the recurrence), ``"mla"`` :func:`_mla_block` (``attn_impl`` then the one
+    made for its scale)."""
     B, L, _ = h.shape
-    hd, H, KV = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
+    hd, H, KV = cfg.head_dim, softmax_heads(cfg, mixer), cfg.n_kv_heads
     # Names in the device program (docs/observability.md): ``attn`` (the
     # projections, ``attn.qk_norm``, rope, the attention itself, the output
-    # projection; in it ``kda``, the chunked recurrence alone, or ``mla``,
-    # the whole latent mixer), ``moe.router``/``moe.dispatch``/
+    # projection; in it ``kda``, the chunked recurrence alone, ``mla``,
+    # the whole latent mixer, ``swa``, a window layer's attention alone, or
+    # ``attn.gate``, the gate on the heads' outputs), ``moe.router``/
+    # ``moe.dispatch``/
     # ``moe.experts``/``moe.combine``/``moe.shared`` or ``ffn``, ``embed``,
     # ``final_norm``, ``exit_gate``, ``head_loss``, ``optimizer``; ``mtp``,
     # outermost, round a multi-token-prediction module's copy of those.
     # Metadata only.
-    if mixer != "attn":
+    if mixer in ("kda", "mla"):
         with jax.named_scope("attn"):
             x = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
             o = (_kda_block(cfg, lp, x, attn_impl) if mixer == "kda"
                  else _mla_block(cfg, lp, x, attn_impl, positions))
             return h + constrain(o)
+    rotate = _rotation(cfg, mixer)
     with jax.named_scope("attn"):
         x = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
         q, k = _qk_norm(cfg, lp, x @ lp["wq"], x @ lp["wk"])
-        q = rope(q.reshape(B, L, H, hd), positions, cfg.rope_theta)
-        k = rope(k.reshape(B, L, KV, hd), positions, cfg.rope_theta)
+        q = rotate(q.reshape(B, L, H, hd), positions)
+        k = rotate(k.reshape(B, L, KV, hd), positions)
         v = (x @ lp["wv"]).reshape(B, L, KV, hd)
-        o = attn_impl(q, k, v).reshape(B, L, H * hd) @ lp["wo"]
+        if mixer == "swa":
+            with jax.named_scope("swa"):
+                o = attn_impl(q, k, v)
+        else:
+            o = attn_impl(q, k, v)
+        if cfg.attn_gate:
+            o = _gate_heads(o, x, lp["wg"])
+        o = o.reshape(B, L, H * hd) @ lp["wo"]
         if cfg.sandwich_norm:
             o = rms_norm(o, lp["attn_post_norm"], cfg.norm_eps)
         h = h + constrain(o)
     return (h, (k, v)) if with_kv else h
+
+
+@jax.named_scope("attn.gate")
+def _gate_heads(o: jax.Array, x: jax.Array, wg: jax.Array) -> jax.Array:
+    """The heads' outputs ``o`` (B, L, H, hd) each times its gate, ``sigmoid(x
+    @ wg)`` of the normed layer input x (B, L, D), one number a head and
+    token, float32 (the headwise gate of "Gated Attention for Large Language
+    Models", arXiv:2505.06708)."""
+    gate = jax.nn.sigmoid((x @ wg).astype(jnp.float32))
+    return (o * gate[..., None]).astype(o.dtype)
 
 
 def _aux_zero(cfg: Config):
@@ -1629,10 +1804,22 @@ def _refuse_rotary_latent(cfg: Config, what: str, missing: str) -> None:
             "make_train_step")
 
 
+def _refuse_window(cfg: Config, what: str, missing: str) -> None:
+    if (cfg.swa_window or cfg.attn_gate or cfg.rope_yarn is not None
+            or cfg.rope_fraction != 1):
+        raise NotImplementedError(
+            f"{what} has no form yet for window layers among full ones, a "
+            f"gate on the attention output or a scaled or partial rotation "
+            f"(swa_window={cfg.swa_window}, attn_gate={cfg.attn_gate}, "
+            f"rope_fraction={cfg.rope_fraction}, rope_yarn={cfg.rope_yarn}): "
+            f"it lacks {missing}; train it with make_train_step")
+
+
 def _mixer_impls(cfg: Config, attn: str, mesh: Optional[Mesh]):
     """{mixer kind: attention callable} for the layers of ``cfg``: the
-    softmax layers' at ``head_dim ** -0.5``, the latent layers' at the scale
-    of their whole key; a KDA layer's is the layer between its projections
+    softmax layers' at ``head_dim ** -0.5`` (a window layer's at its own head
+    count and window), the latent layers' at the scale of their whole key; a
+    KDA layer's is the layer between its projections
     (:func:`_kda_sharded`)."""
     mla = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
     if attn.startswith("ring"):
@@ -1641,13 +1828,20 @@ def _mixer_impls(cfg: Config, attn: str, mesh: Optional[Mesh]):
             "rotated key part all heads share would circulate with every "
             "head's keys) and a module whose next token and target lie past "
             "a sequence shard's edge")
+        _refuse_window(
+            cfg, f"attn={attn!r}", "a ring form of the band (a sequence "
+            "shard meets its left neighbours' last swa_window keys alone) "
+            "and the gate and the scaled, partial rotation in the ring's "
+            "hand-sharded layer")
         if cfg.layer_kinds is not None:
             raise NotImplementedError(
                 "the ring kernels take one head width for q, k and v and no "
                 "recurrent state crosses sequence shards: a stack with KDA "
                 "or latent-attention layers takes attn='full' or 'flash'")
-    return {"attn": _make_attn_impl(cfg, attn, mesh,
-                                    1.0 / np.sqrt(cfg.head_dim)),
+    softmax = 1.0 / np.sqrt(cfg.head_dim)
+    return {"attn": _make_attn_impl(cfg, attn, mesh, softmax),
+            "swa": (_make_attn_impl(cfg, attn, mesh, softmax, "swa")
+                    if cfg.swa_window else None),
             "mla": (_make_attn_impl(cfg, attn, mesh, 1.0 / np.sqrt(mla))
                     if mla else None),
             "kda": _kda_sharded(mesh, cfg.kda_heads, cfg.norm_eps)}
@@ -2105,6 +2299,11 @@ def _decode_step(cfg: Config, params: Params, cache: Params,
                           "a token), the absorbed form of the query "
                           "latent's product with it, and a self-drafting "
                           "step for the module")
+    _refuse_window(cfg, "the decode step", "a rolling cache of swa_window "
+                   "positions for the window layers beside the full layers' "
+                   "(serving/kvcache.py:BlockPool accounts for one kind of "
+                   "block), the gate in the one-row path, and YaRN's "
+                   "positions past the original length")
     _refuse_runs(cfg, "the decode step", "a recurrent-state cache for the "
                  "KDA layers (a head's d x d state and the convolutions' last "
                  "taps) beside a latent cache for the others")
@@ -2179,6 +2378,9 @@ def _prefill(cfg: Config, params: Params, cache: Params,
                           "decoding with (the normed latent and the "
                           "rotated shared key part a token) and the "
                           "module's state for a first draft")
+    _refuse_window(cfg, "prefill", "a rolling cache of the last swa_window "
+                   "positions to seed the window layers' decoding with, "
+                   "beside the full layers' whole one")
     _refuse_runs(cfg, "prefill", "a latent cache (the normed latent and the "
                  "shared key part a token) and the KDA layers' final state "
                  "to seed decoding with")
@@ -2251,6 +2453,9 @@ def make_generate_fn(cfg: Config, prompt_len: int, max_new: int,
     _refuse_rotary_latent(cfg, "make_generate_fn", "the latent cache its "
                           "prefill and decode step would fill and a step "
                           "that drafts with the module and verifies")
+    _refuse_window(cfg, "make_generate_fn", "the two caches its prefill "
+                   "and decode step would fill (a rolling one of swa_window "
+                   "positions, a whole one) and the gate in the one-row path")
     _refuse_runs(cfg, "make_generate_fn", "the two caches its prefill and "
                  "decode step would fill (recurrent state, latent)")
     if prompt_len < 1 or max_new < 1:
@@ -2585,6 +2790,10 @@ def make_pp_train_step(cfg: Config, mesh: Mesh, n_microbatches: int,
                           "hands the module the state before the final "
                           "norm, and the embedding on the first and the "
                           "last stage at once")
+    _refuse_window(cfg, "make_pp_train_step", "stages whose layers differ in "
+                   "head count, window and rotation (a stage is one stacked "
+                   "scan of identical layers, its attention made once) and "
+                   "the gate in the hand-sharded layer")
     _refuse_runs(cfg, "make_pp_train_step", "a stage split by run (a stage "
                  "is one stacked scan of identical layers)")
     if cfg.n_experts:
@@ -2731,6 +2940,10 @@ def make_1f1b_train_step(cfg: Config, mesh: Mesh, n_microbatches: int,
                           "hands the module the state before the final "
                           "norm, and the embedding on the first and the "
                           "last stage at once")
+    _refuse_window(cfg, "make_1f1b_train_step", "stages whose layers differ "
+                   "in head count, window and rotation (a stage is one "
+                   "stacked scan of identical layers, its attention made "
+                   "once) and the gate in the hand-sharded layer")
     _refuse_runs(cfg, "make_1f1b_train_step", "a stage split by run (a stage "
                  "is one stacked scan of identical layers)")
     if cfg.n_experts:

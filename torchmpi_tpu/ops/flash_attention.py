@@ -12,7 +12,11 @@ hierarchy, MXU notes, scratch shapes).
 
 Causal mode predicates whole K blocks above the diagonal off with
 ``pl.when``, skipping ~half the MXU work, and the index maps name no new
-block for such a pair, so nothing is fetched for it either.
+block for such a pair, so nothing is fetched for it either.  With a
+``window`` (a row sees its own key and the ``window - 1`` before it) the
+blocks wholly left of the band go too, and the grid's inner dimension is
+only as long as the band is wide in blocks (:func:`_band_k_map`): a
+windowed layer's cost grows with ``L * window``, not ``L * L``.
 
 A training step forms each score block once in the forward pass and once in
 the backward pass: the backward is one kernel (see its section), and the
@@ -48,10 +52,19 @@ NEG_INF = -1e30
 RESIDUAL_NAMES = ("flash_o", "flash_lse")
 
 
-def _when_unmasked(causal: bool, q_start, bq: int, k_start, compute):
+def _when_unmasked(causal: bool, q_start, bq: int, k_start, compute,
+                   window: Optional[int] = None, bk: int = 0,
+                   seq_len: int = 0):
     """Run ``compute`` unless causal masking blanks the whole pair (the K
-    block lies strictly above the diagonal of the Q block)."""
-    if causal:
+    block lies strictly above the diagonal of the Q block) or, with a
+    ``window``, the ``bk`` keys all lie left of the band of its first row, or
+    the Q block, counted from a band's first, lies past the ``seq_len``
+    rows there are."""
+    if causal and window is not None:
+        pl.when((q_start + bq - 1 >= k_start)
+                & (k_start + bk - 1 > q_start - window)
+                & (q_start < seq_len))(compute)
+    elif causal:
         pl.when(q_start + bq - 1 >= k_start)(compute)
     else:
         compute()
@@ -76,10 +89,72 @@ def _unmasked_q(causal: bool, block_q: int, block_k: int, nq: int):
         qi, jnp.minimum((ki * block_k) // block_q, nq - 1))
 
 
+# A windowed call (causal, row i sees keys i - window < j <= i, q and k of one
+# length) walks the band alone: its grid's inner dimension counts blocks from
+# the band's first, which the two functions below name, and is as long as the
+# widest band of any outer block (``_band_k_map``, ``_band_q_map``).
+
+def _first_k(window: int, block_q: int, block_k: int, qi, lo=jnp.maximum):
+    """The first K block with a key some row of Q block ``qi`` sees: the
+    block of its first row's oldest key.  ``lo``: the maximum to use, jnp's
+    on a program id, Python's on a block number."""
+    return lo(qi * block_q - window + 1, 0) // block_k
+
+
+def _first_q(block_q: int, block_k: int, ki):
+    """The first Q block with a row that sees some key of K block ``ki``:
+    the block of its first key's own row."""
+    return (ki * block_k) // block_q
+
+
+def _band_k_map(window: int, block_q: int, block_k: int, nq: int):
+    """``((qi, ki) -> K block, width)`` of a windowed grid whose K side is
+    innermost: program ``ki`` of Q block ``qi`` is the band's ``ki``-th K
+    block, and past the diagonal's block that one again, so nothing is
+    fetched for a pair that does not run; ``width`` is the most K blocks any
+    Q block's band holds."""
+    last = lambda qi: (qi * block_q + block_q - 1) // block_k
+    width = max(last(i) - _first_k(window, block_q, block_k, i, max) + 1
+                for i in range(nq))
+    return (lambda qi, ki: jnp.minimum(
+        _first_k(window, block_q, block_k, qi) + ki, last(qi))), width
+
+
+def _band_q_map(window: int, block_q: int, block_k: int, nq: int, nk: int):
+    """``((ki, qi) -> Q block, width)`` of a windowed grid whose Q side is
+    innermost, as :func:`_band_k_map`: the last Q block K block ``ki``
+    meets holds the last row its last key is the oldest of."""
+    last = lambda ki, hi: hi(
+        (ki * block_k + block_k + window - 2) // block_q, nq - 1)
+    width = max(last(i, min) - _first_q(block_q, block_k, i) + 1
+                for i in range(nk))
+    return (lambda ki, qi: jnp.minimum(
+        _first_q(block_q, block_k, ki) + qi, last(ki, jnp.minimum))), width
+
+
+def _band_mask(s, q_start, k_start, window: Optional[int]):
+    """Scores ``s`` (bq, bk) of the pair at ``(q_start, k_start)`` with
+    what causal masking hides set to ``NEG_INF``: keys after the row's own
+    and, with a ``window``, keys ``window`` or more before it (one unsigned
+    comparison of row less column sees both edges: a key after the row's own
+    wraps past every window)."""
+    if window is None:
+        rows = q_start + lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        cols = k_start + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        return jnp.where(rows >= cols, s, NEG_INF)
+    back = (lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            - lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            + (q_start - k_start))                  # keys back from its own
+    return jnp.where(back.astype(jnp.uint32) < window, s, NEG_INF)
+
+
 def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
-                 causal: bool, scale: float):
+                 causal: bool, scale: float, window: Optional[int] = None,
+                 seq_len: int = 0):
     """One (batch*head, q-block, k-block) program.  Scratch (acc, m, l)
-    persists across the k dimension (innermost, sequential on TPU)."""
+    persists across the k dimension (innermost, sequential on TPU).  With a
+    ``window`` that dimension counts from the band's first K block
+    (:func:`_first_k`)."""
     bq = q_ref.shape[0]
     bk = k_ref.shape[0]
     qi = pl.program_id(1)
@@ -87,6 +162,8 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
     nk = pl.num_programs(2)
     q_start = qi * bq
     k_start = ki * bk
+    if window is not None:
+        k_start = (_first_k(window, bq, bk, qi) + ki) * bk
 
     @pl.when(ki == 0)
     def _init():
@@ -101,13 +178,16 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         if causal:
-            rows = q_start + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            cols = k_start + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            s = jnp.where(rows >= cols, s, NEG_INF)
+            s = _band_mask(s, q_start, k_start, window)
         m_prev = m_ref[:, 0]
         l_prev = l_ref[:, 0]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        if window is None:
+            p = jnp.exp(s - m_new[:, None])
+        else:
+            # A row may see no key of the band's first block: its max is
+            # still NEG_INF there, and exp(NEG_INF - NEG_INF) would be 1.
+            p = jnp.exp(s - jnp.where(m_new > NEG_INF, m_new, 0.0)[:, None])
         corr = jnp.exp(m_prev - m_new)
         l_ref[:, 0] = l_prev * corr + jnp.sum(p, axis=1)
         m_ref[:, 0] = m_new
@@ -115,7 +195,8 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
                          + jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
                                                preferred_element_type=jnp.float32))
 
-    _when_unmasked(causal, q_start, bq, k_start, _compute)
+    _when_unmasked(causal, q_start, bq, k_start, _compute, window, bk,
+                   seq_len)
 
     @pl.when(ki == nk - 1)
     def _finalize():
@@ -128,7 +209,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
 
 def _flash_bh(qbh, kbh, vbh, *, causal: bool, block_q: int, block_k: int,
               interpret: bool, scale: Optional[float] = None,
-              out_dtype=None):
+              out_dtype=None, window: Optional[int] = None):
     """(BH, L, D) flash attention forward; returns (o, lse).
 
     ``kbh``/``vbh`` may have a different sequence length than ``qbh`` (the
@@ -137,6 +218,7 @@ def _flash_bh(qbh, kbh, vbh, *, causal: bool, block_q: int, block_k: int,
     (latent attention's 192 and 128; no operand is padded to the other's).
     ``out_dtype`` overrides the output dtype (the ring carries its partial
     outputs in f32 across steps so per-step rounding doesn't accumulate).
+    ``window`` (causal, ``Lk == L``): the band alone, see the module's text.
     """
     BH, L, D = qbh.shape
     Lk, Dv = vbh.shape[1:]
@@ -146,6 +228,10 @@ def _flash_bh(qbh, kbh, vbh, *, causal: bool, block_q: int, block_k: int,
     grid = (BH, L // block_q, Lk // block_k)
     kernel = functools.partial(_attn_kernel, causal=causal, scale=scale)
     k_of = _unmasked_k(causal, block_q, block_k, grid[2])
+    if window is not None:
+        k_of, width = _band_k_map(window, block_q, block_k, grid[1])
+        grid = (BH, grid[1], width)
+        kernel = functools.partial(kernel, window=window, seq_len=L)
     kv_block = lambda d: pl.BlockSpec((None, block_k, d),
                                       lambda b, qi, ki: (b, k_of(qi, ki), 0))
     return pl.pallas_call(
@@ -229,10 +315,10 @@ def _bwd_vmem_bytes(block_q: int, block_k: int, D: int, in_dtype,
 
 
 def _bwd_pair(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, q_start,
-              k_start, *, causal: bool, scale: float):
+              k_start, *, causal: bool, scale: float,
+              window: Optional[int] = None):
     """One (q-block, k-block) pair of the backward: the float32 operands and
     the blocks ``p`` and ``ds`` (bq, bk) every gradient is a product of."""
-    bq, bk = q_ref.shape[0], k_ref.shape[0]
     q = q_ref[:, :].astype(jnp.float32)
     k = k_ref[:, :].astype(jnp.float32)
     v = v_ref[:, :].astype(jnp.float32)
@@ -240,9 +326,7 @@ def _bwd_pair(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, q_start,
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     if causal:
-        rows = q_start + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-        cols = k_start + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        s = jnp.where(rows >= cols, s, NEG_INF)
+        s = _band_mask(s, q_start, k_start, window)
     p = jnp.exp(s - lse_ref[:, 0][:, None])
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
@@ -252,10 +336,12 @@ def _bwd_pair(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, q_start,
 
 def _attn_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                      dq_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
-                     causal: bool, scale: float):
+                     causal: bool, scale: float,
+                     window: Optional[int] = None, seq_len: int = 0):
     """One (batch*head, k-block, q-block) program of ``flash_bwd``.
     ``dq_ref`` is the whole (Lq, D) of this batch*head; with ``dq_ref``
-    None the program is ``flash_bwd_dkv``'s."""
+    None the program is ``flash_bwd_dkv``'s.  With a ``window`` the q
+    dimension counts from the band's first Q block (:func:`_first_q`)."""
     bq = q_ref.shape[0]
     bk = k_ref.shape[0]
     ki = pl.program_id(1)
@@ -263,6 +349,8 @@ def _attn_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     nq = pl.num_programs(2)
     q_start = qi * bq
     k_start = ki * bk
+    if window is not None:
+        q_start = (_first_q(bq, bk, ki) + qi) * bq
 
     if dq_ref is not None:
         @pl.when((ki == 0) & (qi == 0))
@@ -277,7 +365,7 @@ def _attn_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _compute():
         q, k, do, p, ds = _bwd_pair(q_ref, k_ref, v_ref, do_ref, lse_ref,
                                     delta_ref, q_start, k_start,
-                                    causal=causal, scale=scale)
+                                    causal=causal, scale=scale, window=window)
         dv_acc[:, :] += jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)                    # p^T @ do
@@ -291,7 +379,8 @@ def _attn_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 preferred_element_type=jnp.float32)                # ds @ k
 
     # Skip Q blocks wholly above the diagonal for this K block.
-    _when_unmasked(causal, q_start, bq, k_start, _compute)
+    _when_unmasked(causal, q_start, bq, k_start, _compute, window, bk,
+                   seq_len)
 
     @pl.when(qi == nq - 1)
     def _finalize():
@@ -300,7 +389,8 @@ def _attn_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _attn_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                        dq_ref, acc_ref, *, causal: bool, scale: float):
+                        dq_ref, acc_ref, *, causal: bool, scale: float,
+                        window: Optional[int] = None, seq_len: int = 0):
     bq = q_ref.shape[0]
     bk = k_ref.shape[0]
     qi = pl.program_id(1)
@@ -308,6 +398,8 @@ def _attn_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     nk = pl.num_programs(2)
     q_start = qi * bq
     k_start = ki * bk
+    if window is not None:
+        k_start = (_first_k(window, bq, bk, qi) + ki) * bk
 
     @pl.when(ki == 0)
     def _init():
@@ -316,12 +408,13 @@ def _attn_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _compute():
         _, k, _, _, ds = _bwd_pair(q_ref, k_ref, v_ref, do_ref, lse_ref,
                                    delta_ref, q_start, k_start,
-                                   causal=causal, scale=scale)
+                                   causal=causal, scale=scale, window=window)
         acc_ref[:, :] += jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    _when_unmasked(causal, q_start, bq, k_start, _compute)
+    _when_unmasked(causal, q_start, bq, k_start, _compute, window, bk,
+                   seq_len)
 
     @pl.when(ki == nk - 1)
     def _finalize():
@@ -337,7 +430,8 @@ def _attn_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _flash_bh_bwd(qbh, kbh, vbh, dobh, lse, delta, *, causal: bool,
                   block_q: int, block_k: int, interpret: bool,
                   scale: Optional[float] = None, out_dtype=None,
-                  vmem_budget: int = _VMEM_BUDGET):
+                  vmem_budget: int = _VMEM_BUDGET,
+                  window: Optional[int] = None):
     """Backward against an externally-supplied (lse, delta).
 
     For single-chip flash, lse/delta come from this call's own forward; the
@@ -349,7 +443,8 @@ def _flash_bh_bwd(qbh, kbh, vbh, dobh, lse, delta, *, causal: bool,
 
     One ``flash_bwd`` kernel where its resident dq block fits
     ``vmem_budget`` (read from the shapes, see the section comment), else
-    the two streaming kernels."""
+    the two streaming kernels.  With a ``window`` every grid's inner
+    dimension is the band's (the module's text)."""
     BH, L, D = qbh.shape
     Lk, Dv = vbh.shape[1:]
     if scale is None:
@@ -362,7 +457,16 @@ def _flash_bh_bwd(qbh, kbh, vbh, dobh, lse, delta, *, causal: bool,
                    pltpu.VMEM((block_k, Dv), jnp.float32)]
 
     # Grid (BH, k-blocks, q-blocks): flash_bwd and flash_bwd_dkv.
-    q_of = _unmasked_q(causal, block_q, block_k, L // block_q)
+    nq, nk = L // block_q, Lk // block_k
+    static = dict(causal=causal, scale=scale)
+    q_of, k_of = (_unmasked_q(causal, block_q, block_k, nq),
+                  _unmasked_k(causal, block_q, block_k, nk))
+    grid_q, grid_k = (BH, nk, nq), (BH, nq, nk)     # the innermost side's
+    if window is not None:
+        static.update(window=window, seq_len=L)
+        q_of, q_width = _band_q_map(window, block_q, block_k, nq, nk)
+        k_of, k_width = _band_k_map(window, block_q, block_k, nq)
+        grid_q, grid_k = (BH, nk, q_width), (BH, nq, k_width)
     q_block2 = lambda d: pl.BlockSpec(
         (None, block_q, d), lambda b, ki, qi: (b, q_of(ki, qi), 0))
     k_block2 = lambda d: pl.BlockSpec(
@@ -379,10 +483,10 @@ def _flash_bh_bwd(qbh, kbh, vbh, dobh, lse, delta, *, causal: bool,
     fused_vmem = stream_vmem + 2 * L * _lanes(D) * 4
     if fused_vmem <= vmem_budget:
         dq, dk, dv = pl.pallas_call(
-            functools.partial(_attn_bwd_kernel, causal=causal, scale=scale),
+            functools.partial(_attn_bwd_kernel, **static),
             out_shape=(jax.ShapeDtypeStruct((BH, L, D), jnp.float32),
                        *dkv_shape),
-            grid=(BH, Lk // block_k, L // block_q),
+            grid=grid_q,
             in_specs=in_specs2,
             out_specs=(pl.BlockSpec((None, L, D), lambda b, ki, qi: (b, 0, 0)),
                        *dkv_specs2),
@@ -395,16 +499,15 @@ def _flash_bh_bwd(qbh, kbh, vbh, dobh, lse, delta, *, causal: bool,
         return dq.astype(dq_dtype), dk, dv
 
     streaming = pltpu.CompilerParams(vmem_limit_bytes=stream_vmem)
-    k_of = _unmasked_k(causal, block_q, block_k, Lk // block_k)
     q_block = lambda d: pl.BlockSpec((None, block_q, d),
                                      lambda b, qi, ki: (b, qi, 0))
     k_block = lambda d: pl.BlockSpec((None, block_k, d),
                                      lambda b, qi, ki: (b, k_of(qi, ki), 0))
     qrow = q_block(1)
     dq = pl.pallas_call(
-        functools.partial(_attn_bwd_dq_kernel, causal=causal, scale=scale),
+        functools.partial(_attn_bwd_dq_kernel, **static),
         out_shape=jax.ShapeDtypeStruct((BH, L, D), dq_dtype),
-        grid=(BH, L // block_q, Lk // block_k),
+        grid=grid_k,
         in_specs=[q_block(D), k_block(D), k_block(Dv), q_block(Dv), qrow,
                   qrow],
         out_specs=q_block(D),
@@ -414,9 +517,9 @@ def _flash_bh_bwd(qbh, kbh, vbh, dobh, lse, delta, *, causal: bool,
         name="flash_bwd_dq",
     )(*args)
     dk, dv = pl.pallas_call(
-        functools.partial(_attn_bwd_dkv_kernel, causal=causal, scale=scale),
+        functools.partial(_attn_bwd_dkv_kernel, **static),
         out_shape=dkv_shape,
-        grid=(BH, Lk // block_k, L // block_q),
+        grid=grid_q,
         in_specs=in_specs2,
         out_specs=dkv_specs2,
         scratch_shapes=dkv_scratch,
@@ -427,30 +530,34 @@ def _flash_bh_bwd(qbh, kbh, vbh, dobh, lse, delta, *, causal: bool,
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4))
-def _flash_core(causal, block_q, block_k, interpret, scale, qbh, kbh, vbh):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4, 5))
+def _flash_core(causal, block_q, block_k, interpret, scale, window,
+                qbh, kbh, vbh):
     o, _ = _flash_bh(qbh, kbh, vbh, causal=causal, block_q=block_q,
-                     block_k=block_k, interpret=interpret, scale=scale)
+                     block_k=block_k, interpret=interpret, scale=scale,
+                     window=window)
     return o
 
 
-def _flash_core_fwd(causal, block_q, block_k, interpret, scale,
+def _flash_core_fwd(causal, block_q, block_k, interpret, scale, window,
                     qbh, kbh, vbh):
     o, lse = _flash_bh(qbh, kbh, vbh, causal=causal, block_q=block_q,
-                       block_k=block_k, interpret=interpret, scale=scale)
+                       block_k=block_k, interpret=interpret, scale=scale,
+                       window=window)
     o = checkpoint_name(o, RESIDUAL_NAMES[0])
     lse = checkpoint_name(lse, RESIDUAL_NAMES[1])
     return o, (qbh, kbh, vbh, o, lse)
 
 
-def _flash_core_bwd(causal, block_q, block_k, interpret, scale, res, dobh):
+def _flash_core_bwd(causal, block_q, block_k, interpret, scale, window, res,
+                    dobh):
     qbh, kbh, vbh, obh, lse = res
     # delta_i = rowsum(do_i * o_i): tiny (BH, L) f32, computed outside Pallas.
     delta = jnp.sum(dobh.astype(jnp.float32) * obh.astype(jnp.float32),
                     axis=-1, keepdims=True)                    # (BH, L, 1)
     return _flash_bh_bwd(qbh, kbh, vbh, dobh, lse, delta, causal=causal,
                          block_q=block_q, block_k=block_k,
-                         interpret=interpret, scale=scale)
+                         interpret=interpret, scale=scale, window=window)
 
 
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
@@ -476,6 +583,49 @@ def _auto_block(L: int, cap: int = 1024) -> int:
     return b
 
 
+def _window_block(L: int, window: Optional[int]) -> int:
+    """Tile size of a causal call, from the length and the window alone;
+    without a window, :func:`_auto_block`'s.  A
+    pair of ``b``-row blocks is executed whole, so a Q block's band of
+    ``window + b - 1`` keys costs ``ceil((window - 1) / b) + 1`` blocks:
+    at a 512-key window 4 times the band's scores at 1024, 2 at 512, 1.5 at
+    256, on tiles that run the slower the smaller they are (``_auto_block``).
+    The largest power of two up to the window, within [256, 1024] and
+    dividing ``L``.  Measured on v5e at ``L`` = 16,384, ``window`` 512, 72
+    heads of 128, forward and backward: 39.0 ms at 256, 29.9 at 512, 38.6 at
+    1024, where the causal kernels take 128.2 (PERF.md section 6, PR 40)."""
+    if window is None:
+        return _auto_block(L)
+    if L <= 256:
+        return L
+    b = 256
+    while b * 2 <= min(window, 1024) and L % (b * 2) == 0:
+        b *= 2
+    return b if L % b == 0 else _auto_block(L)
+
+
+def blocks_met(L: int, window: Optional[int] = None) -> dict:
+    """What a causal call of :func:`flash_attention` at length ``L`` runs,
+    from its shapes: the ``tile`` it chooses, its ``q_blocks``, the forward
+    grid's inner dimension (``grid_inner``) and the K blocks a Q block meets,
+    the most any does and the mean (``k_blocks_max``, ``k_blocks_mean``),
+    counted as the distinct blocks the kernel's own index map names over
+    that dimension: a pair it names no new block for is neither fetched nor
+    run.  A counter for outside the step."""
+    if window is not None and window >= L:
+        window = None
+    b = _window_block(L, window)
+    n = L // b
+    if window is None:
+        k_of, inner = _unmasked_k(True, b, b, n), n
+    else:
+        k_of, inner = _band_k_map(window, b, b, n)
+    named = np.asarray(k_of(np.arange(n)[:, None], np.arange(inner)[None]))
+    met = [len(set(row)) for row in named.tolist()]
+    return {"tile": b, "q_blocks": n, "grid_inner": inner,
+            "k_blocks_max": max(met), "k_blocks_mean": float(np.mean(met))}
+
+
 def flash_attention(
     q: jax.Array, k: jax.Array, v: jax.Array,
     causal: bool = False,
@@ -483,9 +633,14 @@ def flash_attention(
     block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
     scale: Optional[float] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Blocked attention, (B, L, H, D) layout (GQA: repeat K/V first); v
     may be (B, L, H, Dv) with a width of its own, and o then has it too.
+    ``window`` (with ``causal``): row i sees keys ``i - window < j <= i``,
+    its own and the ``window - 1`` before it; the kernels run the blocks
+    that hold such a pair and fetch no other, and the tile comes from
+    ``(L, window)`` (:func:`_window_block`).  None: every key up to its own.
 
     Differentiable: a ``custom_vjp`` pairs the forward with a
     FlashAttention-2 style backward Pallas kernel (``flash_bwd``: all three
@@ -501,8 +656,16 @@ def flash_attention(
     if k.shape != q.shape or v.shape != (B, L, H, Dv):
         raise ValueError("q and k must share (B, L, H, D) and v be (B, L, H, "
                          "Dv); repeat GQA KV first")
-    block_q = _auto_block(L) if block_q is None else min(block_q, L)
-    block_k = _auto_block(L) if block_k is None else min(block_k, L)
+    if window is not None and (not causal or window < 1):
+        raise ValueError("a window is the causal band's width in keys, the "
+                         f"row's own among them: causal=True and window >= 1 "
+                         f"(got causal={causal}, window={window})")
+    if window is not None and window >= L:
+        window = None               # every row sees all it may: plain causal
+    block_q = (_window_block(L, window) if block_q is None
+               else min(block_q, L))
+    block_k = (_window_block(L, window) if block_k is None
+               else min(block_k, L))
     if L % block_q or L % block_k:
         raise ValueError(f"seq len {L} not divisible by blocks "
                          f"({block_q}, {block_k})")
@@ -514,7 +677,7 @@ def flash_attention(
     kbh = k.transpose(0, 2, 1, 3).reshape(B * H, L, D)
     vbh = v.transpose(0, 2, 1, 3).reshape(B * H, L, Dv)
     obh = _flash_core(causal, block_q, block_k, interpret,
-                      None if scale is None else float(scale),
+                      None if scale is None else float(scale), window,
                       qbh, kbh, vbh)
     return obh.reshape(B, H, L, Dv).transpose(0, 2, 1, 3)
 
