@@ -211,6 +211,19 @@ def test_llama_flash_step_on_dp_tp(v5e, monkeypatch):
     fn, args = topology._build_llama_dp_tp("v5e-4", attn="flash")
     compiled = jax.jit(fn).lower(*args).compile()
     assert _kernels(compiled) > 0
+    # `tiny`'s 4 heads over 2 K/V heads, split over tp at BOTH counts: a
+    # device's kernels take its 2 query heads and its 1 K/V head, which the
+    # two share by the kernels' index maps, and `flash_bwd` gives dk and dv
+    # at that one head (no repeat stands in the shard_map's body).
+    calls = [line for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             and "flash_" in line]
+    heads = lambda line: [int(n) for n in re.findall(
+        r"(?:bf16|f32)\[(\d+),\d+,16\]",
+        line.split("operand_layout_constraints")[1])]
+    assert calls and all(heads(line)[:3] == [2 * heads(line)[1],
+                                            heads(line)[1], heads(line)[2]]
+                         for line in calls)
     # The chunked head under GSPMD (head columns over tp, rows over dp): the
     # program with a checkpointed chunk held 11 all-reduces of 255,364 bytes,
     # four in a chunk (max and target logit, then max and sum again in the
@@ -602,9 +615,9 @@ def test_glm_flash_adamw_step_at_published_widths(v5e, monkeypatch):
     of 64 routed experts a layer held here beside the shared one, 19,360 rows
     of the vocabulary (151.25 tiles of 128), 1 x 16,384 tokens, flash at
     heads of 256, the configuration file's remat, AdamW with float32 moments,
-    weights and state donated.  It fits the chip, not by much: the
-    compiler's own peak is 15.39 GB of 16.91 (15.75 GiB), where `"dots"` is
-    refused by 58 MB.  Two flash kernels for each of the six latent layers,
+    weights and state donated.  The compiler's own peak is 13.05 GB of 16.91
+    (15.75 GiB) since PR 41 (15.39 before it, where `"dots"` was refused by 58
+    MB).  Two flash kernels for each of the six latent layers,
     the module's under `mtp`, none replayed, and eleven grouped matmuls for
     each of the five expert layers, as in the Kimi Linear step."""
     import json
@@ -665,35 +678,48 @@ def test_glm_flash_adamw_step_at_published_widths(v5e, monkeypatch):
     assert (named("flash_fwd", module), named("flash_bwd", module)) == (1, 1)
     assert len(kernels) == 6 * 2 + 5 * 11 and len(module) == 2 + 11
     peak = program.memory_analysis().peak_memory_in_bytes
-    assert 14.5e9 < peak < 15.75 * 2 ** 30
+    # 15.39 GB until PR 41, whose rotation (`llama._rotate_pairs`) leaves
+    # the compiler no sequence-on-the-lanes copies of q and k to keep.
+    assert 12.0e9 < peak < 14.0e9
 
 
 @pytest.mark.parametrize("tile", [256, 512, 1024])
 def test_windowed_flash_at_laguna_widths(v5e, tile):
-    """A Laguna-S-2.1 sliding layer's attention, (1, 16384, 72, 128) bf16
-    over a 512-key window, at the tile the program chooses (512) and at its
-    neighbours: one forward kernel and the ONE backward kernel, whose grids
-    walk the band's blocks alone (3, 2 and 2 of them a Q block where the
-    causal grid has 64, 32 and 16), inside the chip's VMEM."""
-    from torchmpi_tpu.ops.flash_attention import blocks_met
+    """A Laguna-S-2.1 sliding layer's attention, q (1, 16384, 72, 128) with
+    K and V at the layer's 8 heads, bf16 over a 512-key window, at the tile
+    the program chooses (512) and at its neighbours: one forward kernel and
+    the ONE backward kernel, whose grids walk the band's blocks alone (3, 2
+    and 2 of them a Q block where the causal grid has 64, 32 and 16), inside
+    the chip's VMEM with the group's float32 dk and dv of the whole (L, 128)
+    beside dq's (three blocks in both buffers).  K and V are read where they
+    are, nine query heads a head: the same two kernels as with K and V
+    repeated to 72 heads first (the form before PR 41), and a compiler's
+    peak of 2.99 GB where that form's is 4.06 (compiled here at PR 41, every
+    tile)."""
+    from torchmpi_tpu.ops.flash_attention import blocks_met, operand_plan
 
-    L, heads, width, window = 16384, 72, 128, 512
+    L, heads, kv_heads, width, window = 16384, 72, 8, 128, 512
     assert blocks_met(L, window)["tile"] == 512
-    x = _sds((1, L, heads, width), jnp.bfloat16, SingleDeviceSharding(v5e[0]))
+    assert operand_plan(1, L, heads, kv_heads, width, width,
+                        window=window)["dkv_in_kernel"]
+    one = SingleDeviceSharding(v5e[0])
+    x = _sds((1, L, heads, width), jnp.bfloat16, one)
+    kv = _sds((1, L, kv_heads, width), jnp.bfloat16, one)
 
     def loss(q, k, v):
         return jnp.sum(flash_attention(q, k, v, causal=True, interpret=False,
                                        window=window, block_q=tile,
                                        block_k=tile).astype(jnp.float32))
 
-    grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(x, x, x).compile()
+    grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(x, kv, kv).compile()
     text = grad.as_text()
     assert _kernels(grad) == 2
     assert "flash_fwd" in text and "flash_bwd" in text
     assert "flash_bwd_dq" not in text and "flash_bwd_dkv" not in text
     (_, fwd_used), (stated, used) = _kernel_vmem(grad)
     assert fwd_used < 16 * 1024 * 1024                  # the default limit
-    assert 2 * L * width * 4 < used <= stated < V5E_VMEM_BYTES
+    assert 3 * 2 * L * width * 4 < used <= stated < V5E_VMEM_BYTES
+    assert grad.memory_analysis().peak_memory_in_bytes < 0.8 * 4.06e9
 
 
 def test_laguna_adamw_step_at_published_widths(v5e, monkeypatch):
@@ -754,6 +780,14 @@ def test_laguna_adamw_step_at_published_widths(v5e, monkeypatch):
     window = [line for line in kernels if "/swa/" in line]
     assert (named("flash_fwd", window), named("flash_bwd", window)) == (3, 3)
     assert len(window) == 6
-    assert len(kernels) == 5 * 2 + 4 * 11
+    assert len(kernels) == 5 * 2 + 4 * 11               # as before PR 41
+    for line in kernels:
+        if "flash_" in line:      # q's 72 or 48 heads, K and V at their 8
+            heads = [int(n) for n in re.findall(
+                r"bf16\[(\d+),16384,128\]",
+                line.split("operand_layout_constraints")[1])]
+            assert heads[0] in (48, 72) and heads[1:3] == [8, 8]
+    # Under the 14.09 GB of the step with K and V repeated and the sliced
+    # rotation (compiled here at PR 40 and again at PR 41): 13.06 GB.
     peak = program.memory_analysis().peak_memory_in_bytes
-    assert 13.5e9 < peak < 15.0e9
+    assert 12.0e9 < peak < 14.0e9

@@ -21,7 +21,8 @@ import jax.numpy as jnp
 from torchmpi_tpu.models import llama
 from torchmpi_tpu.ops.flash_attention import (_band_k_map, _band_q_map,
                                               _flash_bh, _flash_bh_bwd,
-                                              blocks_met, flash_attention)
+                                              blocks_met, flash_attention,
+                                              operand_plan)
 from torchmpi_tpu.parallel import mesh as pmesh
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -110,14 +111,18 @@ def plain(five, reference, sample):
         params, sample)
 
 
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, those of its sub-jaxprs after their own."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
 def _kernels(jaxpr, found):
     """Names of a jaxpr's ``pallas_call`` s, in order, sub-jaxprs included."""
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            found.append(eqn.params["name"])
-            continue
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            _kernels(sub, found)
+    found += [eqn.params["name"] for eqn in _eqns(jaxpr)
+              if eqn.primitive.name == "pallas_call"]
     return found
 
 
@@ -202,6 +207,99 @@ def test_without_a_window_the_kernels_are_what_they_were(qkv):
     assert text() != text(window=64)
     with pytest.raises(ValueError, match="causal"):
         flash_attention(*qkv, causal=False, window=8)
+
+
+@pytest.mark.parametrize("shapes,want", [
+    # a Laguna sliding layer and a full one: the group's sums in the kernel
+    ((1, 16384, 72, 8, 128, 128, jnp.bfloat16, 512),
+     dict(kv_repeat=9, dkv_in_kernel=True, repeated_bytes=0,
+          copied_bytes=2 * 80 * 16384 * 256 * 2)),
+    ((1, 16384, 48, 8, 128, 128),
+     dict(kv_repeat=6, dkv_in_kernel=True, repeated_bytes=0,
+          copied_bytes=2 * 56 * 16384 * 256 * 2)),
+    # 64k rows: dq alone fits, dk and dv are written a query head and summed
+    ((1, 65536, 32, 8, 128, 128),
+     dict(kv_repeat=4, dkv_in_kernel=False,
+          repeated_bytes=24 * 65536 * 256 * 2)),
+    # every head its own K/V: Kimi Linear's latent layer, GLM's, Ouro
+    ((1, 16384, 32, 32, 192, 128),
+     dict(kv_repeat=1, dkv_in_kernel=False, repeated_bytes=0,
+          copied_bytes=4 * 32 * 16384 * 320 * 2)),
+    ((1, 16384, 20, 20, 256, 256), dict(kv_repeat=1, repeated_bytes=0)),
+    ((2, 4096, 16, 16, 128, 128, jnp.float32),
+     dict(kv_repeat=1, copied_bytes=4 * 16 * 2 * 4096 * 256 * 4))])
+def test_what_a_call_moves_for_layout_alone(shapes, want):
+    """``operand_plan``, from shapes: nothing is repeated to the query heads
+    while a group's float32 dk and dv fit ``flash_bwd``'s VMEM; before PR 41
+    a sliding layer's call repeated K and V and summed dk and dv at 72 heads,
+    1,074 MB."""
+    plan = operand_plan(*shapes)
+    assert sorted(plan) == ["copied_bytes", "dkv_in_kernel", "kv_repeat",
+                            "repeated_bytes"]
+    assert {name: plan[name] for name in want} == want
+    assert 2 * (72 - 8) * 16384 * 256 * 2 == 1_073_741_824
+
+
+def test_the_steps_kernels_read_kv_at_their_own_heads(five, sample):
+    """The five-layer step's jaxpr: every flash kernel, forward and
+    backward, of a full layer (4 heads) and of a window layer (6) takes K
+    and V at the 2 K/V heads, ``flash_bwd`` gives dk and dv there, and
+    nothing between the projections and a kernel is broadcast to q's
+    size."""
+    cfg, params = five
+    mesh = pmesh.make_mesh({"dp": 1}, devices=jax.devices()[:1])
+    step = llama.make_train_step(cfg, mesh, attn="flash", remat="full",
+                                 loss_chunk=32)
+    B, L = sample[0].shape
+    found = list(_eqns(jax.make_jaxpr(step)(params, None, *sample).jaxpr))
+    calls = [e for e in found if e.primitive.name == "pallas_call"
+             and str(e.params["name"]).startswith("flash")]
+    assert len(calls) == 2 * cfg.n_layers
+    for call in calls:
+        heads = [x.aval.shape[0] for x in call.invars[:3]]
+        assert heads in ([B * 4, B * 2, B * 2], [B * 6, B * 2, B * 2])
+        if call.params["name"] == "flash_bwd":
+            assert [x.aval.shape[0] for x in call.outvars] == heads
+    q_size = B * L * 4 * cfg.head_dim
+    assert not [e for e in found if e.primitive.name == "broadcast_in_dim"
+                and e.outvars[0].aval.shape[-1] == cfg.head_dim
+                and e.outvars[0].aval.size >= q_size]
+
+
+def test_the_rotation_is_the_sliced_form_to_the_bit():
+    """``rope`` and ``rope_scaled`` meet a pair's partner through a product
+    with a 0/1/-1 matrix (``llama._rotate_pairs``); unjitted, every value
+    and every float32 gradient is that of slicing the channels at a stride
+    of two, in float32 and bfloat16."""
+    def sliced(x, positions, inv_freq, factor=1.0):
+        r = 2 * len(inv_freq)
+        angles = positions[:, None].astype(jnp.float32) * jnp.asarray(inv_freq)
+        cos = factor * jnp.cos(angles)[None, :, None, :]
+        sin = factor * jnp.sin(angles)[None, :, None, :]
+        x1 = x[..., 0:r:2].astype(jnp.float32)
+        x2 = x[..., 1:r:2].astype(jnp.float32)
+        out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+        out = out.reshape(*x.shape[:-1], r).astype(x.dtype)
+        return jnp.concatenate([out, x[..., r:]], axis=-1)
+
+    positions = jnp.arange(48) * 37
+    for dtype, shape in ((jnp.float32, (2, 48, 6, 128)),
+                         (jnp.bfloat16, (1, 48, 3, 128)),
+                         (jnp.bfloat16, (2, 48, 1, 64))):
+        x = jax.random.normal(jax.random.PRNGKey(3), shape, dtype)
+        d = shape[-1]
+        whole = 1.0 / (10000.0 ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+        np.testing.assert_array_equal(
+            llama.rope(x, positions, 10000.0), sliced(x, positions, whole))
+        half = llama.yarn_inv_freq(d // 2, 500000.0, *YARN[:-1])
+        np.testing.assert_array_equal(
+            llama.rope_scaled(x, positions, half, YARN[-1]),
+            sliced(x, positions, half, YARN[-1]))
+        if dtype == jnp.float32:
+            grad = lambda f: jax.grad(lambda x: jnp.sum(jnp.sin(f(x))))(x)
+            np.testing.assert_array_equal(
+                grad(lambda x: llama.rope(x, positions, 10000.0)),
+                grad(lambda x: sliced(x, positions, whole)))
 
 
 @pytest.mark.parametrize("L,window,tile,inner,most", [

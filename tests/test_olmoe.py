@@ -226,8 +226,11 @@ def test_every_routed_unit_is_counted(weights, sample):
 # The loss and the gradient norm of `moe_tiny` configurations at the parent
 # commit of the PR that brought the dropless path (float32 on the CPU, the
 # seeds below): Mixtral-style routing takes the path it took, bit for bit.
-PINNED = {(4, 2): ("0x1.8366820000000p+2", "0x1.7e7bf80000000p+3"),
-          (4, 1): ("0x1.8a8c340000000p+2", "0x1.215e060000000p+3"),
+# Since PR 41 the rotation's products are summed in another order under the
+# CPU compiler (`llama._rotate_pairs`; `tests/test_ouro.py` says how): two
+# of the six numbers moved by one bit, pinned again at that PR.
+PINNED = {(4, 2): ("0x1.8366820000000p+2", "0x1.7e7bf60000000p+3"),
+          (4, 1): ("0x1.8a8c320000000p+2", "0x1.215e060000000p+3"),
           (8, 2): ("0x1.846f020000000p+2", "0x1.7a6d480000000p+3")}
 
 

@@ -13,7 +13,8 @@ import jax.numpy as jnp
 
 from torchmpi_tpu.ops import flash_attention
 from torchmpi_tpu.ops.flash_attention import (
-    _flash_bh_bwd, _bwd_vmem_bytes, flash_bwd_block, flash_fwd_block)
+    _bwd_form, _flash_bh, _flash_bh_bwd, _bwd_vmem_bytes, flash_bwd_block,
+    flash_fwd_block)
 from torchmpi_tpu.parallel import sequence as seq
 
 
@@ -206,6 +207,122 @@ class TestFlashBackward:
 
         assert calls(need) == ["flash_bwd"]
         assert calls(need - 1) == ["flash_bwd_dq", "flash_bwd_dkv"]
+
+
+# ------------------------------------------------------------ grouped queries
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, sub-jaxprs (custom_vjp, pjit) included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def _bf16_ulp(x):
+    """The spacing of bfloat16 at the magnitude of float32 ``x``."""
+    return 2.0 ** (jnp.floor(jnp.log2(jnp.maximum(jnp.abs(x), 1e-30))) - 7)
+
+
+# H, KV, D, Dv, window, B: the Laguna layers' 72 and 48 heads over 8 with and
+# without a window, every head its own K/V (Ouro, OLMoE; GLM's 256-wide), a
+# latent layer's 192 and 128, alone and grouped, and a batch of 2.
+GROUPED = [(72, 8, 128, 128, 24, 1), (72, 8, 128, 128, None, 1),
+           (48, 8, 128, 128, 24, 1), (48, 8, 128, 128, None, 2),
+           (16, 16, 128, 128, None, 2), (20, 20, 256, 256, None, 1),
+           (4, 4, 192, 128, None, 2), (8, 2, 192, 128, 24, 2)]
+
+
+@pytest.mark.parametrize("H,KV,D,Dv,window,B", GROUPED)
+def test_kv_at_their_own_heads_against_kv_repeated(H, KV, D, Dv, window, B):
+    """``flash_attention`` with K and V at ``KV`` heads, bfloat16, against
+    the same kernels fed K and V repeated to the query heads (the form
+    before PR 41): o and dq equal to the bit; dk and dv within one bfloat16
+    ulp of the float32 sum over a group of the gradients a query head (equal
+    to the bit where every head has its own); and the step's jaxpr holds the
+    two kernels, their K and V operands at ``B * KV`` heads, and no
+    broadcast of anything to q's size."""
+    L, rep = 64, H // KV
+    rng = np.random.RandomState(H + D)
+    q, k, v, do = (jnp.asarray(rng.randn(B, L, h, d), jnp.bfloat16)
+                   for h, d in ((H, D), (KV, D), (KV, Dv), (H, Dv)))
+    ours = lambda q, k, v: flash_attention(
+        q, k, v, causal=True, window=window, block_q=32, block_k=32)
+    both = lambda q, k, v, do: (lambda o, vjp: (o, *vjp(do)))(
+        *jax.vjp(ours, q, k, v))
+    o, dq, dk, dv = jax.jit(both)(q, k, v, do)
+    assert o.shape == (B, L, H, Dv) and dk.shape == k.shape
+
+    bh = lambda x: jnp.repeat(x, H // x.shape[2], axis=2).transpose(
+        0, 2, 1, 3).reshape(B * H, L, x.shape[3])
+    back = lambda x: x.reshape(B, H, L, -1).transpose(0, 2, 1, 3)
+    how = dict(causal=True, block_q=32, block_k=32, interpret=True,
+               window=window)
+
+    @jax.jit
+    def repeated(q, k, v, do):      # o, dq, and float32 dk, dv a QUERY head
+        o, lse = _flash_bh(bh(q), bh(k), bh(v), **how)
+        delta = jnp.sum(bh(do).astype(jnp.float32) * o.astype(jnp.float32),
+                        axis=-1, keepdims=True)
+        args = (bh(q), bh(k), bh(v), bh(do), lse, delta)
+        _, k32, v32 = _flash_bh_bwd(*args, **how, out_dtype=jnp.float32)
+        return (back(o), back(_flash_bh_bwd(*args, **how)[0]), back(k32),
+                back(v32))
+
+    want_o, want_dq, k32, v32 = repeated(q, k, v, do)
+    np.testing.assert_array_equal(o, want_o)
+    np.testing.assert_array_equal(dq, want_dq)
+    for name, got, head in (("dk", dk, k32), ("dv", dv, v32)):
+        want = jnp.sum(head.reshape(B, L, KV, rep, -1), axis=3)
+        if rep == 1:
+            np.testing.assert_array_equal(got, want.astype(jnp.bfloat16),
+                                          err_msg=name)
+        off = jnp.abs(got.astype(jnp.float32) - want)
+        assert bool(jnp.all(off <= _bf16_ulp(want))), name
+
+    eqns = list(_eqns(jax.make_jaxpr(both)(q, k, v, do).jaxpr))
+    calls = [e for e in eqns if e.primitive.name == "pallas_call"]
+    assert [e.params["name"] for e in calls] == ["flash_fwd", "flash_bwd"]
+    for call in calls:
+        assert [x.aval.shape[0] for x in call.invars[:3]] == [
+            B * H, B * KV, B * KV]
+    # dq, dk, dv: the K/V heads' own gradients leave the one kernel
+    assert [x.aval.shape[0] for x in calls[1].outvars] == [
+        B * H, B * KV, B * KV]
+    assert not [e for e in eqns if e.primitive.name == "broadcast_in_dim"
+                and e.outvars[0].aval.size >= q.size]
+
+
+@pytest.mark.parametrize("form", ["group", "one", "streamed"])
+def test_a_groups_dk_and_dv_in_every_backward_form(form):
+    """Three query heads a K/V head: ``flash_bwd`` with the group's float32
+    dk and dv in its VMEM, ``flash_bwd`` writing them a query head where
+    those blocks do not fit the budget, and the streamed pair, the two
+    summed over the group after the kernels; each form is the one the
+    budget names, and all give the gradients of plain attention."""
+    (B, L, H, D), KV = (2, 64, 6, 16), 2
+    q, do = _qkv(L=L, H=H)[0], _qkv(L=L, H=H, seed=1)[0]
+    _, k, v = _qkv(L=L, H=KV, seed=2)
+    kw = dict(causal=True, block_q=16, block_k=16, interpret=True)
+    sizes = (L, L, D, D, 16, 16, jnp.float32, jnp.float32, H // KV)
+    budget = {"group": _bwd_form(*sizes, 2 ** 40)[1],
+              "one": _bwd_form(*sizes, 2 ** 40)[1] - 1, "streamed": 0}[form]
+    assert _bwd_form(*sizes, budget)[0] == form
+    o, lse = flash_fwd_block(_bh(q), _bh(k), _bh(v), **kw)
+    delta = jnp.sum(_bh(do) * o, axis=-1, keepdims=True)
+    args = (_bh(q), _bh(k), _bh(v), _bh(do), lse, delta)
+    jaxpr = jax.make_jaxpr(lambda *a: _flash_bh_bwd(
+        *a, **kw, vmem_budget=budget))(*args)
+    assert _pallas_calls(jaxpr.jaxpr) == (
+        ["flash_bwd_dq", "flash_bwd_dkv"] if form == "streamed"
+        else ["flash_bwd"])
+    got = _flash_bh_bwd(*args, **kw, vmem_budget=budget)
+    want_o, want = _reference(q, k, v, do, True)
+    np.testing.assert_allclose(o, _bh(want_o), rtol=1e-5, atol=1e-5)
+    for name, a, w in zip(("dq", "dk", "dv"), got, want):
+        assert a.shape == _bh(w).shape, name
+        np.testing.assert_allclose(a, _bh(w), rtol=1e-5, atol=1e-5,
+                                   err_msg=name)
 
 
 @pytest.mark.parametrize("remat", ["dots", "none", "full"])

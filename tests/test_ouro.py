@@ -381,12 +381,17 @@ def test_weighted_chunked_head_equals_the_dense_weighted_loss():
 # commit of the PR that brought the loop (float32 on the CPU, the seeds
 # below): a model with one step, no sandwich norm and no gate takes the path
 # it took, bit for bit.
-PINNED = {2: ("0x1.93d0900000000p+14", "0x1.79a7220000000p+2",
+# Since PR 41 the rotation meets a pair's partner through a product with a
+# 0/1/-1 matrix and no longer through slices at a stride of two
+# (`llama._rotate_pairs`): the same products, bit-equal unjitted, summed in
+# another order where the CPU compiler contracts them, so the last bits moved
+# once (the loss by at most 1 ulp, the sums by 2); pinned again at that PR.
+PINNED = {2: ("0x1.93d0940000000p+14", "0x1.79a7220000000p+2",
               "0x1.7676460000000p+3", "0x1.79a71c0000000p+2",
-              "0x1.7676480000000p+3"),
-          6: ("0x1.9344520000000p+14", "0x1.74ccda0000000p+2",
-              "0x1.7d995e0000000p+3", "0x1.74ccdc0000000p+2",
-              "0x1.7d995e0000000p+3")}
+              "0x1.7676460000000p+3"),
+          6: ("0x1.9344540000000p+14", "0x1.74ccda0000000p+2",
+              "0x1.7d995a0000000p+3", "0x1.74ccdc0000000p+2",
+              "0x1.7d995a0000000p+3")}
 
 
 @pytest.mark.parametrize("depth", sorted(PINNED))

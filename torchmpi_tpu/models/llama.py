@@ -670,16 +670,44 @@ def rms_norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
     return (norm * w).astype(x.dtype)
 
 
+def _rotate_pairs(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
+    """Adjacent channel pairs of x (B, L, H, d) turned by the angles whose
+    ``cos`` and ``sin`` (L, d / 2) are given, in float32: channel 2i becomes
+    ``x[2i] cos_i - x[2i+1] sin_i`` and 2i+1 ``x[2i] sin_i + x[2i+1] cos_i``.
+
+    A channel meets its partner through a product with the (d, d) matrix that
+    swaps the two of a pair and negates the second: entries 0, 1 and -1, one
+    a column, so the product is exact and the values are those of slicing x
+    at a stride of two.  The slicing is what it replaces.  For it the chip's
+    compiler kept q and k with the SEQUENCE on the lanes and copied each
+    twice on its way to the flash kernels' (heads, L, d), forward, recomputed
+    and as gradients: 17.8 GB of copies a Laguna step, 7.6 with the product,
+    whose 128 x 128 matrix the idle MXU takes (a roll of the lanes by one and
+    a select by parity saves the same copies and costs more than they did:
+    PERF.md section 6, PR 41)."""
+    d = x.shape[-1]
+    swap = np.zeros((d, d), np.float32)
+    swap[np.arange(1, d, 2), np.arange(0, d, 2)] = -1.0    # [2i] = -x[2i+1]
+    swap[np.arange(0, d, 2), np.arange(1, d, 2)] = 1.0     # [2i+1] = x[2i]
+    # The batch rides as a batch dimension of the product (the matrix
+    # broadcast over it): a checkpoint policy that keeps matmul outputs
+    # (``"dots"``: those WITHOUT a batch dimension) would else keep this one,
+    # float32 and of q's size, which no backward pass reads.
+    partner = jnp.einsum(
+        "blhd,bde->blhe", x,
+        jnp.broadcast_to(jnp.asarray(swap, x.dtype), (x.shape[0], d, d)),
+        preferred_element_type=jnp.float32, precision=lax.Precision.HIGHEST)
+    cos = jnp.repeat(cos, 2, axis=-1)[None, :, None, :]
+    sin = jnp.repeat(sin, 2, axis=-1)[None, :, None, :]
+    return (x.astype(jnp.float32) * cos + partner * sin).astype(x.dtype)
+
+
 def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     """Rotary embedding; x: (B, L, H, D_head), positions: (L,)."""
     d = x.shape[-1]
     freqs = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
     angles = positions[:, None].astype(jnp.float32) * freqs[None, :]  # (L, d/2)
-    cos = jnp.cos(angles)[None, :, None, :]
-    sin = jnp.sin(angles)[None, :, None, :]
-    x1, x2 = x[..., 0::2].astype(jnp.float32), x[..., 1::2].astype(jnp.float32)
-    out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
-    return out.reshape(x.shape).astype(x.dtype)
+    return _rotate_pairs(x, jnp.cos(angles), jnp.sin(angles))
 
 
 def yarn_inv_freq(dim: int, theta: float, factor: float, original: int,
@@ -706,12 +734,8 @@ def rope_scaled(x: jax.Array, positions: jax.Array, inv_freq,
     ``factor``; the channels after them pass as they are."""
     r = 2 * len(inv_freq)
     angles = positions[:, None].astype(jnp.float32) * jnp.asarray(inv_freq)
-    cos = factor * jnp.cos(angles)[None, :, None, :]
-    sin = factor * jnp.sin(angles)[None, :, None, :]
-    x1 = x[..., 0:r:2].astype(jnp.float32)
-    x2 = x[..., 1:r:2].astype(jnp.float32)
-    out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
-    out = out.reshape(*x.shape[:-1], r).astype(x.dtype)
+    out = _rotate_pairs(x[..., :r], factor * jnp.cos(angles),
+                        factor * jnp.sin(angles))
     return jnp.concatenate([out, x[..., r:]], axis=-1)
 
 
@@ -826,22 +850,21 @@ def _flash_attention_sharded(mesh: Optional[Mesh], heads: int,
     cannot be automatically partitioned``), and each device wants only its
     own batch rows and head shard anyway — the layout the hand-sharded
     stage (:func:`_decoder_layer_tp_manual`) already runs.  K/V are split
-    at ``kv_heads`` and repeated locally, as in the ring.  A step runs two
-    kernels a layer, ``flash_fwd`` and ``flash_bwd``, under every remat
-    policy: the layer's checkpoint keeps the forward's ``o`` and ``lse``
-    under ``"dots"`` and under ``"full"`` alike (:func:`_wrap_remat`), so
-    ``"full"`` holds one more array of the layer input's size a layer
-    application and never runs the forward kernel twice."""
+    at ``kv_heads`` and stay at that count: the kernels' index maps name a
+    group of query heads its one K/V head and ``flash_bwd`` sums dk and dv
+    over the group, so nothing is repeated in HBM.  A step
+    runs two kernels a layer, ``flash_fwd`` and ``flash_bwd``, under every
+    remat policy: the layer's checkpoint keeps the forward's ``o`` and
+    ``lse`` under ``"dots"`` and under ``"full"`` alike
+    (:func:`_wrap_remat`), so ``"full"`` holds one more array of the layer
+    input's size a layer application and never runs the forward kernel
+    twice."""
     from jax import shard_map
 
     from ..ops import flash_attention
 
-    rep = heads // kv_heads
-
     def local(q, k, v):
-        return flash_attention(q, jnp.repeat(k, rep, axis=2),
-                               jnp.repeat(v, rep, axis=2), causal=True,
-                               window=window)
+        return flash_attention(q, k, v, causal=True, window=window)
 
     if mesh is None or mesh.size == 1:
         return local
@@ -2660,9 +2683,6 @@ def _decoder_layer_tp_manual(cfg: Config, lp, h, positions,
     q = rope((x @ lp["wq"]).reshape(B, L, Hl, hd), positions, cfg.rope_theta)
     k = rope((x @ lp["wk"]).reshape(B, L, KVl, hd), positions, cfg.rope_theta)
     v = (x @ lp["wv"]).reshape(B, L, KVl, hd)
-    rep = Hl // KVl
-    if rep > 1:
-        k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
     o = _flash(q, k, v, causal=True,
                scale=float(1.0 / np.sqrt(hd)))
 

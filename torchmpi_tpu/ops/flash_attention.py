@@ -23,6 +23,14 @@ the backward pass: the backward is one kernel (see its section), and the
 forward rule names its residuals (``RESIDUAL_NAMES``) so that a caller's
 ``jax.checkpoint`` can keep them and not replay the forward kernel.
 
+Grouped queries: K and V stay at their own head count, (B * KV, L, D)
+beside q's (B * H, L, D), and are never repeated to the query heads in HBM:
+program ``b`` of a grid's first dimension (batch * query head) names K/V
+head ``b // (H // KV)`` in its index maps, and ``flash_bwd`` sums dk and dv
+over a group where they are formed, in a float32 block of the K/V head's
+whole (L, D) that stays in VMEM while the group's heads pass
+(:func:`operand_plan` counts what a call still moves for layout alone).
+
 ``interpret=True`` (automatic off-TPU) runs the same kernel through the
 Pallas interpreter, keeping CPU tests exact.
 """
@@ -207,12 +215,21 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
         lse_ref[:, 0] = m_ref[:, 0] + jnp.log(l)
 
 
+def _kv_head(rep: int):
+    """``b -> K/V head`` of program ``b`` (batch * query head) where ``rep``
+    query heads share one: ``b // rep``, the heads of a group adjacent; ``b``
+    itself where each has its own."""
+    return (lambda b: b) if rep == 1 else (lambda b: b // rep)
+
+
 def _flash_bh(qbh, kbh, vbh, *, causal: bool, block_q: int, block_k: int,
               interpret: bool, scale: Optional[float] = None,
               out_dtype=None, window: Optional[int] = None):
     """(BH, L, D) flash attention forward; returns (o, lse).
 
-    ``kbh``/``vbh`` may have a different sequence length than ``qbh`` (the
+    ``kbh``/``vbh`` may hold fewer heads than ``qbh``, (BH / rep, Lk, D):
+    query head ``b`` then reads K/V head ``b // rep`` (grouped queries).
+    They may have a different sequence length than ``qbh`` (the
     ring caller attends local Q against a circulating K/V chunk), and
     ``vbh`` a width of its own: q and k are ``D`` wide, v and o ``Dv``
     (latent attention's 192 and 128; no operand is padded to the other's).
@@ -222,6 +239,7 @@ def _flash_bh(qbh, kbh, vbh, *, causal: bool, block_q: int, block_k: int,
     """
     BH, L, D = qbh.shape
     Lk, Dv = vbh.shape[1:]
+    kv_of = _kv_head(BH // kbh.shape[0])
     if scale is None:
         scale = 1.0 / np.sqrt(D)
     out_dtype = qbh.dtype if out_dtype is None else out_dtype
@@ -232,8 +250,8 @@ def _flash_bh(qbh, kbh, vbh, *, causal: bool, block_q: int, block_k: int,
         k_of, width = _band_k_map(window, block_q, block_k, grid[1])
         grid = (BH, grid[1], width)
         kernel = functools.partial(kernel, window=window, seq_len=L)
-    kv_block = lambda d: pl.BlockSpec((None, block_k, d),
-                                      lambda b, qi, ki: (b, k_of(qi, ki), 0))
+    kv_block = lambda d: pl.BlockSpec(
+        (None, block_k, d), lambda b, qi, ki: (kv_of(b), k_of(qi, ki), 0))
     return pl.pallas_call(
         kernel,
         out_shape=(jax.ShapeDtypeStruct((BH, L, Dv), out_dtype),
@@ -271,7 +289,14 @@ def _flash_bh(qbh, kbh, vbh, *, causal: bool, block_q: int, block_k: int,
 #     of one batch*head, so it stays in VMEM across that b's sweep, is
 #     zeroed at the sweep's first program, added into a block of rows at a
 #     time and written back once.  It needs 2 * Lq * D * 4 bytes of VMEM
-#     (both pipeline buffers) on top of the tiles.
+#     (both pipeline buffers) on top of the tiles.  Where ``group`` query
+#     heads share a K/V head (K and V hold BH / group heads), dk and dv are
+#     float32 output blocks over that K/V head's whole (Lk, D) and (Lk, Dv),
+#     which stay in VMEM while the group's heads pass in turn (their block
+#     index is ``b // group``): zeroed at the group's first program, each
+#     K block's sums added in when its sweep ends, written back once.  No
+#     gradient is ever written a query head and summed in HBM.  Another
+#     2 * Lk * (D + Dv) * 4 bytes.
 #   * Where that does not fit ``_VMEM_BUDGET`` (a local chunk past about
 #     80k rows at D=128), two streaming kernels whose working set is one
 #     tile a side do the same work with the score block formed twice (7
@@ -314,6 +339,22 @@ def _bwd_vmem_bytes(block_q: int, block_k: int, D: int, in_dtype,
     return 2 * tiles + wide * (D + Dv) * 4 + 4 * block_q * block_k * 4
 
 
+def _bwd_form(L: int, Lk: int, D: int, Dv: int, block_q: int, block_k: int,
+              in_dtype, out_dtype, rep: int, budget: int):
+    """``(form, vmem)``: which backward runs at those shapes, read from them
+    and the ``budget``, and the VMEM it states.  ``"one"``: ``flash_bwd``
+    with its float32 dq block of the whole (L, D) in both buffers;
+    ``"group"`` (``rep`` > 1 query heads a K/V head): that with the K/V
+    head's float32 dk and dv of the whole (Lk, D) and (Lk, Dv) beside it;
+    ``"streamed"``: the two streaming kernels."""
+    stream = _bwd_vmem_bytes(block_q, block_k, D, in_dtype, out_dtype, Dv)
+    one = stream + 2 * L * _lanes(D) * 4
+    group = one + 2 * Lk * (_lanes(D) + _lanes(Dv)) * 4
+    if rep > 1 and group <= budget:
+        return "group", group
+    return ("one", one) if one <= budget else ("streamed", stream)
+
+
 def _bwd_pair(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, q_start,
               k_start, *, causal: bool, scale: float,
               window: Optional[int] = None):
@@ -337,11 +378,15 @@ def _bwd_pair(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, q_start,
 def _attn_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                      dq_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
                      causal: bool, scale: float,
-                     window: Optional[int] = None, seq_len: int = 0):
+                     window: Optional[int] = None, seq_len: int = 0,
+                     group: int = 1):
     """One (batch*head, k-block, q-block) program of ``flash_bwd``.
     ``dq_ref`` is the whole (Lq, D) of this batch*head; with ``dq_ref``
     None the program is ``flash_bwd_dkv``'s.  With a ``window`` the q
-    dimension counts from the band's first Q block (:func:`_first_q`)."""
+    dimension counts from the band's first Q block (:func:`_first_q`).
+    ``group`` > 1: ``dk_ref`` and ``dv_ref`` are the float32 whole (Lk, D)
+    and (Lk, Dv) of the K/V head that ``group`` consecutive batch*heads
+    share, and take the sum over them."""
     bq = q_ref.shape[0]
     bk = k_ref.shape[0]
     ki = pl.program_id(1)
@@ -356,6 +401,12 @@ def _attn_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         @pl.when((ki == 0) & (qi == 0))
         def _init_dq():
             dq_ref[:, :] = jnp.zeros_like(dq_ref)
+
+    if group > 1:
+        @pl.when((pl.program_id(0) % group == 0) & (ki == 0) & (qi == 0))
+        def _init_dkv():
+            dk_ref[:, :] = jnp.zeros_like(dk_ref)
+            dv_ref[:, :] = jnp.zeros_like(dv_ref)
 
     @pl.when(qi == 0)
     def _init():
@@ -384,8 +435,13 @@ def _attn_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(qi == nq - 1)
     def _finalize():
-        dk_ref[:, :] = dk_acc[:, :].astype(dk_ref.dtype)
-        dv_ref[:, :] = dv_acc[:, :].astype(dv_ref.dtype)
+        if group > 1:
+            rows = pl.ds(pl.multiple_of(ki * bk, bk), bk)
+            dk_ref[rows, :] += dk_acc[:, :]
+            dv_ref[rows, :] += dv_acc[:, :]
+        else:
+            dk_ref[:, :] = dk_acc[:, :].astype(dk_ref.dtype)
+            dv_ref[:, :] = dv_acc[:, :].astype(dv_ref.dtype)
 
 
 def _attn_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -444,9 +500,17 @@ def _flash_bh_bwd(qbh, kbh, vbh, dobh, lse, delta, *, causal: bool,
     One ``flash_bwd`` kernel where its resident dq block fits
     ``vmem_budget`` (read from the shapes, see the section comment), else
     the two streaming kernels.  With a ``window`` every grid's inner
-    dimension is the band's (the module's text)."""
+    dimension is the band's (the module's text).
+
+    ``kbh``/``vbh`` (BH / rep, Lk, D): grouped queries, dk and dv come at
+    that head count too, the sum over a group's ``rep`` query heads taken
+    in float32: in ``flash_bwd``'s VMEM where the K/V head's two float32
+    blocks fit the budget beside dq's, else of the gradients a query head
+    that the kernels write (:func:`_group_sum`)."""
     BH, L, D = qbh.shape
     Lk, Dv = vbh.shape[1:]
+    rep = BH // kbh.shape[0]
+    own, kv_of = _kv_head(1), _kv_head(rep)     # a program's head, its K/V's
     if scale is None:
         scale = 1.0 / np.sqrt(D)
     dq_dtype = qbh.dtype if out_dtype is None else out_dtype
@@ -469,40 +533,47 @@ def _flash_bh_bwd(qbh, kbh, vbh, dobh, lse, delta, *, causal: bool,
         grid_q, grid_k = (BH, nk, q_width), (BH, nq, k_width)
     q_block2 = lambda d: pl.BlockSpec(
         (None, block_q, d), lambda b, ki, qi: (b, q_of(ki, qi), 0))
-    k_block2 = lambda d: pl.BlockSpec(
-        (None, block_k, d), lambda b, ki, qi: (b, ki, 0))
+    k_block2 = lambda d, head=kv_of: pl.BlockSpec(
+        (None, block_k, d), lambda b, ki, qi: (head(b), ki, 0))
     qrow2 = q_block2(1)
     in_specs2 = [q_block2(D), k_block2(D), k_block2(Dv), q_block2(Dv),
                  qrow2, qrow2]
-    dkv_specs2 = (k_block2(D), k_block2(Dv))
+    dkv_specs2 = (k_block2(D, own), k_block2(Dv, own))  # a query head's
     args = (qbh, kbh, vbh, dobh, lse, delta)
 
-    stream_vmem = _bwd_vmem_bytes(block_q, block_k, D, qbh.dtype, dkv_dtype,
-                                  Dv)
-    # flash_bwd's float32 dq block of the whole (L, D), in both buffers.
-    fused_vmem = stream_vmem + 2 * L * _lanes(D) * 4
-    if fused_vmem <= vmem_budget:
+    form, vmem = _bwd_form(L, Lk, D, Dv, block_q, block_k, qbh.dtype,
+                           dkv_dtype, rep, vmem_budget)
+    if form != "streamed":
+        whole = lambda rows, d, head: pl.BlockSpec(
+            (None, rows, d), lambda b, ki, qi: (head(b), 0, 0))
+        dkv_out, dkv_specs = dkv_shape, dkv_specs2
+        if form == "group":     # the K/V head's own, float32, kept in VMEM
+            dkv_out = (jax.ShapeDtypeStruct(kbh.shape, jnp.float32),
+                       jax.ShapeDtypeStruct(vbh.shape, jnp.float32))
+            dkv_specs = (whole(Lk, D, kv_of), whole(Lk, Dv, kv_of))
         dq, dk, dv = pl.pallas_call(
-            functools.partial(_attn_bwd_kernel, **static),
+            functools.partial(_attn_bwd_kernel, **static,
+                              group=rep if form == "group" else 1),
             out_shape=(jax.ShapeDtypeStruct((BH, L, D), jnp.float32),
-                       *dkv_shape),
+                       *dkv_out),
             grid=grid_q,
             in_specs=in_specs2,
-            out_specs=(pl.BlockSpec((None, L, D), lambda b, ki, qi: (b, 0, 0)),
-                       *dkv_specs2),
+            out_specs=(whole(L, D, own), *dkv_specs),
             scratch_shapes=dkv_scratch,
-            compiler_params=pltpu.CompilerParams(
-                vmem_limit_bytes=fused_vmem),
+            compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem),
             interpret=interpret,
             name="flash_bwd",
         )(*args)
-        return dq.astype(dq_dtype), dk, dv
+        if form == "group":
+            return (dq.astype(dq_dtype), dk.astype(dkv_dtype),
+                    dv.astype(dkv_dtype))
+        return dq.astype(dq_dtype), *_group_sum(rep, dk, dv)
 
-    streaming = pltpu.CompilerParams(vmem_limit_bytes=stream_vmem)
+    streaming = pltpu.CompilerParams(vmem_limit_bytes=vmem)
     q_block = lambda d: pl.BlockSpec((None, block_q, d),
                                      lambda b, qi, ki: (b, qi, 0))
-    k_block = lambda d: pl.BlockSpec((None, block_k, d),
-                                     lambda b, qi, ki: (b, k_of(qi, ki), 0))
+    k_block = lambda d: pl.BlockSpec(
+        (None, block_k, d), lambda b, qi, ki: (kv_of(b), k_of(qi, ki), 0))
     qrow = q_block(1)
     dq = pl.pallas_call(
         functools.partial(_attn_bwd_dq_kernel, **static),
@@ -527,7 +598,18 @@ def _flash_bh_bwd(qbh, kbh, vbh, dobh, lse, delta, *, causal: bool,
         interpret=interpret,
         name="flash_bwd_dkv",
     )(*args)
-    return dq, dk, dv
+    return dq, *_group_sum(rep, dk, dv)
+
+
+def _group_sum(rep: int, *grads):
+    """Gradients a query head, (BH, Lk, d) each, to their K/V heads', (BH /
+    rep, Lk, d): the sum over a group's ``rep`` adjacent heads in float32,
+    rounded once.  The arrays themselves where every head has its own."""
+    if rep == 1:
+        return grads
+    return tuple(
+        jnp.sum(g.reshape(-1, rep, *g.shape[1:]), axis=1,
+                dtype=jnp.float32).astype(g.dtype) for g in grads)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4, 5))
@@ -635,8 +717,11 @@ def flash_attention(
     scale: Optional[float] = None,
     window: Optional[int] = None,
 ) -> jax.Array:
-    """Blocked attention, (B, L, H, D) layout (GQA: repeat K/V first); v
-    may be (B, L, H, Dv) with a width of its own, and o then has it too.
+    """Blocked attention of q (B, L, H, D) with k (B, L, KV, D) and v (B, L,
+    KV, Dv) at their own head count, ``H % KV == 0`` (grouped queries: query
+    head h reads K/V head ``h // (H // KV)``, which is never repeated in
+    HBM); v may have a width of its own, and o, (B, L, H, Dv), then has it
+    too.
     ``window`` (with ``causal``): row i sees keys ``i - window < j <= i``,
     its own and the ``window - 1`` before it; the kernels run the blocks
     that hold such a pair and fetch no other, and the tile comes from
@@ -644,18 +729,20 @@ def flash_attention(
 
     Differentiable: a ``custom_vjp`` pairs the forward with a
     FlashAttention-2 style backward Pallas kernel (``flash_bwd``: all three
-    gradients from one pass over the score blocks), so training never
-    materializes the (L, L) score matrix either.  Under ``jax.checkpoint``,
+    gradients from one pass over the score blocks, dk and dv summed over a
+    group of query heads inside it), so training never materializes the (L,
+    L) score matrix either.  Under ``jax.checkpoint``,
     save ``RESIDUAL_NAMES`` in the policy or the forward kernel runs again
     in the backward pass.  Sequence length must be divisible by the (clamped)
     block sizes; callers pad or pick L accordingly.  Off-TPU the interpreter
     path keeps the semantics identical for tests.
     """
     B, L, H, D = q.shape
-    Dv = v.shape[-1]
-    if k.shape != q.shape or v.shape != (B, L, H, Dv):
-        raise ValueError("q and k must share (B, L, H, D) and v be (B, L, H, "
-                         "Dv); repeat GQA KV first")
+    KV, Dv = v.shape[2:]
+    if k.shape != (B, L, KV, D) or v.shape[:2] != (B, L) or H % KV:
+        raise ValueError(
+            f"q (B, L, H, D) takes k (B, L, KV, D) and v (B, L, KV, Dv) with "
+            f"H a multiple of KV (got q {q.shape}, k {k.shape}, v {v.shape})")
     if window is not None and (not causal or window < 1):
         raise ValueError("a window is the causal band's width in keys, the "
                          f"row's own among them: causal=True and window >= 1 "
@@ -672,14 +759,35 @@ def flash_attention(
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
 
-    # (B, L, H, D) -> (B*H, L, D)
-    qbh = q.transpose(0, 2, 1, 3).reshape(B * H, L, D)
-    kbh = k.transpose(0, 2, 1, 3).reshape(B * H, L, D)
-    vbh = v.transpose(0, 2, 1, 3).reshape(B * H, L, Dv)
+    # (B, L, heads, d) -> (B*heads, L, d), each array at its own head count
+    bh = lambda x: x.transpose(0, 2, 1, 3).reshape(-1, L, x.shape[3])
     obh = _flash_core(causal, block_q, block_k, interpret,
                       None if scale is None else float(scale), window,
-                      qbh, kbh, vbh)
+                      bh(q), bh(k), bh(v))
     return obh.reshape(B, H, L, Dv).transpose(0, 2, 1, 3)
+
+
+def operand_plan(B: int, L: int, H: int, KV: int, D: int, Dv: int,
+                 dtype=jnp.bfloat16, window: Optional[int] = None) -> dict:
+    """What a causal call of :func:`flash_attention` at those shapes, forward
+    and backward, writes to HBM for its operands' layout alone, from the
+    shapes: ``kv_repeat``, the query heads a K/V head serves;
+    ``repeated_bytes``, what K and V, dk and dv take at the query heads'
+    count beyond their own: 0 for K and V always, and for dk and dv while the
+    group's float32 blocks fit ``flash_bwd``'s VMEM beside dq
+    (``dkv_in_kernel``; else they are written a query head and summed);
+    ``copied_bytes``, q, k, v and o forward and do, dq, dk and dv backward,
+    each once between (L, heads, d) and the kernels' (heads, L, d): an upper
+    bound, the compiler folds such a copy into the pass that makes or reads
+    the array where it can.  A counter for outside the step; under a remat
+    policy that replays a layer's forward the forward's half runs twice."""
+    tile = _window_block(L, None if window is None or window >= L else window)
+    form, _ = _bwd_form(L, L, D, Dv, tile, tile, dtype, dtype, H // KV,
+                        _VMEM_BUDGET)
+    head = jnp.dtype(dtype).itemsize * B * L * (D + Dv)   # q and o, k and v
+    return {"kv_repeat": H // KV, "dkv_in_kernel": form == "group",
+            "repeated_bytes": (H - KV) * head if form != "group" else 0,
+            "copied_bytes": 2 * (H + KV) * head}
 
 
 # ------------------------------------------------------- ring building blocks

@@ -200,10 +200,6 @@ def ulysses_attention(
     if local_impl == "flash":
         from ..ops.flash_attention import flash_attention as _flash
 
-        rep = qh.shape[1] // kh.shape[1]
-        if rep > 1:
-            kh = jnp.repeat(kh, rep, axis=1)
-            vh = jnp.repeat(vh, rep, axis=1)
         oh = _flash(qh[None], kh[None], vh[None], causal=causal,
                     scale=scale)[0]
     elif local_impl == "einsum":
