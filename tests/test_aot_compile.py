@@ -105,6 +105,53 @@ def test_flash_at_heads_of_256(v5e):
     assert stated <= 64 * 1024 * 1024
 
 
+# heads, K/V heads, key and value widths of a (1, 16384, ...) causal call, and
+# the parent's (PR 41) own count of scoped VMEM, forward and backward, bytes
+TWO_BODIES = [(20, 20, 256, 256, 13844480, 59879424),     # GLM-4.7-Flash
+              (48, 8, 128, 128, 9867264, 66142208),       # a Laguna full layer
+              (32, 32, 192, 128, 11837440, 55275520)]     # Kimi's latent layer
+
+
+@pytest.mark.parametrize("heads,kv_heads,width,v_width,fwd_was,bwd_was",
+                         TWO_BODIES)
+def test_two_bodies_fit_the_vmem_one_body_took(v5e, heads, kv_heads, width,
+                                               v_width, fwd_was, bwd_was):
+    """``flash_fwd`` and ``flash_bwd`` with a masked and an unmasked body
+    each (PR 42), at three cells' shapes in bfloat16: the two bodies stand
+    under complementary predicates and the compiler gives their score
+    blocks the same VMEM, so neither kernel counts more than the one-body
+    kernels did (less: q, k, v and do are no longer held in float32 beside
+    their tiles), the backward's streamed part stays inside
+    ``_bwd_vmem_bytes`` and the whole inside what ``_bwd_form`` states."""
+    from torchmpi_tpu.ops.flash_attention import (_VMEM_BUDGET, _bwd_form,
+                                                  _bwd_vmem_bytes)
+
+    L, tile, bf16 = 16384, 1024, jnp.bfloat16
+    one = SingleDeviceSharding(v5e[0])
+    q = _sds((1, L, heads, width), bf16, one)
+    k = _sds((1, L, kv_heads, width), bf16, one)
+    v = _sds((1, L, kv_heads, v_width), bf16, one)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True, interpret=False)
+                       .astype(jnp.float32))
+
+    grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, k, v).compile()
+    text = grad.as_text()
+    assert _kernels(grad) == 2
+    assert "flash_fwd" in text and "flash_bwd" in text
+    assert "flash_bwd_dq" not in text and "flash_bwd_dkv" not in text
+    form, vmem = _bwd_form(L, L, width, v_width, tile, tile, bf16, bf16,
+                           heads // kv_heads, _VMEM_BUDGET)
+    assert form == ("group" if heads > kv_heads else "one")
+    streamed = _bwd_vmem_bytes(tile, tile, width, bf16, bf16, v_width)
+    resident = vmem - streamed          # dq's block, and a group's dk and dv
+    (_, fwd_used), (stated, used) = _kernel_vmem(grad)
+    assert fwd_used <= fwd_was < 16 * 1024 * 1024       # the default limit
+    assert stated >= vmem and used <= bwd_was <= stated < V5E_VMEM_BYTES
+    assert used - resident <= streamed
+
+
 def test_kda_kernels_at_kimi_linears_widths(v5e, monkeypatch):
     """``ops.kda`` forward and ``jax.grad`` of all five inputs at (1, 16384,
     32, 128), q, k and v bfloat16, the log-decay float32: one kernel each way

@@ -302,20 +302,33 @@ def test_the_rotation_is_the_sliced_form_to_the_bit():
                 grad(lambda x: sliced(x, positions, whole)))
 
 
-@pytest.mark.parametrize("L,window,tile,inner,most", [
-    (16384, 512, 512, 2, 2), (2048, 512, 512, 2, 2), (4096, 1024, 1024, 2, 2),
-    (4096, 300, 256, 3, 3), (16384, 4096, 1024, 5, 5)])
-def test_a_q_block_runs_the_bands_k_blocks(L, window, tile, inner, most):
+@pytest.mark.parametrize("L,window,tile,inner,most,edge", [
+    (16384, 512, 512, 2, 2, 63 / 32), (2048, 512, 512, 2, 2, 7 / 4),
+    (4096, 1024, 1024, 2, 2, 7 / 4), (4096, 300, 256, 3, 3, 45 / 16),
+    (16384, 4096, 1024, 5, 5, 28 / 16), (16384, None, 1024, 16, 16, 1.0)])
+def test_a_q_block_runs_the_bands_k_blocks(L, window, tile, inner, most, edge):
     """From the index map the forward kernel is given: a Q block of a
     windowed call names the band's K blocks and no other, its grid is as
     long as the band is wide, and the tile comes from ``(L, window)``; a
-    causal call's Q block names the triangle's."""
+    causal call's Q block names the triangle's.  ``edge_blocks_mean``: the
+    blocks of those that run the masked body, by the kernel's predicates: a
+    window under two tiles leaves no other (every block the band names
+    holds one of its edges), a window of four tiles the diagonal's
+    and the left edge's of 4.375, a causal call the diagonal's one of 8.5."""
     met = blocks_met(L, window)
     assert (met["tile"], met["grid_inner"], met["k_blocks_max"]) == (
         tile, inner, most)
+    assert met["edge_blocks_mean"] == edge
     causal = blocks_met(L)
     assert causal["k_blocks_max"] == causal["q_blocks"] == L // causal["tile"]
     assert causal["k_blocks_mean"] == (causal["q_blocks"] + 1) / 2
+    assert causal["edge_blocks_mean"] == 1.0
+    if window is None:
+        return
+    assert 1.0 < met["edge_blocks_mean"] <= met["k_blocks_mean"]
+    # a whole pair's first key is in the band of its last row
+    assert (met["edge_blocks_mean"] == met["k_blocks_mean"]) == (
+        window < 2 * tile - 1)
     # the band's: ceil((window - 1) / tile) + 1 blocks, fewer at the start
     assert met["k_blocks_max"] == -(-(window - 1) // tile) + 1
     assert met["k_blocks_mean"] < met["k_blocks_max"]

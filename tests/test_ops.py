@@ -13,8 +13,8 @@ import jax.numpy as jnp
 
 from torchmpi_tpu.ops import flash_attention
 from torchmpi_tpu.ops.flash_attention import (
-    _bwd_form, _flash_bh, _flash_bh_bwd, _bwd_vmem_bytes, flash_bwd_block,
-    flash_fwd_block)
+    _bwd_form, _flash_bh, _flash_bh_bwd, _bwd_vmem_bytes, _pair_kind,
+    flash_bwd_block, flash_fwd_block)
 from torchmpi_tpu.parallel import sequence as seq
 
 
@@ -323,6 +323,110 @@ def test_a_groups_dk_and_dv_in_every_backward_form(form):
         assert a.shape == _bh(w).shape, name
         np.testing.assert_allclose(a, _bh(w), rtol=1e-5, atol=1e-5,
                                    err_msg=name)
+
+
+@functools.lru_cache(maxsize=None)
+def _frozen_kernels():
+    """``tests/flash_attention_pr41.py``: the kernels before they had two
+    bodies, by path (it is no test module and no package's), loaded once."""
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "flash_attention_pr41.py")
+    spec = importlib.util.spec_from_file_location("flash_attention_pr41", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+# name: query heads, K/V heads, L, Lk, causal, window, block_q, block_k, the
+# backward's form, and the pairs of one head that run whole and masked.
+# D = 64 and Dv = 32: a scale of 1/8, exact in every rounding (with another
+# the CPU's compiler contracts ``s * scale - m`` into one rounding where no
+# select stands between them, which the chip's vector unit cannot).
+TWO_BODIES = {
+    "causal, 4 blocks a side": (2, 2, 256, 256, True, None, 64, 64, "one",
+                                6, 4),
+    "causal, blocks that differ": (2, 2, 256, 256, True, None, 64, 128,
+                                   "one", 2, 4),
+    "window 512, tiles of 256": (2, 2, 2048, 2048, True, 512, 256, 256,
+                                 "one", 7, 14),
+    "window 512, tiles of 512": (2, 2, 2048, 2048, True, 512, 512, 512,
+                                 "one", 0, 7),
+    "four query heads a K/V head": (4, 1, 256, 256, True, None, 64, 64,
+                                    "group", 6, 4),
+    "a group, written a query head": (4, 1, 256, 256, True, None, 64, 64,
+                                      "one", 6, 4),
+    "a group, streamed": (4, 1, 256, 256, True, None, 64, 64, "streamed",
+                          6, 4),
+    "a window, streamed": (2, 2, 512, 512, True, 100, 64, 32, "streamed",
+                           7, 35),
+    "the ring's chunk": (2, 2, 128, 256, False, None, 64, 64, "one", 8, 0),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("case", list(TWO_BODIES))
+def test_two_bodies_a_kernel_are_the_one_body_they_replace(case, dtype):
+    """A pair no mask edge crosses runs a body without the in-block mask,
+    q, k, v and do reach the products in their own dtype, the scale is taken
+    on the Q tile and the forward keeps its row statistics on the lanes: o,
+    lse, dq, dk and dv against the kernels of PR 41 (one body, the mask on
+    every pair, float32 operands, the scale on the score block, (bq, 1)
+    statistics; ``tests/flash_attention_pr41.py``), interpret mode, every
+    backward form.  float32 inputs: equal to the bit.  bfloat16 inputs: p
+    and ds stay float32 in the interpreter, as they did; q . k^T and
+    do . v^T are now products of bfloat16 operands, which the CPU sums in
+    another order (lse moves by 1e-7), so a result rounded to bfloat16 may
+    land on the next value: inside one bfloat16 step of the parent's (and
+    1e-5 of the largest entry, for sums that cancel), lse inside 1e-6.  The
+    shapes hold pairs of all three kinds: whole, masked, not run, as the
+    mask itself says, and ``_pair_kind`` names them so."""
+    H, KV, L, Lk, causal, window, bq, bk, form, whole, masked = (
+        TWO_BODIES[case])
+    D, Dv = 64, 32
+    runs, full = _pair_kind(causal, np.arange(0, L, bq)[:, None], bq,
+                            np.arange(0, Lk, bk)[None], bk, window, L)
+    back = np.arange(L)[:, None] - np.arange(Lk)[None]
+    seen = ((back >= 0) & (back < (window or Lk)) if causal
+            else np.ones((L, Lk), bool)).reshape(L // bq, bq, Lk // bk, bk)
+    some, every = seen.any(axis=(1, 3)), seen.all(axis=(1, 3))
+    np.testing.assert_array_equal(runs, some)       # True alone: not causal
+    np.testing.assert_array_equal(full, every)
+    assert (int(every.sum()), int((some & ~every).sum())) == (whole, masked)
+
+    rng = np.random.RandomState(L + H)
+    q, k, v, do = (jnp.asarray(rng.randn(*shape), dtype) for shape in (
+        (H, L, D), (KV, Lk, D), (KV, Lk, Dv), (H, L, Dv)))
+    how = dict(causal=causal, block_q=bq, block_k=bk, interpret=True,
+               window=window)
+    sizes = (L, Lk, D, Dv, bq, bk, dtype, dtype, H // KV)
+    budget = {"group": 2 ** 40, "one": _bwd_form(*sizes, 2 ** 40)[1] - (
+        1 if H > KV else 0), "streamed": 0}[form]
+    assert _bwd_form(*sizes, budget)[0] == form
+
+    frozen = _frozen_kernels()
+    # both backward passes from the frozen forward's residuals: a step of
+    # bfloat16 in o would move delta, and the gradients with it
+    o, lse = frozen._flash_bh(q, k, v, **how)
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
+                    keepdims=True)
+    got, want = ((*fwd(q, k, v, **how), *bwd(q, k, v, do, lse, delta, **how,
+                                             vmem_budget=budget))
+                 for fwd, bwd in ((_flash_bh, _flash_bh_bwd),
+                                  (frozen._flash_bh, frozen._flash_bh_bwd)))
+    for name, a, b in zip(("o", "lse", "dq", "dk", "dv"), got, want):
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        if dtype == jnp.float32:
+            np.testing.assert_array_equal(a, b, err_msg=name)
+        elif a.dtype == jnp.float32:                       # lse
+            np.testing.assert_allclose(a, b, rtol=1e-6, err_msg=name)
+        else:
+            a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+            step = _bf16_ulp(b) + 1e-5 * jnp.max(jnp.abs(b))   # sums near 0
+            assert bool(jnp.all(jnp.abs(a - b) <= step)), name
 
 
 @pytest.mark.parametrize("remat", ["dots", "none", "full"])

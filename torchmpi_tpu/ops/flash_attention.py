@@ -12,11 +12,29 @@ hierarchy, MXU notes, scratch shapes).
 
 Causal mode predicates whole K blocks above the diagonal off with
 ``pl.when``, skipping ~half the MXU work, and the index maps name no new
-block for such a pair, so nothing is fetched for it either.  With a
-``window`` (a row sees its own key and the ``window - 1`` before it) the
-blocks wholly left of the band go too, and the grid's inner dimension is
-only as long as the band is wide in blocks (:func:`_band_k_map`): a
-windowed layer's cost grows with ``L * window``, not ``L * L``.
+block for such a pair, so nothing is fetched for it either.  A pair that
+runs runs one of two bodies (:func:`_when_unmasked`): where no mask edge
+crosses it (every row sees every key: 120 of the 136 pairs a head at L =
+16,384 on 1024-row tiles, 6 of 10 at L = 4,096; :func:`blocks_met` counts
+them) the body without the in-block mask, and the masked one where the
+diagonal or a band's left edge crosses it.  With a ``window`` (a row sees
+its own key and the ``window - 1`` before it) the blocks wholly left of the
+band go too, and the grid's inner dimension is only as long as the band is
+wide in blocks (:func:`_band_k_map`): a windowed layer's cost grows with
+``L * window``, not ``L * L``.
+
+What a pair costs beside its products (PERF.md section 6, PR 42: the
+compiler's own bundles, and a kernel A/B on the chip): a (1024, 1024)
+float32 score block is 1,024 registers of 64, so every pass over it is the
+compiler's spills and fills, and the forward kernel was bound by its one
+vector-store slot, not by arithmetic.  Hence: q, k, v and do go to the MXU in
+the dtype they were loaded in (:func:`_mxu`); the softmax scale is taken on
+the (bq, D) Q tile, not on the block (:func:`_scaled`); and the forward keeps
+its running max and denominator on all 128 lanes of a register
+(:func:`_over`), which at a window layer's 512-row tile took the cross-lane
+unit out of the critical path (``flash_fwd`` 10.3 -> 7.2 ms a Laguna sliding
+layer).  At 1024-row tiles both kernels now stand within a tenth of what
+their matmul issues alone take; the rest is per-program overhead.
 
 A training step forms each score block once in the forward pass and once in
 the backward pass: the backward is one kernel (see its section), and the
@@ -60,22 +78,43 @@ NEG_INF = -1e30
 RESIDUAL_NAMES = ("flash_o", "flash_lse")
 
 
+def _pair_kind(causal: bool, q_start, bq: int, k_start, bk: int,
+               window: Optional[int] = None, seq_len: int = 0):
+    """``(runs, whole)`` of the pair of a ``bq``-row Q block at ``q_start``
+    and a ``bk``-key K block at ``k_start``, on program ids or on numpy's
+    integers alike.  It runs unless causal masking blanks all of it (the K
+    block lies strictly above the diagonal of the Q block) or, with a
+    ``window``, the keys all lie left of the band of its first row, or the Q
+    block, counted from a band's first, lies past the ``seq_len`` rows there
+    are.  It is ``whole`` where no mask edge crosses it: every row sees every
+    key, the last key no later than the first row and, with a ``window``, the
+    first key inside the band of the last row."""
+    if not causal:
+        return True, True
+    runs = q_start + bq - 1 >= k_start
+    whole = k_start + bk - 1 <= q_start
+    if window is not None:
+        inside = q_start < seq_len
+        runs = runs & (k_start + bk - 1 > q_start - window) & inside
+        whole = whole & (k_start > q_start + bq - 1 - window) & inside
+    return runs, whole
+
+
 def _when_unmasked(causal: bool, q_start, bq: int, k_start, compute,
                    window: Optional[int] = None, bk: int = 0,
                    seq_len: int = 0):
-    """Run ``compute`` unless causal masking blanks the whole pair (the K
-    block lies strictly above the diagonal of the Q block) or, with a
-    ``window``, the ``bk`` keys all lie left of the band of its first row, or
-    the Q block, counted from a band's first, lies past the ``seq_len``
-    rows there are."""
-    if causal and window is not None:
-        pl.when((q_start + bq - 1 >= k_start)
-                & (k_start + bk - 1 > q_start - window)
-                & (q_start < seq_len))(compute)
-    elif causal:
-        pl.when(q_start + bq - 1 >= k_start)(compute)
-    else:
-        compute()
+    """Run ``compute(masked)`` for a pair that runs (:func:`_pair_kind`):
+    ``compute(False)``, the body without the in-block mask, where the pair is
+    whole, ``compute(True)`` where the diagonal or the band's left edge
+    crosses it, and neither where masking blanks the pair.  Two bodies under
+    complementary scalar predicates: a whole pair pays for no mask."""
+    if not causal:
+        compute(False)
+        return
+    runs, whole = _pair_kind(causal, q_start, bq, k_start, bk, window,
+                             seq_len)
+    pl.when(whole)(lambda: compute(False))
+    pl.when(runs & jnp.logical_not(whole))(lambda: compute(True))
 
 
 def _unmasked_k(causal: bool, block_q: int, block_k: int, nk: int):
@@ -156,6 +195,36 @@ def _band_mask(s, q_start, k_start, window: Optional[int]):
     return jnp.where(back.astype(jnp.uint32) < window, s, NEG_INF)
 
 
+def _mxu(a, b, contract):
+    """``a`` and ``b`` contracted over the axes ``contract`` names, one of
+    each, into float32: an MXU product of the operands as they are.  A tile
+    loaded in bfloat16 goes in with no conversion up on the VPU (the v5e has
+    no bfloat16 VALU) and, where both operands are loaded ones, in half the
+    matmul issues; a block computed in float32 (``p``, ``ds``) stays float32
+    beside it, and the MXU's single pass rounds it to bfloat16 as it always
+    did (the interpreter keeps it whole, as it always did)."""
+    return lax.dot_general(a, b, (contract, ((), ())),
+                           preferred_element_type=jnp.float32)
+
+
+def _scaled(q_ref, scale: float):
+    """The Q tile times ``scale``, multiplied in float32 and rounded back to
+    its own dtype: the softmax scale taken once on (bq, D) and not on every
+    (bq, bk) score block the tile meets."""
+    return (q_ref[:, :].astype(jnp.float32) * scale).astype(q_ref.dtype)
+
+
+_STAT_LANES = 128     # a row statistic is kept on all lanes of one register
+
+
+def _over(x, n: int):
+    """A row statistic ``x`` (bq, _STAT_LANES), one value a row on every
+    lane, as (bq, n): whole registers side by side, no lane broadcast."""
+    if n % _STAT_LANES:
+        return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+    return x if n == _STAT_LANES else pltpu.repeat(x, n // _STAT_LANES, axis=1)
+
+
 def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
                  causal: bool, scale: float, window: Optional[int] = None,
                  seq_len: int = 0):
@@ -179,40 +248,36 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
         m_ref[:, :] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:, :] = jnp.zeros_like(l_ref)
 
-    def _compute():
-        q = q_ref[:, :].astype(jnp.float32)
-        k = k_ref[:, :].astype(jnp.float32)
-        v = v_ref[:, :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if causal:
+    def _compute(masked: bool):
+        s = _mxu(_scaled(q_ref, scale), k_ref[:, :], ((1,), (1,)))
+        if masked:
             s = _band_mask(s, q_start, k_start, window)
-        m_prev = m_ref[:, 0]
-        l_prev = l_ref[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        if window is None:
-            p = jnp.exp(s - m_new[:, None])
-        else:
+        m_prev = m_ref[:, :]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        if masked and window is not None:
             # A row may see no key of the band's first block: its max is
             # still NEG_INF there, and exp(NEG_INF - NEG_INF) would be 1.
-            p = jnp.exp(s - jnp.where(m_new > NEG_INF, m_new, 0.0)[:, None])
+            # (A row of a whole pair has seen a key.)
+            p = jnp.exp(s - _over(jnp.where(m_new > NEG_INF, m_new, 0.0), bk))
+        else:
+            p = jnp.exp(s - _over(m_new, bk))
         corr = jnp.exp(m_prev - m_new)
-        l_ref[:, 0] = l_prev * corr + jnp.sum(p, axis=1)
-        m_ref[:, 0] = m_new
-        acc_ref[:, :] = (acc_ref[:, :] * corr[:, None]
-                         + jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
-                                               preferred_element_type=jnp.float32))
+        l_ref[:, :] = l_ref[:, :] * corr + jnp.sum(p, axis=1, keepdims=True)
+        m_ref[:, :] = m_new
+        acc_ref[:, :] = (acc_ref[:, :] * _over(corr, acc_ref.shape[1])
+                         + _mxu(p, v_ref[:, :], ((1,), (0,))))
 
     _when_unmasked(causal, q_start, bq, k_start, _compute, window, bk,
                    seq_len)
 
     @pl.when(ki == nk - 1)
     def _finalize():
-        l = jnp.maximum(l_ref[:, 0], 1e-20)
-        o_ref[:, :] = (acc_ref[:, :] / l[:, None]).astype(o_ref.dtype)
+        l = jnp.maximum(l_ref[:, :], 1e-20)
+        o_ref[:, :] = (acc_ref[:, :]
+                       / _over(l, acc_ref.shape[1])).astype(o_ref.dtype)
         # log-sum-exp per query row — the single residual the backward
         # kernel needs to re-form p = exp(s - lse) block-by-block.
-        lse_ref[:, 0] = m_ref[:, 0] + jnp.log(l)
+        lse_ref[:, :] = (m_ref[:, :] + jnp.log(l))[:, :1]
 
 
 def _kv_head(rep: int):
@@ -266,8 +331,8 @@ def _flash_bh(qbh, kbh, vbh, *, causal: bool, block_q: int, block_k: int,
                                 lambda b, qi, ki: (b, qi, 0))),
         scratch_shapes=[
             pltpu.VMEM((block_q, Dv), jnp.float32),  # output accumulator
-            pltpu.VMEM((block_q, 1), jnp.float32),   # running max
-            pltpu.VMEM((block_q, 1), jnp.float32),   # running denominator
+            pltpu.VMEM((block_q, _STAT_LANES), jnp.float32),    # running max
+            pltpu.VMEM((block_q, _STAT_LANES), jnp.float32),    # denominator
         ],
         interpret=interpret,
         name="flash_fwd",       # the kernel's name in the compiled program
@@ -324,12 +389,17 @@ def _bwd_vmem_bytes(block_q: int, block_k: int, D: int, in_dtype,
                     out_dtype, Dv: Optional[int] = None) -> int:
     """VMEM a streaming backward kernel asks for, from its shapes (q, k and
     their gradients ``D`` wide, v, do and dv ``Dv``, which is ``D`` unless
-    given): every streamed tile in
-    both pipeline buffers (a (block_q, 1) column of lse or delta pads to 128
-    lanes), the float32 accumulators, and four float32 (block_q, block_k)
-    blocks for s/p, dp/ds and the operands the compiler transposes; a width
-    counts as the lanes it takes (:func:`_lanes`).  At 1024-wide blocks and D=Dv=128 in bfloat16 this gives 22 MiB, where the
-    compiler's own count for ``flash_bwd`` is 16.6 beside its dq block."""
+    given): every streamed tile in both pipeline buffers (a (block_q, 1)
+    column of lse or delta pads to 128 lanes), the float32 accumulators, and
+    four float32 (block_q, block_k) blocks for s/p, dp/ds and the operands
+    the compiler transposes; a width counts as the lanes it takes
+    (:func:`_lanes`).  The masked and the unmasked body stand under
+    complementary predicates and share those blocks: the count is one
+    body's.  At 1024-wide blocks and D=Dv=128 in bfloat16 this gives 22 MiB,
+    where the compiler's own count for ``flash_bwd`` is 15.3 beside its
+    resident blocks (a Laguna full layer, 48 heads over 8; 15.8 with one
+    body and v converted to float32: ``tests/test_aot_compile.py`` holds the
+    count under that)."""
     isz, osz = jnp.dtype(in_dtype).itemsize, jnp.dtype(out_dtype).itemsize
     D, Dv = _lanes(D), _lanes(D if Dv is None else Dv)
     wide = max(block_q, block_k)
@@ -356,22 +426,21 @@ def _bwd_form(L: int, Lk: int, D: int, Dv: int, block_q: int, block_k: int,
 
 
 def _bwd_pair(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, q_start,
-              k_start, *, causal: bool, scale: float,
+              k_start, *, masked: bool, scale: float,
               window: Optional[int] = None):
-    """One (q-block, k-block) pair of the backward: the float32 operands and
-    the blocks ``p`` and ``ds`` (bq, bk) every gradient is a product of."""
-    q = q_ref[:, :].astype(jnp.float32)
-    k = k_ref[:, :].astype(jnp.float32)
-    v = v_ref[:, :].astype(jnp.float32)
-    do = do_ref[:, :].astype(jnp.float32)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    if causal:
+    """One (q-block, k-block) pair of the backward: the operands as loaded, q
+    times ``scale`` (:func:`_scaled`), and the float32 blocks ``p`` and
+    ``ds`` (bq, bk) every gradient is a product of.  The scale is in ``s``
+    through q and in dk through ``ds^T q``; ``ds`` itself is without it, so
+    dq takes it on its (bq, D) product.  ``masked``: a mask edge crosses the
+    pair (:func:`_when_unmasked`)."""
+    q, k, do = _scaled(q_ref, scale), k_ref[:, :], do_ref[:, :]
+    s = _mxu(q, k, ((1,), (1,)))
+    if masked:
         s = _band_mask(s, q_start, k_start, window)
     p = jnp.exp(s - lse_ref[:, 0][:, None])
-    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    ds = p * (dp - delta_ref[:, 0][:, None]) * scale
+    dp = _mxu(do, v_ref[:, :], ((1,), (1,)))
+    ds = p * (dp - delta_ref[:, 0][:, None])
     return q, k, do, p, ds
 
 
@@ -413,23 +482,17 @@ def _attn_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc[:, :] = jnp.zeros_like(dk_acc)
         dv_acc[:, :] = jnp.zeros_like(dv_acc)
 
-    def _compute():
+    def _compute(masked: bool):
         q, k, do, p, ds = _bwd_pair(q_ref, k_ref, v_ref, do_ref, lse_ref,
                                     delta_ref, q_start, k_start,
-                                    causal=causal, scale=scale, window=window)
-        dv_acc[:, :] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)                    # p^T @ do
-        dk_acc[:, :] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)                    # ds^T @ q
+                                    masked=masked, scale=scale, window=window)
+        dv_acc[:, :] += _mxu(p, do, ((0,), (0,)))                  # p^T @ do
+        dk_acc[:, :] += _mxu(ds, q, ((0,), (0,)))                  # ds^T @ q
         if dq_ref is not None:
             rows = pl.ds(pl.multiple_of(q_start, bq), bq)
-            dq_ref[rows, :] += jax.lax.dot_general(
-                ds, k, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)                # ds @ k
+            dq_ref[rows, :] += _mxu(ds, k, ((1,), (0,))) * scale   # ds @ k
 
-    # Skip Q blocks wholly above the diagonal for this K block.
+    # No Q block wholly above the diagonal for this K block; no mask below it.
     _when_unmasked(causal, q_start, bq, k_start, _compute, window, bk,
                    seq_len)
 
@@ -461,20 +524,18 @@ def _attn_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _init():
         acc_ref[:, :] = jnp.zeros_like(acc_ref)
 
-    def _compute():
+    def _compute(masked: bool):
         _, k, _, _, ds = _bwd_pair(q_ref, k_ref, v_ref, do_ref, lse_ref,
                                    delta_ref, q_start, k_start,
-                                   causal=causal, scale=scale, window=window)
-        acc_ref[:, :] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+                                   masked=masked, scale=scale, window=window)
+        acc_ref[:, :] += _mxu(ds, k, ((1,), (0,)))
 
     _when_unmasked(causal, q_start, bq, k_start, _compute, window, bk,
                    seq_len)
 
     @pl.when(ki == nk - 1)
     def _finalize():
-        dq_ref[:, :] = acc_ref[:, :].astype(dq_ref.dtype)
+        dq_ref[:, :] = (acc_ref[:, :] * scale).astype(dq_ref.dtype)
 
 
 def _attn_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -648,11 +709,14 @@ _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
 def _auto_block(L: int, cap: int = 1024) -> int:
     """Default tile size: the whole sequence when L <= cap (a single block
     is always tile-legal), else the largest power-of-two divisor of L up to
-    ``cap``.  Measured on v5e at L=8192 (fwd+bwd, H=32, D=128): 128-blocks
-    reach 12 TFLOP/s, 512 62, 1024 85 — big tiles keep the MXU fed and
-    amortize the per-program overhead; past 1024 the VMEM working set no
-    longer fits.  Low-2-adic long sequences (no >=128 tile divides them)
-    raise rather than silently degrading to sliver tiles."""
+    ``cap``.  Big tiles keep the MXU fed and amortize the per-program
+    overhead (some 800 cycles of 6,000 a pair at 1024): measured on v5e at
+    L=8192 (fwd+bwd, H=32, D=128) when the backward was two kernels,
+    128-blocks reached 12 TFLOP/s, 512 62, 1024 85; the one ``flash_bwd``
+    at L=16,384 executes 155 of the chip's 197 on 1024-row tiles and 105 on
+    the window layers' 512 (PERF.md section 6, PR 40); past 1024 the VMEM
+    working set no longer fits.  Low-2-adic long sequences (no >=128 tile
+    divides them) raise rather than silently degrading to sliver tiles."""
     if L <= cap:
         return L
     b = cap
@@ -693,19 +757,27 @@ def blocks_met(L: int, window: Optional[int] = None) -> dict:
     the most any does and the mean (``k_blocks_max``, ``k_blocks_mean``),
     counted as the distinct blocks the kernel's own index map names over
     that dimension: a pair it names no new block for is neither fetched nor
-    run.  A counter for outside the step."""
+    run.  ``edge_blocks_mean``: of those, the mean number that run the
+    masked body, the diagonal or the band's left edge crossing them, by the
+    kernel's own predicates (:func:`_pair_kind`); the others run the body
+    without a mask.  A counter for outside the step."""
     if window is not None and window >= L:
         window = None
     b = _window_block(L, window)
     n = L // b
+    qi, ki = np.arange(n)[:, None], np.arange(n)[None]
     if window is None:
         k_of, inner = _unmasked_k(True, b, b, n), n
     else:
         k_of, inner = _band_k_map(window, b, b, n)
-    named = np.asarray(k_of(np.arange(n)[:, None], np.arange(inner)[None]))
+        ki = _first_k(window, b, b, qi, np.maximum) + np.arange(inner)[None]
+    named = np.asarray(k_of(qi, np.arange(inner)[None]))
     met = [len(set(row)) for row in named.tolist()]
+    runs, whole = _pair_kind(True, qi * b, b, ki * b, b, window, L)
+    assert runs.sum(axis=1).tolist() == met     # it runs what it names
     return {"tile": b, "q_blocks": n, "grid_inner": inner,
-            "k_blocks_max": max(met), "k_blocks_mean": float(np.mean(met))}
+            "k_blocks_max": max(met), "k_blocks_mean": float(np.mean(met)),
+            "edge_blocks_mean": float(np.mean((runs & ~whole).sum(axis=1)))}
 
 
 def flash_attention(
