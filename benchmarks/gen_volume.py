@@ -36,7 +36,7 @@ import numpy as np
 from jax.sharding import NamedSharding
 
 from torchmpi_tpu import parallel
-from torchmpi_tpu.models import llama
+from torchmpi_tpu.models import llama, llama_decode
 from torchmpi_tpu.models.llama import param_specs
 from torchmpi_tpu.models._common import mesh_spec
 
@@ -56,7 +56,7 @@ def main():
         B = 2 * dict(axes).get("dp", 1)
         prompt = jax.ShapeDtypeStruct((B, 512), jnp.int32)
         rng = jax.ShapeDtypeStruct((2,), jnp.uint32)
-        gen = llama.make_generate_fn(cfg, prompt_len=512, max_new=512,
+        gen = llama_decode.make_generate_fn(cfg, prompt_len=512, max_new=512,
                                      mesh=mesh)
         t0 = time.perf_counter()
         compiled = gen.lower(abstract, prompt, rng).compile()

@@ -71,7 +71,7 @@ def main():
     import jax
     import jax.numpy as jnp
 
-    from torchmpi_tpu.models import llama
+    from torchmpi_tpu.models import llama, llama_decode
 
     if args.preset == "tiny":
         cfg = llama.tiny()
@@ -171,7 +171,7 @@ def main():
             params = llama.init(jax.random.PRNGKey(0), cfg, dtype=dtype)
         B, Lp, N = args.decode_batch, args.prompt_len, args.max_new
         prompt = jnp.asarray(rng.randint(0, cfg.vocab, (B, Lp)), jnp.int32)
-        gen = llama.make_generate_fn(cfg, prompt_len=Lp, max_new=N)
+        gen = llama_decode.make_generate_fn(cfg, prompt_len=Lp, max_new=N)
         np.asarray(gen(params, prompt, jax.random.PRNGKey(1)))  # compile
 
         def run_gen():

@@ -27,13 +27,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from torchmpi_tpu import parallel
-from torchmpi_tpu.models import llama
+from torchmpi_tpu.models import llama, llama_pipeline
 from moe_volume import collective_bytes, _flops
 
 
 def build_pp_step(cfg, axes, zero1=False):
     mesh = parallel.make_mesh(axes)
-    params = llama.shard_params_pp(
+    params = llama_pipeline.shard_params_pp(
         llama.init(jax.random.PRNGKey(0), cfg), mesh, cfg)
     B, L = 8, cfg.max_seq
     tokens = jnp.zeros((B, L), jnp.int32)
@@ -41,13 +41,13 @@ def build_pp_step(cfg, axes, zero1=False):
         import optax
 
         opt = optax.adam(1e-3)
-        step, _ = llama.make_pp_train_step(
+        step, _ = llama_pipeline.make_pp_train_step(
             cfg, mesh, n_microbatches=2, optimizer=opt,
             opt_state_example=jax.eval_shape(opt.init, params), zero1=True)
         opt_state = opt.init(params)
         lowered = step.lower(params, opt_state, tokens, tokens)
     else:
-        step, _ = llama.make_pp_train_step(cfg, mesh, n_microbatches=2,
+        step, _ = llama_pipeline.make_pp_train_step(cfg, mesh, n_microbatches=2,
                                            lr=1e-3)
         lowered = step.lower(params, tokens, tokens)
     compiled = lowered.compile()
@@ -73,7 +73,7 @@ def eight_b_slice():
 
     from jax.sharding import NamedSharding
 
-    from torchmpi_tpu.models.llama import param_specs_pp
+    from torchmpi_tpu.models.llama_pipeline import param_specs_pp
     from torchmpi_tpu.models._common import mesh_spec
 
     cfg = dataclasses.replace(llama.llama3_8b(), n_layers=4)
@@ -86,18 +86,18 @@ def eight_b_slice():
             sharding=NamedSharding(mesh, mesh_spec(sp, mesh, sh.shape))),
         pshapes, param_specs_pp(cfg))
     builds = [
-        ("gpipe", "auto", 2, llama.make_pp_train_step),
-        ("gpipe", "manual", 2, llama.make_pp_train_step),
+        ("gpipe", "auto", 2, llama_pipeline.make_pp_train_step),
+        ("gpipe", "manual", 2, llama_pipeline.make_pp_train_step),
         # 1F1B x manual stage: the S-bounded (2S-1 stash) schedule hosting
         # the hand-sharded flash stage — the long-context config-5 form
         # that previously ran GPipe-only (VERDICT r04 item 1).
-        ("1f1b", "manual", 2, llama.make_1f1b_train_step),
+        ("1f1b", "manual", 2, llama_pipeline.make_1f1b_train_step),
         # The stash bound itself: at M=8 GPipe's per-stage activation
         # stash is M-deep and its temp memory grows with it; 1F1B's stays
         # at the 2S-1 level (measured 18.37 vs 10.21 GB, BASELINE.md
         # round-5 table).
-        ("gpipe", "manual", 8, llama.make_pp_train_step),
-        ("1f1b", "manual", 8, llama.make_1f1b_train_step),
+        ("gpipe", "manual", 8, llama_pipeline.make_pp_train_step),
+        ("1f1b", "manual", 8, llama_pipeline.make_1f1b_train_step),
     ]
     for sched, stage_tp, M, make in builds:
         tok = jax.ShapeDtypeStruct((2 * M, 4096), jnp.int32)
@@ -133,7 +133,7 @@ def schedule_8b_rows():
 
     from jax.sharding import NamedSharding
 
-    from torchmpi_tpu.models.llama import param_specs_pp
+    from torchmpi_tpu.models.llama_pipeline import param_specs_pp
     from torchmpi_tpu.models._common import mesh_spec
 
     cfg = dataclasses.replace(llama.llama3_8b(), n_layers=4)
@@ -147,7 +147,7 @@ def schedule_8b_rows():
         pshapes, param_specs_pp(cfg))
     tok = jax.ShapeDtypeStruct((8, 4096), jnp.int32)
     for sched in ("combined", "alternating"):
-        step, _ = llama.make_1f1b_train_step(
+        step, _ = llama_pipeline.make_1f1b_train_step(
             cfg, mesh, n_microbatches=8, lr=1e-4, remat="dots",
             loss_chunk=512, attn="flash", stage_tp="manual",
             manual_schedule=sched)

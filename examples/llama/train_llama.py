@@ -34,7 +34,7 @@ import jax.numpy as jnp
 
 import torchmpi_tpu as mpi
 from torchmpi_tpu import parallel
-from torchmpi_tpu.models import llama
+from torchmpi_tpu.models import llama, llama_decode, llama_pipeline
 
 
 def synthetic_tokens(cfg, n_seq, seq_len, seed=0):
@@ -167,10 +167,10 @@ def main():
             expert_top_k=min(args.moe_top_k, args.moe_experts))
     dtype = jnp.bfloat16 if jax.default_backend() == "tpu" else jnp.float32
     if args.pp > 0:
-        pp_step, V = llama.make_pp_train_step(
+        pp_step, V = llama_pipeline.make_pp_train_step(
             cfg, mesh, n_microbatches=args.microbatches, lr=args.lr,
             attn=args.attn, remat=args.remat, loss_chunk=args.loss_chunk)
-        params = llama.shard_params_pp(
+        params = llama_pipeline.shard_params_pp(
             llama.init(jax.random.PRNGKey(0), cfg, dtype=dtype), mesh, cfg)
         def step(p, o, t, tg):
             p2, loss = pp_step(p, t, tg)
@@ -218,11 +218,10 @@ def main():
             # is fanout/vocab; a trained model should be far above it.
             pl = min(16, args.seq)
             prompts = data[:4, :pl]
-            gen = llama.make_generate_fn(cfg, prompt_len=pl,
-                                         max_new=args.generate,
-                                         temperature=args.temperature,
-                                         top_k=args.top_k,
-                                         top_p=args.top_p)
+            gen = llama_decode.make_generate_fn(
+                cfg, prompt_len=pl, max_new=args.generate,
+                temperature=args.temperature, top_k=args.top_k,
+                top_p=args.top_p)
             out = np.asarray(gen(params, jnp.asarray(prompts),
                                  jax.random.PRNGKey(7)))
             seqs = np.concatenate([prompts, out], axis=1)

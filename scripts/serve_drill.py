@@ -38,7 +38,7 @@ with ``scripts/loadgen.py``'s concurrent clients:
   provisioned on — detection turned into capacity.
 * ``llama_runner`` — the compiled path: two requests of different
   lengths decoded CONCURRENTLY by ``LlamaRunner``'s per-slot-position
-  step match ``models/llama.make_generate_fn`` token for token.
+  step match ``models/llama_decode.make_generate_fn`` token for token.
 
     python scripts/serve_drill.py --quick     # seconds-scale smoke
     python scripts/serve_drill.py             # full drill
@@ -548,7 +548,7 @@ def leg_llama_runner(workdir, quick):
     import jax
     import jax.numpy as jnp
 
-    from torchmpi_tpu.models import llama
+    from torchmpi_tpu.models import llama, llama_decode
 
     cfg = llama.tiny()
     runner = LlamaRunner(slots=2, max_len=64)
@@ -561,7 +561,7 @@ def leg_llama_runner(workdir, quick):
         reqs = [eng.submit(prompts[0], max_new=6, deadline_ms=300000),
                 eng.submit(prompts[1], max_new=3, deadline_ms=300000)]
         done = all(r.done.wait(timeout=300.0) for r in reqs)
-        gen = llama.make_generate_fn(cfg, prompt_len=5, max_new=6)
+        gen = llama_decode.make_generate_fn(cfg, prompt_len=5, max_new=6)
         ref = gen(runner.params, jnp.asarray(prompts, jnp.int32),
                   jax.random.PRNGKey(0))
         ref0 = [int(t) for t in ref[0]]

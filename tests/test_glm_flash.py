@@ -440,28 +440,6 @@ def test_kimi_linear_is_what_it_was():
     assert float(got) == pytest.approx(5.376918792724609, rel=1e-6)
 
 
-@pytest.mark.parametrize("call,missing", [
-    (lambda cfg, p: llama._decode_step(cfg, p, None, None, None),
-     "absorbed form of the query latent"),
-    (lambda cfg, p: llama._prefill(cfg, p, None, jnp.zeros((1, 8), int)),
-     "latent cache to seed decoding"),
-    (lambda cfg, p: llama.make_generate_fn(cfg, 8, 8),
-     "drafts with the module"),
-    (lambda cfg, p: llama.make_pp_train_step(cfg, None, 2),
-     "state before the final norm"),
-    (lambda cfg, p: llama.make_1f1b_train_step(cfg, None, 2),
-     "state before the final norm"),
-    (lambda cfg, p: llama.apply(cfg, p, jnp.zeros((1, 8), int), attn="ring"),
-     "ring form of the latent layer")],
-    ids=["decode", "prefill", "generate", "gpipe", "1f1b", "ring"])
-def test_the_refusals_say_their_reason(model, call, missing):
-    cfg, params = model
-    with pytest.raises(NotImplementedError, match=missing) as refused:
-        call(cfg, params)
-    assert "q_lora_rank=40" in str(refused.value)
-    assert "mtp_layers=1" in str(refused.value)
-
-
 def test_the_programs_names(model, sample):
     """``mtp`` outermost round the module's copy of the names every layer
     has, its embedding read and its pass over the vocabulary; ``mla`` inside

@@ -885,25 +885,6 @@ def test_adamw_leaves_the_selection_bias_alone(model, sample):
     assert sum("router_bias" in run for run in params["layers"]) == 3
 
 
-@pytest.mark.parametrize("call,missing", [
-    (lambda cfg, p: llama._decode_step(cfg, p, None, None, None),
-     "recurrent-state cache"),
-    (lambda cfg, p: llama._prefill(cfg, p, None, jnp.zeros((1, 8), int)),
-     "latent cache"),
-    (lambda cfg, p: llama.make_generate_fn(cfg, 8, 8), "two caches"),
-    (lambda cfg, p: llama.make_pp_train_step(cfg, None, 2),
-     "stage split by run"),
-    (lambda cfg, p: llama.make_1f1b_train_step(cfg, None, 2),
-     "stage split by run"),
-    (lambda cfg, p: llama.apply(cfg, p, jnp.zeros((1, 8), int), attn="ring"),
-     "one head width")],
-    ids=["decode", "prefill", "generate", "gpipe", "1f1b", "ring"])
-def test_the_refusals_say_their_reason(model, call, missing):
-    cfg, params = model
-    with pytest.raises(NotImplementedError, match=missing):
-        call(cfg, params)
-
-
 def test_the_programs_names(model, sample):
     """``kda`` (the recurrence alone) and ``mla`` (the whole latent mixer)
     inside ``attn``, ``moe.shared`` beside the four ``moe.`` scopes, ``ffn``

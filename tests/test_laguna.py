@@ -683,28 +683,6 @@ def test_the_head_width_is_a_field():
     assert float(flash) == pytest.approx(float(full), rel=1e-5)
 
 
-@pytest.mark.parametrize("call,missing", [
-    (lambda cfg, p: llama._decode_step(cfg, p, None, None, None),
-     "rolling cache of swa_window positions"),
-    (lambda cfg, p: llama._prefill(cfg, p, None, jnp.zeros((1, 8), int)),
-     "rolling cache of the last swa_window positions"),
-    (lambda cfg, p: llama.make_generate_fn(cfg, 8, 8),
-     "the gate in the one-row path"),
-    (lambda cfg, p: llama.make_pp_train_step(cfg, None, 2),
-     "head count, window and rotation"),
-    (lambda cfg, p: llama.make_1f1b_train_step(cfg, None, 2),
-     "head count, window and rotation"),
-    (lambda cfg, p: llama.apply(cfg, p, jnp.zeros((1, 8), int), attn="ring"),
-     "ring form of the band")],
-    ids=["decode", "prefill", "generate", "gpipe", "1f1b", "ring"])
-def test_the_refusals_say_their_reason(five, call, missing):
-    cfg, params = five
-    with pytest.raises(NotImplementedError, match=missing) as refused:
-        call(cfg, params)
-    assert "swa_window=24" in str(refused.value)
-    assert "attn_gate=True" in str(refused.value)
-
-
 def test_the_programs_names(five, sample):
     """``swa`` inside ``attn`` round a sliding layer's kernels alone, forward
     and backward; ``attn.gate`` round the gate; the full layers' kernels

@@ -1,0 +1,220 @@
+"""What each consumer of a Llama-family configuration refuses, and says why
+(``llama._LACKS``, read by ``llama._refuse``): one case a (configuration,
+consumer) pair.  The phrases are written out here, not read back from the
+table they check.  Every refusal fires before a parameter is touched, so the
+configurations are toy ones and the parameters mostly absent."""
+
+import dataclasses
+import os
+import re
+import subprocess
+import sys
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from torchmpi_tpu.models import llama, llama_decode, llama_pipeline
+from torchmpi_tpu.parallel import make_mesh
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+_TOY = dict(vocab=128, d_model=64, n_layers=5, n_heads=4, d_ff=32,
+            dense_d_ff=96, max_seq=256, n_experts=8, expert_top_k=2,
+            experts_held=(0, 2))
+_KIMI, _GLM, _LAGUNA = (llama.kimi_linear_48b_a3b(), llama.glm_4_7_flash(),
+                        llama.laguna_s_2_1())
+LOOPED = llama.Config(vocab=256, d_model=64, n_layers=2, n_heads=4,
+                      n_kv_heads=4, d_ff=96, max_seq=128, ut_steps=4,
+                      sandwich_norm=True, exit_gate=True)
+
+CONFIGS = {
+    # Ouro's shape: the stack passed four times, sandwich norms, an exit gate
+    "looped": LOOPED,
+    "sandwich": dataclasses.replace(LOOPED, ut_steps=1, exit_gate=False),
+    # Kimi Linear's: runs of KDA and NoPE latent layers, nothing else
+    "runs": dataclasses.replace(
+        _KIMI, **_TOY, n_kv_heads=4, kda_heads=4, kda_head_dim=16,
+        kv_lora_rank=24, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        v_head_dim=16, layer_kinds=_KIMI.layer_kinds[:5]),
+    # GLM-4.7-Flash's: a query latent, a rotated key part, a module
+    "rotary_latent": dataclasses.replace(
+        _GLM, **_TOY, n_kv_heads=4, q_lora_rank=40, kv_lora_rank=24,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=24,
+        layer_kinds=_GLM.layer_kinds[:5]),
+    # Laguna-S-2.1's: window layers, head gates, YaRN on half a head
+    "window": dataclasses.replace(
+        _LAGUNA, **{**_TOY, "d_model": 48}, n_kv_heads=2, head_dim=16,
+        swa_heads=6, swa_window=24, layer_kinds=_LAGUNA.layer_kinds[:5]),
+    "experts": llama.moe_tiny(),
+    "dropless": dataclasses.replace(llama.moe_tiny(), capacity_factor=None,
+                                    moe_aux_coef=0.0),
+}
+
+_PROMPT = jnp.zeros((1, 8), jnp.int32)
+
+
+def _ep_mesh():
+    return make_mesh({"dp": 2, "ep": 2}, devices=jax.devices()[:4])
+
+
+def _ring(attn):
+    """:func:`llama.apply` on a ring: the embedding is read before the
+    layers' attention is made, so that leaf is there."""
+    return lambda cfg: llama.apply(
+        cfg, {"embed": jnp.zeros((cfg.vocab, cfg.d_model))}, _PROMPT,
+        attn=attn)
+
+
+# A case's consumer -> (its name in the table, a call that reaches its check).
+CONSUMERS = {
+    "decode": ("the decode step", lambda cfg: llama_decode._decode_step(
+        cfg, None, None, None, None)),
+    "prefill": ("prefill", lambda cfg: llama_decode._prefill(
+        cfg, None, None, _PROMPT)),
+    "generate": ("make_generate_fn",
+                 lambda cfg: llama_decode.make_generate_fn(cfg, 8, 8)),
+    "gpipe": ("make_pp_train_step",
+              lambda cfg: llama_pipeline.make_pp_train_step(cfg, None, 2)),
+    "1f1b": ("make_1f1b_train_step",
+             lambda cfg: llama_pipeline.make_1f1b_train_step(cfg, None, 2)),
+    "ring": ("attn='ring'", _ring("ring")),
+    "ring-xla": ("attn='ring-xla'", _ring("ring-xla")),
+    "apply-on-ep": ("apply", lambda cfg: llama.apply(
+        cfg, None, _PROMPT, mesh=_ep_mesh())),
+    "counts": ("expert_unit_counts", lambda cfg: llama.expert_unit_counts(
+        cfg, None, _PROMPT)),
+    "counts-on-ep": ("expert_unit_counts",
+                     lambda cfg: llama.expert_unit_counts(
+                         cfg, None, _PROMPT, mesh=_ep_mesh())),
+}
+
+# (configuration, consumer) -> the phrases its refusal holds.
+_GLM_FIELDS = ("q_lora_rank=40", "mtp_layers=1")
+_LAGUNA_FIELDS = ("swa_window=24", "attn_gate=True")
+CASES = {
+    ("looped", "decode"): ("looped configuration", "the decode step"),
+    ("looped", "prefill"): ("looped configuration", "prefill"),
+    ("looped", "generate"): ("looped configuration", "make_generate_fn"),
+    ("looped", "gpipe"): ("looped configuration", "make_pp_train_step",
+                          "passed ut_steps times"),
+    ("looped", "1f1b"): ("looped configuration", "make_1f1b_train_step"),
+    ("looped", "counts"): ("looped configuration", "ut_steps=4",
+                           "each recurrent step's routers"),
+    # sandwich norms alone refuse too: those paths norm no branch's output
+    ("sandwich", "prefill"): ("sandwich_norm=True", "ut_steps=1"),
+    ("runs", "decode"): ("recurrent-state cache",),
+    ("runs", "prefill"): ("latent cache",),
+    ("runs", "generate"): ("two caches",),
+    ("runs", "gpipe"): ("stage split by run",),
+    ("runs", "1f1b"): ("stage split by run",),
+    ("runs", "ring"): ("one head width", "attn='ring'", "kda/moe"),
+    ("runs", "ring-xla"): ("one head width", "attn='ring-xla'"),
+    ("rotary_latent", "decode"): ("absorbed form of the query latent",
+                                  *_GLM_FIELDS),
+    ("rotary_latent", "prefill"): ("latent cache to seed decoding",
+                                   *_GLM_FIELDS),
+    ("rotary_latent", "generate"): ("drafts with the module", *_GLM_FIELDS),
+    ("rotary_latent", "gpipe"): ("state before the final norm",
+                                 *_GLM_FIELDS),
+    ("rotary_latent", "1f1b"): ("state before the final norm", *_GLM_FIELDS),
+    ("rotary_latent", "ring"): ("ring form of the latent layer",
+                                *_GLM_FIELDS),
+    ("window", "decode"): ("rolling cache of swa_window positions",
+                           *_LAGUNA_FIELDS),
+    ("window", "prefill"): ("rolling cache of the last swa_window positions",
+                            *_LAGUNA_FIELDS),
+    ("window", "generate"): ("the gate in the one-row path",
+                             *_LAGUNA_FIELDS),
+    ("window", "gpipe"): ("head count, window and rotation",
+                          *_LAGUNA_FIELDS),
+    ("window", "1f1b"): ("head count, window and rotation", *_LAGUNA_FIELDS),
+    ("window", "ring"): ("ring form of the band", *_LAGUNA_FIELDS),
+    ("experts", "gpipe"): ("mixture of experts", "n_experts=4",
+                           "aux loss through the stage boundary"),
+    ("experts", "1f1b"): ("mixture of experts", "make_1f1b_train_step"),
+    ("dropless", "apply-on-ep"): ("ep", "capacity_factor=None",
+                                  "sorted dispatch", "mesh without ep"),
+    ("dropless", "counts-on-ep"): ("expert_unit_counts", "sorted dispatch"),
+}
+
+
+@pytest.mark.parametrize("config,consumer", list(CASES),
+                         ids=["-".join(case) for case in CASES])
+def test_the_refusals_say_their_reason(config, consumer):
+    with pytest.raises(NotImplementedError) as refused:
+        CONSUMERS[consumer][1](CONFIGS[config])
+    for phrase in CASES[config, consumer]:
+        assert phrase in str(refused.value)
+
+
+def test_every_row_of_the_table_is_reached():
+    """A row whose trait no predicate yields could never fire: every trait
+    the table names is one of a configuration here, and each consumer's
+    cases above reach every one of its rows."""
+    traits = set().union(*(llama._traits(cfg) for cfg in CONFIGS.values()))
+    assert traits == {"looped", "runs", "rotary_latent", "window", "experts",
+                      "dropless"}
+    for consumer, rows in llama._LACKS.items():
+        assert set(rows) <= traits, consumer
+    reached = {(CONSUMERS[consumer][0],
+                "looped" if config == "sandwich" else config)
+               for config, consumer in CASES}
+    # the rings share their rows, one name each
+    wanted = {(consumer, trait) for consumer, rows in llama._LACKS.items()
+              for trait in rows if consumer not in ("attn='ring-zigzag'",
+                                                    "attn='ring-xla'")}
+    assert wanted <= reached, wanted - reached
+
+
+def test_what_is_not_refused():
+    """The plain stack passes every consumer's check; experts do where the
+    consumer has no row for them; a dropless configuration everywhere but
+    on an ``ep`` axis."""
+    for consumer in llama._LACKS:
+        llama._refuse(llama.tiny(), consumer)
+        llama._refuse(llama.tiny(), consumer, _ep_mesh())
+    for consumer in ("the decode step", "prefill", "make_generate_fn",
+                     "apply", "expert_unit_counts", "attn='ring'"):
+        llama._refuse(CONFIGS["experts"], consumer, _ep_mesh())
+        llama._refuse(CONFIGS["dropless"], consumer)
+        llama._refuse(CONFIGS["dropless"], consumer,
+                      make_mesh({"dp": 2, "tp": 2},
+                                devices=jax.devices()[:4]))
+
+
+def _names_the_benchmark_takes():
+    """Every ``llama.<name>`` in the files under ``benchmark/``, in code or
+    in a docstring that points at the program."""
+    names = set()
+    for where, _, files in os.walk(os.path.join(ROOT, "benchmark")):
+        for name in files:
+            if name.endswith(".py"):
+                with open(os.path.join(where, name)) as f:
+                    names.update(re.findall(r"\bllama\.([A-Za-z_]\w*)",
+                                            f.read()))
+    return names - {"py"}
+
+
+def test_the_training_path_stands_alone():
+    """In a fresh interpreter ``models.llama`` imports neither of the modules
+    built on it nor ``serving``, and holds every name ``benchmark/`` takes
+    from it."""
+    names = sorted(_names_the_benchmark_takes())
+    assert {"Config", "apply", "make_train_step", "_wrap_remat"} <= set(names)
+    script = (
+        "import sys\n"
+        "import torchmpi_tpu.models.llama as llama\n"
+        "above = [m for m in sys.modules if m in (\n"
+        "    'torchmpi_tpu.models.llama_decode',\n"
+        "    'torchmpi_tpu.models.llama_pipeline')\n"
+        "    or m.startswith('torchmpi_tpu.serving')]\n"
+        "assert not above, above\n"
+        f"missing = [n for n in {names!r} if not hasattr(llama, n)]\n"
+        "assert not missing, missing\n")
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "PYTHONPATH": ROOT + os.pathsep + os.environ.get("PYTHONPATH", "")}
+    done = subprocess.run([sys.executable, "-c", script], env=env, cwd=ROOT,
+                          capture_output=True, text=True, timeout=300)
+    assert done.returncode == 0, done.stderr[-2000:]

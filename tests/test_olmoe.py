@@ -17,7 +17,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from torchmpi_tpu.models import llama
+from torchmpi_tpu.models import llama, llama_decode
 from torchmpi_tpu.parallel import make_mesh
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -254,23 +254,15 @@ def test_prefill_and_decode_apply_qk_norm(weights, sample):
     forcing, which ignoring the norm's weights would not."""
     tokens = sample[0][:, :33]
     full = llama.apply(CFG, weights, tokens)
-    cache = llama.init_kv_cache(CFG, tokens.shape[0], 64)
-    logits, cache = jax.jit(lambda p, c, t: llama._prefill(CFG, p, c, t))(
+    cache = llama_decode.init_kv_cache(CFG, tokens.shape[0], 64)
+    logits, cache = jax.jit(lambda p, c, t: llama_decode._prefill(CFG, p, c, t))(
         weights, cache, tokens[:, :32])
     np.testing.assert_allclose(logits, full[:, 31], rtol=1e-4, atol=1e-4)
-    step, _ = jax.jit(lambda p, c, t: llama._decode_step(
-        CFG, p, c, t, jnp.asarray(32)))(weights, cache, tokens[:, 32])
+    step, _ = jax.jit(lambda p, c, t: llama_decode._decode_step(
+        CFG, p, c, t, jnp.full(t.shape, 32)))(weights, cache, tokens[:, 32])
     np.testing.assert_allclose(step, full[:, 32], rtol=1e-4, atol=1e-4)
     plain = llama.apply(dataclasses.replace(CFG, qk_norm=False), weights, tokens)
     assert float(jnp.max(jnp.abs(plain[:, 32] - full[:, 32]))) > 1e-2
-
-
-def test_the_tp_manual_stage_refuses_qk_norm(weights):
-    """(e) its column shards cannot norm over the whole projection."""
-    lp = jax.tree.map(lambda a: a[0], weights["layers"])
-    h = jnp.zeros((1, 16, CFG.d_model))
-    with pytest.raises(NotImplementedError, match="QK-norm"):
-        llama._decoder_layer_tp_manual(CFG, lp, h, jnp.arange(16))
 
 
 def test_dropless_on_a_mesh(weights, sample):

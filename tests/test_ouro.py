@@ -413,29 +413,6 @@ def test_one_step_is_bit_equal_to_before(depth):
     assert tuple(found) == PINNED[depth]
 
 
-# ------------------------------------------------------------- what refuses
-
-def test_decode_prefill_and_the_pipelines_refuse_a_looped_model(weights):
-    """A cache of T x N slots and looped pipeline stages are not written:
-    each path says so by name and does not run the layers once."""
-    cache = llama.init_kv_cache(CFG, 1, 16)
-    prompt = jnp.zeros((1, 8), jnp.int32)
-    mesh = make_mesh({"pp": 1}, devices=jax.devices()[:1])
-    for call in (
-            lambda: llama._prefill(CFG, weights, cache, prompt),
-            lambda: llama._decode_step(CFG, weights, cache, prompt[:, 0],
-                                       jnp.asarray(8)),
-            lambda: llama.make_generate_fn(CFG, 8, 4),
-            lambda: llama.make_pp_train_step(CFG, mesh, 1),
-            lambda: llama.make_1f1b_train_step(CFG, mesh, 1)):
-        with pytest.raises(NotImplementedError, match="looped configuration"):
-            call()
-    # sandwich norms alone refuse too: those paths norm no branch's output
-    with pytest.raises(NotImplementedError, match="sandwich_norm=True"):
-        llama._prefill(dataclasses.replace(CFG, ut_steps=1, exit_gate=False),
-                       weights, cache, prompt)
-
-
 def test_looped_on_a_mesh(weights, sample):
     """Under GSPMD on dp x tp the looped step gives one device's loss and
     gradients: the post-norms run over a row-sharded product's sum."""

@@ -429,28 +429,77 @@ class TestLlamaRunner:
         assert _bucket_len(600, 512) == 512     # capped at cache length
         assert len({_bucket_len(n, 1 << 15) for n in range(1, 513)}) == 7
 
-    def test_matches_reference_generation(self):
+    @staticmethod
+    def _served_and_generated(cfg, prompt, max_new=4):
+        """(the tokens the engine serves, ``make_generate_fn``'s) for one
+        prompt under one set of weights."""
         import jax
+        import numpy as np
 
-        from torchmpi_tpu.models import llama
+        from torchmpi_tpu.models import llama_decode
 
-        cfg = llama.tiny()
         runner = LlamaRunner(2, cfg=cfg, max_len=32)
-        prompt = [1, 2, 3, 4, 5]
-        ecfg = _cfg(max_batch=2, max_new_tokens=4, block_size=4,
+        ecfg = _cfg(max_batch=2, max_new_tokens=max_new, block_size=4,
                     kv_blocks=32)
         pool = BlockPool(ecfg["kv_blocks"], ecfg["block_size"])
         eng = ServeEngine(runner=runner, pool=pool, cfg=ecfg)
-        req = eng.submit(prompt, max_new=4)
+        req = eng.submit(prompt, max_new=max_new)
         _drive(eng, [req])
-        ref_fn = llama.make_generate_fn(cfg, prompt_len=len(prompt),
-                                        max_new=4)
-        import numpy as np
-
-        ref = ref_fn(runner.params,
-                     np.asarray([prompt], dtype=np.int32),
+        ref_fn = llama_decode.make_generate_fn(cfg, prompt_len=len(prompt),
+                                               max_new=max_new)
+        ref = ref_fn(runner.params, np.asarray([prompt], dtype=np.int32),
                      jax.random.PRNGKey(0))
-        assert req.tokens == [int(t) for t in np.asarray(ref)[0]]
+        return req.tokens, [int(t) for t in np.asarray(ref)[0]]
+
+    def test_matches_reference_generation(self):
+        from torchmpi_tpu.models import llama
+
+        served, generated = self._served_and_generated(llama.tiny(),
+                                                       [1, 2, 3, 4, 5])
+        assert served == generated
+
+    def test_serves_experts_and_qk_norm(self):
+        """The runner decodes through the model's own step, so what that
+        step knows it serves: a mixture of experts with QK-norm, token for
+        token (a whole bucket of prompt: an expert's capacity counts pads)."""
+        import dataclasses
+
+        from torchmpi_tpu.models import llama
+
+        cfg = dataclasses.replace(llama.moe_tiny(), qk_norm=True,
+                                  capacity_factor=8.0)
+        served, generated = self._served_and_generated(
+            cfg, [1, 2, 3, 4, 5, 6, 7, 8])
+        assert served == generated
+        plain = self._served_and_generated(
+            dataclasses.replace(cfg, qk_norm=False), [1, 2, 3, 4, 5, 6, 7, 8])
+        assert plain[0] == plain[1] and plain[0] != served
+
+    @pytest.mark.parametrize("trait,said", [
+        ("looped", "looped configuration"),
+        ("runs", "stack of runs")])
+    def test_refuses_what_decode_refuses(self, trait, said):
+        """A configuration the decode path has no cache for is stopped by
+        the model's table at the first prefill; nothing is decoded."""
+        import dataclasses
+
+        from torchmpi_tpu.models import llama
+
+        kimi = llama.kimi_linear_48b_a3b()
+        cfg = {
+            "looped": dataclasses.replace(llama.tiny(), ut_steps=2),
+            "runs": dataclasses.replace(
+                kimi, vocab=128, d_model=64, n_layers=2, n_heads=4,
+                n_kv_heads=4, d_ff=32, dense_d_ff=96, max_seq=64,
+                n_experts=8, expert_top_k=2, kda_heads=4, kda_head_dim=16,
+                kv_lora_rank=24, qk_nope_head_dim=16, qk_rope_head_dim=8,
+                v_head_dim=16, layer_kinds=kimi.layer_kinds[:2]),
+        }[trait]
+        runner = LlamaRunner(2, cfg=cfg, max_len=32)
+        with pytest.raises(NotImplementedError, match=said):
+            runner.prefill(0, [1, 2, 3])
+        with pytest.raises(NotImplementedError, match="the decode step"):
+            runner.decode([1, 1], [3, 0], [True, False])
 
 
 # ------------------------------------------------- concurrent race class

@@ -80,8 +80,8 @@ SUPPRESSIONS: List[Suppression] = [
         rationale="1F1B tick/stage predicates depend only on "
                   "(tick, stage, microbatch count) — identical constants "
                   "on every rank of the group, so every rank takes the "
-                  "same branch (llama._make_tp_ce_sum docstring; the "
-                  "alternating schedule is cond-gated by design)")
+                  "same branch (llama_pipeline._make_tp_ce_sum docstring; "
+                  "the alternating schedule is cond-gated by design)")
     for p in ("1f1b_manual_tp_combined", "1f1b_manual_tp_alternating")
 ] + [
     Suppression(
@@ -92,7 +92,7 @@ SUPPRESSIONS: List[Suppression] = [
                   "numerics whose operands are already vocab-reduced "
                   "(B, C) — bytes are B*C, not the B*C*V a gradient wire "
                   "carries; the CE *gradient* psum rides the gate "
-                  "(llama._make_tp_ce_sum bwd)")
+                  "(llama_pipeline._make_tp_ce_sum bwd)")
     for p in ("1f1b_manual_tp_combined", "1f1b_manual_tp_alternating")
 ]
 

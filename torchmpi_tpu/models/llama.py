@@ -1,53 +1,45 @@
-"""Llama-family decoder-only transformer (RMSNorm, RoPE, SwiGLU, GQA) with
-first-class dp x tp x sp sharding — BASELINE config 5 ("Llama-3-8B
-hierarchical comm (intra-host ICI x inter-host DCN) data+model parallel").
+"""Llama-family decoder-only transformer (RMSNorm, RoPE, SwiGLU, GQA) and its
+training path on a dp x tp x sp (x ep) mesh: ``Config`` and the presets,
+:func:`init`, :func:`param_specs` and :func:`shard_params`, the blocks,
+:func:`apply`, :func:`make_loss_fn`, :func:`make_train_step`.  Decoding is
+``llama_decode``, the pipeline schedules ``llama_pipeline``; both import this
+module and it imports neither.
 
-The reference has no transformer; this model exists because the driver's
-north star includes Llama-scale training over the hierarchical communicator
-machinery (SURVEY.md §5.7, §7 item 7-8).  TPU-first design:
-
-* layer parameters are **stacked** (leading ``n_layers`` axis) and the
-  forward is a ``lax.scan`` over layers — one compiled block, fast compiles
-  at depth 32+, and the natural substrate for pipeline stacking; a shallow
-  stack (a chip's slice of an MoE model) is inlined instead, which spares
-  the scan's copies (:func:`apply`, ``layer_loop``); a looped model
-  (``Config(ut_steps=T)``, Ouro-style — :func:`ouro_2_6b`) runs the one
-  stack T times with shared weights, a head and an exit gate at every step;
-  a stack whose layers differ in kind (``Config(layer_kinds=...)``, Kimi
-  Linear-style — :func:`kimi_linear_48b_a3b`: KDA linear-attention layers
-  among latent-attention ones, a dense first layer before expert layers) is
-  a sequence of homogeneous runs (:func:`layer_runs`), ``params["layers"]``
-  a tuple of such stacks, and the depth that decides between scan and
-  inlining is each run's own: the runs follow one another inlined, so kinds
-  that alternate every few layers mean every layer inlined; a
-  multi-token-prediction module (``Config(mtp_layers=1)``, GLM-4.7-Flash —
-  :func:`glm_4_7_flash`) is one more layer after the stack with a loss of its
-  own through the same embedding and head (:func:`_mtp_input`); window and
-  full softmax layers in a pattern (``"swa"`` among ``"attn"``, Laguna-S-2.1
-  — :func:`laguna_s_2_1`) are two kinds of run with head counts, rotations
-  and a key window of their own, and a gate a head on the attention output;
+* Layer parameters are **stacked** (leading ``n_layers`` axis).  A shallow
+  stack is inlined, a deeper one a ``lax.scan`` over layers (one compiled
+  block; :func:`apply`, ``_INLINE_MAX_LAYERS``).  A looped model
+  (``Config(ut_steps=T)``, :func:`ouro_2_6b`) runs the one stack T times with
+  shared weights, a head and an exit gate at every step.  A stack whose
+  layers differ in kind (``Config(layer_kinds=...)``: KDA linear-attention
+  and latent-attention layers, :func:`kimi_linear_48b_a3b`; window and full
+  softmax layers with head counts, rotations and a key window of their own,
+  :func:`laguna_s_2_1`) is a sequence of homogeneous runs
+  (:func:`layer_runs`), ``params["layers"]`` a tuple of such stacks, each run
+  inlined or scanned by its own length.  A multi-token-prediction module
+  (``Config(mtp_layers=1)``, :func:`glm_4_7_flash`) is one more layer after
+  the stack with a loss of its own through the same embedding and head
+  (:func:`_mtp_input`).
 * :func:`param_specs` returns the PartitionSpec pytree for Megatron-style
-  tensor parallelism (qkv/gate/up column-sharded, o/down row-sharded) —
-  under pjit GSPMD inserts exactly the one-psum-per-block collectives the
-  hand-written shard_map forms in parallel/tp.py produce;
-* activations carry ``with_sharding_constraint`` annotations: batch on
-  ``dp``, sequence on ``sp``;
-* attention is pluggable: ``attn="full"`` (GSPMD partitions heads over
-  tp), ``attn="flash"`` (Pallas kernels, ops/flash_attention.py), or
+  tensor parallelism (qkv/gate/up column-sharded, o/down row-sharded): under
+  pjit GSPMD inserts the one-psum-per-block collectives the hand-written
+  shard_map forms in parallel/tp.py produce.  Activations carry
+  ``with_sharding_constraint`` annotations: batch on ``dp``, sequence on
+  ``sp``.
+* Attention is pluggable: ``attn="full"`` (GSPMD partitions heads over tp),
+  ``attn="flash"`` (Pallas kernels, ops/flash_attention.py), or
   ``attn="ring"`` (shard_map ring attention over ``sp`` for long contexts,
-  parallel/sequence.py);
-* beyond the scanned dp x tp (x sp) step: pipeline-parallel training
-  (:func:`make_pp_train_step`, layers as GPipe stages) and compiled
-  KV-cache autoregressive generation (:func:`make_generate_fn`, batched
-  prefill + grouped-GQA cache attention, token-exact vs teacher forcing);
-* mixture-of-experts FFN (``Config(n_experts=E, expert_top_k=k)``,
-  Mixtral-style — :func:`mixtral_8x7b`): GShard dispatch/combine einsums
-  with expert weights sharded over ``ep`` (:func:`_moe_ffn`), Switch
-  load-balance aux loss through the layer loop, dropless decode routing;
-  a dropless configuration sorts its routed units for a grouped matmul
-  (:func:`_moe_ffn_sorted`), with sigmoid scoring, a selection bias, a shared
-  expert and a chip's share of the experts (``Config(experts_held=...)``,
-  :func:`_held_experts`) where the configuration has them.
+  parallel/sequence.py).
+* Mixture-of-experts FFN (``Config(n_experts=E, expert_top_k=k)``,
+  :func:`mixtral_8x7b`): GShard dispatch/combine einsums with expert weights
+  sharded over ``ep`` (:func:`_moe_ffn`) and a Switch load-balance aux loss
+  through the layer loop.  A dropless configuration sorts its routed units
+  for a grouped matmul (:func:`_moe_ffn_sorted`), with sigmoid scoring, a
+  selection bias, a shared expert and a chip's share of the experts
+  (``Config(experts_held=...)``, :func:`_held_experts`) where the
+  configuration has them.
+* What each consumer of a configuration (decode, prefill, generation, the
+  pipeline schedules, the rings, :func:`apply` itself) cannot run yet is one
+  table, ``_LACKS``, read by one :func:`_refuse`.
 
 Compute dtype is configurable (bfloat16 for TPU, float32 for CPU tests);
 norms, softmax, and the loss run in f32.
@@ -67,7 +59,7 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..parallel.mesh import AXIS_DP, AXIS_EP, AXIS_PP, AXIS_SP, AXIS_TP
+from ..parallel.mesh import AXIS_DP, AXIS_EP, AXIS_SP, AXIS_TP
 from ..parallel.moe import route_topk as _route_topk
 from ._common import dense_init as _dense, mesh_spec as _mesh_spec, \
     num_params, shard_by_specs, stack_dense
@@ -903,6 +895,9 @@ def _kda_sharded(mesh: Optional[Mesh], heads: int, eps: float) -> Callable:
         in_specs=(wide,) * 6 + (P(None, tp),) * 3 + (P(tp), P(tp), P()))
 
 
+_RINGS = ("ring", "ring-zigzag", "ring-xla")
+
+
 def _make_attn_impl(cfg: Config, attn: str, mesh: Optional[Mesh],
                     scale: float, mixer: str = "attn") -> Callable:
     """Resolve the attention mode to one callable ``(q, k, v) -> o`` with
@@ -912,10 +907,10 @@ def _make_attn_impl(cfg: Config, attn: str, mesh: Optional[Mesh],
     last ``cfg.swa_window`` keys."""
     H, KV = softmax_heads(cfg, mixer), cfg.n_kv_heads
     window = cfg.swa_window if mixer == "swa" else None
-    if attn in ("ring", "ring-xla", "ring-zigzag"):
+    if attn in _RINGS:
         if mesh is None:
             raise ValueError("attn='ring' needs a mesh with an sp axis")
-        assert window is None, "refused before (_refuse_window)"
+        assert window is None, "refused before (_LACKS, the rings' rows)"
         # K/V enter the ring at their native n_kv_heads — the ring
         # circulates 1/(H/KV) of the bytes; blocks repeat locally.
         # Contiguous head sharding over tp keeps each rank's q heads
@@ -1692,150 +1687,150 @@ def _nll_from_hidden(head: jax.Array, h: jax.Array, targets: jax.Array,
     return _chunked_nll(head, h, targets, weights, C)
 
 
-def _make_tp_ce_sum(axis: str):
-    """Summed next-token CE with a VOCAB-COLUMN-SHARDED head, for use
-    INSIDE a manual shard_map region: ``ce(head_local, h, targets)`` where
-    ``head_local`` is this device's (D, V/tp) shard and ``h`` is
-    tp-replicated.  Forward uses pmax/psum over ``axis`` for the global
-    logsumexp and the cross-shard target-logit pick; backward is the
-    ANALYTIC softmax-minus-onehot rule with an explicit psum on ``dh`` —
-    a ``custom_vjp``, because inside a manual region no partitioner
-    rewrites transposes and a plain ``lax.psum``'s transpose is identity
-    (measured wrong, round-5 probe).  Collectives are legal under the
-    1F1B schedule's ``lax.cond`` s: every predicate is uniform across the
-    tp group (it depends only on (tick, stage)).
 
-    Returns the SUM of per-token NLL over the block (callers divide by
-    the global token count), so chunked accumulation composes by
-    addition.  Reference: the tp-sharded classifier + criterion the
-    reference runs per model-parallel shard, mnist_modelparallel.lua.
-    """
-
-    @jax.custom_vjp
-    def ce(head_local, h, targets):
-        return _fwd_core(head_local, h, targets)[0]
-
-    def _fwd_core(head_local, h, targets):
-        Vl = head_local.shape[-1]
-        off = lax.axis_index(axis) * Vl
-        logits = (h @ head_local).astype(jnp.float32)       # (B, C, Vl)
-        m = lax.pmax(jnp.max(logits, axis=-1), axis)        # (B, C)
-        e = jnp.exp(logits - m[..., None])
-        s = lax.psum(jnp.sum(e, axis=-1), axis)             # (B, C)
-        lse = jnp.log(s) + m
-        tloc = targets - off
-        in_shard = (tloc >= 0) & (tloc < Vl)
-        tclip = jnp.clip(tloc, 0, Vl - 1)
-        tlogit = jnp.take_along_axis(logits, tclip[..., None], axis=-1)[..., 0]
-        tlogit = lax.psum(jnp.where(in_shard, tlogit, 0.0), axis)
-        return jnp.sum(lse - tlogit), (e, s, m, in_shard, tclip)
-
-    def fwd(head_local, h, targets):
-        loss, (e, s, m, in_shard, tclip) = _fwd_core(head_local, h, targets)
-        # Residuals are the SMALL terms only (m, s, masks: (B, C) each);
-        # the (B, C, V/tp) exp array is recomputed in bwd from h @ head —
-        # otherwise the chunked scan would stack full-logits-sized
-        # residuals per chunk and loss_chunk's memory cap would be a lie.
-        return loss, (head_local, h, s, m, in_shard, tclip)
-
-    def bwd(saved, g):
-        from ..parallel import tp as _tp
-
-        head_local, h, s, m, in_shard, tclip = saved
-        Vl = head_local.shape[-1]
-        logits = (h @ head_local).astype(jnp.float32)
-        p = jnp.exp(logits - m[..., None]) / s[..., None]   # local softmax cols
-        sub = jnp.where(in_shard, g, 0.0)
-        dl = p * g - jax.nn.one_hot(tclip, Vl, dtype=p.dtype) * sub[..., None]
-        # dh sums over the local vocab shard only — psum completes it (the
-        # seed hand-off downstream needs the true cotangent).  This is a
-        # gradient wire: it rides the backend-gated manual wire dtype
-        # (bf16 on TPU — half the bytes per seed hand-off; f32 elsewhere).
-        wire = _tp.resolve_wire_dtype()
-        dh = lax.psum((dl @ head_local.T.astype(jnp.float32)).astype(wire),
-                      axis).astype(jnp.float32)
-        dw = jnp.einsum("bcd,bcv->dv", h.astype(jnp.float32), dl)
-        return (dw.astype(head_local.dtype), dh.astype(h.dtype),
-                np.zeros(tclip.shape, jax.dtypes.float0))
-
-    ce.defvjp(fwd, bwd)
-    return ce
-
-
-def _nll_from_hidden_tp_manual(head_local: jax.Array, h: jax.Array,
-                               targets: jax.Array, loss_chunk: int,
-                               axis: str = AXIS_TP) -> jax.Array:
-    """Mean next-token NLL from post-norm hidden states with the head
-    vocab-sharded over the manual ``axis`` — the manual-region counterpart
-    of :func:`_nll_from_hidden`, same chunking contract (``loss_chunk``
-    caps the live (B, C, V/tp) f32 logits)."""
-    B, L, _ = h.shape
-    N = B * L
-    ce = _make_tp_ce_sum(axis)
-    if not loss_chunk:
-        return ce(head_local, h, targets) / N
-    C = int(loss_chunk)
-    if L % C:
-        raise ValueError(f"seq len {L} not divisible by loss_chunk {C}")
-
-    def step(acc, idx):
-        h_c = lax.dynamic_slice_in_dim(h, idx * C, C, axis=1)
-        t_c = lax.dynamic_slice_in_dim(targets, idx * C, C, axis=1)
-        return acc + ce(head_local, h_c, t_c), None
-
-    total, _ = lax.scan(step, jnp.zeros((), jnp.float32), jnp.arange(L // C))
-    return total / N
-
-
-def _refuse_dropless_ep(cfg: Config, mesh: Optional[Mesh]) -> None:
-    if (cfg.n_experts and cfg.capacity_factor is None and mesh is not None
-            and dict(mesh.shape).get(AXIS_EP, 1) > 1):
-        raise NotImplementedError(
-            "a dropless configuration (capacity_factor=None) sorts its "
-            "routed units into one array for a grouped matmul, which has no "
-            "form yet for experts sharded over an ep axis "
-            f"(ep={dict(mesh.shape)[AXIS_EP]}): use a mesh without ep, or a "
-            "capacity_factor for the one-hot dispatch that GSPMD shards")
-
-
-def _refuse_looped(cfg: Config, what: str) -> None:
+def _traits(cfg: Config) -> Dict[str, str]:
+    """What ``cfg`` is beyond one stack of identical full-attention layers
+    passed once, ``{trait: what to call it, with the field values that say
+    so}``: the one place each trait's predicate is written.  Every consumer of
+    a configuration takes the plain stack; what it takes of these is
+    ``_LACKS``'s to say, so a new kind of configuration adds a predicate here
+    and its rows there."""
+    called = lambda what, *fields: "{} ({})".format(
+        what, ", ".join(f"{name}={getattr(cfg, name)}" for name in fields))
+    found = {}
     if cfg.ut_steps > 1 or cfg.sandwich_norm or cfg.exit_gate:
-        raise NotImplementedError(
-            f"{what} runs its layers once, with no norm on a branch's output "
-            "and no exit gate: it has no form yet for a looped configuration "
-            f"(ut_steps={cfg.ut_steps}, sandwich_norm={cfg.sandwich_norm}, "
-            f"exit_gate={cfg.exit_gate}), whose cache would hold ut_steps x "
-            "n_layers slots and whose pipeline stages would be passed "
-            "ut_steps times; train it with make_train_step")
-
-
-def _refuse_runs(cfg: Config, what: str, missing: str) -> None:
+        found["looped"] = called("a looped configuration", "ut_steps",
+                                 "sandwich_norm", "exit_gate")
     if cfg.layer_kinds is not None:
-        raise NotImplementedError(
-            f"{what} has no form yet for a stack of runs of different layer "
-            f"kinds (KDA and latent-attention layers among them): it lacks "
-            f"{missing}; train it with make_train_step")
-
-
-def _refuse_rotary_latent(cfg: Config, what: str, missing: str) -> None:
+        found["runs"] = (
+            "a stack of runs of different layer kinds (KDA and "
+            "latent-attention layers among them; layer_kinds of {})".format(
+                ", ".join(sorted({"/".join(k) for k in cfg.layer_kinds}))))
     if cfg.q_lora_rank or cfg.mla_rope or cfg.mtp_layers:
-        raise NotImplementedError(
-            f"{what} has no form yet for rotary latent attention with a query "
-            f"latent or for a multi-token-prediction module (q_lora_rank="
-            f"{cfg.q_lora_rank}, mla_rope={cfg.mla_rope}, mtp_layers="
-            f"{cfg.mtp_layers}): it lacks {missing}; train it with "
-            "make_train_step")
-
-
-def _refuse_window(cfg: Config, what: str, missing: str) -> None:
+        found["rotary_latent"] = called(
+            "rotary latent attention with a query latent or a "
+            "multi-token-prediction module", "q_lora_rank", "mla_rope",
+            "mtp_layers")
     if (cfg.swa_window or cfg.attn_gate or cfg.rope_yarn is not None
             or cfg.rope_fraction != 1):
-        raise NotImplementedError(
-            f"{what} has no form yet for window layers among full ones, a "
-            f"gate on the attention output or a scaled or partial rotation "
-            f"(swa_window={cfg.swa_window}, attn_gate={cfg.attn_gate}, "
-            f"rope_fraction={cfg.rope_fraction}, rope_yarn={cfg.rope_yarn}): "
-            f"it lacks {missing}; train it with make_train_step")
+        found["window"] = called(
+            "window layers among full ones, a gate on the attention output "
+            "or a scaled or partial rotation", "swa_window", "attn_gate",
+            "rope_fraction", "rope_yarn")
+    if cfg.n_experts and cfg.capacity_factor is None:
+        found["dropless"] = called("a dropless configuration on an ep axis",
+                                   "capacity_factor")
+    if cfg.n_experts:
+        found["experts"] = called("a mixture of experts", "n_experts")
+    return found
+
+
+def _rows(instead: str, **lacks: str) -> Dict[str, str]:
+    """A consumer's rows of ``_LACKS``, ``{trait: what the consumer lacks for
+    it; what to do instead}``, in the order the consumer checks them."""
+    return {trait: f"{text}; {instead}" for trait, text in lacks.items()}
+
+
+_TRAIN = "train it with make_train_step"
+
+# Both pipeline schedules run one stage program.
+_STAGE_ROWS = _rows(
+    _TRAIN,
+    rotary_latent="a last stage that hands the module the state before the "
+    "final norm, and the embedding on the first and the last stage at once",
+    window="stages whose layers differ in head count, window and rotation (a "
+    "stage is one stacked scan of identical layers, its attention made once) "
+    "and the gate in the hand-sharded layer",
+    runs="a stage split by run (a stage is one stacked scan of identical "
+    "layers)",
+    experts="a carrier that threads the aux loss through the stage boundary "
+    "(the carrier is a single (mb, L, D) array)",
+    looped="stages that are passed ut_steps times, a norm on a branch's "
+    "output and an exit gate (a stage runs its layers once)")
+
+_RING_ROWS = _rows(
+    "take attn='full' or 'flash'",
+    rotary_latent="a ring form of the latent layer (the one rotated key part "
+    "all heads share would circulate with every head's keys) and a module "
+    "whose next token and target lie past a sequence shard's edge",
+    window="a ring form of the band (a sequence shard meets its left "
+    "neighbours' last swa_window keys alone) and the gate and the scaled, "
+    "partial rotation in the ring's hand-sharded layer",
+    runs="ring kernels for more than one head width (they take one head "
+    "width for q, k and v) and a recurrent state that crosses sequence shards")
+
+_NO_EP = _rows(
+    "use a mesh without ep, or a capacity_factor for the one-hot dispatch "
+    "that GSPMD shards",
+    dropless="a form of the sorted dispatch, which gathers its routed units "
+    "into one array for a grouped matmul, for experts sharded over ep")
+
+# What each consumer of a configuration cannot run, and why: consumer ->
+# trait (:func:`_traits`) -> what the consumer lacks for it.  A trait without
+# a row the consumer runs.  :func:`_refuse` is the table's one reader; the
+# consumers in ``llama_decode`` and ``llama_pipeline`` have their rows here,
+# beside the model they would have to follow.
+_LACKS: Dict[str, Dict[str, str]] = {
+    "apply": _NO_EP,
+    "expert_unit_counts": {**_NO_EP, **_rows(
+        _TRAIN,
+        looped="a row for each recurrent step's routers (it runs its layers "
+        "once, with no norm on a branch's output)")},
+    **{f"attn={attn!r}": _RING_ROWS for attn in _RINGS},
+    "the decode step": _rows(
+        _TRAIN,
+        looped="a cache of ut_steps x n_layers slots, a norm on a branch's "
+        "output and an exit gate (it runs its layers once)",
+        rotary_latent="a latent cache (the normed latent and the rotated "
+        "shared key part a token), the absorbed form of the query latent's "
+        "product with it, and a self-drafting step for the module",
+        window="a rolling cache of swa_window positions for the window "
+        "layers beside the full layers' (serving/kvcache.py:BlockPool "
+        "accounts for one kind of block), the gate in the one-row path, and "
+        "YaRN's positions past the original length",
+        runs="a recurrent-state cache for the KDA layers (a head's d x d "
+        "state and the convolutions' last taps) beside a latent cache for "
+        "the others"),
+    "prefill": _rows(
+        _TRAIN,
+        looped="a cache of ut_steps x n_layers slots to seed, a norm on a "
+        "branch's output and an exit gate (it runs its layers once)",
+        rotary_latent="a latent cache to seed decoding with (the normed "
+        "latent and the rotated shared key part a token) and the module's "
+        "state for a first draft",
+        window="a rolling cache of the last swa_window positions to seed "
+        "the window layers' decoding with, beside the full layers' whole one",
+        runs="a latent cache (the normed latent and the shared key part a "
+        "token) and the KDA layers' final state to seed decoding with"),
+    "make_generate_fn": _rows(
+        _TRAIN,
+        looped="the cache of ut_steps x n_layers slots its prefill and "
+        "decode step would fill, and an exit rule",
+        rotary_latent="the latent cache its prefill and decode step would "
+        "fill and a step that drafts with the module and verifies",
+        window="the two caches its prefill and decode step would fill (a "
+        "rolling one of swa_window positions, a whole one) and the gate in "
+        "the one-row path",
+        runs="the two caches its prefill and decode step would fill "
+        "(recurrent state, latent)"),
+    "make_pp_train_step": _STAGE_ROWS,
+    "make_1f1b_train_step": _STAGE_ROWS,
+}
+
+
+def _refuse(cfg: Config, consumer: str, mesh: Optional[Mesh] = None) -> None:
+    """Raise ``NotImplementedError`` if ``consumer`` has a row in ``_LACKS``
+    for something ``cfg`` is: the first such row in the consumer's order, by
+    the consumer's name, the trait with its field values and what is
+    missing.  ``mesh`` is the mesh the consumer runs on, where it has one: a
+    dropless configuration is refused on an ``ep`` axis alone."""
+    ep = 1 if mesh is None else dict(mesh.shape).get(AXIS_EP, 1)
+    has = _traits(cfg)
+    for trait, lacks in _LACKS[consumer].items():
+        if trait in has and (trait != "dropless" or ep > 1):
+            raise NotImplementedError(
+                f"{consumer} has no form yet for {has[trait]}: it lacks "
+                f"{lacks}")
 
 
 def _mixer_impls(cfg: Config, attn: str, mesh: Optional[Mesh]):
@@ -1845,22 +1840,8 @@ def _mixer_impls(cfg: Config, attn: str, mesh: Optional[Mesh]):
     KDA layer's is the layer between its projections
     (:func:`_kda_sharded`)."""
     mla = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
-    if attn.startswith("ring"):
-        _refuse_rotary_latent(
-            cfg, f"attn={attn!r}", "a ring form of the latent layer (the one "
-            "rotated key part all heads share would circulate with every "
-            "head's keys) and a module whose next token and target lie past "
-            "a sequence shard's edge")
-        _refuse_window(
-            cfg, f"attn={attn!r}", "a ring form of the band (a sequence "
-            "shard meets its left neighbours' last swa_window keys alone) "
-            "and the gate and the scaled, partial rotation in the ring's "
-            "hand-sharded layer")
-        if cfg.layer_kinds is not None:
-            raise NotImplementedError(
-                "the ring kernels take one head width for q, k and v and no "
-                "recurrent state crosses sequence shards: a stack with KDA "
-                "or latent-attention layers takes attn='full' or 'flash'")
+    if attn in _RINGS:
+        _refuse(cfg, f"attn={attn!r}")
     softmax = 1.0 / np.sqrt(cfg.head_dim)
     return {"attn": _make_attn_impl(cfg, attn, mesh, softmax),
             "swa": (_make_attn_impl(cfg, attn, mesh, softmax, "swa")
@@ -1967,8 +1948,7 @@ def expert_unit_counts(cfg: Config, params: Params, tokens: jax.Array,
     columns are what the step's tiles see.  A multi-token-prediction
     module's router is one more row, the last, on the next tokens
     ``mtp_tokens`` it reads (:func:`_mtp_input`)."""
-    _refuse_dropless_ep(cfg, mesh)
-    _refuse_looped(cfg, "expert_unit_counts")
+    _refuse(cfg, "expert_unit_counts", mesh)
     if cfg.mtp_layers and mtp_tokens is None:
         raise ValueError("a configuration with a multi-token-prediction "
                          "module needs mtp_tokens, the batch's targets")
@@ -2066,32 +2046,22 @@ def apply(cfg: Config, params: Params, tokens: jax.Array,
     or auto-sharded).
 
     ``remat`` is the rematerialization policy applied to each layer
-    (gradient checkpointing — the HBM/FLOPs trade SURVEY.md §7 prescribes
-    for 8B-scale):
+    (:func:`_wrap_remat` has the list of what each keeps; no policy runs a
+    flash kernel twice):
       * ``"none"``  — save all residuals (small models),
-      * ``"dots"``  — save matmul outputs, the flash kernel's output and
-        log-sum-exp and the sorted expert layer's gate and up grouped
-        matmuls, recompute elementwise
-        (``jax.checkpoint_policies.dots_with_no_batch_dims_saveable`` plus
-        the kernels' names, :func:`_wrap_remat`; the transformer
-        default: activations per layer shrink ~4x),
+      * ``"dots"``  — save matmul outputs and the kernels' named outputs,
+        recompute elementwise (the transformer default: activations per layer
+        shrink ~4x),
       * ``"full"``  — save only layer boundaries and, with ``attn="flash"``,
-        the kernel's output and log-sum-exp (one more array of the layer
-        input's size a layer application; the backward pass recomputes the
-        rest of each layer's forward, the grouped matmuls included, and never
-        the L^2 part; longest contexts).
-    No policy runs a flash kernel twice (:func:`_wrap_remat` has the list of
-    what each keeps, and why a scanned layer's checkpoint carries no
-    optimization barrier).
+        the kernel's output and log-sum-exp; the backward pass recomputes the
+        rest of each layer's forward and never the L^2 part (longest
+        contexts).
     For a looped configuration the policy holds for every layer application
     of every recurrent step, or ``remat`` is a sequence of T such names, one
     for each recurrent step's layers: the T * n_layers applications of a
     step's backward pass all keep what their policy keeps at once, so
     ``"dots"`` for as many steps as the memory holds and ``"full"`` for the
-    rest is the recomputation chosen to fit (8 layers x 4 steps of Ouro-2.6B
-    on a v5e: ``"dots"`` everywhere needs 20.25 GB of 15.75, ``("full",
-    "full", "full", "dots")`` runs a step in 1041.5 ms where ``"full"`` takes
-    1078.7; chip runs of PR 30).
+    rest is the recomputation chosen to fit.
 
     ``layer_loop`` is the form of the loop over the stacked layers.  Left
     ``None``, the code chooses from the depth it is given: a stack of at
@@ -2103,13 +2073,9 @@ def apply(cfg: Config, params: Params, tokens: jax.Array,
     layer's weights as an XLA convolution does, so each is first copied out
     of the stack, forward and again backward, and the layer's gradients and
     saved residuals are ``dynamic-update-slice``d into stacked buffers the
-    loop carries.  On OLMoE's two layers at published widths
-    (``olmoe-1b-7b-l4096``) those were ``fusion:_dynamic-slice_bitcast``,
-    12.4 ms, and ``fusion:_bitcast_dynamic-update-slice``, 10.0 ms, of a
-    316.65 ms step (PERF_LEDGER.jsonl, PR 28), and 3.65 GB of the plan;
-    on ViT 23% of a step (BASELINE.md round 3).  Inlined, a layer's slice
-    of a weight is a static slice, its gradient an operand of the update,
-    its residuals plain buffers.  Both forms compute the same function
+    loop carries.  Inlined, a layer's slice of a weight is a static slice,
+    its gradient an operand of the update, its residuals plain buffers.
+    Both forms compute the same function
     (``tests/test_llama.py::test_unrolled_matches_scan``), and both names
     stay accepted for that test's sake.  The form is that of one pass
     through the stack; a looped configuration's T passes are always inlined
@@ -2143,7 +2109,7 @@ def apply(cfg: Config, params: Params, tokens: jax.Array,
         kept = _mesh_spec(P(AXIS_DP, AXIS_SP, None), mesh)
         return lax.with_sharding_constraint(x, NamedSharding(mesh, kept))
 
-    _refuse_dropless_ep(cfg, mesh)
+    _refuse(cfg, "apply", mesh)
     with jax.named_scope("embed"):
         h = constrain(params["embed"][tokens])      # (B, L, D)
     impls = _mixer_impls(cfg, attn, mesh)
@@ -2299,281 +2265,6 @@ def make_loss_fn(cfg: Config, mesh: Optional[Mesh] = None, attn: str = "full",
     return loss_fn
 
 
-# ---------------------------------------------------------------- inference
-
-def init_kv_cache(cfg: Config, batch: int, max_len: int,
-                  dtype=jnp.float32) -> Params:
-    """Per-layer K/V cache at native GQA head count, stacked on the layer
-    axis to match the stacked parameters (one ``lax.scan`` drives both)."""
-    hd, KV = cfg.head_dim, cfg.n_kv_heads
-    shape = (cfg.n_layers, batch, max_len, KV, hd)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
-
-
-def _decode_step(cfg: Config, params: Params, cache: Params,
-                 tokens: jax.Array, pos: jax.Array):
-    """One autoregressive position: tokens (B,) int32 at position ``pos`` ->
-    (logits (B, V) f32, updated cache).  Attention reads the cache up to and
-    including ``pos`` (causality holds by construction: later slots are
-    still zero and masked off)."""
-    _refuse_looped(cfg, "the decode step")
-    _refuse_rotary_latent(cfg, "the decode step", "a latent cache (the "
-                          "normed latent and the rotated shared key part "
-                          "a token), the absorbed form of the query "
-                          "latent's product with it, and a self-drafting "
-                          "step for the module")
-    _refuse_window(cfg, "the decode step", "a rolling cache of swa_window "
-                   "positions for the window layers beside the full layers' "
-                   "(serving/kvcache.py:BlockPool accounts for one kind of "
-                   "block), the gate in the one-row path, and YaRN's "
-                   "positions past the original length")
-    _refuse_runs(cfg, "the decode step", "a recurrent-state cache for the "
-                 "KDA layers (a head's d x d state and the convolutions' last "
-                 "taps) beside a latent cache for the others")
-    B = tokens.shape[0]
-    hd, H, KV = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
-    scale = 1.0 / np.sqrt(hd)
-    max_len = cache["k"].shape[2]
-    positions = pos[None]                            # (1,)
-    h = params["embed"][tokens]                      # (B, D)
-
-    def layer(h, xs):
-        lp, ck, cv = xs                              # ck/cv: (B, max_len, KV, hd)
-        x = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
-        q, k_new = _qk_norm(cfg, lp, x @ lp["wq"], x @ lp["wk"])
-        q = rope(q.reshape(B, 1, H, hd), positions,
-                 cfg.rope_theta)[:, 0]               # (B, H, hd)
-        k_new = rope(k_new.reshape(B, 1, KV, hd), positions, cfg.rope_theta)
-        v_new = (x @ lp["wv"]).reshape(B, 1, KV, hd)
-        ck = lax.dynamic_update_slice(ck, k_new.astype(ck.dtype),
-                                      (0, pos, 0, 0))
-        cv = lax.dynamic_update_slice(cv, v_new.astype(cv.dtype),
-                                      (0, pos, 0, 0))
-        # GQA attention of the single query against the cache, f32 softmax.
-        # Grouped contraction against the cache at its native KV head count
-        # — repeating the cache to H heads would multiply the dominant HBM
-        # read of the decode step by H/KV.
-        rep = H // KV
-        qg = q.reshape(B, KV, rep, hd).astype(jnp.float32)
-        s = jnp.einsum("bgrd,blgd->bgrl", qg,
-                       ck.astype(jnp.float32)) * scale
-        mask = jnp.arange(max_len)[None, None, None, :] <= pos
-        s = jnp.where(mask, s, _NEG_INF)
-        w = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("bgrl,blgd->bgrd", w, cv.astype(jnp.float32))
-        h = h + (o.reshape(B, H * hd).astype(h.dtype) @ lp["wo"])
-        x = rms_norm(h, lp["mlp_norm"], cfg.norm_eps)
-        if cfg.n_experts:
-            # Dropless at decode: capacity = tokens-per-group covers the
-            # worst case (top-k experts are distinct, so an expert gets at
-            # most one unit per token), so routing never depends on bucket
-            # pressure.
-            g, _ = _moe_ffn(cfg, lp, x[:, None, :], dropless=True)
-            return h + g[:, 0], (ck, cv)
-        g = jax.nn.silu(x @ lp["w_gate"]) * (x @ lp["w_up"])
-        return h + g @ lp["w_down"], (ck, cv)
-
-    h, (new_k, new_v) = lax.scan(layer, h,
-                                 (params["layers"], cache["k"], cache["v"]))
-    h = rms_norm(h, params["norm"], cfg.norm_eps)
-    logits = (h @ params["head"]).astype(jnp.float32)
-    return logits, {"k": new_k, "v": new_v}
-
-
-def _prefill(cfg: Config, params: Params, cache: Params,
-             prompt: jax.Array, attn: str = "auto",
-             mesh: Optional[Mesh] = None):
-    """Batched prefill: ONE full forward over the prompt (matmul-bound, the
-    parameters stream from HBM once) seeding the K/V cache, instead of
-    prompt_len matrix-vector decode steps.  Returns (last-position logits,
-    cache).
-
-    ``attn="auto"`` picks the prefill attention by prompt length: full for
-    short prompts (XLA's fused attention is fine and tiles freely), the
-    Pallas flash kernels once the prompt's (Lp, Lp) score matrix is the
-    memory term that matters (>= 1024, where flash also wins on time —
-    the Llama table in BASELINE.md) and a legal tile divides ``Lp``.
-    ``mesh`` is the mesh the params are sharded on, if any: the flash
-    kernel needs it to run per batch/head shard.
-    """
-    _refuse_looped(cfg, "prefill")
-    _refuse_rotary_latent(cfg, "prefill", "a latent cache to seed "
-                          "decoding with (the normed latent and the "
-                          "rotated shared key part a token) and the "
-                          "module's state for a first draft")
-    _refuse_window(cfg, "prefill", "a rolling cache of the last swa_window "
-                   "positions to seed the window layers' decoding with, "
-                   "beside the full layers' whole one")
-    _refuse_runs(cfg, "prefill", "a latent cache (the normed latent and the "
-                 "shared key part a token) and the KDA layers' final state "
-                 "to seed decoding with")
-    B, Lp = prompt.shape
-    positions = jnp.arange(Lp)
-    scale = 1.0 / np.sqrt(cfg.head_dim)
-    if attn == "auto":
-        attn = "full"
-        if Lp >= 1024:
-            # Tile legality is _auto_block's call, not a duplicated
-            # divisibility literal here — illegal lengths stay on the
-            # full path instead of erroring.
-            from ..ops.flash_attention import _auto_block
-
-            try:
-                _auto_block(Lp)
-                attn = "flash"
-            except ValueError:
-                pass
-    attn_impl = _make_attn_impl(cfg, attn, mesh, scale)
-    h = params["embed"][prompt]
-
-    def layer(h, xs):
-        lp, ck, cv = xs
-        h, _, (k, v) = _decoder_layer(cfg, lp, h, positions, attn_impl,
-                                      with_kv=True, mesh=mesh)
-        ck = lax.dynamic_update_slice(ck, k.astype(ck.dtype), (0, 0, 0, 0))
-        cv = lax.dynamic_update_slice(cv, v.astype(cv.dtype), (0, 0, 0, 0))
-        return h, (ck, cv)
-
-    h, (new_k, new_v) = lax.scan(layer, h,
-                                 (params["layers"], cache["k"], cache["v"]))
-    h = rms_norm(h[:, -1], params["norm"], cfg.norm_eps)
-    logits = (h @ params["head"]).astype(jnp.float32)
-    return logits, {"k": new_k, "v": new_v}
-
-
-def make_generate_fn(cfg: Config, prompt_len: int, max_new: int,
-                     temperature: float = 0.0, top_k: int = 0,
-                     top_p: float = 0.0, mesh: Optional[Mesh] = None):
-    """Compiled autoregressive generation:
-    ``fn(params, prompt (B, prompt_len) int32, rng) -> (B, max_new) int32``.
-
-    One compiled program: a batched prefill forward seeds the K/V cache,
-    then a ``lax.scan`` of single-position decode steps (cache in the
-    carry — static shapes, no host round-trips).  ``temperature=0`` is
-    greedy; otherwise tokens are sampled from softmax(logits / temperature),
-    optionally filtered first by ``top_k`` (keep the k highest logits) and
-    ``top_p`` (nucleus: keep the smallest prefix of the sorted distribution
-    whose probability mass reaches p; the top token always survives).
-    Both filters are static-shape mask-and-renormalize forms — no
-    data-dependent shapes, so the whole sampler stays inside the compiled
-    scan.
-
-    **Distributed generation** (``mesh``): pass params placed by
-    :func:`shard_params` and the mesh they live on.  Weights stay in their
-    Megatron layout (never gathered), the batch shards over ``dp``, and
-    the K/V cache — the array that grows with context and would otherwise
-    replicate — is PINNED sharded over dp x tp (tp on the KV-head axis,
-    matching the column-sharded wk/wv that produce it), through prefill
-    and every decode tick.  This is what makes the flagship samplable at
-    all: full-8B bf16 params are 16.1 GB against a 16 GB chip
-    (BASELINE.md projection), so decode must run tp-sharded with
-    per-shard caches.  Token-exact vs the single-device oracle (greedy;
-    tested at tiny geometry on the virtual mesh).  Sampling collectives
-    (the per-layer attention/MLP psums) are GSPMD's, inferred from the
-    pinned weight + cache shardings.
-    """
-    _refuse_looped(cfg, "make_generate_fn")
-    _refuse_rotary_latent(cfg, "make_generate_fn", "the latent cache its "
-                          "prefill and decode step would fill and a step "
-                          "that drafts with the module and verifies")
-    _refuse_window(cfg, "make_generate_fn", "the two caches its prefill "
-                   "and decode step would fill (a rolling one of swa_window "
-                   "positions, a whole one) and the gate in the one-row path")
-    _refuse_runs(cfg, "make_generate_fn", "the two caches its prefill and "
-                 "decode step would fill (recurrent state, latent)")
-    if prompt_len < 1 or max_new < 1:
-        raise ValueError("prompt_len and max_new must be >= 1")
-    if mesh is not None and cfg.n_kv_heads % dict(mesh.shape).get(AXIS_TP, 1):
-        raise ValueError(
-            f"tp={dict(mesh.shape).get(AXIS_TP)} must divide n_kv_heads "
-            f"{cfg.n_kv_heads} (the cache shards on the KV-head axis)")
-    if not 0.0 <= top_p <= 1.0:
-        raise ValueError(f"top_p must be in [0, 1], got {top_p}")
-    if top_k < 0 or (top_k and top_k > cfg.vocab):
-        raise ValueError(f"top_k must be in [0, {cfg.vocab}], got {top_k}")
-    if temperature <= 0.0 and (top_k or top_p):
-        # Greedy ignores the filters; silently doing so would let a caller
-        # believe they sampled.
-        raise ValueError("top_k/top_p require temperature > 0 "
-                         "(temperature=0 is greedy)")
-    max_len = prompt_len + max_new
-
-    def constrain_cache(cache):
-        if mesh is None:
-            return cache
-        # (n_layers, B, max_len, KV, hd): batch over dp, KV heads over tp.
-        spec = _mesh_spec(P(None, AXIS_DP, None, AXIS_TP, None), mesh)
-        sh = NamedSharding(mesh, spec)
-        return jax.tree.map(
-            lambda a: lax.with_sharding_constraint(a, sh), cache)
-
-    def constrain_logits(x):
-        if mesh is None:
-            return x
-        # (B, V) — batch over dp, vocab gathered for the sampler (2 MB at
-        # 8B width; sort/cumsum over a sharded vocab axis buys nothing).
-        return lax.with_sharding_constraint(
-            x, NamedSharding(mesh, _mesh_spec(P(AXIS_DP, None), mesh)))
-
-    def fn(params: Params, prompt: jax.Array, rng: jax.Array) -> jax.Array:
-        if prompt.shape[1] != prompt_len:
-            raise ValueError(f"prompt has length {prompt.shape[1]}, "
-                             f"generate_fn was built for {prompt_len}")
-        B = prompt.shape[0]
-        cache0 = constrain_cache(
-            init_kv_cache(cfg, B, max_len, params["embed"].dtype))
-        logits, cache = _prefill(cfg, params, cache0, prompt, mesh=mesh)
-        cache = constrain_cache(cache)
-        logits = constrain_logits(logits)
-
-        def pick(logits, key):
-            if temperature <= 0.0:
-                return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            l = (logits / temperature).astype(jnp.float32)
-            neg = jnp.asarray(-1e30, l.dtype)
-            if top_k:
-                # Keep the k highest logits (kth value as threshold).
-                kth = lax.top_k(l, top_k)[0][..., -1:]
-                l = jnp.where(l < kth, neg, l)
-            if 0.0 < top_p < 1.0:
-                # Nucleus: drop tokens whose EXCLUSIVE cumulative mass (in
-                # descending-probability order) already reached p; the top
-                # token's exclusive mass is 0, so it always survives.
-                sorted_l = jnp.sort(l, axis=-1)[..., ::-1]
-                probs = jax.nn.softmax(sorted_l, axis=-1)
-                cum_excl = jnp.cumsum(probs, axis=-1) - probs
-                cut = jnp.sum((cum_excl < top_p).astype(jnp.int32), axis=-1)
-                # Threshold = smallest kept (sorted) logit.
-                thresh = jnp.take_along_axis(
-                    sorted_l, jnp.maximum(cut[..., None] - 1, 0), axis=-1)
-                l = jnp.where(l < thresh, neg, l)
-            return jax.random.categorical(key, l, axis=-1).astype(jnp.int32)
-
-        def decode(carry, i):
-            cache, logits, key = carry
-            key, sub = jax.random.split(key)
-            tok = pick(logits, sub)
-            logits, cache = _decode_step(cfg, params, cache, tok,
-                                         prompt_len + i)
-            # Re-pin the carried cache/logits every tick: without the
-            # constraint GSPMD is free to settle the scan carry on a
-            # replicated layout (the cache is the array that cannot
-            # replicate at 8B).
-            return (constrain_cache(cache), constrain_logits(logits),
-                    key), tok
-
-        # max_new - 1 cache-advancing steps; the last token needs only a
-        # pick from the final logits (no wasted trailing forward).
-        (_, logits, key), toks = lax.scan(decode, (cache, logits, rng),
-                                          jnp.arange(max_new - 1))
-        _, sub = jax.random.split(key)
-        last = pick(logits, sub)
-        return jnp.concatenate([toks, last[None]], axis=0).T  # (B, max_new)
-
-    return jax.jit(fn)
-
-
-# ------------------------------------------------------------- pipeline (pp)
 
 # The sorted expert layer's gate and up products (:func:`_moe_ffn_sorted`
 # names them): the grouped matmul's outputs that a backward pass reads again.
@@ -2644,483 +2335,6 @@ def _wrap_remat(layer: Callable, remat: str,
             policies.save_only_these_names(*kernels, *GROUPED_DOT_NAMES))
     return jax.checkpoint(layer, policy=policy, prevent_cse=not scanned)
 
-
-def _decoder_layer_tp_manual(cfg: Config, lp, h, positions,
-                             markers: bool = False):
-    """Decoder block under MANUAL tensor parallelism: ``lp`` leaves are this
-    device's tp shards (wq/wk/wv/gate/up column shards, wo/down row shards;
-    norms replicated) and the block writes its own Megatron collectives —
-    exactly two ``psum`` s over ``tp``.  Attention runs the Pallas flash
-    kernels on the LOCAL head shard: this is the composition GSPMD cannot
-    produce (it would replicate the unpartitionable custom call and gather
-    its operands — measured, BASELINE.md round 4).
-
-    ``markers=True`` wraps each parallel block in the Megatron f/g
-    ``custom_vjp`` pair (``parallel.tp.block_input``/``block_output``) so
-    the layer's vjp is correct when taken PER DEVICE — required by the
-    cond-free 1F1B body, which calls ``jax.vjp`` inside the manual region
-    where no partitioner rewrites transposes.  The GPipe path (AD from
-    outside the shard_map) differentiates the unmarked form."""
-    from ..ops import flash_attention as _flash
-    from ..parallel import tp as _tp
-
-    if cfg.qk_norm:
-        raise NotImplementedError(
-            "QK-norm runs over the whole q and k projections, which the "
-            "tp-manual stage holds as column shards: it would need a psum of "
-            "the squares over tp that this stage does not write; use the "
-            "GSPMD pipeline (tp_manual=False) or make_train_step")
-    B, L, _ = h.shape
-    hd = cfg.head_dim
-    x = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
-    if markers:
-        # After the (replicated) norm, before the sharded projections: the
-        # backward psum the marker adds must deliver the COMPLETE branch
-        # cotangent to the norm so its weight grads arrive whole.
-        x = _tp.block_input(x, AXIS_TP)
-    Hl = lp["wq"].shape[-1] // hd          # local head count (H / tp)
-    KVl = lp["wk"].shape[-1] // hd
-    q = rope((x @ lp["wq"]).reshape(B, L, Hl, hd), positions, cfg.rope_theta)
-    k = rope((x @ lp["wk"]).reshape(B, L, KVl, hd), positions, cfg.rope_theta)
-    v = (x @ lp["wv"]).reshape(B, L, KVl, hd)
-    o = _flash(q, k, v, causal=True,
-               scale=float(1.0 / np.sqrt(hd)))
-
-    def tp_sum(part):
-        # The wire dtype is backend-gated (parallel.tp.resolve_wire_dtype):
-        # f32 off-TPU — partial-sum accuracy, and XLA-CPU's
-        # AllReducePromotion pass asserts on bf16 all-reduce inside
-        # partial-manual regions (crashes the compiler at 8B width) — and
-        # bf16 on TPU, where the pipeline compiles it clean (proven by AOT
-        # topology compilation, TOPOLOGY_r06.json) at half the bytes.
-        if markers:
-            return _tp.block_output(part, AXIS_TP)
-        wire = _tp.resolve_wire_dtype()
-        return lax.psum(part.astype(wire), AXIS_TP).astype(h.dtype)
-
-    h = h + tp_sum(o.reshape(B, L, Hl * hd) @ lp["wo"])   # row-sharded
-    x = rms_norm(h, lp["mlp_norm"], cfg.norm_eps)
-    if markers:
-        x = _tp.block_input(x, AXIS_TP)
-    g = jax.nn.silu(x @ lp["w_gate"]) * (x @ lp["w_up"])  # local d_ff shard
-    h = h + tp_sum(g @ lp["w_down"])                      # row-sharded
-    return h
-
-
-def _gspmd_compose(mesh: Mesh) -> bool:
-    """Does this mesh carry dp/tp axes the pipeline should hand to GSPMD
-    (auto axes) alongside manual pp?  One definition for both schedules."""
-    sizes = dict(mesh.shape)
-    return sizes.get(AXIS_TP, 1) > 1 or sizes.get(AXIS_DP, 1) > 1
-
-
-def _make_pp_stage_fn_tp_manual(cfg: Config, remat: str,
-                                markers: bool = False):
-    """Stage program for the tp-MANUAL pipeline: scans ``V`` hand-sharded
-    decoder layers (see :func:`_decoder_layer_tp_manual`; ``markers`` for
-    the cond-free 1F1B body's in-region vjp)."""
-
-    def stage_fn(lp_stage, h):
-        positions = jnp.arange(h.shape[1])
-
-        def layer(h, lp):
-            return _decoder_layer_tp_manual(cfg, lp, h, positions,
-                                            markers=markers), None
-
-        h, _ = lax.scan(_wrap_remat(layer, remat), h, lp_stage)
-        return h
-
-    return stage_fn
-
-
-def _make_pp_stage_fn(cfg: Config, attn_impl: Callable, remat: str):
-    """One pipeline stage: scan ``V`` decoder layers over a (mb, L, D)
-    carrier — shared by the GPipe and 1F1B steps so the two schedules run
-    the identical stage program."""
-
-    def stage_fn(lp_stage, h):
-        # lp_stage: layer pytree with leading dim V; h: (mb, L, D).
-        positions = jnp.arange(h.shape[1])
-
-        def layer(h, lp):
-            h, _ = _decoder_layer(cfg, lp, h, positions, attn_impl)
-            return h, None
-
-        # Per-layer checkpointing bounds the stage's activation memory the
-        # way GPipe needs at depth (shared taxonomy: _wrap_remat).
-        h, _ = lax.scan(_wrap_remat(layer, remat), h, lp_stage)
-        return h
-
-    return stage_fn
-
-
-def make_pp_train_step(cfg: Config, mesh: Mesh, n_microbatches: int,
-                       lr: float = 3e-4, attn: str = "full",
-                       remat: str = "none", loss_chunk: int = 0,
-                       optimizer=None, opt_state_example=None,
-                       zero1: bool = False, stage_tp: str = "auto"):
-    """Pipeline-parallel training step: the stacked decoder layers become
-    pipeline stages over the mesh's ``pp`` axis (BASELINE config 4's
-    pipelined model parallelism applied to the flagship transformer).
-
-    Layers are cut into ``S`` contiguous stages of ``n_layers/S`` each;
-    embed and the output head run outside the pipeline (replicated over pp —
-    the GPipe carrier must be one (mb, L, D) shape).  The GPipe schedule is
-    the differentiable sharded-I/O one (parallel/pipeline.py), so
-    ``jax.grad`` produces the backward pipeline.
-
-    **3-D composition**: when the mesh also carries ``tp`` and/or ``dp``
-    axes, only ``pp`` is manual in the pipeline's shard_map
-    (``auto_other_axes``) and the rest is GSPMD's: stage parameters arrive
-    tp-sharded per :func:`param_specs` (place with
-    ``shard_params_pp(params, mesh, cfg)``), micro-batches are dp-sharded
-    on their batch dim, and the compiler inserts the tp activation psums
-    and dp gradient reductions inside every stage tick — the
-    multi-communicator-level run of the reference (EASGD over DP with two
-    communicators, examples/mnist/mnist_parameterserver_easgd_dataparallel
-    .lua:28-36) expressed as one jit over one mesh.  ``zero1=True``
-    additionally shards optimizer moments over dp (needs ``optimizer`` +
-    ``opt_state_example``).
-
-    ``attn`` supports 'full' and 'flash' (ring/sp does not compose with the
-    stage carrier).
-
-    ``stage_tp``: 'auto' (GSPMD partitions the stage over tp — right for
-    attn='full', which it tp-shards natively) or 'manual' — the stage body
-    is HAND-sharded: tp joins pp as a manual shard_map axis, each device's
-    stage_fn gets raw weight shards, writes the two Megatron psums itself,
-    and runs the Pallas flash kernels on its own head shard.  'manual' is
-    the long-context 3-D form.  GSPMD cannot partition a Pallas custom
-    call, so under 'auto' + attn='flash' the kernel nests its own
-    shard_map over dp x tp (:func:`_flash_attention_sharded`) while the
-    projections around it stay GSPMD's.  'manual' requires attn='flash'.
-
-    Returns ``(step, V)`` with ``V = n_layers/S`` layers per stage.
-    Without ``optimizer``: ``step(params, tokens, targets) -> (params,
-    loss)`` (plain SGD at ``lr``).  With ``optimizer`` (an optax
-    gradient transform): ``step(params, opt_state, tokens, targets) ->
-    (params, opt_state, loss)``.  ``params`` as from :func:`init` placed by
-    :func:`shard_params_pp`; global batch must be divisible by
-    ``n_microbatches``.
-    """
-    from ..parallel import pipeline as _pp
-    from ..parallel.mesh import AXIS_PP
-
-    _refuse_rotary_latent(cfg, "make_pp_train_step", "a last stage that "
-                          "hands the module the state before the final "
-                          "norm, and the embedding on the first and the "
-                          "last stage at once")
-    _refuse_window(cfg, "make_pp_train_step", "stages whose layers differ in "
-                   "head count, window and rotation (a stage is one stacked "
-                   "scan of identical layers, its attention made once) and "
-                   "the gate in the hand-sharded layer")
-    _refuse_runs(cfg, "make_pp_train_step", "a stage split by run (a stage "
-                 "is one stacked scan of identical layers)")
-    if cfg.n_experts:
-        # The GPipe carrier is a single (mb, L, D) array; threading the MoE
-        # aux loss through the stage boundary needs an augmented carrier.
-        # Train MoE configs with the dp x tp x ep step (make_train_step).
-        raise NotImplementedError("pipeline step does not support MoE configs")
-    _refuse_looped(cfg, "make_pp_train_step")
-    S = mesh.shape[AXIS_PP]
-    sizes = dict(mesh.shape)
-    compose = _gspmd_compose(mesh)
-    if cfg.n_layers % S:
-        raise ValueError(f"n_layers {cfg.n_layers} not divisible by pp={S}")
-    V = cfg.n_layers // S
-    if attn not in ("full", "flash"):
-        raise ValueError("pp step supports attn='full'|'flash'")
-    if zero1 and (optimizer is None or opt_state_example is None):
-        raise ValueError("zero1 needs optimizer and opt_state_example")
-    if stage_tp == "manual":
-        tp = sizes.get(AXIS_TP, 1)
-        if AXIS_TP not in mesh.axis_names:
-            raise ValueError("stage_tp='manual' needs a tp mesh axis")
-        if attn != "flash":
-            raise ValueError("stage_tp='manual' runs the flash kernels on "
-                             "the local head shard; pass attn='flash'")
-        if (cfg.n_heads % tp or cfg.n_kv_heads % tp or cfg.d_ff % tp
-                or cfg.d_model % tp):
-            raise ValueError(
-                f"tp={tp} must divide n_heads/n_kv_heads/d_ff/d_model")
-        stage_fn = _make_pp_stage_fn_tp_manual(cfg, remat)
-        # Stacked stage-param specs: (S, V, per-layer dims) — pp on the
-        # stage dim, tp on the Megatron weight dims.
-        stage_specs = {k: P(AXIS_PP, None, *tuple(sp)[1:])
-                       for k, sp in param_specs(cfg)["layers"].items()}
-        manual = [AXIS_TP]
-        io_batch = None
-        if sizes.get(AXIS_DP, 1) > 1:
-            # dp manual too: an auto batch axis would still gather the
-            # Pallas call's operands to replicate it over dp.
-            manual.append(AXIS_DP)
-            io_batch = AXIS_DP
-        pipe = _pp.make_pipeline_fn(mesh, stage_fn, n_microbatches,
-                                    axis=AXIS_PP, manual_axes=tuple(manual),
-                                    param_in_specs=stage_specs,
-                                    io_batch_axis=io_batch)
-    elif stage_tp == "auto":
-        scale = 1.0 / np.sqrt(cfg.head_dim)
-        attn_impl = _make_attn_impl(cfg, attn, mesh if compose else None,
-                                    scale)
-        stage_fn = _make_pp_stage_fn(cfg, attn_impl, remat)
-        pipe = _pp.make_pipeline_fn(mesh, stage_fn, n_microbatches,
-                                    axis=AXIS_PP, auto_other_axes=compose)
-    else:
-        raise ValueError("stage_tp must be 'auto' or 'manual'")
-
-    def constrain(x, spec):
-        if not compose:
-            return x
-        kept = _mesh_spec(spec, mesh, x.shape)
-        return lax.with_sharding_constraint(x, NamedSharding(mesh, kept))
-
-    def loss_fn(params, tokens, targets):
-        h = params["embed"][tokens]                     # (B, L, D)
-        h = constrain(h, P(AXIS_DP, None, None))
-        M = n_microbatches
-        B = h.shape[0]
-        if B % M:
-            raise ValueError(f"batch {B} not divisible by {M} micro-batches")
-        # Micro-batch axis to pp (the pipe's manual axis), per-micro-batch
-        # batch dim to dp: each stage tick computes on 1/dp of a micro-batch.
-        hm = h.reshape(M, B // M, *h.shape[1:])
-        hm = constrain(hm, P(AXIS_PP, AXIS_DP, None, None))
-        # (n_layers, ...) -> (S, V, ...): one stage row per pipeline device,
-        # V layers inside each stage's scan.
-        staged = jax.tree.map(
-            lambda a: a.reshape(S, V, *a.shape[1:]), params["layers"])
-        hm = pipe(staged, hm)
-        h = hm.reshape(B, *h.shape[1:])
-        h = constrain(h, P(AXIS_DP, None, None))
-        h = rms_norm(h, params["norm"], cfg.norm_eps)
-        return _nll_from_hidden(params["head"], h, targets, loss_chunk)
-
-    if optimizer is None:
-        def step(params, tokens, targets):
-            loss, grads = jax.value_and_grad(loss_fn)(params, tokens, targets)
-            params = jax.tree.map(lambda p, g: p - lr * g.astype(p.dtype),
-                                  params, grads)
-            return params, loss
-
-        return jax.jit(step, donate_argnums=(0,)), V
-
-    opt_sh = (_zero1_opt_shardings(cfg, mesh, opt_state_example,
-                                   specs=param_specs_pp(cfg))
-              if zero1 else None)
-
-    def step_opt(params, opt_state, tokens, targets):
-        loss, grads = jax.value_and_grad(loss_fn)(params, tokens, targets)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        if opt_sh is not None:
-            opt_state = jax.lax.with_sharding_constraint(opt_state, opt_sh)
-        params = jax.tree.map(lambda p, u: p + u, params, updates)
-        return params, opt_state, loss
-
-    return jax.jit(step_opt, donate_argnums=(0, 1)), V
-
-
-def make_1f1b_train_step(cfg: Config, mesh: Mesh, n_microbatches: int,
-                         lr: float = 3e-4, attn: str = "full",
-                         remat: str = "none", loss_chunk: int = 0,
-                         stage_tp: str = "auto",
-                         manual_schedule: str = "combined"):
-    """Pipeline-parallel llama training on the **1F1B / PipeDream-flush**
-    schedule: same stage split and stage program as
-    :func:`make_pp_train_step` (shared ``_make_pp_stage_fn``), but the
-    explicit interleaved schedule caps the per-stage activation stash at
-    ~S micro-batches instead of GPipe's M (parallel/pipeline.py:
-    ``make_1f1b_step`` + ``pipeline_stats``) — the schedule that matters
-    when M is large enough to amortize the bubble.
-
-    The full model trains: stage grads come from the scheduled vjps, the
-    final-norm and output-head grads accumulate at the last stage
-    (``loss_params``), and the embedding grad is scatter-added from the
-    pipeline-input gradients (``return_dx``).  Returns ``(step, V)``;
-    ``step(params, tokens, targets) -> (params, loss)`` (SGD at ``lr``),
-    params placed by :func:`shard_params_pp`.
-
-    ``stage_tp='manual'`` (requires ``attn='flash'`` and a tp mesh axis,
-    like :func:`make_pp_train_step`'s): the stage body is HAND-sharded —
-    tp (and dp when present) join pp as manual shard_map axes, the layers
-    carry Megatron f/g markers so the schedule's in-region vjps are exact,
-    and the flash kernels run on the local head shard.  This is the
-    long-context 3-D form on the S-bounded schedule: GPipe's manual stage
-    stashes M micro-batch activations; this one bounds the stash per
-    ``manual_schedule`` — ``"combined"`` (default): the packed cond-free
-    body, T ~= M+2S-1 ticks at stash <= 2S-1, best wall-clock;
-    ``"alternating"``: classic cond-gated one-op ticks, stash <= S+1, the
-    memory-optimal form (see ``pipeline.make_1f1b_step``).  The head
-    enters vocab-sharded over tp (analytic tp-CE); loss is cond-gated to
-    the last stage either way.
-    """
-    from ..parallel import pipeline as _pp
-
-    _refuse_rotary_latent(cfg, "make_1f1b_train_step", "a last stage that "
-                          "hands the module the state before the final "
-                          "norm, and the embedding on the first and the "
-                          "last stage at once")
-    _refuse_window(cfg, "make_1f1b_train_step", "stages whose layers differ "
-                   "in head count, window and rotation (a stage is one "
-                   "stacked scan of identical layers, its attention made "
-                   "once) and the gate in the hand-sharded layer")
-    _refuse_runs(cfg, "make_1f1b_train_step", "a stage split by run (a stage "
-                 "is one stacked scan of identical layers)")
-    if cfg.n_experts:
-        raise NotImplementedError("pipeline step does not support MoE configs")
-    _refuse_looped(cfg, "make_1f1b_train_step")
-    S = mesh.shape[AXIS_PP]
-    sizes = dict(mesh.shape)
-    if cfg.n_layers % S:
-        raise ValueError(f"n_layers {cfg.n_layers} not divisible by pp={S}")
-    V = cfg.n_layers // S
-    if attn not in ("full", "flash"):
-        raise ValueError("pp step supports attn='full'|'flash'")
-    M = n_microbatches
-
-    def loss_fn(lp, h, tgt):
-        h = rms_norm(h, lp["norm"], cfg.norm_eps)
-        return _nll_from_hidden(lp["head"], h, tgt, loss_chunk)
-
-    lp_example = jax.eval_shape(
-        lambda: {"norm": jnp.zeros((cfg.d_model,), jnp.float32),
-                 "head": jnp.zeros((cfg.d_model, cfg.vocab), jnp.float32)})
-    compose = _gspmd_compose(mesh)
-    if stage_tp == "manual":
-        tp = sizes.get(AXIS_TP, 1)
-        if AXIS_TP not in mesh.axis_names:
-            raise ValueError("stage_tp='manual' needs a tp mesh axis")
-        if attn != "flash":
-            raise ValueError("stage_tp='manual' runs the flash kernels on "
-                             "the local head shard; pass attn='flash'")
-        if (cfg.n_heads % tp or cfg.n_kv_heads % tp or cfg.d_ff % tp
-                or cfg.d_model % tp or cfg.vocab % tp):
-            raise ValueError(
-                f"tp={tp} must divide n_heads/n_kv_heads/d_ff/d_model/vocab")
-        stage_fn = _make_pp_stage_fn_tp_manual(cfg, remat, markers=True)
-        stage_specs = {k: P(AXIS_PP, None, *tuple(sp)[1:])
-                       for k, sp in param_specs(cfg)["layers"].items()}
-        manual = [AXIS_TP]
-        io_batch = None
-        if sizes.get(AXIS_DP, 1) > 1:
-            manual.append(AXIS_DP)
-            io_batch = AXIS_DP
-
-        # The head enters VOCAB-SHARDED over tp (its resting layout —
-        # no per-step gather of the (D, vocab) matrix) and the loss is
-        # the analytic tp-sharded CE; norm stays replicated.
-        def loss_fn_manual(lp, h, tgt):
-            h = rms_norm(h, lp["norm"], cfg.norm_eps)
-            return _nll_from_hidden_tp_manual(lp["head"], h, tgt, loss_chunk)
-
-        pipe = _pp.make_1f1b_step(mesh, stage_fn, loss_fn_manual, M,
-                                  axis=AXIS_PP,
-                                  loss_params_example=lp_example,
-                                  return_dx=True,
-                                  manual_axes=tuple(manual),
-                                  param_in_specs=stage_specs,
-                                  io_batch_axis=io_batch,
-                                  loss_param_specs={
-                                      "norm": P(),
-                                      "head": P(None, AXIS_TP)},
-                                  manual_schedule=manual_schedule)
-    elif stage_tp == "auto":
-        if manual_schedule != "combined":
-            # The auto path always runs the cond-gated alternating body;
-            # silently accepting the knob would let a caller believe they
-            # selected a schedule they did not get.
-            raise ValueError("manual_schedule applies to stage_tp='manual' "
-                             "only (the auto path is always cond-gated)")
-        scale = 1.0 / np.sqrt(cfg.head_dim)
-        # No mesh for the kernel here, unlike make_pp_train_step: a
-        # shard_map nested in this schedule's lax.cond ticks aborts XLA's
-        # SPMD partitioner (spmd_partitioner_util.cc check failure, on the
-        # CPU mesh too).  So attn='flash' with composed dp/tp does not
-        # lower for a TPU on this path; stage_tp='manual' is the flash form.
-        attn_impl = _make_attn_impl(cfg, attn, None, scale)
-        stage_fn = _make_pp_stage_fn(cfg, attn_impl, remat)
-        # dp/tp compose via GSPMD (auto axes): the scheduled lax.cond
-        # predicates depend only on (tick, stage), so they are uniform
-        # along dp/tp and the partitioner's placements execute
-        # consistently inside the branches.
-        pipe = _pp.make_1f1b_step(mesh, stage_fn, loss_fn, M, axis=AXIS_PP,
-                                  loss_params_example=lp_example,
-                                  return_dx=True,
-                                  auto_other_axes=compose)
-    else:
-        raise ValueError("stage_tp must be 'auto' or 'manual'")
-
-    def constrain(x, spec):
-        if not compose:
-            return x
-        kept = _mesh_spec(spec, mesh, x.shape)
-        return lax.with_sharding_constraint(x, NamedSharding(mesh, kept))
-
-    def step(params, tokens, targets):
-        B, L = tokens.shape
-        if B % M:
-            raise ValueError(f"batch {B} not divisible by {M} micro-batches")
-        h = params["embed"][tokens]                     # (B, L, D)
-        # Batch to dp BEFORE the micro-batch reshape (GPipe's compose path
-        # pins the same thing) — the hint propagates through the reshape;
-        # constraining the (M, mb, ...) form directly trips an XLA-CPU
-        # compiler abort at the partial-manual shard_map boundary.
-        h = constrain(h, P(AXIS_DP, None, None))
-        hm = h.reshape(M, B // M, L, -1)
-        tm = targets.reshape(M, B // M, L)
-        staged = jax.tree.map(
-            lambda a: a.reshape(S, V, *a.shape[1:]), params["layers"])
-        lp = {"norm": params["norm"], "head": params["head"]}
-        loss, g_staged, g_lp, dx = pipe(staged, lp, hm, tm)
-        g_layers = jax.tree.map(
-            lambda a: a.reshape(cfg.n_layers, *a.shape[2:]), g_staged)
-        # Embedding grad: scatter-add the pipeline-input gradients back to
-        # the used rows (d embed[t] = sum of dx over positions with token t).
-        d_embed = jnp.zeros(params["embed"].shape, jnp.float32)
-        d_embed = d_embed.at[tokens.reshape(-1)].add(
-            dx.reshape(B * L, -1).astype(jnp.float32))
-        grads = {"embed": d_embed, "layers": g_layers,
-                 "norm": g_lp["norm"], "head": g_lp["head"]}
-        params = jax.tree.map(lambda p, g: p - lr * g.astype(p.dtype),
-                              params, grads)
-        return params, loss
-
-    return jax.jit(step, donate_argnums=(0,)), V
-
-
-def param_specs_pp(cfg: Config) -> Params:
-    """PartitionSpec pytree for the pipeline step: stacked layer leaves'
-    leading (n_layers) axis shards over ``pp`` — contiguous rows land on
-    contiguous stages, matching the (S, V) reshape inside the step — while
-    the within-layer dims keep :func:`param_specs`' Megatron tp layout.
-    Embed/norm stay replicated; the head keeps its tp column sharding."""
-    base = param_specs(cfg)
-    layers = {k: P(AXIS_PP, *tuple(s)[1:]) for k, s in base["layers"].items()}
-    return {"embed": base["embed"], "layers": layers,
-            "norm": base["norm"], "head": base["head"]}
-
-
-def shard_params_pp(params: Params, mesh: Mesh,
-                    cfg: Optional[Config] = None) -> Params:
-    """Place an :func:`init` pytree for the pipeline step: stacked layer
-    leaves (n_layers, ...) sharded over ``pp`` (and, with ``cfg`` given,
-    tp within each stage per :func:`param_specs_pp` — the 3-D layout);
-    embed/norm replicated."""
-    from ..parallel.mesh import AXIS_PP
-
-    if cfg is not None:
-        return shard_by_specs(params, mesh, param_specs_pp(cfg))
-
-    def place(path_is_layer, a):
-        spec = P(AXIS_PP) if path_is_layer else P()
-        return jax.device_put(a, NamedSharding(mesh, spec))
-
-    return {
-        "embed": place(False, params["embed"]),
-        "layers": jax.tree.map(lambda a: place(True, a), params["layers"]),
-        "norm": place(False, params["norm"]),
-        "head": place(False, params["head"]),
-    }
 
 
 # ----------------------------------------------------------------- train step

@@ -395,18 +395,17 @@ def _build_1f1b(topology: str, manual_schedule: str):
     (cond-free packed and cond-gated alternating) compile here."""
     import jax
 
-    from ..models import llama
+    from ..models import llama, llama_pipeline
 
     n = len(topology_devices(topology))
     cfg = llama.tiny()
     mesh = topology_mesh(topology, {"dp": -1, "pp": 2, "tp": 2})
     B, L = max(2, n // 2) * 2, 32
-    step, _ = llama.make_1f1b_train_step(cfg, mesh, n_microbatches=4,
-                                         lr=0.05, attn="flash",
-                                         stage_tp="manual",
-                                         manual_schedule=manual_schedule)
+    step, _ = llama_pipeline.make_1f1b_train_step(
+        cfg, mesh, n_microbatches=4, lr=0.05, attn="flash",
+        stage_tp="manual", manual_schedule=manual_schedule)
     params, tokens, targets = _llama_arg_structs(
-        cfg, mesh, llama.param_specs_pp, B, L)
+        cfg, mesh, llama_pipeline.param_specs_pp, B, L)
     return step, (params, tokens, targets)
 
 

@@ -9,10 +9,9 @@ short one behind it (no head-of-line blocking).
 Two runners implement the same contract:
 
 - :class:`LlamaRunner` — the real compiled path over
-  ``models/llama``'s ``_prefill``/decode primitives, extended here with
-  per-slot decode positions (each slot of the batched step sits at its
-  own sequence position — the continuous-batching requirement the
-  training-shaped ``_decode_step`` does not have).
+  ``models/llama_decode``'s ``_prefill`` and ``_decode_step``, whose
+  batch has a position a row: each slot of the batched step sits at its
+  own sequence position, the continuous-batching requirement.
 - :class:`StubRunner` — deterministic tokens with optional simulated
   per-token latency (``serve_stub_token_s``), so thousand-client load
   and chaos legs run on one host without XLA in the loop.
@@ -127,15 +126,17 @@ def _bucket_len(n: int, max_len: int, floor: int = 8) -> int:
 
 
 class LlamaRunner:
-    """Compiled prefill/decode over ``models/llama`` with per-slot
-    positions.
+    """Compiled prefill/decode over ``models/llama_decode``, a sequence a
+    slot, each at its own position.
 
     The device cache is slot-strided ``(layers, slots, max_len, KV, hd)``
     — XLA wants static shapes, so paging is host-side admission over
     this storage (the BlockPool) rather than a device gather.  Prefill
-    runs the batched ``_prefill`` into a slot's stripe; decode is one
-    jitted step over all slots where each slot reads/writes its own
-    position via a one-hot scatter and a per-slot causal mask.
+    runs the batched ``_prefill`` into a slot's stripe; decode is the
+    model's own ``_decode_step`` over all slots, which takes a position a
+    row.  The runner holds slots, buckets, the jitted calls and the cache's
+    placement; what a configuration must be to be decoded is the model's to
+    say (``llama._LACKS``), at the first prefill.
     """
 
     def __init__(self, slots: int, cfg=None, rng_seed: int = 0,
@@ -143,28 +144,27 @@ class LlamaRunner:
         import jax
         import jax.numpy as jnp
 
-        from ..models import llama
+        from ..models import llama, llama_decode
 
         self._jnp = jnp
-        self._llama = llama
+        self._decode = llama_decode
         self.cfg = cfg if cfg is not None else llama.tiny()
         self.slots = int(slots)
         self.max_len = int(max_len) if max_len else self.cfg.max_seq
         self.params = llama.init(jax.random.PRNGKey(rng_seed), self.cfg)
-        cache = llama.init_kv_cache(self.cfg, self.slots, self.max_len)
-        self._cache = cache
+        self._cache = llama_decode.init_kv_cache(self.cfg, self.slots,
+                                                 self.max_len)
         self._prefill_fn = jax.jit(self._prefill_impl)
-        self._decode_fn = jax.jit(self._decode_impl)
+        self._decode_fn = jax.jit(self._greedy_step)
 
     # -- compiled bodies ---------------------------------------------------
     def _prefill_impl(self, params, cache, prompt, slot):
         """Seed one slot's cache stripe from a (1, Lp) prompt."""
         from jax import lax
 
-        llama = self._llama
-        small = llama.init_kv_cache(self.cfg, 1, self.max_len)
-        _, seeded = llama._prefill(self.cfg, params, small, prompt,
-                                   attn="full")
+        small = self._decode.init_kv_cache(self.cfg, 1, self.max_len)
+        _, seeded = self._decode._prefill(self.cfg, params, small, prompt,
+                                          attn="full")
         k = lax.dynamic_update_slice(
             cache["k"], seeded["k"].astype(cache["k"].dtype),
             (0, slot, 0, 0, 0))
@@ -173,71 +173,12 @@ class LlamaRunner:
             (0, slot, 0, 0, 0))
         return {"k": k, "v": v}
 
-    def _decode_impl(self, params, cache, tokens, pos):
-        """One decode position for every slot at its OWN position.
-
-        tokens/pos: (S,) int32.  Returns (next_tokens (S,), new cache).
-        Adapted from ``llama._decode_step`` (shared scalar ``pos``) to
-        per-slot positions: rope angles per slot, cache write via one-hot
-        scatter at ``pos[s]``, causal mask ``arange(max_len) <= pos[s]``.
-        """
-        import jax
-        import jax.numpy as jnp
-        import numpy as np
-        from jax import lax
-
-        cfg, llama = self.cfg, self._llama
-        S = self.slots
-        hd, H, KV = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
-        scale = 1.0 / np.sqrt(hd)
-        max_len = self.max_len
-
-        def rope1(x, p):
-            # x: (S, Heads, hd) at per-slot positions p: (S,)
-            d = x.shape[-1]
-            freqs = 1.0 / (cfg.rope_theta
-                           ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-            ang = p[:, None].astype(jnp.float32) * freqs[None, :]
-            cos = jnp.cos(ang)[:, None, :]
-            sin = jnp.sin(ang)[:, None, :]
-            x1 = x[..., 0::2].astype(jnp.float32)
-            x2 = x[..., 1::2].astype(jnp.float32)
-            out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
-                            axis=-1)
-            return out.reshape(x.shape).astype(x.dtype)
-
-        write = (jnp.arange(max_len)[None, :] == pos[:, None])  # (S, L)
-        mask = (jnp.arange(max_len)[None, :] <= pos[:, None])   # (S, L)
-        h = params["embed"][tokens]                              # (S, D)
-
-        def layer(h, xs):
-            lp, ck, cv = xs                      # ck/cv: (S, max_len, KV, hd)
-            x = llama.rms_norm(h, lp["attn_norm"], cfg.norm_eps)
-            q = rope1((x @ lp["wq"]).reshape(S, H, hd), pos)
-            k_new = rope1((x @ lp["wk"]).reshape(S, KV, hd), pos)
-            v_new = (x @ lp["wv"]).reshape(S, KV, hd)
-            ck = jnp.where(write[:, :, None, None],
-                           k_new[:, None].astype(ck.dtype), ck)
-            cv = jnp.where(write[:, :, None, None],
-                           v_new[:, None].astype(cv.dtype), cv)
-            rep = H // KV
-            qg = q.reshape(S, KV, rep, hd).astype(jnp.float32)
-            s = jnp.einsum("sgrd,slgd->sgrl", qg,
-                           ck.astype(jnp.float32)) * scale
-            s = jnp.where(mask[:, None, None, :], s, llama._NEG_INF)
-            w = jax.nn.softmax(s, axis=-1)
-            o = jnp.einsum("sgrl,slgd->sgrd", w, cv.astype(jnp.float32))
-            h = h + (o.reshape(S, H * hd).astype(h.dtype) @ lp["wo"])
-            x = llama.rms_norm(h, lp["mlp_norm"], cfg.norm_eps)
-            g = jax.nn.silu(x @ lp["w_gate"]) * (x @ lp["w_up"])
-            return h + g @ lp["w_down"], (ck, cv)
-
-        h, (nk, nv) = lax.scan(layer, h,
-                               (params["layers"], cache["k"], cache["v"]))
-        h = llama.rms_norm(h, params["norm"], cfg.norm_eps)
-        logits = (h @ params["head"]).astype(jnp.float32)
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), \
-            {"k": nk, "v": nv}
+    def _greedy_step(self, params, cache, tokens, pos):
+        """Greedy next token of every slot at its own position: tokens and
+        pos (S,) int32 -> (next tokens (S,), new cache)."""
+        logits, cache = self._decode._decode_step(self.cfg, params, cache,
+                                                  tokens, pos)
+        return self._jnp.argmax(logits, axis=-1).astype(self._jnp.int32), cache
 
     # -- runner contract ---------------------------------------------------
     def prefill(self, slot: int, tokens: Sequence[int]) -> None:
@@ -249,7 +190,10 @@ class LlamaRunner:
         # K/V, and decode's ``arange <= pos`` mask keeps each garbage
         # pad entry invisible until the generated token at that position
         # overwrites it (the cache write lands before the attention
-        # read inside the layer).
+        # read inside the layer).  An expert layer with a capacity
+        # (``capacity_factor``) does see the pad: its slots grow with the
+        # padded length and the pads' first choices queue before the
+        # prompt's second ones; a dropless configuration has no queue.
         pad = _bucket_len(len(toks), self.max_len)
         toks += [0] * (pad - len(toks))
         prompt = jnp.asarray([toks], dtype=jnp.int32)
