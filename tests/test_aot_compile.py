@@ -838,3 +838,112 @@ def test_laguna_adamw_step_at_published_widths(v5e, monkeypatch):
     # rotation (compiled here at PR 40 and again at PR 41): 13.06 GB.
     peak = program.memory_analysis().peak_memory_in_bytes
     assert 12.0e9 < peak < 14.0e9
+
+
+def test_mellum2_ep4_adamw_step_at_published_widths(v5e, monkeypatch):
+    """The benchmark's `mellum2-12b-a2.5b-ep4-l8k` step on the four chips of
+    a described v5e 2x2, a mesh of ``ep`` = 4: Mellum2-12B-A2.5B at its
+    published widths, one whole period of 28 layers (three window layers and
+    a full one, experts in each), all 64 experts, 16 a chip, the whole
+    vocabulary on every chip, 8 x 8,192 tokens, two rows a chip, the
+    configuration file's remat, AdamW with bfloat16 moments, weights and state
+    donated.  The first program of this file that is one program across four
+    chips.  The compiler's own peak a chip is 13.53 GB of 16.91 (15.75 GiB)
+    with a pass of the whole uniform share a peer; with float32 moments that
+    is 17.1 GB and refused; at half the share a pass 12.02 GB with bfloat16
+    moments and 15.60 with float32 (my compiles of PR 44; the latter ran on
+    the chip, its steps moving by whole passes with the routing): two rows a
+    chip fit, the moments' type is the file's choice.  The flash kernels stand in their
+    ``shard_map`` over ``ep`` (the batch's rows), the experts' grouped
+    matmuls are Mosaic kernels too (every axis of the mesh is the expert
+    layer's ``shard_map``'s), and the exchange is ``all-to-all``s by name."""
+    import json
+    import os
+
+    import numpy as np
+    import optax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from torchmpi_tpu.models import llama
+    from torchmpi_tpu.models._common import mesh_spec
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "mellum2-12b-a2.5b.json")) as fh:
+        file = json.load(fh)
+    with open(os.path.join(root, "benchmark", "traffic",
+                           "ep4-l8k.json")) as fh:
+        mix = json.load(fh)
+    run = file["run"]
+    published = llama.mellum2_12b_a2_5b()
+    cfg = dataclasses.replace(published, n_layers=4,
+                              layer_kinds=published.layer_kinds[:4])
+    assert (file["num_hidden_layers"], file["num_experts"],
+            file["vocab_size"]) == (4, 64, 98304)
+    assert [n for *_, n in llama.layer_runs(cfg)] == [3, 1]
+    assert mix["mesh"] == {"ep": 4} and (mix["batch"], mix["seq_len"]) == (
+        8, 8192)
+    mesh = Mesh(np.array(v5e[:4]), ("ep",))
+    params = jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0), cfg,
+                                               dtype=jnp.bfloat16))
+    assert sum(a.size for a in jax.tree.leaves(params)) == 2_123_976_960
+    placed = jax.tree.map(
+        lambda a, s: _sds(a.shape, a.dtype, NamedSharding(
+            mesh, mesh_spec(s, mesh, a.shape))), params,
+        llama.param_specs(cfg))
+    a_chip = sum(int(np.prod(a.sharding.shard_shape(a.shape)))
+                 for a in jax.tree.leaves(placed))
+    assert a_chip == 934_891_776                # 16 of 64 experts a layer
+    # AdamW as `benchmark/runners/step_tokens_adamw.py:_optimizer` builds it:
+    # both moments in the file's type, whatever the weights'.
+    moments = jnp.dtype(run["optimizer"]["moments_dtype"])
+    assert moments == jnp.bfloat16
+    adamw = optax.adamw(run["optimizer"]["learning_rate"], b1=0.9, b2=0.95,
+                        weight_decay=0.1)
+    cast = lambda tree: jax.tree.map(lambda a: a.astype(moments), tree)
+
+    def update(grads, state, p):
+        updates, state = adamw.update(cast(grads), state, cast(p))
+        return jax.tree.map(lambda u, a: u.astype(a.dtype), updates,
+                            p), state
+
+    optimizer = optax.GradientTransformation(
+        lambda p: adamw.init(cast(p)), update)
+    like = iter(jax.tree.leaves(placed) * 2)
+    state = jax.tree.map(
+        lambda a: _sds(a.shape, a.dtype, next(like).sharding if a.ndim
+                       else NamedSharding(mesh, P())),
+        jax.eval_shape(optimizer.init, params))
+    assert llama.batch_spec(cfg, mesh) == P("ep", None)
+    tokens = _sds((mix["batch"], mix["seq_len"]), jnp.int32,
+                  NamedSharding(mesh, llama.batch_spec(cfg, mesh)))
+    step = llama.make_train_step(cfg, mesh, attn="flash", optimizer=optimizer,
+                                 remat=run["remat"],
+                                 loss_chunk=run["loss_chunk"],
+                                 with_delivered=True)
+    program = step.lower(placed, state, tokens, tokens).compile()
+    text = program.as_text()
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    named = lambda what, lines=kernels: sum(
+        bool(re.search(what, line)) for line in lines)
+    assert run["remat"] == "full"
+    assert (named("flash_fwd"), named("flash_bwd[^_]")) == (4, 4)
+    window = [line for line in kernels if "/swa/" in line]
+    assert (named("flash_fwd", window), named("flash_bwd", window)) == (3, 3)
+    # A layer's grouped matmuls: 3 forward, and gate and up again with the
+    # three products' two gradients each backward, inside the loops of passes.
+    assert len(kernels) == 4 * 2 + 4 * 11
+    assert llama.ep_pass_rows(cfg, 2 * 8192, 4) == 32768    # the share
+    exchanged = [line for line in text.splitlines()
+                 if re.search(r"= \S+ all-to-all", line)]
+    # five a pass in each layer's two loops, and one after the forward loop
+    # for the senders' counts of the rows they filled
+    assert len(exchanged) == 4 * (2 * 5 + 1) and all(
+        "moe.exchange" in line for line in exchanged)
+    # every (4, 32768, ...) block of rows is bfloat16: 604 MB a pass
+    assert sum("bf16[4,32768,2304]" in line for line in exchanged) == 4 * 5
+    peak = program.memory_analysis().peak_memory_in_bytes
+    assert 13.0e9 < peak < 14.1e9
+    assert peak > 0.25 * 16e9                   # the benchmark's floor

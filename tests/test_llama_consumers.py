@@ -50,6 +50,10 @@ CONFIGS = {
     "experts": llama.moe_tiny(),
     "dropless": dataclasses.replace(llama.moe_tiny(), capacity_factor=None,
                                     moe_aux_coef=0.0),
+    # a chip's share of a dropless layer's experts, which stands for the
+    # absent chips: what an ep axis has no form for
+    "held": dataclasses.replace(llama.moe_tiny(), capacity_factor=None,
+                                moe_aux_coef=0.0, experts_held=(0, 2)),
 }
 
 _PROMPT = jnp.zeros((1, 8), jnp.int32)
@@ -81,13 +85,10 @@ CONSUMERS = {
              lambda cfg: llama_pipeline.make_1f1b_train_step(cfg, None, 2)),
     "ring": ("attn='ring'", _ring("ring")),
     "ring-xla": ("attn='ring-xla'", _ring("ring-xla")),
-    "apply-on-ep": ("apply", lambda cfg: llama.apply(
-        cfg, None, _PROMPT, mesh=_ep_mesh())),
     "counts": ("expert_unit_counts", lambda cfg: llama.expert_unit_counts(
         cfg, None, _PROMPT)),
-    "counts-on-ep": ("expert_unit_counts",
-                     lambda cfg: llama.expert_unit_counts(
-                         cfg, None, _PROMPT, mesh=_ep_mesh())),
+    "experts-on-ep": ("an ep axis", lambda cfg: llama._moe_ffn(
+        cfg, None, jnp.zeros((4, 8, cfg.d_model)), mesh=_ep_mesh())),
 }
 
 # (configuration, consumer) -> the phrases its refusal holds.
@@ -134,9 +135,9 @@ CASES = {
     ("experts", "gpipe"): ("mixture of experts", "n_experts=4",
                            "aux loss through the stage boundary"),
     ("experts", "1f1b"): ("mixture of experts", "make_1f1b_train_step"),
-    ("dropless", "apply-on-ep"): ("ep", "capacity_factor=None",
-                                  "sorted dispatch", "mesh without ep"),
-    ("dropless", "counts-on-ep"): ("expert_unit_counts", "sorted dispatch"),
+    ("held", "experts-on-ep"): ("a chip's share of the experts",
+                                "experts_held=(0, 2)", "an ep axis",
+                                "the absent experts have none"),
 }
 
 
@@ -155,7 +156,7 @@ def test_every_row_of_the_table_is_reached():
     cases above reach every one of its rows."""
     traits = set().union(*(llama._traits(cfg) for cfg in CONFIGS.values()))
     assert traits == {"looped", "runs", "rotary_latent", "window", "experts",
-                      "dropless"}
+                      "held"}
     for consumer, rows in llama._LACKS.items():
         assert set(rows) <= traits, consumer
     reached = {(CONSUMERS[consumer][0],
@@ -170,18 +171,41 @@ def test_every_row_of_the_table_is_reached():
 
 def test_what_is_not_refused():
     """The plain stack passes every consumer's check; experts do where the
-    consumer has no row for them; a dropless configuration everywhere but
-    on an ``ep`` axis."""
+    consumer has no row for them, dropless or not, an ``ep`` axis too."""
     for consumer in llama._LACKS:
         llama._refuse(llama.tiny(), consumer)
-        llama._refuse(llama.tiny(), consumer, _ep_mesh())
     for consumer in ("the decode step", "prefill", "make_generate_fn",
-                     "apply", "expert_unit_counts", "attn='ring'"):
-        llama._refuse(CONFIGS["experts"], consumer, _ep_mesh())
+                     "an ep axis", "expert_unit_counts", "attn='ring'"):
+        llama._refuse(CONFIGS["experts"], consumer)
         llama._refuse(CONFIGS["dropless"], consumer)
-        llama._refuse(CONFIGS["dropless"], consumer,
-                      make_mesh({"dp": 2, "tp": 2},
-                                devices=jax.devices()[:4]))
+
+
+@pytest.mark.parametrize("consumer", ["apply-on-ep", "counts-on-ep",
+                                      "loss-on-ep"])
+def test_a_dropless_configuration_runs_on_an_ep_axis(consumer):
+    """What ``apply`` and ``expert_unit_counts`` refused until the sorted
+    dispatch had a form over ``ep``, they run: on dp x ep the logits, the
+    routed units an expert and the loss with its auxiliary term (the means
+    over all the ranks' tokens) are one device's."""
+    cfg = dataclasses.replace(CONFIGS["dropless"], moe_aux_coef=0.01,
+                              moe_z_coef=1e-3)
+    params = llama.init(jax.random.PRNGKey(0), cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0, cfg.vocab)
+    mesh = _ep_mesh()
+    sharded = llama.shard_params(params, mesh, cfg)
+    run = {
+        "apply-on-ep": lambda p, mesh: llama.apply(cfg, p, tokens, mesh=mesh),
+        "counts-on-ep": lambda p, mesh: llama.expert_unit_counts(
+            cfg, p, tokens, mesh=mesh),
+        "loss-on-ep": lambda p, mesh: llama.make_loss_fn(cfg, mesh)(
+            p, (tokens, jnp.roll(tokens, -1, 1))),
+    }[consumer]
+    got = jax.jit(lambda p: run(p, mesh))(sharded)
+    want = jax.jit(lambda p: run(p, None))(params)
+    if consumer == "counts-on-ep":
+        assert (got == want).all() and int(got.sum()) == 2 * 2 * tokens.size
+    else:
+        assert jnp.allclose(got, want, rtol=1e-5, atol=1e-5)
 
 
 def _names_the_benchmark_takes():

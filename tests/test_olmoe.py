@@ -265,12 +265,18 @@ def test_prefill_and_decode_apply_qk_norm(weights, sample):
     assert float(jnp.max(jnp.abs(plain[:, 32] - full[:, 32]))) > 1e-2
 
 
-def test_dropless_on_a_mesh(weights, sample):
+@pytest.mark.parametrize("axes", [{"dp": 2, "tp": 2}, {"ep": 2},
+                                  {"ep": 2, "tp": 2}],
+                         ids=["dp2-tp2", "ep2", "ep2-tp2"])
+def test_dropless_on_a_mesh(weights, sample, axes):
     """Under GSPMD on dp x tp the sorted dispatch gives one device's loss
-    and gradients; an `ep` axis is refused, since experts sharded over
-    chips have no sorted form yet."""
+    and gradients, the auxiliary and z terms with it; on an `ep` axis too
+    (the batch's two rows over it, 32 of the 64 experts a rank, the units
+    exchanged), which was refused until the sorted dispatch had a form for
+    experts sharded over chips."""
     alone = jax.jit(jax.value_and_grad(llama.make_loss_fn(CFG)))(weights, sample)
-    mesh = make_mesh({"dp": 2, "tp": 2}, devices=jax.devices()[:4])
+    mesh = make_mesh(axes, devices=jax.devices()[:int(np.prod(
+        list(axes.values())))])
     sharded = llama.shard_params(weights, mesh, CFG)
     loss, grads = jax.jit(jax.value_and_grad(
         llama.make_loss_fn(CFG, mesh)))(sharded, sample)
@@ -278,7 +284,3 @@ def test_dropless_on_a_mesh(weights, sample):
     for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(alone[1])):
         np.testing.assert_allclose(a, b, rtol=1e-3,
                                    atol=1e-5 * float(jnp.max(jnp.abs(b))))
-    with pytest.raises(NotImplementedError, match="ep"):
-        llama.make_loss_fn(CFG, make_mesh({"dp": 2, "ep": 2},
-                                          devices=jax.devices()[:4]))(
-            weights, sample)

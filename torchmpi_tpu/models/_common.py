@@ -35,15 +35,23 @@ def mesh_spec(spec: P, mesh: Mesh, shape=None) -> P:
     """THE axis-dropping rule, shared by every placement site: drop spec
     axes the mesh lacks; with ``shape`` also drop axes whose dimension the
     mesh axis size does not divide (e.g. a 10-class head over tp=4 stays
-    replicated instead of erroring).  Keeping one copy prevents the
-    placement helpers and the jit in/out shardings from disagreeing about
-    the same leaf."""
+    replicated instead of erroring).  An entry that names several axes (a
+    batch's rows over ``("dp", "ep")``) keeps those the mesh has, the one
+    that is left as a plain name.  Keeping one copy prevents the placement
+    helpers and the jit in/out shardings from disagreeing about the same
+    leaf."""
     sizes = dict(mesh.shape)
 
     def keep(i, ax):
-        if ax not in sizes:
+        if isinstance(ax, tuple):
+            kept = tuple(a for a in ax if a in sizes)
+            ax = kept[0] if len(kept) == 1 else kept or None
+            size = int(np.prod([sizes[a] for a in kept]))
+        elif ax in sizes:
+            size = sizes[ax]
+        else:
             return None
-        if shape is not None and shape[i] % sizes[ax] != 0:
+        if shape is not None and shape[i] % size != 0:
             return None
         return ax
 

@@ -36,10 +36,13 @@ module and it imports neither.
   for a grouped matmul (:func:`_moe_ffn_sorted`), with sigmoid scoring, a
   selection bias, a shared expert and a chip's share of the experts
   (``Config(experts_held=...)``, :func:`_held_experts`) where the
-  configuration has them.
+  configuration has them; on an ``ep`` axis its tokens are sharded over the
+  axis too (:func:`batch_spec`) and the sorted units go to the rank of their
+  expert and come back by an exchange (:func:`_moe_ffn_ep`,
+  :func:`mellum2_12b_a2_5b`).
 * What each consumer of a configuration (decode, prefill, generation, the
-  pipeline schedules, the rings, :func:`apply` itself) cannot run yet is one
-  table, ``_LACKS``, read by one :func:`_refuse`.
+  pipeline schedules, the rings, :func:`expert_unit_counts`) cannot run yet is
+  one table, ``_LACKS``, read by one :func:`_refuse`.
 
 Compute dtype is configurable (bfloat16 for TPU, float32 for CPU tests);
 norms, softmax, and the loss run in f32.
@@ -60,7 +63,8 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..parallel.mesh import AXIS_DP, AXIS_EP, AXIS_SP, AXIS_TP
-from ..parallel.moe import route_topk as _route_topk
+from ..parallel.moe import exchange as _exchange, pass_plan as _pass_plan, \
+    route_topk as _route_topk
 from ._common import dense_init as _dense, mesh_spec as _mesh_spec, \
     num_params, shard_by_specs, stack_dense
 
@@ -374,6 +378,27 @@ def laguna_s_2_1() -> Config:
                       ["dense"] + ["sparse"] * 47))
 
 
+def mellum2_12b_a2_5b() -> Config:
+    """Mellum2-12B-A2.5B geometry (``JetBrains/Mellum2-12B-A2.5B-Instruct``,
+    ``mellum``): 28 layers on a 2304-wide state, 32 heads of 128 over 4 KV
+    heads; three layers over the last 1,024 keys, rotated whole at theta
+    500,000, then one over all earlier keys, rotated whole with YaRN's
+    frequencies (factor 16 over 8,192) and attention factor, the full layer
+    LAST in each period of four; in every layer 64 SwiGLU experts of width
+    896, 8 a token, softmax over the 64, renormalised, dropless, no shared
+    expert, no dense layer; untied embedding and head of 98,304 rows."""
+    return Config(vocab=98304, d_model=2304, n_layers=28, n_heads=32,
+                  n_kv_heads=4, head_dim=128, d_ff=896, max_seq=131072,
+                  rope_theta=500000.0, norm_eps=1e-6, n_experts=64,
+                  expert_top_k=8, capacity_factor=None, moe_aux_coef=0.0,
+                  moe_renormalize=True, swa_window=1024,
+                  swa_rope_theta=500000.0,
+                  rope_yarn=(16.0, 8192, 32.0, 1.0, 1.2772588722239782),
+                  layer_kinds=window_layer_kinds(
+                      ["sliding_attention"] * 3 + ["full_attention"],
+                      ["sparse"] * 4) * 7)
+
+
 def layer_runs(cfg: Config) -> Tuple[Tuple[str, str, int], ...]:
     """The stack as homogeneous runs, ``(mixer, ffn, length)`` each:
     consecutive layers of one kind.  A configuration without
@@ -654,6 +679,39 @@ def shard_params(params: Params, mesh: Mesh, cfg: Config) -> Params:
     return shard_by_specs(params, mesh, param_specs(cfg))
 
 
+def _ep_ranks(cfg: Config, mesh: Optional[Mesh]) -> int:
+    """Ranks the sorted, dropless expert layer exchanges its units over: the
+    size of the mesh's ``ep`` axis; 1 on a mesh without one and for every
+    other FFN (the one-hot dispatch leaves ``ep`` to GSPMD)."""
+    if mesh is None or not cfg.n_experts or cfg.capacity_factor is not None:
+        return 1
+    return dict(mesh.shape).get(AXIS_EP, 1)
+
+
+def _batch_axes(cfg: Config, mesh: Optional[Mesh]):
+    """The mesh axes a batch's rows are sharded over: ``dp``, and ``ep`` too
+    where the expert layer exchanges over it (:func:`_moe_ffn_ep`: each rank
+    routes its own tokens, so between the expert layers the axis is one more
+    data-parallel one)."""
+    return (AXIS_DP, AXIS_EP) if _ep_ranks(cfg, mesh) > 1 else AXIS_DP
+
+
+def _rows_divide(cfg: Config, mesh: Mesh, n_rows: int) -> None:
+    """Raise ``ValueError`` unless a batch of ``n_rows`` rows divides over
+    the axes :func:`_batch_axes` names."""
+    axes = [a for a in _batch_axes(cfg, mesh) if a in mesh.shape]
+    if n_rows % int(np.prod([mesh.shape[a] for a in axes])):
+        raise ValueError(
+            f"a batch of {n_rows} rows does not divide over the mesh's "
+            f"{axes} axes: the sorted expert layer shards its tokens over ep")
+
+
+def batch_spec(cfg: Config, mesh: Mesh) -> P:
+    """The ``PartitionSpec`` of a ``(batch, seq_len)`` array of tokens or
+    targets as :func:`make_train_step` takes it on ``mesh``."""
+    return _mesh_spec(P(_batch_axes(cfg, mesh), None), mesh)
+
+
 # -------------------------------------------------------------------- forward
 
 def rms_norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
@@ -831,13 +889,14 @@ def _ring_attention_batched(mesh: Mesh, causal_scale,
 
 
 def _flash_attention_sharded(mesh: Optional[Mesh], heads: int,
-                             kv_heads: int,
-                             window: Optional[int] = None) -> Callable:
+                             kv_heads: int, window: Optional[int] = None,
+                             rows=AXIS_DP) -> Callable:
     """Causal flash attention ``(q, k, v) -> o`` for K/V at their native
     ``kv_heads``, over the last ``window`` keys where one is given (the
     kernels then run the band's blocks alone).  On a mesh the kernel runs
     inside a ``shard_map`` over
-    the batch (``dp``) and head (``tp``) axes: the TPU compiler refuses to
+    the batch (``rows``: ``dp``, and ``ep`` where :func:`_batch_axes` says so)
+    and head (``tp``) axes: the TPU compiler refuses to
     partition a Mosaic kernel itself (``NotImplementedError: Mosaic kernels
     cannot be automatically partitioned``), and each device wants only its
     own batch rows and head shard anyway — the layout the hand-sharded
@@ -861,7 +920,7 @@ def _flash_attention_sharded(mesh: Optional[Mesh], heads: int,
     if mesh is None or mesh.size == 1:
         return local
     spec = _mesh_spec(
-        P(AXIS_DP, None, _tp_head_axis(mesh, heads, kv_heads), None), mesh)
+        P(rows, None, _tp_head_axis(mesh, heads, kv_heads), None), mesh)
 
     def sharded(q, k, v):
         # Inside a pipeline stage ``pp`` is already manual: the nested
@@ -924,7 +983,8 @@ def _make_attn_impl(cfg: Config, attn: str, mesh: Optional[Mesh],
                 "ring-xla": "ring"}[attn]
         return _ring_attention_batched(mesh, scale, H, KV, impl=impl)
     if attn == "flash":
-        return _flash_attention_sharded(mesh, H, KV, window)
+        return _flash_attention_sharded(mesh, H, KV, window,
+                                        _batch_axes(cfg, mesh))
     if attn == "full":
         return lambda q, k, v: _causal_attention(q, k, v, scale, window)
     raise ValueError(
@@ -1116,7 +1176,8 @@ def _grouped_matmul(xs: jax.Array, w: jax.Array, counts: jax.Array,
                         interpret=jax.default_backend() != "tpu")[:M]
 
 
-def _route_tokens(cfg: Config, lp: Params, xt: jax.Array):
+def _route_tokens(cfg: Config, lp: Params, xt: jax.Array,
+                  axes: Tuple[str, ...] = ()):
     """The dropless router on tokens ``xt`` (T, D): float32 softmax over all
     experts, top-k with the weights renormalised over the chosen k or not as
     the configuration says.  Returns ``(weight (T, k) f32, expert (T, k)
@@ -1129,8 +1190,14 @@ def _route_tokens(cfg: Config, lp: Params, xt: jax.Array):
     the choice alone: the weights are the chosen scores without it, and its
     gradient is exactly zero), renormalised as published (``w / (sum + 1e-20)
     ``), and there is no auxiliary term (``aux`` 0).  ``cfg.routed_scale``
-    multiplies the weights of either router."""
+    multiplies the weights of either router.
+
+    Inside a ``shard_map`` whose ranks each hold an equal share of the tokens
+    (:func:`_moe_ffn_ep`), ``axes`` names its axes: ``counts`` stay this
+    rank's, and ``aux`` is the whole batch's, its means taken over the ranks
+    too."""
     E, k = cfg.n_experts, cfg.expert_top_k
+    over = lambda a: lax.pmean(a, axes) if axes else a
     logits = xt.astype(jnp.float32) @ lp["router"].astype(jnp.float32)
     if cfg.router_act == "sigmoid":
         scores = jax.nn.sigmoid(logits)
@@ -1155,10 +1222,10 @@ def _route_tokens(cfg: Config, lp: Params, xt: jax.Array):
         weight = weight * cfg.routed_scale
     chosen = jax.nn.one_hot(expert, E, dtype=jnp.int32)             # (T, k, E)
     counts = jnp.sum(chosen, axis=(0, 1))
-    aux = E * jnp.sum(jnp.mean(probs, axis=0)
-                      * jnp.mean(chosen[:, 0].astype(jnp.float32), axis=0))
+    aux = E * jnp.sum(over(jnp.mean(probs, axis=0)) * over(
+        jnp.mean(chosen[:, 0].astype(jnp.float32), axis=0)))
     if cfg.moe_z_coef:
-        aux = jnp.stack([aux, _router_z(logits)])
+        aux = jnp.stack([aux, over(_router_z(logits))])
     return weight, expert, counts, aux
 
 
@@ -1180,8 +1247,9 @@ def _moe_ffn_sorted(cfg: Config, lp: Params, x: jax.Array,
     keeps any dot (:func:`_wrap_remat`): a step forms the 9 products a layer
     that it requires and none twice.  The cost is k/E of the
     one-hot form's at C = G and does not grow with E.  On one device
-    (``mesh`` None or of size 1) or under GSPMD on dp and tp; :func:`apply`
-    refuses an ``ep`` axis.
+    (``mesh`` None or of size 1) or under GSPMD on dp and tp; on a mesh with
+    an ``ep`` axis the layer is :func:`_moe_ffn_ep`, and ``aux`` is then the
+    pair of it and the units the exchange delivered to each rank.
 
     For a chip's share of the experts (``cfg.experts_held``) the router is
     still ``n_experts`` wide and its weights are normalised over all k
@@ -1190,6 +1258,8 @@ def _moe_ffn_sorted(cfg: Config, lp: Params, x: jax.Array,
     experts' part of the layer's result plus the shared expert's, which every
     chip computes alike.  What the absent experts would add is left out: on
     one chip there is no exchange, and nothing stands in for one."""
+    if _ep_ranks(cfg, mesh) > 1:
+        return _moe_ffn_ep(cfg, lp, x, mesh)
     B, L, D = x.shape
     k = cfg.expert_top_k
     T = B * L
@@ -1372,6 +1442,237 @@ def _held_experts_bwd(k, R, kernel, saved, dy):
 _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
+def ep_pass_rows(cfg: Config, n_tokens: int, ep: int) -> int:
+    """Rows a peer a pass of :func:`_ep_experts` for a rank's ``n_tokens``
+    tokens on ``ep`` ranks: the rows uniform routing sends one rank's experts
+    from one rank's tokens (k * T / ep), in whole tiles of 16, and never more
+    than the units one rank's experts can be sent (a token meets an expert
+    once).  It decides time and memory, never the result: a pair of ranks
+    with more units takes another pass.  A pass costs what its buffers hold,
+    filled or not (the all-to-all and the gathers move ep times its rows), so
+    a step's time moves in whole passes.  The fullest pair is always above
+    the share and at seeded weights under twice it, so at the share itself
+    every layer takes two passes and a step's time is steady.  Two other
+    sizes were read and none between one and two shares (PERF.md sections 6
+    and 7, PR 44, Mellum2's layer: 131,072 routed units a rank, 2304 wide):
+    at half the share a layer takes three or four passes as its routing
+    falls, a batch with one pass more ran 5% longer and the compiler's plan
+    was 1.5 GB smaller; a size that one pass covers has to pass the fullest
+    pair of every layer of every step, or that step takes two passes of the
+    larger buffers."""
+    k = cfg.expert_top_k
+    most = min(k, cfg.n_experts // ep) * n_tokens
+    return -(-min(-(-k * n_tokens // ep), most) // 16) * 16
+
+
+def _ep_pass(k, R, n_tokens, order, first, sent, p):
+    """Pass ``p`` of :func:`_ep_experts` on the sending side: for each rank,
+    rows ``p * R`` to ``(p + 1) * R`` of the units that go to its experts, in
+    sorted order.  Returns ``(token, unit)``, (ranks, R) each: ``n_tokens``
+    and ``n_tokens * k``, which no gather or scatter reaches, where a row is
+    past the units that rank is sent."""
+    at = p * R + jnp.arange(R)
+    valid = at < jnp.sum(sent, axis=1)[:, None]
+    unit = jnp.where(valid, order.at[first[:, None] + at].get(mode="clip"),
+                     n_tokens * k)
+    return jnp.where(valid, unit // k, n_tokens), unit
+
+
+def _ep_arrived(R, arrived, p):
+    """Pass ``p`` on the receiving side, from the units ``arrived`` (ranks,
+    experts held) each rank sends in all: the valid rows of each rank's block
+    as a column (ranks, R, 1), and each held expert's rows in it."""
+    ends = jnp.cumsum(arrived, axis=1)
+    lo = p * R
+    kept = jnp.clip(ends - lo, 0, R) - jnp.clip(ends - arrived - lo, 0, R)
+    return (jnp.arange(R) < jnp.sum(kept, axis=1)[:, None])[..., None], kept
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
+def _ep_experts(k, R, kernel, axis, xt, wflat, order, plan, w):
+    """The routed experts, sharded over the mesh axis ``axis``, on this
+    rank's tokens ``xt`` (T, D) -> (T, D), inside a ``shard_map``: the sum,
+    for each token, of its k units, each unit its expert's SwiGLU times the
+    router's weight ``wflat`` (T * k, float32), whichever rank holds the
+    expert; and with it ``delivered`` (ranks,) int32, the rows of each rank
+    that the passes carried here and ran.  ``order`` lists this rank's units
+    by expert (so by rank: rank r holds experts r * E / ep and on), ``plan``
+    is ``parallel.moe.pass_plan`` of their counts at ``R`` rows a pass, ``w``
+    is ``(w_gate, w_up, w_down)`` of the experts held here.
+
+    A pass gathers ``R`` rows for each rank (:func:`ep_pass_rows`; tokens'
+    rows and router weights), sends each block to its rank and receives one
+    from each (``parallel.moe.exchange``: one all-to-all of (ranks, R, D)),
+    runs the held experts on each block (:func:`_held_swiglu`: a block's rows
+    lie by expert, ``arrived`` says how many each), sends the results back
+    the same way and adds them to their tokens in float32.  As many passes
+    run as the fullest pair of ranks needs, the same number on every rank
+    (``parallel.moe.pass_plan``): the shapes are static, memory is a pass's
+    and no unit is dropped under any imbalance.  A loop whose length the data
+    decides has no transpose, so the gradient is written out
+    (:func:`_ep_experts_bwd`): it keeps the inputs alone and takes the same
+    passes, the rows and the results' cotangents out, the rows' and the router
+    weights' cotangents back, each block through :func:`_held_swiglu`'s own
+    VJP, the weights' gradients summed over blocks and passes in float32 and
+    left on the rank that holds the experts.
+
+    ``delivered`` is counted where the rows move, not read from the plan: a
+    sender counts the rows of each block that its gather filled, a receiver
+    the rows of each block that its experts ran, both over the passes that
+    ran; the senders' counts follow their rows (one small exchange after the
+    loop) and a block delivered the lesser of the two.  With too few passes,
+    or a mask on either side that leaves rows out, ``delivered`` falls short
+    of the routers' counts: that is how a caller sees a dropped unit.
+
+    :func:`_held_experts` is the same passes without an exchange, and the two
+    share what a pass computes (:func:`_held_swiglu`, :func:`_grouped_matmul`)
+    but not the loop: a chip's share runs on one device under no
+    ``shard_map``, so it has no axis to exchange over; it leaves the units of
+    the experts it lacks out where this sends every unit somewhere; it has one
+    block where this has one a source; and its passes are counted from its own
+    units where these are the fullest pair's over the axis.  One loop for
+    both would change the compiled steps of the three accepted cells that
+    hold a share (``experts_held``), which this form's arrival left to the
+    byte: ROADMAP.md R6 has it as a change of its own, measured in those
+    cells."""
+    return _ep_experts_fwd(k, R, kernel, axis, xt, wflat, order, plan, w)[0]
+
+
+def _ep_experts_fwd(k, R, kernel, axis, xt, wflat, order, plan, w):
+    T = xt.shape[0]
+    sent, arrived, first, passes = plan
+
+    def one_pass(p, carry):
+        y, filled, ran = carry
+        with jax.named_scope("moe.dispatch"):
+            token, unit = _ep_pass(k, R, T, order, first, sent, p)
+            xs = xt.at[token].get(mode="fill", fill_value=0)
+            ws = wflat.at[unit].get(mode="fill", fill_value=0)
+        xs, ws = _exchange(xs, axis), _exchange(ws, axis)
+        rows, kept = _ep_arrived(R, arrived, p)
+        with jax.named_scope("moe.experts"):
+            ys = lax.map(lambda block: _held_swiglu(kernel, *block, *w),
+                         (rows, kept, xs, ws[..., None]))
+        ys = _exchange(ys, axis)
+        with jax.named_scope("moe.combine"):
+            return (y.at[token].add(ys.astype(jnp.float32), mode="drop"),
+                    filled + jnp.sum(token < T, axis=1, dtype=jnp.int32),
+                    ran + jnp.sum(rows[..., 0], axis=1, dtype=jnp.int32))
+
+    none = jnp.zeros(sent.shape[:1], jnp.int32)
+    y, filled, ran = lax.fori_loop(
+        0, passes, one_pass, (jnp.zeros(xt.shape, jnp.float32), none, none))
+    delivered = jnp.minimum(_exchange(filled, axis), ran)
+    return (y.astype(xt.dtype), delivered), (xt, wflat, order, plan, w)
+
+
+def _ep_experts_bwd(k, R, kernel, axis, saved, given):
+    dy, _ = given
+    xt, wflat, order, plan, w = saved
+    T = xt.shape[0]
+    f32 = lambda a: a.astype(jnp.float32)
+    sent, arrived, first, passes = plan
+
+    def one_pass(p, grads):
+        dxt, dwflat, dw = grads
+        with jax.named_scope("moe.dispatch"):
+            token, unit = _ep_pass(k, R, T, order, first, sent, p)
+            xs = xt.at[token].get(mode="fill", fill_value=0)
+            ws = wflat.at[unit].get(mode="fill", fill_value=0)
+        with jax.named_scope("moe.combine"):
+            dys = dy.at[token].get(mode="fill", fill_value=0)
+        xs, ws, dys = (_exchange(a, axis) for a in (xs, ws, dys))
+
+        def block(dw, given):
+            rows, kept, xs, ws, dys = given
+            dxs, dws, *dwp = jax.vjp(functools.partial(
+                _held_swiglu, kernel, rows, kept), xs, ws, *w)[1](dys)
+            return tuple(a + f32(b) for a, b in zip(dw, dwp)), (dxs, dws)
+
+        with jax.named_scope("moe.experts"):
+            dw, (dxs, dws) = lax.scan(
+                block, dw, (*_ep_arrived(R, arrived, p), xs, ws[..., None],
+                            dys))
+        dxs, dws = _exchange(dxs, axis), _exchange(dws[..., 0], axis)
+        with jax.named_scope("moe.dispatch"):
+            return (dxt.at[token].add(f32(dxs), mode="drop"),
+                    dwflat.at[unit].add(dws, mode="drop"), dw)
+
+    zeros = lambda a: jnp.zeros(a.shape, jnp.float32)
+    dxt, dwflat, dw = lax.fori_loop(
+        0, passes, one_pass,
+        (zeros(xt), zeros(wflat), tuple(zeros(a) for a in w)))
+    none = lambda a: np.zeros(a.shape, jax.dtypes.float0)
+    return (dxt.astype(xt.dtype), dwflat.astype(wflat.dtype), none(order),
+            jax.tree.map(none, plan),
+            tuple(g.astype(a.dtype) for g, a in zip(dw, w)))
+
+
+_ep_experts.defvjp(_ep_experts_fwd, _ep_experts_bwd)
+
+
+def _moe_ffn_ep(cfg: Config, lp: Params, x: jax.Array, mesh: Mesh):
+    """:func:`_moe_ffn_sorted` on a mesh with an ``ep`` axis, x (B, L, D) ->
+    ``(out, (aux, delivered))``.  The batch's rows are sharded over ``dp`` and
+    ``ep`` (:func:`_batch_axes`) and each rank holds ``n_experts / ep``
+    experts, a contiguous range, whole.  In one ``shard_map`` over the axes
+    that share the tokens each rank routes its own over all the experts
+    (:func:`_route_tokens`, the auxiliary terms' means over the ranks too),
+    sorts its units by expert, so by the rank that holds it, and hands them
+    to :func:`_ep_experts`, which sends every unit to its expert and brings
+    the result back: no capacity, no routing group, none dropped.  The router
+    and whatever else every rank holds alike enter whole, so their gradients
+    leave summed over the ranks; the experts' stay where the experts are.
+    The grouped matmul is the Mosaic kernel where every axis of the mesh is
+    one of the ``shard_map``'s, ``lax.ragged_dot`` where ``tp`` is left to
+    GSPMD.  ``delivered`` (ep, ep) int32: the units of rank s that reached
+    the experts of rank r and ran there, at [r, s], counted in the passes
+    themselves (:func:`_ep_experts`); a layer's sum to ``k * B * L``, or a
+    unit was dropped."""
+    from jax import shard_map
+
+    _refuse(cfg, "an ep axis")
+    ep, k = _ep_ranks(cfg, mesh), cfg.expert_top_k
+    if cfg.n_experts % ep:
+        raise ValueError(f"{cfg.n_experts} experts do not divide over "
+                         f"ep={ep}")
+    sp = AXIS_SP if AXIS_SP in mesh.shape else None
+    shared = tuple(a for a in (AXIS_DP, AXIS_EP, sp) if a in mesh.shape)
+    kernel = set(shared) == set(mesh.axis_names)
+    _rows_divide(cfg, mesh, x.shape[0])
+    rows = _mesh_spec(P(_batch_axes(cfg, mesh), sp, None), mesh)
+    routers = {name: lp[name] for name in ("router", "router_bias")
+               if name in lp}
+
+    def local(x, routers, w):
+        B, L, D = x.shape
+        T = B * L
+        xt = x.reshape(T, D)
+        with jax.named_scope("moe.router"):
+            weight, expert, units, aux = _route_tokens(cfg, routers, xt,
+                                                       shared)
+        with jax.named_scope("moe.dispatch"):
+            order = jnp.argsort(expert.reshape(T * k), stable=True)
+        R = ep_pass_rows(cfg, T, ep)
+        plan = _pass_plan(units, R, AXIS_EP)
+        y, delivered = _ep_experts(k, R, kernel, AXIS_EP, xt,
+                                   weight.reshape(T * k), order, plan, w)
+        if len(shared) > 1:
+            delivered = lax.psum(delivered, tuple(
+                a for a in shared if a != AXIS_EP))
+        return y.reshape(B, L, D), aux, delivered[None]
+
+    held = P(AXIS_EP, None, None)
+    y, aux, delivered = shard_map(
+        local, mesh=mesh, axis_names=set(shared), check_vma=False,
+        in_specs=(rows, jax.tree.map(lambda _: P(), routers), (held,) * 3),
+        out_specs=(rows, P(), P(AXIS_EP, None)))(
+            x, routers, (lp["w_gate"], lp["w_up"], lp["w_down"]))
+    B, L, D = x.shape
+    y = _add_shared_expert(cfg, lp, x.reshape(B * L, D), y.reshape(B * L, D))
+    return y.reshape(B, L, D), (aux, delivered)
+
+
 def _qk_norm(cfg: Config, lp: Params, q: jax.Array, k: jax.Array):
     """QK-norm: RMSNorm over the whole q and k projections (..., H*hd) and
     (..., KV*hd), before the heads are split and rotated (OLMoE,
@@ -1530,11 +1831,14 @@ def _gate_heads(o: jax.Array, x: jax.Array, wg: jax.Array) -> jax.Array:
     return (o * gate[..., None]).astype(o.dtype)
 
 
-def _aux_zero(cfg: Config):
+def _aux_zero(cfg: Config, mesh: Optional[Mesh] = None):
     """What a layer without experts adds to the stack's aux sum: 0 (two for
-    a configuration with a z-loss)."""
-    return jnp.zeros((2,) if cfg.n_experts and cfg.moe_z_coef else (),
+    a configuration with a z-loss); where the expert layers exchange over
+    ``ep`` (:func:`_moe_ffn_ep`) the pair of it and no unit delivered."""
+    zero = jnp.zeros((2,) if cfg.n_experts and cfg.moe_z_coef else (),
                      jnp.float32)
+    ep = _ep_ranks(cfg, mesh)
+    return (zero, jnp.zeros((ep, ep), jnp.int32)) if ep > 1 else zero
 
 
 def _ffn_block(cfg: Config, lp: Params, h: jax.Array,
@@ -1552,7 +1856,7 @@ def _ffn_block(cfg: Config, lp: Params, h: jax.Array,
         with jax.named_scope("ffn"):
             g = ((jax.nn.silu(x @ lp["w_gate"]) * (x @ lp["w_up"]))
                  @ lp["w_down"])
-        aux = _aux_zero(cfg)
+        aux = _aux_zero(cfg, mesh)
     if cfg.sandwich_norm:
         with jax.named_scope("ffn"):
             g = rms_norm(g, lp["mlp_post_norm"], cfg.norm_eps)
@@ -1717,11 +2021,11 @@ def _traits(cfg: Config) -> Dict[str, str]:
             "window layers among full ones, a gate on the attention output "
             "or a scaled or partial rotation", "swa_window", "attn_gate",
             "rope_fraction", "rope_yarn")
-    if cfg.n_experts and cfg.capacity_factor is None:
-        found["dropless"] = called("a dropless configuration on an ep axis",
-                                   "capacity_factor")
     if cfg.n_experts:
         found["experts"] = called("a mixture of experts", "n_experts")
+    if cfg.experts_held:
+        found["held"] = called("a chip's share of the experts",
+                               "experts_held")
     return found
 
 
@@ -1759,23 +2063,21 @@ _RING_ROWS = _rows(
     runs="ring kernels for more than one head width (they take one head "
     "width for q, k and v) and a recurrent state that crosses sequence shards")
 
-_NO_EP = _rows(
-    "use a mesh without ep, or a capacity_factor for the one-hot dispatch "
-    "that GSPMD shards",
-    dropless="a form of the sorted dispatch, which gathers its routed units "
-    "into one array for a grouped matmul, for experts sharded over ep")
-
 # What each consumer of a configuration cannot run, and why: consumer ->
 # trait (:func:`_traits`) -> what the consumer lacks for it.  A trait without
 # a row the consumer runs.  :func:`_refuse` is the table's one reader; the
 # consumers in ``llama_decode`` and ``llama_pipeline`` have their rows here,
 # beside the model they would have to follow.
 _LACKS: Dict[str, Dict[str, str]] = {
-    "apply": _NO_EP,
-    "expert_unit_counts": {**_NO_EP, **_rows(
+    "an ep axis": _rows(
+        "hold all the experts (experts_held=None), or use a mesh without ep",
+        held="the ranks the share stands for (a share is what ONE of the "
+        "chips that divide a layer holds; the exchange sends a unit to the "
+        "rank of its expert, and the absent experts have none)"),
+    "expert_unit_counts": _rows(
         _TRAIN,
         looped="a row for each recurrent step's routers (it runs its layers "
-        "once, with no norm on a branch's output)")},
+        "once, with no norm on a branch's output)"),
     **{f"attn={attn!r}": _RING_ROWS for attn in _RINGS},
     "the decode step": _rows(
         _TRAIN,
@@ -1818,16 +2120,14 @@ _LACKS: Dict[str, Dict[str, str]] = {
 }
 
 
-def _refuse(cfg: Config, consumer: str, mesh: Optional[Mesh] = None) -> None:
+def _refuse(cfg: Config, consumer: str) -> None:
     """Raise ``NotImplementedError`` if ``consumer`` has a row in ``_LACKS``
     for something ``cfg`` is: the first such row in the consumer's order, by
     the consumer's name, the trait with its field values and what is
-    missing.  ``mesh`` is the mesh the consumer runs on, where it has one: a
-    dropless configuration is refused on an ``ep`` axis alone."""
-    ep = 1 if mesh is None else dict(mesh.shape).get(AXIS_EP, 1)
+    missing."""
     has = _traits(cfg)
     for trait, lacks in _LACKS[consumer].items():
-        if trait in has and (trait != "dropless" or ep > 1):
+        if trait in has:
             raise NotImplementedError(
                 f"{consumer} has no form yet for {has[trait]}: it lacks "
                 f"{lacks}")
@@ -1948,7 +2248,7 @@ def expert_unit_counts(cfg: Config, params: Params, tokens: jax.Array,
     columns are what the step's tiles see.  A multi-token-prediction
     module's router is one more row, the last, on the next tokens
     ``mtp_tokens`` it reads (:func:`_mtp_input`)."""
-    _refuse(cfg, "expert_unit_counts", mesh)
+    _refuse(cfg, "expert_unit_counts")
     if cfg.mtp_layers and mtp_tokens is None:
         raise ValueError("a configuration with a multi-token-prediction "
                          "module needs mtp_tokens, the batch's targets")
@@ -2043,7 +2343,13 @@ def apply(cfg: Config, params: Params, tokens: jax.Array,
 
     ``mesh`` enables activation sharding constraints (and is required for
     ``attn='ring'``); without it the model runs unconstrained (single-device
-    or auto-sharded).
+    or auto-sharded).  On a mesh with an ``ep`` axis a dropless configuration
+    shards the batch's rows over ``ep`` as over ``dp`` (:func:`batch_spec`)
+    and exchanges its routed units over the axis (:func:`_moe_ffn_ep`), and
+    ``aux`` is then the pair ``(aux, delivered)``: ``delivered`` (ep, ep)
+    int32, the units of rank s that reached the experts of rank r at [r, s],
+    counted in the exchange's passes and summed over the stack's expert
+    layers: ``k * B * L`` a layer in all, or one was dropped.
 
     ``remat`` is the rematerialization policy applied to each layer
     (:func:`_wrap_remat` has the list of what each keeps; no policy runs a
@@ -2106,10 +2412,12 @@ def apply(cfg: Config, params: Params, tokens: jax.Array,
         if mesh is None or mesh.empty:
             return x
         # Drop axes the mesh doesn't have (e.g. sp on a pure dp x tp mesh).
-        kept = _mesh_spec(P(AXIS_DP, AXIS_SP, None), mesh)
+        kept = _mesh_spec(P(_batch_axes(cfg, mesh), AXIS_SP, None), mesh)
         return lax.with_sharding_constraint(x, NamedSharding(mesh, kept))
 
-    _refuse(cfg, "apply", mesh)
+    exchanged = _ep_ranks(cfg, mesh) > 1
+    if exchanged:
+        _rows_divide(cfg, mesh, B)
     with jax.named_scope("embed"):
         h = constrain(params["embed"][tokens])      # (B, L, D)
     impls = _mixer_impls(cfg, attn, mesh)
@@ -2156,12 +2464,16 @@ def apply(cfg: Config, params: Params, tokens: jax.Array,
         with jax.named_scope("final_norm"):
             return (rms_norm(h, params["norm"], cfg.norm_eps), aux), h
 
-    carry = (h, _aux_zero(cfg))
+    carry = (h, _aux_zero(cfg, mesh))
     states = []
     for r in remats:
         carry, before_norm = ut_step(carry, r)
         states.append(carry[0])
-    aux = carry[1] / (cfg.n_layers * cfg.ut_steps)
+    aux = carry[1]
+    if exchanged:
+        aux = (aux[0] / (cfg.n_layers * cfg.ut_steps), aux[1])
+    else:
+        aux = aux / (cfg.n_layers * cfg.ut_steps)
     h = jnp.stack(states) if all_steps else states[-1]
     head = lambda h: (h if return_hidden
                       else (h @ params["head"]).astype(jnp.float32))
@@ -2173,7 +2485,7 @@ def apply(cfg: Config, params: Params, tokens: jax.Array,
         with jax.named_scope("mtp"):
             x = _mtp_input(cfg, params, before_norm, mtp_tokens, constrain)
             x, _ = run_loop(*cfg.layer_kinds[-1], 1, params["mtp"]["layer"])[
-                remats[-1]]((x, _aux_zero(cfg)))
+                remats[-1]]((x, _aux_zero(cfg, mesh)))
             with jax.named_scope("final_norm"):
                 x = rms_norm(x, params["mtp"]["norm"], cfg.norm_eps)
             out = (out, head(x))
@@ -2226,8 +2538,23 @@ def make_loss_fn(cfg: Config, mesh: Optional[Mesh] = None, attn: str = "full",
     the embedding and the head are each read twice, and their gradients are
     the sums of both paths.
     """
+    both = _loss_and_delivered(cfg, mesh, attn, remat, loss_chunk, layer_loop)
 
     def loss_fn(params: Params, batch: Tuple[jax.Array, jax.Array]) -> jax.Array:
+        return both(params, batch)[0]
+
+    return loss_fn
+
+
+def _loss_and_delivered(cfg: Config, mesh: Optional[Mesh], attn: str,
+                        remat: Remat, loss_chunk: int,
+                        layer_loop: Optional[str] = None):
+    """:func:`make_loss_fn`'s loss as the pair ``(loss, delivered)``:
+    ``delivered`` is what the expert layers' exchange over ``ep`` counted
+    (:func:`apply`'s ``aux`` on such a mesh), None where nothing is
+    exchanged."""
+
+    def loss_fn(params: Params, batch: Tuple[jax.Array, jax.Array]):
         tokens, targets = batch
         positions = None
         if attn == "ring-zigzag":
@@ -2245,11 +2572,12 @@ def make_loss_fn(cfg: Config, mesh: Optional[Mesh] = None, attn: str = "full",
             tokens = tokens[:, idx]
             targets = targets[:, idx]
             positions = jnp.asarray(idx)
-        h, aux = apply(cfg, params, tokens, mesh=mesh, attn=attn, remat=remat,
-                       return_hidden=True, return_aux=True,
-                       layer_loop=layer_loop, positions=positions,
-                       all_steps=cfg.exit_gate,              # (B, L, D)
-                       mtp_tokens=targets if cfg.mtp_layers else None)
+        h, aux = apply(
+            cfg, params, tokens, mesh=mesh, attn=attn, remat=remat,
+            return_hidden=True, return_aux=True, layer_loop=layer_loop,
+            positions=positions, all_steps=cfg.exit_gate,   # (B, L, D)
+            mtp_tokens=targets if cfg.mtp_layers else None)
+        aux, delivered = aux if _ep_ranks(cfg, mesh) > 1 else (aux, None)
         if cfg.exit_gate:
             nll = _expected_exit_nll(cfg, params, h, targets, loss_chunk)
         elif cfg.mtp_layers:
@@ -2260,7 +2588,7 @@ def make_loss_fn(cfg: Config, mesh: Optional[Mesh] = None, attn: str = "full",
             nll = nll + cfg.moe_aux_coef * aux[0] + cfg.moe_z_coef * aux[1]
         elif cfg.n_experts:
             nll = nll + cfg.moe_aux_coef * aux
-        return nll
+        return nll, delivered
 
     return loss_fn
 
@@ -2406,11 +2734,20 @@ def _zero1_opt_shardings(cfg: Config, mesh: Mesh, opt_state_example,
 def make_train_step(cfg: Config, mesh: Mesh, lr: float = 3e-4,
                     attn: str = "full", optimizer=None,
                     remat: Remat = "none", loss_chunk: int = 0,
-                    zero1: bool = False, opt_state_example=None):
+                    zero1: bool = False, opt_state_example=None,
+                    with_delivered: bool = False):
     """One pjit'd dp x tp (x sp/ep) training step over ``mesh``:
     ``step(params, opt_state, tokens, targets) -> (params, opt_state, loss)``.
     Params tp-sharded per :func:`param_specs`; batch dp-sharded; XLA inserts
-    the gradient psums over dp and the activation psums over tp.  ``remat``/
+    the gradient psums over dp and the activation psums over tp.  For a
+    dropless configuration on a mesh with an ``ep`` axis the batch's rows are
+    sharded over ``ep`` too (:func:`batch_spec`) and the experts over it:
+    between the expert layers the axis is data-parallel, so the gradients of
+    what every rank holds alike (attention, routers, norms, embedding, head)
+    are summed over it as over ``dp``, and an expert's stay on the rank that
+    holds it; ``with_delivered`` adds a fourth result, the units the exchange
+    delivered in this step ((ep, ep) int32 as :func:`apply`'s ``aux`` has
+    them; None on a mesh that exchanges nothing).  ``remat``/
     ``loss_chunk`` as in :func:`apply`/:func:`make_loss_fn` — pass
     ``remat="dots"`` and a ``loss_chunk`` for 8B-scale configs.
 
@@ -2424,8 +2761,7 @@ def make_train_step(cfg: Config, mesh: Mesh, lr: float = 3e-4,
     outside the gradient owns: its gradient is exactly zero and no optimizer
     steps or decays it here (the leaf ``router_bias`` leaves the step as it
     came)."""
-    loss_fn = make_loss_fn(cfg, mesh=mesh, attn=attn, remat=remat,
-                           loss_chunk=loss_chunk)
+    loss_fn = _loss_and_delivered(cfg, mesh, attn, remat, loss_chunk)
     specs = param_specs(cfg)
     # Shape-aware axis dropping so these jit shardings agree with
     # shard_params' placement on every leaf (shared rule: _common.mesh_spec).
@@ -2433,7 +2769,7 @@ def make_train_step(cfg: Config, mesh: Mesh, lr: float = 3e-4,
     p_shard = jax.tree.map(
         lambda sh, s: NamedSharding(mesh, _mesh_spec(s, mesh, sh.shape)),
         pshapes, specs)
-    batch_sh = NamedSharding(mesh, P(AXIS_DP, None))
+    batch_sh = NamedSharding(mesh, batch_spec(cfg, mesh))
     repl = NamedSharding(mesh, P())
     if zero1:
         if optimizer is None or opt_state_example is None:
@@ -2444,7 +2780,8 @@ def make_train_step(cfg: Config, mesh: Mesh, lr: float = 3e-4,
         opt_sh = None
 
     def step(params, opt_state, tokens, targets):
-        loss, grads = jax.value_and_grad(loss_fn)(params, (tokens, targets))
+        (loss, delivered), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+            params, (tokens, targets))
         before = params
         with jax.named_scope("optimizer"):
             if optimizer is not None:
@@ -2459,11 +2796,13 @@ def make_train_step(cfg: Config, mesh: Mesh, lr: float = 3e-4,
                 lambda path, old, new: old if getattr(
                     path[-1], "key", None) == "router_bias" else new,
                 before, params)
+        if with_delivered:
+            return params, opt_state, loss, delivered
         return params, opt_state, loss
 
     return jax.jit(
         step,
         in_shardings=(p_shard, opt_sh, batch_sh, batch_sh),
-        out_shardings=(p_shard, opt_sh, repl),
+        out_shardings=(p_shard, opt_sh, repl) + (repl,) * with_delivered,
         donate_argnums=(0, 1),
     )

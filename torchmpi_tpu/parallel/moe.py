@@ -17,6 +17,15 @@ Absent from the reference (SURVEY.md §2.3: "EP — absent; new in TPU build")
   then the inverse all-to-all returns outputs to the tokens' home devices.
 
 ``shard_map`` body + a jit wrapper, same structure as parallel/sequence.py.
+
+Two expert layers move tokens over ``ep``, and both through :func:`exchange`,
+the one all-to-all written here.  This module's capacity-bucket layer is the
+building block for a program that wants static buckets and accepts drops; no
+model of ``models/`` uses it.  ``models.llama._moe_ffn_sorted`` is the
+dropless one a model trains with: its units are sorted by destination rank
+and expert and sent a fixed pass of rows a peer at a time, as many passes as
+arrived, so its shapes are as static as the buckets' and nothing is dropped
+(:func:`pass_plan` counts what a rank sends and receives).
 """
 
 from __future__ import annotations
@@ -90,6 +99,35 @@ def route_topk(probs: jax.Array, k: int, renormalize: bool):
     return expert_f, weight_f, onehot, pos_excl
 
 
+def exchange(blocks: jax.Array, axis: str) -> jax.Array:
+    """``blocks`` (p, ...), one block for each rank of ``axis`` -> (p, ...):
+    block j goes to rank j, and block i of the result is what rank i sent
+    here.  Applied twice it is the identity, and it is its own transpose: a
+    result goes home, and a gradient goes back, by the same call.  Scope
+    ``moe.exchange`` in the device program."""
+    with jax.named_scope("moe.exchange"):
+        return lax.all_to_all(blocks, axis, split_axis=0, concat_axis=0,
+                              tiled=True)
+
+
+def pass_plan(units: jax.Array, rows: int, axis: str):
+    """What a rank of ``axis`` sends and receives when its routed units,
+    sorted by expert (so by the rank that holds the expert, ranks holding
+    equal contiguous shares), go out ``rows`` a peer a pass.  ``units`` (E,)
+    int32 counts this rank's units by expert.  Returns ``(sent, arrived,
+    first, passes)``: ``sent`` (p, E / p) the units for each rank's experts,
+    ``arrived`` (p, E / p) those each rank sends for the experts held here
+    (one small :func:`exchange`), ``first`` (p,) where each rank's units begin
+    in the sorted order, and ``passes``, the same on every rank: as many as
+    the fullest pair of ranks needs, so no unit is left behind."""
+    p = lax.psum(1, axis)
+    sent = units.reshape(p, -1)
+    arrived = exchange(sent, axis)
+    total = jnp.sum(sent, axis=1)
+    passes = -(-lax.pmax(jnp.max(total), axis) // rows)
+    return sent, arrived, jnp.cumsum(total) - total, passes
+
+
 def _moe_body(x, gate_w, w_in, w_out, *, n_experts: int, capacity: int,
               axis: str, k: int, renormalize: bool):
     """Per-device body.  x: (T_local, D); w_in/w_out: (E_local, D, F)/(E_local, F, D).
@@ -123,9 +161,7 @@ def _moe_body(x, gate_w, w_in, w_out, *, n_experts: int, capacity: int,
     # destined for j's local experts.  Leading axis E = p * E_local in
     # global-expert order; tiled exchange splits it and stacks received
     # pieces in source order: recv[i] = device i's buckets for my experts.
-    buckets = buckets.reshape(p, E_local * capacity, D)
-    recv = lax.all_to_all(buckets, axis, split_axis=0, concat_axis=0,
-                          tiled=True)
+    recv = exchange(buckets.reshape(p, E_local * capacity, D), axis)
     recv = recv.reshape(p, E_local, capacity, D)
     recv = jnp.moveaxis(recv, 0, 1).reshape(E_local, p * capacity, D)
 
@@ -136,8 +172,7 @@ def _moe_body(x, gate_w, w_in, w_out, *, n_experts: int, capacity: int,
     # --- inverse all_to_all: return outputs to token-home devices ---
     out = out.reshape(E_local, p, capacity, D)
     out = jnp.moveaxis(out, 1, 0).reshape(p, E_local * capacity, D)
-    back = lax.all_to_all(out, axis, split_axis=0, concat_axis=0, tiled=True)
-    back = back.reshape(n_experts * capacity, D)
+    back = exchange(out, axis).reshape(n_experts * capacity, D)
 
     # --- un-bucket: gather each unit's slot, combine weighted choices ---
     yu = back[slot_idx]                                            # (k*T, D)
