@@ -52,6 +52,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple, Union
 
 import numpy as np
@@ -1443,86 +1444,145 @@ _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
 def ep_pass_rows(cfg: Config, n_tokens: int, ep: int) -> int:
-    """Rows a peer a pass of :func:`_ep_experts` for a rank's ``n_tokens``
-    tokens on ``ep`` ranks: the rows uniform routing sends one rank's experts
-    from one rank's tokens (k * T / ep), in whole tiles of 16, and never more
-    than the units one rank's experts can be sent (a token meets an expert
-    once).  It decides time and memory, never the result: a pair of ranks
-    with more units takes another pass.  A pass costs what its buffers hold,
-    filled or not (the all-to-all and the gathers move ep times its rows), so
-    a step's time moves in whole passes.  The fullest pair is always above
-    the share and at seeded weights under twice it, so at the share itself
-    every layer takes two passes and a step's time is steady.  Two other
-    sizes were read and none between one and two shares (PERF.md sections 6
-    and 7, PR 44, Mellum2's layer: 131,072 routed units a rank, 2304 wide):
-    at half the share a layer takes three or four passes as its routing
-    falls, a batch with one pass more ran 5% longer and the compiler's plan
-    was 1.5 GB smaller; a size that one pass covers has to pass the fullest
-    pair of every layer of every step, or that step takes two passes of the
-    larger buffers."""
+    """Rows a peer of the FIRST pass of :func:`_ep_experts` for a rank's
+    ``n_tokens`` tokens on ``ep`` ranks: the rows uniform routing sends one
+    rank's experts from one rank's tokens (k * T / ep), in whole tiles of 16,
+    and never more than the units one rank's experts can be sent (a token
+    meets an expert once).  The units of a pair of ranks that it does not
+    hold go in overflow passes of :func:`ep_overflow_rows` rows a peer.  The
+    two sizes decide time and memory, never the result: a pair of ranks with
+    more units takes another overflow pass.  A pass costs what its buffers
+    hold, filled or not (the all-to-all and the gathers move ep times its
+    rows), so a step's time moves in whole overflow passes.  The fullest pair
+    is always above the share (at seeded weights 1.11 to 1.16 of it in the
+    Mellum2 cell, one overflow pass a layer), so a first pass of the share is
+    full on the fullest pair and about 0.8 full on the mean one, and what is
+    left is a small multiple of a quarter.
+    What was read on the way here (PERF.md section 6, PRs 44 to 46, Mellum2's
+    layer: 131,072 routed units a rank, 2304 wide): every pass the share,
+    two a layer, moved 2.0 shares where the routing needs 1.1; every pass half
+    the share, three or four a layer as the routing falls, a batch with one
+    pass more 5% longer; a first pass that covers the fullest pair has to
+    pass it in every layer of every step, or that step takes a second pass of
+    the larger buffers."""
     k = cfg.expert_top_k
     most = min(k, cfg.n_experts // ep) * n_tokens
     return -(-min(-(-k * n_tokens // ep), most) // 16) * 16
 
 
-def _ep_pass(k, R, n_tokens, order, first, sent, p):
-    """Pass ``p`` of :func:`_ep_experts` on the sending side: for each rank,
-    rows ``p * R`` to ``(p + 1) * R`` of the units that go to its experts, in
-    sorted order.  Returns ``(token, unit)``, (ranks, R) each: ``n_tokens``
-    and ``n_tokens * k``, which no gather or scatter reaches, where a row is
-    past the units that rank is sent."""
-    at = p * R + jnp.arange(R)
+def ep_overflow_rows(cfg: Config, n_tokens: int, ep: int) -> int:
+    """Rows a peer of each pass of :func:`_ep_experts` after the first: a
+    quarter of :func:`ep_pass_rows`, in whole tiles of 16 (Mellum2's layer:
+    8,192 rows a peer, 512 a held expert on average, one row tile of the
+    grouped matmul)."""
+    return -(-ep_pass_rows(cfg, n_tokens, ep) // 64) * 16
+
+
+def _ep_pass(k, R, n_tokens, order, first, sent, lo):
+    """One pass of :func:`_ep_experts` on the sending side: for each rank,
+    rows ``lo`` to ``lo + R`` of the units that go to its experts, in sorted
+    order.  Returns ``(token, unit)``, (ranks, R) each: ``n_tokens`` and
+    ``n_tokens * k``, which no gather or scatter reaches, where a row is past
+    the units that rank is sent."""
+    at = lo + jnp.arange(R)
     valid = at < jnp.sum(sent, axis=1)[:, None]
     unit = jnp.where(valid, order.at[first[:, None] + at].get(mode="clip"),
                      n_tokens * k)
     return jnp.where(valid, unit // k, n_tokens), unit
 
 
-def _ep_arrived(R, arrived, p):
-    """Pass ``p`` on the receiving side, from the units ``arrived`` (ranks,
-    experts held) each rank sends in all: the valid rows of each rank's block
-    as a column (ranks, R, 1), and each held expert's rows in it."""
+def _ep_arrived(R, arrived, lo):
+    """Rows ``lo`` to ``lo + R`` on the receiving side, from the units
+    ``arrived`` (ranks, experts held) each rank sends in all: the valid rows
+    of each rank's block as a column (ranks, R, 1), and each held expert's
+    rows in it."""
     ends = jnp.cumsum(arrived, axis=1)
-    lo = p * R
     kept = jnp.clip(ends - lo, 0, R) - jnp.clip(ends - arrived - lo, 0, R)
     return (jnp.arange(R) < jnp.sum(kept, axis=1)[:, None])[..., None], kept
 
 
+def _ep_blocks(R, B, arrived, lo):
+    """:func:`_ep_arrived` of a pass of ``R`` rows from row ``lo``, cut into
+    blocks of ``B`` rows for the experts: ``(valid, kept)``, (ranks * R / B,
+    B, 1) and (ranks * R / B, experts held), a rank's blocks one after the
+    other as its rows lie."""
+    valid, kept = jax.vmap(lambda at: _ep_arrived(B, arrived, at),
+                           out_axes=1)(lo + B * jnp.arange(R // B))
+    return valid.reshape(-1, B, 1), kept.reshape(-1, kept.shape[-1])
+
+
+def _ep_passes(rows, passes, one_pass, carry):
+    """``carry`` through the passes of :func:`_ep_experts`, forward or
+    backward: ``one_pass(R, lo, carry)`` once at ``rows[0]`` rows a peer from
+    row 0, then at ``rows[1]`` from where the pass before ended, ``passes``
+    times in all.  Two loops, the first of at most one pass: the bodies of a
+    program's ``while``s share the lowering of what both call (the grouped
+    matmuls, eight functions for fifteen), which a ``cond``'s branch and a
+    ``while``'s body do not."""
+    share, overflow = rows
+    carry = lax.fori_loop(0, jnp.minimum(passes, 1),
+                          lambda _, carry: one_pass(share, 0, carry), carry)
+    return lax.fori_loop(
+        1, passes, lambda p, carry: one_pass(
+            overflow, share + (p - 1) * overflow, carry), carry)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
-def _ep_experts(k, R, kernel, axis, xt, wflat, order, plan, w):
+def _ep_experts(k, rows, kernel, axis, xt, wflat, order, plan, w):
     """The routed experts, sharded over the mesh axis ``axis``, on this
     rank's tokens ``xt`` (T, D) -> (T, D), inside a ``shard_map``: the sum,
     for each token, of its k units, each unit its expert's SwiGLU times the
     router's weight ``wflat`` (T * k, float32), whichever rank holds the
     expert; and with it ``delivered`` (ranks,) int32, the rows of each rank
     that the passes carried here and ran.  ``order`` lists this rank's units
-    by expert (so by rank: rank r holds experts r * E / ep and on), ``plan``
-    is ``parallel.moe.pass_plan`` of their counts at ``R`` rows a pass, ``w``
-    is ``(w_gate, w_up, w_down)`` of the experts held here.
+    by expert (so by rank: rank r holds experts r * E / ep and on), ``rows``
+    is the pair of a pass's rows a peer, the first pass's and each later
+    one's (:func:`ep_pass_rows`, :func:`ep_overflow_rows`), ``plan`` is
+    ``parallel.moe.pass_plan`` of the units' counts at ``rows``, ``w`` is
+    ``(w_gate, w_up, w_down)`` of the experts held here.
 
-    A pass gathers ``R`` rows for each rank (:func:`ep_pass_rows`; tokens'
-    rows and router weights), sends each block to its rank and receives one
-    from each (``parallel.moe.exchange``: one all-to-all of (ranks, R, D)),
-    runs the held experts on each block (:func:`_held_swiglu`: a block's rows
-    lie by expert, ``arrived`` says how many each), sends the results back
-    the same way and adds them to their tokens in float32.  As many passes
-    run as the fullest pair of ranks needs, the same number on every rank
-    (``parallel.moe.pass_plan``): the shapes are static, memory is a pass's
-    and no unit is dropped under any imbalance.  A loop whose length the data
-    decides has no transpose, so the gradient is written out
-    (:func:`_ep_experts_bwd`): it keeps the inputs alone and takes the same
-    passes, the rows and the results' cotangents out, the rows' and the router
-    weights' cotangents back, each block through :func:`_held_swiglu`'s own
-    VJP, the weights' gradients summed over blocks and passes in float32 and
-    left on the rank that holds the experts.
+    A pass gathers its rows for each rank (tokens' rows and router weights),
+    sends each block to its rank and receives one from each
+    (``parallel.moe.exchange``: one all-to-all of (ranks, R, D)), runs the
+    held experts on what arrived (:func:`_held_swiglu`: a source's rows lie
+    by expert, ``arrived`` says how many each), sends the results back the
+    same way and adds them to their tokens in float32.  The first pass takes
+    the uniform share from every pair of ranks; what a pair has above it goes
+    in overflow passes a quarter that size, as many as the fullest pair of
+    ranks needs, the same number on every rank (``parallel.moe.pass_plan``;
+    :func:`_ep_passes`): the shapes are static, memory is a first pass's, the
+    rows moved are the share and the overflow in whole quarters, and no unit
+    is dropped under any imbalance.  The experts take either pass's rows in
+    blocks of the two sizes' greatest common divisor, the overflow's wherever
+    a quarter of the share is whole tiles (:func:`_ep_blocks`; the row tiles
+    a grouped matmul visits are the same), so a program holds ONE set of
+    grouped-matmul shapes.  A loop whose length the data decides has
+    no transpose, so the gradient is written out (:func:`_ep_experts_bwd`): it
+    keeps the inputs alone and takes the same passes, the rows and the
+    results' cotangents out, the rows' and the router weights' cotangents
+    back, each block through :func:`_held_swiglu`'s own VJP, the weights'
+    gradients summed over blocks and passes in float32 and left on the rank
+    that holds the experts.
+
+    The forward and the backward pass are each a ``jax.jit`` of their own
+    (``k``, ``rows``, ``kernel`` and ``axis`` static): every expert layer of
+    a stack has the same shapes, so a program traces and lowers the four
+    bodies (forward and backward, the share's and the overflow's) once and
+    every layer, and every replay of one under ``remat``, calls them
+    (``tests/test_mellum2.py::test_a_body_is_traced_once_a_shape``).  Staged
+    once a layer, the second size cost 9.6 s of set-up in the Mellum2 cell,
+    more than its bound (PERF.md section 6, PRs 45 and 46).  What they call
+    is read when they are traced: a test that patches :func:`_ep_pass` or
+    :func:`_ep_arrived` clears JAX's caches round its patch.
 
     ``delivered`` is counted where the rows move, not read from the plan: a
     sender counts the rows of each block that its gather filled, a receiver
-    the rows of each block that its experts ran, both over the passes that
-    ran; the senders' counts follow their rows (one small exchange after the
-    loop) and a block delivered the lesser of the two.  With too few passes,
-    or a mask on either side that leaves rows out, ``delivered`` falls short
-    of the routers' counts: that is how a caller sees a dropped unit.
+    the rows of each block that its experts ran, both over the passes of
+    both sizes that ran; the senders' counts follow their rows (one small
+    exchange after the loop) and a block delivered the lesser of the two.
+    With too few passes, or a mask on either side that leaves rows out,
+    ``delivered`` falls short of the routers' counts: that is how a caller
+    sees a dropped unit.
 
     :func:`_held_experts` is the same passes without an exchange, and the two
     share what a pass computes (:func:`_held_swiglu`, :func:`_grouped_matmul`)
@@ -1535,48 +1595,52 @@ def _ep_experts(k, R, kernel, axis, xt, wflat, order, plan, w):
     hold a share (``experts_held``), which this form's arrival left to the
     byte: ROADMAP.md R6 has it as a change of its own, measured in those
     cells."""
-    return _ep_experts_fwd(k, R, kernel, axis, xt, wflat, order, plan, w)[0]
+    return _ep_experts_fwd(k, rows, kernel, axis, xt, wflat, order, plan, w)[0]
 
 
-def _ep_experts_fwd(k, R, kernel, axis, xt, wflat, order, plan, w):
-    T = xt.shape[0]
-    sent, arrived, first, passes = plan
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
+def _ep_experts_fwd(k, rows, kernel, axis, xt, wflat, order, plan, w):
+    (T, D), B = xt.shape, math.gcd(*rows)
+    sent, arrived, first, passes = plan[:4]
 
-    def one_pass(p, carry):
+    def one_pass(R, lo, carry):
         y, filled, ran = carry
         with jax.named_scope("moe.dispatch"):
-            token, unit = _ep_pass(k, R, T, order, first, sent, p)
+            token, unit = _ep_pass(k, R, T, order, first, sent, lo)
             xs = xt.at[token].get(mode="fill", fill_value=0)
             ws = wflat.at[unit].get(mode="fill", fill_value=0)
         xs, ws = _exchange(xs, axis), _exchange(ws, axis)
-        rows, kept = _ep_arrived(R, arrived, p)
+        valid, kept = _ep_blocks(R, B, arrived, lo)
         with jax.named_scope("moe.experts"):
             ys = lax.map(lambda block: _held_swiglu(kernel, *block, *w),
-                         (rows, kept, xs, ws[..., None]))
-        ys = _exchange(ys, axis)
+                         (valid, kept, xs.reshape(-1, B, D),
+                          ws.reshape(-1, B, 1)))
+        ys = _exchange(ys.reshape(xs.shape), axis)
         with jax.named_scope("moe.combine"):
             return (y.at[token].add(ys.astype(jnp.float32), mode="drop"),
                     filled + jnp.sum(token < T, axis=1, dtype=jnp.int32),
-                    ran + jnp.sum(rows[..., 0], axis=1, dtype=jnp.int32))
+                    ran + jnp.sum(valid.reshape(-1, R), axis=1,
+                                  dtype=jnp.int32))
 
     none = jnp.zeros(sent.shape[:1], jnp.int32)
-    y, filled, ran = lax.fori_loop(
-        0, passes, one_pass, (jnp.zeros(xt.shape, jnp.float32), none, none))
+    y, filled, ran = _ep_passes(
+        rows, passes, one_pass, (jnp.zeros(xt.shape, jnp.float32), none, none))
     delivered = jnp.minimum(_exchange(filled, axis), ran)
     return (y.astype(xt.dtype), delivered), (xt, wflat, order, plan, w)
 
 
-def _ep_experts_bwd(k, R, kernel, axis, saved, given):
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
+def _ep_experts_bwd(k, rows, kernel, axis, saved, given):
     dy, _ = given
     xt, wflat, order, plan, w = saved
-    T = xt.shape[0]
+    (T, D), B = xt.shape, math.gcd(*rows)
     f32 = lambda a: a.astype(jnp.float32)
-    sent, arrived, first, passes = plan
+    sent, arrived, first, passes = plan[:4]
 
-    def one_pass(p, grads):
+    def one_pass(R, lo, grads):
         dxt, dwflat, dw = grads
         with jax.named_scope("moe.dispatch"):
-            token, unit = _ep_pass(k, R, T, order, first, sent, p)
+            token, unit = _ep_pass(k, R, T, order, first, sent, lo)
             xs = xt.at[token].get(mode="fill", fill_value=0)
             ws = wflat.at[unit].get(mode="fill", fill_value=0)
         with jax.named_scope("moe.combine"):
@@ -1584,23 +1648,25 @@ def _ep_experts_bwd(k, R, kernel, axis, saved, given):
         xs, ws, dys = (_exchange(a, axis) for a in (xs, ws, dys))
 
         def block(dw, given):
-            rows, kept, xs, ws, dys = given
+            valid, kept, xs, ws, dys = given
             dxs, dws, *dwp = jax.vjp(functools.partial(
-                _held_swiglu, kernel, rows, kept), xs, ws, *w)[1](dys)
+                _held_swiglu, kernel, valid, kept), xs, ws, *w)[1](dys)
             return tuple(a + f32(b) for a, b in zip(dw, dwp)), (dxs, dws)
 
         with jax.named_scope("moe.experts"):
             dw, (dxs, dws) = lax.scan(
-                block, dw, (*_ep_arrived(R, arrived, p), xs, ws[..., None],
-                            dys))
-        dxs, dws = _exchange(dxs, axis), _exchange(dws[..., 0], axis)
+                block, dw, (*_ep_blocks(R, B, arrived, lo),
+                            xs.reshape(-1, B, D), ws.reshape(-1, B, 1),
+                            dys.reshape(-1, B, D)))
+        dxs = _exchange(dxs.reshape(xs.shape), axis)
+        dws = _exchange(dws.reshape(ws.shape), axis)
         with jax.named_scope("moe.dispatch"):
             return (dxt.at[token].add(f32(dxs), mode="drop"),
                     dwflat.at[unit].add(dws, mode="drop"), dw)
 
     zeros = lambda a: jnp.zeros(a.shape, jnp.float32)
-    dxt, dwflat, dw = lax.fori_loop(
-        0, passes, one_pass,
+    dxt, dwflat, dw = _ep_passes(
+        rows, passes, one_pass,
         (zeros(xt), zeros(wflat), tuple(zeros(a) for a in w)))
     none = lambda a: np.zeros(a.shape, jax.dtypes.float0)
     return (dxt.astype(xt.dtype), dwflat.astype(wflat.dtype), none(order),
@@ -1653,9 +1719,9 @@ def _moe_ffn_ep(cfg: Config, lp: Params, x: jax.Array, mesh: Mesh):
                                                        shared)
         with jax.named_scope("moe.dispatch"):
             order = jnp.argsort(expert.reshape(T * k), stable=True)
-        R = ep_pass_rows(cfg, T, ep)
-        plan = _pass_plan(units, R, AXIS_EP)
-        y, delivered = _ep_experts(k, R, kernel, AXIS_EP, xt,
+        sizes = ep_pass_rows(cfg, T, ep), ep_overflow_rows(cfg, T, ep)
+        plan = _pass_plan(units, sizes, AXIS_EP)
+        y, delivered = _ep_experts(k, sizes, kernel, AXIS_EP, xt,
                                    weight.reshape(T * k), order, plan, w)
         if len(shared) > 1:
             delivered = lax.psum(delivered, tuple(

@@ -23,8 +23,9 @@ the one all-to-all written here.  This module's capacity-bucket layer is the
 building block for a program that wants static buckets and accepts drops; no
 model of ``models/`` uses it.  ``models.llama._moe_ffn_sorted`` is the
 dropless one a model trains with: its units are sorted by destination rank
-and expert and sent a fixed pass of rows a peer at a time, as many passes as
-arrived, so its shapes are as static as the buckets' and nothing is dropped
+and expert and sent a fixed pass of rows a peer at a time (the uniform share
+first, then the overflow in smaller passes), as many passes as arrived, so
+its shapes are as static as the buckets' and nothing is dropped
 (:func:`pass_plan` counts what a rank sends and receives).
 """
 
@@ -110,22 +111,26 @@ def exchange(blocks: jax.Array, axis: str) -> jax.Array:
                               tiled=True)
 
 
-def pass_plan(units: jax.Array, rows: int, axis: str):
+def pass_plan(units: jax.Array, rows, axis: str):
     """What a rank of ``axis`` sends and receives when its routed units,
     sorted by expert (so by the rank that holds the expert, ranks holding
-    equal contiguous shares), go out ``rows`` a peer a pass.  ``units`` (E,)
+    equal contiguous shares), go out in passes of ``rows`` a peer: ``rows[0]``
+    in the first pass and ``rows[1]`` in each pass after it.  ``units`` (E,)
     int32 counts this rank's units by expert.  Returns ``(sent, arrived,
-    first, passes)``: ``sent`` (p, E / p) the units for each rank's experts,
-    ``arrived`` (p, E / p) those each rank sends for the experts held here
-    (one small :func:`exchange`), ``first`` (p,) where each rank's units begin
-    in the sorted order, and ``passes``, the same on every rank: as many as
-    the fullest pair of ranks needs, so no unit is left behind."""
+    first, passes, overflow)``: ``sent`` (p, E / p) the units for each rank's
+    experts, ``arrived`` (p, E / p) those each rank sends for the experts held
+    here (one small :func:`exchange`), ``first`` (p,) where each rank's units
+    begin in the sorted order, ``passes``, the same on every rank: as many as
+    the fullest pair of ranks needs, so no unit is left behind, and
+    ``overflow``, those of them after the first."""
     p = lax.psum(1, axis)
     sent = units.reshape(p, -1)
     arrived = exchange(sent, axis)
     total = jnp.sum(sent, axis=1)
-    passes = -(-lax.pmax(jnp.max(total), axis) // rows)
-    return sent, arrived, jnp.cumsum(total) - total, passes
+    most = lax.pmax(jnp.max(total), axis)
+    overflow = -(-jnp.maximum(most - rows[0], 0) // rows[1])
+    return (sent, arrived, jnp.cumsum(total) - total,
+            jnp.minimum(most, 1) + overflow, overflow)
 
 
 def _moe_body(x, gate_w, w_in, w_out, *, n_experts: int, capacity: int,
