@@ -74,15 +74,19 @@ def main():
             meter.add(loss)
         print(f"epoch {epoch}: loss {meter.mean:.4f}")
 
-    accs = []
-    for xb, yb in ShardedIterator(ds, global_batch=args.batch, num_shards=1,
-                                  shuffle=False):
-        x, y = jnp.asarray(xb[0]), jnp.asarray(yb[0])
+    @jax.jit
+    def accuracy(params, x, y):
         emb, body_p, hd = params
         h = x.reshape(x.shape[0], -1) @ emb["w"] + emb["b"]
         h = pl.unmicrobatch(pipe(body_p, pl.microbatch(h, M)))
         pred = jnp.argmax(h @ hd["w"] + hd["b"], axis=-1)
-        accs.append(float(jnp.mean(pred == y)))
+        return jnp.mean(pred == y)
+
+    accs = []
+    for xb, yb in ShardedIterator(ds, global_batch=args.batch, num_shards=1,
+                                  shuffle=False):
+        accs.append(float(accuracy(params, jnp.asarray(xb[0]),
+                                   jnp.asarray(yb[0]))))
     print(f"final accuracy {100 * np.mean(accs):.2f}%")
     mpi.stop()
 

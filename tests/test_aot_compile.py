@@ -6,11 +6,16 @@ have to partition — so these guard every later PR at no chip time.  A
 compile that passes is not a chip run: nothing here says a result is right
 or fast.
 
+The benchmark's steps of six model families at their published widths, the
+long compiles, are in ``tests/test_aot_steps_*.py``, three a file: the driver
+hands a worker a FILE at a time, and a step holds four to five cores for
+minutes while the chip's compiler works, so they are queued last
+(``tests/conftest.py``) and fill the cores the run's last workers leave.
+
 Refusals are pinned as they are.  The day someone re-tiles the ring kernel
 the ``pytest.raises`` below fails, and tells them to move the bound.
 """
 
-import dataclasses
 import re
 
 import pytest
@@ -248,488 +253,6 @@ def test_ring_allreduce_vmem_bound_pinned(v5e):
         _ring_allreduce(1 << 22)
 
 
-def test_llama_flash_step_on_dp_tp(v5e, monkeypatch):
-    """``make_train_step(attn="flash")`` on dp=2 x tp=2: the Mosaic kernel
-    must reach the compiler inside a shard_map (under GSPMD it is refused:
-    "Mosaic kernels cannot be automatically partitioned").  The step reads
-    the running backend to choose interpret mode, so the test answers for
-    it; no option of the program does."""
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    fn, args = topology._build_llama_dp_tp("v5e-4", attn="flash")
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert _kernels(compiled) > 0
-    # `tiny`'s 4 heads over 2 K/V heads, split over tp at BOTH counts: a
-    # device's kernels take its 2 query heads and its 1 K/V head, which the
-    # two share by the kernels' index maps, and `flash_bwd` gives dk and dv
-    # at that one head (no repeat stands in the shard_map's body).
-    calls = [line for line in compiled.as_text().splitlines()
-             if 'custom_call_target="tpu_custom_call"' in line
-             and "flash_" in line]
-    heads = lambda line: [int(n) for n in re.findall(
-        r"(?:bf16|f32)\[(\d+),\d+,16\]",
-        line.split("operand_layout_constraints")[1])]
-    assert calls and all(heads(line)[:3] == [2 * heads(line)[1],
-                                            heads(line)[1], heads(line)[2]]
-                         for line in calls)
-    # The chunked head under GSPMD (head columns over tp, rows over dp): the
-    # program with a checkpointed chunk held 11 all-reduces of 255,364 bytes,
-    # four in a chunk (max and target logit, then max and sum again in the
-    # replay) and dh's after the scan.  Now three in a chunk (max, sum, and
-    # the target logit with the chunk's dh: the same bytes a step), and dW is
-    # still summed over dp once, after the scan, with the other gradients.
-    # The text counts an instruction once: `tiny`'s two layers are inlined,
-    # so each layer's four activation all-reduces over tp (16,384 bytes,
-    # attention and FFN, forward and backward) stands there itself, where a
-    # scan's body stood once whatever its trips (9 instructions, 246,916
-    # bytes), and the layers' dp sums ride with the embedding's.
-    text = compiled.as_text()
-    stats = topology.hlo_collective_stats(text)
-    assert set(stats["counts"]) == {"all-reduce:f32"}
-    assert stats["total"] <= 12
-    assert sum(stats["operand_bytes"].values()) <= 386_692
-    in_chunk = [line for line in text.splitlines()
-                if " all-reduce(" in line and "head_loss" in line]
-    assert len(in_chunk) == 3 and all("while/body" in line for line in in_chunk)
-
-
-def test_olmoe_adamw_step_at_published_widths(v5e, monkeypatch):
-    """The benchmark's `olmoe-1b-7b-l4096` step on one chip: OLMoE-1B-7B at
-    its published widths, 2 of 16 layers, inlined (`llama.apply` scans no
-    stack this shallow), 4 x 4096 tokens, flash, remat "dots", AdamW with
-    bfloat16 moments, weights and state donated.  It fits the chip, and
-    holds, for each layer, the two flash kernels and the grouped matmuls of
-    the sorted dispatch: `gmm` for gate, up and down forward and the three
-    gradients of the rows (6: the 9 products a layer requires and none again,
-    remat "dots" keeping the gate and up products by their names and nothing
-    reading the down product's), `tgmm` for the three gradients of the
-    weights.  What the scan cost is not there: no
-    layer's expert weights copied out of the stack by a `dynamic-slice`, no
-    gradient written into it by a `dynamic-update-slice` (22.4 ms of a
-    316.65 ms step and 3.65 GB of the plan: PERF_LEDGER.jsonl, PR 28)."""
-    import dataclasses
-    import re
-
-    import optax
-    from jax.sharding import Mesh
-
-    from torchmpi_tpu.models import llama
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    cfg = dataclasses.replace(llama.olmoe_1b_7b(), n_layers=2)
-    assert cfg.n_layers <= llama._INLINE_MAX_LAYERS
-    one = SingleDeviceSharding(v5e[0])
-    place = lambda tree: jax.tree.map(
-        lambda a: _sds(a.shape, a.dtype, one), tree)
-    params = jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0), cfg,
-                                               dtype=jnp.bfloat16))
-    assert sum(a.size for a in jax.tree.leaves(params)) == 1_045_186_560
-    optimizer = optax.adamw(4e-4, b1=0.9, b2=0.95, weight_decay=0.1)
-    state = jax.eval_shape(optimizer.init, jax.tree.map(
-        lambda a: _sds(a.shape, jnp.bfloat16, one), params))
-    mesh = Mesh([v5e[0]], ("dp",))
-    step = llama.make_train_step(cfg, mesh, attn="flash", optimizer=optimizer,
-                                 remat="dots", loss_chunk=512)
-    tokens = _sds((4, 4096), jnp.int32, one)
-    compiled = step.lower(place(params), place(state), tokens, tokens).compile()
-    text = compiled.as_text().splitlines()
-    kernels = [line for line in text
-               if 'custom_call_target="tpu_custom_call"' in line]
-    # (inlined, a forward kernel's scope reads `jvp(moe.experts)/jit(gmm)`)
-    named = lambda what: sum(bool(re.search(what, line)) for line in kernels)
-    assert (named("flash_fwd"), named("flash_bwd")) == (2, 2)
-    assert named(r"moe\.experts\)?/jit\(gmm\)") == 12
-    assert named(r"moe\.experts\)?/jit\(tgmm\)") == 6
-    assert len(kernels) == 22
-    assert not named(r"rematted_computation.*jit\(t?gmm\)")
-    # No slice of the stacked expert weights, (2, 64, 2048, 1024) and its
-    # transpose, cut or written at an index the program computes.
-    expert = re.compile(r"bf16\[(2,)?64,(2048,1024|1024,2048)\]")
-    assert not [line for line in text
-                if re.search(r"dynamic-(update-)?slice", line)
-                and expert.search(line)]
-    # The head: three products over the vocabulary in one scan body (logits,
-    # dh, dW), and no replay of `h_c @ head` in a backward scan.
-    head = [line for line in text
-            if "head_loss" in line and " convolution(" in line]
-    assert len(head) == 3 and not any("rematted" in line for line in head)
-    assert all("jvp(head_loss)/while/body" in line for line in head)
-    assert sum('head_loss)/while"' in line and " while(" in line
-               for line in text) == 1
-    assert sum("bf16[4,512,50304]" in line.split(" convolution(")[0]
-               for line in head) == 1
-    m = compiled.memory_analysis()
-    held = (m.argument_size_in_bytes + m.output_size_in_bytes
-            - m.alias_size_in_bytes + m.temp_size_in_bytes)
-    # weights and both moments donated: all but the tokens and a few norms
-    assert m.argument_size_in_bytes - m.alias_size_in_bytes < 1e6
-    assert m.alias_size_in_bytes > 3 * 2 * 1_045_000_000
-    # The plan: 12.04 GB, 6.27 of weights and moments (3 x 2 bytes x 1.045 G)
-    # and 5.77 of temporaries.  The gate and up products kept for the backward
-    # pass are 2 layers x 2 x (8 x 16,384 rows) x 1024 x 2 bytes = 1.07 GB,
-    # yet the plan that replayed them held 11.83 (temporaries 5.56): its peak
-    # lies in the last layer's backward pass, where the replayed pair stood
-    # too, so only the first layer's pair, less what it displaces, is new.
-    assert 9e9 < held < 12.5e9
-
-
-def test_olmoe_step_on_dp_tp_takes_the_compilers_grouped_matmul(monkeypatch):
-    """On more than one device the sorted dispatch leaves the grouped matmul
-    to `lax.ragged_dot`, which the compiler partitions under GSPMD (its own
-    Mosaic kernel, named `ragged-dot-none`); megablox's, a Mosaic kernel of
-    ours, it would refuse to.  One layer at published widths on dp=2 x tp=2:
-    the 9 products the layer requires, the gate and up products kept through
-    remat "dots" by the names they carry in this form too."""
-    import dataclasses
-
-    from torchmpi_tpu.models import llama
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    cfg = dataclasses.replace(llama.olmoe_1b_7b(), n_layers=1)
-    mesh = topology.topology_mesh("v5e-4", {"dp": 2, "tp": 2})
-    args = topology._llama_arg_structs(cfg, mesh, llama.param_specs, 4, 4096)
-    step = llama.make_train_step(cfg, mesh, attn="flash", remat="dots",
-                                 loss_chunk=512)
-    text = jax.jit(lambda p, t, y: step(p, None, t, y)).lower(
-        *args).compile().as_text()
-    assert text.count('op_name="ragged-dot-none"') == 9
-    assert "jit(gmm)" not in text
-
-
-def test_kimi_linear_step_on_dp_tp_runs_each_devices_kernels(monkeypatch):
-    """On more than one device a KDA layer between its projections (the way
-    in, the recurrence, the way out) runs in ONE ``shard_map`` over the batch
-    and the heads (``llama._kda_sharded``), as flash does: its kernels are
-    Mosaic's, and the compiler refuses to partition one
-    (``NotImplementedError: Mosaic kernels cannot be automatically
-    partitioned``; bare under GSPMD this step does not lower).  Kimi Linear's
-    first four layers at published widths (KDA, KDA, KDA, MLA; a share of the
-    experts) on dp=2 x tp=2: each device runs ``kda_fwd`` and ``kda_bwd``
-    once a KDA layer on its own row of the batch and its 16 of 32 heads,
-    beside them ``kda_pre`` and ``kda_post`` twice (``"full"`` forms them
-    again from their inputs) and ``kda_pre_bwd`` and ``kda_post_bwd`` once,
-    and the latent layer's two flash kernels on its 16 heads."""
-    import dataclasses
-
-    from torchmpi_tpu.models import llama
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    published = llama.kimi_linear_48b_a3b()
-    cfg = dataclasses.replace(
-        published, n_layers=4, layer_kinds=published.layer_kinds[:4],
-        experts_held=(0, 8), vocab=20480)
-    assert [m for m, _ in cfg.layer_kinds] == ["kda", "kda", "kda", "mla"]
-    mesh = topology.topology_mesh("v5e-4", {"dp": 2, "tp": 2})
-    args = topology._llama_arg_structs(cfg, mesh, llama.param_specs, 2, 4096)
-
-    def lowered():
-        step = llama.make_train_step(cfg, mesh, attn="flash", remat="full",
-                                     loss_chunk=512)
-        return jax.jit(lambda p, t, y: step(p, None, t, y)).lower(*args)
-
-    text = lowered().compile().as_text()
-    kernels = [line for line in text.splitlines()
-               if 'custom_call_target="tpu_custom_call"' in line]
-    named = lambda what: sum(bool(re.search(what, line)) for line in kernels)
-    assert (named("kda_fwd"), named("kda_bwd")) == (3, 3)
-    assert (named(r"kda_pre(?!_bwd)"), named(r"kda_post(?!_bwd)")) == (6, 6)
-    assert (named("kda_pre_bwd"), named("kda_post_bwd")) == (3, 3)
-    assert (named("flash_fwd"), named("flash_bwd")) == (1, 1)
-    assert "jit(gmm)" not in text
-    # a device's o and states: its one row, 4096 tokens in 64 chunks, 16 heads
-    fwd = next(line for line in kernels if "kda_fwd" in line)
-    assert "[1,4096,2048]" in fwd and "[64,1,16,128,128]" in fwd
-    # the way in's four results on the same rows and heads
-    pre = next(line for line in kernels if "kda_pre" in line
-               and "kda_pre_bwd" not in line)
-    assert pre.split(" custom-call(")[0].count("[1,4096,2048]") == 4
-    import functools
-
-    from torchmpi_tpu.ops.kda_mixer import kda_mixer
-    monkeypatch.setattr(llama, "_kda_sharded", lambda mesh, heads, eps:
-                        functools.partial(kda_mixer, eps=eps))
-    with pytest.raises(NotImplementedError, match="automatically partitioned"):
-        lowered()
-
-
-def test_ouro_adamw_step_at_published_widths(v5e, monkeypatch):
-    """The benchmark's `ouro-2.6b-b2-l4096` step on one chip: Ouro-2.6B at
-    its published widths, 8 of 48 layers run four times (32 layer
-    applications: the layer scan inside, the recurrent steps inlined round
-    it), 2 x 4096 tokens, flash, the configuration file's remat (three steps
-    `"full"`, one `"dots"`), the four heads through one chunked call, AdamW
-    with float32 moments, weights and state donated.  `benchmark/sizing.py`
-    knows no function for this runner, so this is the cell's plan: 16.80 GB
-    under the chip's 15.75 GiB (16.91 GB), where the plan that replayed the
-    forward kernels held 15.07 (15.16 with no barrier round the scanned
-    layers' checkpoints).  The o and lse of the 24 layer applications under
-    `"full"` are 24 x (33.6 + 0.5) MB = 0.82 GB, and the compiler's own peak
-    (`peak_memory_in_bytes`) rose by just that, 12.60 to 13.42 GB; arguments
-    plus temporaries, the sum the cell reports, count it twice.  `"dots"` at
-    every step is refused."""
-    import dataclasses
-    import json
-    import os
-
-    import optax
-    from jax.sharding import Mesh
-
-    from torchmpi_tpu.models import llama
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmark", "configs", "ouro-2.6b.json")) as fh:
-        run = json.load(fh)["run"]
-    cfg = dataclasses.replace(llama.ouro_2_6b(), n_layers=8)
-    assert cfg.n_layers > llama._INLINE_MAX_LAYERS and cfg.ut_steps == 4
-    one = SingleDeviceSharding(v5e[0])
-    place = lambda tree: jax.tree.map(
-        lambda a: _sds(a.shape, a.dtype, one), tree)
-    params = jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0), cfg,
-                                               dtype=jnp.bfloat16))
-    assert sum(a.size for a in jax.tree.leaves(params)) == 612_438_017
-    adamw = optax.adamw(3e-4, b1=0.9, b2=0.95, weight_decay=0.1)
-    f32 = lambda tree: jax.tree.map(lambda a: a.astype(jnp.float32), tree)
-
-    def update(grads, state, params):       # moments float32, as the runner
-        updates, state = adamw.update(f32(grads), state, f32(params))
-        return jax.tree.map(lambda u, p: u.astype(p.dtype), updates,
-                            params), state
-
-    optimizer = optax.GradientTransformation(lambda p: adamw.init(f32(p)),
-                                             update)
-    state = jax.eval_shape(optimizer.init, params)
-    mesh = Mesh([v5e[0]], ("dp",))
-    tokens = _sds((2, 4096), jnp.int32, one)
-
-    def compiled(remat):
-        step = llama.make_train_step(cfg, mesh, attn="flash",
-                                     optimizer=optimizer, remat=remat,
-                                     loss_chunk=run["loss_chunk"])
-        return step.lower(place(params), place(state), tokens, tokens).compile()
-
-    program = compiled(run["remat"])
-    text = program.as_text().splitlines()
-    kernels = [line for line in text
-               if 'custom_call_target="tpu_custom_call"' in line]
-    # One forward and one backward kernel in each recurrent step's scan body
-    # and no other: a step that recomputes its layers ("full") keeps the
-    # forward kernel's o and lse as a "dots" step does, and still recomputes
-    # the rest (the SwiGLU's products under `rematted_computation/ffn`).
-    assert run["remat"] == ["full", "full", "full", "dots"]
-    assert sum("flash_fwd" in line for line in kernels) == 4
-    assert sum("flash_bwd" in line for line in kernels) == 4
-    assert len(kernels) == 8
-    assert not any("rematted_computation" in line for line in kernels)
-    assert sum("rematted_computation/ffn" in line and " convolution(" in line
-               for line in text) >= 3
-    # The head: three products over the vocabulary in one scan body, on the
-    # 4 x 2 rows of all the recurrent steps' states, none replayed.
-    head = [line for line in text
-            if "head_loss" in line and " convolution(" in line]
-    assert len(head) == 3 and not any("rematted" in line for line in head)
-    assert sum("bf16[8,512,49152]" in line.split(" convolution(")[0]
-               for line in head) == 1
-    m = program.memory_analysis()
-    held = (m.argument_size_in_bytes + m.output_size_in_bytes
-            - m.alias_size_in_bytes + m.temp_size_in_bytes)
-    # weights and both float32 moments donated: 10 bytes a parameter
-    assert m.alias_size_in_bytes > 10 * 612_000_000
-    assert 0.25 * 16e9 < held < 15.75 * 2**30
-    with pytest.raises(Exception, match="hbm"):
-        compiled("dots")
-
-
-def test_kimi_linear_adamw_step_at_published_widths(v5e, monkeypatch):
-    """The benchmark's `kimi-linear-48b-a3b-l16k` step on one chip:
-    Kimi-Linear-48B-A3B at its published widths, the first 5 of 27 layers (KDA
-    and a dense FFN; KDA, KDA, MLA, KDA with experts: four runs, inlined), 8
-    of 256 routed experts a layer held here beside the shared one, 20,480
-    rows of the vocabulary, 1 x 16,384 tokens, flash with keys of 192 and
-    values of 128, the configuration file's remat, AdamW with float32
-    moments, weights and state donated.  It fits the chip: the compiler's
-    own peak is 13.41 GB of 16.91 (15.75 GiB) and the sum the cell reports
-    13.89 GB.  Two flash kernels for the one MLA layer and, for each of the
-    four expert layers, the grouped matmuls of one pass of the held experts'
-    loops: `gmm` forward (3), for the rows' gradients (3) and, the backward
-    loop forming what it does not keep, gate and up again (2), `tgmm` for
-    the weights' gradients (3); the forward loop that `"full"` replays is
-    dead there and gone.  A KDA layer's recurrence is two more, `kda_fwd`
-    and `kda_bwd`, the 256 chunks a grid axis each runs in turn: no loop is
-    left under `kda`, and the forward kernel that `"full"` would replay is
-    dead, its output, states, inverses and `P` kept.  The layer's passes
-    round the recurrence are six more (`ops/kda_mixer.py`): `kda_pre` and
-    `kda_post` forward and, kept by their inputs alone, formed again under
-    `"full"`,
-    `kda_pre_bwd` and `kda_post_bwd` once; they stand under `attn`, not under
-    `kda`."""
-    import dataclasses
-    import json
-    import os
-
-    import optax
-    from jax.sharding import Mesh
-
-    from torchmpi_tpu.models import llama
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmark", "configs",
-                           "kimi-linear-48b-a3b.json")) as fh:
-        file = json.load(fh)
-    run = file["run"]
-    published = llama.kimi_linear_48b_a3b()
-    cfg = dataclasses.replace(
-        published, n_layers=5, layer_kinds=published.layer_kinds[:5],
-        experts_held=(0, 8), vocab=20480)
-    assert (file["num_hidden_layers"], file["num_experts"],
-            file["vocab_size"]) == (5, 8, 20480)
-    assert [n for *_, n in llama.layer_runs(cfg)] == [1, 2, 1, 1]
-    one = SingleDeviceSharding(v5e[0])
-    place = lambda tree: jax.tree.map(
-        lambda a: _sds(a.shape, a.dtype, one), tree)
-    params = jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0), cfg,
-                                               dtype=jnp.bfloat16))
-    assert sum(a.size for a in jax.tree.leaves(params)) == 602_450_816
-    adamw = optax.adamw(run["optimizer"]["learning_rate"], b1=0.9, b2=0.95,
-                        weight_decay=0.1)
-    f32 = lambda tree: jax.tree.map(lambda a: a.astype(jnp.float32), tree)
-
-    def update(grads, state, params):       # moments float32, as the runner
-        updates, state = adamw.update(f32(grads), state, f32(params))
-        return jax.tree.map(lambda u, p: u.astype(p.dtype), updates,
-                            params), state
-
-    optimizer = optax.GradientTransformation(lambda p: adamw.init(f32(p)),
-                                             update)
-    state = jax.eval_shape(optimizer.init, params)
-    mesh = Mesh([v5e[0]], ("dp",))
-    tokens = _sds((1, 16384), jnp.int32, one)
-    step = llama.make_train_step(cfg, mesh, attn="flash", optimizer=optimizer,
-                                 remat=run["remat"],
-                                 loss_chunk=run["loss_chunk"])
-    program = step.lower(place(params), place(state), tokens,
-                         tokens).compile()
-    text = program.as_text().splitlines()
-    kernels = [line for line in text
-               if 'custom_call_target="tpu_custom_call"' in line]
-    named = lambda what: sum(bool(re.search(what, line)) for line in kernels)
-    assert run["remat"] == "full"
-    assert (named("flash_fwd"), named("flash_bwd")) == (1, 1)
-    assert all("/mla/" in line for line in kernels if "flash_" in line)
-    assert named(r"jit\(gmm\)") == 4 * 8 and named(r"jit\(tgmm\)") == 4 * 3
-    assert (named("kda_fwd"), named("kda_bwd")) == (4, 4)
-    assert (named(r"kda_pre(?!_bwd)"), named(r"kda_post(?!_bwd)")) == (8, 8)
-    assert (named("kda_pre_bwd"), named("kda_post_bwd")) == (4, 4)
-    recurrence = lambda line: "kda_fwd" in line or "kda_bwd" in line
-    assert all(("/kda/" in line) == recurrence(line)
-               and re.search(r"[/(]attn[/)]", line)
-               for line in kernels if "kda_" in line)
-    assert len(kernels) == 46 + 2 * 4 + 6 * 4
-    # what "full" forms again: the way in and the way out, never a
-    # recurrence or a flash kernel
-    assert not any("rematted_computation" in line for line in kernels
-                   if "flash_" in line or recurrence(line))
-    assert sum("rematted_computation" in line for line in kernels
-               if "kda_" in line) == 2 * 4
-    assert not any(" while(" in line and "/kda/" in line for line in text)
-    m = program.memory_analysis()
-    held = (m.argument_size_in_bytes + m.output_size_in_bytes
-            - m.alias_size_in_bytes + m.temp_size_in_bytes)
-    # weights and both float32 moments donated: 10 bytes a parameter
-    assert m.alias_size_in_bytes > 10 * 602_000_000
-    # That it compiled is the check that it fits 15.75 GiB.  The compiler's
-    # own peak is 13.41 GB and arguments plus temporaries, the sum the cell
-    # reports as `hbm_program_gb`, 13.89 GB: a record of the plan, not a
-    # limit of the chip.  Before the four KDA layers kept their tiles'
-    # inverses and `P` (`ops.kda.residual_bytes`: 4 x (0.134 + 0.067) = 0.81
-    # GB from forward to backward) they read 12.94 and 13.69; the sum grew by
-    # 0.20 and not by 0.81 because its temporaries are the highest point of a
-    # heap that the compiler packs anew, not a sum of what is kept; the peak
-    # by 0.47.
-    from torchmpi_tpu.ops import kda
-
-    kept = kda.residual_bytes(1, 16384, cfg.kda_heads, cfg.kda_head_dim,
-                              jnp.bfloat16)
-    assert 4 * (kept["kda_inverse"] + kept["kda_p"]) == 6 * 2**27
-    assert 8e9 < m.peak_memory_in_bytes < 14e9
-    assert m.peak_memory_in_bytes < held < 14e9
-
-
-def test_glm_flash_adamw_step_at_published_widths(v5e, monkeypatch):
-    """The benchmark's `glm-4.7-flash-l16k` step on one chip: GLM-4.7-Flash at
-    its published widths, the first 5 of 47 layers (a dense FFN, then four
-    with experts: two runs, inlined) and the multi-token-prediction module, 8
-    of 64 routed experts a layer held here beside the shared one, 19,360 rows
-    of the vocabulary (151.25 tiles of 128), 1 x 16,384 tokens, flash at
-    heads of 256, the configuration file's remat, AdamW with float32 moments,
-    weights and state donated.  The compiler's own peak is 13.05 GB of 16.91
-    (15.75 GiB) since PR 41 (15.39 before it, where `"dots"` was refused by 58
-    MB).  Two flash kernels for each of the six latent layers,
-    the module's under `mtp`, none replayed, and eleven grouped matmuls for
-    each of the five expert layers, as in the Kimi Linear step."""
-    import json
-    import os
-
-    import optax
-    from jax.sharding import Mesh
-
-    from torchmpi_tpu.models import llama
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmark", "configs",
-                           "glm-4.7-flash.json")) as fh:
-        file = json.load(fh)
-    run = file["run"]
-    published = llama.glm_4_7_flash()
-    cfg = dataclasses.replace(
-        published, n_layers=5, layer_kinds=published.layer_kinds[:5],
-        experts_held=(0, 8), vocab=19360)
-    assert (file["num_hidden_layers"], file["n_routed_experts"],
-            file["vocab_size"], file["num_nextn_predict_layers"]) == (
-        5, 8, 19360, 1)
-    assert [n for *_, n in llama.layer_runs(cfg)] == [1, 4]
-    one = SingleDeviceSharding(v5e[0])
-    place = lambda tree: jax.tree.map(
-        lambda a: _sds(a.shape, a.dtype, one), tree)
-    params = jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0), cfg,
-                                               dtype=jnp.bfloat16))
-    assert sum(a.size for a in jax.tree.leaves(params)) == 706_518_848
-    adamw = optax.adamw(run["optimizer"]["learning_rate"], b1=0.9, b2=0.95,
-                        weight_decay=0.1)
-    f32 = lambda tree: jax.tree.map(lambda a: a.astype(jnp.float32), tree)
-
-    def update(grads, state, params):       # moments float32, as the runner
-        updates, state = adamw.update(f32(grads), state, f32(params))
-        return jax.tree.map(lambda u, p: u.astype(p.dtype), updates,
-                            params), state
-
-    optimizer = optax.GradientTransformation(lambda p: adamw.init(f32(p)),
-                                             update)
-    state = jax.eval_shape(optimizer.init, params)
-    mesh = Mesh([v5e[0]], ("dp",))
-    tokens = _sds((1, 16384), jnp.int32, one)
-    step = llama.make_train_step(cfg, mesh, attn="flash", optimizer=optimizer,
-                                 remat=run["remat"],
-                                 loss_chunk=run["loss_chunk"])
-    program = step.lower(place(params), place(state), tokens,
-                         tokens).compile()
-    kernels = [line for line in program.as_text().splitlines()
-               if 'custom_call_target="tpu_custom_call"' in line]
-    named = lambda what, lines=kernels: sum(
-        bool(re.search(what, line)) for line in lines)
-    assert run["remat"] == "full"
-    assert (named("flash_fwd"), named("flash_bwd[^_]")) == (6, 6)
-    assert all("/mla/" in line for line in kernels if "flash_" in line)
-    module = [line for line in kernels if re.search(r"[(/]mtp[)/]", line)]
-    assert (named("flash_fwd", module), named("flash_bwd", module)) == (1, 1)
-    assert len(kernels) == 6 * 2 + 5 * 11 and len(module) == 2 + 11
-    peak = program.memory_analysis().peak_memory_in_bytes
-    # 15.39 GB until PR 41, whose rotation (`llama._rotate_pairs`) leaves
-    # the compiler no sequence-on-the-lanes copies of q and k to keep.
-    assert 12.0e9 < peak < 14.0e9
-
-
 @pytest.mark.parametrize("tile", [256, 512, 1024])
 def test_windowed_flash_at_laguna_widths(v5e, tile):
     """A Laguna-S-2.1 sliding layer's attention, q (1, 16384, 72, 128) with
@@ -767,192 +290,3 @@ def test_windowed_flash_at_laguna_widths(v5e, tile):
     assert fwd_used < 16 * 1024 * 1024                  # the default limit
     assert 3 * 2 * L * width * 4 < used <= stated < V5E_VMEM_BYTES
     assert grad.memory_analysis().peak_memory_in_bytes < 0.8 * 4.06e9
-
-
-def test_laguna_adamw_step_at_published_widths(v5e, monkeypatch):
-    """The benchmark's `laguna-s-2.1-l16k` step on one chip: Laguna-S-2.1 at
-    its published widths, the first 5 of 48 layers (a full layer with the
-    dense FFN, three window layers and a full one with experts: three runs,
-    inlined), 8 of 256 routed experts a layer held here beside the shared
-    one, 12,544 rows of the vocabulary, 1 x 16,384 tokens, the configuration
-    file's remat, AdamW with bfloat16 moments, weights and state donated.
-    The compiler's own peak is 14.09 GB of 16.91 (15.75 GiB); with float32
-    moments it refuses the step by 437 MB (my compile of PR 40: 2.5 GB of the
-    plan is the five layers' log-sum-exp columns padded to 128 lanes).  Two
-    flash kernels a layer, the window layers' under `swa`, none replayed."""
-    import json
-    import os
-
-    import optax
-    from jax.sharding import Mesh
-
-    from torchmpi_tpu.models import llama
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmark", "configs",
-                           "laguna-s-2.1.json")) as fh:
-        file = json.load(fh)
-    run = file["run"]
-    published = llama.laguna_s_2_1()
-    cfg = dataclasses.replace(
-        published, n_layers=5, layer_kinds=published.layer_kinds[:5],
-        experts_held=(0, 8), vocab=12544)
-    assert (file["num_hidden_layers"], file["num_experts"],
-            file["vocab_size"]) == (5, 8, 12544)
-    assert [n for *_, n in llama.layer_runs(cfg)] == [1, 3, 1]
-    one = SingleDeviceSharding(v5e[0])
-    place = lambda tree: jax.tree.map(
-        lambda a: _sds(a.shape, a.dtype, one), tree)
-    params = jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0), cfg,
-                                               dtype=jnp.bfloat16))
-    assert sum(a.size for a in jax.tree.leaves(params)) == 811_017_216
-    assert run["optimizer"]["moments_dtype"] == "bfloat16"
-    optimizer = optax.adamw(run["optimizer"]["learning_rate"], b1=0.9,
-                            b2=0.95, weight_decay=0.1)
-    state = jax.eval_shape(optimizer.init, params)      # bfloat16, as they
-    mesh = Mesh([v5e[0]], ("dp",))
-    tokens = _sds((1, 16384), jnp.int32, one)
-    step = llama.make_train_step(cfg, mesh, attn="flash", optimizer=optimizer,
-                                 remat=run["remat"],
-                                 loss_chunk=run["loss_chunk"])
-    program = step.lower(place(params), place(state), tokens,
-                         tokens).compile()
-    kernels = [line for line in program.as_text().splitlines()
-               if 'custom_call_target="tpu_custom_call"' in line]
-    named = lambda what, lines=kernels: sum(
-        bool(re.search(what, line)) for line in lines)
-    assert run["remat"] == "full"
-    assert (named("flash_fwd"), named("flash_bwd[^_]")) == (5, 5)
-    window = [line for line in kernels if "/swa/" in line]
-    assert (named("flash_fwd", window), named("flash_bwd", window)) == (3, 3)
-    assert len(window) == 6
-    assert len(kernels) == 5 * 2 + 4 * 11               # as before PR 41
-    for line in kernels:
-        if "flash_" in line:      # q's 72 or 48 heads, K and V at their 8
-            heads = [int(n) for n in re.findall(
-                r"bf16\[(\d+),16384,128\]",
-                line.split("operand_layout_constraints")[1])]
-            assert heads[0] in (48, 72) and heads[1:3] == [8, 8]
-    # Under the 14.09 GB of the step with K and V repeated and the sliced
-    # rotation (compiled here at PR 40 and again at PR 41): 13.06 GB.
-    peak = program.memory_analysis().peak_memory_in_bytes
-    assert 12.0e9 < peak < 14.0e9
-
-
-def test_mellum2_ep4_adamw_step_at_published_widths(v5e, monkeypatch):
-    """The benchmark's `mellum2-12b-a2.5b-ep4-l8k` step on the four chips of
-    a described v5e 2x2, a mesh of ``ep`` = 4: Mellum2-12B-A2.5B at its
-    published widths, one whole period of 28 layers (three window layers and
-    a full one, experts in each), all 64 experts, 16 a chip, the whole
-    vocabulary on every chip, 8 x 8,192 tokens, two rows a chip, the
-    configuration file's remat, AdamW with bfloat16 moments, weights and state
-    donated.  The first program of this file that is one program across four
-    chips.  The compiler's own peak a chip is 12.92 GB of 16.91 (15.75 GiB)
-    with a first pass of the whole uniform share a peer and overflow passes
-    a quarter of it (my compile of PR 46; 13.53 GB when every pass was the
-    share, and then with float32 moments 17.1 GB and refused; at half the
-    share a pass 12.02 GB with bfloat16 moments and 15.60 with float32, my
-    compiles of PR 44; the latter ran on the chip, its steps moving by whole
-    passes with the routing): two rows a chip fit, the moments' type is the
-    file's choice.  The flash kernels stand in their
-    ``shard_map`` over ``ep`` (the batch's rows), the experts' grouped
-    matmuls are Mosaic kernels too (every axis of the mesh is the expert
-    layer's ``shard_map``'s), and the exchange is ``all-to-all``s by name."""
-    import json
-    import os
-
-    import numpy as np
-    import optax
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-    from torchmpi_tpu.models import llama
-    from torchmpi_tpu.models._common import mesh_spec
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmark", "configs",
-                           "mellum2-12b-a2.5b.json")) as fh:
-        file = json.load(fh)
-    with open(os.path.join(root, "benchmark", "traffic",
-                           "ep4-l8k.json")) as fh:
-        mix = json.load(fh)
-    run = file["run"]
-    published = llama.mellum2_12b_a2_5b()
-    cfg = dataclasses.replace(published, n_layers=4,
-                              layer_kinds=published.layer_kinds[:4])
-    assert (file["num_hidden_layers"], file["num_experts"],
-            file["vocab_size"]) == (4, 64, 98304)
-    assert [n for *_, n in llama.layer_runs(cfg)] == [3, 1]
-    assert mix["mesh"] == {"ep": 4} and (mix["batch"], mix["seq_len"]) == (
-        8, 8192)
-    mesh = Mesh(np.array(v5e[:4]), ("ep",))
-    params = jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0), cfg,
-                                               dtype=jnp.bfloat16))
-    assert sum(a.size for a in jax.tree.leaves(params)) == 2_123_976_960
-    placed = jax.tree.map(
-        lambda a, s: _sds(a.shape, a.dtype, NamedSharding(
-            mesh, mesh_spec(s, mesh, a.shape))), params,
-        llama.param_specs(cfg))
-    a_chip = sum(int(np.prod(a.sharding.shard_shape(a.shape)))
-                 for a in jax.tree.leaves(placed))
-    assert a_chip == 934_891_776                # 16 of 64 experts a layer
-    # AdamW as `benchmark/runners/step_tokens_adamw.py:_optimizer` builds it:
-    # both moments in the file's type, whatever the weights'.
-    moments = jnp.dtype(run["optimizer"]["moments_dtype"])
-    assert moments == jnp.bfloat16
-    adamw = optax.adamw(run["optimizer"]["learning_rate"], b1=0.9, b2=0.95,
-                        weight_decay=0.1)
-    cast = lambda tree: jax.tree.map(lambda a: a.astype(moments), tree)
-
-    def update(grads, state, p):
-        updates, state = adamw.update(cast(grads), state, cast(p))
-        return jax.tree.map(lambda u, a: u.astype(a.dtype), updates,
-                            p), state
-
-    optimizer = optax.GradientTransformation(
-        lambda p: adamw.init(cast(p)), update)
-    like = iter(jax.tree.leaves(placed) * 2)
-    state = jax.tree.map(
-        lambda a: _sds(a.shape, a.dtype, next(like).sharding if a.ndim
-                       else NamedSharding(mesh, P())),
-        jax.eval_shape(optimizer.init, params))
-    assert llama.batch_spec(cfg, mesh) == P("ep", None)
-    tokens = _sds((mix["batch"], mix["seq_len"]), jnp.int32,
-                  NamedSharding(mesh, llama.batch_spec(cfg, mesh)))
-    step = llama.make_train_step(cfg, mesh, attn="flash", optimizer=optimizer,
-                                 remat=run["remat"],
-                                 loss_chunk=run["loss_chunk"],
-                                 with_delivered=True)
-    program = step.lower(placed, state, tokens, tokens).compile()
-    text = program.as_text()
-    kernels = [line for line in text.splitlines()
-               if 'custom_call_target="tpu_custom_call"' in line]
-    named = lambda what, lines=kernels: sum(
-        bool(re.search(what, line)) for line in lines)
-    assert run["remat"] == "full"
-    assert (named("flash_fwd"), named("flash_bwd[^_]")) == (4, 4)
-    window = [line for line in kernels if "/swa/" in line]
-    assert (named("flash_fwd", window), named("flash_bwd", window)) == (3, 3)
-    # A layer's grouped matmuls: 3 forward, and gate and up again with the
-    # three products' two gradients each backward, in the first pass's body
-    # and again in the overflow passes'.
-    assert len(kernels) == 4 * 2 + 4 * 2 * 11
-    sizes = (llama.ep_pass_rows(cfg, 2 * 8192, 4),
-             llama.ep_overflow_rows(cfg, 2 * 8192, 4))
-    assert sizes == (32768, 8192)               # the share, a quarter of it
-    exchanged = [line for line in text.splitlines()
-                 if re.search(r"= \S+ all-to-all", line)]
-    # at each size, a layer's forward pass sends rows and weights out and
-    # results back, its backward pass rows, weights and cotangents out and
-    # two cotangents back; the plan's counts, forward and replayed; and one
-    # after the forward loop for the senders' counts of the rows they filled
-    assert len(exchanged) == 4 * (2 * (3 + 5) + 2 + 1) and all(
-        "moe.exchange" in line for line in exchanged)
-    # every (4, rows, ...) block of rows is bfloat16: 604 MB a first pass
-    for rows in sizes:
-        assert sum(f"bf16[4,{rows},2304]" in line
-                   for line in exchanged) == 4 * 5
-    peak = program.memory_analysis().peak_memory_in_bytes
-    assert 12.4e9 < peak < 13.5e9
-    assert peak > 0.25 * 16e9                   # the benchmark's floor

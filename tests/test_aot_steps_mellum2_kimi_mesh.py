@@ -1,0 +1,218 @@
+"""Ask the chip's compiler, without the chip (``tests/test_aot_compile.py`` says
+what that is worth and what it is not): the benchmark's steps of Mellum2 on
+an ep axis of four chips, and of Kimi Linear and OLMoE on dp x tp, at their
+published widths.  Two long steps and a short one, one of three such files,
+because the driver hands a worker a FILE at a time and a long step holds four
+to five cores for minutes: queued last (``tests/conftest.py``), they fill the
+cores the run's last workers leave.  Three cases, not two: a worker is handed
+its next file when two cases are left to it, and one that holds a file of two
+would take the next such file as well while other workers sit idle."""
+
+import dataclasses
+import re
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from torchmpi_tpu.runtime import topology
+
+from test_aot_compile import _sds, v5e  # noqa: F401
+
+
+def test_mellum2_ep4_adamw_step_at_published_widths(v5e, monkeypatch):
+    """The benchmark's `mellum2-12b-a2.5b-ep4-l8k` step on the four chips of
+    a described v5e 2x2, a mesh of ``ep`` = 4: Mellum2-12B-A2.5B at its
+    published widths, one whole period of 28 layers (three window layers and
+    a full one, experts in each), all 64 experts, 16 a chip, the whole
+    vocabulary on every chip, 8 x 8,192 tokens, two rows a chip, the
+    configuration file's remat, AdamW with bfloat16 moments, weights and state
+    donated.  The first program of this file that is one program across four
+    chips.  The compiler's own peak a chip is 12.92 GB of 16.91 (15.75 GiB)
+    with a first pass of the whole uniform share a peer and overflow passes
+    a quarter of it (my compile of PR 46; 13.53 GB when every pass was the
+    share, and then with float32 moments 17.1 GB and refused; at half the
+    share a pass 12.02 GB with bfloat16 moments and 15.60 with float32, my
+    compiles of PR 44; the latter ran on the chip, its steps moving by whole
+    passes with the routing): two rows a chip fit, the moments' type is the
+    file's choice.  The flash kernels stand in their
+    ``shard_map`` over ``ep`` (the batch's rows), the experts' grouped
+    matmuls are Mosaic kernels too (every axis of the mesh is the expert
+    layer's ``shard_map``'s), and the exchange is ``all-to-all``s by name."""
+    import json
+    import os
+
+    import numpy as np
+    import optax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from torchmpi_tpu.models import llama
+    from torchmpi_tpu.models._common import mesh_spec
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "mellum2-12b-a2.5b.json")) as fh:
+        file = json.load(fh)
+    with open(os.path.join(root, "benchmark", "traffic",
+                           "ep4-l8k.json")) as fh:
+        mix = json.load(fh)
+    run = file["run"]
+    published = llama.mellum2_12b_a2_5b()
+    cfg = dataclasses.replace(published, n_layers=4,
+                              layer_kinds=published.layer_kinds[:4])
+    assert (file["num_hidden_layers"], file["num_experts"],
+            file["vocab_size"]) == (4, 64, 98304)
+    assert [n for *_, n in llama.layer_runs(cfg)] == [3, 1]
+    assert mix["mesh"] == {"ep": 4} and (mix["batch"], mix["seq_len"]) == (
+        8, 8192)
+    mesh = Mesh(np.array(v5e[:4]), ("ep",))
+    params = jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0), cfg,
+                                               dtype=jnp.bfloat16))
+    assert sum(a.size for a in jax.tree.leaves(params)) == 2_123_976_960
+    placed = jax.tree.map(
+        lambda a, s: _sds(a.shape, a.dtype, NamedSharding(
+            mesh, mesh_spec(s, mesh, a.shape))), params,
+        llama.param_specs(cfg))
+    a_chip = sum(int(np.prod(a.sharding.shard_shape(a.shape)))
+                 for a in jax.tree.leaves(placed))
+    assert a_chip == 934_891_776                # 16 of 64 experts a layer
+    # AdamW as `benchmark/runners/step_tokens_adamw.py:_optimizer` builds it:
+    # both moments in the file's type, whatever the weights'.
+    moments = jnp.dtype(run["optimizer"]["moments_dtype"])
+    assert moments == jnp.bfloat16
+    adamw = optax.adamw(run["optimizer"]["learning_rate"], b1=0.9, b2=0.95,
+                        weight_decay=0.1)
+    cast = lambda tree: jax.tree.map(lambda a: a.astype(moments), tree)
+
+    def update(grads, state, p):
+        updates, state = adamw.update(cast(grads), state, cast(p))
+        return jax.tree.map(lambda u, a: u.astype(a.dtype), updates,
+                            p), state
+
+    optimizer = optax.GradientTransformation(
+        lambda p: adamw.init(cast(p)), update)
+    like = iter(jax.tree.leaves(placed) * 2)
+    state = jax.tree.map(
+        lambda a: _sds(a.shape, a.dtype, next(like).sharding if a.ndim
+                       else NamedSharding(mesh, P())),
+        jax.eval_shape(optimizer.init, params))
+    assert llama.batch_spec(cfg, mesh) == P("ep", None)
+    tokens = _sds((mix["batch"], mix["seq_len"]), jnp.int32,
+                  NamedSharding(mesh, llama.batch_spec(cfg, mesh)))
+    step = llama.make_train_step(cfg, mesh, attn="flash", optimizer=optimizer,
+                                 remat=run["remat"],
+                                 loss_chunk=run["loss_chunk"],
+                                 with_delivered=True)
+    program = step.lower(placed, state, tokens, tokens).compile()
+    text = program.as_text()
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    named = lambda what, lines=kernels: sum(
+        bool(re.search(what, line)) for line in lines)
+    assert run["remat"] == "full"
+    assert (named("flash_fwd"), named("flash_bwd[^_]")) == (4, 4)
+    window = [line for line in kernels if "/swa/" in line]
+    assert (named("flash_fwd", window), named("flash_bwd", window)) == (3, 3)
+    # A layer's grouped matmuls: 3 forward, and gate and up again with the
+    # three products' two gradients each backward, in the first pass's body
+    # and again in the overflow passes'.
+    assert len(kernels) == 4 * 2 + 4 * 2 * 11
+    sizes = (llama.ep_pass_rows(cfg, 2 * 8192, 4),
+             llama.ep_overflow_rows(cfg, 2 * 8192, 4))
+    assert sizes == (32768, 8192)               # the share, a quarter of it
+    exchanged = [line for line in text.splitlines()
+                 if re.search(r"= \S+ all-to-all", line)]
+    # at each size, a layer's forward pass sends rows and weights out and
+    # results back, its backward pass rows, weights and cotangents out and
+    # two cotangents back; the plan's counts, forward and replayed; and one
+    # after the forward loop for the senders' counts of the rows they filled
+    assert len(exchanged) == 4 * (2 * (3 + 5) + 2 + 1) and all(
+        "moe.exchange" in line for line in exchanged)
+    # every (4, rows, ...) block of rows is bfloat16: 604 MB a first pass
+    for rows in sizes:
+        assert sum(f"bf16[4,{rows},2304]" in line
+                   for line in exchanged) == 4 * 5
+    peak = program.memory_analysis().peak_memory_in_bytes
+    assert 12.4e9 < peak < 13.5e9
+    assert peak > 0.25 * 16e9                   # the benchmark's floor
+
+
+def test_kimi_linear_step_on_dp_tp_runs_each_devices_kernels(monkeypatch):
+    """On more than one device a KDA layer between its projections (the way
+    in, the recurrence, the way out) runs in ONE ``shard_map`` over the batch
+    and the heads (``llama._kda_sharded``), as flash does: its kernels are
+    Mosaic's, and the compiler refuses to partition one
+    (``NotImplementedError: Mosaic kernels cannot be automatically
+    partitioned``; bare under GSPMD this step does not lower).  Kimi Linear's
+    first four layers at published widths (KDA, KDA, KDA, MLA; a share of the
+    experts) on dp=2 x tp=2: each device runs ``kda_fwd`` and ``kda_bwd``
+    once a KDA layer on its own row of the batch and its 16 of 32 heads,
+    beside them ``kda_pre`` and ``kda_post`` twice (``"full"`` forms them
+    again from their inputs) and ``kda_pre_bwd`` and ``kda_post_bwd`` once,
+    and the latent layer's two flash kernels on its 16 heads."""
+    import dataclasses
+
+    from torchmpi_tpu.models import llama
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    published = llama.kimi_linear_48b_a3b()
+    cfg = dataclasses.replace(
+        published, n_layers=4, layer_kinds=published.layer_kinds[:4],
+        experts_held=(0, 8), vocab=20480)
+    assert [m for m, _ in cfg.layer_kinds] == ["kda", "kda", "kda", "mla"]
+    mesh = topology.topology_mesh("v5e-4", {"dp": 2, "tp": 2})
+    args = topology._llama_arg_structs(cfg, mesh, llama.param_specs, 2, 4096)
+
+    def lowered():
+        step = llama.make_train_step(cfg, mesh, attn="flash", remat="full",
+                                     loss_chunk=512)
+        return jax.jit(lambda p, t, y: step(p, None, t, y)).lower(*args)
+
+    text = lowered().compile().as_text()
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    named = lambda what: sum(bool(re.search(what, line)) for line in kernels)
+    assert (named("kda_fwd"), named("kda_bwd")) == (3, 3)
+    assert (named(r"kda_pre(?!_bwd)"), named(r"kda_post(?!_bwd)")) == (6, 6)
+    assert (named("kda_pre_bwd"), named("kda_post_bwd")) == (3, 3)
+    assert (named("flash_fwd"), named("flash_bwd")) == (1, 1)
+    assert "jit(gmm)" not in text
+    # a device's o and states: its one row, 4096 tokens in 64 chunks, 16 heads
+    fwd = next(line for line in kernels if "kda_fwd" in line)
+    assert "[1,4096,2048]" in fwd and "[64,1,16,128,128]" in fwd
+    # the way in's four results on the same rows and heads
+    pre = next(line for line in kernels if "kda_pre" in line
+               and "kda_pre_bwd" not in line)
+    assert pre.split(" custom-call(")[0].count("[1,4096,2048]") == 4
+    import functools
+
+    from torchmpi_tpu.ops.kda_mixer import kda_mixer
+    monkeypatch.setattr(llama, "_kda_sharded", lambda mesh, heads, eps:
+                        functools.partial(kda_mixer, eps=eps))
+    with pytest.raises(NotImplementedError, match="automatically partitioned"):
+        lowered()
+
+
+def test_olmoe_step_on_dp_tp_takes_the_compilers_grouped_matmul(monkeypatch):
+    """On more than one device the sorted dispatch leaves the grouped matmul
+    to `lax.ragged_dot`, which the compiler partitions under GSPMD (its own
+    Mosaic kernel, named `ragged-dot-none`); megablox's, a Mosaic kernel of
+    ours, it would refuse to.  One layer at published widths on dp=2 x tp=2:
+    the 9 products the layer requires, the gate and up products kept through
+    remat "dots" by the names they carry in this form too."""
+    import dataclasses
+
+    from torchmpi_tpu.models import llama
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(llama.olmoe_1b_7b(), n_layers=1)
+    mesh = topology.topology_mesh("v5e-4", {"dp": 2, "tp": 2})
+    args = topology._llama_arg_structs(cfg, mesh, llama.param_specs, 4, 4096)
+    step = llama.make_train_step(cfg, mesh, attn="flash", remat="dots",
+                                 loss_chunk=512)
+    text = jax.jit(lambda p, t, y: step(p, None, t, y)).lower(
+        *args).compile().as_text()
+    assert text.count('op_name="ragged-dot-none"') == 9
+    assert "jit(gmm)" not in text

@@ -22,6 +22,8 @@ from torchmpi_tpu.models import llama
 from torchmpi_tpu.ops.flash_attention import flash_attention
 from torchmpi_tpu.parallel import mesh as pmesh
 
+pytestmark = pytest.mark.usefixtures("quick_compiles")    # conftest.py
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PUBLISHED = llama.glm_4_7_flash()
 
@@ -415,6 +417,7 @@ def test_the_published_47_layers_and_the_module_build():
     assert counts.shape == (47, cfg.n_experts)
 
 
+@pytest.mark.usefixtures("full_optimisation")
 def test_kimi_linear_is_what_it_was():
     """With the new fields at their defaults the Kimi Linear preset builds
     the parameter tree and the weights for a seed that the commit before this

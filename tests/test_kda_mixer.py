@@ -17,6 +17,8 @@ import jax.numpy as jnp
 from torchmpi_tpu.models import llama
 from torchmpi_tpu.ops import kda_mixer as km
 
+pytestmark = pytest.mark.usefixtures("quick_compiles")    # conftest.py
+
 PRE_INPUTS = "xq xk xv f conv_q conv_k conv_v a_log dt_bias".split()
 PRE_OUTPUTS = "q k v g".split()
 POST_INPUTS = "o z o_norm".split()

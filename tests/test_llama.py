@@ -14,6 +14,8 @@ import jax.numpy as jnp
 from torchmpi_tpu import parallel
 from torchmpi_tpu.models import llama
 
+pytestmark = pytest.mark.usefixtures("quick_compiles")    # conftest.py
+
 
 def _data(cfg, B=4, L=16, seed=0):
     rng = np.random.RandomState(seed)
@@ -266,7 +268,8 @@ class TestSharded:
         l_full, g_full = jax.value_and_grad(
             llama.make_loss_fn(cfg))(params, (tokens, targets))
         lf = llama.make_loss_fn(cfg, mesh=mesh, attn="ring-zigzag")
-        l_zz, g_zz = jax.value_and_grad(lf)(sharded, (tokens, targets))
+        l_zz, g_zz = jax.jit(jax.value_and_grad(lf))(sharded,
+                                                     (tokens, targets))
         np.testing.assert_allclose(float(l_zz), float(l_full), rtol=2e-4)
         for a, b in zip(jax.tree.leaves(g_zz), jax.tree.leaves(g_full)):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -290,8 +293,8 @@ class TestSharded:
         sharded = llama.shard_params(params, mesh, cfg)
         l_full, g_full = jax.value_and_grad(
             llama.make_loss_fn(cfg))(params, (tokens, targets))
-        l_zz, g_zz = jax.value_and_grad(
-            llama.make_loss_fn(cfg, mesh=mesh, attn="ring-zigzag"))(
+        l_zz, g_zz = jax.jit(jax.value_and_grad(
+            llama.make_loss_fn(cfg, mesh=mesh, attn="ring-zigzag")))(
             sharded, (tokens, targets))
         np.testing.assert_allclose(float(l_zz), float(l_full), rtol=2e-4)
         for a, b in zip(jax.tree.leaves(g_zz), jax.tree.leaves(g_full)):

@@ -11,6 +11,8 @@ import jax.numpy as jnp
 from torchmpi_tpu import parallel
 from torchmpi_tpu.models import llama, llama_decode
 
+pytestmark = pytest.mark.usefixtures("quick_compiles")    # conftest.py
+
 
 def _data(cfg, B=4, L=16, seed=0):
     rng = np.random.RandomState(seed)

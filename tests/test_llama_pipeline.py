@@ -14,6 +14,8 @@ from torchmpi_tpu.models.llama_pipeline import (
     _decoder_layer_tp_manual, make_1f1b_train_step, make_pp_train_step,
     shard_params_pp)
 
+pytestmark = pytest.mark.usefixtures("quick_compiles")    # conftest.py
+
 
 def _data(cfg, B=4, L=16, seed=0):
     rng = np.random.RandomState(seed)

@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from torchmpi_tpu.models import llama, llama_decode
 from torchmpi_tpu.parallel import make_mesh
 
+pytestmark = pytest.mark.usefixtures("quick_compiles")    # conftest.py
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 E, K, LAYERS = 8, 4, 2
@@ -235,6 +237,7 @@ PINNED = {(4, 2): ("0x1.8366820000000p+2", "0x1.7e7bf60000000p+3"),
 
 
 @pytest.mark.parametrize("experts,k", sorted(PINNED))
+@pytest.mark.usefixtures("full_optimisation")
 def test_capacity_routing_is_bit_equal_to_before(experts, k):
     """(d) `capacity_factor` 1.25 in routing groups, renormalised for k > 1."""
     cfg = llama.moe_tiny(n_experts=experts, k=k)

@@ -17,6 +17,8 @@ from torchmpi_tpu.ops.flash_attention import (
     flash_bwd_block, flash_fwd_block)
 from torchmpi_tpu.parallel import sequence as seq
 
+pytestmark = pytest.mark.usefixtures("quick_compiles")    # conftest.py
+
 
 def _qkv(B=2, L=64, H=4, D=16, seed=0):
     rng = np.random.RandomState(seed)

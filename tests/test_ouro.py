@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from torchmpi_tpu.models import llama
 from torchmpi_tpu.parallel import make_mesh
 
+pytestmark = pytest.mark.usefixtures("quick_compiles")    # conftest.py
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 T, LAYERS = 4, 2
@@ -395,6 +397,7 @@ PINNED = {2: ("0x1.93d0940000000p+14", "0x1.79a7220000000p+2",
 
 
 @pytest.mark.parametrize("depth", sorted(PINNED))
+@pytest.mark.usefixtures("full_optimisation")
 def test_one_step_is_bit_equal_to_before(depth):
     """Depth 2 is inlined, depth 6 scanned; `remat="dots"`."""
     cfg = dataclasses.replace(llama.tiny(), n_layers=depth)

@@ -1443,6 +1443,85 @@ def _held_experts_bwd(k, R, kernel, saved, dy):
 _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
+# The tile of a sum (K, N) that one grid step of a weight gradient's
+# ``tgmm_add`` holds in :func:`_held_swiglu_bwd`, beside the row tile of the
+# ``gmm``s; chosen on the chip at Mellum2's block, 8,192 rows of 2304 x 896
+# (PERF.md section 6, PR 47).
+_TGMM_TILES = (1152, 1152)
+
+
+def _held_swiglu_bwd(kernel, rows, kept, xs, ws, w, dys, dw):
+    """:func:`_held_swiglu`'s VJP on one block of :func:`_ep_experts`' rows,
+    written out, with the weights' gradients ADDED to sums it is handed:
+    ``(dxs, dws, dw)`` for the block's results' cotangents ``dys`` (R, D),
+    where ``dw`` comes in as the float32 sums of ``(w_gate, w_up, w_down)``'s
+    gradients over the blocks before this one and leaves with this block's
+    in them.
+
+    Gate and up are formed again (the layer keeps its inputs alone), the
+    hidden rows' cotangent is the grouped matmul of ``dys`` with the down
+    weights transposed, the SwiGLU's and the router weight's cotangents are
+    taken in float32 and rounded once, ``dxs`` is the two transposed products
+    of the gate's and the up's: the five ``gmm``s autodiff runs, at its
+    tiles.  The three weight gradients are ``ops.tgmm.tgmm_add``: each adds
+    its groups' products into the sum of its weight in float32 inside the
+    kernel, the sum aliased to the result, and visits only the experts the
+    block has rows for.  Autodiff through megablox's VJP had each ``tgmm``
+    write all the held experts' gradients in the compute dtype, whichever few
+    the block's sorted rows belong to, and an XLA pass read them and read and
+    wrote the float32 sums: 1 GB a block of Mellum2's layer, 80 blocks a step
+    (PERF.md section 6, PRs 46 and 47).  Now no array of the weights' shape
+    is written a block, a block's gradient is not rounded before it is
+    summed, and a block with no valid row returns the sums to the bit.  The
+    selects against the valid ``rows`` stand wherever ``gmm`` leaves rows
+    unwritten (gate, up, the hidden rows' cotangent, ``dxs``); ``tgmm_add``
+    reads the rows its groups cover and no others.
+
+    With ``kernel`` False (``tp`` left to GSPMD: ``lax.ragged_dot``, which
+    has no form that adds into an operand; no cell) the block keeps
+    :func:`_held_swiglu`'s own VJP and the sums an XLA add.
+    :func:`_held_experts` keeps autodiff's too: it takes one pass of one
+    block as a rule, so its sum has one term and there is no pass over sums
+    to save, and its three cells' compiled steps stay what they are."""
+    f32 = lambda a: a.astype(jnp.float32)
+    if not kernel:
+        dxs, dws, *dwp = jax.vjp(functools.partial(
+            _held_swiglu, kernel, rows, kept), xs, ws, *w)[1](dys)
+        return dxs, dws, tuple(a + f32(b) for a, b in zip(dw, dwp))
+    from jax.experimental.pallas.ops.tpu.megablox.ops import backend
+
+    from ..ops.tgmm import tgmm_add
+
+    R = xs.shape[0]
+    tile = min(512, -(-R // 16) * 16)
+    pad = -R % tile         # whole row tiles, as _grouped_matmul pads them
+    if pad:
+        rows, xs, ws, dys = (jnp.pad(a, ((0, pad), (0, 0)))
+                             for a in (rows, xs, ws, dys))
+        kept = kept.at[-1].add(pad)
+    interpret = jax.default_backend() != "tpu"
+    # a product's cotangent back through its weights (E, K, N), transposed, at
+    # the forward product's tiles, as megablox's VJP has it
+    back = lambda da, w: jnp.where(rows, backend.gmm(
+        da, w, kept, xs.dtype, (tile, *(min(n, 1024) for n in w.shape[1:])),
+        transpose_rhs=True, interpret=interpret), 0)
+    add = lambda a, b, sums: tgmm_add(
+        a, b, kept, sums, tiling=(tile, *_TGMM_TILES), interpret=interpret)
+    w_gate, w_up, w_down = w
+    gate, up = (jnp.where(rows, _grouped_matmul(xs, a, kept, kernel), 0)
+                for a in (w_gate, w_up))
+    act = jax.nn.silu(gate) * up
+    hs = jnp.where(rows, act * ws, 0).astype(xs.dtype)
+    dhs = f32(back(dys, w_down))
+    dws = jnp.sum(dhs * f32(act), axis=-1, keepdims=True).astype(ws.dtype)
+    dact, g, sig = dhs * ws, f32(gate), jax.nn.sigmoid(f32(gate))
+    dup = (dact * g * sig).astype(xs.dtype)
+    dgate = (dact * f32(up) * sig * (1 + g * (1 - sig))).astype(xs.dtype)
+    dxs = back(dgate, w_gate) + back(dup, w_up)
+    dw = (add(xs, dgate, dw[0]), add(xs, dup, dw[1]), add(hs, dys, dw[2]))
+    return dxs[:R], dws[:R], dw
+
+
 def ep_pass_rows(cfg: Config, n_tokens: int, ep: int) -> int:
     """Rows a peer of the FIRST pass of :func:`_ep_experts` for a rank's
     ``n_tokens`` tokens on ``ep`` ranks: the rows uniform routing sends one
@@ -1476,6 +1555,31 @@ def ep_overflow_rows(cfg: Config, n_tokens: int, ep: int) -> int:
     8,192 rows a peer, 512 a held expert on average, one row tile of the
     grouped matmul)."""
     return -(-ep_pass_rows(cfg, n_tokens, ep) // 64) * 16
+
+
+def ep_grad_plan(cfg: Config, n_tokens: int, ep: int, kernel: bool = True,
+                 itemsize: int = 2) -> Dict[str, int]:
+    """The account of how :func:`_ep_experts_bwd` sums the held experts'
+    weight gradients, for one rank's ``n_tokens`` tokens and one layer on
+    ``ep`` ranks: the rows of a block of the experts, the blocks of the first
+    pass and of each overflow pass, the weight-gradient products of a block
+    that add into the float32 sums inside their kernel
+    (:func:`_held_swiglu_bwd`), and the bytes a block's summing moves outside
+    a kernel: none, where before each of the three products wrote every held
+    expert's gradient in the compute dtype (``itemsize`` bytes) and an XLA
+    pass read it and read and wrote the float32 sum, as it still does with
+    ``kernel`` False.  Static, from shapes alone (a test pins it against the
+    jaxpr); no metric reads it."""
+    share, overflow = (ep_pass_rows(cfg, n_tokens, ep),
+                       ep_overflow_rows(cfg, n_tokens, ep))
+    B = math.gcd(share, overflow)
+    a_sum = (cfg.n_experts // ep) * cfg.d_model * cfg.d_ff
+    return {"block_rows": B,
+            "first_pass_blocks": ep * share // B,
+            "overflow_pass_blocks": ep * overflow // B,
+            "added_in_place_a_block": 3 if kernel else 0,
+            "summed_outside_bytes_a_block":
+                0 if kernel else 3 * (2 * 4 + itemsize) * a_sum}
 
 
 def _ep_pass(k, R, n_tokens, order, first, sent, lo):
@@ -1560,16 +1664,19 @@ def _ep_experts(k, rows, kernel, axis, xt, wflat, order, plan, w):
     no transpose, so the gradient is written out (:func:`_ep_experts_bwd`): it
     keeps the inputs alone and takes the same passes, the rows and the
     results' cotangents out, the rows' and the router weights' cotangents
-    back, each block through :func:`_held_swiglu`'s own VJP, the weights'
-    gradients summed over blocks and passes in float32 and left on the rank
-    that holds the experts.
+    back, each block through :func:`_held_swiglu_bwd` (the VJP of
+    :func:`_held_swiglu` written out), which adds the block's weight
+    gradients, in float32 and inside the kernels that form them, into the sums
+    the loops carry; rounded to the weights' dtype once, after the passes, and
+    left on the rank that holds the experts (:func:`ep_grad_plan` is the
+    account of it).
 
     The forward and the backward pass are each a ``jax.jit`` of their own
     (``k``, ``rows``, ``kernel`` and ``axis`` static): every expert layer of
     a stack has the same shapes, so a program traces and lowers the four
     bodies (forward and backward, the share's and the overflow's) once and
     every layer, and every replay of one under ``remat``, calls them
-    (``tests/test_mellum2.py::test_a_body_is_traced_once_a_shape``).  Staged
+    (``tests/test_mellum2_passes.py``: a body is traced once a shape).  Staged
     once a layer, the second size cost 9.6 s of set-up in the Mellum2 cell,
     more than its bound (PERF.md section 6, PRs 45 and 46).  What they call
     is read when they are traced: a test that patches :func:`_ep_pass` or
@@ -1586,8 +1693,10 @@ def _ep_experts(k, rows, kernel, axis, xt, wflat, order, plan, w):
 
     :func:`_held_experts` is the same passes without an exchange, and the two
     share what a pass computes (:func:`_held_swiglu`, :func:`_grouped_matmul`)
-    but not the loop: a chip's share runs on one device under no
-    ``shard_map``, so it has no axis to exchange over; it leaves the units of
+    but not the loop, nor the block's backward (its one block a pass goes
+    through autodiff: a sum of one term has no pass over sums to save): a
+    chip's share runs on one device under no ``shard_map``, so it has no axis
+    to exchange over; it leaves the units of
     the experts it lacks out where this sends every unit somewhere; it has one
     block where this has one a source; and its passes are counted from its own
     units where these are the fullest pair's over the axis.  One loop for
@@ -1649,9 +1758,9 @@ def _ep_experts_bwd(k, rows, kernel, axis, saved, given):
 
         def block(dw, given):
             valid, kept, xs, ws, dys = given
-            dxs, dws, *dwp = jax.vjp(functools.partial(
-                _held_swiglu, kernel, valid, kept), xs, ws, *w)[1](dys)
-            return tuple(a + f32(b) for a, b in zip(dw, dwp)), (dxs, dws)
+            dxs, dws, dw = _held_swiglu_bwd(kernel, valid, kept, xs, ws, w,
+                                            dys, dw)
+            return dw, (dxs, dws)
 
         with jax.named_scope("moe.experts"):
             dw, (dxs, dws) = lax.scan(

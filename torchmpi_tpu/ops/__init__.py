@@ -10,7 +10,10 @@ KDA linear-attention layers, forward and a hand-written backward, in two
 Pallas kernels where a head fills whole lanes (``from torchmpi_tpu.ops import
 kda``; the function is ``kda.kda``), and ``ops/kda_mixer.py`` the layer's
 passes round it (``kda_mixer.kda_mixer``: short convolutions, SiLU, norms,
-decay and gate, a fused kernel each way in and out).
+decay and gate, a fused kernel each way in and out).  ``ops/tgmm.py`` is the
+grouped matmul of a weight gradient that adds into float32 sums it is given
+(``tgmm.tgmm_add``: megablox's ``tgmm`` with the empty groups not visited),
+which the ``ep`` path's backward pass sums the experts' gradients with.
 """
 
 from .flash_attention import flash_attention  # noqa: F401
