@@ -25,6 +25,7 @@ _TOY = dict(vocab=128, d_model=64, n_layers=5, n_heads=4, d_ff=32,
             experts_held=(0, 2))
 _KIMI, _GLM, _LAGUNA = (llama.kimi_linear_48b_a3b(), llama.glm_4_7_flash(),
                         llama.laguna_s_2_1())
+_FALCON = llama.falcon_h1_34b()
 LOOPED = llama.Config(vocab=256, d_model=64, n_layers=2, n_heads=4,
                       n_kv_heads=4, d_ff=96, max_seq=128, ut_steps=4,
                       sandwich_norm=True, exit_gate=True)
@@ -47,6 +48,14 @@ CONFIGS = {
     "window": dataclasses.replace(
         _LAGUNA, **{**_TOY, "d_model": 48}, n_kv_heads=2, head_dim=16,
         swa_heads=6, swa_window=24, layer_kinds=_LAGUNA.layer_kinds[:5]),
+    # Falcon-H1's: attention and a state-space branch side by side in every
+    # layer, constants on the forward pass
+    "two_branches": dataclasses.replace(
+        _FALCON, vocab=128, d_model=48, n_layers=2, n_heads=4, n_kv_heads=2,
+        head_dim=16, d_ff=96, max_seq=256, ssm_heads=4, ssm_head_dim=8,
+        ssm_state=16, ssm_chunk=16, layer_kinds=_FALCON.layer_kinds[:2]),
+    # the constants alone refuse too: those paths read none of them
+    "multipliers": dataclasses.replace(llama.tiny(), embed_multiplier=2.0),
     "experts": llama.moe_tiny(),
     "dropless": dataclasses.replace(llama.moe_tiny(), capacity_factor=None,
                                     moe_aux_coef=0.0),
@@ -61,6 +70,10 @@ _PROMPT = jnp.zeros((1, 8), jnp.int32)
 
 def _ep_mesh():
     return make_mesh({"dp": 2, "ep": 2}, devices=jax.devices()[:4])
+
+
+def _tp_mesh():
+    return make_mesh({"dp": 2, "tp": 2}, devices=jax.devices()[:4])
 
 
 def _ring(attn):
@@ -87,6 +100,9 @@ CONSUMERS = {
     "ring-xla": ("attn='ring-xla'", _ring("ring-xla")),
     "counts": ("expert_unit_counts", lambda cfg: llama.expert_unit_counts(
         cfg, None, _PROMPT)),
+    "heads-on-tp": ("a tp axis", lambda cfg: llama.apply(
+        cfg, {"embed": jnp.zeros((cfg.vocab, cfg.d_model))},
+        jnp.zeros((2, 8), jnp.int32), mesh=_tp_mesh())),
     "experts-on-ep": ("an ep axis", lambda cfg: llama._moe_ffn(
         cfg, None, jnp.zeros((4, 8, cfg.d_model)), mesh=_ep_mesh())),
 }
@@ -94,7 +110,25 @@ CONSUMERS = {
 # (configuration, consumer) -> the phrases its refusal holds.
 _GLM_FIELDS = ("q_lora_rank=40", "mtp_layers=1")
 _LAGUNA_FIELDS = ("swa_window=24", "attn_gate=True")
+_FALCON_FIELDS = ("ssm_heads=4", "ssm_state=16", "ssm_out_multiplier=0.088")
 CASES = {
+    ("two_branches", "decode"): (
+        "recurrent-state cache", "BESIDE a key-value cache in one layer",
+        "BlockPool accounts for one kind", *_FALCON_FIELDS),
+    ("two_branches", "prefill"): ("the convolution's last taps to seed",
+                                  *_FALCON_FIELDS),
+    ("two_branches", "generate"): ("two caches of one layer",
+                                   *_FALCON_FIELDS),
+    ("two_branches", "gpipe"): ("hand-sharded layer with the state-space "
+                                "branch", *_FALCON_FIELDS),
+    ("two_branches", "1f1b"): ("make_1f1b_train_step", *_FALCON_FIELDS),
+    ("two_branches", "ring"): ("a state that crosses sequence shards",
+                               *_FALCON_FIELDS),
+    ("two_branches", "ring-xla"): ("attn='ring-xla'", *_FALCON_FIELDS),
+    ("two_branches", "heads-on-tp"): ("a tp axis", "heads over tp",
+                                      *_FALCON_FIELDS),
+    ("multipliers", "decode"): ("constants in the one-row path",
+                                "embed_multiplier=2.0", "ssm_heads=0"),
     ("looped", "decode"): ("looped configuration", "the decode step"),
     ("looped", "prefill"): ("looped configuration", "prefill"),
     ("looped", "generate"): ("looped configuration", "make_generate_fn"),
@@ -156,11 +190,11 @@ def test_every_row_of_the_table_is_reached():
     cases above reach every one of its rows."""
     traits = set().union(*(llama._traits(cfg) for cfg in CONFIGS.values()))
     assert traits == {"looped", "runs", "rotary_latent", "window", "experts",
-                      "held"}
+                      "held", "two_branches"}
     for consumer, rows in llama._LACKS.items():
         assert set(rows) <= traits, consumer
-    reached = {(CONSUMERS[consumer][0],
-                "looped" if config == "sandwich" else config)
+    same = {"sandwich": "looped", "multipliers": "two_branches"}
+    reached = {(CONSUMERS[consumer][0], same.get(config, config))
                for config, consumer in CASES}
     # the rings share their rows, one name each
     wanted = {(consumer, trait) for consumer, rows in llama._LACKS.items()
@@ -175,7 +209,8 @@ def test_what_is_not_refused():
     for consumer in llama._LACKS:
         llama._refuse(llama.tiny(), consumer)
     for consumer in ("the decode step", "prefill", "make_generate_fn",
-                     "an ep axis", "expert_unit_counts", "attn='ring'"):
+                     "an ep axis", "a tp axis", "expert_unit_counts",
+                     "attn='ring'"):
         llama._refuse(CONFIGS["experts"], consumer)
         llama._refuse(CONFIGS["dropless"], consumer)
 

@@ -149,6 +149,59 @@ def test_looped_train_step_carries_scope_names(depth):
     assert _carried(names, "flash_bwd", inside="checkpoint/attn/")
 
 
+def _two_branch_tiny():
+    """An attention branch and a state-space branch side by side, at toy
+    widths: the published layer of `llama.falcon_h1_34b`."""
+    published = llama.falcon_h1_34b()
+    return dataclasses.replace(
+        published, vocab=128, d_model=48, n_layers=2, n_heads=4, n_kv_heads=2,
+        head_dim=16, d_ff=96, max_seq=256, ssm_heads=4, ssm_head_dim=8,
+        ssm_state=16, ssm_chunk=16, layer_kinds=published.layer_kinds[:2])
+
+
+@pytest.mark.parametrize("remat", ["dots", "full"])
+def test_two_branch_train_step_carries_scope_names(remat):
+    """`ssm` stands BESIDE `attn`; in it `ssm.conv`, `ssd` (the scan alone)
+    and `ssm.norm`; the scan runs forward once and backward once under either
+    policy (its output and chunk-entry states are kept by name), its one
+    loop over the chunks with it, the loop's body under `ssd` each way, while
+    the convolution and the norm round it are formed again."""
+    cfg = _two_branch_tiny()
+    mesh = pmesh.make_mesh({"dp": 1}, devices=jax.devices()[:1])
+    step = llama.make_train_step(cfg, mesh, attn="flash", remat=remat,
+                                 loss_chunk=32)
+    params = jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0), cfg))
+    tokens = jax.ShapeDtypeStruct((1, 64), jnp.int32)
+    names = _op_names(step.lower(params, None, tokens, tokens))
+    for scope in ("embed", "attn", "ssm", "ssm.conv", "ssd", "ssm.norm",
+                  "ffn", "final_norm", "head_loss", "optimizer"):
+        assert _carried(names, scope), scope
+    for scope in MOE + ("kda", "mla", "swa"):
+        assert not _carried(names, scope), scope
+    for inner in ("ssm.conv", "ssd", "ssm.norm"):
+        assert _carried(names, inner, inside="ssm/" + inner), inner
+        assert not _carried(names, inner, inside="attn/"), inner
+    assert not _carried(names, "ssm", inside="attn/ssm")
+    # The scan's products: forward in the forward pass, backward in the
+    # checkpointed layer's backward pass, none in the recomputation.
+    products = [n for n in names if _carried([n], "ssd")
+                and n.endswith("dot_general")]
+    assert [n for n in products if "jvp(ssm)/ssd/" in n]
+    assert [n for n in products if "checkpoint/ssm/ssd/" in n]
+    assert not [n for n in products if "rematted_computation" in n]
+    # The loop over the chunks.  Forward it stands in a `custom_vjp` rule
+    # under `jax.checkpoint` and keeps no name of its callers': the body
+    # names `ssd` itself, which is what a join by scope finds.
+    assert {"ssd/exp", "ssd/mul", "ssd/add"} <= names
+    assert [n for n in names if "checkpoint/ssm/ssd/while/body/" in n]
+    assert not [n for n in names if "rematted_computation" in n
+                and "while" in n and _carried([n], "ssd")]
+    for formed_again in ("ssm.conv", "ssm.norm"):
+        assert _carried(names, formed_again, inside="rematted_computation")
+    assert _carried(names, "flash_fwd", inside="attn/flash_fwd")
+    assert not _carried(names, "flash_fwd", inside="rematted_computation")
+
+
 RESNET = ("stem", "conv", "bn", "residual", "pool", "fc_loss")
 
 

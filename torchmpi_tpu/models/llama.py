@@ -13,7 +13,9 @@ module and it imports neither.
   layers differ in kind (``Config(layer_kinds=...)``: KDA linear-attention
   and latent-attention layers, :func:`kimi_linear_48b_a3b`; window and full
   softmax layers with head counts, rotations and a key window of their own,
-  :func:`laguna_s_2_1`) is a sequence of homogeneous runs
+  :func:`laguna_s_2_1`; an attention branch and a Mamba-2 state-space
+  branch side by side on one normed input in every layer, with constants on
+  the forward pass, :func:`falcon_h1_34b`) is a sequence of homogeneous runs
   (:func:`layer_runs`), ``params["layers"]`` a tuple of such stacks, each run
   inlined or scanned by its own length.  A multi-token-prediction module
   (``Config(mtp_layers=1)``, :func:`glm_4_7_flash`) is one more layer after
@@ -119,7 +121,8 @@ class Config:
     # A stack that is not homogeneous (Kimi Linear,
     # :func:`kimi_linear_48b_a3b`): for every layer its mixer (``"attn"``, the
     # softmax attention over ``n_heads`` heads of ``head_dim``; ``"kda"``,
-    # :func:`_kda_block`; ``"mla"``, :func:`_mla_block`) and its FFN
+    # :func:`_kda_block`; ``"mla"``, :func:`_mla_block`; ``"swa"`` and
+    # ``"attn+ssm"``, below) and its FFN
     # (``"dense"`` or ``"moe"``); :func:`layer_kinds` builds it from a
     # configuration file's lists.  None: every layer ``"attn"`` with the FFN
     # that ``n_experts`` says, one run, the parameter tree it always had.
@@ -193,12 +196,43 @@ class Config:
     # layer input, before the output projection (leaf ``wg`` (d_model, heads)
     # of every softmax layer; scope ``attn.gate``).
     attn_gate: bool = False
+    # Two mixers side by side in a layer (``"attn+ssm"`` in ``layer_kinds``;
+    # Falcon-H1, :func:`falcon_h1_34b`): the softmax attention of an
+    # ``"attn"`` layer and a Mamba-2 state-space branch
+    # (:func:`_ssm_block`) read ONE normed input and their outputs are summed
+    # into the residual.  The branch: ``ssm_heads`` heads of ``ssm_head_dim``
+    # channels, ``ssm_groups`` groups of heads sharing their B and C, a state
+    # of ``ssm_head_dim x ssm_state`` a head, a causal depthwise convolution
+    # of ``ssm_conv`` taps with bias, the scan in chunks of ``ssm_chunk``.
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
+    # Constants of the forward pass (they scale gradients too, so they are
+    # not folded into seeded weights), each 1 where a model has none: on the
+    # embedding's rows and on the logits; on the attention branch's input,
+    # on its keys before the rotation and on its output; on the state-space
+    # branch's input, on the five sections of its projection (gate, x, B, C,
+    # dt) and on its output; on the dense SwiGLU's gate (inside the SiLU)
+    # and on its output.
+    embed_multiplier: float = 1.0
+    head_multiplier: float = 1.0
+    attn_in_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    attn_out_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_multipliers: Tuple[float, float, float, float, float] = (1.0,) * 5
+    ssm_out_multiplier: float = 1.0
+    ffn_multipliers: Tuple[float, float] = (1.0, 1.0)
 
     def __post_init__(self):
         # ``head_dim`` is the softmax ("attn", "swa") layers'; a stack without
         # one (GLM-4.7-Flash: 20 latent heads on 2048) need not divide.
         softmax = self.layer_kinds is None or any(
-            mixer in ("attn", "swa") for mixer, _ in self.layer_kinds)
+            mixer in ("attn", "swa", "attn+ssm")
+            for mixer, _ in self.layer_kinds)
         assert (self.head_dim or not softmax
                 or self.d_model % self.n_heads == 0)
         if not self.head_dim:
@@ -227,13 +261,27 @@ class Config:
             assert self.ut_steps == 1 and not self.sandwich_norm
             assert not self.qk_norm
             for mixer, ffn in self.layer_kinds:
-                assert mixer in ("attn", "swa", "kda", "mla"), mixer
+                assert mixer in ("attn", "swa", "kda", "mla",
+                                 "attn+ssm"), mixer
                 assert ffn in ("dense", "moe"), ffn
                 assert ffn == "dense" or self.n_experts
                 assert mixer != "swa" or self.swa_window >= 1
+                assert mixer != "attn+ssm" or (
+                    self.ssm_heads and self.ssm_head_dim and self.ssm_state
+                    and self.ssm_heads % self.ssm_groups == 0)
         if self.attn_gate:
             # The gate is a leaf of a run (:func:`_init_run`).
             assert self.layer_kinds is not None
+        two = self.layer_kinds is not None and any(
+            mixer == "attn+ssm" for mixer, _ in self.layer_kinds)
+        # The branches' constants are read where the two branches meet.
+        assert two or (self.attn_in_multiplier == self.key_multiplier
+                       == self.attn_out_multiplier == self.ssm_in_multiplier
+                       == self.ssm_out_multiplier == 1
+                       and set(self.ssm_multipliers) == {1})
+        assert len(self.ssm_multipliers) == 5
+        # The FFN's are the dense SwiGLU's.
+        assert not self.n_experts or set(self.ffn_multipliers) == {1}
         if self.q_lora_rank or self.mla_rope:
             assert self.layer_kinds is not None and self.kv_lora_rank
         assert self.mtp_layers in (0, 1)
@@ -400,6 +448,33 @@ def mellum2_12b_a2_5b() -> Config:
                       ["sparse"] * 4) * 7)
 
 
+def falcon_h1_34b() -> Config:
+    """Falcon-H1-34B geometry (``tiiuae/Falcon-H1-34B-Instruct``,
+    ``falcon_h1``): 72 layers alike on a 5120-wide state, in each an
+    attention branch (20 heads of 128 over 4 KV heads, rotated whole at theta
+    1e11) and a Mamba-2 state-space branch (one projection to 9248, a
+    convolution of 4 taps over 5120 channels, 32 heads of 128 in 2 groups, a
+    state of 128 x 256 a head, chunks of 128, a gated norm a group) side by
+    side on one normed input, then a dense SwiGLU of 21,504; the published
+    constants on the embedding, the logits, every branch and the five
+    sections of the projection; untied embedding and head of 261,120 rows."""
+    return Config(vocab=261120, d_model=5120, n_layers=72, n_heads=20,
+                  n_kv_heads=4, head_dim=128, d_ff=21504, max_seq=262144,
+                  rope_theta=1e11, norm_eps=1e-5,
+                  ssm_heads=32, ssm_head_dim=128, ssm_state=256, ssm_groups=2,
+                  ssm_conv=4, ssm_chunk=128,
+                  embed_multiplier=5.656854249492381,
+                  head_multiplier=0.0078125, attn_in_multiplier=1.0,
+                  key_multiplier=0.011048543456039804,
+                  attn_out_multiplier=0.0375, ssm_in_multiplier=0.25,
+                  ssm_multipliers=(0.3535533905932738, 0.25,
+                                   0.1767766952966369, 0.5,
+                                   0.3535533905932738),
+                  ssm_out_multiplier=0.08838834764831845,
+                  ffn_multipliers=(0.1767766952966369, 0.011160714285714284),
+                  layer_kinds=(("attn+ssm", "dense"),) * 72)
+
+
 def layer_runs(cfg: Config) -> Tuple[Tuple[str, str, int], ...]:
     """The stack as homogeneous runs, ``(mixer, ffn, length)`` each:
     consecutive layers of one kind.  A configuration without
@@ -487,6 +562,26 @@ def _init_run(key: jax.Array, cfg: Config, mixer: str, ffn: str, n: int,
                   wv=dense(D, KV * hd), wo=dense(H * hd, D))
         if cfg.attn_gate:
             lp.update(wg=dense(D, H))
+        if mixer == "attn+ssm":
+            # The state-space branch beside the attention: one projection to
+            # [gate | x | B | C | dt], the convolution over [x | B | C] with
+            # its bias, A, D and dt's bias a head (A uniform in [1, 16],
+            # softplus(dt_bias) log-uniform in [1e-3, 1e-1], as the KDA
+            # layers' decay above), the gated norm's weight, the way out.
+            Hs, taps = cfg.ssm_heads, cfg.ssm_conv
+            inner = Hs * cfg.ssm_head_dim
+            conved = inner + 2 * cfg.ssm_groups * cfg.ssm_state
+            dt = jnp.exp(jax.random.uniform(next(keys), (n, Hs), jnp.float32,
+                                            np.log(1e-3), np.log(1e-1)))
+            lp.update(
+                ssm_in=dense(D, inner + conved + Hs),
+                ssm_conv=normal((taps, conved), taps ** -0.5).astype(dtype),
+                ssm_conv_bias=normal((conved,), 0.02).astype(dtype),
+                ssm_a_log=jnp.log(jax.random.uniform(
+                    next(keys), (n, Hs), jnp.float32, 1.0, 16.0)),
+                ssm_dt_bias=dt + jnp.log(-jnp.expm1(-dt)),
+                ssm_d=ones(Hs), ssm_norm=ones(inner),
+                ssm_out=dense(inner, D))
     if ffn == "dense":
         W = cfg.dense_d_ff or F
         lp.update(w_gate=dense(D, W), w_up=dense(D, W), w_down=dense(W, D))
@@ -1944,6 +2039,93 @@ def _mla_block(cfg: Config, lp: Params, x: jax.Array, attn_impl: Callable,
         return o.reshape(B, L, H * vd) @ lp["wo"]
 
 
+def _times(x: jax.Array, m: float) -> jax.Array:
+    """x times a constant of the forward pass, in float32 (a constant
+    rounded to bfloat16 is off by up to 0.4%, every element alike); x itself
+    where the constant is 1."""
+    return x if m == 1 else (x.astype(jnp.float32) * m).astype(x.dtype)
+
+
+def _ssm_block(cfg: Config, lp: Params, x: jax.Array) -> jax.Array:
+    """The Mamba-2 state-space branch on the normed input x (B, L, D), scope
+    ``ssm``: ``ssm_in_multiplier`` on x; one projection to [gate z | x | B |
+    C | dt], its five sections times ``ssm_multipliers``; a causal depthwise
+    convolution with bias over [x | B | C], then SiLU (``ssm.conv``); ``dt =
+    softplus(dt + dt_bias)`` and ``A = -exp(a_log)`` a head, float32; the
+    scan (``ops.ssd.ssd``, scope ``ssd``: the heads of a group share B and
+    C, the skip ``D x`` in it); the gated norm a group (``ssm.norm``); the
+    way out.  The branch's output multiplier is the caller's, where the two
+    branches meet."""
+    from ..ops import ssd
+
+    Bt, L, _ = x.shape
+    H, G, N = cfg.ssm_heads, cfg.ssm_groups, cfg.ssm_state
+    inner = H * cfg.ssm_head_dim
+    with jax.named_scope("ssm"):
+        proj = _times(x, cfg.ssm_in_multiplier) @ lp["ssm_in"]
+        if set(cfg.ssm_multipliers) != {1}:
+            scale = np.repeat(np.asarray(cfg.ssm_multipliers, np.float32),
+                              (inner, inner, G * N, G * N, H))
+            proj = (proj.astype(jnp.float32) * scale).astype(proj.dtype)
+        z, dt = proj[..., :inner], proj[..., -H:]
+        with jax.named_scope("ssm.conv"):
+            xbc = ssd.conv_silu(proj[..., inner:-H], lp["ssm_conv"],
+                                lp["ssm_conv_bias"])
+        y = ssd.ssd(
+            xbc[..., :inner].reshape(Bt, L, H, cfg.ssm_head_dim),
+            jax.nn.softplus(dt.astype(jnp.float32) + lp["ssm_dt_bias"]),
+            -jnp.exp(lp["ssm_a_log"]),
+            xbc[..., inner:inner + G * N].reshape(Bt, L, G, N),
+            xbc[..., inner + G * N:].reshape(Bt, L, G, N), lp["ssm_d"],
+            chunk=cfg.ssm_chunk)
+        with jax.named_scope("ssm.norm"):
+            y = ssd.gated_norm(y.reshape(Bt, L, inner), z, lp["ssm_norm"], G,
+                               cfg.norm_eps)
+        return y @ lp["ssm_out"]
+
+
+def _softmax_mixer(cfg: Config, lp: Params, x: jax.Array,
+                   positions: jax.Array, attn_impl: Callable,
+                   mixer: str = "attn"):
+    """A softmax layer's mixer on the normed input x (B, L, D), for a caller
+    inside the scope ``attn``: the projections (``attn_in_multiplier`` on x,
+    ``key_multiplier`` on the keys before the rotation, each 1 but in a
+    two-branch layer), QK-norm, the rotation of the layer's kind, the
+    attention (``swa`` round a window layer's), the gate a head, the way
+    out.  Returns it with the (pre-repeat, native-KV-head) keys and
+    values."""
+    B, L, _ = x.shape
+    hd, H, KV = cfg.head_dim, softmax_heads(cfg, mixer), cfg.n_kv_heads
+    rotate = _rotation(cfg, mixer)
+    x = _times(x, cfg.attn_in_multiplier)
+    q, k = _qk_norm(cfg, lp, x @ lp["wq"],
+                    _times(x @ lp["wk"], cfg.key_multiplier))
+    q = rotate(q.reshape(B, L, H, hd), positions)
+    k = rotate(k.reshape(B, L, KV, hd), positions)
+    v = (x @ lp["wv"]).reshape(B, L, KV, hd)
+    if mixer == "swa":
+        with jax.named_scope("swa"):
+            o = attn_impl(q, k, v)
+    else:
+        o = attn_impl(q, k, v)
+    if cfg.attn_gate:
+        o = _gate_heads(o, x, lp["wg"])
+    return o.reshape(B, L, H * hd) @ lp["wo"], (k, v)
+
+
+def _two_branches(cfg: Config, lp: Params, h: jax.Array,
+                  positions: jax.Array, attn_impl: Callable):
+    """What an ``"attn+ssm"`` layer's two mixers add to the residual, each
+    with its output multiplier on it, float32: ``(attention's, the
+    state-space branch's)``, both of ONE normed input."""
+    with jax.named_scope("attn"):
+        x = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
+        a, _ = _softmax_mixer(cfg, lp, x, positions, attn_impl)
+    s = _ssm_block(cfg, lp, x)
+    return (a.astype(jnp.float32) * cfg.attn_out_multiplier,
+            s.astype(jnp.float32) * cfg.ssm_out_multiplier)
+
+
 def _attention_block(cfg: Config, lp: Params, h: jax.Array,
                      positions: jax.Array, attn_impl: Callable,
                      constrain: Callable = lambda x: x,
@@ -1956,14 +2138,15 @@ def _attention_block(cfg: Config, lp: Params, h: jax.Array,
     at a window layer's head count and rotation (``attn_impl`` then the one
     made for its window), ``"kda"`` :func:`_kda_block` (``attn_impl`` then
     the recurrence), ``"mla"`` :func:`_mla_block` (``attn_impl`` then the one
-    made for its scale)."""
-    B, L, _ = h.shape
-    hd, H, KV = cfg.head_dim, softmax_heads(cfg, mixer), cfg.n_kv_heads
+    made for its scale), ``"attn+ssm"`` :func:`_two_branches` (``attn_impl``
+    the full layers'; the state-space branch beside it needs none)."""
     # Names in the device program (docs/observability.md): ``attn`` (the
     # projections, ``attn.qk_norm``, rope, the attention itself, the output
     # projection; in it ``kda``, the chunked recurrence alone, ``mla``,
     # the whole latent mixer, ``swa``, a window layer's attention alone, or
-    # ``attn.gate``, the gate on the heads' outputs), ``moe.router``/
+    # ``attn.gate``, the gate on the heads' outputs), ``ssm`` BESIDE it in a
+    # two-branch layer (in it ``ssm.conv``, ``ssd``, ``ssm.norm``),
+    # ``moe.router``/
     # ``moe.dispatch``/
     # ``moe.experts``/``moe.combine``/``moe.shared`` or ``ffn``, ``embed``,
     # ``final_norm``, ``exit_gate``, ``head_loss``, ``optimizer``; ``mtp``,
@@ -1975,21 +2158,14 @@ def _attention_block(cfg: Config, lp: Params, h: jax.Array,
             o = (_kda_block(cfg, lp, x, attn_impl) if mixer == "kda"
                  else _mla_block(cfg, lp, x, attn_impl, positions))
             return h + constrain(o)
-    rotate = _rotation(cfg, mixer)
+    if mixer == "attn+ssm":
+        # ``ssm`` stands beside ``attn``, not in it; in it ``ssm.conv``,
+        # ``ssd`` (the scan alone) and ``ssm.norm``.
+        a, s = _two_branches(cfg, lp, h, positions, attn_impl)
+        return h + constrain((a + s).astype(h.dtype))
     with jax.named_scope("attn"):
         x = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
-        q, k = _qk_norm(cfg, lp, x @ lp["wq"], x @ lp["wk"])
-        q = rotate(q.reshape(B, L, H, hd), positions)
-        k = rotate(k.reshape(B, L, KV, hd), positions)
-        v = (x @ lp["wv"]).reshape(B, L, KV, hd)
-        if mixer == "swa":
-            with jax.named_scope("swa"):
-                o = attn_impl(q, k, v)
-        else:
-            o = attn_impl(q, k, v)
-        if cfg.attn_gate:
-            o = _gate_heads(o, x, lp["wg"])
-        o = o.reshape(B, L, H * hd) @ lp["wo"]
+        o, (k, v) = _softmax_mixer(cfg, lp, x, positions, attn_impl, mixer)
         if cfg.sandwich_norm:
             o = rms_norm(o, lp["attn_post_norm"], cfg.norm_eps)
         h = h + constrain(o)
@@ -2016,6 +2192,14 @@ def _aux_zero(cfg: Config, mesh: Optional[Mesh] = None):
     return (zero, jnp.zeros((ep, ep), jnp.int32)) if ep > 1 else zero
 
 
+def _dense_ffn(cfg: Config, lp: Params, x: jax.Array) -> jax.Array:
+    """The dense SwiGLU of the normed input x, ``ffn_multipliers`` on the
+    gate (inside the SiLU) and on the output."""
+    on_gate, on_out = cfg.ffn_multipliers
+    return _times((jax.nn.silu(_times(x @ lp["w_gate"], on_gate))
+                   * (x @ lp["w_up"])) @ lp["w_down"], on_out)
+
+
 def _ffn_block(cfg: Config, lp: Params, h: jax.Array,
                constrain: Callable = lambda x: x,
                mesh: Optional[Mesh] = None, ffn: Optional[str] = None):
@@ -2029,8 +2213,7 @@ def _ffn_block(cfg: Config, lp: Params, h: jax.Array,
         g, aux = _moe_ffn(cfg, lp, x, mesh=mesh)
     else:
         with jax.named_scope("ffn"):
-            g = ((jax.nn.silu(x @ lp["w_gate"]) * (x @ lp["w_up"]))
-                 @ lp["w_down"])
+            g = _dense_ffn(cfg, lp, x)
         aux = _aux_zero(cfg, mesh)
     if cfg.sandwich_norm:
         with jax.named_scope("ffn"):
@@ -2196,6 +2379,16 @@ def _traits(cfg: Config) -> Dict[str, str]:
             "window layers among full ones, a gate on the attention output "
             "or a scaled or partial rotation", "swa_window", "attn_gate",
             "rope_fraction", "rope_yarn")
+    if cfg.ssm_heads or {
+            cfg.embed_multiplier, cfg.head_multiplier, cfg.attn_in_multiplier,
+            cfg.key_multiplier, cfg.attn_out_multiplier,
+            cfg.ssm_in_multiplier, cfg.ssm_out_multiplier,
+            *cfg.ssm_multipliers, *cfg.ffn_multipliers} != {1}:
+        found["two_branches"] = called(
+            "an attention and a state-space branch side by side in a layer, "
+            "or constants on the forward pass", "ssm_heads", "ssm_head_dim",
+            "ssm_state", "embed_multiplier", "attn_out_multiplier",
+            "ssm_out_multiplier")
     if cfg.n_experts:
         found["experts"] = called("a mixture of experts", "n_experts")
     if cfg.experts_held:
@@ -2215,6 +2408,10 @@ _TRAIN = "train it with make_train_step"
 # Both pipeline schedules run one stage program.
 _STAGE_ROWS = _rows(
     _TRAIN,
+    two_branches="a hand-sharded layer with the state-space branch (its "
+    "projection's sections, convolution and scan over the heads of a tp "
+    "shard) and the constants on the embedding, both branches, the FFN and "
+    "the logits, which the stage program does not read",
     rotary_latent="a last stage that hands the module the state before the "
     "final norm, and the embedding on the first and the last stage at once",
     window="stages whose layers differ in head count, window and rotation (a "
@@ -2229,6 +2426,10 @@ _STAGE_ROWS = _rows(
 
 _RING_ROWS = _rows(
     "take attn='full' or 'flash'",
+    two_branches="a state that crosses sequence shards (a shard's scan "
+    "starts from its left neighbour's last state of ssm_head_dim x "
+    "ssm_state a head, and its convolution from that neighbour's last taps) "
+    "and the key's constant in the ring's hand-sharded layer",
     rotary_latent="a ring form of the latent layer (the one rotated key part "
     "all heads share would circulate with every head's keys) and a module "
     "whose next token and target lie past a sequence shard's edge",
@@ -2249,6 +2450,12 @@ _LACKS: Dict[str, Dict[str, str]] = {
         held="the ranks the share stands for (a share is what ONE of the "
         "chips that divide a layer holds; the exchange sends a unit to the "
         "rank of its expert, and the absent experts have none)"),
+    "a tp axis": _rows(
+        "use a mesh without tp (dp alone)",
+        two_branches="the state-space branch's heads over tp: its one "
+        "projection's five sections, the convolution's channels, A, D, dt's "
+        "bias and the gated norm's groups each split by head, and the scan "
+        "in a shard_map beside the flash kernel's"),
     "expert_unit_counts": _rows(
         _TRAIN,
         looped="a row for each recurrent step's routers (it runs its layers "
@@ -2256,6 +2463,11 @@ _LACKS: Dict[str, Dict[str, str]] = {
     **{f"attn={attn!r}": _RING_ROWS for attn in _RINGS},
     "the decode step": _rows(
         _TRAIN,
+        two_branches="a recurrent-state cache (ssm_heads x ssm_head_dim x "
+        "ssm_state float32 a layer and the convolution's last ssm_conv - 1 "
+        "taps) BESIDE a key-value cache in one layer "
+        "(serving/kvcache.py:BlockPool accounts for one kind of block), the "
+        "scan's one-token form, and the constants in the one-row path",
         looped="a cache of ut_steps x n_layers slots, a norm on a branch's "
         "output and an exit gate (it runs its layers once)",
         rotary_latent="a latent cache (the normed latent and the rotated "
@@ -2270,6 +2482,9 @@ _LACKS: Dict[str, Dict[str, str]] = {
         "the others"),
     "prefill": _rows(
         _TRAIN,
+        two_branches="the scan's final state and the convolution's last "
+        "taps to seed a recurrent-state cache with, beside the keys and "
+        "values of the same layer",
         looped="a cache of ut_steps x n_layers slots to seed, a norm on a "
         "branch's output and an exit gate (it runs its layers once)",
         rotary_latent="a latent cache to seed decoding with (the normed "
@@ -2281,6 +2496,8 @@ _LACKS: Dict[str, Dict[str, str]] = {
         "token) and the KDA layers' final state to seed decoding with"),
     "make_generate_fn": _rows(
         _TRAIN,
+        two_branches="the two caches of one layer its prefill and decode "
+        "step would fill (recurrent state and last taps, keys and values)",
         looped="the cache of ut_steps x n_layers slots its prefill and "
         "decode step would fill, and an exit rule",
         rotary_latent="the latent cache its prefill and decode step would "
@@ -2318,7 +2535,10 @@ def _mixer_impls(cfg: Config, attn: str, mesh: Optional[Mesh]):
     if attn in _RINGS:
         _refuse(cfg, f"attn={attn!r}")
     softmax = 1.0 / np.sqrt(cfg.head_dim)
-    return {"attn": _make_attn_impl(cfg, attn, mesh, softmax),
+    full = _make_attn_impl(cfg, attn, mesh, softmax)
+    if mesh is not None and dict(mesh.shape).get(AXIS_TP, 1) > 1:
+        _refuse(cfg, "a tp axis")
+    return {"attn": full, "attn+ssm": full,
             "swa": (_make_attn_impl(cfg, attn, mesh, softmax, "swa")
                     if cfg.swa_window else None),
             "mla": (_make_attn_impl(cfg, attn, mesh, 1.0 / np.sqrt(mla))
@@ -2483,6 +2703,34 @@ def expert_unit_counts(cfg: Config, params: Params, tokens: jax.Array,
 # inlined: a ``lax.scan`` over them with the stack closed over ran 1061.6 ms
 # for 1078.7 (-1.6%) under "full", but it takes one remat policy for all its
 # steps, and a policy for each step wins more (1041.5).
+def branch_contributions(cfg: Config, params: Params, tokens: jax.Array,
+                         mesh: Optional[Mesh] = None, attn: str = "full"):
+    """What each branch of each ``"attn+ssm"`` layer adds to the residual on
+    ``tokens`` (B, L), by the code the training step runs
+    (:func:`_two_branches`, :func:`_dense_ffn`), a layer after the other with
+    no checkpoint: ``{"attn": (n_layers, B, L, D), "ssm": ..., "ffn": ...}``
+    float32, each with its multiplier on it.  A counter for outside the step:
+    a branch left out or a constant dropped reads here against a reference
+    as an error of its own size, where the logits bury it under the other
+    branches' sum."""
+    assert all(kinds == ("attn+ssm", "dense")
+               for kinds in cfg.layer_kinds or ()), cfg.layer_kinds
+    impl = _mixer_impls(cfg, attn, mesh)["attn+ssm"]
+    positions = jnp.arange(tokens.shape[1])
+    h = _times(params["embed"][tokens], cfg.embed_multiplier)
+    found = {"attn": [], "ssm": [], "ffn": []}
+    for stacked in _stacks(cfg, params):
+        for i in range(jax.tree.leaves(stacked)[0].shape[0]):
+            lp = jax.tree.map(lambda a: a[i], stacked)
+            a, s = _two_branches(cfg, lp, h, positions, impl)
+            h = h + (a + s).astype(h.dtype)
+            g = _dense_ffn(cfg, lp, rms_norm(h, lp["mlp_norm"], cfg.norm_eps))
+            h = h + g
+            for name, value in (("attn", a), ("ssm", s), ("ffn", g)):
+                found[name].append(value.astype(jnp.float32))
+    return {name: jnp.stack(values) for name, values in found.items()}
+
+
 _INLINE_MAX_LAYERS = 4
 
 
@@ -2594,7 +2842,8 @@ def apply(cfg: Config, params: Params, tokens: jax.Array,
     if exchanged:
         _rows_divide(cfg, mesh, B)
     with jax.named_scope("embed"):
-        h = constrain(params["embed"][tokens])      # (B, L, D)
+        h = constrain(_times(params["embed"][tokens],
+                             cfg.embed_multiplier))  # (B, L, D)
     impls = _mixer_impls(cfg, attn, mesh)
 
     remats = (remat,) * cfg.ut_steps if isinstance(remat, str) else tuple(remat)
@@ -2650,8 +2899,8 @@ def apply(cfg: Config, params: Params, tokens: jax.Array,
     else:
         aux = aux / (cfg.n_layers * cfg.ut_steps)
     h = jnp.stack(states) if all_steps else states[-1]
-    head = lambda h: (h if return_hidden
-                      else (h @ params["head"]).astype(jnp.float32))
+    head = lambda h: (h if return_hidden else _times(
+        (h @ params["head"]).astype(jnp.float32), cfg.head_multiplier))
     out = head(h)
     if cfg.mtp_layers and mtp_tokens is not None:
         # One more layer of the stack's last kind after the stack, under the
@@ -2758,7 +3007,11 @@ def _loss_and_delivered(cfg: Config, mesh: Optional[Mesh], attn: str,
         elif cfg.mtp_layers:
             nll = sum(_mtp_loss_parts(cfg, params, h, targets, loss_chunk))
         else:
-            nll = _nll_from_hidden(params["head"], h, targets, loss_chunk)
+            # The logits' constant on the states that make them: the same
+            # logits by linearity, and the chunked head stays the one it is.
+            nll = _nll_from_hidden(params["head"],
+                                   _times(h, cfg.head_multiplier), targets,
+                                   loss_chunk)
         if cfg.n_experts and cfg.moe_z_coef:
             nll = nll + cfg.moe_aux_coef * aux[0] + cfg.moe_z_coef * aux[1]
         elif cfg.n_experts:
@@ -2802,11 +3055,14 @@ def _wrap_remat(layer: Callable, remat: str,
       kernels, each chunk's inverse and ``P`` (``ops.kda.KDA_RESIDUAL_NAMES``:
       L/64 states of d x d and tiles of 64 x 64 a head; one, four, one and a
       half layer inputs' bytes at Kimi Linear's widths), so the recurrence
-      runs once each way and its kernels invert no tile and sum no P twice.
+      runs once each way and its kernels invert no tile and sum no P twice;
+      and a state-space branch's scan's output and chunk-entry states
+      (``ops.ssd.SSD_RESIDUAL_NAMES``: one layer input's bytes at Falcon-H1's
+      widths less a fifth, and 3.2), so that scan runs once each way too.
     * ``"none"``: everything, no checkpoint.
 
     No other attention mode emits the flash names, no other mixer the KDA
-    ones and no other FFN the grouped ones, so ``attn="full"``, the rings, the dense SwiGLU and the
+    or the scan's ones and no other FFN the grouped ones, so ``attn="full"``, the rings, the dense SwiGLU and the
     one-hot experts compile as before.
 
     ``scanned`` says the wrapped layer is the body of a ``lax.scan``.  The
@@ -2827,9 +3083,10 @@ def _wrap_remat(layer: Callable, remat: str,
         raise ValueError("remat must be 'none', 'dots', or 'full'")
     from ..ops.flash_attention import RESIDUAL_NAMES
     from ..ops.kda import KDA_RESIDUAL_NAMES
+    from ..ops.ssd import SSD_RESIDUAL_NAMES
 
     policies = jax.checkpoint_policies
-    kernels = (*RESIDUAL_NAMES, *KDA_RESIDUAL_NAMES)
+    kernels = (*RESIDUAL_NAMES, *KDA_RESIDUAL_NAMES, *SSD_RESIDUAL_NAMES)
     if remat == "full":
         policy = policies.save_only_these_names(*kernels)
     else:
