@@ -29,17 +29,19 @@ def test_mellum2_ep4_adamw_step_at_published_widths(v5e, monkeypatch):
     vocabulary on every chip, 8 x 8,192 tokens, two rows a chip, the
     configuration file's remat, AdamW with bfloat16 moments, weights and state
     donated.  The first program of this file that is one program across four
-    chips.  The compiler's own peak a chip is 12.92 GB of 16.91 (15.75 GiB)
-    with a first pass of the whole uniform share a peer and overflow passes
-    a quarter of it (my compile of PR 46; 13.53 GB when every pass was the
-    share, and then with float32 moments 17.1 GB and refused; at half the
+    chips.  Since PR 50 the expert layers gather the TOKENS over ``ep`` (4
+    ranks, 8 choices a token: ``llama._ep_form``) and the compiler's own peak
+    a chip is 10.61 GB of 16.91 (15.75 GiB); with the unit exchange it was
+    12.92 GB (a first pass of the whole uniform share a peer and overflow
+    passes a quarter of it, my compile of PR 46; 13.53 GB when every pass was
+    the share, and then with float32 moments 17.1 GB and refused; at half the
     share a pass 12.02 GB with bfloat16 moments and 15.60 with float32, my
-    compiles of PR 44; the latter ran on the chip, its steps moving by whole
-    passes with the routing): two rows a chip fit, the moments' type is the
-    file's choice.  The flash kernels stand in their
+    compiles of PR 44; the latter ran on the chip): two rows a chip fit, the
+    moments' type is the file's choice.  The flash kernels stand in their
     ``shard_map`` over ``ep`` (the batch's rows), the experts' grouped
     matmuls are Mosaic kernels too (every axis of the mesh is the expert
-    layer's ``shard_map``'s), and the exchange is ``all-to-all``s by name."""
+    layer's ``shard_map``'s), and what crosses the axis is ``all-gather``s of
+    rows and ``all-to-all``s of partial sums by name."""
     import json
     import os
 
@@ -116,26 +118,34 @@ def test_mellum2_ep4_adamw_step_at_published_widths(v5e, monkeypatch):
     window = [line for line in kernels if "/swa/" in line]
     assert (named("flash_fwd", window), named("flash_bwd", window)) == (3, 3)
     # A layer's grouped matmuls: 3 forward, and gate and up again with the
-    # three products' two gradients each backward, in the first pass's body
-    # and again in the overflow passes'.
-    assert len(kernels) == 4 * 2 + 4 * 2 * 11
-    sizes = (llama.ep_pass_rows(cfg, 2 * 8192, 4),
-             llama.ep_overflow_rows(cfg, 2 * 8192, 4))
-    assert sizes == (32768, 8192)               # the share, a quarter of it
-    exchanged = [line for line in text.splitlines()
-                 if re.search(r"= \S+ all-to-all", line)]
-    # at each size, a layer's forward pass sends rows and weights out and
-    # results back, its backward pass rows, weights and cotangents out and
-    # two cotangents back; the plan's counts, forward and replayed; and one
-    # after the forward loop for the senders' counts of the rows they filled
-    assert len(exchanged) == 4 * (2 * (3 + 5) + 2 + 1) and all(
-        "moe.exchange" in line for line in exchanged)
-    # every (4, rows, ...) block of rows is bfloat16: 604 MB a first pass
-    for rows in sizes:
-        assert sum(f"bf16[4,{rows},2304]" in line
-                   for line in exchanged) == 4 * 5
+    # three products' two gradients each backward, ONE body each way (a pass
+    # is one block; the forward pass's replay under "full" keeps nothing the
+    # backward pass reads, so it is not there).
+    assert len(kernels) == 4 * 2 + 4 * 11
+    assert llama.ep_exchange_plan(cfg, 2 * 8192, 4) == {
+        "form": "tokens", "pass_rows": 8192, "overflow_pass_rows": 8192,
+        "block_rows": 8192,
+        "rows_forward": 2 * 3 * 16384, "rows_backward": 3 * 3 * 16384,
+        "rows_forward_overflow": 0, "rows_backward_overflow": 0,
+        "bytes_forward": 452_984_832, "bytes_backward": 679_477_248}
+    moved = [re.search(r"= (\w+\[[\d,]*\])\S* (all-to-all|all-gather)\(",
+                       line) for line in text.splitlines()]
+    moved = [(m.group(2), m.group(1), m.string) for m in moved if m]
+    assert moved and all("moe.exchange" in line for *_, line in moved)
+    shapes = lambda kind: [s for k, s, _ in moved if k == kind]
+    # a layer sends the partial sums home forward and the rows' cotangents
+    # (and the router weights', 2 MB) backward: no block of units crosses
+    assert sorted(shapes("all-to-all")) == (
+        ["bf16[4,16384,2304]"] * 4 * 2 + ["f32[4,1,131072]"] * 4)
+    # and gathers its rows forward and its rows and the result's cotangent
+    # backward, 302 MB each (some inside the compiler's fusions with what
+    # reads them, where the text names the instruction twice), with the
+    # choices and the router's weights, 2 MB each
+    gathered = shapes("all-gather")
+    assert set(gathered) == {"bf16[65536,2304]", "f32[524288]", "s32[524288]"}
+    assert gathered.count("bf16[65536,2304]") >= 4 * 3
     peak = program.memory_analysis().peak_memory_in_bytes
-    assert 12.4e9 < peak < 13.5e9
+    assert 10.2e9 < peak < 11.2e9
     assert peak > 0.25 * 16e9                   # the benchmark's floor
 
 

@@ -39,9 +39,10 @@ module and it imports neither.
   selection bias, a shared expert and a chip's share of the experts
   (``Config(experts_held=...)``, :func:`_held_experts`) where the
   configuration has them; on an ``ep`` axis its tokens are sharded over the
-  axis too (:func:`batch_spec`) and the sorted units go to the rank of their
-  expert and come back by an exchange (:func:`_moe_ffn_ep`,
-  :func:`mellum2_12b_a2_5b`).
+  axis too (:func:`batch_spec`) and meet the rank of their experts there
+  (:func:`_moe_ffn_ep`, :func:`mellum2_12b_a2_5b`): on an axis no wider than
+  the choices a token the tokens are gathered over it and the partial sums
+  come home, past that the sorted units go out and come back by an exchange.
 * What each consumer of a configuration (decode, prefill, generation, the
   pipeline schedules, the rings, :func:`expert_unit_counts`) cannot run yet is
   one table, ``_LACKS``, read by one :func:`_refuse`.
@@ -1367,13 +1368,7 @@ def _moe_ffn_sorted(cfg: Config, lp: Params, x: jax.Array,
         first, held = cfg.experts_held
         R = held_pass_rows(cfg, T)
         with jax.named_scope("moe.dispatch"):
-            local = expert.reshape(T * k) - first
-            here = (local >= 0) & (local < held)
-            # Held units first, by expert, in token order within one; whole
-            # passes, so that no slice of a pass is moved to fit.
-            order = jnp.pad(
-                jnp.argsort(jnp.where(here, local, held), stable=True),
-                (0, -(T * k) % R))
+            order = _held_order(expert.reshape(T * k), first, held, R)[0]
         y = _held_experts(k, R, kernel, xt, weight.reshape(T * k), order,
                           counts[first:first + held],
                           (lp["w_gate"], lp["w_up"], lp["w_down"]))
@@ -1396,6 +1391,18 @@ def _moe_ffn_sorted(cfg: Config, lp: Params, x: jax.Array,
     with jax.named_scope("moe.combine"):
         y = _combine_rows(ys, order, inverse, k)
     return _add_shared_expert(cfg, lp, xt, y).reshape(B, L, D), aux
+
+
+def _held_order(chosen, first, held, R):
+    """The units whose expert ``chosen`` (n,) is one of the ``held`` from
+    ``first`` on, FIRST and by expert, in their own order within one (a stable
+    sort), the others after them; padded to whole passes of ``R`` rows, so
+    that no slice of a pass is moved to fit.  Returns ``(order, at)``: ``at``
+    is each unit's held expert, ``held`` for a unit of an expert not held."""
+    local = chosen - first
+    here = (local >= 0) & (local < held)
+    at = jnp.where(here, local, held)
+    return jnp.pad(jnp.argsort(at, stable=True), (0, -chosen.size % R)), at
 
 
 def _add_shared_expert(cfg: Config, lp: Params, xt: jax.Array, y: jax.Array):
@@ -1546,7 +1553,8 @@ _TGMM_TILES = (1152, 1152)
 
 
 def _held_swiglu_bwd(kernel, rows, kept, xs, ws, w, dys, dw):
-    """:func:`_held_swiglu`'s VJP on one block of :func:`_ep_experts`' rows,
+    """:func:`_held_swiglu`'s VJP on one block of :func:`_ep_experts`' rows
+    or one pass of :func:`_ep_gathered`'s,
     written out, with the weights' gradients ADDED to sums it is handed:
     ``(dxs, dws, dw)`` for the block's results' cotangents ``dys`` (R, D),
     where ``dw`` comes in as the float32 sums of ``(w_gate, w_up, w_down)``'s
@@ -1881,24 +1889,232 @@ def _ep_experts_bwd(k, rows, kernel, axis, saved, given):
 _ep_experts.defvjp(_ep_experts_fwd, _ep_experts_bwd)
 
 
+def _ep_form(cfg: Config, ep: int) -> str:
+    """What the expert layer moves over an ``ep`` axis of ``ep`` ranks
+    (:func:`_moe_ffn_ep`): ``"tokens"`` where the axis is no wider than the
+    choices a token (``ep <= expert_top_k``), ``"units"`` past it.  A gather
+    sends a token's row to the ``ep - 1`` other ranks once; the unit exchange
+    sends ``k * (ep - 1) / ep`` rows a token, and in whole passes a pair of
+    ranks.  Up to ``ep`` = k the gather sends fewer rows (Mellum2's 8 choices
+    over 4 ranks: 3 for 6, and the overflow passes besides); past it it sends
+    rows that no expert of the rank wants, and every rank holds ``ep`` times
+    its tokens.  Read from the mesh and the configuration, nothing else: one
+    algorithm (dropless passes over the held experts) with the rows brought
+    either way."""
+    return "tokens" if ep <= cfg.expert_top_k else "units"
+
+
+def ep_token_pass_rows(cfg: Config, n_tokens: int, ep: int) -> int:
+    """Rows of one pass of :func:`_ep_gathered` for a rank's ``n_tokens``
+    tokens on ``ep`` ranks: the rows ONE held expert draws from the
+    ``ep * n_tokens`` gathered tokens under uniform routing (k * ep * T / E),
+    in whole tiles of 16; Mellum2's layer: 8,192, sixteen row tiles of the
+    grouped matmul, one to three experts a pass.  It decides time and memory,
+    never the result: a rank takes as many passes as the rows that arrived
+    fill (what arrived and at most one pass's rows more), a fuller rank more
+    than the others.  On the chip twice and four times these rows a pass ran
+    24% and 11% SLOWER (the gathers and scatter-adds cost more a row in
+    larger ops there); smaller is untried (PERF.md section 6, PR 50)."""
+    return -(-cfg.expert_top_k * ep * n_tokens // (16 * cfg.n_experts)) * 16
+
+
+def ep_exchange_plan(cfg: Config, n_tokens: int, ep: int,
+                     itemsize: int = 2) -> Dict[str, Any]:
+    """The account of what :func:`_moe_ffn_ep` moves over ``ep`` ranks for
+    one rank's ``n_tokens`` tokens and one layer: the ``form``
+    (:func:`_ep_form`), the rows of d_model numbers of ``itemsize`` bytes
+    that LEAVE a rank forward and backward (``rows_forward``,
+    ``rows_backward``; ``bytes_*`` their bytes), the rows the held experts
+    take a pass (``pass_rows``) and a grouped matmul sees (``block_rows``).
+
+    ``"tokens"``: forward the gather of the rows and the exchange of the
+    partial sums, backward the gathers of the rows and of the result's
+    cotangent and the exchange of the rows' cotangents, each ``(ep - 1) * T``
+    rows; no pass moves a row between ranks, so an overflow costs no row
+    (``rows_*_overflow`` 0).  ``"units"``: the FIRST pass's two exchanges
+    forward and three backward, ``(ep - 1) * ep_pass_rows`` rows each, and
+    what each overflow pass adds (``rows_*_overflow``, at
+    :func:`ep_overflow_rows`).  The choices, the router's weights and the
+    counts also cross (4 bytes a unit and less): not counted.  Static, from
+    shapes alone (``tests/test_ep_tokens.py`` pins it against the collectives
+    of the layer's jaxpr); the passes a rank took are data:
+    :func:`ep_pass_counts`.  No metric reads it."""
+    T, form = n_tokens, _ep_form(cfg, ep)
+    if form == "tokens":
+        first = over = ep_token_pass_rows(cfg, T, ep)
+        block, sent, sent_over = first, (ep - 1) * T, 0
+    else:
+        share, overflow = (ep_pass_rows(cfg, T, ep),
+                           ep_overflow_rows(cfg, T, ep))
+        first, over, block = ep * share, ep * overflow, math.gcd(share,
+                                                                 overflow)
+        sent, sent_over = (ep - 1) * share, (ep - 1) * overflow
+    row = cfg.d_model * itemsize
+    return {"form": form, "pass_rows": first, "overflow_pass_rows": over,
+            "block_rows": block,
+            "rows_forward": 2 * sent, "rows_backward": 3 * sent,
+            "rows_forward_overflow": 2 * sent_over,
+            "rows_backward_overflow": 3 * sent_over,
+            "bytes_forward": 2 * sent * row, "bytes_backward": 3 * sent * row}
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
+def _ep_gathered(k, R, kernel, axis, xt, wflat, order, arrived, w):
+    """The routed experts, sharded over the mesh axis ``axis``, on this
+    rank's tokens ``xt`` (T, D) -> (T, D), inside a ``shard_map``, with the
+    TOKENS brought to the experts (:func:`_ep_form`): the same sum as
+    :func:`_ep_experts`, each token's k units through their experts' SwiGLUs
+    times the router's weights ``wflat`` (T * k, float32), and with it
+    ``delivered`` (ranks,) int32, the units of each rank's tokens that ran
+    here.  ``order`` lists the units of ALL the ranks' tokens (unit u of the
+    gathered tokens: token u // k, rank u // (k * T)), those of the experts
+    held here first and by expert, so by source rank and token within one
+    (padded to whole passes), ``arrived`` counts them by held expert, ``w``
+    is ``(w_gate, w_up, w_down)`` of the experts held here.
+
+    Every rank's rows are gathered over the axis once, (ranks * T, D), and
+    the router's weights with them.  Then the passes of
+    :func:`_held_experts`, on what this rank has now: a pass takes ``R`` rows
+    of the order (:func:`ep_token_pass_rows`), gathers them from the gathered
+    tokens, runs the held experts (:func:`_held_swiglu`) and adds the results
+    to this rank's PARTIAL sums (ranks * T, D) in float32; as many passes as
+    the units that arrived HERE fill, for no collective stands inside the
+    loop and the ranks need not agree, so none is dropped under any
+    imbalance.  The partial sums, rounded once to the rows' dtype, go home
+    (``parallel.moe.exchange``: block s to rank s) and a token's partials are
+    summed in float32.  Against :func:`_ep_experts`: a row leaves its rank
+    ``ranks - 1`` times where its k units left ``k * (ranks - 1) / ranks``
+    times in whole passes a pair of ranks, and the experts see their rows in
+    ONE order by expert, a pass of the mean expert's rows meeting one to three
+    experts where a per-source block met all that are held (megablox's
+    ``gmm`` visits a row tile once for each expert in it).
+
+    The gradient is written out, for a loop whose length the data decides
+    has no transpose (:func:`_ep_gathered_bwd`): it keeps the inputs alone;
+    the rows are gathered again and the result's cotangent as they were; the
+    same passes run on the same order, each through :func:`_held_swiglu_bwd`,
+    which adds the pass's weight gradients into the float32 sums the loop
+    carries, inside the kernels that form them; the rows' cotangents
+    (ranks * T, D), summed over a rank's units in float32 and rounded once,
+    go home by the exchange and are summed in float32 (the gather's own
+    transpose would be a reduce-scatter in the rows' dtype), the router
+    weights' likewise in float32.  The experts' weight gradients stay on
+    their rank.
+
+    The forward and the backward pass are each a ``jax.jit`` of their own, as
+    :func:`_ep_experts`' are and for its reason: a stack's expert layers
+    share one trace of each loop body, two bodies a program and ONE set of
+    grouped-matmul shapes.
+
+    ``delivered`` is counted in the passes: each row a pass ran, by the rank
+    its token came from (its index over T).  With too few passes, or a mask
+    that leaves rows out, it falls short of the routers' counts."""
+    return _ep_gathered_fwd(k, R, kernel, axis, xt, wflat, order, arrived,
+                            w)[0]
+
+
+def _gathered(rows, axis):
+    """Every rank's ``rows``, rank after rank, under scope
+    ``moe.exchange``."""
+    with jax.named_scope("moe.exchange"):
+        return lax.all_gather(rows, axis, tiled=True)
+
+
+def _summed_home(partials, ranks, axis):
+    """``partials`` (ranks * n, ...), this rank's part of every rank's rows
+    -> (n, ...) float32: block s goes to rank s and the blocks that arrive,
+    every rank's part of this rank's rows, are summed in float32."""
+    home = _exchange(partials.reshape(ranks, -1, *partials.shape[1:]), axis)
+    with jax.named_scope("moe.combine"):
+        return jnp.sum(home, axis=0, dtype=jnp.float32)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
+def _ep_gathered_fwd(k, R, kernel, axis, xt, wflat, order, arrived, w):
+    T = xt.shape[0]
+    xg, wg = (_gathered(a, axis) for a in (xt, wflat))
+    p = xg.shape[0] // T
+
+    def one_pass(i, carry):
+        y, ran = carry
+        with jax.named_scope("moe.dispatch"):
+            token, _, rows, xs, ws, kept = _held_pass(k, R, xg, wg, order,
+                                                      arrived, i)
+        with jax.named_scope("moe.experts"):
+            ys = _held_swiglu(kernel, rows, kept, xs, ws, *w)
+        with jax.named_scope("moe.combine"):
+            return (y.at[token].add(ys.astype(jnp.float32), mode="drop"),
+                    ran + jnp.sum(jax.nn.one_hot(token // T, p,
+                                                 dtype=jnp.int32), axis=0))
+
+    y, ran = lax.fori_loop(
+        0, -(-jnp.sum(arrived) // R), one_pass,
+        (jnp.zeros(xg.shape, jnp.float32), jnp.zeros((p,), jnp.int32)))
+    y = _summed_home(y.astype(xt.dtype), p, axis)
+    return (y.astype(xt.dtype), ran), (xt, wflat, order, arrived, w)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
+def _ep_gathered_bwd(k, R, kernel, axis, saved, given):
+    dy, _ = given
+    xt, wflat, order, arrived, w = saved
+    T = xt.shape[0]
+    xg, wg, dyg = (_gathered(a, axis) for a in (xt, wflat, dy))
+    p = xg.shape[0] // T
+
+    def one_pass(i, grads):
+        dxg, dwg, dw = grads
+        with jax.named_scope("moe.dispatch"):
+            token, unit, rows, xs, ws, kept = _held_pass(k, R, xg, wg, order,
+                                                         arrived, i)
+        with jax.named_scope("moe.combine"):
+            dys = dyg.at[token].get(mode="fill", fill_value=0)
+        with jax.named_scope("moe.experts"):
+            dxs, dws, dw = _held_swiglu_bwd(kernel, rows, kept, xs, ws, w,
+                                            dys, dw)
+        with jax.named_scope("moe.dispatch"):
+            return (dxg.at[token].add(dxs.astype(jnp.float32), mode="drop"),
+                    dwg.at[unit].add(dws[:, 0], mode="drop"), dw)
+
+    zeros = lambda a: jnp.zeros(a.shape, jnp.float32)
+    dxg, dwg, dw = lax.fori_loop(
+        0, -(-jnp.sum(arrived) // R), one_pass,
+        (zeros(xg), zeros(wg), tuple(zeros(a) for a in w)))
+    dxt = _summed_home(dxg.astype(xt.dtype), p, axis)
+    dwflat = _summed_home(dwg, p, axis)
+    none = lambda a: np.zeros(a.shape, jax.dtypes.float0)
+    return (dxt.astype(xt.dtype), dwflat.astype(wflat.dtype), none(order),
+            none(arrived), tuple(g.astype(a.dtype) for g, a in zip(dw, w)))
+
+
+_ep_gathered.defvjp(_ep_gathered_fwd, _ep_gathered_bwd)
+
+
 def _moe_ffn_ep(cfg: Config, lp: Params, x: jax.Array, mesh: Mesh):
     """:func:`_moe_ffn_sorted` on a mesh with an ``ep`` axis, x (B, L, D) ->
     ``(out, (aux, delivered))``.  The batch's rows are sharded over ``dp`` and
     ``ep`` (:func:`_batch_axes`) and each rank holds ``n_experts / ep``
     experts, a contiguous range, whole.  In one ``shard_map`` over the axes
     that share the tokens each rank routes its own over all the experts
-    (:func:`_route_tokens`, the auxiliary terms' means over the ranks too),
-    sorts its units by expert, so by the rank that holds it, and hands them
-    to :func:`_ep_experts`, which sends every unit to its expert and brings
-    the result back: no capacity, no routing group, none dropped.  The router
+    (:func:`_route_tokens`, the auxiliary terms' means over the ranks too).
+    Then the rows meet the held experts, dropless passes either way, brought
+    as the shapes say (:func:`_ep_form`).  On an axis no wider than the
+    choices a token (``ep <= expert_top_k``; Mellum2: 4 <= 8) the TOKENS move:
+    every rank's choices are gathered, the units of all the ranks' tokens
+    whose expert is held here are sorted by expert, and :func:`_ep_gathered`
+    gathers the rows, runs the held experts on them and sends each rank the
+    partial sums of its tokens.  Past that the UNITS move: a rank sorts its
+    own by expert, so by the rank that holds it, and :func:`_ep_experts`
+    sends every unit to its expert and brings the result back.  No capacity,
+    no routing group, none dropped in either.  The router
     and whatever else every rank holds alike enter whole, so their gradients
     leave summed over the ranks; the experts' stay where the experts are.
     The grouped matmul is the Mosaic kernel where every axis of the mesh is
     one of the ``shard_map``'s, ``lax.ragged_dot`` where ``tp`` is left to
     GSPMD.  ``delivered`` (ep, ep) int32: the units of rank s that reached
     the experts of rank r and ran there, at [r, s], counted in the passes
-    themselves (:func:`_ep_experts`); a layer's sum to ``k * B * L``, or a
-    unit was dropped."""
+    themselves (of either form); a layer's sum to ``k * B * L``, or a unit
+    was dropped."""
     from jax import shard_map
 
     _refuse(cfg, "an ep axis")
@@ -1921,12 +2137,24 @@ def _moe_ffn_ep(cfg: Config, lp: Params, x: jax.Array, mesh: Mesh):
         with jax.named_scope("moe.router"):
             weight, expert, units, aux = _route_tokens(cfg, routers, xt,
                                                        shared)
-        with jax.named_scope("moe.dispatch"):
-            order = jnp.argsort(expert.reshape(T * k), stable=True)
-        sizes = ep_pass_rows(cfg, T, ep), ep_overflow_rows(cfg, T, ep)
-        plan = _pass_plan(units, sizes, AXIS_EP)
-        y, delivered = _ep_experts(k, sizes, kernel, AXIS_EP, xt,
-                                   weight.reshape(T * k), order, plan, w)
+        if _ep_form(cfg, ep) == "tokens":
+            held, R = cfg.n_experts // ep, ep_token_pass_rows(cfg, T, ep)
+            chosen = _gathered(expert.reshape(T * k), AXIS_EP)
+            with jax.named_scope("moe.dispatch"):
+                order, at = _held_order(
+                    chosen, held * lax.axis_index(AXIS_EP), held, R)
+                arrived = jnp.sum(jax.nn.one_hot(at, held, dtype=jnp.int32),
+                                  axis=0)
+            y, delivered = _ep_gathered(k, R, kernel, AXIS_EP, xt,
+                                        weight.reshape(T * k), order, arrived,
+                                        w)
+        else:
+            with jax.named_scope("moe.dispatch"):
+                order = jnp.argsort(expert.reshape(T * k), stable=True)
+            sizes = ep_pass_rows(cfg, T, ep), ep_overflow_rows(cfg, T, ep)
+            plan = _pass_plan(units, sizes, AXIS_EP)
+            y, delivered = _ep_experts(k, sizes, kernel, AXIS_EP, xt,
+                                       weight.reshape(T * k), order, plan, w)
         if len(shared) > 1:
             delivered = lax.psum(delivered, tuple(
                 a for a in shared if a != AXIS_EP))
@@ -2630,6 +2858,47 @@ def mtp_loss_parts(cfg: Config, params: Params, batch, mesh=None,
     return main, module / cfg.mtp_coef
 
 
+def _expert_layers_read(cfg: Config, params: Params, tokens: jax.Array,
+                        mesh: Optional[Mesh], attn: str,
+                        mtp_tokens: Optional[jax.Array],
+                        read: Callable) -> jax.Array:
+    """``read(lp, x, aux)`` of every layer with experts on ``tokens``, a row
+    each in the stack's order: the layer's weights, the normed input its
+    router sees (B, L, D) and the ``aux`` its FFN returns, by the code the
+    training step runs, a layer after the other with no checkpoint.  A
+    multi-token-prediction module's layer is one more row, the last, on the
+    next tokens ``mtp_tokens`` it reads (:func:`_mtp_input`)."""
+    if cfg.mtp_layers and mtp_tokens is None:
+        raise ValueError("a configuration with a multi-token-prediction "
+                         "module needs mtp_tokens, the batch's targets")
+    positions = jnp.arange(tokens.shape[1])
+    impls = _mixer_impls(cfg, attn, mesh)
+    h, rows = params["embed"][tokens], []
+
+    def through(h, mixer, ffn, stack):
+        """A run's layers on ``h``, what is read of them added to ``rows``."""
+        def layer(h, lp):
+            h = _attention_block(cfg, lp, h, positions, impls[mixer],
+                                 mixer=mixer)
+            out, aux = _ffn_block(cfg, lp, h, mesh=mesh, ffn=ffn)
+            if ffn != "moe":
+                return out, None
+            return out, read(lp, rms_norm(h, lp["mlp_norm"], cfg.norm_eps),
+                             aux)
+
+        h, read_of = lax.scan(layer, h, stack)
+        if read_of is not None:
+            rows.append(read_of)
+        return h
+
+    for (mixer, ffn, _), stack in zip(layer_runs(cfg), _stacks(cfg, params)):
+        h = through(h, mixer, ffn, stack)
+    if cfg.mtp_layers:
+        through(_mtp_input(cfg, params, h, mtp_tokens, lambda x: x),
+                *cfg.layer_kinds[-1], params["mtp"]["layer"])
+    return jnp.concatenate(rows)
+
+
 def expert_unit_counts(cfg: Config, params: Params, tokens: jax.Array,
                        mesh: Optional[Mesh] = None, attn: str = "full",
                        mtp_tokens: Optional[jax.Array] = None) -> jax.Array:
@@ -2644,35 +2913,40 @@ def expert_unit_counts(cfg: Config, params: Params, tokens: jax.Array,
     module's router is one more row, the last, on the next tokens
     ``mtp_tokens`` it reads (:func:`_mtp_input`)."""
     _refuse(cfg, "expert_unit_counts")
-    if cfg.mtp_layers and mtp_tokens is None:
-        raise ValueError("a configuration with a multi-token-prediction "
-                         "module needs mtp_tokens, the batch's targets")
-    positions = jnp.arange(tokens.shape[1])
-    impls = _mixer_impls(cfg, attn, mesh)
-    h, rows = params["embed"][tokens], []
+    return _expert_layers_read(
+        cfg, params, tokens, mesh, attn, mtp_tokens, lambda lp, x, _:
+        _route_tokens(cfg, lp, x.reshape(-1, x.shape[-1]))[2])
 
-    def through(h, mixer, ffn, stack):
-        """A run's layers on ``h``, its routers' counts added to ``rows``."""
-        def layer(h, lp):
-            h = _attention_block(cfg, lp, h, positions, impls[mixer],
-                                 mixer=mixer)
-            counts = None
-            if ffn == "moe":
-                x = rms_norm(h, lp["mlp_norm"], cfg.norm_eps)
-                counts = _route_tokens(cfg, lp, x.reshape(-1, x.shape[-1]))[2]
-            return _ffn_block(cfg, lp, h, mesh=mesh, ffn=ffn)[0], counts
 
-        h, counts = lax.scan(layer, h, stack)
-        if counts is not None:
-            rows.append(counts)
-        return h
+def ep_pass_counts(cfg: Config, params: Params, tokens: jax.Array,
+                   mesh: Mesh, attn: str = "full") -> jax.Array:
+    """(layers with experts, ep) int32: the passes each rank's held experts
+    took in each layer on a batch, on a mesh whose ``ep`` axis alone shares
+    the tokens (:func:`_moe_ffn_ep`).  The passes are data, so this is a
+    jitted read beside the step, as :func:`expert_unit_counts` is, and from
+    what the passes themselves counted: a layer's ``delivered``, the units of
+    each rank that ran on each rank.  Where the layer gathers tokens
+    (:func:`ep_exchange_plan`'s ``form``) a rank takes the passes its own
+    arrivals fill, so a fuller rank reads more than the others (Mellum2's
+    layer: 16 passes of 8,192 rows are the uniform share, a rank 6% over it
+    takes 17 or 18); where it exchanges units every rank takes the passes of
+    the fullest PAIR of ranks, one and the overflow passes."""
+    ep = _ep_ranks(cfg, mesh)
+    if ep < 2 or any(mesh.shape.get(a, 1) > 1 for a in (AXIS_DP, AXIS_SP)):
+        raise ValueError("ep_pass_counts reads a mesh whose ep axis alone "
+                         f"shares the tokens, not {dict(mesh.shape)}")
+    plan = ep_exchange_plan(cfg, tokens.size // ep, ep)
+    first, over = plan["pass_rows"], plan["overflow_pass_rows"]
 
-    for (mixer, ffn, _), stack in zip(layer_runs(cfg), _stacks(cfg, params)):
-        h = through(h, mixer, ffn, stack)
-    if cfg.mtp_layers:
-        through(_mtp_input(cfg, params, h, mtp_tokens, lambda x: x),
-                *cfg.layer_kinds[-1], params["mtp"]["layer"])
-    return jnp.concatenate(rows)
+    def read(lp, x, aux):
+        delivered = aux[1]
+        if plan["form"] == "tokens":
+            return -(-jnp.sum(delivered, axis=1) // first)
+        most = ep * jnp.max(delivered)      # a pass takes a block a peer
+        return jnp.full((ep,), jnp.minimum(most, 1)
+                        - (-jnp.maximum(most - first, 0) // over))
+
+    return _expert_layers_read(cfg, params, tokens, mesh, attn, None, read)
 
 
 # The deepest stack :func:`apply` inlines; a deeper one it scans.  In a stack

@@ -22,11 +22,15 @@ Two expert layers move tokens over ``ep``, and both through :func:`exchange`,
 the one all-to-all written here.  This module's capacity-bucket layer is the
 building block for a program that wants static buckets and accepts drops; no
 model of ``models/`` uses it.  ``models.llama._moe_ffn_sorted`` is the
-dropless one a model trains with: its units are sorted by destination rank
-and expert and sent a fixed pass of rows a peer at a time (the uniform share
-first, then the overflow in smaller passes), as many passes as arrived, so
-its shapes are as static as the buckets' and nothing is dropped
-(:func:`pass_plan` counts what a rank sends and receives).
+dropless one a model trains with.  On an axis wider than the choices a token
+its units are sorted by destination rank and expert and sent a fixed pass of
+rows a peer at a time (the uniform share first, then the overflow in smaller
+passes), as many passes as arrived, so its shapes are as static as the
+buckets' and nothing is dropped (:func:`pass_plan` counts what a rank sends
+and receives).  On an axis no wider than that it gathers every rank's tokens
+instead, runs the held experts on all of them and sends each rank the partial
+sums of its tokens, by :func:`exchange` again (``models.llama._ep_form``,
+``ep_exchange_plan``).
 """
 
 from __future__ import annotations
