@@ -343,19 +343,21 @@ def main() -> None:
             f"{step_flops/compute_s/n_dev/peak*100:.1f}%)")
 
     # Optional profiler trace of the steady-state window (TPU_PROFILE=1),
-    # with the per-op roofline attribution printed from it.
+    # with device time by scope and pass printed from it.
     if int(os.environ.get("TPU_PROFILE", "0")):
-        from torchmpi_tpu.utils.profiler import op_breakdown, trace
+        from torchmpi_tpu.utils.profiler import load_capture, step_profile
 
-        with trace("/tmp/torchmpi_tpu_bench_trace") as d:
+        d = "/tmp/torchmpi_tpu_bench_trace"
+        jax.profiler.start_trace(d)
+        try:
             run_engine(engine, p2, resident * 6)
+        finally:
+            jax.profiler.stop_trace()
         log(f"bench: profiler trace written to {d}")
         try:
-            b = op_breakdown(d)
-            log(f"bench: {b['total_ms_per_step']:.2f} ms/step attributed "
-                f"over {b['steps']} steps; top categories:")
-            for c, ms, share in b["categories"][:6]:
-                log(f"bench:   {ms:8.2f} ms/step {100*share:5.1f}%  {c}")
+            for row in step_profile(load_capture(d),
+                                    compiled.as_text()).table().splitlines():
+                log(f"bench:   {row}")
         except Exception as e:  # noqa: BLE001 — best-effort diagnostic:
             # a corrupt/stale capture must not abort the benchmark after
             # the full chip run completed.

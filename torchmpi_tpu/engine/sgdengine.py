@@ -210,6 +210,15 @@ class RunRecord:
         # Sums of the step that is open, moved into step_stamps at its end.
         self._wait_ns = 0
         self._hook_ns = 0
+        self.profiler = None    # the StepWindowProfiler closed in the call
+
+    @property
+    def device(self) -> Optional[Dict[str, Any]]:
+        """``utils/profiler.py:StepProfile.summary()`` of the capture, its idle
+        gaps named by these stamps, parsed when read; ``None`` without one."""
+        profile = self.profiler and self.profiler.profile()
+        return profile and dict(profile.summary(), gaps=profile.gaps(
+            self.step_stamps, self.epoch_offset_ns))
 
     def summary(self) -> Dict[str, Any]:
         """The arithmetic, once.  ``step_ms_p50/p90/p95``: percentiles of
@@ -618,6 +627,13 @@ class AllReduceSGDEngine:
             out_shardings=out_sh,
             donate_argnums=(0, 1),
         )
+
+    def step_text(self, state: Dict[str, Any]) -> str:
+        """The text of the compiled step on ``state``'s arrays, which a device
+        capture is joined by: one lowering and compile, no execution."""
+        batch = [_stage(b, self._batch_sh).array for b in state["sample"]]
+        return self._compiled_step.lower(
+            state["params"], state["opt_state"], *batch).compile().as_text()
 
     # ---------------------------------------------------------------- eager
 

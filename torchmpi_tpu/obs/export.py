@@ -8,9 +8,9 @@ JSON (load in ``chrome://tracing`` or ui.perfetto.dev):
 * native phase events (``obs.native``) -> one pid per plane, instant
   ("i") events for start/chunk/retry/error and synthesized "X" events
   for start..complete pairs of the same (correlation, op, rank);
-* the device timeline (``jax.profiler.ProfileData`` over a
-  ``jax.profiler`` xplane capture) -> pid "device:<plane>", one tid per
-  timeline line.
+* the device timeline (``utils/profiler.py:load_capture`` over a
+  ``jax.profiler`` xplane capture) -> pid "device <plane>", one tid per
+  timeline line, at the capture's own origin on the spans' clock.
 
 Cluster (:func:`merge_ranks`) — N per-rank obsdump bundles
 (``obs/aggregate.py``) onto ONE timeline: each rank's spans/events are
@@ -37,6 +37,7 @@ from __future__ import annotations
 
 import json
 import os
+import time
 from typing import Any, Dict, List, Mapping, Optional, Sequence, Tuple
 
 from . import native as obs_native
@@ -135,34 +136,37 @@ def _native_events(events, t0: int,
     return out
 
 
-def _device_events(xplane_path: str, t0_us: float) -> List[Dict[str, Any]]:
-    """The xplane capture's lines as Chrome events, shifted so the
-    earliest device event starts at ``t0_us``."""
-    from jax.profiler import ProfileData
+def _device_events(xplane_path: str, t0: int,
+                   epoch_offset_ns: int) -> List[Dict[str, Any]]:
+    """The capture's device lines as Chrome events ON THE SPANS' CLOCK: a
+    device event counts from the capture's own origin, which the capture
+    states on ``time.time_ns()``, and ``epoch_offset_ns`` (the run record's,
+    the start-up account's, the ``profiler.window`` span's) is what that
+    clock is ahead of the spans' ``time.monotonic_ns()``."""
+    from ..utils.profiler import load_capture
 
-    pd = ProfileData.from_file(xplane_path)
-    raw = [(p_i, l_i, ev.name, ev.start_ns, ev.duration_ns)
-           for p_i, plane in enumerate(pd.planes)
-           for l_i, line in enumerate(plane.lines)
-           for ev in line.events]
-    base = min((r[3] for r in raw), default=0.0)
-    return [{
-        "ph": "X",
-        "name": name,
-        "cat": "device",
-        "pid": _PID_DEVICE + p_i,
-        "tid": l_i,
-        "ts": t0_us + (start_ns - base) / 1e3,
-        "dur": max(dur_ns, 1.0) / 1e3,
-    } for p_i, l_i, name, start_ns, dur_ns in raw]
+    capture = load_capture(xplane_path)
+    shift = capture["profile_start_ns"] - epoch_offset_ns - t0
+    out: List[Dict[str, Any]] = []
+    for p_i, (plane, lines) in enumerate(capture["devices"].items()):
+        out.append(_meta(_PID_DEVICE + p_i, f"device {plane}"))
+        out += [{"ph": "X", "name": name.split(" = ")[0], "cat": "device",
+                 "pid": _PID_DEVICE + p_i, "tid": l_i,
+                 "ts": (start_ns + shift) / 1e3,
+                 "dur": max(dur_ns, 1) / 1e3}
+                for l_i, events in enumerate(lines.values())
+                for name, start_ns, dur_ns in events]
+    return out
 
 
 def chrome_trace(spans: Sequence[Dict[str, Any]],
                  events,
-                 xplane_path: Optional[str] = None) -> Dict[str, Any]:
+                 xplane_path: Optional[str] = None,
+                 epoch_offset_ns: Optional[int] = None) -> Dict[str, Any]:
     """Merge Python spans, native trace events and (optionally) a device
     xplane capture into one Chrome-trace dict (``{"traceEvents": [...]}``).
-    Timestamps are normalized to the earliest host event."""
+    Timestamps are normalized to the earliest host event.
+    ``epoch_offset_ns``: else the ``profiler.window`` span's, else ours."""
     t0_candidates = [s["t0_ns"] for s in spans]
     t0_candidates += [int(e["t_ns"]) for e in events]
     t0 = min(t0_candidates) if t0_candidates else 0
@@ -174,8 +178,12 @@ def chrome_trace(spans: Sequence[Dict[str, Any]],
     trace += _span_events(spans, t0)
     trace += _native_events(events, t0)
     if xplane_path is not None:
-        trace.append(_meta(_PID_DEVICE, "device (xplane)"))
-        trace += _device_events(xplane_path, 0.0)
+        if epoch_offset_ns is None:
+            epoch_offset_ns = next(
+                (int(s["attrs"]["epoch_offset_ns"]) for s in spans
+                 if "epoch_offset_ns" in s["attrs"]),
+                time.time_ns() - time.monotonic_ns())
+        trace += _device_events(xplane_path, t0, epoch_offset_ns)
     return {"traceEvents": trace,
             "displayTimeUnit": "ms",
             "metadata": {"clock": "CLOCK_MONOTONIC, normalized",
