@@ -290,3 +290,37 @@ def test_windowed_flash_at_laguna_widths(v5e, tile):
     assert fwd_used < 16 * 1024 * 1024                  # the default limit
     assert 3 * 2 * L * width * 4 < used <= stated < V5E_VMEM_BYTES
     assert grad.memory_analysis().peak_memory_in_bytes < 0.8 * 4.06e9
+
+
+@pytest.mark.parametrize("N,R,D,held", [(65536, 8192, 2304, 16),
+                                        (16384, 32768, 2048, 8)],
+                         ids=["mellum2", "glm"])
+def test_scatter_add_rows_at_the_cells_passes(v5e, N, R, D, held):
+    """The passes' float32 scatter-add alone, 16 calls in a loop that carries
+    the sums as the expert layer's loops do, at the Mellum2 cell's pass
+    (8,192 rows of 2,304 into 65,536 gathered tokens' sums, 16 held experts)
+    and at the GLM cell's (32,768 rows of 2,048 into 16,384 tokens', 8): one
+    kernel under its own name, the sums a row a slab (what Mosaic will slice
+    a row of: of (N, D) it refuses, "Slice shape along dimension 0 must be
+    aligned to tiling (8), but is 1"), added to in place (no copy of them in
+    the loop), the tile the width's (256 rows), inside the VMEM it states."""
+    from jax import lax
+
+    from torchmpi_tpu.ops.scatter_add_rows import row_tile, scatter_add_rows
+
+    one = SingleDeviceSharding(v5e[0])
+
+    def passes(sums, index, rows, kept):
+        return lax.fori_loop(0, 16, lambda i, s: scatter_add_rows(
+            s, index[i], rows, kept[i]), sums)
+
+    args = (_sds((N, 1, D), jnp.float32, one), _sds((16, R), jnp.int32, one),
+            _sds((R, D), jnp.bfloat16, one), _sds((16, held), jnp.int32, one))
+    program = jax.jit(passes, donate_argnums=0).lower(*args).compile()
+    text = program.as_text()
+    assert _kernels(program) == 1 and "scatter_add_rows" in text
+    assert not re.search(rf"f32\[{N},1,{D}\]\S* (copy|scatter)\(", text)
+    (stated, used), = _kernel_vmem(program)
+    tile = row_tile(D)
+    assert tile == 256
+    assert 4 * tile * D * 4 <= used <= stated < V5E_VMEM_BYTES // 2

@@ -90,7 +90,10 @@ def test_glm_flash_adamw_step_at_published_widths(v5e, monkeypatch):
     assert all("/mla/" in line for line in kernels if "flash_" in line)
     module = [line for line in kernels if re.search(r"[(/]mtp[)/]", line)]
     assert (named("flash_fwd", module), named("flash_bwd", module)) == (1, 1)
-    assert len(kernels) == 6 * 2 + 5 * 11 and len(module) == 2 + 11
+    # an expert layer: 11 grouped matmuls and, since PR 52, the pass's two
+    # scatter-adds (`ops/scatter_add_rows.py`), forward and backward
+    assert len(kernels) == 6 * 2 + 5 * 13 and len(module) == 2 + 13
+    assert named("scatter_add_rows") == 5 * 2
     peak = program.memory_analysis().peak_memory_in_bytes
     # 15.39 GB until PR 41, whose rotation (`llama._rotate_pairs`) leaves
     # the compiler no sequence-on-the-lanes copies of q and k to keep.

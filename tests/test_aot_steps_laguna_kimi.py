@@ -76,7 +76,9 @@ def test_laguna_adamw_step_at_published_widths(v5e, monkeypatch):
     window = [line for line in kernels if "/swa/" in line]
     assert (named("flash_fwd", window), named("flash_bwd", window)) == (3, 3)
     assert len(window) == 6
-    assert len(kernels) == 5 * 2 + 4 * 11               # as before PR 41
+    # as before PR 41, and since PR 52 a pass's two scatter-adds a layer
+    assert len(kernels) == 5 * 2 + 4 * 13
+    assert named("scatter_add_rows") == 4 * 2
     for line in kernels:
         if "flash_" in line:      # q's 72 or 48 heads, K and V at their 8
             heads = [int(n) for n in re.findall(
@@ -174,7 +176,8 @@ def test_kimi_linear_adamw_step_at_published_widths(v5e, monkeypatch):
     assert all(("/kda/" in line) == recurrence(line)
                and re.search(r"[/(]attn[/)]", line)
                for line in kernels if "kda_" in line)
-    assert len(kernels) == 46 + 2 * 4 + 6 * 4
+    assert named("scatter_add_rows") == 4 * 2         # a pass's, each way
+    assert len(kernels) == 46 + 2 * 4 + 6 * 4 + 2 * 4
     # what "full" forms again: the way in and the way out, never a
     # recurrence or a flash kernel
     assert not any("rematted_computation" in line for line in kernels

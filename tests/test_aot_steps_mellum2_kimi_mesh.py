@@ -120,8 +120,17 @@ def test_mellum2_ep4_adamw_step_at_published_widths(v5e, monkeypatch):
     # A layer's grouped matmuls: 3 forward, and gate and up again with the
     # three products' two gradients each backward, ONE body each way (a pass
     # is one block; the forward pass's replay under "full" keeps nothing the
-    # backward pass reads, so it is not there).
-    assert len(kernels) == 4 * 2 + 4 * 11
+    # backward pass reads, so it is not there).  And, since PR 52, the pass's
+    # float32 scatter-add each way (`ops/scatter_add_rows.py`: the results to
+    # their tokens' partial sums forward, the rows' cotangents backward).
+    assert len(kernels) == 4 * 2 + 4 * 13
+    assert named("scatter_add_rows") == 4 * 2
+    # XLA's scatter of a pass's rows into the sums is gone, whichever way the
+    # sums are shaped, and the loops' carry is added to in place: the float32
+    # sums are copied nowhere in the program
+    sums = r"f32\[65536,(1,)?2304\]"
+    assert not [line for line in text.splitlines()
+                if re.search(sums + r"\S* (scatter|copy)\(", line)]
     assert llama.ep_exchange_plan(cfg, 2 * 8192, 4) == {
         "form": "tokens", "pass_rows": 8192, "overflow_pass_rows": 8192,
         "block_rows": 8192,

@@ -20,7 +20,8 @@ import jax.numpy as jnp
 
 from torchmpi_tpu.models import llama
 
-from test_mellum2 import ep_mesh, layer_of, mellum_tiny, rel
+from test_mellum2 import (ep_mesh, file_of, layer_of, mellum_tiny, rel,
+                          reference)  # noqa: F401 — reference is a fixture
 from test_mellum2_passes import traced_anew  # noqa: F401 — a fixture
 
 pytestmark = pytest.mark.usefixtures("quick_compiles")    # conftest.py
@@ -116,6 +117,39 @@ def test_a_fuller_rank_takes_more_passes_and_nothing_is_dropped(monkeypatch):
     assert passes[2] > max(np.delete(passes, 2)) >= 1
     _as_units(monkeypatch)
     _assert_same(got, _run(cfg, lp, x), 1e-5)
+
+
+def test_a_tokens_two_units_in_one_row_tile_are_both_added(reference):
+    """One token of each row of the batch chooses experts 8 and 9 and no other
+    token does: on rank 2, which holds both, the first pass's rows 0 to 7 are
+    expert 8's and rows 8 to 15 expert 9's, the SAME eight tokens, in one row
+    tile of the scatter-add (``ops/scatter_add_rows.py``, 64 rows here).  The
+    kernel takes a tile one expert's segment at a time, so both units land on
+    their token's sum, forward and in the rows' cotangents: the layer and its
+    gradients are the plain reference's, which loops over the experts."""
+    from torchmpi_tpu.ops.scatter_add_rows import row_tile
+
+    cfg, lp, x = _layer()
+    both = jnp.asarray((8, 9))
+    x = x.at[..., 0].set(-5.0).at[:, 3, 0].set(5.0)
+    lp = {**lp, "router": lp["router"].at[:, both].set(0).at[0, both].set(4)}
+    xt = x.reshape(-1, cfg.d_model)
+    units = np.asarray(reference.routed_units(file_of(cfg), lp, xt))
+    assert units[8] == units[9] == B and units.sum() == 4 * B * L
+    assert min(row_tile(cfg.d_model),
+               llama.ep_token_pass_rows(cfg, T, EP)) >= 2 * B
+    mesh = ep_mesh()
+    probe = jax.random.normal(jax.random.PRNGKey(7), xt.shape, jnp.float32)
+    ours = lambda lp, x: jnp.sum(probe * llama._moe_ffn(
+        cfg, lp, x, mesh=mesh)[0].reshape(xt.shape))
+    plain = lambda lp, x: jnp.sum(probe * reference.experts_ffn(
+        file_of(cfg), lp, x.reshape(xt.shape)))
+    got, want = (jax.jit(jax.value_and_grad(f, argnums=(0, 1)))(lp, x)
+                 for f in (ours, plain))
+    assert rel(got[0], want[0]) < 1e-5
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got[1]),
+                            jax.tree.leaves(want[1])):
+        assert rel(a, b) < 1e-5, jax.tree_util.keystr(path)
 
 
 def _a_row_left_out(k, R, xt, wflat, order, arrived, p,

@@ -1474,6 +1474,41 @@ def _held_swiglu(kernel, rows, kept, xs, ws, w_gate, w_up, w_down):
     return product(hs.astype(xs.dtype), w_down)
 
 
+def _row_sums(xt):
+    """Float32 zeros for the sums of the rows ``xt`` (N, D), a row a slab:
+    (N, 1, D), what :func:`_add_rows` adds into."""
+    return jnp.zeros((xt.shape[0], 1, xt.shape[1]), jnp.float32)
+
+
+def _add_rows(kernel, sums, token, rows, kept):
+    """``sums`` (N, 1, D) float32 with one pass's ``rows`` (R, D) added to
+    their tokens' in float32: row r to ``sums[token[r]]``, a row whose token
+    is N (past the units that arrived: :func:`_held_pass`) to none.  The
+    loops carry their sums a row a slab, (N, 1, D): on the chip that shape
+    lies row after row in memory, so a row is one DMA
+    (``ops/scatter_add_rows.py``), where a row of (N, D) is D / 128 pieces of
+    an (8, 128) tiling that Mosaic will not slice.
+
+    With ``kernel`` (the condition :func:`_grouped_matmul` has) the Pallas
+    kernel: it takes the rows one expert's segment at a time (``kept``, the
+    pass's rows by held expert: within a segment a token appears once, and a
+    token's two or three units in one pass lie in different segments, which
+    it takes in turn), moves a row by two DMAs through VMEM, in place, and
+    does not visit the row tiles past the rows that arrived.  XLA's own
+    scatter-add took 273 to 318 ns a row of 2,304 numbers there, a tenth of
+    the HBM's rate (PERF.md section 6, PR 52).  ``at[].add`` where ``tp`` is
+    left to GSPMD, which would refuse to partition a kernel of ours (no
+    cell), and on the chip where a row is not whole lanes of 128 (no cell)."""
+    D = sums.shape[-1]
+    if not kernel or (D % 128 and jax.default_backend() == "tpu"):
+        return sums.at[token].add(rows.astype(jnp.float32)[:, None],
+                                  mode="drop")
+    from ..ops.scatter_add_rows import scatter_add_rows
+
+    return scatter_add_rows(sums, token, rows, kept,
+                            interpret=jax.default_backend() != "tpu")
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
 def _held_experts(k, R, kernel, xt, wflat, order, arrived, w):
     """The routed experts held here on tokens ``xt`` (T, D) -> (T, D): the
@@ -1491,7 +1526,10 @@ def _held_experts(k, R, kernel, xt, wflat, order, arrived, w):
     arrived need, one as a rule: the shapes are static, time and memory are
     those of the units that arrived, and no unit is ever dropped.  Each pass gathers its rows,
     runs the grouped matmuls (:func:`_held_swiglu`) and adds the results to
-    their tokens in float32.  A loop whose length the data decides has no
+    their tokens in float32 (:func:`_add_rows`: since PR 52 a Pallas kernel
+    that moves the rows that arrived by DMA, one expert's segment at a time,
+    into sums the loop carries a row a slab; the rows' cotangents backward
+    likewise; the gathers are XLA's).  A loop whose length the data decides has no
     transpose, so the gradient is written out: it keeps the inputs alone and
     takes the same passes, each through :func:`_held_swiglu`'s own VJP (its
     gate and up products formed again there), the weights' gradients summed
@@ -1507,11 +1545,11 @@ def _held_experts_fwd(k, R, kernel, xt, wflat, order, arrived, w):
         with jax.named_scope("moe.experts"):
             ys = _held_swiglu(kernel, rows, kept, xs, ws, *w)
         with jax.named_scope("moe.combine"):
-            return y.at[token].add(ys.astype(jnp.float32), mode="drop")
+            return _add_rows(kernel, y, token, ys, kept)
 
     y = lax.fori_loop(0, -(-jnp.sum(arrived) // R), one_pass,
-                      jnp.zeros(xt.shape, jnp.float32))
-    return y.astype(xt.dtype), (xt, wflat, order, arrived, w)
+                      _row_sums(xt))
+    return y[:, 0].astype(xt.dtype), (xt, wflat, order, arrived, w)
 
 
 def _held_experts_bwd(k, R, kernel, saved, dy):
@@ -1529,17 +1567,18 @@ def _held_experts_bwd(k, R, kernel, saved, dy):
             dxs, dws, *dwp = jax.vjp(functools.partial(
                 _held_swiglu, kernel, rows, kept), xs, ws, *w)[1](dys)
         with jax.named_scope("moe.dispatch"):
-            return (dxt.at[token].add(f32(dxs), mode="drop"),
+            return (_add_rows(kernel, dxt, token, dxs, kept),
                     dwflat.at[unit].add(dws[:, 0], mode="drop"),
                     tuple(a + f32(b) for a, b in zip(dw, dwp)))
 
     zeros = lambda a: jnp.zeros(a.shape, jnp.float32)
     dxt, dwflat, dw = lax.fori_loop(
         0, -(-jnp.sum(arrived) // R), one_pass,
-        (zeros(xt), zeros(wflat), tuple(zeros(a) for a in w)))
+        (_row_sums(xt), zeros(wflat), tuple(zeros(a) for a in w)))
     none = lambda a: np.zeros(a.shape, jax.dtypes.float0)
-    return (dxt.astype(xt.dtype), dwflat.astype(wflat.dtype), none(order),
-            none(arrived), tuple(g.astype(a.dtype) for g, a in zip(dw, w)))
+    return (dxt[:, 0].astype(xt.dtype), dwflat.astype(wflat.dtype),
+            none(order), none(arrived),
+            tuple(g.astype(a.dtype) for g, a in zip(dw, w)))
 
 
 _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
@@ -1753,7 +1792,10 @@ def _ep_experts(k, rows, kernel, axis, xt, wflat, order, plan, w):
     (``parallel.moe.exchange``: one all-to-all of (ranks, R, D)), runs the
     held experts on what arrived (:func:`_held_swiglu`: a source's rows lie
     by expert, ``arrived`` says how many each), sends the results back the
-    same way and adds them to their tokens in float32.  The first pass takes
+    same way and adds them to their tokens in float32 (XLA's ``at[].add``
+    here, forward and backward: no cell runs this form, so :func:`_add_rows`'
+    kernel, which would take a peer's block one expert's segment at a time,
+    was not wired in and not measured).  The first pass takes
     the uniform share from every pair of ranks; what a pair has above it goes
     in overflow passes a quarter that size, as many as the fullest pair of
     ranks needs, the same number on every rank (``parallel.moe.pass_plan``;
@@ -1913,8 +1955,13 @@ def ep_token_pass_rows(cfg: Config, n_tokens: int, ep: int) -> int:
     never the result: a rank takes as many passes as the rows that arrived
     fill (what arrived and at most one pass's rows more), a fuller rank more
     than the others.  On the chip twice and four times these rows a pass ran
-    24% and 11% SLOWER (the gathers and scatter-adds cost more a row in
-    larger ops there); smaller is untried (PERF.md section 6, PR 50)."""
+    24% and 11% SLOWER (PERF.md section 6, PR 50): that was XLA's lowering of
+    the passes' float32 scatter-adds, 285 ns a row at this pass alone and
+    more at a larger one, and not the gathers (55 ns a row).  Since PR 52 a
+    Pallas kernel adds a pass's rows (:func:`_add_rows`), at a cost a row
+    that does not follow the pass's size (a visit is a row tile of one
+    expert's segment); other sizes are untried with it (PERF.md section 6,
+    PR 52)."""
     return -(-cfg.expert_top_k * ep * n_tokens // (16 * cfg.n_experts)) * 16
 
 
@@ -1977,7 +2024,10 @@ def _ep_gathered(k, R, kernel, axis, xt, wflat, order, arrived, w):
     :func:`_held_experts`, on what this rank has now: a pass takes ``R`` rows
     of the order (:func:`ep_token_pass_rows`), gathers them from the gathered
     tokens, runs the held experts (:func:`_held_swiglu`) and adds the results
-    to this rank's PARTIAL sums (ranks * T, D) in float32; as many passes as
+    to this rank's PARTIAL sums (ranks * T, D) in float32 (:func:`_add_rows`:
+    the Pallas scatter-add of ``ops/scatter_add_rows.py``, by expert's
+    segment, in place; XLA's own took a third of the cell's step, PERF.md
+    section 6, PR 52); as many passes as
     the units that arrived HERE fill, for no collective stands inside the
     loop and the ranks need not agree, so none is dropped under any
     imbalance.  The partial sums, rounded once to the rows' dtype, go home
@@ -1995,7 +2045,8 @@ def _ep_gathered(k, R, kernel, axis, xt, wflat, order, arrived, w):
     same passes run on the same order, each through :func:`_held_swiglu_bwd`,
     which adds the pass's weight gradients into the float32 sums the loop
     carries, inside the kernels that form them; the rows' cotangents
-    (ranks * T, D), summed over a rank's units in float32 and rounded once,
+    (ranks * T, D), summed over a rank's units in float32 by the same
+    scatter-add kernel (:func:`_add_rows`) and rounded once,
     go home by the exchange and are summed in float32 (the gather's own
     transpose would be a reduce-scatter in the rows' dtype), the router
     weights' likewise in float32.  The experts' weight gradients stay on
@@ -2043,14 +2094,14 @@ def _ep_gathered_fwd(k, R, kernel, axis, xt, wflat, order, arrived, w):
         with jax.named_scope("moe.experts"):
             ys = _held_swiglu(kernel, rows, kept, xs, ws, *w)
         with jax.named_scope("moe.combine"):
-            return (y.at[token].add(ys.astype(jnp.float32), mode="drop"),
+            return (_add_rows(kernel, y, token, ys, kept),
                     ran + jnp.sum(jax.nn.one_hot(token // T, p,
                                                  dtype=jnp.int32), axis=0))
 
     y, ran = lax.fori_loop(
         0, -(-jnp.sum(arrived) // R), one_pass,
-        (jnp.zeros(xg.shape, jnp.float32), jnp.zeros((p,), jnp.int32)))
-    y = _summed_home(y.astype(xt.dtype), p, axis)
+        (_row_sums(xg), jnp.zeros((p,), jnp.int32)))
+    y = _summed_home(y[:, 0].astype(xt.dtype), p, axis)
     return (y.astype(xt.dtype), ran), (xt, wflat, order, arrived, w)
 
 
@@ -2073,14 +2124,14 @@ def _ep_gathered_bwd(k, R, kernel, axis, saved, given):
             dxs, dws, dw = _held_swiglu_bwd(kernel, rows, kept, xs, ws, w,
                                             dys, dw)
         with jax.named_scope("moe.dispatch"):
-            return (dxg.at[token].add(dxs.astype(jnp.float32), mode="drop"),
+            return (_add_rows(kernel, dxg, token, dxs, kept),
                     dwg.at[unit].add(dws[:, 0], mode="drop"), dw)
 
     zeros = lambda a: jnp.zeros(a.shape, jnp.float32)
     dxg, dwg, dw = lax.fori_loop(
         0, -(-jnp.sum(arrived) // R), one_pass,
-        (zeros(xg), zeros(wg), tuple(zeros(a) for a in w)))
-    dxt = _summed_home(dxg.astype(xt.dtype), p, axis)
+        (_row_sums(xg), zeros(wg), tuple(zeros(a) for a in w)))
+    dxt = _summed_home(dxg[:, 0].astype(xt.dtype), p, axis)
     dwflat = _summed_home(dwg, p, axis)
     none = lambda a: np.zeros(a.shape, jax.dtypes.float0)
     return (dxt.astype(xt.dtype), dwflat.astype(wflat.dtype), none(order),

@@ -14,6 +14,10 @@ decay and gate, a fused kernel each way in and out).  ``ops/tgmm.py`` is the
 grouped matmul of a weight gradient that adds into float32 sums it is given
 (``tgmm.tgmm_add``: megablox's ``tgmm`` with the empty groups not visited),
 which the ``ep`` path's backward pass sums the experts' gradients with.
+``ops/scatter_add_rows.py`` adds a pass's rows to the float32 sums of the
+tokens they name, one expert's segment at a time, by DMA through VMEM and in
+place (``scatter_add_rows.scatter_add_rows``), where the expert layer's
+passes ran XLA's scatter-add.
 ``ops/ssd.py`` is Mamba-2's state-space scan in its chunked form, forward and
 a hand-written backward with a float32 state, as XLA products (``ssd.ssd``),
 and the passes round it (``ssd.conv_silu``, ``ssd.gated_norm``); the layer
